@@ -138,7 +138,7 @@ _STATE_SHAPE_OPS = frozenset({"reshape", "squeeze", "transpose",
                               "convert_element_type"})
 _CMP = frozenset({"eq", "ne"})
 # call-like prims whose single sub-jaxpr maps invars/outvars positionally
-_CALL_PRIMS = frozenset({"pjit", "closed_call", "core_call", "remat",
+_CALL_PRIMS = frozenset({"jit", "closed_call", "core_call", "remat",
                          "checkpoint", "custom_jvp_call",
                          "custom_vjp_call", "custom_vjp_call_jaxpr",
                          "custom_jvp_call_jaxpr"})
@@ -485,12 +485,7 @@ class _Analyzer:
 
     @staticmethod
     def _kernel_name(eqn) -> str:
-        name = ""
-        for k in ("name", "name_and_src_info", "debug"):
-            v = eqn.params.get(k)
-            if v is not None:
-                name += str(v)
-        return name
+        return str(eqn.params.get("name") or "")
 
     def _pallas_lock_kernel(self, eqn) -> bool:
         """The fused lock pass (ops/pallas_gather.lock_arbitrate): named

@@ -216,7 +216,7 @@ def _subst_var(old, new):
 
 
 def _fresh_var(aval) -> jcore.Var:
-    return jcore.Var("", aval)
+    return jcore.Var(aval)
 
 
 def _aval_bytes(aval) -> int:
@@ -477,7 +477,7 @@ def _find_drop_donation(trace, flow):
     cost._footprint credits the donation discount to)."""
     out = []
     for i, eqn in enumerate(trace.jaxpr.eqns):
-        if eqn.primitive.name != "pjit":
+        if eqn.primitive.name != "jit":
             continue
         don = tuple(eqn.params.get("donated_invars") or ())
         if not any(don):

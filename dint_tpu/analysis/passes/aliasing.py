@@ -93,7 +93,7 @@ def aliasing(trace: TargetTrace) -> list[Finding]:
                         suggestion="thread the kernel's OUTPUT to the "
                                    "later use, or drop the alias"))
 
-        elif ctx.prim == "pjit":
+        elif ctx.prim == "jit":
             donated = eqn.params.get("donated_invars") or ()
             if not any(donated):
                 continue

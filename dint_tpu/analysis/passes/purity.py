@@ -17,8 +17,9 @@ Two detection layers:
   * eqn scan: callback-class primitives inside the jaxpr —
     `pure_callback` / `io_callback` / unbatched `custom_partitioning`
     callbacks -> ERROR (host round-trip per step);
-    `debug_callback` (jax.debug.print / jax.debug.callback) -> WARNING
-    (tolerable while debugging, never in the benchmarked path);
+    `debug_print` / `debug_callback` (jax.debug.print /
+    jax.debug.callback; both reported under the `debug_callback` code)
+    -> WARNING (tolerable while debugging, never in the benchmarked path);
     `infeed` / `outfeed` -> ERROR.
 """
 from __future__ import annotations
@@ -30,7 +31,8 @@ _HOST_SYNC = {"pure_callback": SEV_ERROR,
               "io_callback": SEV_ERROR,
               "infeed": SEV_ERROR,
               "outfeed": SEV_ERROR,
-              "debug_callback": SEV_WARNING}
+              "debug_callback": SEV_WARNING,
+              "debug_print": SEV_WARNING}
 
 
 @register_pass("purity")
@@ -54,10 +56,11 @@ def purity(trace: TargetTrace) -> list[Finding]:
         sev = _HOST_SYNC.get(ctx.prim)
         if sev is None:
             continue
-        what = ("debug print/callback" if ctx.prim == "debug_callback"
-                else "host callback")
+        debug = ctx.prim in ("debug_callback", "debug_print")
+        what = "debug print/callback" if debug else "host callback"
         out.append(Finding(
-            "purity", ctx.prim, sev, trace.name,
+            "purity", "debug_callback" if debug else ctx.prim, sev,
+            trace.name,
             f"{what} `{ctx.prim}` inside the jitted step: the device "
             "program stalls on a host round-trip every step",
             primitive=ctx.prim, site=site_of(ctx.eqn),

@@ -18,9 +18,10 @@ Checks, walking shard_map bodies with the eqn's mesh in scope:
     destination out of range -> ERROR; duplicate destination (two senders
     into one receiver lane: the backend keeps an unspecified one) or
     duplicate source -> ERROR;
-  * `shard_map` with `check_rep=False` -> INFO: replication checking is
-    delegated to this pass (the old-jax shim in parallel/__init__.py
-    disables the built-in checker because it cannot type pallas_call).
+  * `shard_map` with `check_vma=False` -> INFO: varying-manual-axes
+    typing is off (ops/pallas_gather.shard_map_check_vma: a body that
+    runs Pallas kernels in interpret mode, whose interpreter drops the
+    types) and this pass's axis checks are what remains.
 """
 from __future__ import annotations
 
@@ -50,12 +51,12 @@ def shard_consistency(trace: TargetTrace) -> list[Finding]:
         eqn, site, path = ctx.eqn, site_of(ctx.eqn), "/".join(ctx.path)
 
         if ctx.prim == "shard_map":
-            if eqn.params.get("check_rep") is False:
+            if eqn.params.get("check_vma") is False:
                 out.append(Finding(
-                    "shard_consistency", "check-rep-disabled", SEV_INFO,
+                    "shard_consistency", "check-vma-disabled", SEV_INFO,
                     trace.name,
-                    "shard_map runs with check_rep=False (the old-jax "
-                    "pallas compatibility shim): built-in replication "
+                    "shard_map runs with check_vma=False (interpret-mode "
+                    "Pallas kernels in the body): built-in varying-axes "
                     "typing is off, this pass's axis checks are the "
                     "standing substitute",
                     primitive=ctx.prim, site=site, path=path))

@@ -123,14 +123,14 @@ class StoreClient(_SteppedClient):
         run0 = None
         if self.use_scan:
             run0 = run_mod.from_table(table, delta_cap=int(delta_cap))
-            if up and not pg.scan_kernels_available(
+            if up:
+                pg.scan_kernels_available(
                     n_idx=width, lg=self.scan_max + run0.delta_cap,
-                    vw=val_words):
-                up = False
+                    vw=val_words)
         hot = None
         if self.use_hotset:
-            if up and not pg.hot_kernels_available(n_idx=width):
-                up = False
+            if up:
+                pg.hot_kernels_available(n_idx=width)
             frac = 0.04 if hot_frac is None else float(hot_frac)
             # mirror ids are key_lo < hot_n; keys are 1-based, so cover
             # keys 1..frac*n with hot_n = frac*n + 1

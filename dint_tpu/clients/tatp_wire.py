@@ -25,7 +25,7 @@ hundreds outstanding via per-uthread resend loops
 sockets and chunks are pipelined concurrently across them, each socket
 being its own u8-ord space. Unanswered lanes retry on their own socket;
 after `max_tries` the lane is marked Reply.TIMEOUT and its txn is counted
-in the ab_timeout taxonomy (the reference resends forever, so loss shows
+in the ab_timeout classification (the reference resends forever, so loss shows
 up as latency; a capped budget must yield a number + timeout count, not a
 voided run). Replies whose echoed ord/key/table do not match a STILL
 OUTSTANDING request are late stragglers from a timed-out try and are
@@ -90,7 +90,7 @@ class WireCoordinator(tc.Coordinator):
     """tc.Coordinator with every wave crossing the wire to 3 UDP servers.
 
     Inherits the whole transaction state machine (run_cohort: mix/NURand
-    generation, wave structure, abort taxonomy, magic asserts) — only the
+    generation, wave structure, abort classes, magic asserts) — only the
     transport differs, exactly like the reference's client_udp vs
     client_caladan variants share their txn logic."""
 

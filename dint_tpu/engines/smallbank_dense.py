@@ -622,22 +622,22 @@ def build_pipelined_runner(n_accounts: int, w: int = 8192,
       init(db)        -> carry with one bootstrap cohort in flight
       drain(carry)    -> (db, stats [1, N_STATS]) flushing the pipeline
 
-    ``use_pallas``: None = honor DINT_USE_PALLAS env; Mosaic failure falls
-    back to the XLA gathers (ops/pallas_gather.resolve_use_pallas).
+    ``use_pallas``: None = honor DINT_USE_PALLAS env; a Mosaic refusal
+    raises pg.KernelRefused (ops/pallas_gather.resolve_use_pallas).
 
     ``use_hotset``: None = honor DINT_USE_HOTSET env. Serves the step's
     random gathers through the dintcache hot/cold partition; the hot set
     defaults to the WORKLOAD's hot set (``hot_frac``, else the SmallBank
     90%/4% skew constant) so the mirror covers exactly the keys the skew
     concentrates on. init() attaches the mirror to a db that lacks one.
-    A Mosaic rejection of the hot kernels degrades the serving backend to
-    the XLA index-compare partition, never the split itself.
+    Without use_pallas the XLA index-compare partition serves the split;
+    with it, a Mosaic refusal of the hot kernels raises.
 
     ``use_fused``: None = honor DINT_USE_FUSED env; True/False forces.
     Routes the step through the round-12 megakernels (gather-stream
     lock_validate + scatter-stream install_log) after probing them at
-    this runner's geometry; probe failure degrades to the unfused path
-    with a logged warning (pg.resolve_use_fused).
+    this runner's geometry; a probe failure raises pg.KernelRefused
+    (pg.resolve_use_fused).
 
     ``monitor``: thread the dintmon counter plane — the carry grows a
     trailing monitor.Counters leaf and drain returns (db, stats,
@@ -664,8 +664,8 @@ def build_pipelined_runner(n_accounts: int, w: int = 8192,
     if use_hotset:
         frac = wl.SB_HOT_FRAC if hot_frac is None else float(hot_frac)
         hot_n = max(1, min(int(n_accounts * frac), n_accounts))
-        if use_pallas and not pg.hot_kernels_available(n_idx=w * L):
-            use_pallas = False      # partition stays; XLA serves it
+        if use_pallas:
+            pg.hot_kernels_available(n_idx=w * L)
     ew3 = N_SHARDS * (logring.HDR_WORDS + VW)
     scat_geoms = ((w * L, 1), (w * L, ew3))
     if use_hotset:

@@ -364,9 +364,9 @@ def build_serve_runner(n_keys: int, w: int = 4096,
     generated and the carry/jaxpr are unchanged from the point engine.
 
     ``use_pallas``: None = honor DINT_USE_PALLAS; gates BOTH the point
-    gathers and the sequential-DMA scan_rows kernel (probe-and-degrade:
-    a Mosaic rejection of the scan kernel at this geometry falls back
-    to the XLA slab route, bit-identical by contract).
+    gathers and the sequential-DMA scan_rows kernel (bit-identical to
+    the XLA slab route by contract; a Mosaic refusal raises
+    pg.KernelRefused).
 
     ``serve``: variable-occupancy mode — run takes occ/shed i32
     [cohorts_per_block]; lanes >= occ are masked to NOP/PAD before the

@@ -90,7 +90,7 @@ KIND_NAMES: dict[int, str] = {
     EV_OUTCOME: "outcome",
 }
 
-# EV_OUTCOME aux payload: the dintmon abort taxonomy, one code per ab_*
+# EV_OUTCOME aux payload: the dintmon abort classes, one code per ab_*
 CAUSE_COMMIT = 0
 CAUSE_LOCK = 1     # ab_lock
 CAUSE_MISSING = 2  # ab_missing

@@ -206,7 +206,7 @@ def slowest(groups: dict[int, list[dict]], n: int = 10) -> list[dict]:
 def aborts(groups: dict[int, list[dict]],
            by_cause: bool = False) -> dict:
     """Aborted txns (final classification != commit); ``by_cause`` folds
-    them into the dintmon ab_* taxonomy with example txn ids."""
+    them into the dintmon ab_* classification with example txn ids."""
     rows = [{"txn": txn, "cause": oc,
              "events": len(g),
              "step": max(e["step"] for e in g

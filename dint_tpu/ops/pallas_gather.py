@@ -84,30 +84,31 @@ mirror write-through, and the replication-log append = `install_log`).
 Every stream is the round-6/10 ring verbatim — only dispatch boundaries
 are removed — so outputs stay bit-identical to the unfused path
 (tests/test_fused_ops.py) and `resolve_use_fused()` carries the same
-probe-and-degrade contract below.
+refusal contract below.
 
-Fallback contract (ISSUE 1): Mosaic rejection must DEGRADE, not crash —
-round 3 already hit one such rejection class (scalar VMEM stores,
-tools/profile_pallas.py). `resolve_use_pallas()` therefore compiles + runs
-both kernels at the caller's real lane geometry (tiny tables — the failure
-modes are construct/SMEM-budget level, not table-size level) and verifies
-the gather against `jnp.take` before saying yes; any exception or mismatch
-logs one warning and returns False, and every builder falls back to the
-XLA path. The hot-set kernels carry the same contract through
-`hot_kernels_available()`, and the hot PARTITION itself has a pure-XLA
-form (`hot_gather`'s index-compare partition + small-array gather), so a
-Mosaic rejection costs the VMEM residency, never the hot-set split. The
-probes cache per (backend, interpret, kernel, geometry) —
-`kernels_available` re-probes only the kernel whose geometry changed, so
-a builder rebuild (bench.py's full-geometry fallback) no longer recompiles
-probes it already ran. On CPU every kernel runs under `interpret=True`
-(the Mosaic pipeline never runs), which is what makes the whole layer
-tier-1-testable without hardware.
+Refusal contract (ISSUE 25): a kernel that was ASKED FOR never degrades
+in silence. `resolve_use_pallas()` / `resolve_use_fused()` / the
+`*_kernels_available()` probes compile + run the requested kernels at the
+caller's real lane geometry (tiny tables — the failure modes are
+construct/SMEM-budget level, not table-size level) and check them against
+their XLA forms; a Mosaic refusal or a mismatch RAISES `KernelRefused`
+naming the kernel and carrying the compiler's text, so nobody runs the
+XLA route believing a kernel served it. The XLA forms (`hot_gather`'s
+index-compare partition, `scan_slab`, the unfused dispatches) stay as
+what a caller gets when it does NOT ask. Only successful probes are
+cached, per (backend, interpret, kernel, geometry): a rebuild never
+re-compiles a probe that passed, and a refusal is raised every time. On
+CPU every kernel runs under `interpret=True` (the Mosaic pipeline never
+runs), which is what makes the layer tier-1-testable without hardware —
+and why only a compile for the chip (tests/test_chip_compile.py) says
+whether Mosaic accepts a kernel. On TPU v5e it refuses every kernel in
+this file at the row widths the engines use: a row slice of a 1-D HBM
+table must be a multiple of 1024 words, of a 2-D view a multiple of 128
+(PERF.md "Round 25").
 """
 from __future__ import annotations
 
 import functools
-import logging
 import os
 
 import jax
@@ -123,7 +124,12 @@ RMW_SLOTS = 8    # outstanding read DMAs in the lock RMW ring
 WIN = 2 * RMW_SLOTS   # recent-grant window: covers every write a read
 #                       prefetched RMW_SLOTS ahead can race (see module doc)
 
-log = logging.getLogger("dint_tpu.pallas")
+def _out(shape, *operands) -> jax.ShapeDtypeStruct:
+    """u32 `out_shape` entry that varies over every mesh axis any operand
+    varies over: inside `jax.shard_map(check_vma=True)` a pallas_call
+    must say so itself (outside one the set is empty)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, U32, vma=vma)
 
 
 def use_interpret() -> bool:
@@ -133,6 +139,15 @@ def use_interpret() -> bool:
     if env is not None:
         return env != "0"
     return jax.default_backend() != "tpu"
+
+
+def shard_map_check_vma(runs_kernels: bool) -> bool:
+    """`check_vma` for a `jax.shard_map` whose body may run these kernels.
+    Compiled kernels type-check (`_out` declares their outputs varying);
+    JAX's Pallas HLO interpreter drops the varying-manual-axes types
+    inside its own grid loop and says to pass check_vma=False, so only a
+    body that runs kernels in interpret mode turns the check off."""
+    return not (runs_kernels and use_interpret())
 
 
 def env_use_pallas() -> bool:
@@ -156,7 +171,7 @@ def resolve_use_scan(explicit: bool | None = None) -> bool:
     explicit kwarg wins, else the DINT_USE_SCAN env. No kernel probe here
     — the scan path has a pure-XLA slab gather (scan_slab); whether the
     streaming DMA kernel serves it rides the engine's use_pallas
-    resolution (scan_kernels_available), per the round-6/10 contract."""
+    resolution (scan_kernels_available)."""
     if explicit is None:
         return env_use_scan()
     return bool(explicit)
@@ -222,14 +237,15 @@ def gather_rows(tab, idx, vw: int = 1, interpret: bool | None = None):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA((NSLOTS,))],
     )
     return pl.pallas_call(
         functools.partial(_gather_kernel, vw, NSLOTS),
+        name="gather_rows",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((k * vw,), U32),
+        out_shape=_out((k * vw,), idx, tab),
         interpret=bool(interpret),
     )(idx.astype(I32), tab)
 
@@ -313,9 +329,9 @@ def gather_rows_hot(tab, mirror, idx, midx, vw: int = 1,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((mirror.shape[0],), U32),
             pltpu.SemaphoreType.DMA(()),
@@ -324,16 +340,17 @@ def gather_rows_hot(tab, mirror, idx, midx, vw: int = 1,
     )
     return pl.pallas_call(
         functools.partial(_gather_hot_kernel, vw, NSLOTS),
+        name="gather_rows_hot",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((k * vw,), U32),
+        out_shape=_out((k * vw,), idx, midx, tab, mirror),
         interpret=bool(interpret),
     )(idx.astype(I32), midx.astype(I32), tab, mirror)
 
 
 def _xla_hot_gather(tab, mirror, idx, midx, vw: int):
-    """The XLA fallback partition: index-compare + small-array gather.
-    Same semantics as the kernel; exists so a Mosaic rejection costs the
-    VMEM residency, never the hot-set split."""
+    """The XLA partition: index-compare + small-array gather. Same
+    semantics as the kernel; what use_hotset without use_pallas runs, and
+    the probe's ground truth."""
     flat_c = (idx[:, None] * vw + jnp.arange(vw, dtype=I32)).reshape(-1)
     mc = jnp.maximum(midx, 0)
     flat_h = (mc[:, None] * vw + jnp.arange(vw, dtype=I32)).reshape(-1)
@@ -427,28 +444,28 @@ def scan_rows(run_hi, run_lo, run_ver, run_val, off, order, lg: int,
     if interpret is None:
         interpret = use_interpret()
     k = off.shape[0]
+    ops = (off, order, run_hi, run_lo, run_ver, run_val)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 4,
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 4,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 4,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 4,
         scratch_shapes=[pltpu.SemaphoreType.DMA((NSLOTS, 4))],
     )
     return pl.pallas_call(
         functools.partial(_scan_kernel, vw, lg, NSLOTS),
+        name="scan_rows",
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((k * lg,), U32),
-                   jax.ShapeDtypeStruct((k * lg,), U32),
-                   jax.ShapeDtypeStruct((k * lg,), U32),
-                   jax.ShapeDtypeStruct((k * lg * vw,), U32)],
+        out_shape=[_out((k * n,), *ops) for n in (lg, lg, lg, lg * vw)],
         interpret=bool(interpret),
-    )(off.astype(I32), order.astype(I32), run_hi, run_lo, run_ver, run_val)
+    )(off.astype(I32), order.astype(I32), *ops[2:])
 
 
 def _xla_scan_slab(run_hi, run_lo, run_ver, run_val, off, lg: int, vw: int):
-    """The XLA fallback partition: per-lane dynamic-slice-shaped gathers
-    of the same contiguous windows. Costs random-gather issue rate where
-    the kernel streams, never correctness."""
+    """The XLA form: per-lane dynamic-slice-shaped gathers of the same
+    contiguous windows (random-gather issue rate where the kernel
+    streams). What use_scan without use_pallas runs, and the probe's
+    ground truth."""
     idx = off[:, None] + jnp.arange(lg, dtype=I32)[None, :]
     widx = (idx * vw)[:, :, None] + jnp.arange(vw, dtype=I32)[None, None, :]
     return (run_hi[idx], run_lo[idx], run_ver[idx],
@@ -561,11 +578,11 @@ def scatter_rows_hot(tab, mirror, idx, midx, mask, vals, vw: int = 1,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)),
         scratch_shapes=[
             pltpu.SMEM((NSLOTS,), I32),     # tlane: lane holding tab slot
             pltpu.SMEM((NSLOTS,), I32),     # mlane: lane holding mir slot
@@ -575,9 +592,10 @@ def scatter_rows_hot(tab, mirror, idx, midx, mask, vals, vw: int = 1,
     )
     return pl.pallas_call(
         functools.partial(_scatter_hot_kernel, vw, NSLOTS),
+        name="scatter_rows_hot",
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct(tab.shape, U32),
-                   jax.ShapeDtypeStruct(mirror.shape, U32)),
+        out_shape=tuple(_out(t.shape, idx, midx, mask, vals, tab, mirror)
+                        for t in (tab, mirror)),
         # operands 4/5 (post scalar-prefetch: vals, tab, mirror) -> in-place
         input_output_aliases={4: 0, 5: 1},
         interpret=bool(interpret),
@@ -803,9 +821,9 @@ def lock_arbitrate(arb, rows, active, step, k_arb: int,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)),
         scratch_shapes=[
             pltpu.SMEM((RMW_SLOTS,), U32),    # rbuf: in-flight read words
             pltpu.SMEM((RMW_SLOTS,), U32),    # wbuf: in-flight write words
@@ -820,9 +838,10 @@ def lock_arbitrate(arb, rows, active, step, k_arb: int,
     )
     arb2, grant = pl.pallas_call(
         functools.partial(_arbitrate_kernel, k_arb, hot_n),
+        name="lock_arbitrate",
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct(arb.shape, U32),
-                   jax.ShapeDtypeStruct((m,), U32)),
+        out_shape=tuple(_out(shp, rows, active, step, arb)
+                        for shp in (arb.shape, (m,))),
         # operand 3 (post scalar-prefetch) -> output 0: in-place arb update
         input_output_aliases={3: 0},
         interpret=bool(interpret),
@@ -931,12 +950,12 @@ def lock_validate(arb, meta, vidx, vv1, ridx, rows, active, step,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)),
         scratch_shapes=[
             pltpu.SMEM((RMW_SLOTS,), U32),    # rbuf: in-flight read words
             pltpu.SMEM((RMW_SLOTS,), U32),    # wbuf: in-flight write words
@@ -956,11 +975,11 @@ def lock_validate(arb, meta, vidx, vv1, ridx, rows, active, step,
     )
     arb2, grant, vbad, rmeta = pl.pallas_call(
         functools.partial(_lock_validate_kernel, k_arb, hot_n),
+        name="lock_validate",
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct(arb.shape, U32),
-                   jax.ShapeDtypeStruct((m,), U32),
-                   jax.ShapeDtypeStruct((v,), U32),
-                   jax.ShapeDtypeStruct((r,), U32)),
+        out_shape=tuple(
+            _out(shp, vidx, vv1, ridx, rows, active, step, meta, arb)
+            for shp in (arb.shape, (m,), (v,), (r,))),
         # operand 7 (post scalar-prefetch: meta, arb) -> output 0
         input_output_aliases={7: 0},
         interpret=bool(interpret),
@@ -996,17 +1015,18 @@ def gather_streams(tabs, idxs, vws: tuple, interpret: bool | None = None):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=s_n,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * s_n,
-        out_specs=tuple(pl.BlockSpec(memory_space=pltpu.ANY)
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * s_n,
+        out_specs=tuple(pl.BlockSpec(memory_space=pl.ANY)
                         for _ in range(s_n)),
         scratch_shapes=[pltpu.SemaphoreType.DMA((NSLOTS,))
                         for _ in range(s_n)],
     )
     return pl.pallas_call(
         functools.partial(_gather_streams_kernel, tuple(vws), NSLOTS),
+        name="gather_streams",
         grid_spec=grid_spec,
         out_shape=tuple(
-            jax.ShapeDtypeStruct((idxs[s].shape[0] * vws[s],), U32)
+            _out((idxs[s].shape[0] * vws[s],), *idxs, *tabs)
             for s in range(s_n)),
         interpret=bool(interpret),
     )(*idxs, *tabs)
@@ -1107,8 +1127,8 @@ def scatter_streams(tabs, idxs, vals, vws: tuple,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=s_n,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * (2 * s_n),
-        out_specs=tuple(pl.BlockSpec(memory_space=pltpu.ANY)
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (2 * s_n),
+        out_specs=tuple(pl.BlockSpec(memory_space=pl.ANY)
                         for _ in range(s_n)),
         scratch_shapes=(
             [pltpu.SMEM((NSLOTS,), I32) for _ in range(s_n)]
@@ -1116,8 +1136,9 @@ def scatter_streams(tabs, idxs, vals, vws: tuple,
     )
     return pl.pallas_call(
         functools.partial(_scatter_streams_kernel, tuple(vws), NSLOTS),
+        name="scatter_streams",
         grid_spec=grid_spec,
-        out_shape=tuple(jax.ShapeDtypeStruct(t.shape, U32) for t in tabs),
+        out_shape=tuple(_out(t.shape, *idxs, *vals, *tabs) for t in tabs),
         # operands 2S+s (post scalar-prefetch: vals x S, tabs x S) -> s
         input_output_aliases={2 * s_n + s: s for s in range(s_n)},
         interpret=bool(interpret),
@@ -1139,34 +1160,35 @@ def _xla_scatter_streams(tabs, idxs, vals, vws):
     return tuple(outs)
 
 
-# ------------------------------------------------------ fallback plumbing
+# ------------------------------------------------------- probe plumbing
 
-# per-kernel probe results, keyed ("gather"|"lock"|"hot", backend,
-# interpret, geometry...): a builder rebuild that reuses one kernel's
-# geometry never re-compiles that kernel's probe just because the OTHER
-# kernel's geometry (or None-ness) changed — bench.py's full-geometry
-# fallback rebuild used to pay the gather probe twice for exactly that
+
+class KernelRefused(RuntimeError):
+    """A Pallas kernel the caller asked for does not compile, or does not
+    match its XLA form, on this backend."""
+
+
 def _probe_key(kernel: str, *geom) -> tuple:
     return (kernel, jax.default_backend(), use_interpret()) + geom
 
 
-_probe_cache: dict[tuple, bool] = {}
+# probes that PASSED, keyed (kernel, backend, interpret, geometry...): a
+# builder rebuild that reuses one kernel's geometry never re-compiles that
+# kernel's probe. A refusal is never cached: it raises every time
+_probe_cache: set[tuple] = set()
 
 
 def _probed(key, probe) -> bool:
-    hit = _probe_cache.get(key)
-    if hit is not None:
-        return hit
-    ok = True
+    if key in _probe_cache:
+        return True
     try:
         probe()
-    except Exception as e:  # Mosaic rejection / SMEM overflow / interp bug
-        log.warning("pallas kernel probe %s unavailable on %s (falling "
-                    "back to the XLA path): %r", key[0],
-                    jax.default_backend(), repr(e)[:300])
-        ok = False
-    _probe_cache[key] = ok
-    return ok
+    except Exception as e:  # Mosaic refusal / SMEM overflow / mismatch
+        raise KernelRefused(
+            f"pallas kernel {key[0]!r} (geometry {key[3:]}) was asked for "
+            f"and is refused on backend {key[1]!r}: {e}") from e
+    _probe_cache.add(key)
+    return True
 
 
 def _probe_gather(n_idx: int) -> bool:
@@ -1231,8 +1253,7 @@ def _probe_hot(n_idx: int, vw: int = 1) -> bool:
 
 def _probe_scan(n_idx: int, lg: int, vw: int) -> bool:
     """Compile + run scan_rows at the caller's lane geometry over a tiny
-    run and check it bit-for-bit against the XLA slab gather. Same
-    degrade contract as the other probes."""
+    run and check it bit-for-bit against the XLA slab gather."""
     def probe():
         n = max(lg + 8, 64)
         hi = jnp.arange(n, dtype=U32)
@@ -1255,9 +1276,8 @@ def _probe_scan(n_idx: int, lg: int, vw: int) -> bool:
 
 def scan_kernels_available(n_idx: int = 512, lg: int = 16,
                            vw: int = 4) -> bool:
-    """Availability probe for the dintscan streaming slab kernel. Same
-    degrade contract as kernels_available: False routes the scan path to
-    the XLA slab gather (bandwidth cost, never correctness)."""
+    """Probe the dintscan streaming slab kernel: True, or KernelRefused
+    (same contract as kernels_available)."""
     return _probe_scan(n_idx, lg, vw)
 
 
@@ -1265,34 +1285,35 @@ def kernels_available(n_idx: int = 512, m_lock: int | None = 64,
                       k_arb: int = 18) -> bool:
     """Compile AND run the requested kernels at the caller's lane geometry
     (small tables — SMEM budget scales with lane count, not table bytes),
-    checking the gather against jnp.take. Any exception or mismatch =>
-    False. Each kernel's probe is cached independently per (backend,
-    interpret, geometry): one small compile per kernel per runner
-    configuration, once per process."""
-    ok = _probe_gather(n_idx)
-    if ok and m_lock is not None:
-        ok = _probe_lock(m_lock, k_arb)
-    return ok
+    checking the gather against jnp.take. Returns True, or raises
+    KernelRefused naming the kernel that failed. Each kernel's passing
+    probe is cached independently per (backend, interpret, geometry): one
+    small compile per kernel per runner configuration, once per
+    process."""
+    _probe_gather(n_idx)
+    if m_lock is not None:
+        _probe_lock(m_lock, k_arb)
+    return True
 
 
 def hot_kernels_available(n_idx: int = 512, vw: int = 1,
                           m_lock: int | None = None, k_arb: int = 18,
                           hot_n: int = 16) -> bool:
-    """Availability probe for the hot-set kernel family (gather + fused
-    install, plus the hot-prefix lock pass when m_lock is given). Same
-    degrade contract as kernels_available."""
-    ok = _probe_hot(n_idx, vw)
-    if ok and m_lock is not None:
-        ok = _probe_lock(m_lock, k_arb, hot_n=min(hot_n, 16))
-    return ok
+    """Probe the hot-set kernel family (gather + fused install, plus the
+    hot-prefix lock pass when m_lock is given): True, or KernelRefused."""
+    _probe_hot(n_idx, vw)
+    if m_lock is not None:
+        _probe_lock(m_lock, k_arb, hot_n=min(hot_n, 16))
+    return True
 
 
 def resolve_use_pallas(explicit: bool | None = None, *, n_idx: int = 512,
                        m_lock: int | None = 64, k_arb: int = 18) -> bool:
     """Engine-builder entry point: explicit kwarg wins, else the
-    DINT_USE_PALLAS env; when requested, the availability probe runs at the
-    builder's real lane geometry and a Mosaic failure degrades to False
-    (logged warning, never an exception)."""
+    DINT_USE_PALLAS env; when requested, the probe runs at the builder's
+    real lane geometry and a Mosaic refusal raises KernelRefused — the
+    caller asked for the kernels and does not get the XLA route in their
+    place."""
     if explicit is None:
         explicit = env_use_pallas()
     if not explicit:
@@ -1307,8 +1328,7 @@ def _probe_lockv(n_val: int, n_read: int, m_lock: int, k_arb: int,
                  hot_n: int = 0) -> bool:
     """Compile + run lock_validate at the caller's lane geometry and check
     it against the COMPOSITION it replaces: lock_arbitrate (itself proven
-    against the XLA chain) + the direct meta gathers/compares. Any
-    mismatch or Mosaic rejection degrades to the unfused dispatches."""
+    against the XLA chain) + the direct meta gathers/compares."""
     def probe():
         n = 64
         meta = ((jnp.arange(n, dtype=U32) * U32(7)) << 1) | U32(1)
@@ -1380,21 +1400,18 @@ def _probe_scatter_streams(geoms: tuple) -> bool:
 
 def fused_kernels_available(*, lockv=None, gathers=None,
                             scatters=None) -> bool:
-    """Availability probe for the round-12 megakernels. ``lockv`` is
-    (n_val, n_read, m_lock, k_arb, hot_n) or None; ``gathers`` /
-    ``scatters`` are tuples of per-stream (k, vw) geometry or None. Same
-    degrade contract and per-(backend, interpret, geometry) cache as
-    kernels_available."""
-    ok = True
+    """Probe the round-12 megakernels. ``lockv`` is (n_val, n_read,
+    m_lock, k_arb, hot_n) or None; ``gathers`` / ``scatters`` are tuples
+    of per-stream (k, vw) geometry or None. True, or KernelRefused; same
+    per-(backend, interpret, geometry) cache as kernels_available."""
     if lockv is not None:
         n_val, n_read, m_lock, k_arb, hot_n = lockv
-        ok = _probe_lockv(n_val, n_read, m_lock, k_arb,
-                          hot_n=min(hot_n, 16))
-    if ok and gathers:
-        ok = _probe_gather_streams(tuple(gathers))
-    if ok and scatters:
-        ok = _probe_scatter_streams(tuple(scatters))
-    return ok
+        _probe_lockv(n_val, n_read, m_lock, k_arb, hot_n=min(hot_n, 16))
+    if gathers:
+        _probe_gather_streams(tuple(gathers))
+    if scatters:
+        _probe_scatter_streams(tuple(scatters))
+    return True
 
 
 def resolve_use_fused(explicit: bool | None = None, *, lockv=None,
@@ -1402,8 +1419,7 @@ def resolve_use_fused(explicit: bool | None = None, *, lockv=None,
     """Engine-builder gate for the fused wave pairs: explicit kwarg wins,
     else the DINT_USE_FUSED env (default off — PERF.md round-12 decision
     rule); when requested, every megakernel the engine would dispatch is
-    probed at its real geometry and any failure degrades to the unfused
-    two-kernel/XLA path (logged warning, never an exception)."""
+    probed at its real geometry and any refusal raises KernelRefused."""
     if explicit is None:
         explicit = env_use_fused()
     if not explicit:

@@ -1,22 +1,3 @@
-"""Multi-chip paths. Compat: this package targets the current
-`jax.shard_map` + varying-manual-axes (`jax.typeof(...).vma` /
-`jax.lax.pcast`) API; on older pins (the CPU test container runs jax
-0.4.37) `shard_map` still lives under `jax.experimental` and replication
-is tracked by shard_map itself (`check_rep`), so map the new name onto
-the old implementation here instead of failing at runner-build time —
-`sharded.pcast_varying` handles the pcast half of the skew."""
-import functools
-
-import jax
-
-if not hasattr(jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # check_rep=False: the old checker has no replication rule for
-    # pallas_call (the DMA-ring kernels run inside shard_map bodies), and
-    # this package's bodies manage replication explicitly anyway (psum'd
-    # stats, pcast_varying for carry closure on the new API)
-    jax.shard_map = functools.wraps(_shard_map)(
-        functools.partial(_shard_map, check_rep=False))
-
-from . import sharded  # noqa: F401,E402
+"""Multi-chip paths, written for `jax.shard_map` and its varying-manual-
+axes typing (`jax.typeof(...).vma`, `jax.lax.pcast`)."""
+from . import sharded  # noqa: F401

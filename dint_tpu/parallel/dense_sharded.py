@@ -42,8 +42,7 @@ import functools
 import flax.struct
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from ..engines import tatp_dense as td
 from ..engines._memo import memoize_builder
@@ -51,7 +50,8 @@ from ..monitor import counters as mon
 from ..monitor import waves
 from ..ops import pallas_gather as pg
 from ..tables import log as logring
-from .sharded import SHARD_AXIS, make_mesh, pcast_varying   # noqa: F401 (re-exported)
+from .sharded import (SHARD_AXIS, make_mesh, pcast_varying,   # noqa: F401 (re-exported)
+                      stack_on_mesh)
 
 I32 = jnp.int32
 U32 = jnp.uint32
@@ -73,42 +73,52 @@ def n_sub_local(n_sub_global: int, n_shards: int) -> int:
     return (n_sub_global + n_shards - 1) // n_shards
 
 
+def ring_perm(n: int, off: int) -> list:
+    """The replication hop: device i sends to device i+off around the
+    ring (CommitBck x2, client_ebpf_shard.cc:812-860)."""
+    return [(i, (i + off) % n) for i in range(n)]
+
+
+def populate_local(seed: int, part, n_loc: int, val_words: int, pull,
+                   **kw) -> ShardState:
+    """What every device runs ON ITSELF inside the create functions'
+    shard_map: populate partition `part` with the single-chip rules
+    (td.populate_device — reference populate,
+    client_ebpf_shard.cc:96-341), then fetch the two predecessors'
+    populated tables as the backup copies through `pull(x, off)`, the
+    same ppermute hop the installs ride. db.val / db.meta end in the
+    all-zero sentinel row, which is each backup slot's padding row."""
+    # log_replicas=1: the 3 log copies live on 3 devices here (forwarded
+    # installs are appended by each receiver), not packed per-slot
+    db = td.populate_device(
+        jax.random.fold_in(jax.random.PRNGKey(seed), part), n_loc,
+        val_words=val_words, log_replicas=1, **kw)
+    return ShardState(
+        db=db,
+        bck_val=jnp.concatenate([pull(db.val, off) for off in (1, 2)]),
+        bck_meta=jnp.concatenate([pull(db.meta, off) for off in (1, 2)]))
+
+
 def create_sharded(mesh: Mesh, n_shards: int, n_sub_global: int,
                    val_words: int = 10, seed: int = 0,
                    **kw) -> ShardState:
     """Stacked per-device state sharded over the mesh (leading axis =
-    device). Population matches the single-chip engine per local range
-    (reference populate, client_ebpf_shard.cc:96-341)."""
+    device). One shard_map program: each shard is populated on its own
+    device and the backups arrive over the ring, so nothing global is
+    ever materialised on one chip (7 M subscribers is ~18.6 GB of primary
+    + 2 backups against 16 GB of HBM)."""
     n_loc = n_sub_local(n_sub_global, n_shards)
-    n1 = td.n_rows(n_loc) + 1
 
-    # log_replicas=1: the 3 log copies live on 3 devices here (forwarded
-    # installs are appended by each receiver), not packed per-slot
-    dbs = [td.populate(np.random.default_rng(seed + d), n_loc,
-                       val_words=val_words, log_replicas=1, **kw)
-           for d in range(n_shards)]
-    db = jax.tree.map(lambda *xs: jnp.stack(xs), *dbs)
-    # backups start as copies of the predecessors' populated tables
-    # (db.val is already the tight interleaved 1-D layout; drop the
-    # sentinel row's words)
-    val1d = jnp.stack([d_.val[:-val_words] for d_ in dbs])  # [D, (n1-1)*VW]
-    # primary meta is already ver<<1|exists (locks live in db.arb), the
-    # exact backup format
-    meta1 = jnp.stack([d_.meta[:-1] for d_ in dbs])             # [D, n1-1]
+    def pull(x, off):
+        return jax.lax.ppermute(x, SHARD_AXIS, ring_perm(n_shards, off))
 
-    def pred(x, off):
-        return jnp.roll(x, off, axis=0)     # device d gets device d-off's copy
+    def local():
+        one = populate_local(seed, jax.lax.axis_index(SHARD_AXIS), n_loc,
+                             val_words, pull, **kw)
+        return jax.tree.map(lambda x: x[None], one)
 
-    pad_v = jnp.zeros((n_shards, val_words), U32)   # sentinel row padding
-    pad_m = jnp.zeros((n_shards, 1), U32)
-    bck_val = jnp.concatenate([pred(val1d, 1), pad_v,
-                               pred(val1d, 2), pad_v], axis=1)
-    bck_meta = jnp.concatenate([pred(meta1, 1), pad_m,
-                                pred(meta1, 2), pad_m], axis=1)
-
-    state = ShardState(db=db, bck_val=bck_val, bck_meta=bck_meta)
-    shard = NamedSharding(mesh, P(SHARD_AXIS))
-    return jax.tree.map(lambda x: jax.device_put(x, shard), state)
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(),
+                                 out_specs=P(SHARD_AXIS)))()
 
 
 def _apply_backup(state: ShardState, inst: td.Installs, slot: int,
@@ -156,16 +166,16 @@ def build_sharded_pipelined_runner(mesh: Mesh, n_shards: int,
     ``use_pallas``: None = honor DINT_USE_PALLAS env; the per-device
     pipe_step then runs the DMA-ring kernels on ITS shard's local arrays
     (shard_map bodies see local shapes, so the kernels drop straight in).
-    The availability probe runs once outside shard_map; Mosaic failure
-    falls back to the XLA path with a logged warning.
+    The probe runs once outside shard_map; a Mosaic refusal raises
+    pg.KernelRefused.
 
     ``use_fused``: None = honor DINT_USE_FUSED env. Routes each device's
     local pipe_step through the round-12 megakernels (lock_validate +
     install_log) at the shard-local geometry (log stream width uses this
     path's log_replicas=1 rings); the replicate fan-out stays the
     ppermute + XLA backup apply, so REPL_PUSHED provenance is unchanged.
-    Probed once outside shard_map like use_pallas; probe failure
-    degrades to the unfused path.
+    Probed once outside shard_map like use_pallas; a probe failure
+    raises.
 
     ``monitor``: thread the dintmon counter plane PER DEVICE — the carry
     grows a trailing stacked monitor.Counters (buf [D, N_COUNTERS]; each
@@ -209,10 +219,9 @@ def build_sharded_pipelined_runner(mesh: Mesh, n_shards: int,
         # hop's payload is dropped on the floor.
         with waves.scope("dense_sharded", "replicate"):
             for off in (1, 2):
-                perm = [(i, (i + off) % n_shards) for i in range(n_shards)]
                 fwd = jax.tree.map(functools.partial(
-                    jax.lax.ppermute, axis_name=SHARD_AXIS, perm=perm),
-                    inst)
+                    jax.lax.ppermute, axis_name=SHARD_AXIS,
+                    perm=ring_perm(n_shards, off)), inst)
                 if cnt is not None:
                     # replication pushes, counted where they are APPLIED
                     # (the receiving backup — the reference's CommitBck
@@ -262,18 +271,14 @@ def build_sharded_pipelined_runner(mesh: Mesh, n_shards: int,
 
     n_carry = 4 if monitor else 3
     spec = (P(SHARD_AXIS),) * n_carry + (P(),)
+    check_vma = pg.shard_map_check_vma(use_pallas or use_fused)
     block = jax.shard_map(block_local, mesh=mesh, in_specs=spec,
-                          out_specs=(P(SHARD_AXIS),) * n_carry + (P(),))
+                          out_specs=(P(SHARD_AXIS),) * n_carry + (P(),),
+                          check_vma=check_vma)
     drain_m = jax.shard_map(
         drain_local, mesh=mesh, in_specs=spec,
-        out_specs=(P(SHARD_AXIS),) * (2 if monitor else 1) + (P(),))
-
-    def stack_leaf(one):
-        shard = NamedSharding(mesh, P(SHARD_AXIS))
-        return jax.tree.map(
-            lambda x: jax.device_put(
-                jnp.broadcast_to(x[None], (n_shards,) + x.shape), shard),
-            one)
+        out_specs=(P(SHARD_AXIS),) * (2 if monitor else 1) + (P(),),
+        check_vma=check_vma)
 
     donate = tuple(range(n_carry))
     jit_block = jax.jit(block, donate_argnums=donate)
@@ -284,9 +289,9 @@ def build_sharded_pipelined_runner(mesh: Mesh, n_shards: int,
         return out[:-1], out[-1]
 
     def init(state):
-        base = (state, stack_leaf(td.empty_ctx(w)),
-                stack_leaf(td.empty_ctx(w)))
-        return base + ((stack_leaf(mon.create()),) if monitor else ())
+        fresh = (td.empty_ctx(w), td.empty_ctx(w)) + (
+            (mon.create(),) if monitor else ())
+        return (state,) + stack_on_mesh(mesh, fresh)
 
     def drain(carry):
         out = jit_drain(*carry, jax.random.PRNGKey(0))

@@ -229,8 +229,8 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
 
     ``use_pallas``: None = honor DINT_USE_PALLAS env; routes the owner-side
     held-stamp and balance gathers through the DMA-ring kernel
-    (ops/pallas_gather.gather_rows) on each device's local arrays; Mosaic
-    failure falls back to the XLA gathers (logged warning).
+    (ops/pallas_gather.gather_rows) on each device's local arrays; a
+    Mosaic refusal raises pg.KernelRefused.
 
     ``use_hotset``: None = honor DINT_USE_HOTSET env. Per-device dintcache
     partition over the owner-side gathers (SBShard docstring): hot lanes
@@ -243,8 +243,8 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
     dispatch and its primary install + CommitLog append through ONE
     scatter-stream install_log dispatch (round-12 megakernels); the
     all_to_all routing and the ppermute replicate fan-out stay
-    collective + XLA. Probed once outside shard_map; probe failure
-    degrades to the unfused path (pg.resolve_use_fused).
+    collective + XLA. Probed once outside shard_map; a probe failure
+    raises (pg.resolve_use_fused).
 
     ``monitor``: thread the dintmon counter plane PER DEVICE. Txn
     outcomes count at the source device (where the cohort completes);
@@ -280,8 +280,8 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
         frac = wl.SB_HOT_FRAC if hot_frac is None else float(hot_frac)
         hot_n = max(1, min(int(n_accounts * frac), n_accounts))
         hot_loc = min((hot_n + d - 1) // d, n_loc)
-        if use_pallas and not pg.hot_kernels_available(n_idx=d * cap):
-            use_pallas = False      # partition stays; XLA serves it
+        if use_pallas:
+            pg.hot_kernels_available(n_idx=d * cap)
     ew1 = logring.HDR_WORDS + VW                 # replicas=1 rings
     scat_geoms = ((d * cap, 1), (d * cap, ew1))
     if use_hotset:
@@ -714,11 +714,14 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
 
     n_carry = 2 + int(trace_on) + int(monitor)
     spec = (P(AXIS),) * n_carry + (P(),)
+    check_vma = pg.shard_map_check_vma(use_pallas or use_fused)
     block = jax.shard_map(block_local, mesh=mesh, in_specs=spec,
-                          out_specs=(P(AXIS),) * n_carry + (P(),))
+                          out_specs=(P(AXIS),) * n_carry + (P(),),
+                          check_vma=check_vma)
     drain_m = jax.shard_map(
         drain_local, mesh=mesh, in_specs=spec,
-        out_specs=(P(AXIS),) * (n_carry - 1) + (P(),))
+        out_specs=(P(AXIS),) * (n_carry - 1) + (P(),),
+        check_vma=check_vma)
     donate = tuple(range(n_carry))
     jit_block = jax.jit(block, donate_argnums=donate)
     jit_drain = jax.jit(drain_m, donate_argnums=donate)

@@ -48,13 +48,14 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from ..engines import tatp_dense as td
 from ..engines._memo import memoize_builder
 from ..tables import log as logring
-from .dense_sharded import (N_BCK, ShardState, _apply_backup, n_sub_local)
-from .sharded import pcast_varying
+from .dense_sharded import (ShardState, _apply_backup, n_sub_local,
+                            populate_local, ring_perm)
+from .sharded import pcast_varying, stack_on_mesh
 
 I32 = jnp.int32
 U32 = jnp.uint32
@@ -96,40 +97,27 @@ def make_mesh_2d(n_hosts: int, chips_per_host: int) -> Mesh:
 
 def create_multihost(mesh: Mesh, n_sub_global: int, val_words: int = 10,
                      seed: int = 0, **kw) -> ShardState:
-    """Stacked per-device state [H, C, ...]: device (h, c)'s primary range
-    populated locally, backup copies initialized from hosts h-1, h-2 at
-    the same chip coordinate (jnp.roll over the HOST axis only)."""
+    """Stacked per-device state [H, C, ...]: device (h, c) populates its
+    own primary range on itself (the same partition streams as the 1-D
+    dense_sharded.create_sharded), backup copies arrive from hosts h-1,
+    h-2 at the same chip coordinate (a ppermute over the HOST axis)."""
     n_hosts, n_ici = mesh.devices.shape
     if n_hosts < 3:
         raise ValueError("multihost replication needs >= 3 hosts "
                          "(reference topology: 3 server machines)")
-    n_parts = n_hosts * n_ici
-    n_loc = n_sub_local(n_sub_global, n_parts)
+    n_loc = n_sub_local(n_sub_global, n_hosts * n_ici)
 
-    dbs = [td.populate(np.random.default_rng(seed + d), n_loc,
-                       val_words=val_words, log_replicas=1, **kw)
-           for d in range(n_parts)]
-    stack = jax.tree.map(
-        lambda *xs: jnp.stack(xs).reshape((n_hosts, n_ici)
-                                          + xs[0].shape), *dbs)
-    val1d = jnp.stack([d_.val[:-val_words] for d_ in dbs]).reshape(
-        n_hosts, n_ici, -1)
-    meta1 = jnp.stack([d_.meta[:-1] for d_ in dbs]).reshape(
-        n_hosts, n_ici, -1)
+    def pull(x, off):       # host h gets host h-off's copy, same chip
+        return jax.lax.ppermute(x, DCN_AXIS, ring_perm(n_hosts, off))
 
-    def pred(x, off):         # host h gets host h-off's copy, same chip
-        return jnp.roll(x, off, axis=0)
+    def local():
+        part = (jax.lax.axis_index(DCN_AXIS) * n_ici
+                + jax.lax.axis_index(ICI_AXIS))
+        one = populate_local(seed, part, n_loc, val_words, pull, **kw)
+        return jax.tree.map(lambda x: x[None, None], one)
 
-    pad_v = jnp.zeros((n_hosts, n_ici, val_words), U32)
-    pad_m = jnp.zeros((n_hosts, n_ici, 1), U32)
-    bck_val = jnp.concatenate([pred(val1d, 1), pad_v,
-                               pred(val1d, 2), pad_v], axis=2)
-    bck_meta = jnp.concatenate([pred(meta1, 1), pad_m,
-                                pred(meta1, 2), pad_m], axis=2)
-
-    state = ShardState(db=stack, bck_val=bck_val, bck_meta=bck_meta)
-    shard = NamedSharding(mesh, P(DCN_AXIS, ICI_AXIS))
-    return jax.tree.map(lambda x: jax.device_put(x, shard), state)
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(),
+                                 out_specs=P(DCN_AXIS, ICI_AXIS)))()
 
 
 @memoize_builder
@@ -166,9 +154,9 @@ def build_multihost_runner(mesh: Mesh, n_sub_global: int, w: int = 4096,
         # CommitBck + CommitLog fan-out: forward installs to hosts h+1,
         # h+2 at the same chip — the only DCN traffic in the program
         for off in (1, 2):
-            perm = [(i, (i + off) % n_hosts) for i in range(n_hosts)]
             fwd = jax.tree.map(functools.partial(
-                jax.lax.ppermute, axis_name=DCN_AXIS, perm=perm), inst)
+                jax.lax.ppermute, axis_name=DCN_AXIS,
+                perm=ring_perm(n_hosts, off)), inst)
             src_dev = ((h - off) % n_hosts) * n_ici + c
             state = _apply_backup(state, fwd, off - 1, n1, val_words,
                                   src_dev)
@@ -211,15 +199,6 @@ def build_multihost_runner(mesh: Mesh, n_sub_global: int, w: int = 4096,
     drain_m = jax.shard_map(drain_local, mesh=mesh, in_specs=spec,
                             out_specs=(grid, P()))
 
-    def stack_ctx():
-        shard = NamedSharding(mesh, grid)
-        one = td.empty_ctx(w)
-        return jax.tree.map(
-            lambda x: jax.device_put(
-                jnp.broadcast_to(x[None, None],
-                                 (n_hosts, n_ici) + x.shape), shard),
-            one)
-
     jit_block = jax.jit(block, donate_argnums=(0, 1, 2))
     jit_drain = jax.jit(drain_m, donate_argnums=(0, 1, 2))
 
@@ -229,7 +208,8 @@ def build_multihost_runner(mesh: Mesh, n_sub_global: int, w: int = 4096,
         return (state, c1, c2), stats
 
     def init(state):
-        return (state, stack_ctx(), stack_ctx())
+        return (state,) + stack_on_mesh(
+            mesh, (td.empty_ctx(w), td.empty_ctx(w)))
 
     def drain(carry):
         state, c1, c2 = carry

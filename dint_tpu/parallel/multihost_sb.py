@@ -549,8 +549,11 @@ def build_multihost_sb_runner(mesh: Mesh, n_accounts: int, w: int = 2048,
                 )
                 ring, cnt = txe.emit(ring, tcfg, groups, cnt)
 
-        new_ctx = jax.tree.map(
-            lambda x: pcast_varying(x, DCN_AXIS, ICI_AXIS), new_ctx)
+        # pf_next too: its key/occupancy arrive replicated (scan xs) but
+        # ride a carry whose other leaves vary over the mesh
+        new_ctx, pf_next = jax.tree.map(
+            lambda x: pcast_varying(x, DCN_AXIS, ICI_AXIS),
+            (new_ctx, pf_next))
         stats = jax.lax.psum(
             jax.lax.psum(_stats_of(c1), ICI_AXIS), DCN_AXIS)
         return state, new_ctx, pf_next, stats, cnt, ring

@@ -50,13 +50,10 @@ SHARD_AXIS = "shard"
 
 def pcast_varying(x, *axes):
     """`jax.lax.pcast(x, axis, to="varying")` for each axis the value is
-    not already varying over — needed under the new shard_map typing when
-    constants born inside the body must close a scan carry. On older jax
-    (0.4.37: no `lax.pcast`, no `jax.typeof`) shard_map tracks replication
-    itself and the cast is an identity."""
-    if not hasattr(jax.lax, "pcast"):
-        return x
-    vma = getattr(jax.typeof(x), "vma", ())
+    not already varying over — needed under shard_map's varying-manual-
+    axes typing when constants born inside the body must close a scan
+    carry."""
+    vma = jax.typeof(x).vma
     for ax in axes:
         if ax not in vma:
             x = jax.lax.pcast(x, ax, to="varying")
@@ -171,12 +168,17 @@ def build_sharded_step(mesh: Mesh, n_shards: int, engine: str = "tatp"):
     return jax.jit(fn)
 
 
-def _shard_tree(mesh: Mesh, n_shards: int, proto):
-    def stack(x):
-        stacked = jnp.broadcast_to(x[None], (n_shards,) + x.shape)
-        return jax.device_put(stacked, NamedSharding(mesh, P(SHARD_AXIS)))
-
-    return jax.tree.map(stack, proto)
+def stack_on_mesh(mesh: Mesh, tree):
+    """One copy of `tree` per device of `mesh`: every leaf grows one
+    leading axis per mesh axis and is sharded one slice per device. The
+    broadcast is compiled with `out_shardings`, so each device fills its
+    own slice and the stacked array never exists whole on one chip."""
+    lead = mesh.devices.shape
+    stack = jax.jit(
+        lambda t: jax.tree.map(
+            lambda x: jnp.broadcast_to(x, lead + x.shape), t),
+        out_shardings=NamedSharding(mesh, P(*mesh.axis_names)))
+    return stack(tree)
 
 
 def create_sharded_state(mesh: Mesh, n_shards: int, n_subscribers: int,
@@ -184,8 +186,8 @@ def create_sharded_state(mesh: Mesh, n_shards: int, n_subscribers: int,
     """Stacked per-device TATP state, device-local table sizes, sharded
     over the mesh (leading axis = device)."""
     rows = local_rows(n_subscribers + 1, n_shards)
-    return _shard_tree(mesh, n_shards,
-                       tatp.create(rows - 1, val_words=val_words, **kw))
+    return stack_on_mesh(mesh,
+                         tatp.create(rows - 1, val_words=val_words, **kw))
 
 
 def create_sharded_smallbank(mesh: Mesh, n_shards: int, n_accounts: int,
@@ -193,8 +195,8 @@ def create_sharded_smallbank(mesh: Mesh, n_shards: int, n_accounts: int,
     """Stacked per-device SmallBank state (reference shards its 3 servers
     identically, smallbank/caladan/client_ebpf_shard.cc:287-289)."""
     rows = local_rows(n_accounts, n_shards)
-    return _shard_tree(mesh, n_shards,
-                       smallbank.create(rows, val_words=val_words, **kw))
+    return stack_on_mesh(mesh,
+                         smallbank.create(rows, val_words=val_words, **kw))
 
 
 def route_batches(ops, tbls, keys, vals, vers, n_shards: int, width: int,

@@ -8,6 +8,7 @@ C++ buffers — zero copies on the poll side.
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 
@@ -44,21 +45,26 @@ _lib = None
 
 
 def load() -> ctypes.CDLL:
-    """Load libdintshim.so, (re)building it with make if missing/stale."""
+    """Load libdintshim.so, (re)building it with make if missing/stale.
+    The binary is never tracked in git — a checkout builds its own — so
+    several processes (the test workers) may arrive here at once: an
+    exclusive file lock makes one of them build while the rest wait."""
     global _lib
     if _lib is not None:
         return _lib
-    if (not os.path.exists(_SO)
-            or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-        subprocess.run(["make", "-C", os.path.dirname(_SO)], check=True,
-                       capture_output=True)
-    try:
-        lib = ctypes.CDLL(_SO)
-    except OSError:
-        # stale/foreign binary (e.g. built on another arch): force a rebuild
-        subprocess.run(["make", "-B", "-C", os.path.dirname(_SO)], check=True,
-                       capture_output=True)
-        lib = ctypes.CDLL(_SO)
+    with open(_SO + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if (not os.path.exists(_SO)
+                or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
+            subprocess.run(["make", "-C", os.path.dirname(_SO)], check=True,
+                           capture_output=True)
+        try:
+            lib = ctypes.CDLL(_SO)
+        except OSError:
+            # stale/foreign binary (e.g. built on another arch): rebuild
+            subprocess.run(["make", "-B", "-C", os.path.dirname(_SO)],
+                           check=True, capture_output=True)
+            lib = ctypes.CDLL(_SO)
     u8p = ctypes.POINTER(ctypes.c_uint8)
     u32p = ctypes.POINTER(ctypes.c_uint32)
     u64p = ctypes.POINTER(ctypes.c_uint64)

@@ -1,0 +1,215 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed here and compiles for a v5e that is
+described, not attached: what it refuses in this file it would refuse on
+the chip, at no chip time. Nothing runs, so nothing here is a result or a
+time — only "compiles, and fits" or the compiler's refusal.
+
+Geometry is the flagship deployment's (chip_smoke.py): TATP at 7 000 000
+subscribers, val_words=10, w=8192, 16 cohorts per block.
+
+The Pallas kernel cases ASSERT THE REFUSAL. Mosaic on v5e requires a row
+slice of a 1-D HBM table to be a multiple of 1024 words and the engines'
+rows are 1, 10 or 42 words (PERF.md "Round 25"), so every kernel family in
+ops/pallas_gather.py is refused, and asking for one raises
+pg.KernelRefused on the chip instead of quietly running XLA. A case fails
+the day its kernel compiles: flip it to assert the compile, and give
+chip_smoke.py its `--kernels` phase.
+
+The topology is described inside a module-scoped fixture (never at
+import, never in conftest.py: only one process may hold the TPU library,
+and every xdist worker imports every test file)."""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from dint_tpu.engines import tatp_dense as td
+from dint_tpu.ops import pallas_gather as pg
+from dint_tpu.parallel import dense_sharded as ds
+from dint_tpu.tables import log as logring
+
+HBM_BYTES = 16e9                 # one v5e chip
+N_SUB, W, CPB, VW = 7_000_000, 8192, 16, 10
+K = td.K
+N1 = td.n_rows(N_SUB) + 1        # table rows incl. the sentinel
+EW3 = 3 * (logring.HDR_WORDS + VW)   # one log slot: 3 packed replicas
+LOG_WORDS = 16 * (1 << 16) * EW3     # tatp_dense.create's default rings
+HOT = 1 << 16                    # mirror rows of the hot-tier cases
+U32, I32 = jnp.uint32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e 2x2. Around these compiles the persistent compile
+    cache is off (a compile for a described device cannot be read back)
+    and XLA optimises as it does on the chip, not as conftest.py sets it
+    for the CPU suite."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ["TPU_LOG_DIR"] = "disabled"   # or libtpu logs under /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cache_was = jax.config.jax_enable_compilation_cache
+    opt_was = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_disable_most_optimizations", False)
+    cc.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    jax.config.update("jax_disable_most_optimizations", opt_was)
+    cc.reset_cache()
+    if log_dir is None:
+        del os.environ["TPU_LOG_DIR"]
+    else:
+        os.environ["TPU_LOG_DIR"] = log_dir
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def placed(tree, sharding):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def compiled_bytes(jitted, *args):
+    c = jitted.lower(*args).compile()
+    return c, c.memory_analysis()
+
+
+# ------------------------------------------------ the default program
+
+
+def _runner():
+    return td.build_pipelined_runner(
+        N_SUB, w=W, val_words=VW, cohorts_per_block=CPB, monitor=True,
+        use_pallas=False, use_fused=False, trace=False)
+
+
+def test_tatp7m_populate_fits_one_chip(one_chip):
+    """Round 5 lost a chip window to a populate that OOMed at compile
+    time (a [p1, 4, 3] draw padded 42.7x); the flat layout must fit."""
+    key = placed(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+    _, ma = compiled_bytes(
+        jax.jit(lambda k: td.populate_device(k, N_SUB, val_words=VW)), key)
+    assert ma.output_size_in_bytes > 7e9          # the real tables
+    assert ma.output_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+
+
+def test_tatp7m_block_program_fits_one_chip(one_chip):
+    run, init, drain = _runner()
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    carry = jax.eval_shape(
+        lambda k: init(td.populate_device(k, N_SUB, val_words=VW)), key)
+    carry, key = placed(carry, one_chip), placed(key, one_chip)
+    for fn, args in ((run, (carry, key)), (drain, (carry,))):
+        _, ma = compiled_bytes(fn, *args)
+        assert ma.argument_size_in_bytes > 7e9    # the real tables
+        assert ma.argument_size_in_bytes + ma.temp_size_in_bytes \
+            < HBM_BYTES
+        # the carry is donated: the tables update in place
+        assert ma.alias_size_in_bytes > 0.99 * ma.argument_size_in_bytes
+        assert ma.temp_size_in_bytes < 1e9
+
+
+# ------------------------------------------------- the Pallas kernels
+
+
+def _s(shape, dt=U32):
+    return jax.ShapeDtypeStruct(shape, dt)
+
+
+# (id, fn, argument shapes) at the geometry the TATP-7M builders pass;
+# interpret=False is explicit because use_interpret() sees the CPU here
+KERNELS = (
+    ("gather_rows-vw1-meta", lambda t, i: pg.gather_rows(t, i, 1, False),
+     (_s((N1,)), _s((2 * W * K,), I32))),
+    ("gather_rows-vw10-val", lambda t, i: pg.gather_rows(t, i, VW, False),
+     (_s((N1 * VW,)), _s((W * K,), I32))),
+    ("scatter_streams-install_log",
+     lambda tabs, idxs, vals: pg.scatter_streams(
+         tabs, idxs, vals, (VW, 1, EW3), False),
+     ((_s((N1 * VW,)), _s((N1,)), _s((LOG_WORDS,))),
+      (_s((2 * W,), I32),) * 3,
+      (_s((2 * W * VW,)), _s((2 * W,)), _s((2 * W * EW3,))))),
+    ("scatter_rows_hot-install",
+     lambda t, m, i, mi, mk, v: pg.scatter_rows_hot(
+         t, m, i, mi, mk, v, VW, False),
+     (_s((N1 * VW,)), _s((HOT * VW,)), _s((2 * W,), I32),
+      _s((2 * W,), I32), _s((2 * W,), I32), _s((2 * W * VW,)))),
+    ("gather_rows_hot-meta",
+     lambda t, m, i, mi: pg.gather_rows_hot(t, m, i, mi, 1, False),
+     (_s((N1,)), _s((HOT,)), _s((2 * W * K,), I32),
+      _s((2 * W * K,), I32))),
+    ("lock_arbitrate",
+     lambda a, r, act, s: pg.lock_arbitrate(a, r, act, s, td.K_ARB, False),
+     (_s((N1,)), _s((2 * W,), I32), _s((2 * W,), jnp.bool_), _s(()))),
+    ("lock_validate",
+     lambda a, m, vi, vv, ri, r, act, s: pg.lock_validate(
+         a, m, vi, vv, ri, r, act, s, td.K_ARB, False),
+     (_s((N1,)), _s((N1,)), _s((W * K,), I32), _s((W * K,)),
+      _s((W * K,), I32), _s((2 * W,), I32), _s((2 * W,), jnp.bool_),
+      _s(()))),
+    ("gather_streams",
+     lambda tabs, idxs: pg.gather_streams(tabs, idxs, (1, 1, 1), False),
+     ((_s((N1,)),) * 3, (_s((W * K,), I32),) * 3)),
+    ("scan_rows-lg16",
+     lambda hi, lo, ver, val, off, order: pg.scan_rows(
+         hi, lo, ver, val, off, order, 16, VW, False),
+     (_s((1 << 20,)), _s((1 << 20,)), _s((1 << 20,)),
+      _s(((1 << 20) * VW,)), _s((512,), I32), _s((512,), I32))),
+)
+
+
+@pytest.mark.parametrize("fn,shapes", [k[1:] for k in KERNELS],
+                         ids=[k[0] for k in KERNELS])
+def test_mosaic_refuses_the_kernel_on_v5e(one_chip, fn, shapes):
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(fn).lower(*placed(shapes, one_chip)).compile()
+
+
+# ------------------------------------------------------ four chips
+
+
+def test_dense_sharded_block_program_on_four_chips(topo):
+    """The sharded TATP-7M programs compile for a 4-device v5e mesh, each
+    device holds a quarter of the state (created sharded: nothing global
+    on one chip), and the replication rides collective-permutes."""
+    mesh = Mesh(np.array(topo.devices), (ds.SHARD_AXIS,))
+    n = mesh.size
+    run, init, drain = ds.build_sharded_pipelined_runner(
+        mesh, n, N_SUB, w=W, val_words=VW, cohorts_per_block=CPB,
+        monitor=True, use_pallas=False, use_fused=False)
+
+    def create():
+        return ds.create_sharded(mesh, n, N_SUB, val_words=VW, seed=0)
+
+    shapes = jax.eval_shape(lambda: init(create()))
+    total = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                for s in jax.tree.leaves(shapes))
+    assert total > 20e9          # primary + 2 backups: more than one chip
+    carry = placed(shapes, NamedSharding(mesh, P(ds.SHARD_AXIS)))
+    key = placed(jax.eval_shape(lambda: jax.random.PRNGKey(0)),
+                 NamedSharding(mesh, P()))
+
+    _, ma = compiled_bytes(jax.jit(create))
+    assert abs(ma.output_size_in_bytes - total / n) < 0.01 * total / n
+    assert ma.output_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+
+    c, ma = compiled_bytes(jax.jit(run, donate_argnums=0), carry, key)
+    assert abs(ma.argument_size_in_bytes - total / n) < 0.01 * total / n
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+    assert "collective-permute" in c.as_text()

@@ -9,6 +9,14 @@ VW = 4
 D = 8
 
 
+def _fresh(n_sub_global, seed=0):
+    """Per-device populated tables [D, ...] on the host: create_sharded is
+    a function of (geometry, seed), so a second call is the snapshot."""
+    state = ds.create_sharded(ds.make_mesh(D), D, n_sub_global,
+                              val_words=VW, seed=seed)
+    return jax.tree.map(np.asarray, state)
+
+
 def _run(n_sub_global, w, blocks, seed=0, mix=None):
     mesh = ds.make_mesh(D)
     state = ds.create_sharded(mesh, D, n_sub_global, val_words=VW,
@@ -25,6 +33,34 @@ def _run(n_sub_global, w, blocks, seed=0, mix=None):
     state, tail = drain(carry)
     total += np.asarray(tail, np.int64).sum(axis=0)
     return state, total
+
+
+def test_create_sharded_populates_each_shard_on_its_own_device():
+    """Every leaf is born sharded one slice per device (nothing global on
+    one chip), each shard obeys the single-chip population rules, and the
+    backup slots are the two ring predecessors' populated tables."""
+    n_glob = 8 * 64
+    mesh = ds.make_mesh(D)
+    state = ds.create_sharded(mesh, D, n_glob, val_words=VW, seed=5)
+    for leaf in jax.tree.leaves(state):
+        shards = leaf.addressable_shards
+        assert {s.device for s in shards} == set(mesh.devices.flat)
+        assert all(s.data.shape == (1,) + leaf.shape[1:] for s in shards)
+
+    n_loc = ds.n_sub_local(n_glob, D)
+    n1 = td.n_rows(n_loc) + 1
+    meta, val = np.asarray(state.db.meta), np.asarray(state.db.val)
+    assert (meta[:, 1:n_loc + 1] == 3).all()       # subscribers: ver 1, live
+    assert not meta[:, -1].any() and not val[:, -VW:].any()   # sentinel row
+    assert len({m.tobytes() for m in meta}) == D   # one stream per device
+    bck_meta, bck_val = np.asarray(state.bck_meta), np.asarray(state.bck_val)
+    for d in range(D):
+        for off in (1, 2):
+            lo = (off - 1) * n1
+            assert np.array_equal(bck_meta[(d + off) % D, lo:lo + n1],
+                                  meta[d])
+            assert np.array_equal(
+                bck_val[(d + off) % D, lo * VW:(lo + n1) * VW], val[d])
 
 
 def test_accounting_closes_and_scales_by_devices():
@@ -68,10 +104,7 @@ def test_backups_mirror_primaries_and_logs_replicate():
     heads = np.asarray(state.db.log.head).sum()
     # deleted rows bumped ver but exists=0; every bump logged once per
     # device x3 replicas-over-devices. ver counts bumps exactly.
-    vers0 = []
-    for d in range(D):
-        db0 = td.populate(np.random.default_rng(d), n_loc, val_words=VW)
-        vers0.append(np.asarray(db0.meta) >> 1)
+    vers0 = _fresh(8 * 256).db.meta >> 1
     bumps = int(sum((meta[d].astype(np.int64) >> 1).sum()
                     - vers0[d].astype(np.int64).sum() for d in range(D)))
     assert heads == 3 * bumps, (heads, bumps)
@@ -85,7 +118,6 @@ def test_lost_device_recovers_from_any_log_stream():
     from dint_tpu import recovery
 
     n_sub_global = 8 * 256
-    n_loc = ds.n_sub_local(n_sub_global, D)
     state, _ = _run(n_sub_global=n_sub_global, w=64, blocks=3)
 
     meta = np.asarray(state.db.meta)
@@ -98,9 +130,9 @@ def test_lost_device_recovers_from_any_log_stream():
     def ring_of(dev):
         return entries[dev].reshape(lanes, cap, -1), heads[dev]
 
+    fresh = _fresh(n_sub_global).db
     for dead in (0, 3):
-        snap = td.populate(np.random.default_rng(dead), n_loc, val_words=4,
-                           log_replicas=1)
+        snap = jax.tree.map(lambda x: x[dead], fresh)
         # own log stream (tag 0) and both backup holders' streams (tag d+1)
         sources = [(dead, 0), ((dead + 1) % D, dead + 1),
                    ((dead + 2) % D, dead + 1)]

@@ -19,15 +19,11 @@ def test_quick_tatp_sweep(tmp_path):
     assert "tatp_wire" in names
     assert any(n.startswith("tatp_colocate_c") for n in names)
 
-    measured = 0
     for name, block in results.items():
-        # a point may legitimately be an error artifact (run_point's
-        # record-and-continue fault tolerance — e.g. a loaded CI box
-        # starving a core-pinned colocate point); measured points must
-        # carry the full reference metric contract
-        if "error" in block:
-            continue
-        measured += 1
+        # run_point has no error artifacts: a point that fails raises out
+        # of run_all, so every point here measured and must carry the
+        # full reference metric contract
+        assert "error" not in block, (name, block)
         for field in ("throughput", "goodput", "abort_rate", "avg_us",
                       "p50_us", "p99_us", "p999_us"):
             assert field in block, (name, field)
@@ -40,13 +36,6 @@ def test_quick_tatp_sweep(tmp_path):
         # one JSON file per config, written the moment the point landed
         with open(os.path.join(out, f"{name}.json")) as f:
             assert json.load(f) == block
-    # the closed/open pipeline points must actually measure (they carry
-    # the sweep's anchor); only the wire/colocate extras may error out
-    pipeline_pts = [n for n in names
-                    if n.startswith(("tatp_closed", "tatp_open"))]
-    assert all("error" not in results[n] for n in pipeline_pts), pipeline_pts
-    assert measured >= len(pipeline_pts)
-
     with open(os.path.join(out, "summary.json")) as f:
         summary = json.load(f)
     assert sorted(summary["configs"]) == names
@@ -82,3 +71,22 @@ def test_quick_serve_mesh_sweep(tmp_path):
         blk["mesh"]["n_hosts"] * blk["mesh"]["n_ici"]
     # the ladder ran past the anchor
     assert any(n.startswith("serve_mesh_r") for n in names)
+
+
+def test_run_point_raises_instead_of_recording_an_error_artifact(tmp_path):
+    """A failed point fails the run: no retry, no backoff, no
+    {"error": ...} artifact for a sweep that then exits 0. Points that
+    finished before it stay on disk."""
+    sink = exp._ResultSink(str(tmp_path))
+    exp.run_point(sink, "good", lambda: {"goodput": 1.0})
+    calls = []
+
+    def bad():
+        calls.append(1)
+        raise RuntimeError("device lost")
+
+    with pytest.raises(RuntimeError, match="device lost"):
+        exp.run_point(sink, "bad", bad)
+    assert calls == [1]                          # ran once, not retried
+    assert sorted(os.listdir(tmp_path)) == ["good.json"]
+    assert "bad" not in sink

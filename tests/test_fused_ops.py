@@ -177,29 +177,33 @@ def test_resolve_use_fused_env_and_explicit(monkeypatch):
     assert pg.resolve_use_fused(None, lockv=(16, 16, 16, 18, 8)) is True
 
 
-def test_probe_failure_degrades_and_caches(monkeypatch):
-    """A kernel that raises at probe time (the Mosaic-rejection shape)
-    degrades resolve_use_fused to False — no exception escapes — and the
-    verdict is cached per geometry: restoring the kernel does not flip
-    an already-probed key."""
+def test_probe_failure_raises_and_is_not_cached(monkeypatch):
+    """A kernel that raises at probe time (the Mosaic-refusal shape)
+    makes resolve_use_fused raise KernelRefused with the kernel's name
+    and the compiler's text, and the failure is NOT cached as a verdict:
+    restoring the kernel lets the same geometry probe clean."""
     real_lockv = pg.lock_validate
-    monkeypatch.setattr(pg, "_probe_cache", {})       # isolate the cache
+    monkeypatch.setattr(pg, "_probe_cache", set())    # isolate the cache
 
     def boom(*a, **k):
         raise RuntimeError("simulated Mosaic rejection")
 
     monkeypatch.setattr(pg, "lock_validate", boom)
     geom = (24, 24, 16, 18, 0)
-    assert pg.resolve_use_fused(True, lockv=geom) is False
+    for _ in range(2):
+        with pytest.raises(pg.KernelRefused,
+                           match=r"'lockv'.*simulated Mosaic rejection"):
+            pg.resolve_use_fused(True, lockv=geom)
+    assert not pg._probe_cache
     monkeypatch.setattr(pg, "lock_validate", real_lockv)
-    assert pg.resolve_use_fused(True, lockv=geom) is False    # cached
-    # a DIFFERENT geometry re-probes and succeeds with the real kernel
-    assert pg.resolve_use_fused(True, lockv=(16, 16, 16, 18, 0)) is True
-    # the stream kernels degrade the same way
+    assert pg.resolve_use_fused(True, lockv=geom) is True
+    # the stream kernels refuse the same way
     monkeypatch.setattr(pg, "scatter_streams", boom)
-    assert pg.resolve_use_fused(True, scatters=((24, 4),)) is False
+    with pytest.raises(pg.KernelRefused, match="'sstreams'"):
+        pg.resolve_use_fused(True, scatters=((24, 4),))
     monkeypatch.setattr(pg, "gather_streams", boom)
-    assert pg.resolve_use_fused(True, gathers=((24, 1),)) is False
+    with pytest.raises(pg.KernelRefused, match="'gstreams'"):
+        pg.resolve_use_fused(True, gathers=((24, 1),))
 
 
 # ------------------------------------------------ engine parity (dense)

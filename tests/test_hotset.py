@@ -152,24 +152,29 @@ def test_probe_cache_is_per_kernel(monkeypatch):
     pg._probe_cache.clear()
 
 
-def test_broken_hot_kernel_degrades_to_xla_partition(monkeypatch, caplog):
-    """Mosaic rejection of the hot kernels costs the VMEM residency,
-    never the partition: the builder serves the hot set via the XLA
-    index-compare route and outputs stay correct."""
+def test_broken_hot_kernel_raises_and_xla_partition_still_serves(monkeypatch):
+    """A Mosaic refusal of the hot kernels raises for the caller that
+    asked for them (use_pallas + use_hotset) — resolver and builder both,
+    never cached — while the partition itself has an XLA form that a
+    caller who does NOT ask for the kernels still gets."""
     pg._probe_cache.clear()
 
     def boom(*a, **k):
         raise RuntimeError("Mosaic lowering failed (simulated)")
 
     monkeypatch.setattr(pg, "gather_rows_hot", boom)
-    with caplog.at_level("WARNING", logger="dint_tpu.pallas"):
-        assert pg.hot_kernels_available(n_idx=64) is False
-    assert any("falling back" in r.message for r in caplog.records)
-    # bypass the builder memo: this build must see the broken kernel,
-    # and the degraded build must not be cached for healthy callers
+    for _ in range(2):
+        with pytest.raises(pg.KernelRefused,
+                           match=r"'hot'.*Mosaic lowering failed"):
+            pg.hot_kernels_available(n_idx=64)
+    assert not any(k[0] == "hot" for k in pg._probe_cache)
+    # bypass the builder memo: this build must see the broken kernel
     sd.build_pipelined_runner.cache.clear()
+    with pytest.raises(pg.KernelRefused, match="'hot'"):
+        sd.build_pipelined_runner(100, w=16, cohorts_per_block=2,
+                                  use_pallas=True, use_hotset=True)
     run_f, init, drain = sd.build_pipelined_runner(
-        100, w=16, cohorts_per_block=2, use_pallas=True, use_hotset=True)
+        100, w=16, cohorts_per_block=2, use_pallas=False, use_hotset=True)
     carry = init(sd.create(100))
     carry, s = run_f(carry, jax.random.PRNGKey(0))
     db, tail = drain(carry)

@@ -78,8 +78,11 @@ def test_host_failure_recovers_from_surviving_host():
     from dint_tpu import recovery
 
     n_sub_global = D * 256
-    n_loc = mh.n_sub_local(n_sub_global, D)
     state, _ = _run(n_sub_global=n_sub_global, w=64, blocks=3)
+    # create_multihost is a function of (geometry, seed): a second call is
+    # the pre-run snapshot
+    fresh = jax.tree.map(np.asarray, mh.create_multihost(
+        mh.make_mesh_2d(H, C), n_sub_global, val_words=VW, seed=0).db)
 
     meta = np.asarray(state.db.meta)
     val = np.asarray(state.db.val)
@@ -91,8 +94,7 @@ def test_host_failure_recovers_from_surviving_host():
     dead_h = 1
     for c in range(C):
         dead = dead_h * C + c                    # linear partition id
-        snap = td.populate(np.random.default_rng(dead), n_loc,
-                           val_words=VW, log_replicas=1)
+        snap = jax.tree.map(lambda x: x[dead_h, c], fresh)
         for off in (1, 2):
             hh = (dead_h + off) % H
             e = entries[hh, c].reshape(lanes, cap, -1)

@@ -7,8 +7,9 @@ reproduce the XLA path's stats, table state, and log rings exactly. These
 tests pin (a) each kernel against its XLA formula, (b) the fused lock pass
 against tatp_dense's actual arb chain on adversarial duplicate/held
 batches, (c) both dense engines end-to-end pallas-vs-XLA, with the env-var
-plumbing exercised for real, and (d) the fallback contract: a broken
-kernel degrades resolve_use_pallas to False instead of raising."""
+plumbing exercised for real, and (d) the refusal contract: a kernel
+that was asked for and is broken raises KernelRefused, from the resolver
+and from the builder, and is never cached as available."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -135,35 +136,34 @@ def test_resolve_use_pallas_env(monkeypatch):
     assert pg.resolve_use_pallas(False) is False      # explicit kwarg wins
 
 
-def test_broken_kernel_degrades_not_raises(monkeypatch, caplog):
-    """The Mosaic-rejection contract: if a kernel fails to compile/run,
-    resolve_use_pallas returns False with a logged warning — builders then
-    run the XLA path; nothing raises (bench.py/exp.py acceptance)."""
+def test_broken_kernel_raises_not_degrades(monkeypatch):
+    """The refusal contract: if a kernel that was asked for fails to
+    compile/run, resolve_use_pallas raises KernelRefused naming the
+    kernel and carrying the compiler's text — from the resolver and from
+    a builder given the flag; nobody gets the XLA route in silence. The
+    refusal is not cached: the next ask raises again, and once the kernel
+    works the same geometry probes clean."""
     pg._probe_cache.clear()
+    real_gather = pg.gather_rows
 
     def boom(*a, **k):
         raise RuntimeError("Mosaic lowering failed (simulated)")
 
     monkeypatch.setattr(pg, "gather_rows", boom)
-    with caplog.at_level("WARNING", logger="dint_tpu.pallas"):
-        assert pg.resolve_use_pallas(True, n_idx=64, m_lock=None) is False
-    assert any("falling back" in r.message for r in caplog.records)
-    pg._probe_cache.clear()
-    # and a builder given the env still comes up on the XLA path
-    # (bypass the builder memo both ways: a healthy cached build would
-    # dodge the broken kernel, and the degraded build must not leak)
+    for _ in range(2):                                 # never cached
+        with pytest.raises(pg.KernelRefused,
+                           match=r"'gather'.*Mosaic lowering failed"):
+            pg.resolve_use_pallas(True, n_idx=64, m_lock=None)
+    assert not pg._probe_cache
+    # a builder given the env raises too (bypass the builder memo: a
+    # healthy cached build would dodge the broken kernel)
     monkeypatch.setenv("DINT_USE_PALLAS", "1")
     td.build_pipelined_runner.cache.clear()
-    run, init, drain = td.build_pipelined_runner(20, w=16, val_words=4,
-                                                 cohorts_per_block=2)
-    carry = init(td.populate(np.random.default_rng(0), 20, val_words=4))
-    tot = np.zeros(td.N_STATS, np.int64)
-    for i in range(2):
-        carry, s = run(carry, jax.random.fold_in(jax.random.PRNGKey(0), i))
-        tot += np.asarray(s, np.int64).sum(axis=0)
-    _, tail = drain(carry)
-    tot += np.asarray(tail, np.int64).sum(axis=0)
-    assert int(tot[td.STAT_ATTEMPTED]) == 2 * 2 * 16  # XLA path ran fine
+    with pytest.raises(pg.KernelRefused, match="'gather'"):
+        td.build_pipelined_runner(20, w=16, val_words=4,
+                                  cohorts_per_block=2)
+    monkeypatch.setattr(pg, "gather_rows", real_gather)
+    assert pg.resolve_use_pallas(True, n_idx=64, m_lock=None) is True
     pg._probe_cache.clear()
     td.build_pipelined_runner.cache.clear()
 

@@ -303,7 +303,7 @@ def test_tatp_full_transactions_over_wire():  # above stay tier-1
             st = coord.stats
             assert st.attempted == 3 * 64
             assert st.committed > 0
-            # outcome taxonomy closes
+            # outcome classification closes
             assert (st.committed + st.aborted_lock + st.aborted_validate
                     + st.aborted_missing + st.aborted_timeout) \
                 == st.attempted

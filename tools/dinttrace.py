@@ -164,7 +164,7 @@ def main(argv=None) -> int:
     p.add_argument("--json", action="store_true")
     p.set_defaults(fn=cmd_slowest)
 
-    p = sub.add_parser("aborts", help="aborted txns (+ cause taxonomy)")
+    p = sub.add_parser("aborts", help="aborted txns (+ cause classes)")
     p.add_argument("file")
     p.add_argument("--by-cause", action="store_true")
     p.add_argument("--json", action="store_true")
