@@ -29,6 +29,26 @@ def enable_compile_cache() -> str:
     code only when the environment is silent."""
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+    # op metadata is left out of the cache key by default (jax 0.9.0), so
+    # a program that differs from a cached one only in its named scopes
+    # is a HIT and runs the old executable under the old names: a wave or
+    # part added or renamed would reach a trace only after a cold
+    # compile. With the metadata in the key such a program misses once.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    # The metadata is the ops' MLIR locations: the name stack, and by
+    # default up to ten frames of the Python call stack under it. A
+    # helper jitted earlier in a process (`_where`, `_threefry_split`)
+    # keeps its first caller's frames, so a process that runs another
+    # phase first (the benchmark's traced run makes its comparison
+    # before it populates) would key the same programs differently and
+    # compile them again (6 of 14 on the chip; PERF.md §6, PR 29). With
+    # no frames the locations are the names alone: the key follows the
+    # ops and their scopes, not who called them or from which line, and
+    # a second run of any kind in a checkout compiles nothing. The price:
+    # compiled programs carry no source file or line in their metadata;
+    # nothing in this repo reads one (the name stack is what the traces'
+    # readers use).
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     return compile_cache_dir()
 
 

@@ -473,7 +473,8 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
     sent = n1 - 1     # sentinel row: gathered by NOP lanes, never written
     oob = n1          # scatter index for masked lanes under mode="drop"
     base = jnp.asarray(_bases(p1))
-    kg, kv3 = jax.random.split(key)
+    with waves.part("tatp_dense", "step_frame"):
+        kg, kv3 = jax.random.split(key)
     t = db.step
 
     # ---- wave 3 of c2: install + log --------------------------------------
@@ -488,29 +489,30 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
     # validate-before-install; severing it fails the tier-1 gate.
     with waves.scope("tatp_dense",
                      "install_log" if use_fused else "install"):
-        do_write = c2.ws_active & c2.alive[:, None]             # [w, 2]
-        wmask = do_write.reshape(-1)
-        wkind = c2.ws_kind.reshape(-1)
-        newex = (wkind != 2) & wmask
-        vv = c2.ws_vv.reshape(-1)   # wave-1 meta (ver<<1|exists): the row
-        #                             was X-held since, so still current
-        meta_new = (((vv >> 1) + 1) << 1) | newex.astype(U32)
-        wrows = jnp.where(wmask, c2.ws_rows.reshape(-1), oob)   # [2w]
-        hn = db.hot_n
-        hot_meta, hot_val = db.hot_meta, db.hot_val
-        payload = jax.random.randint(kv3, (w, 2), 0, 1 << 16, dtype=I32)
-        newval = jnp.zeros((w, 2, val_words), U32)
-        newval = newval.at[:, :, 0].set(payload.astype(U32))
-        newval = newval.at[:, :, 1].set(
-            jnp.where(do_write & (c2.ws_kind != 2), U32(MAGIC), U32(0)))
-        newval = newval.reshape(-1, val_words)
-        newval = jnp.where((wkind == 2)[:, None], U32(0),
-                           newval)                      # delete zeroes
-        newver = (vv >> 1) + 1
-        flags_del = (wkind == 2).astype(I32)
-        log_tbl = c2.ws_tbl.reshape(-1)
-        log_key = c2.ws_key.reshape(-1).astype(U32)
-        zero_hi = jnp.zeros_like(log_key)
+        with waves.part("tatp_dense", "install_build"):
+            do_write = c2.ws_active & c2.alive[:, None]             # [w, 2]
+            wmask = do_write.reshape(-1)
+            wkind = c2.ws_kind.reshape(-1)
+            newex = (wkind != 2) & wmask
+            vv = c2.ws_vv.reshape(-1)   # wave-1 meta (ver<<1|exists): the row
+            #                             was X-held since, so still current
+            meta_new = (((vv >> 1) + 1) << 1) | newex.astype(U32)
+            wrows = jnp.where(wmask, c2.ws_rows.reshape(-1), oob)   # [2w]
+            hn = db.hot_n
+            hot_meta, hot_val = db.hot_meta, db.hot_val
+            payload = jax.random.randint(kv3, (w, 2), 0, 1 << 16, dtype=I32)
+            newval = jnp.zeros((w, 2, val_words), U32)
+            newval = newval.at[:, :, 0].set(payload.astype(U32))
+            newval = newval.at[:, :, 1].set(
+                jnp.where(do_write & (c2.ws_kind != 2), U32(MAGIC), U32(0)))
+            newval = newval.reshape(-1, val_words)
+            newval = jnp.where((wkind == 2)[:, None], U32(0),
+                               newval)                      # delete zeroes
+            newver = (vv >> 1) + 1
+            flags_del = (wkind == 2).astype(I32)
+            log_tbl = c2.ws_tbl.reshape(-1)
+            log_key = c2.ws_key.reshape(-1).astype(U32)
+            zero_hi = jnp.zeros_like(log_key)
         if use_fused:
             # install_log megakernel: the val + meta installs, the
             # replicated log append, and (hotset) the mirror write-through
@@ -553,16 +555,18 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                                           wmask, newval.reshape(-1),
                                           val_words, use_pallas=use_pallas)
         else:
-            meta = db.meta.at[wrows].set(meta_new, mode="drop",
-                                         unique_indices=True)
+            with waves.part("tatp_dense", "meta_scatter"):
+                meta = db.meta.at[wrows].set(meta_new, mode="drop",
+                                             unique_indices=True)
             # interleaved-1-D install: row r's words live at
             # [r*VW, (r+1)*VW); the masked-lane oob row lands at
             # n1*VW >= len and drops (same discipline as
             # parallel/dense_sharded._apply_backup)
-            wflat = (wrows[:, None] * val_words
-                     + jnp.arange(val_words, dtype=I32)).reshape(-1)
-            val = db.val.at[wflat].set(newval.reshape(-1), mode="drop",
-                                       unique_indices=True)
+            with waves.part("tatp_dense", "val_scatter"):
+                wflat = (wrows[:, None] * val_words
+                         + jnp.arange(val_words, dtype=I32)).reshape(-1)
+                val = db.val.at[wflat].set(newval.reshape(-1), mode="drop",
+                                           unique_indices=True)
 
     if not use_fused:
         with waves.scope("tatp_dense", "log_append"):
@@ -597,9 +601,10 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
             ops = jnp.where(lane_ok[:, None], ops, Op.NOP)
             ws_active = ws_active & lane_ok[:, None]
 
-    used = ops != Op.NOP
-    rows = jnp.where(used, base[tbl] + kk, sent)                # [w, K]
-    is_read = ops == Op.OCC_READ
+    with waves.part("tatp_dense", "addr"):
+        used = ops != Op.NOP
+        rows = jnp.where(used, base[tbl] + kk, sent)                # [w, K]
+        is_read = ops == Op.OCC_READ
 
     if use_fused:
         # lock_validate megakernel: c1's validate re-read + verdict, the
@@ -627,7 +632,8 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
             rmeta = rmeta_f.reshape(w, K)                       # [w, K]
         # in-kernel verdict == (meta[vidx] != vv1); the is_read mask is
         # applied here exactly as the unfused compare applied it
-        bad = c1.is_read & (vbad.reshape(w, K) != 0)
+        with waves.part("tatp_dense", "validate"):
+            bad = c1.is_read & (vbad.reshape(w, K) != 0)
     else:
         # ONE fused meta gather serves wave 2 (c1's validate re-read) AND
         # wave 1 (the new cohort's reads). Both gathers depend on the same
@@ -646,27 +652,31 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                      else meta[gidx])
             vvB = g[: w * K].reshape(w, K)                      # [w, K]
             rmeta = g[w * K:].reshape(w, K)                     # [w, K]
-        bad = c1.is_read & (vvB != c1.vv1)
+        with waves.part("tatp_dense", "validate"):
+            bad = c1.is_read & (vvB != c1.vv1)
 
     # ---- wave 2 of c1: validate read-set version compare ------------------
-    changed = bad.any(axis=1)
-    if counters is not None or ring is not None:
-        # lanes of surviving RW txns checked / failed — the same lane set
-        # the generic pipeline re-reads (_validate_lanes), so the parity
-        # counters are engine-independent. The flight recorder needs the
-        # per-lane masks (and c1's PRE-verdict alive) for its VALIDATE
-        # and wave-2 OUTCOME events, captured before the replace below.
-        v_alive = c1.alive[:, None]
-        v_lanes = (c1.is_read & v_alive).sum(dtype=I32)
-        v_failed = (bad & v_alive).sum(dtype=I32)
-        val_mask = (c1.is_read & v_alive).reshape(-1)       # [wK]
-        val_bad = (bad & v_alive).reshape(-1)               # [wK]
-        c1_alive_pre = c1.alive
-    c1 = c1.replace(alive=c1.alive & ~changed,
-                    ab_validate=(c1.alive & changed).sum(dtype=I32))
+    with waves.part("tatp_dense", "validate"):
+        changed = bad.any(axis=1)
+        if counters is not None or ring is not None:
+            # lanes of surviving RW txns checked / failed — the same lane set
+            # the generic pipeline re-reads (_validate_lanes), so the parity
+            # counters are engine-independent. The flight recorder needs the
+            # per-lane masks (and c1's PRE-verdict alive) for its VALIDATE
+            # and wave-2 OUTCOME events, captured before the replace below.
+            with waves.part("tatp_dense", "monitor"):
+                v_alive = c1.alive[:, None]
+                v_lanes = (c1.is_read & v_alive).sum(dtype=I32)
+                v_failed = (bad & v_alive).sum(dtype=I32)
+                val_mask = (c1.is_read & v_alive).reshape(-1)       # [wK]
+                val_bad = (bad & v_alive).reshape(-1)               # [wK]
+                c1_alive_pre = c1.alive
+        c1 = c1.replace(alive=c1.alive & ~changed,
+                        ab_validate=(c1.alive & changed).sum(dtype=I32))
 
-    vv1 = rmeta                     # ver<<1|exists — locks live elsewhere
-    rex = (rmeta & 1) != 0
+    with waves.part("tatp_dense", "classify"):
+        vv1 = rmeta                 # ver<<1|exists — locks live elsewhere
+        rex = (rmeta & 1) != 0
     if check_magic:
         # the magic-parity oracle costs one [w,K] single-word gather over
         # the 6.2 GB val array per step; check_magic=False is an A/B
@@ -697,7 +707,8 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
     # Candidates for held rows are masked OUT of the scatter so rejected
     # attempts cannot keep a hot row stamped (no livelock). On the fused
     # route the whole chain already ran inside lock_validate above.
-    ws_vv = jnp.take_along_axis(rmeta, ws_lane, axis=1)
+    with waves.part("tatp_dense", "ws_pick"):
+        ws_vv = jnp.take_along_axis(rmeta, ws_lane, axis=1)
     if not use_fused:
         with waves.scope("tatp_dense", "lock"):
             ws_rows = jnp.where(ws_active, base[ws_tbl] + ws_key,
@@ -725,102 +736,109 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                     hot_n=hn if use_hotset else 0)
                 grant = (grant_u != 0).reshape(w, 2)
             else:
-                arb_old = db.arb[flat_ws]   # [2w]; sentinel never stamped
-                held = (arb_old >> K_ARB) == (t - 1)
-                inv_slot = U32(2 * w - 1) - jnp.arange(2 * w, dtype=U32)
-                packed = (t << K_ARB) | inv_slot
-                cand = active & ~held
-                arb = db.arb.at[jnp.where(cand, flat_ws, oob)].max(
-                    packed, mode="drop")
-                grant = (cand & (arb[flat_ws] == packed)).reshape(w, 2)
+                with waves.part("tatp_dense", "lock_read"):
+                    arb_old = db.arb[flat_ws]   # [2w]; sentinel never stamped
+                    held = (arb_old >> K_ARB) == (t - 1)
+                with waves.part("tatp_dense", "lock_scatter_max"):
+                    inv_slot = U32(2 * w - 1) - jnp.arange(2 * w, dtype=U32)
+                    packed = (t << K_ARB) | inv_slot
+                    cand = active & ~held
+                    arb = db.arb.at[jnp.where(cand, flat_ws, oob)].max(
+                        packed, mode="drop")
+                with waves.part("tatp_dense", "lock_readback"):
+                    grant = (cand & (arb[flat_ws] == packed)).reshape(w, 2)
 
-    # reply types: reads from the gather; write-slot GRANT/REJECT direct
-    rt = jnp.where(is_read & used,
-                   jnp.where(rex, Reply.VAL, Reply.NOT_EXIST), Reply.NONE)
-    ws_rt = jnp.where(grant, Reply.GRANT,
-                      jnp.where(ws_active, Reply.REJECT, Reply.NONE))
+    with waves.part("tatp_dense", "classify"):
+        # reply types: reads from the gather; write-slot GRANT/REJECT direct
+        rt = jnp.where(is_read & used,
+                       jnp.where(rex, Reply.VAL, Reply.NOT_EXIST), Reply.NONE)
+        ws_rt = jnp.where(grant, Reply.GRANT,
+                          jnp.where(ws_active, Reply.REJECT, Reply.NONE))
 
-    # ---- wave-1 outcome: shared per-txn-type rules ------------------------
-    is_ro, rw, granted, lock_rejected, missing = classify_wave1(
-        ttype, rt, ops, ws_active, ws_lane, ws_rt=ws_rt)
+        # ---- wave-1 outcome: shared per-txn-type rules --------------------
+        is_ro, rw, granted, lock_rejected, missing = classify_wave1(
+            ttype, rt, ops, ws_active, ws_lane, ws_rt=ws_rt)
 
-    new_ctx = DenseCtx(
-        rows=rows, is_read=is_read & used, vv1=vv1,
-        alive=rw & ~lock_rejected & ~missing,
-        ro_commit=is_ro & ~missing, granted=granted,
-        ws_rows=ws_rows, ws_vv=ws_vv,
-        ws_tbl=ws_tbl, ws_key=ws_key, ws_kind=ws_kind,
-        ws_active=ws_active,
-        attempted=(occ if occupancy is not None
-                   else jnp.asarray(w if gen_new else 0, I32)),
-        ab_lock=(rw & lock_rejected).sum(dtype=I32),
-        ab_missing=((rw & ~lock_rejected & missing)
-                    | (is_ro & missing)).sum(dtype=I32),
-        ab_validate=jnp.asarray(0, I32),
-        magic_bad=magic_bad)
+        new_ctx = DenseCtx(
+            rows=rows, is_read=is_read & used, vv1=vv1,
+            alive=rw & ~lock_rejected & ~missing,
+            ro_commit=is_ro & ~missing, granted=granted,
+            ws_rows=ws_rows, ws_vv=ws_vv,
+            ws_tbl=ws_tbl, ws_key=ws_key, ws_kind=ws_kind,
+            ws_active=ws_active,
+            attempted=(occ if occupancy is not None
+                       else jnp.asarray(w if gen_new else 0, I32)),
+            ab_lock=(rw & lock_rejected).sum(dtype=I32),
+            ab_missing=((rw & ~lock_rejected & missing)
+                        | (is_ro & missing)).sum(dtype=I32),
+            ab_validate=jnp.asarray(0, I32),
+            magic_bad=magic_bad)
 
-    db = db.replace(val=val, meta=meta, arb=arb, step=t + 1, log=logs,
-                    hot_meta=hot_meta, hot_val=hot_val)
+    with waves.part("tatp_dense", "step_frame"):
+        db = db.replace(val=val, meta=meta, arb=arb, step=t + 1, log=logs,
+                        hot_meta=hot_meta, hot_val=hot_val)
     if counters is not None:
-        grant_l = grant.reshape(-1)
-        hot_ctrs = {}
-        if use_hotset:
-            # partition accounting over the meta + magic gathers (the arb
-            # prefix residency has no per-lane split to count). The fused
-            # lock_validate reads the main meta table directly (bit-
-            # identical by the mirror invariant), so its lanes are not
-            # partitioned and only the magic gather counts there
-            if use_fused:
-                hits = jnp.asarray(0, I32)
-                lanes = 0
-                refresh = 0
-            else:
-                hits = (g_midx >= 0).sum(dtype=I32)
-                lanes = 2 * w * K
-                refresh = hn * 4
-            if check_magic:
-                hits = hits + (mg_midx >= 0).sum(dtype=I32)
-                lanes += w * K
-                refresh += hn * val_words * 4
-            hot_ctrs = {
-                mon.CTR_HOT_HITS: hits,
-                mon.CTR_HOT_COLD_ROWS: lanes - hits,
-                mon.CTR_HOT_REFRESH_BYTES: refresh if use_pallas else 0,
-            }
-        serve_ctrs = {}
-        if occupancy is not None:
-            serve_ctrs = {
-                mon.CTR_SERVE_OCC_LANES: occ,
-                mon.CTR_SERVE_PAD_LANES: jnp.asarray(w, I32) - occ,
-                mon.CTR_SERVE_SHED_LANES:
-                    jnp.asarray(0 if shed is None else shed, I32),
-            }
-        counters = mon.bump(counters, {
-            **hot_ctrs,
-            **serve_ctrs,
-            mon.CTR_STEPS: 1,
-            mon.CTR_TXN_ATTEMPTED: c2.attempted,
-            mon.CTR_TXN_COMMITTED: (c2.ro_commit | c2.alive).sum(dtype=I32),
-            mon.CTR_AB_LOCK: c2.ab_lock,
-            mon.CTR_AB_MISSING: c2.ab_missing,
-            mon.CTR_AB_VALIDATE: c2.ab_validate,
-            mon.CTR_MAGIC_BAD: c2.magic_bad,
-            mon.CTR_LOCK_REQUESTS: active.sum(dtype=I32),
-            mon.CTR_LOCK_GRANTED: (active & grant_l).sum(dtype=I32),
-            mon.CTR_LOCK_REJECTED: (active & ~grant_l).sum(dtype=I32),
-            mon.CTR_LOCK_REJECT_HELD: (active & held).sum(dtype=I32),
-            mon.CTR_LOCK_REJECT_ARB:
-                (active & ~held & ~grant_l).sum(dtype=I32),
-            mon.CTR_VALIDATE_LANES: v_lanes,
-            mon.CTR_VALIDATE_FAILED: v_failed,
-            mon.CTR_INSTALL_WRITES: wmask.sum(dtype=I32),
-            mon.CTR_LOG_APPENDS: wmask.sum(dtype=I32),
-            (mon.CTR_DISPATCH_PALLAS if use_pallas
-             else mon.CTR_DISPATCH_XLA): 1,
-            **({mon.CTR_FUSED_DISPATCH: 1} if use_fused else {}),
-        })
-        counters = mon.gauge_max(
-            counters, {mon.CTR_RING_HWM: logs.head.max()})
+        with waves.part("tatp_dense", "monitor"):
+            grant_l = grant.reshape(-1)
+            hot_ctrs = {}
+            if use_hotset:
+                # partition accounting over the meta + magic gathers (the arb
+                # prefix residency has no per-lane split to count). The fused
+                # lock_validate reads the main meta table directly (bit-
+                # identical by the mirror invariant), so its lanes are not
+                # partitioned and only the magic gather counts there
+                if use_fused:
+                    hits = jnp.asarray(0, I32)
+                    lanes = 0
+                    refresh = 0
+                else:
+                    hits = (g_midx >= 0).sum(dtype=I32)
+                    lanes = 2 * w * K
+                    refresh = hn * 4
+                if check_magic:
+                    hits = hits + (mg_midx >= 0).sum(dtype=I32)
+                    lanes += w * K
+                    refresh += hn * val_words * 4
+                hot_ctrs = {
+                    mon.CTR_HOT_HITS: hits,
+                    mon.CTR_HOT_COLD_ROWS: lanes - hits,
+                    mon.CTR_HOT_REFRESH_BYTES: refresh if use_pallas else 0,
+                }
+            serve_ctrs = {}
+            if occupancy is not None:
+                serve_ctrs = {
+                    mon.CTR_SERVE_OCC_LANES: occ,
+                    mon.CTR_SERVE_PAD_LANES: jnp.asarray(w, I32) - occ,
+                    mon.CTR_SERVE_SHED_LANES:
+                        jnp.asarray(0 if shed is None else shed, I32),
+                }
+            counters = mon.bump(counters, {
+                **hot_ctrs,
+                **serve_ctrs,
+                mon.CTR_STEPS: 1,
+                mon.CTR_TXN_ATTEMPTED: c2.attempted,
+                mon.CTR_TXN_COMMITTED:
+                    (c2.ro_commit | c2.alive).sum(dtype=I32),
+                mon.CTR_AB_LOCK: c2.ab_lock,
+                mon.CTR_AB_MISSING: c2.ab_missing,
+                mon.CTR_AB_VALIDATE: c2.ab_validate,
+                mon.CTR_MAGIC_BAD: c2.magic_bad,
+                mon.CTR_LOCK_REQUESTS: active.sum(dtype=I32),
+                mon.CTR_LOCK_GRANTED: (active & grant_l).sum(dtype=I32),
+                mon.CTR_LOCK_REJECTED: (active & ~grant_l).sum(dtype=I32),
+                mon.CTR_LOCK_REJECT_HELD: (active & held).sum(dtype=I32),
+                mon.CTR_LOCK_REJECT_ARB:
+                    (active & ~held & ~grant_l).sum(dtype=I32),
+                mon.CTR_VALIDATE_LANES: v_lanes,
+                mon.CTR_VALIDATE_FAILED: v_failed,
+                mon.CTR_INSTALL_WRITES: wmask.sum(dtype=I32),
+                mon.CTR_LOG_APPENDS: wmask.sum(dtype=I32),
+                (mon.CTR_DISPATCH_PALLAS if use_pallas
+                 else mon.CTR_DISPATCH_XLA): 1,
+                **({mon.CTR_FUSED_DISPATCH: 1} if use_fused else {}),
+            })
+            counters = mon.gauge_max(
+                counters, {mon.CTR_RING_HWM: logs.head.max()})
     extra = ()
     if ring is not None:
         # dinttrace: the txn id is recomputable per cohort — gen_step*w +
@@ -865,6 +883,8 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
             )
             ring, counters = txe.emit(ring, tcfg, groups, counters)
         extra = (ring,)
+    with waves.part("tatp_dense", "stats"):
+        stats = _stats_of(c2)
     if emit_installs:
         inst = Installs(
             wmask=wmask, rows=c2.ws_rows.reshape(-1),
@@ -872,11 +892,11 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
             val=newval, tbl=log_tbl, key=log_key,
             is_del=flags_del, ver=newver)
         if counters is not None:
-            return (db, new_ctx, c1, _stats_of(c2), inst, counters) + extra
-        return (db, new_ctx, c1, _stats_of(c2), inst) + extra
+            return (db, new_ctx, c1, stats, inst, counters) + extra
+        return (db, new_ctx, c1, stats, inst) + extra
     if counters is not None:
-        return (db, new_ctx, c1, _stats_of(c2), counters) + extra
-    return (db, new_ctx, c1, _stats_of(c2)) + extra
+        return (db, new_ctx, c1, stats, counters) + extra
+    return (db, new_ctx, c1, stats) + extra
 
 
 def rebase_stamps(db: DenseDB) -> DenseDB:
@@ -1006,23 +1026,22 @@ def build_pipelined_runner(n_sub: int, w: int = 8192, val_words: int = 10,
                + ((cnt,) if monitor else ()))
         return out, stats
 
-    def _pre(carry):
-        db = jax.lax.cond(carry[0].step >= U32(REBASE_AT), rebase_stamps,
-                          lambda d: d, carry[0])
-        carry = (db,) + carry[1:]
-        if trace_on:     # each drained window is self-contained
-            carry = carry[:3] + (txe.reset(carry[3]),) + carry[4:]
-        return carry
+    def _pre(carry, key):
+        with waves.part("tatp_dense", "block_pre"):
+            db = jax.lax.cond(carry[0].step >= U32(REBASE_AT),
+                              rebase_stamps, lambda d: d, carry[0])
+            carry = (db,) + carry[1:]
+            if trace_on:     # each drained window is self-contained
+                carry = carry[:3] + (txe.reset(carry[3]),) + carry[4:]
+            return carry, jax.random.split(key, cohorts_per_block)
 
     if serve:
         def block(carry, key, occ, shed):
-            carry = _pre(carry)
-            keys = jax.random.split(key, cohorts_per_block)
+            carry, keys = _pre(carry, key)
             return jax.lax.scan(scan_fn, carry, (keys, occ, shed))
     else:
         def block(carry, key):
-            carry = _pre(carry)
-            keys = jax.random.split(key, cohorts_per_block)
+            carry, keys = _pre(carry, key)
             return jax.lax.scan(scan_fn, carry, keys)
 
     def init(db):

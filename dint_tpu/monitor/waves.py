@@ -18,9 +18,10 @@ that survives fusion, so it is schema:
   regenerate the fixture with `python tools/dintscope.py synth`.
 * **Semantics-neutral.** `jax.named_scope` only pushes the name stack —
   it adds no jaxpr equations, so engine outputs are bit-identical with
-  scopes on or off (pinned in tests/test_dintscope.py) and the
-  dintlint/dintproof target matrix is unaffected. `DINT_SCOPE=0` disables
-  the annotations entirely (the A/B knob behind that pin).
+  or without the scopes (pinned in tests/test_dintscope.py) and the
+  dintlint/dintproof target matrix is unaffected. There is no switch:
+  the scopes cost nothing, and every device metric of the benchmark
+  reads them.
 * **Bytes formulas are declared, not measured.** Each wave may carry an
   expected-bytes-per-step formula (a string evaluated against the run's
   geometry: w, k, l, vw, d, ...), the same hand accounting PERF.md's
@@ -30,11 +31,19 @@ that survives fusion, so it is schema:
   Formulas are estimates of logical bytes moved (random-access row
   traffic; they ignore XLA padding/tiling) and `None` marks compute-only
   waves.
+
+**Parts** are the level below the waves: ``jax.named_scope("part.<name>")``
+(the `part` helper) around the pieces of a wave, and around what a step
+does outside every wave. A part's name is deliberately not of the form
+``dint.<engine>.<wave>``: benchmarks/trace_reduce.py takes the FIRST such
+name on an op's stack and analysis/cost.py the LAST, so a nested ``dint.``
+name would move dintcost's per-wave budgets and every artifact pinned on
+them. A ``part.`` name moves neither; benchmarks/part_times.py reads the
+last one on the stack. Waves stay the schema; parts may change with the
+code (registered, but not append-only, and no artifact keys on them but
+the benchmark's per-layer metrics named in `_PARTS`).
 """
 from __future__ import annotations
-
-import contextlib
-import os
 
 PREFIX = "dint"
 
@@ -327,25 +336,90 @@ def wave_bytes(name: str, **geometry) -> int | None:
         return None
 
 
-def scopes_enabled() -> bool:
-    """DINT_SCOPE=0 disables the annotations (the A/B knob behind the
-    bit-identical pin); default on — the scopes are free when no profiler
-    is attached."""
-    return os.environ.get("DINT_SCOPE", "1") != "0"
-
-
 def scope(engine: str, wave: str):
     """`jax.named_scope("dint.<engine>.<wave>")` for a REGISTERED wave —
     annotating an unregistered name raises at trace time, so the registry
-    and the annotations cannot drift apart. Returns a null context when
-    scopes are disabled."""
+    and the annotations cannot drift apart."""
     name = full_name(engine, wave)
     if name not in WAVE_DOCS:
         raise KeyError(
             f"wave {name!r} is not in the dintscope registry "
             "(monitor/waves.py); append it there first")
-    if not scopes_enabled():
-        return contextlib.nullcontext()
     import jax
 
     return jax.named_scope(name)
+
+
+# --------------------------------------------------------------- the parts
+PART_PREFIX = "part"
+
+# (owner, wave | None, part, doc). The owner is the engine, or the shared
+# module ("log" = tables/log.py), whose code opens the part; the wave is
+# the one it lies under in that owner's step, None for what a step does
+# outside every wave. append_rep's parts also run under
+# `dense_sharded.replicate`, where a backup appends; that wave's own
+# parts come with the cell that reads them. The innermost part on an
+# op's name stack is the one its time is booked to
+# (benchmarks/part_times.py).
+_PARTS: tuple[tuple[str, str | None, str, str], ...] = (
+    # --- dense TATP (engines/tatp_dense.py), the XLA route --------------
+    ("tatp_dense", "install", "install_build",
+     "masks, new meta words, payload draw and the [2w, VW] new rows"),
+    ("tatp_dense", "install", "meta_scatter",
+     "unique-index scatter of 2w meta words"),
+    ("tatp_dense", "install", "val_scatter",
+     "unique-index scatter of 2w x VW single value words into the 1-D "
+     "val array, with its flat index (val_scatter_ms.* reads this)"),
+    ("tatp_dense", "lock", "lock_read",
+     "gather of the 2w write slots' arb stamps + the held compare"),
+    ("tatp_dense", "lock", "lock_scatter_max",
+     "packed stamps + masked scatter-max into arb"),
+    ("tatp_dense", "lock", "lock_readback",
+     "winner gather-back + the grant compare"),
+    ("tatp_dense", None, "step_frame",
+     "the step's frame: its key split and the step counter's increment"),
+    ("tatp_dense", None, "addr",
+     "wave-1 addressing: used lanes, table base + key, read mask"),
+    ("tatp_dense", None, "validate",
+     "wave 2: c1's version compare, its reductions, the new alive mask"),
+    ("tatp_dense", None, "ws_pick",
+     "the write slots' version pick: a take_along_axis gather of [w, 2] "
+     "out of the [w, K] meta words read"),
+    ("tatp_dense", None, "classify",
+     "reply types, classify_wave1 and the new cohort's context"),
+    ("tatp_dense", None, "monitor",
+     "everything the step does only because the counter plane (or the "
+     "flight recorder) is threaded: the reductions, the scatter-add, "
+     "the gauge max (monitor_ms.* reads this)"),
+    ("tatp_dense", None, "stats",
+     "the completing cohort's stats vector"),
+    ("tatp_dense", None, "block_pre",
+     "block prologue: per-step key split, the stamp-rebase cond, the "
+     "flight recorder's ring reset"),
+    # --- tables/log.py append_rep, under whichever wave calls it --------
+    ("log", "log_append", "log_plan",
+     "lane / rank / slot plan and the replica-packed entry rows"),
+    ("log", "log_append", "log_scatter",
+     "unique-index row scatter into the rings + the head advance"),
+)
+
+# keyed on the part's name alone: the scope is `part.<name>`, so two
+# owners could not tell a shared name apart in a trace
+PART_OWNER: dict[str, str] = {p: o for o, _, p, _ in _PARTS}
+assert len(PART_OWNER) == len(_PARTS), "duplicate part in registry"
+
+
+def part_name(name: str) -> str:
+    return f"{PART_PREFIX}.{name}"
+
+
+def part(owner: str, name: str):
+    """`jax.named_scope("part.<name>")` for a REGISTERED part of `owner`;
+    an unregistered one raises at trace time, as `scope` does."""
+    if PART_OWNER.get(name) != owner:
+        raise KeyError(
+            f"part {name!r} of {owner!r} is not in the part registry "
+            "(monitor/waves.py _PARTS); add it there first")
+    import jax
+
+    return jax.named_scope(part_name(name))

@@ -18,6 +18,8 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 
+from ..monitor import waves
+
 I32 = jnp.int32
 U32 = jnp.uint32
 
@@ -172,14 +174,18 @@ def append_rep(ring: RepLog, do_append, table_id, is_del, key_hi, key_lo,
     """Batched replicated append; same slot assignment as `append` (lane =
     round-robin, slot = head[lane] + arrival rank within the lane, rings
     wrap). One unique-index row scatter installs all replicas."""
-    flat, entry3, lane_counts = plan_rep(ring, do_append, table_id,
-                                         is_del, key_hi, key_lo, ver, val)
-    lanes = ring.lanes
-    cap = ring.capacity
-    widx = jnp.where(flat >= 0, flat, lanes * cap)
-    new_entries = ring.entries.at[widx].set(entry3, mode="drop",
-                                            unique_indices=True)
-    return ring.replace(entries=new_entries, head=ring.head + lane_counts)
+    with waves.part("log", "log_plan"):
+        flat, entry3, lane_counts = plan_rep(ring, do_append, table_id,
+                                             is_del, key_hi, key_lo, ver,
+                                             val)
+    with waves.part("log", "log_scatter"):
+        lanes = ring.lanes
+        cap = ring.capacity
+        widx = jnp.where(flat >= 0, flat, lanes * cap)
+        new_entries = ring.entries.at[widx].set(entry3, mode="drop",
+                                                unique_indices=True)
+        return ring.replace(entries=new_entries,
+                            head=ring.head + lane_counts)
 
 
 def advance_watermark(ring: LogRing | RepLog, watermark, consumed):

@@ -107,16 +107,21 @@ def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
 
 def test_compile_cache_helper(monkeypatch):
     """JAX_COMPILATION_CACHE_DIR wins and no path is set in code; without
-    it the cache lives at the fixed in-checkout path."""
+    it the cache lives at the fixed in-checkout path. Either way the ops'
+    names go into the cache key and their call stacks stay out of it
+    (tests/test_parts.py says why)."""
+    in_key = [("jax_compilation_cache_include_metadata_in_key", True),
+              ("jax_traceback_in_locations_limit", 0)]
     updates = []
     monkeypatch.setattr(jax.config, "update",
                         lambda name, val: updates.append((name, val)))
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
     assert _runtime.compile_cache_dir() == "/some/dir"
     assert _runtime.enable_compile_cache() == "/some/dir"
-    assert updates == []
+    assert updates == in_key
+    updates.clear()
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     fixed = os.path.join(REPO, ".jax_cache")
     assert _runtime.compile_cache_dir() == fixed
     assert _runtime.enable_compile_cache() == fixed
-    assert updates == [("jax_compilation_cache_dir", fixed)]
+    assert updates == [("jax_compilation_cache_dir", fixed)] + in_key

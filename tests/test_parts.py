@@ -1,0 +1,199 @@
+"""Parts: the named level below the waves (monitor/waves.py ``_PARTS``).
+
+A part is ``jax.named_scope("part.<name>")`` round a piece of a wave or
+round what a step does outside every wave. Pinned here: the registry's
+shape, that every part reaches compiled HLO under its wave, that no
+equation of the dense block is left without a wave or a part, that the
+names move neither reader of the waves, and that outputs are
+bit-identical without them. tests/test_dintcost.py, test_dintcal.py and
+test_dintscope.py pass unedited beside this file: the proof that the
+per-wave budgets did not move."""
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import trace_reduce
+from dint_tpu import _runtime
+from dint_tpu.analysis import cost
+from dint_tpu.engines import tatp_dense as td
+from dint_tpu.monitor import waves
+from dint_tpu.parallel import dense_sharded as ds
+
+pytestmark = pytest.mark.scope
+
+N_SUB, W, CPB, VW = 2000, 64, 2, 10
+NAMED = re.compile(r"dint\.[a-z0-9_]+\.[a-z0-9_]+|part\.[a-z0-9_]+")
+
+
+def _dense(monitor=True):
+    run, init, drain = td.build_pipelined_runner(
+        N_SUB, w=W, val_words=VW, cohorts_per_block=CPB, monitor=monitor)
+    db = td.populate(np.random.default_rng(0), N_SUB, val_words=VW)
+    return run, init(db), drain
+
+
+def _op_names(compiled_text: str) -> set:
+    """The name stacks in a compiled module's metadata (XLA joins the
+    names of ops it merged with ``;``)."""
+    return {n for joined in re.findall(r'op_name="([^"]*)"', compiled_text)
+            for n in joined.split(";")}
+
+
+def _assert_parts_under_their_waves(names: set, owners: tuple,
+                                    skip: tuple = ()) -> None:
+    """Every registered part of ``owners`` is in some op_name, and where
+    the registry gives it a wave, each such name has that wave before
+    it."""
+    for owner, wave, part, _ in waves._PARTS:
+        if owner not in owners or part in skip:
+            continue
+        here = waves.part_name(part)
+        stacks = [n.split("/") for n in names if here in n.split("/")]
+        assert stacks, f"{here} is in no op_name"
+        for stack in stacks if wave else ():
+            assert any(re.fullmatch(rf"dint\.[a-z0-9_]+\.{wave}", s)
+                       for s in stack[:stack.index(here)]), "/".join(stack)
+
+
+def test_part_registry_schema():
+    assert len({p for _, _, p, _ in waves._PARTS}) == len(waves._PARTS)
+    short = {n.split(".", 2)[2] for n in waves.ALL_WAVES}
+    for owner, wave, part, doc in waves._PARTS:
+        assert re.fullmatch(r"[a-z0-9_]+", part) and doc, part
+        assert wave is None or wave in short, (part, wave)
+        assert owner in waves.ENGINES or owner == "log", owner
+        # a part's name is read by neither reader of the waves
+        name = f"jit(block)/while/body/{waves.part_name(part)}/scatter"
+        assert not trace_reduce.SCOPE.search(name)
+        assert not cost._WAVE_RE.search(name)
+
+
+def test_part_rejects_unregistered_name():
+    with pytest.raises(KeyError, match="part registry"):
+        waves.part("tatp_dense", "no_such_part")
+    with pytest.raises(KeyError):
+        waves.part("smallbank_dense", "val_scatter")    # another's part
+
+
+def test_every_dense_part_reaches_compiled_hlo_under_its_wave():
+    run, carry, _ = _dense()
+    text = run.lower(carry, jax.random.PRNGKey(0)).compile().as_text()
+    _assert_parts_under_their_waves(_op_names(text), ("tatp_dense", "log"))
+
+
+def test_the_sharded_block_carries_the_dense_steps_parts():
+    """The four-device block runs the same `pipe_step`, so its trace
+    splits the same way; `replicate` has no parts of its own until a
+    cell reads them, and `append_rep`'s ride under it where a backup
+    appends."""
+    d = 4
+    mesh = ds.make_mesh(d)
+    state = ds.create_sharded(mesh, d, 4 * 512, val_words=4, seed=0)
+    run, init, _ = ds.build_sharded_pipelined_runner(
+        mesh, d, 4 * 512, w=16, val_words=4, cohorts_per_block=2,
+        monitor=True)
+    names = _op_names(jax.jit(run).lower(
+        init(state), jax.random.PRNGKey(0)).compile().as_text())
+    # block_pre is the one-chip runner's prologue
+    _assert_parts_under_their_waves(
+        {n for n in names if "dint.dense_sharded.replicate" not in n},
+        ("tatp_dense", "log"), skip=("block_pre",))
+    assert any(re.search(r"dint\.dense_sharded\.replicate/.*"
+                         r"part\.log_scatter", n) for n in names)
+
+
+def _unnamed_equations(jaxpr, named: bool, path: tuple, out: list) -> list:
+    """Leaf equations with neither a wave nor a part on their own name
+    stack or on that of an equation that holds them (inner jaxprs' name
+    stacks are relative to their holder's, as analysis/cost.py's
+    ``wave_ctx`` has it)."""
+    for eqn in jaxpr.eqns:
+        here = named or bool(NAMED.search(str(eqn.source_info.name_stack)))
+        inner = [j for v in eqn.params.values()
+                 for x in (v if isinstance(v, (list, tuple)) else (v,))
+                 for j in (getattr(x, "jaxpr", x),)
+                 if hasattr(j, "eqns")]
+        for j in inner:
+            _unnamed_equations(j, here, path + (eqn.primitive.name,), out)
+        if not inner and not here:
+            out.append(("/".join(path), eqn.primitive.name,
+                        str(eqn.source_info.name_stack)))
+    return out
+
+
+@pytest.mark.parametrize("monitor", [True, False])
+def test_every_equation_of_the_dense_block_carries_a_wave_or_a_part(
+        monitor):
+    """What is left for ``unnamed_ms`` is what XLA inserts on its own:
+    the program leaves no equation of the block unnamed (the ``scan``
+    equation itself is a holder: its body's equations are the step)."""
+    run, carry, _ = _dense(monitor)
+    closed = jax.make_jaxpr(run)(carry, jax.random.PRNGKey(0))
+    assert _unnamed_equations(closed.jaxpr, False, (), []) == []
+
+
+def test_parts_are_semantics_neutral(monkeypatch):
+    def run_once():
+        run, carry, drain = _dense()
+        carry, stats = run(carry, jax.random.PRNGKey(3))
+        db, tail, counters = drain(carry)
+        return [np.asarray(x) for x in (
+            stats, tail, counters.buf, db.val, db.meta, db.arb,
+            db.log.entries, db.log.head)]
+
+    a = run_once()
+    td.build_pipelined_runner.cache.clear()     # not in the memo's key
+    monkeypatch.setattr(waves, "part",
+                        lambda owner, name: contextlib.nullcontext())
+    b = run_once()
+    td.build_pipelined_runner.cache.clear()
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(x, y)
+
+
+def _scoped(name):
+    def f(x):
+        with jax.named_scope(name):
+            return jnp.where(x > 0, x, 0).sum()
+    return f
+
+
+def _lower_here(f, x):
+    return jax.jit(f).lower(x).as_text(debug_info=True)
+
+
+def _lower_there(f, x):
+    # another caller, another line: another call stack
+    return jax.jit(f).lower(x).as_text(debug_info=True)
+
+
+def test_compile_cache_key_holds_the_names_and_no_call_stack(monkeypatch):
+    """With the metadata in the key a program that differs from a cached
+    one only in its named scopes is a miss (without it: a hit that runs
+    under the old names). The metadata is the module's locations, the
+    text compared here; with no stack frames in them the same ops under
+    the same names key alike whoever called them."""
+    seen = {}
+    update = jax.config.update
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: seen.__setitem__(k, v))
+    _runtime.enable_compile_cache()
+    monkeypatch.undo()
+    assert seen["jax_compilation_cache_include_metadata_in_key"] is True
+    limit = "jax_traceback_in_locations_limit"
+    before = jax.config.jax_traceback_in_locations_limit
+    x = jnp.arange(8.0)
+    try:
+        update(limit, seen[limit])
+        one = _lower_here(_scoped("part.one"), x)
+        assert "part.one" in one and __file__ not in one
+        assert _lower_there(_scoped("part.one"), x) == one
+        assert _lower_here(_scoped("part.two"), x) != one
+    finally:
+        update(limit, before)
+    assert _lower_there(_scoped("part.one"), x) \
+        != _lower_here(_scoped("part.one"), x)       # the default: frames
