@@ -218,6 +218,9 @@ class Dataflow:
     ppermutes: list[SeedSite]          # fact == REPL_PUSHED sites
     pallas_locks: list[SeedSite]       # detected lock_arbitrate calls
     perms: list[PermRec] = dataclasses.field(default_factory=list)
+    # the vars that ARE persistent state (phase-1 STATE provenance): a
+    # gather out of one is a table read, out of anything else it is not
+    state_vars: frozenset = frozenset()
 
     def seeded(self, fact: str) -> list[SeedSite]:
         return [s for s in self.seeds if s.fact == fact]
@@ -338,7 +341,9 @@ class _Analyzer:
             scatters=list(self._scatters.values()),
             ppermutes=list(self._ppermutes.values()),
             pallas_locks=list(self._pallas.values()),
-            perms=list(self._perms.values()))
+            perms=list(self._perms.values()),
+            state_vars=frozenset(v for v, fs in self.prov.items()
+                                 if STATE in fs))
 
     def _phase(self, jaxpr, protocol: bool, top_facts):
         self.protocol_phase = protocol

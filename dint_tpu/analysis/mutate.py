@@ -443,11 +443,16 @@ def _find_axis_swap(trace, flow):
 
 
 def _find_widen_gather(trace, flow):
-    """Double the leading output dim of the largest gather (the table-row
-    read that dominates its wave's byte ledger)."""
+    """Double the leading output dim of the largest gather out of
+    persistent state (the table-row read that dominates its wave's byte
+    ledger; a gather out of a temporary, as a chunk of the compacted
+    install makes of its 2w-wide operands, is no table read and the
+    ledger does not price it)."""
     best = None
     for addr, jaxpr, i, eqn, in_pl in walk_addressed(trace.jaxpr):
         if in_pl or eqn.primitive.name != "gather" or not eqn.outvars:
+            continue
+        if eqn.invars[0] not in flow.state_vars:
             continue
         aval = eqn.outvars[0].aval
         shape = tuple(getattr(aval, "shape", ()))

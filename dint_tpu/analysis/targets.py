@@ -1016,15 +1016,18 @@ _DSB_GEOM = dict(w=_W, l=3, vw=2, d=_MESH_SHARDS)
 #
 # The XLA-route dintcache variants serve every partitioned table wave as
 # TWO masked full-width passes (hot partition + cold partition): logical
-# lanes stay w, but the static walker sees both gathers/scatters. The
+# lanes stay w, but the static walker sees both gathers/scatters (the
+# hot install is not compacted: no chunk gathers in its formula). The
 # VMEM-kernel hot variants (@hot+pallas, @fused+hot) do NOT double — one
 # kernel serves both partitions per wave.
 _HOT2_TD = {"dint.tatp_dense.meta_gather": 2.0,
             "dint.tatp_dense.magic_gather": 2.0,
-            "dint.tatp_dense.install": 2.0}
+            "dint.tatp_dense.install": "2*2*w*(4 + 4*vw)"}
 _HOT2_SB = {"dint.smallbank_dense.read": 2.0,
             "dint.smallbank_dense.lock": 2.0,
             "dint.smallbank_dense.install": 2.0}
+# ... and its install is that one kernel's, not the compacted one.
+_HOTPL_TD = {"dint.tatp_dense.install": "2*w*(4 + 4*vw)"}
 # The monitored pallas route adds the pre-kernel held-stamp read: one
 # extra full arb pass before lock_arbitrate (4 passes, not 3).
 _MONPL_TD = {"dint.tatp_dense.lock": "4*2*w*4"}
@@ -1077,6 +1080,7 @@ _MH_EXPECT = {"dint.tatp_dense.log_append": "2*w*(20 + 4*vw)"}
 # the scan_requests/scan_rows/scan_delta_hits rows widen the device
 # Counters leaf by 12 B per device (3 x u32), +12 B single-chip, +12*d
 # on the sharded/mesh targets — a fleet-wide recalibration, not a leak.
+# PR 30's install_chunks row: +4 B per device in the same way.
 def _cost(geom, dispatches, footprint, *, steps=float(_BLK),
           bytes_budget="1.25*ledger", wave_expect=None):
     return dict(steps=float(steps), geom=dict(geom),
@@ -1087,48 +1091,56 @@ def _cost(geom, dispatches, footprint, *, steps=float(_BLK),
 
 TARGET_COST.update({
     # dense TATP — the fused ladder the round-12 claim rides: 9 (XLA)
-    # -> 7 (@pallas) -> 4 (@fused) dispatches/step, bytes flat
-    "tatp_dense/block": _cost(_TD_GEOM, 9, 216844),
-    "tatp_dense/block@pallas": _cost(_TD_GEOM, 7, 216844),
-    "tatp_dense/block@mon": _cost(_TD_GEOM, 11, 216992),
-    "tatp_dense/block@mon+pallas": _cost(_TD_GEOM, 10, 216992,
+    # -> 7 (@pallas) -> 4 (@fused) dispatches/step, bytes flat. PR 30's
+    # write-set compaction (ops/compact.py) adds 3 to every unfused XLA
+    # install + log: a chunk's gathers of row ids, meta words and ring
+    # slots out of the 2w-wide operands (the value and entry rows' gathers
+    # read temporaries, which the walker does not price); the hot tier's
+    # install is its own, so only its log's gather is new (+1). One trip
+    # of a chunk loop is priced, at a geometry where a chunk is all 2w
+    # slots: the scatters' bytes are the parent's
+    "tatp_dense/block": _cost(_TD_GEOM, 12, 216844),
+    "tatp_dense/block@pallas": _cost(_TD_GEOM, 10, 216844),
+    "tatp_dense/block@mon": _cost(_TD_GEOM, 14, 216996),
+    "tatp_dense/block@mon+pallas": _cost(_TD_GEOM, 13, 216996,
                                          wave_expect=_MONPL_TD),
-    "tatp_dense/drain": _cost(_TD_GEOM, 9, 216836),
-    "tatp_dense/block@hot": _cost(_TD_GEOM, 13, 216864,
+    "tatp_dense/drain": _cost(_TD_GEOM, 12, 216836),
+    "tatp_dense/block@hot": _cost(_TD_GEOM, 14, 216864,
                                   wave_expect=_HOT2_TD),
-    "tatp_dense/block@hot+pallas": _cost(_TD_GEOM, 7, 216864),
+    "tatp_dense/block@hot+pallas": _cost(_TD_GEOM, 8, 216864,
+                                         wave_expect=_HOTPL_TD),
     # dintserve serve-mode blocks: dispatches/step identical to the
     # closed-loop rows above (the occupancy mask fuses into the gen
     # wave), footprint +16 B (@mon +28 B) for the occ/shed step inputs
-    "tatp_dense/serve": _cost(_TD_GEOM, 9, 216860),
-    "tatp_dense/serve@mon": _cost(_TD_GEOM, 11, 217008),
+    "tatp_dense/serve": _cost(_TD_GEOM, 12, 216860),
+    "tatp_dense/serve@mon": _cost(_TD_GEOM, 14, 217012),
     "tatp_dense/block@fused": _cost(_TD_GEOM, 4, 216844),
     "tatp_dense/block@fused+hot": _cost(_TD_GEOM, 5, 216864,
                                         wave_expect=_TD_FUSED_HOT),
-    "tatp_dense/block@fused+mon": _cost(_TD_GEOM, 7, 216992),
+    "tatp_dense/block@fused+mon": _cost(_TD_GEOM, 7, 216996),
     # dense SmallBank: 8 -> 5 dispatches/step under the megakernels
     "smallbank_dense/block": _cost(_SB_GEOM, 8, 150984),
     "smallbank_dense/block@pallas": _cost(_SB_GEOM, 8, 150984),
-    "smallbank_dense/block@mon": _cost(_SB_GEOM, 10, 151132),
+    "smallbank_dense/block@mon": _cost(_SB_GEOM, 10, 151136),
     "smallbank_dense/block@hot": _cost(_SB_GEOM, 14, 151032,
                                        wave_expect=_HOT2_SB),
     "smallbank_dense/block@hot+pallas": _cost(_SB_GEOM, 10, 151032),
-    "smallbank_dense/block@hot+mon": _cost(_SB_GEOM, 16, 151180,
+    "smallbank_dense/block@hot+mon": _cost(_SB_GEOM, 16, 151184,
                                            wave_expect=_HOT2_SB),
     "smallbank_dense/serve": _cost(_SB_GEOM, 8, 151000),
-    "smallbank_dense/serve@mon": _cost(_SB_GEOM, 10, 151148),
+    "smallbank_dense/serve@mon": _cost(_SB_GEOM, 10, 151152),
     "smallbank_dense/block@fused": _cost(_SB_GEOM, 5, 150984),
     "smallbank_dense/block@fused+hot": _cost(_SB_GEOM, 7, 151032),
-    "smallbank_dense/block@fused+mon": _cost(_SB_GEOM, 7, 151132),
+    "smallbank_dense/block@fused+mon": _cost(_SB_GEOM, 7, 151136),
     # generic pipelines: sort-bound, no formula-backed waves -> absolute
     # bytes ceilings instead of a ledger multiple
     "tatp_pipeline/block": _cost(_TD_GEOM, 50, 1610736022,
                                  bytes_budget=256000),
-    "tatp_pipeline/block@mon": _cost(_TD_GEOM, 51, 1610736170,
+    "tatp_pipeline/block@mon": _cost(_TD_GEOM, 51, 1610736174,
                                      bytes_budget=256000),
     "smallbank_pipeline/block": _cost(_SB_GEOM, 36, 1207967480,
                                       bytes_budget=72000),
-    "smallbank_pipeline/block@mon": _cost(_SB_GEOM, 37, 1207967628,
+    "smallbank_pipeline/block@mon": _cost(_SB_GEOM, 37, 1207967632,
                                           bytes_budget=72000),
     # generic replicated shard step: one engine step per trace
     "sharded/tatp": _cost(_DS_GEOM, 62, 4295279296, steps=1.0,
@@ -1136,25 +1148,25 @@ TARGET_COST.update({
     "sharded/smallbank": _cost(_DSB_GEOM, 30, 3221242768, steps=1.0,
                                bytes_budget=4000),
     # dense multi-chip TATP: 33 -> 28 dispatches/step fused
-    "dense_sharded/block": _cost(_DS_GEOM, 33, 459240,
+    "dense_sharded/block": _cost(_DS_GEOM, 36, 459240,
                                  wave_expect=_DS_EXPECT),
-    "dense_sharded/block@pallas": _cost(_DS_GEOM, 31, 459240,
+    "dense_sharded/block@pallas": _cost(_DS_GEOM, 34, 459240,
                                         wave_expect=_DS_EXPECT),
-    "dense_sharded/block@mon": _cost(_DS_GEOM, 37, 459832,
+    "dense_sharded/block@mon": _cost(_DS_GEOM, 40, 459848,
                                      wave_expect=_DS_EXPECT),
     "dense_sharded/block@fused": _cost(_DS_GEOM, 28, 459240,
                                        wave_expect=_DS_EXPECT_FUSED),
-    "dense_sharded/block@fused+mon": _cost(_DS_GEOM, 33, 459832,
+    "dense_sharded/block@fused+mon": _cost(_DS_GEOM, 33, 459848,
                                            wave_expect=_DS_EXPECT_FUSED),
     # dense multi-chip SmallBank: 33 -> 30 dispatches/step fused
     "dense_sharded_sb/block": _cost(_DSB_GEOM, 33, 100676560),
-    "dense_sharded_sb/block@mon": _cost(_DSB_GEOM, 37, 100677152),
+    "dense_sharded_sb/block@mon": _cost(_DSB_GEOM, 37, 100677168),
     "dense_sharded_sb/block@hot": _cost(_DSB_GEOM, 39, 100676848,
                                         wave_expect=_DSB_HOT),
     "dense_sharded_sb/block@fused": _cost(_DSB_GEOM, 30, 100676560),
     "dense_sharded_sb/block@fused+hot": _cost(
         _DSB_GEOM, 32, 100676848, wave_expect=_DSB_FUSED_HOT),
-    "dense_sharded_sb/block@fused+mon": _cost(_DSB_GEOM, 34, 100677152),
+    "dense_sharded_sb/block@fused+mon": _cost(_DSB_GEOM, 34, 100677168),
     # 2-D (dcn x ici) SmallBank: the hierarchical route pays +9
     # dispatches/step (each exchange runs ici + dcn stages) to move
     # strictly fewer DCN-axis link bytes than its flat twin — the
@@ -1163,7 +1175,7 @@ TARGET_COST.update({
     "multihost_sb/block": _cost(_MHSB_GEOM, 42, 201353056),
     "multihost_sb/block@flat": _cost(_MHSB_GEOM, 33, 201353056,
                                      wave_expect=_MHSB_FLAT),
-    "multihost_sb/block@mon": _cost(_MHSB_GEOM, 46, 201354240),
+    "multihost_sb/block@mon": _cost(_MHSB_GEOM, 46, 201354272),
     "multihost_sb/block@h3": _cost(_MHSB_GEOM_H3, 42, 151014808),
     "multihost_sb/block@h3+flat": _cost(_MHSB_GEOM_H3, 33, 151014808,
                                         wave_expect=_MHSB_FLAT),
@@ -1176,20 +1188,20 @@ TARGET_COST.update({
     "multihost_sb/serve": _cost(_MHSB_GEOM, 42, 201353184),
     "multihost_sb/serve@flat": _cost(_MHSB_GEOM, 33, 201353184,
                                      wave_expect=_MHSB_FLAT),
-    "multihost_sb/serve@mon": _cost(_MHSB_GEOM, 47, 201354368),
+    "multihost_sb/serve@mon": _cost(_MHSB_GEOM, 47, 201354400),
     "multihost_sb/serve@overlap": _cost(_MHSB_GEOM, 44, 201359424),
-    "multihost_sb/serve@overlap+mon": _cost(_MHSB_GEOM, 50, 201360608),
+    "multihost_sb/serve@overlap+mon": _cost(_MHSB_GEOM, 50, 201360640),
     # 2-D TATP (parallel/multihost.py, flat tuple-axis collectives):
     # replication traffic pre-dates wave scoping -> absolute bytes
     # ceiling like the pipeline targets, not a ledger multiple
-    "multihost/block": _cost(dict(w=_W, k=4, vw=_VW, d=8, h=4), 33,
+    "multihost/block": _cost(dict(w=_W, k=4, vw=_VW, d=8, h=4), 36,
                              918424, bytes_budget=11000,
                              wave_expect=_MH_EXPECT),
     # dinttrace flight-recorder variants: the ring scatter-add adds one
     # dispatch per step plus the txn-id route fields (per-family "trace"
     # wave rows in monitor/waves.py price the 16 B x candidate-lane
     # update operand); footprint grows by the per-device ring buffers
-    "tatp_dense/block@trace": _cost(_TD_GEOM, 10, 221968),
+    "tatp_dense/block@trace": _cost(_TD_GEOM, 13, 221968),
     "smallbank_dense/block@trace": _cost(_SB_GEOM, 9, 154572),
     "dense_sharded_sb/block@trace": _cost(_DSB_GEOM, 38, 100735968,
                                           wave_expect=_DSB_TRACE),
@@ -1330,7 +1342,7 @@ TARGET_COST.update({
     "store/block@scan+pallas": _cost(_ST_GEOM, 32.5, 4077,
                                      bytes_budget=11700),
     "store/serve@scan": _cost(_ST_GEOM, 35.5, 4093, bytes_budget=11700),
-    "store/serve@scan+mon": _cost(_ST_GEOM, 36.5, 4241,
+    "store/serve@scan+mon": _cost(_ST_GEOM, 36.5, 4245,
                                   bytes_budget=11750),
     "store/rebuild@scan": _cost(_ST_GEOM, 5, 6122, steps=1.0,
                                 bytes_budget=1950),

@@ -76,7 +76,12 @@ row-major on axis 0 with ``unique_indices=True`` and masked lanes routed
 OUT OF BOUNDS under ``mode="drop"`` — duplicate-index and multi-dim-index
 scatters serialize, and uniqueness is guaranteed by certification (one
 X-lock holder per row). Row N is a never-written sentinel that NOP lanes
-gather from; OOB gather indices clip onto it.
+gather from; OOB gather indices clip onto it. A dropped index costs what a
+live one costs (PR 28's trace: the value scatter took 13.31 ms with no live
+slot and 13.33 with ~1,430; 81 ns a value word, 87 a meta word, 132 a log
+row, landed or dropped), so a mask is compacted before it reaches a
+scatter: the install and the log append issue the live write slots, C lanes
+a chunk (ops/compact.py), not all 2w of which TATP's mix leaves 9 % live.
 
 The 3-stage software pipeline (wave 1 of cohort t + validate of t-1 +
 commit of t-2 fused into ONE device program) is inherited from
@@ -103,6 +108,7 @@ from ._memo import memoize_builder
 from ..monitor import counters as mon
 from ..monitor import txnevents as txe
 from ..monitor import waves
+from ..ops import compact
 from ..ops import pallas_gather as pg
 from ..tables import log as logring
 from . import tatp
@@ -513,6 +519,14 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
             log_tbl = c2.ws_tbl.reshape(-1)
             log_key = c2.ws_key.reshape(-1).astype(U32)
             zero_hi = jnp.zeros_like(log_key)
+        if not use_fused:
+            # the 2w slots are ~9 % live (TATP writes 0.22 slots a txn) and
+            # a dropped index costs what a live one costs: the slots are
+            # ranked once, for the scatters of this step (meta and val
+            # here, the log's below), which issue the live ones C lanes a
+            # chunk, as many chunks as this step's live count needs
+            with waves.part("tatp_dense", "ws_compact"):
+                ranks, n_live = compact.live_ranks(wmask)
         if use_fused:
             # install_log megakernel: the val + meta installs, the
             # replicated log append, and (hotset) the mirror write-through
@@ -555,23 +569,36 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                                           wmask, newval.reshape(-1),
                                           val_words, use_pallas=use_pallas)
         else:
-            with waves.part("tatp_dense", "meta_scatter"):
-                meta = db.meta.at[wrows].set(meta_new, mode="drop",
-                                             unique_indices=True)
-            # interleaved-1-D install: row r's words live at
-            # [r*VW, (r+1)*VW); the masked-lane oob row lands at
-            # n1*VW >= len and drops (same discipline as
-            # parallel/dense_sharded._apply_backup)
-            with waves.part("tatp_dense", "val_scatter"):
-                wflat = (wrows[:, None] * val_words
-                         + jnp.arange(val_words, dtype=I32)).reshape(-1)
-                val = db.val.at[wflat].set(newval.reshape(-1), mode="drop",
-                                           unique_indices=True)
+            with waves.part("tatp_dense", "ws_compact"):
+                def install_chunk(tabs, lanes, ok):
+                    meta, val = tabs
+                    rows_c = jnp.where(ok, wrows[lanes], oob)
+                    meta_c, val_c = meta_new[lanes], newval[lanes]
+                    with waves.part("tatp_dense", "meta_scatter"):
+                        meta = meta.at[rows_c].set(meta_c, mode="drop",
+                                                   unique_indices=True)
+                    # interleaved-1-D install: row r's words live at
+                    # [r*VW, (r+1)*VW); the masked-lane oob row lands at
+                    # n1*VW >= len and drops (same discipline as
+                    # parallel/dense_sharded._apply_backup)
+                    with waves.part("tatp_dense", "val_scatter"):
+                        wflat = (rows_c[:, None] * val_words
+                                 + jnp.arange(val_words, dtype=I32)
+                                 ).reshape(-1)
+                        val = val.at[wflat].set(val_c.reshape(-1),
+                                                mode="drop",
+                                                unique_indices=True)
+                    return meta, val
+
+                (meta, val), chunks = compact.for_chunks(
+                    ranks, n_live, compact.chunk_lanes(2 * w), install_chunk,
+                    (db.meta, db.val))
 
     if not use_fused:
         with waves.scope("tatp_dense", "log_append"):
-            logs = logring.append_rep(db.log, wmask, log_tbl, flags_del,
-                                      zero_hi, log_key, newver, newval)
+            logs = logring.append_rep_live(
+                db.log, ranks, n_live, wmask, log_tbl, flags_del, zero_hi,
+                log_key, newver, newval)
 
     # ---- wave 1: new cohort read + lock -----------------------------------
     if gen_new:
@@ -836,6 +863,8 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                 (mon.CTR_DISPATCH_PALLAS if use_pallas
                  else mon.CTR_DISPATCH_XLA): 1,
                 **({mon.CTR_FUSED_DISPATCH: 1} if use_fused else {}),
+                **({} if use_fused or use_hotset
+                   else {mon.CTR_INSTALL_CHUNKS: chunks}),
             })
             counters = mon.gauge_max(
                 counters, {mon.CTR_RING_HWM: logs.head.max()})

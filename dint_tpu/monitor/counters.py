@@ -156,6 +156,13 @@ _REGISTRY: tuple[tuple[str, str, str], ...] = (
      "overlay rather than the sorted run (scan_delta_hits <= scan_rows; "
      "0 in the step right after a drain-boundary rebuild — the overlay "
      "freshness diagnostic)"),
+    ("install_chunks", FLOW,
+     "write-set compaction (ops/compact.py): chunk trips of the dense "
+     "TATP install loop, C = chunk_lanes(2w) lanes a trip. "
+     "install_chunks == sum over steps of ceil(install_writes / C): one a "
+     "step under TATP's mix, none for a step with nothing to write; "
+     "install_writes / (C x install_chunks) is the fill share of the "
+     "indices the scatters issue. 0 on the fused and hot-tier routes"),
 )
 
 ALL_NAMES: tuple[str, ...] = tuple(n for n, _, _ in _REGISTRY)
@@ -203,6 +210,7 @@ CTR_ROUTE_PREFETCH_LANES = COUNTER_INDEX["route_prefetch_lanes"]
 CTR_SCAN_REQUESTS = COUNTER_INDEX["scan_requests"]
 CTR_SCAN_ROWS = COUNTER_INDEX["scan_rows"]
 CTR_SCAN_DELTA_HITS = COUNTER_INDEX["scan_delta_hits"]
+CTR_INSTALL_CHUNKS = COUNTER_INDEX["install_chunks"]
 
 # the subset defined with IDENTICAL semantics by the dense engines and
 # the generic sort-based pipelines: on the parity workloads

@@ -59,9 +59,13 @@ _REGISTRY: tuple[tuple[str, str, str, str | None], ...] = (
      "compute-only", None),
     ("tatp_dense", "install",
      "wave-3 install: meta + interleaved-val scatters of cohort t-2's "
-     "certified writes (2w write slots)", "2*w*(4 + 4*vw)"),
+     "certified writes, the live ones of the 2w write slots, C lanes a "
+     "chunk (ops/compact.py). Priced at all 2w slots live, which is what "
+     "a write-only mix issues, plus a chunk's gathers of row ids and "
+     "meta words", "2*w*(4 + 4*vw) + 2*w*8"),
     ("tatp_dense", "log_append",
-     "log x3 append of cohort t-2's installs (RepLog packed entries)",
+     "log x3 append of cohort t-2's installs (RepLog packed entries; the "
+     "live ones, C lanes a chunk, priced at all 2w)",
      "2*w*3*(20 + 4*vw)"),
     ("tatp_dense", "meta_gather",
      "fused meta gather serving c1's validate re-read AND the new "
@@ -366,10 +370,12 @@ _PARTS: tuple[tuple[str, str | None, str, str], ...] = (
     ("tatp_dense", "install", "install_build",
      "masks, new meta words, payload draw and the [2w, VW] new rows"),
     ("tatp_dense", "install", "meta_scatter",
-     "unique-index scatter of 2w meta words"),
+     "unique-index scatter of the live slots' meta words, C lanes a "
+     "chunk"),
     ("tatp_dense", "install", "val_scatter",
-     "unique-index scatter of 2w x VW single value words into the 1-D "
-     "val array, with its flat index (val_scatter_ms.* reads this)"),
+     "unique-index scatter of the live slots' C x VW single value words "
+     "a chunk into the 1-D val array, with its flat index "
+     "(val_scatter_ms.* reads this)"),
     ("tatp_dense", "lock", "lock_read",
      "gather of the 2w write slots' arb stamps + the held compare"),
     ("tatp_dense", "lock", "lock_scatter_max",
@@ -400,7 +406,14 @@ _PARTS: tuple[tuple[str, str | None, str, str], ...] = (
     ("log", "log_append", "log_plan",
      "lane / rank / slot plan and the replica-packed entry rows"),
     ("log", "log_append", "log_scatter",
-     "unique-index row scatter into the rings + the head advance"),
+     "unique-index row scatter of the live entries into the rings, C "
+     "lanes a chunk (append_rep: all R at once) + the head advance"),
+    # --- write-set compaction (ops/compact.py), appended in PR 30 -------
+    ("tatp_dense", "install", "ws_compact",
+     "the running count of live write slots (one 2w-element cumsum), "
+     "the chunk loop of the install, each chunk's lane search (C x 2w "
+     "compares) and its gathers of row ids, meta words and value rows "
+     "out of the 2w-wide operands"),
 )
 
 # keyed on the part's name alone: the scope is `part.<name>`, so two

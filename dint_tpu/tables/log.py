@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..monitor import waves
+from ..ops import compact
 
 I32 = jnp.int32
 U32 = jnp.uint32
@@ -184,6 +185,31 @@ def append_rep(ring: RepLog, do_append, table_id, is_del, key_hi, key_lo,
         widx = jnp.where(flat >= 0, flat, lanes * cap)
         new_entries = ring.entries.at[widx].set(entry3, mode="drop",
                                                 unique_indices=True)
+        return ring.replace(entries=new_entries,
+                            head=ring.head + lane_counts)
+
+
+def append_rep_live(ring: RepLog, ranks, n_live, do_append, table_id, is_del,
+                    key_hi, key_lo, ver, val) -> RepLog:
+    """`append_rep` for a caller that has compacted ``do_append``
+    (``ranks, n_live = compact.live_ranks(do_append)``): the plan is made at
+    full width, so lane, rank and slot, and with them the rings' bytes,
+    are `append_rep`'s; the row scatter issues the live entries only, C
+    lanes a chunk (ops/compact.py)."""
+    with waves.part("log", "log_plan"):
+        flat, entry3, lane_counts = plan_rep(ring, do_append, table_id,
+                                             is_del, key_hi, key_lo, ver,
+                                             val)
+    with waves.part("log", "log_scatter"):
+        oob = ring.lanes * ring.capacity
+
+        def scatter(entries, lanes, ok):
+            return entries.at[jnp.where(ok, flat[lanes], oob)].set(
+                entry3[lanes], mode="drop", unique_indices=True)
+
+        new_entries, _ = compact.for_chunks(
+            ranks, n_live, compact.chunk_lanes(do_append.shape[0]), scatter,
+            ring.entries)
         return ring.replace(entries=new_entries,
                             head=ring.head + lane_counts)
 

@@ -20,6 +20,7 @@ The topology is described inside a module-scoped fixture (never at
 import, never in conftest.py: only one process may hold the TPU library,
 and every xdist worker imports every test file)."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +30,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from dint_tpu.engines import tatp_dense as td
+from dint_tpu.ops import compact
 from dint_tpu.ops import pallas_gather as pg
 from dint_tpu.parallel import dense_sharded as ds
 from dint_tpu.tables import log as logring
@@ -85,6 +87,10 @@ def placed(tree, sharding):
         tree)
 
 
+def _s(shape, dt=U32):
+    return jax.ShapeDtypeStruct(shape, dt)
+
+
 def compiled_bytes(jitted, *args):
     c = jitted.lower(*args).compile()
     return c, c.memory_analysis()
@@ -109,6 +115,19 @@ def test_tatp7m_populate_fits_one_chip(one_chip):
     assert ma.output_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
 
 
+def scatter_index_counts(hlo: str, table_words: int) -> list:
+    """How many indices each native scatter into a u32[table_words] table
+    issues (its index operand's element count), from compiled HLO text."""
+    shape_of = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", hlo))
+    counts = []
+    for idx in re.findall(
+            rf"u32\[{table_words}\]\S* scatter\(%[\w.\-]+, %([\w.\-]+),",
+            hlo):
+        counts.append(int(np.prod([int(d) for d in
+                                   shape_of[idx].split(",") if d])))
+    return counts
+
+
 def test_tatp7m_block_program_fits_one_chip(one_chip):
     run, init, drain = _runner()
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
@@ -116,20 +135,52 @@ def test_tatp7m_block_program_fits_one_chip(one_chip):
         lambda k: init(td.populate_device(k, N_SUB, val_words=VW)), key)
     carry, key = placed(carry, one_chip), placed(key, one_chip)
     for fn, args in ((run, (carry, key)), (drain, (carry,))):
-        _, ma = compiled_bytes(fn, *args)
+        c, ma = compiled_bytes(fn, *args)
         assert ma.argument_size_in_bytes > 7e9    # the real tables
         assert ma.argument_size_in_bytes + ma.temp_size_in_bytes \
             < HBM_BYTES
-        # the carry is donated: the tables update in place
+        # the carry is donated: the tables update in place, through the
+        # chunk loops of the compacted install as well
         assert ma.alias_size_in_bytes > 0.99 * ma.argument_size_in_bytes
         assert ma.temp_size_in_bytes < 1e9
+        # the install issues a chunk of the live write slots, not all 2w
+        # (ops/compact.py): C x VW value words, C meta words
+        chunk = compact.chunk_lanes(2 * W)
+        assert chunk == 512
+        hlo = c.as_text()
+        assert " sort(" not in hlo      # no sort: the proofs read one
+        assert set(scatter_index_counts(hlo, N1 * VW)) == {chunk * VW}
+        # meta's install; the lock wave's scatter-max over arb, the same
+        # shape, still issues all 2w (ROADMAP Queue 1)
+        assert sorted(set(scatter_index_counts(hlo, N1))) == [chunk, 2 * W]
+
+
+def test_windowed_row_scatter_is_expanded_to_a_loop_on_v5e(one_chip):
+    """"Scatter rows, not words" (PERF.md §7 before PR 30) does not
+    compile to what it hopes: a window of VW words into the 1-D val array
+    is no native scatter on v5e, the compiler expands it into a `while`
+    of one dynamic-update-slice per index (16,384 trips on the 6.16 GB
+    array). This case fails, and reopens the row form, the day the
+    compiler keeps it native."""
+    dn = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1,), inserted_window_dims=(),
+        scatter_dims_to_operand_dims=(0,))
+
+    def rows(val, start, new):
+        return jax.lax.scatter(val, start[:, None], new, dn,
+                               unique_indices=True, mode="drop")
+
+    c, _ = compiled_bytes(
+        jax.jit(rows, donate_argnums=0),
+        *placed((_s((N1 * VW,)), _s((2 * W,), I32), _s((2 * W, VW))),
+                one_chip))
+    hlo = c.as_text()
+    assert " scatter(" not in hlo
+    assert " while(" in hlo and "dynamic-update-slice(" in hlo
+    assert f"constant({2 * W})" in hlo            # the loop's trip count
 
 
 # ------------------------------------------------- the Pallas kernels
-
-
-def _s(shape, dt=U32):
-    return jax.ShapeDtypeStruct(shape, dt)
 
 
 # (id, fn, argument shapes) at the geometry the TATP-7M builders pass;

@@ -136,6 +136,38 @@ def test_tatp_dense_reconciles_with_stats():
     assert snap["repl_push_hop1"] == 0      # single chip: no ICI pushes
 
 
+def test_install_chunks_reconcile_with_install_writes_step_by_step():
+    """Write-set compaction (ops/compact.py): a step's install loop makes
+    ceil(install_writes / C) trips. One cohort a block, so a window's
+    delta is one step's; an update-only mix, so that a step can write
+    more than one chunk holds (2w = 512 slots, C = 128)."""
+    from dint_tpu.engines import tatp_dense as td
+    from dint_tpu.ops import compact
+
+    w, chunk = 256, compact.chunk_lanes(512)
+    assert chunk == 128
+    mix = np.array([0, 0, 0, 50, 50, 0, 0], np.float64) / 100.0
+    run, init, drain = td.build_pipelined_runner(
+        N_SUB * 8, w=w, val_words=VW, cohorts_per_block=1, mix=mix,
+        monitor=True)
+    carry = init(td.populate(np.random.default_rng(0), N_SUB * 8,
+                             val_words=VW))
+    prev, per_step = None, []
+    for i in range(6):
+        carry, _ = run(carry, jax.random.fold_in(KEY(0), i))
+        snap = M.snapshot(carry[-1])
+        d = mc.delta(snap, prev)
+        prev = snap
+        assert d["steps"] == 1
+        assert d["install_chunks"] == -(-d["install_writes"] // chunk)
+        per_step.append(d["install_chunks"])
+    assert per_step[:2] == [0, 0]       # an empty c2: nothing to install
+    assert max(per_step) >= 2           # more than one chunk's worth
+    d = mc.delta(M.snapshot(drain(carry)[2]), prev)     # two steps
+    low = -(-d["install_writes"] // chunk)
+    assert d["steps"] == 2 and low <= d["install_chunks"] <= low + 1
+
+
 def test_tatp_dense_monitoring_off_is_bit_identical():
     db_off, tot_off, _ = _run_tatp_dense(False)
     db_on, tot_on, _ = _run_tatp_dense(True)
@@ -267,9 +299,11 @@ def test_fused_dispatch_counter_reconciles():
     assert fus_t["fused_dispatch"] == fus_t["steps"] == steps_t
     assert fus_t["dispatch_xla"] == steps_t  # the split stays total
     assert fus_t["dispatch_pallas"] == 0
-    drop = ("fused_dispatch",)
+    # install_chunks: the megakernel route runs no chunk loop
+    drop = ("fused_dispatch", "install_chunks")
     assert {k: v for k, v in base_t.items() if k not in drop} == \
         {k: v for k, v in fus_t.items() if k not in drop}
+    assert fus_t["install_chunks"] == 0 < base_t["install_chunks"]
 
     _, tot_s, base_s = _run_sb_dense(True, blocks=blocks)
     _, tot_sf, fus_s = _run_sb_dense(True, blocks=blocks,

@@ -1,9 +1,15 @@
 """Sort-free dense TATP engine: semantics vs the generic pipelined engine."""
+import functools
+
 import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from dint_tpu.clients import tatp_client as tc
 from dint_tpu.engines import tatp, tatp_dense as td, tatp_pipeline as tp
+from dint_tpu.monitor import counters as mon
+from dint_tpu.ops import compact
 from dint_tpu.tables import log as logring
 
 VW = 4
@@ -260,3 +266,158 @@ def test_matches_generic_pipelined_engine_at_low_contention():
         got = ver_d[base[tid]:base[tid] + n]
         want = np.asarray(t.ver)[0]
         assert np.array_equal(got, want), tid
+
+
+# ---------------------------------------------------- write-set compaction
+# (ops/compact.py, PR 30): the install and the log append issue the live
+# write slots only, C lanes a chunk. The reference below is the parent's
+# form: one full-width masked scatter each, all 2w slots at once.
+
+UPDATES_ONLY = np.array([0, 0, 0, 50, 50, 0, 0], np.float64) / 100.0
+
+
+@functools.lru_cache(maxsize=None)
+def _forced_step(w, n_sub):
+    step = functools.partial(td.pipe_step, w=w, n_sub=n_sub, val_words=VW,
+                             gen_new=False, emit_installs=True,
+                             counters=mon.create())
+    return jax.jit(step)
+
+
+def _forced_ctx(rng, w, n_sub, n_live):
+    """A cohort t-2 with exactly n_live live write slots at random places
+    among the 2w (the rest split between inactive slots and dead txns),
+    unique rows, and commits, inserts and deletes among them."""
+    live = np.zeros(2 * w, bool)
+    live[rng.choice(2 * w, n_live, replace=False)] = True
+    live = live.reshape(w, 2)
+    alive = live.any(axis=1) | (rng.random(w) < 0.5)
+    active = live | (~alive[:, None] & (rng.random((w, 2)) < 0.5))
+    rows = rng.choice(td.n_rows(n_sub), 2 * w, replace=False)
+    return td.empty_ctx(w).replace(
+        alive=jnp.asarray(alive), ws_active=jnp.asarray(active),
+        ws_rows=jnp.asarray(rows.reshape(w, 2), jnp.int32),
+        ws_vv=jnp.asarray(rng.integers(0, 1 << 20, (w, 2)), jnp.uint32),
+        ws_tbl=jnp.asarray(rng.integers(0, 5, (w, 2)), jnp.int32),
+        ws_key=jnp.asarray(rng.integers(0, n_sub, (w, 2)), jnp.int32),
+        ws_kind=jnp.asarray(rng.integers(0, 3, (w, 2)), jnp.int32))
+
+
+@pytest.mark.parametrize("w,n_live", [
+    (512, 0), (512, 1), (512, 127), (512, 128), (512, 129), (512, 1024),
+    (100, 150)], ids=lambda v: str(v))
+def test_compacted_install_and_log_equal_full_width_reference(w, n_live):
+    """C = 128 at both widths: 0, 1, C-1, C, C+1 and 2w live slots, and a
+    width that is no multiple of C (the last chunk's positions run past the
+    last lane)."""
+    n_sub, chunk = 2000, compact.chunk_lanes(2 * w)
+    assert chunk == 128
+    rng = np.random.default_rng(n_live)
+    db = td.populate(rng, n_sub, val_words=VW, log_capacity=1 << 10)
+    # heads in mid-ring, so that slots wrap inside the step's appends
+    db = db.replace(log=db.log.replace(head=jnp.asarray(
+        rng.integers(0, 1 << 12, db.log.lanes), jnp.uint32)))
+    c2 = _forced_ctx(rng, w, n_sub, n_live)
+    got, _, _, _, inst, cnt = _forced_step(w, n_sub)(
+        db, td.empty_ctx(w), c2, jax.random.PRNGKey(n_live))
+
+    assert int(inst.wmask.sum()) == n_live
+    assert (np.asarray(inst.is_del)[np.asarray(inst.wmask)] == 1).any() \
+        or n_live < 2
+    oob = td.n_rows(n_sub) + 1
+    wrows = jnp.where(inst.wmask, inst.rows, oob)
+    meta = db.meta.at[wrows].set(inst.meta, mode="drop")
+    wflat = (wrows[:, None] * VW + jnp.arange(VW)).reshape(-1)
+    val = db.val.at[wflat].set(inst.val.reshape(-1), mode="drop")
+    log = logring.append_rep(db.log, inst.wmask, inst.tbl, inst.is_del,
+                             jnp.zeros_like(inst.key), inst.key, inst.ver,
+                             inst.val)
+    assert np.array_equal(np.asarray(got.meta), np.asarray(meta))
+    assert np.array_equal(np.asarray(got.val), np.asarray(val))
+    assert np.array_equal(np.asarray(got.log.entries),
+                          np.asarray(log.entries))
+    assert np.array_equal(np.asarray(got.log.head), np.asarray(log.head))
+    assert int(np.asarray(got.log.head - db.log.head).sum()) == n_live
+    snap = mon.snapshot(cnt)
+    assert snap["install_writes"] == n_live
+    assert snap["install_chunks"] == -(-n_live // chunk)
+
+
+@pytest.mark.parametrize("r,p,chunk", [
+    (1, 1.0, 1), (7, 0.5, 4), (256, 0.0, 128), (256, 0.1, 128),
+    (256, 0.6, 128), (256, 1.0, 128), (200, 0.9, 128)],
+    ids=lambda v: str(v))
+def test_for_chunks_visits_the_live_lanes_in_lane_order(r, p, chunk):
+    mask = np.random.default_rng(r).random(r) < p
+    live = np.nonzero(mask)[0]
+    ranks, n_live = compact.live_ranks(jnp.asarray(mask))
+    assert int(n_live) == len(live)
+
+    def visit(state, lanes, ok):
+        seen, k = state
+        return (jax.lax.dynamic_update_slice(
+            seen, jnp.where(ok, lanes, -1), (k,)), k + chunk)
+
+    room = -(-r // chunk) * chunk
+    (seen, _), trips = compact.for_chunks(
+        ranks, n_live, chunk, visit,
+        (jnp.full(room, -1, jnp.int32), jnp.asarray(0, jnp.int32)))
+    assert int(trips) == -(-len(live) // chunk)
+    assert np.asarray(seen).tolist() == \
+        live.tolist() + [-1] * (room - len(live))
+
+
+def test_chunk_lanes_is_a_rule_in_the_width_alone():
+    assert [compact.chunk_lanes(r) for r in
+            (16, 128, 200, 512, 4096, 8192, 16384)] == \
+        [16, 128, 128, 128, 128, 256, 512]
+
+
+def test_write_heavy_mix_runs_several_chunks_and_matches_generic_engine():
+    """Every transaction an update (two write slots or one): more live
+    slots than a chunk holds, so several chunks run a step; stats and
+    table versions equal the generic engine's, as chip_smoke.compare_small
+    compares them."""
+    n_sub, w, blocks, seed = 2000, 256, 2, 0
+    db = td.populate(np.random.default_rng(seed), n_sub, val_words=VW)
+    run_d, init_d, drain_d = td.build_pipelined_runner(
+        n_sub, w=w, val_words=VW, cohorts_per_block=2, mix=UPDATES_ONLY,
+        monitor=True)
+    shards, _ = tc.populate_shards(np.random.default_rng(seed), n_sub,
+                                   val_words=VW, log_capacity=1 << 14)
+    run_g, init_g, drain_g = tp.build_pipelined_runner(
+        n_sub, w=w, val_words=VW, cohorts_per_block=2, mix=UPDATES_ONLY)
+    carry, carry_g = init_d(db), init_g(tp.stack_shards(shards))
+    key = jax.random.PRNGKey(seed)
+    tot_d = np.zeros(td.N_STATS, np.int64)
+    tot_g = np.zeros(tp.N_STATS, np.int64)
+    for i in range(blocks):
+        carry, s_d = run_d(carry, jax.random.fold_in(key, i))
+        carry_g, s_g = run_g(carry_g, jax.random.fold_in(key, i))
+        tot_d += np.asarray(s_d, np.int64).sum(axis=0)
+        tot_g += np.asarray(s_g, np.int64).sum(axis=0)
+    db, tail_d, cnt = drain_d(carry)
+    stacked, tail_g = drain_g(carry_g)
+    tot_d += np.asarray(tail_d, np.int64).sum(axis=0)
+    tot_g += np.asarray(tail_g, np.int64).sum(axis=0)
+    assert tot_d.tolist() == tot_g.tolist(), (tot_d, tot_g)
+
+    base = td._bases(n_sub + 1)
+    ver_d = np.asarray(db.ver)
+    for tid, t in enumerate((stacked.sub, stacked.sec, stacked.ai,
+                             stacked.sf)):
+        want = np.asarray(t.ver)[0]
+        assert np.array_equal(ver_d[base[tid]:base[tid] + len(want)],
+                              want), tid
+    for r in (1, 2):
+        assert np.array_equal(
+            np.asarray(logring.replica_entries(db.log, r)),
+            np.asarray(logring.replica_entries(db.log, 0)))
+
+    snap = mon.snapshot(cnt)
+    chunk = compact.chunk_lanes(2 * w)
+    writing_steps = blocks * 2            # cohorts complete two steps late
+    assert snap["install_writes"] > writing_steps * chunk
+    assert snap["install_chunks"] >= 2 * writing_steps
+    assert snap["install_chunks"] <= \
+        -(-snap["install_writes"] // chunk) + writing_steps
