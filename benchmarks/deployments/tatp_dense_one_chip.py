@@ -18,10 +18,43 @@ STAT_NAMES = ("attempted", "committed", "ab_lock", "ab_missing",
 assert [td.STAT_ATTEMPTED, td.STAT_COMMITTED, td.STAT_AB_LOCK,
         td.STAT_AB_MISSING, td.STAT_AB_VALIDATE,
         td.STAT_MAGIC_BAD] == list(range(td.N_STATS))
+OUTCOMES = ("committed", "ab_lock", "ab_missing", "ab_validate")
+FAULTS = ("magic_bad",)
+CONTENTION = ("ab_lock", "ab_validate")     # another transaction's doing
+COUNTER_PAIRS = (("txn_attempted", "attempted"),
+                 ("txn_committed", "committed"), ("ab_lock", "ab_lock"),
+                 ("ab_missing", "ab_missing"),
+                 ("ab_validate", "ab_validate"), ("magic_bad", "magic_bad"))
+
+# names verify makes in every phase beyond the harness's own, and names
+# compare_small makes (tests/bench holds every cell to them)
+GUARANTEE_CHECKS = ("lock_ledger_closes", "no_row_left_locked",
+                    "ab_missing_in_analytic_band")
+COMPARE_CHECKS = ("compare.dense_stats_equal_generic_engine",
+                  "compare.recovered_from_replica_2")
+
+
+def check_stats(checks: ck.Checks, tag: str, totals: dict, snap: dict,
+                dispatched: int) -> None:
+    """The accounting any deployment makes, then TATP's own."""
+    ck.check_accounting(checks, tag, totals, snap, dispatched, OUTCOMES,
+                        FAULTS, COUNTER_PAIRS)
+    ck.check_lock_ledger(checks, tag, snap)
+    ck.check_ab_missing_band(checks, tag, totals)
+
+
+def compare_small(config: dict, seed: int, checks: ck.Checks) -> None:
+    """Dense against generic engine and recovery from each ring, at the
+    configuration's ``compare_small`` size (checks.compare_small)."""
+    ck.compare_small(checks, seed, config["compare_small"],
+                     config["sizes"]["val_words"])
 
 
 class OneChip:
     stat_names = STAT_NAMES
+    outcomes = OUTCOMES
+    faults = FAULTS
+    contention = CONTENTION
     depth = 3
     n_devices = 1
 
@@ -83,7 +116,7 @@ class OneChip:
                dispatched: int) -> dict:
         db, _, counters = final
         snap = monitor.snapshot(counters)
-        ck.check_accounting(checks, tag, totals, snap, dispatched)
+        check_stats(checks, tag, totals, snap, dispatched)
         checks.add(f"{tag}.no_row_left_locked",
                    not bool(self._any_locked(db)))
 
@@ -105,7 +138,8 @@ class OneChip:
                    install_writes=snap["install_writes"],
                    log_appends=snap["log_appends"])
         def read_back(ring):
-            plan = ck.plan_readback(ring, heads, self.n_sub, self.vw)
+            plan = ck.plan_readback(ring, heads,
+                                    ck.tatp_table_rows(self.n_sub), self.vw)
             n = len(plan["rows"])
             rows = np.full(self.ring_rows, -1, np.int32)
             rows[:n] = plan["rows"]

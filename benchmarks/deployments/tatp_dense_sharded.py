@@ -12,16 +12,23 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from benchmarks import checks as ck
-from benchmarks.deployments.tatp_dense_one_chip import STAT_NAMES
+from benchmarks.deployments import tatp_dense_one_chip as one
 from dint_tpu import monitor
 from dint_tpu.engines import tatp_dense as td
 from dint_tpu.parallel import dense_sharded as ds
 
 AX = ds.SHARD_AXIS
+# the same engine step, stats and small comparison as on one chip
+GUARANTEE_CHECKS = one.GUARANTEE_CHECKS
+COMPARE_CHECKS = one.COMPARE_CHECKS
+compare_small = one.compare_small
 
 
 class Sharded:
-    stat_names = STAT_NAMES
+    stat_names = one.STAT_NAMES
+    outcomes = one.OUTCOMES
+    faults = one.FAULTS
+    contention = one.CONTENTION
     depth = 3
 
     def __init__(self, sizes: dict, params: dict, seed: int, devices, emit):
@@ -109,7 +116,7 @@ class Sharded:
         state, _, counters = final
         n, vw = self.n_devices, self.vw
         snap = monitor.snapshot(counters)
-        ck.check_accounting(checks, tag, totals, snap, dispatched)
+        one.check_stats(checks, tag, totals, snap, dispatched)
         checks.add(f"{tag}.no_row_left_locked",
                    not bool(self._any_locked(state.db)))
         checks.add(f"{tag}.replication_pushes_equal_installs",
@@ -131,10 +138,11 @@ class Sharded:
         # device d's stream: in its own ring (tag 0) and, tagged d + 1, in
         # the rings of the two devices that hold its backups
         rng = np.random.default_rng(self.seed % (1 << 32))
+        table_rows = ck.tatp_table_rows(self.n_loc)
         sample = primary = None
         for off in range(3):
             plans = [ck.plan_readback(
-                rings[(d + off) % n], heads[(d + off) % n], self.n_loc, vw,
+                rings[(d + off) % n], heads[(d + off) % n], table_rows, vw,
                 key_hi=0 if off == 0 else d + 1) for d in range(n)]
             rows = np.full((n, self.ring_rows), -1, np.int32)
             if off == 0:    # the padding is the sample the backups get

@@ -9,11 +9,29 @@ is an entry of BENCHMARK.json's ``workloads``; everything that belongs to
 it is found by name:
 
     benchmarks/configs/<config>.json       the deployment's sizes
-    benchmarks/deployments/<kind>.py       how that kind is built and checked
+    benchmarks/deployments/<kind>.py       how that kind is built, checked
+                                           and compared (the contract:
+                                           benchmarks/deployments/__init__.py)
     benchmarks/traffic/<traffic>.json      the traffic mix: loop, parameters
     benchmarks/loops/<kind>.py             the loop that offers it
     benchmarks/end_to_end/<metric>.py      one reader per end-to-end metric
     benchmarks/layer_metrics/<metric>.py   one reader per per-layer metric
+
+What is the harness's and what the deployment's. This file knows two
+stat columns, ``attempted`` and ``committed``, and reads one key of a
+configuration, ``deployment``; the rest of the file goes to that module
+untouched. The module brings ``build`` (the object a loop drives, with
+``stat_names`` and, among them, the lawful ``outcomes``, the ``faults``
+and the ``contention`` outcomes; its ``verify`` holds the deployment's
+guarantees after the warm-up and after the window) and ``compare_small``
+(the engine against independent code at a small size, in the traced run).
+The harness computes ``failed = attempted - sum(outcomes) + sum(faults)``,
+hands ``contention`` to the readers in ``ctx``, and adds the checks that
+hold for any deployment: ``window.nothing_compiled`` here, the
+accounting in checks.check_accounting for a ``verify`` to call. A
+deployment of a new engine must bring a ``verify`` that holds its own
+guarantees and a ``compare_small`` against code that shares nothing with
+the engine; nothing here supplies either.
 
 Every line of output is one JSON object. The last is the result the
 driver reads. A run that cannot give one says why in a line
@@ -132,26 +150,22 @@ def peak_bytes(devices) -> list:
             for d in devices]
 
 
-def result_line(cell: str, seed: int, checks, res: dict, metrics: dict,
-                device: dict, peaks: list) -> dict:
+def result_line(cell: str, seed: int, checks, res: dict, dep,
+                metrics: dict, device: dict, peaks: list) -> dict:
     """The last line. ``attempted``: transactions dispatched in the
     window. ``failed``: those without a lawful outcome (the gap in
-    committed + aborts == attempted, plus rows whose magic word was
-    wrong); an abort is an answer the protocol gives, and is a per-layer
-    metric."""
+    sum(``dep.outcomes``) == attempted) plus what the ``dep.faults``
+    columns counted; an abort is an answer the protocol gives, and is a
+    per-layer metric."""
     t = res["totals"]
     peaks = [p for p in peaks if p is not None]
     device["memory_peak_bytes"] = max(peaks) if peaks else None
     return {"correct": checks.ok, "attempted": res["dispatched_txns"],
-            "failed": t["attempted"] - lawful_outcomes(t) + t["magic_bad"],
+            "failed": t["attempted"] - sum(t[n] for n in dep.outcomes)
+            + sum(t[n] for n in dep.faults),
             "metrics": metrics, "device": device,
             "failed_checks": checks.failed, "checks": checks.n,
             "workload": cell, "seed": seed}
-
-
-def lawful_outcomes(t: dict) -> int:
-    return (t["committed"] + t["ab_lock"] + t["ab_missing"]
-            + t["ab_validate"])
 
 
 def _main(argv) -> int:
@@ -187,8 +201,8 @@ def _main(argv) -> int:
     from benchmarks import trace_reduce
     from dint_tpu import _runtime
 
-    build = importlib.import_module(
-        "benchmarks.deployments." + config["deployment"]).build
+    deployment = importlib.import_module(
+        "benchmarks.deployments." + config["deployment"])
     loop = importlib.import_module("benchmarks.loops." + traffic["loop"])
 
     if args.rehearse:
@@ -229,13 +243,12 @@ def _main(argv) -> int:
         # traced run the driver makes per cell and not in every run
         stage("compare_small")
         t = time.perf_counter()
-        ck.compare_small(checks, args.seed, config["compare_small"],
-                         config["sizes"]["val_words"])
+        deployment.compare_small(config, args.seed, checks)
         emit(compare_small_s=time.perf_counter() - t)
 
     stage("populate")
-    dep = build(config, traffic["params"], args.seed, devices, emit,
-                args.rehearse)
+    dep = deployment.build(config, traffic["params"], args.seed, devices,
+                           emit, args.rehearse)
     keys = KeySchedule(args.seed, traffic["keys_per_chunk"])
 
     stage("warmup")
@@ -307,7 +320,8 @@ def _main(argv) -> int:
         if not args.rehearse:
             trace_reduce.require_device_work(reduced, len(devices))
     ctx = {"loop": res, "window_s": window_s, "setup_s": setup_s,
-           "totals": res["totals"], "counters": snap, "trace": reduced,
+           "totals": res["totals"], "contention": dep.contention,
+           "counters": snap, "trace": reduced,
            "device": device, "geometry": dep.geometry,
            "steps": dep.steps_per_dispatch * res["dispatches"],
            "txns_per_dispatch": dep.txns_per_dispatch,
@@ -322,8 +336,8 @@ def _main(argv) -> int:
                 or m["source"] == "program_counter" else off_device)
         keep[m["name"]] = {"value": float(value), "unit": m["unit"]}
 
-    line = result_line(cell["name"], args.seed, checks, res, metrics, device,
-                       ctx["peak_bytes_in_use"])
+    line = result_line(cell["name"], args.seed, checks, res, dep, metrics,
+                       device, ctx["peak_bytes_in_use"])
     if reduced is not None and not args.rehearse:
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]

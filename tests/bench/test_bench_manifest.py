@@ -108,6 +108,21 @@ def test_every_name_resolves_to_its_file(manifest):
         == os.path.join(BENCH, "layer_metrics", "hbm_peak_gb.x4.py")
 
 
+@pytest.mark.parametrize("kind", sorted(
+    f[:-3] for f in os.listdir(os.path.join(BENCH, "deployments"))
+    if f.endswith(".py") and f != "__init__.py"))
+def test_every_deployment_module_brings_the_whole_contract(kind):
+    """benchmarks/deployments/__init__.py: what run.py and tests/bench
+    take from the module, before any object is built."""
+    import importlib
+    dep = importlib.import_module("benchmarks.deployments." + kind)
+    assert callable(dep.build) and callable(dep.compare_small)
+    for names in (dep.GUARANTEE_CHECKS, dep.COMPARE_CHECKS):
+        assert names and all(isinstance(n, str) and n for n in names)
+    assert all(n.startswith("compare.") for n in dep.COMPARE_CHECKS)
+    assert not any("." in n for n in dep.GUARANTEE_CHECKS)  # no phase tag
+
+
 def test_every_cell_reports_enough_and_moves_point_at_what_it_reports(
         manifest):
     cells = [c["name"] for c in manifest["workloads"]]

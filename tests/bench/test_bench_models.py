@@ -42,6 +42,7 @@ def test_roofline_share_and_unknown_device_kind():
 # ------------------------------------------------------------ read-back
 
 N_SUB, VW, LANES, CAP = 50, 3, 2, 8
+TABLE_ROWS = ck.tatp_table_rows(N_SUB)
 
 
 def _entry(table, key, ver, val, is_del=0, key_hi=0):
@@ -54,7 +55,7 @@ def _ring_and_tables(writes, lanes=LANES, cap=CAP):
     n1 = 22 * (N_SUB + 1) + 1
     meta = np.zeros(n1, np.uint32)
     val = np.zeros((n1, VW), np.uint32)
-    base = ck.table_bases(N_SUB)
+    base = ck.table_bases(TABLE_ROWS)
     ring = np.zeros((lanes, cap, ck.HDR_WORDS + VW), np.uint32)
     heads = np.zeros(lanes, np.uint32)
     for i, (table, key, v, is_del) in enumerate(writes):
@@ -69,7 +70,7 @@ def _ring_and_tables(writes, lanes=LANES, cap=CAP):
 
 
 def _readback(ring, heads, meta, val, **kw):
-    plan = ck.plan_readback(ring, heads, N_SUB, VW, **kw)
+    plan = ck.plan_readback(ring, heads, TABLE_ROWS, VW, **kw)
     return ck.compare_readback(plan, meta[plan["rows"]], val[plan["rows"]])
 
 
@@ -96,7 +97,7 @@ def test_readback_fails_when_one_replicas_entry_is_altered(word, what):
 
 def test_readback_fails_when_the_table_lost_or_changed_a_write():
     ring, heads, meta, val = _ring_and_tables(WRITES)
-    row = ck.table_bases(N_SUB)[2] + 30
+    row = ck.table_bases(TABLE_ROWS)[2] + 30
     val[row, 1] ^= 1                             # same version, other value
     assert _readback(ring, heads, meta, val)["differs"] == 1
     val[row, 1] ^= 1
@@ -109,7 +110,7 @@ def test_a_newer_live_row_is_lawful_only_where_the_ring_wrapped_over_it():
     # unwrapped: a live row newer than its newest entry means an
     # acknowledged write is in no log
     ring, heads, meta, val = _ring_and_tables(WRITES)
-    row = ck.table_bases(N_SUB)[1] + 9
+    row = ck.table_bases(TABLE_ROWS)[1] + 9
     meta[row] += 2
     res = _readback(ring, heads, meta, val)
     assert not res["ok"] and res["unlawful_stale"] == 1
@@ -127,7 +128,7 @@ def test_a_newer_live_row_is_lawful_only_where_the_ring_wrapped_over_it():
     assert res["wrapped"] and res["ok"] and res["keys"] == 16
     # a fresh entry (newer half of the window) that the table has
     # outrun is never lawful, wrapped or not
-    row = ck.table_bases(N_SUB)[0] + 44          # the last write's row
+    row = ck.table_bases(TABLE_ROWS)[0] + 44          # the last write's row
     meta[row] += 2
     res = _readback(ring, heads, meta, val)
     assert not res["ok"] and res["unlawful_stale"] == 1
@@ -166,6 +167,29 @@ def test_a_key_outside_its_table_is_reported_not_followed():
     ring[0, 0, 2] = 10_000                       # subscriber table, key 10k
     res = _readback(ring, heads, meta, val)
     assert not res["ok"] and not res["in_range"]
+
+
+@pytest.mark.parametrize("table_rows,table,key,row", [
+    ((40, 40), 1, 39, 79),              # two tables of one size
+    ((7, 100, 3), 2, 2, 109), ((7, 100, 3), 1, 0, 7)])
+def test_readback_takes_the_table_layout_as_an_argument(table_rows, table,
+                                                        key, row):
+    assert ck.table_bases(table_rows).tolist() == [
+        sum(table_rows[:t]) for t in range(len(table_rows))]
+    ring = np.zeros((1, 4, ck.HDR_WORDS + 2), np.uint32)
+    ring[0, 0] = _entry(table, key, 5, [900, 77])
+    ring[0, 1] = _entry(table, key, 6, [850, 77])        # the newer one
+    plan = ck.plan_readback(ring, np.array([2], np.uint32), table_rows, 2)
+    assert plan["in_range"] and plan["rows"].tolist() == [row]
+    assert plan["val"].tolist() == [[850, 77]] and not plan["wrapped"]
+    assert plan["fresh"].tolist() == [True] and plan["n_entries"] == 2
+    # a table the layout does not have, and a key past its table's end
+    ring[0, 1] = _entry(len(table_rows), 0, 6, [1, 1])
+    assert not ck.plan_readback(ring, np.array([2], np.uint32), table_rows,
+                                2)["in_range"]
+    ring[0, 1] = _entry(table, table_rows[table], 6, [1, 1])
+    assert not ck.plan_readback(ring, np.array([2], np.uint32), table_rows,
+                                2)["in_range"]
 
 
 def test_ab_missing_band_is_at_least_four_sigma():
