@@ -305,7 +305,8 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
     oob = m1
     h = db.lock_slots
     t = db.step
-    kgen, kamt = jax.random.split(key)
+    with waves.part("smallbank_dense", "sb_addr"):
+        kgen, kamt = jax.random.split(key)
 
     # ---- wave 1: new cohort lock + fused read + compute -------------------
     if gen_new:
@@ -318,12 +319,14 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
             ttype, a1, a2 = gen_cohort(kgen, w, n_accounts, **skew)
             l_op, l_tb, l_ac = _lock_slots(ttype, a1, a2)  # [w, L]
     else:
-        ttype = jnp.zeros((w,), I32)
-        l_op = jnp.zeros((w, L), I32)
-        l_tb = jnp.zeros((w, L), I32)
-        l_ac = jnp.zeros((w, L), I32)
-    ts_amt = jax.random.randint(kamt, (w,), -TS_AMT_MAX, TS_AMT_MAX + 1,
-                                dtype=I32)
+        with waves.part("smallbank_dense", "sb_addr"):
+            ttype = jnp.zeros((w,), I32)
+            l_op = jnp.zeros((w, L), I32)
+            l_tb = jnp.zeros((w, L), I32)
+            l_ac = jnp.zeros((w, L), I32)
+    with waves.part("smallbank_dense", "sb_addr"):
+        ts_amt = jax.random.randint(kamt, (w,), -TS_AMT_MAX, TS_AMT_MAX + 1,
+                                    dtype=I32)
 
     if occupancy is not None:
         # serving-plane occupancy mask: the cohort generates full-width
@@ -336,22 +339,23 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
             lane_ok = jnp.arange(w, dtype=I32) < occ
             l_op = jnp.where(lane_ok[:, None], l_op, 0)
 
-    active = l_op != 0
-    rows = jnp.where(active, l_tb * n_accounts + l_ac, sent)  # [w, L]
-    flat_rows = rows.reshape(-1)
-    slot = _slot_of(flat_rows, m1, h)                         # [wL]
-    is_x_lane = (l_op == Op.ACQ_X_READ).reshape(-1)
-    is_s_lane = (l_op == Op.ACQ_S_READ).reshape(-1)
-    lane = jnp.arange(w * L, dtype=I32)
+    with waves.part("smallbank_dense", "sb_addr"):
+        active = l_op != 0
+        rows = jnp.where(active, l_tb * n_accounts + l_ac, sent)  # [w, L]
+        flat_rows = rows.reshape(-1)
+        slot = _slot_of(flat_rows, m1, h)                         # [wL]
+        is_x_lane = (l_op == Op.ACQ_X_READ).reshape(-1)
+        is_s_lane = (l_op == Op.ACQ_S_READ).reshape(-1)
+        lane = jnp.arange(w * L, dtype=I32)
 
-    # dintcache partition: a lane is hot iff its account sits in the
-    # mirrored prefix; mirror index = tbl*hot_n + acc. Stamps share the
-    # same mapping in the exact slot regime (slot == row).
-    hn = db.hot_n
-    stamp_hot = use_hotset and db.hot_x is not None
-    if use_hotset:
-        hot_lane = (active & (l_ac < hn)).reshape(-1)
-        midx = jnp.where(hot_lane, (l_tb * hn + l_ac).reshape(-1), -1)
+        # dintcache partition: a lane is hot iff its account sits in the
+        # mirrored prefix; mirror index = tbl*hot_n + acc. Stamps share the
+        # same mapping in the exact slot regime (slot == row).
+        hn = db.hot_n
+        stamp_hot = use_hotset and db.hot_x is not None
+        if use_hotset:
+            hot_lane = (active & (l_ac < hn)).reshape(-1)
+            midx = jnp.where(hot_lane, (l_tb * hn + l_ac).reshape(-1), -1)
 
     if use_fused:
         # lock_validate megakernel: both held-stamp gathers AND the wave-1
@@ -366,51 +370,56 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
                 (slot, slot, flat_rows), (1, 1, 1))
 
     with waves.scope("smallbank_dense", "lock"):
-        first_x = jnp.full((h,), BIG, I32).at[
-            jnp.where(is_x_lane, slot, h)].min(lane, mode="drop")
-        first_s = jnp.full((h,), BIG, I32).at[
-            jnp.where(is_s_lane, slot, h)].min(lane, mode="drop")
+        with waves.part("smallbank_dense", "lock_arb"):
+            first_x = jnp.full((h,), BIG, I32).at[
+                jnp.where(is_x_lane, slot, h)].min(lane, mode="drop")
+            first_s = jnp.full((h,), BIG, I32).at[
+                jnp.where(is_s_lane, slot, h)].min(lane, mode="drop")
         # held = stamped by the previous step's cohort (released implicitly
         # one step later; acquire-before-release semantics preserved)
-        if use_fused:
-            held_x = hx_raw == t - 1
-            held_s = hs_raw == t - 1
-        elif stamp_hot:
-            held_x = pg.hot_gather(db.x_step, db.hot_x, slot, midx, 1,
-                                   use_pallas=use_pallas) == t - 1
-            held_s = pg.hot_gather(db.s_step, db.hot_s, slot, midx, 1,
-                                   use_pallas=use_pallas) == t - 1
-        elif use_pallas:
-            held_x = pg.gather_rows(db.x_step, slot, 1) == t - 1
-            held_s = pg.gather_rows(db.s_step, slot, 1) == t - 1
-        else:
-            held_x = db.x_step[slot] == t - 1
-            held_s = db.s_step[slot] == t - 1
-        slot_free = ~held_x & ~held_s
-        x_wins = (first_x[slot] < first_s[slot]) & slot_free
-        grant_x = is_x_lane & x_wins & (first_x[slot] == lane)
-        grant_s = is_s_lane & ~held_x & ~x_wins
-        x_step = db.x_step.at[jnp.where(grant_x, slot, h)].set(
-            t, mode="drop", unique_indices=True)
-        # one writer per slot: the first S lane stamps for all sharers
-        s_writer = grant_s & (first_s[slot] == lane)
-        s_step = db.s_step.at[
-            jnp.where(s_writer, slot, h)].set(
-            t, mode="drop", unique_indices=True)
-        hot_x, hot_s = db.hot_x, db.hot_s
-        if stamp_hot:
-            # stamp write-through: the grant masks are one-writer-per-slot,
-            # so their hot subsets are one-writer-per-mirror-index
-            hot_x = hot_x.at[jnp.where(grant_x & (midx >= 0), midx,
-                                       2 * hn)].set(t, mode="drop",
-                                                    unique_indices=True)
-            hot_s = hot_s.at[jnp.where(s_writer & (midx >= 0), midx,
-                                       2 * hn)].set(t, mode="drop",
-                                                    unique_indices=True)
+        with waves.part("smallbank_dense", "lock_held_read"):
+            if use_fused:
+                held_x = hx_raw == t - 1
+                held_s = hs_raw == t - 1
+            elif stamp_hot:
+                held_x = pg.hot_gather(db.x_step, db.hot_x, slot, midx, 1,
+                                       use_pallas=use_pallas) == t - 1
+                held_s = pg.hot_gather(db.s_step, db.hot_s, slot, midx, 1,
+                                       use_pallas=use_pallas) == t - 1
+            elif use_pallas:
+                held_x = pg.gather_rows(db.x_step, slot, 1) == t - 1
+                held_s = pg.gather_rows(db.s_step, slot, 1) == t - 1
+            else:
+                held_x = db.x_step[slot] == t - 1
+                held_s = db.s_step[slot] == t - 1
+            slot_free = ~held_x & ~held_s
+        with waves.part("smallbank_dense", "lock_grant"):
+            x_wins = (first_x[slot] < first_s[slot]) & slot_free
+            grant_x = is_x_lane & x_wins & (first_x[slot] == lane)
+            grant_s = is_s_lane & ~held_x & ~x_wins
+            # one writer per slot: the first S lane stamps for all sharers
+            s_writer = grant_s & (first_s[slot] == lane)
+        with waves.part("smallbank_dense", "lock_stamp"):
+            x_step = db.x_step.at[jnp.where(grant_x, slot, h)].set(
+                t, mode="drop", unique_indices=True)
+            s_step = db.s_step.at[
+                jnp.where(s_writer, slot, h)].set(
+                t, mode="drop", unique_indices=True)
+            hot_x, hot_s = db.hot_x, db.hot_s
+            if stamp_hot:
+                # stamp write-through: the grant masks are one-writer-per-
+                # slot, so their hot subsets are one-writer-per-mirror-index
+                hot_x = hot_x.at[jnp.where(grant_x & (midx >= 0), midx,
+                                           2 * hn)].set(t, mode="drop",
+                                                        unique_indices=True)
+                hot_s = hot_s.at[jnp.where(s_writer & (midx >= 0), midx,
+                                           2 * hn)].set(t, mode="drop",
+                                                        unique_indices=True)
 
-        granted = (grant_x | grant_s).reshape(w, L)
-        lock_rejected = (active & ~granted).any(axis=1)
-        alive = ~lock_rejected & (l_op[:, 0] != 0)
+        with waves.part("smallbank_dense", "lock_grant"):
+            granted = (grant_x | grant_s).reshape(w, L)
+            lock_rejected = (active & ~granted).any(axis=1)
+            alive = ~lock_rejected & (l_op[:, 0] != 0)
 
     # fused reads from the pre-install table: rows c1 installs below were
     # X-stamped by c1, so this cohort never granted (or consumed) them
@@ -431,15 +440,16 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
         do_write = do & commit[:, None] & active
         bal_delta = jnp.sum(jnp.where(do_write, nw - bal, 0), dtype=I32)
 
-    new_ctx = BankCtx(
-        rows=rows, do_write=do_write, nw=nw, tbl=l_tb, acc=l_ac,
-        attempted=(occ if occupancy is not None
-                   else jnp.asarray(w if gen_new else 0, I32)),
-        committed=committed.sum(dtype=I32),
-        ab_lock=(lock_rejected & (l_op[:, 0] != 0)).sum(dtype=I32),
-        ab_logic=logic_abort.sum(dtype=I32),
-        magic_bad=jnp.asarray(0, I32),
-        bal_delta=bal_delta)
+    with waves.part("smallbank_dense", "sb_ctx"):
+        new_ctx = BankCtx(
+            rows=rows, do_write=do_write, nw=nw, tbl=l_tb, acc=l_ac,
+            attempted=(occ if occupancy is not None
+                       else jnp.asarray(w if gen_new else 0, I32)),
+            committed=committed.sum(dtype=I32),
+            ab_lock=(lock_rejected & (l_op[:, 0] != 0)).sum(dtype=I32),
+            ab_logic=logic_abort.sum(dtype=I32),
+            magic_bad=jnp.asarray(0, I32),
+            bal_delta=bal_delta)
 
     # ---- wave 2 of c1: install + log x3 (locks expire by stamp) -----------
     # MACHINE-CHECKED (dintlint protocol pass): c1.do_write descends from
@@ -504,22 +514,27 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
 
     if not use_fused:
         with waves.scope("smallbank_dense", "log_append"):
-            newval = jnp.zeros((wrows.shape[0], VW), U32)
-            newval = newval.at[:, 0].set(newbal.astype(U32))
-            newval = newval.at[:, 1].set(jnp.where(dwf, U32(MAGIC),
-                                                   U32(0)))
-            zero = jnp.zeros_like(newbal, U32)
-            # log ver = step index: monotonic per row (one X-writer per
-            # row per step), all recovery's max-ver-per-row rule needs
-            stepv = jnp.broadcast_to(t, newbal.shape)
+            with waves.part("smallbank_dense", "log_build"):
+                newval = jnp.zeros((wrows.shape[0], VW), U32)
+                newval = newval.at[:, 0].set(newbal.astype(U32))
+                newval = newval.at[:, 1].set(jnp.where(dwf, U32(MAGIC),
+                                                       U32(0)))
+                zero = jnp.zeros_like(newbal, U32)
+                # log ver = step index: monotonic per row (one X-writer
+                # per row per step), all recovery's max-ver-per-row rule
+                # needs
+                stepv = jnp.broadcast_to(t, newbal.shape)
             logs = logring.append_rep(db.log, dwf, c1.tbl.reshape(-1),
                                       jnp.zeros_like(newbal), zero,
                                       c1.acc.reshape(-1).astype(U32),
                                       stepv, newval)
 
-    db = db.replace(bal=bal_new, x_step=x_step, s_step=s_step,
-                    step=t + 1, log=logs, hot_bal=hot_bal,
-                    hot_x=hot_x, hot_s=hot_s)
+    with waves.part("smallbank_dense", "sb_ctx"):
+        db = db.replace(bal=bal_new, x_step=x_step, s_step=s_step,
+                        step=t + 1, log=logs, hot_bal=hot_bal,
+                        hot_x=hot_x, hot_s=hot_s)
+    with waves.part("smallbank_dense", "stats"):
+        stats = _stats_of(c1)
     extra = ()
     if ring is not None:
         # dinttrace: this step's candidate events — lock verdicts of the
@@ -555,58 +570,59 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
             ring, counters = txe.emit(ring, tcfg, groups, counters)
         extra = (ring,)
     if counters is not None:
-        act_l = active.reshape(-1)
-        grant_l = granted.reshape(-1)
-        held_l = held_x | held_s            # [wL] slot stamped last step
-        rej_l = act_l & ~grant_l
-        hot_ctrs = {}
-        if use_hotset:
-            # partition accounting: every hot-partitioned gather serves
-            # (midx >= 0) lanes from the mirror and the rest via cold row
-            # DMAs; the mirror refresh is one bulk DMA per pallas gather
-            # invocation (0 on the XLA partition route). The fused route
-            # reads the main arrays directly (no gather is partitioned),
-            # so its partition counters are structurally zero
-            n_g = 0 if use_fused else 1 + (2 if stamp_hot else 0)
-            hits = (midx >= 0).sum(dtype=I32)
-            hot_ctrs = {
-                mon.CTR_HOT_HITS: n_g * hits,
-                mon.CTR_HOT_COLD_ROWS: n_g * (w * L) - n_g * hits,
-                mon.CTR_HOT_REFRESH_BYTES:
-                    (n_g * 2 * hn * 4) if use_pallas else 0,
-            }
-        serve_ctrs = {}
-        if occupancy is not None:
-            serve_ctrs = {
-                mon.CTR_SERVE_OCC_LANES: occ,
-                mon.CTR_SERVE_PAD_LANES: jnp.asarray(w, I32) - occ,
-                mon.CTR_SERVE_SHED_LANES:
-                    jnp.asarray(0 if shed is None else shed, I32),
-            }
-        counters = mon.bump(counters, {
-            **hot_ctrs,
-            **serve_ctrs,
-            mon.CTR_STEPS: 1,
-            mon.CTR_TXN_ATTEMPTED: c1.attempted,
-            mon.CTR_TXN_COMMITTED: c1.committed,
-            mon.CTR_AB_LOCK: c1.ab_lock,
-            mon.CTR_AB_LOGIC: c1.ab_logic,
-            mon.CTR_MAGIC_BAD: c1.magic_bad,
-            mon.CTR_LOCK_REQUESTS: act_l.sum(dtype=I32),
-            mon.CTR_LOCK_GRANTED: grant_l.sum(dtype=I32),
-            mon.CTR_LOCK_REJECTED: rej_l.sum(dtype=I32),
-            mon.CTR_LOCK_REJECT_HELD: (rej_l & held_l).sum(dtype=I32),
-            mon.CTR_LOCK_REJECT_ARB: (rej_l & ~held_l).sum(dtype=I32),
-            mon.CTR_INSTALL_WRITES: dwf.sum(dtype=I32),
-            mon.CTR_LOG_APPENDS: dwf.sum(dtype=I32),
-            (mon.CTR_DISPATCH_PALLAS if use_pallas
-             else mon.CTR_DISPATCH_XLA): 1,
-            **({mon.CTR_FUSED_DISPATCH: 1} if use_fused else {}),
-        })
-        counters = mon.gauge_max(
-            counters, {mon.CTR_RING_HWM: logs.head.max()})
-        return (db, new_ctx, _stats_of(c1), counters) + extra
-    return (db, new_ctx, _stats_of(c1)) + extra
+        with waves.part("smallbank_dense", "monitor"):
+            act_l = active.reshape(-1)
+            grant_l = granted.reshape(-1)
+            held_l = held_x | held_s            # [wL] slot stamped last step
+            rej_l = act_l & ~grant_l
+            hot_ctrs = {}
+            if use_hotset:
+                # partition accounting: every hot-partitioned gather serves
+                # (midx >= 0) lanes from the mirror and the rest via cold row
+                # DMAs; the mirror refresh is one bulk DMA per pallas gather
+                # invocation (0 on the XLA partition route). The fused route
+                # reads the main arrays directly (no gather is partitioned),
+                # so its partition counters are structurally zero
+                n_g = 0 if use_fused else 1 + (2 if stamp_hot else 0)
+                hits = (midx >= 0).sum(dtype=I32)
+                hot_ctrs = {
+                    mon.CTR_HOT_HITS: n_g * hits,
+                    mon.CTR_HOT_COLD_ROWS: n_g * (w * L) - n_g * hits,
+                    mon.CTR_HOT_REFRESH_BYTES:
+                        (n_g * 2 * hn * 4) if use_pallas else 0,
+                }
+            serve_ctrs = {}
+            if occupancy is not None:
+                serve_ctrs = {
+                    mon.CTR_SERVE_OCC_LANES: occ,
+                    mon.CTR_SERVE_PAD_LANES: jnp.asarray(w, I32) - occ,
+                    mon.CTR_SERVE_SHED_LANES:
+                        jnp.asarray(0 if shed is None else shed, I32),
+                }
+            counters = mon.bump(counters, {
+                **hot_ctrs,
+                **serve_ctrs,
+                mon.CTR_STEPS: 1,
+                mon.CTR_TXN_ATTEMPTED: c1.attempted,
+                mon.CTR_TXN_COMMITTED: c1.committed,
+                mon.CTR_AB_LOCK: c1.ab_lock,
+                mon.CTR_AB_LOGIC: c1.ab_logic,
+                mon.CTR_MAGIC_BAD: c1.magic_bad,
+                mon.CTR_LOCK_REQUESTS: act_l.sum(dtype=I32),
+                mon.CTR_LOCK_GRANTED: grant_l.sum(dtype=I32),
+                mon.CTR_LOCK_REJECTED: rej_l.sum(dtype=I32),
+                mon.CTR_LOCK_REJECT_HELD: (rej_l & held_l).sum(dtype=I32),
+                mon.CTR_LOCK_REJECT_ARB: (rej_l & ~held_l).sum(dtype=I32),
+                mon.CTR_INSTALL_WRITES: dwf.sum(dtype=I32),
+                mon.CTR_LOG_APPENDS: dwf.sum(dtype=I32),
+                (mon.CTR_DISPATCH_PALLAS if use_pallas
+                 else mon.CTR_DISPATCH_XLA): 1,
+                **({mon.CTR_FUSED_DISPATCH: 1} if use_fused else {}),
+            })
+            counters = mon.gauge_max(
+                counters, {mon.CTR_RING_HWM: logs.head.max()})
+        return (db, new_ctx, stats, counters) + extra
+    return (db, new_ctx, stats) + extra
 
 
 @memoize_builder
@@ -708,21 +724,20 @@ def build_pipelined_runner(n_accounts: int, w: int = 8192,
                + ((cnt,) if monitor else ()))
         return out, stats
 
-    def _pre(carry):
-        if trace_on:
-            # each block is one drain window: self-contained ring
-            carry = carry[:2] + (txe.reset(carry[2]),) + carry[3:]
-        return carry
+    def _pre(carry, key):
+        with waves.part("smallbank_dense", "block_pre"):
+            if trace_on:
+                # each block is one drain window: self-contained ring
+                carry = carry[:2] + (txe.reset(carry[2]),) + carry[3:]
+            return carry, jax.random.split(key, cohorts_per_block)
 
     if serve:
         def block(carry, key, occ, shed):
-            carry = _pre(carry)
-            keys = jax.random.split(key, cohorts_per_block)
+            carry, keys = _pre(carry, key)
             return jax.lax.scan(scan_fn, carry, (keys, occ, shed))
     else:
         def block(carry, key):
-            carry = _pre(carry)
-            keys = jax.random.split(key, cohorts_per_block)
+            carry, keys = _pre(carry, key)
             return jax.lax.scan(scan_fn, carry, keys)
 
     def init(db):

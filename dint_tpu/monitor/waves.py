@@ -356,16 +356,19 @@ def scope(engine: str, wave: str):
 
 # --------------------------------------------------------------- the parts
 PART_PREFIX = "part"
+_DENSE = ("tatp_dense", "smallbank_dense")    # the engine-neutral parts
 
 # (owner, wave | None, part, doc). The owner is the engine, or the shared
-# module ("log" = tables/log.py), whose code opens the part; the wave is
+# module ("log" = tables/log.py), whose code opens the part, or a tuple of
+# engines where the part is engine-neutral and each of them opens it in
+# its own step (`monitor`, `stats`, `block_pre`); the wave is
 # the one it lies under in that owner's step, None for what a step does
 # outside every wave. append_rep's parts also run under
 # `dense_sharded.replicate`, where a backup appends; that wave's own
 # parts come with the cell that reads them. The innermost part on an
 # op's name stack is the one its time is booked to
 # (benchmarks/part_times.py).
-_PARTS: tuple[tuple[str, str | None, str, str], ...] = (
+_PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
     # --- dense TATP (engines/tatp_dense.py), the XLA route --------------
     ("tatp_dense", "install", "install_build",
      "masks, new meta words, payload draw and the [2w, VW] new rows"),
@@ -393,13 +396,13 @@ _PARTS: tuple[tuple[str, str | None, str, str], ...] = (
      "out of the [w, K] meta words read"),
     ("tatp_dense", None, "classify",
      "reply types, classify_wave1 and the new cohort's context"),
-    ("tatp_dense", None, "monitor",
+    (_DENSE, None, "monitor",
      "everything the step does only because the counter plane (or the "
      "flight recorder) is threaded: the reductions, the scatter-add, "
      "the gauge max (monitor_ms.* reads this)"),
-    ("tatp_dense", None, "stats",
+    (_DENSE, None, "stats",
      "the completing cohort's stats vector"),
-    ("tatp_dense", None, "block_pre",
+    (_DENSE, None, "block_pre",
      "block prologue: per-step key split, the stamp-rebase cond, the "
      "flight recorder's ring reset"),
     # --- tables/log.py append_rep, under whichever wave calls it --------
@@ -414,12 +417,36 @@ _PARTS: tuple[tuple[str, str | None, str, str], ...] = (
      "the chunk loop of the install, each chunk's lane search (C x 2w "
      "compares) and its gathers of row ids, meta words and value rows "
      "out of the 2w-wide operands"),
+    # --- dense SmallBank (engines/smallbank_dense.py), the XLA route ----
+    ("smallbank_dense", "lock", "lock_arb",
+     "the arbitration proper: two fresh slot-table-wide arrays filled "
+     "and scatter-min'ed with the lane index over the X and the S "
+     "requests (lock_arb_ms.* reads this)"),
+    ("smallbank_dense", "lock", "lock_held_read",
+     "gathers of the wL lanes' X and S stamps + the held compares"),
+    ("smallbank_dense", "lock", "lock_grant",
+     "gathers back out of the two arbitration arrays, the grant masks "
+     "and the transactions' lock verdicts"),
+    ("smallbank_dense", "lock", "lock_stamp",
+     "the two unique-index stamp scatters of the granted lanes (and the "
+     "hot mirror's write-through)"),
+    ("smallbank_dense", "log_append", "log_build",
+     "the [wL, VW] value rows {balance, magic} and the step-index "
+     "version column handed to append_rep"),
+    ("smallbank_dense", None, "sb_addr",
+     "the step's key split, the TRANSACT_SAVING amount draw, flat rows, "
+     "lock slots (identity or multiply-shift) and the lane masks"),
+    ("smallbank_dense", None, "sb_ctx",
+     "the new cohort's context (its outcome sums) and the step counter's "
+     "increment"),
 )
 
 # keyed on the part's name alone: the scope is `part.<name>`, so two
-# owners could not tell a shared name apart in a trace
-PART_OWNER: dict[str, str] = {p: o for o, _, p, _ in _PARTS}
-assert len(PART_OWNER) == len(_PARTS), "duplicate part in registry"
+# owners could not tell a shared name apart in a trace; a name that
+# several engines open is one row with all of them as its owners
+PART_OWNERS: dict[str, tuple[str, ...]] = {
+    p: (o,) if isinstance(o, str) else o for o, _, p, _ in _PARTS}
+assert len(PART_OWNERS) == len(_PARTS), "duplicate part in registry"
 
 
 def part_name(name: str) -> str:
@@ -429,7 +456,7 @@ def part_name(name: str) -> str:
 def part(owner: str, name: str):
     """`jax.named_scope("part.<name>")` for a REGISTERED part of `owner`;
     an unregistered one raises at trace time, as `scope` does."""
-    if PART_OWNER.get(name) != owner:
+    if owner not in PART_OWNERS.get(name, ()):
         raise KeyError(
             f"part {name!r} of {owner!r} is not in the part registry "
             "(monitor/waves.py _PARTS); add it there first")

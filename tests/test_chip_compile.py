@@ -29,6 +29,7 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
+from dint_tpu.engines import smallbank_dense as sd
 from dint_tpu.engines import tatp_dense as td
 from dint_tpu.ops import compact
 from dint_tpu.ops import pallas_gather as pg
@@ -153,6 +154,46 @@ def test_tatp7m_block_program_fits_one_chip(one_chip):
         # meta's install; the lock wave's scatter-max over arb, the same
         # shape, still issues all 2w (ROADMAP Queue 1)
         assert sorted(set(scatter_index_counts(hlo, N1))) == [chunk, 2 * W]
+
+
+def test_smallbank24m_block_program_fits_one_chip(one_chip):
+    """The `smallbank24m-sat` cell's programs at the deployment's scale:
+    24,000,000 accounts, 2^25 hashed lock slots, w=8192 x 16 cohorts. Pins
+    the sizes PERF.md reckons with: 0.56 GB of state donated and updated
+    in place, and of the two slot-table-wide arbitration arrays (134 MB
+    each) one live at a time as the step's temporaries."""
+    n_acc = 24_000_000
+    run, init, drain = sd.build_pipelined_runner(
+        n_acc, w=W, cohorts_per_block=CPB, monitor=True, use_pallas=False,
+        use_fused=False, use_hotset=False, trace=False)
+    carry = placed(jax.eval_shape(lambda: init(sd.create(n_acc))), one_chip)
+    key = placed(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+    db = carry[0]
+    assert db.lock_slots == sd.MAX_LOCK_SLOTS == 1 << 25    # hashed
+    assert db.bal.shape == (2 * n_acc + 1,)
+    # balances, two stamp tables, and the rings as the chip lays them
+    # out (a slot's 18 words padded to 24)
+    state = 4 * (2 * n_acc + 1) + 2 * 4 * (1 << 25) \
+        + 4 * 16 * (1 << 16) * 24
+    for fn, args in ((run, (carry, key)), (drain, (carry,))):
+        c, ma = compiled_bytes(fn, *args)
+        assert state < ma.argument_size_in_bytes < state + 2e6
+        assert ma.alias_size_in_bytes > 0.99 * ma.argument_size_in_bytes
+        assert ma.temp_size_in_bytes < 0.2e9
+        # the program asks for no sort; the compiler puts one of wL =
+        # 24,576 (index, value) pairs before each scatter of the lock wave
+        # (the two scatter-mins, whose indices repeat, and the two stamp
+        # scatters), and before no other (PERF.md section 7)
+        sorts = re.findall(r' sort\([^\n]*op_name="([^"]*)"', c.as_text())
+        assert all(name.endswith(("part.lock_arb/scatter-min",
+                                  "part.lock_stamp/scatter"))
+                   for name in sorts)
+        if fn is run:
+            assert len(sorts) == 4
+            # the compiler gathers out of the first arbitration array
+            # before it fills the second: one is live, not both (ISSUE 33
+            # reckoned 268 MB); the drain has no requests to arbitrate
+            assert ma.temp_size_in_bytes >= 4 * (1 << 25)
 
 
 def test_windowed_row_scatter_is_expanded_to_a_loop_on_v5e(one_chip):

@@ -19,6 +19,7 @@ import pytest
 from benchmarks import trace_reduce
 from dint_tpu import _runtime
 from dint_tpu.analysis import cost
+from dint_tpu.engines import smallbank_dense as sd
 from dint_tpu.engines import tatp_dense as td
 from dint_tpu.monitor import waves
 from dint_tpu.parallel import dense_sharded as ds
@@ -36,6 +37,13 @@ def _dense(monitor=True):
     return run, init(db), drain
 
 
+def _bank(monitor=True):
+    run, init, drain = sd.build_pipelined_runner(
+        N_SUB, w=W, cohorts_per_block=CPB, monitor=monitor,
+        use_pallas=False, use_fused=False, use_hotset=False, trace=False)
+    return run, init(sd.create(N_SUB, log_capacity=1 << 10)), drain
+
+
 def _op_names(compiled_text: str) -> set:
     """The name stacks in a compiled module's metadata (XLA joins the
     names of ops it merged with ``;``)."""
@@ -49,7 +57,7 @@ def _assert_parts_under_their_waves(names: set, owners: tuple,
     the registry gives it a wave, each such name has that wave before
     it."""
     for owner, wave, part, _ in waves._PARTS:
-        if owner not in owners or part in skip:
+        if not set(waves.PART_OWNERS[part]) & set(owners) or part in skip:
             continue
         here = waves.part_name(part)
         stacks = [n.split("/") for n in names if here in n.split("/")]
@@ -65,7 +73,14 @@ def test_part_registry_schema():
     for owner, wave, part, doc in waves._PARTS:
         assert re.fullmatch(r"[a-z0-9_]+", part) and doc, part
         assert wave is None or wave in short, (part, wave)
-        assert owner in waves.ENGINES or owner == "log", owner
+        owners = waves.PART_OWNERS[part]
+        assert owners == ((owner,) if isinstance(owner, str) else owner)
+        assert len(set(owners)) == len(owners) >= 1
+        for o in owners:
+            assert o in waves.ENGINES or o == "log", o
+        # a part several engines open lies outside any wave: a wave's
+        # name would belong to one of them
+        assert len(owners) == 1 or wave is None, part
         # a part's name is read by neither reader of the waves
         name = f"jit(block)/while/body/{waves.part_name(part)}/scatter"
         assert not trace_reduce.SCOPE.search(name)
@@ -77,12 +92,33 @@ def test_part_rejects_unregistered_name():
         waves.part("tatp_dense", "no_such_part")
     with pytest.raises(KeyError):
         waves.part("smallbank_dense", "val_scatter")    # another's part
+    with pytest.raises(KeyError):
+        waves.part("tatp_dense", "lock_arb")
+
+
+def test_an_engine_neutral_part_is_shared_by_the_dense_engines_alone():
+    for name in ("monitor", "stats", "block_pre"):
+        assert waves.PART_OWNERS[name] == ("tatp_dense", "smallbank_dense")
+        for owner in waves.PART_OWNERS[name]:
+            with waves.part(owner, name):
+                pass
+        with pytest.raises(KeyError, match="part registry"):
+            waves.part("tatp_pipeline", name)
+        with pytest.raises(KeyError, match="part registry"):
+            waves.part("log", name)
 
 
 def test_every_dense_part_reaches_compiled_hlo_under_its_wave():
     run, carry, _ = _dense()
     text = run.lower(carry, jax.random.PRNGKey(0)).compile().as_text()
     _assert_parts_under_their_waves(_op_names(text), ("tatp_dense", "log"))
+
+
+def test_every_smallbank_part_reaches_compiled_hlo_under_its_wave():
+    run, carry, _ = _bank()
+    text = run.lower(carry, jax.random.PRNGKey(0)).compile().as_text()
+    _assert_parts_under_their_waves(_op_names(text),
+                                    ("smallbank_dense", "log"))
 
 
 def test_the_sharded_block_carries_the_dense_steps_parts():
@@ -136,6 +172,14 @@ def test_every_equation_of_the_dense_block_carries_a_wave_or_a_part(
     assert _unnamed_equations(closed.jaxpr, False, (), []) == []
 
 
+@pytest.mark.parametrize("monitor", [True, False])
+def test_every_equation_of_the_smallbank_block_carries_a_wave_or_a_part(
+        monitor):
+    run, carry, _ = _bank(monitor)
+    closed = jax.make_jaxpr(run)(carry, jax.random.PRNGKey(0))
+    assert _unnamed_equations(closed.jaxpr, False, (), []) == []
+
+
 def test_parts_are_semantics_neutral(monkeypatch):
     def run_once():
         run, carry, drain = _dense()
@@ -151,6 +195,25 @@ def test_parts_are_semantics_neutral(monkeypatch):
                         lambda owner, name: contextlib.nullcontext())
     b = run_once()
     td.build_pipelined_runner.cache.clear()
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(x, y)
+
+
+def test_smallbank_parts_are_semantics_neutral(monkeypatch):
+    def run_once():
+        run, carry, drain = _bank()
+        carry, stats = run(carry, jax.random.PRNGKey(3))
+        db, tail, counters = drain(carry)
+        return [np.asarray(x) for x in (
+            stats, tail, counters.buf, db.bal, db.x_step, db.s_step,
+            db.log.entries, db.log.head)]
+
+    a = run_once()
+    sd.build_pipelined_runner.cache.clear()     # not in the memo's key
+    monkeypatch.setattr(waves, "part",
+                        lambda owner, name: contextlib.nullcontext())
+    b = run_once()
+    sd.build_pipelined_runner.cache.clear()
     for x, y in zip(a, b):
         np.testing.assert_array_equal(x, y)
 

@@ -1,9 +1,14 @@
-"""Sort-free dense SmallBank pipeline: invariants + contention response."""
+"""Sort-free dense SmallBank pipeline: invariants, contention response,
+and the engine held exactly to the sequential reference
+(dint_tpu/testing/oracle.py ``SmallBankOracle``)."""
 import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from dint_tpu.engines import smallbank_dense as sd
 from dint_tpu.tables import log as logring
+from dint_tpu.testing import oracle
 
 
 def _run_blocks(n_accounts, w, blocks, cohorts_per_block=2, seed=0, **kw):
@@ -113,3 +118,124 @@ def test_hashed_lock_slots_conserve_balance(monkeypatch):
     final = int(np.asarray(sd.total_balance(db)))
     assert (final - base) % (1 << 32) == \
         int(total[sd.STAT_BAL_DELTA]) % (1 << 32)
+
+
+# ------------------------------------------- against the sequential reference
+
+ENGINE = dict(use_pallas=False, use_fused=False, use_hotset=False,
+              trace=False)
+N_ACC, W, CPB = 2000, 128, 2
+
+
+def _cohorts(key, blocks, **skew):
+    """The cohorts the runner generates from ``key``, again: a block splits
+    its key into one per step, a step splits off the generator's key and
+    the TRANSACT_SAVING amounts' (pipe_step). Traffic, shared with the
+    engine; lock sets and balance logic are not."""
+    out = []
+    for i in range(blocks):
+        for step_key in jax.random.split(jax.random.fold_in(key, i), CPB):
+            kgen, kamt = jax.random.split(step_key)
+            ttype, a1, a2 = sd.gen_cohort(kgen, W, N_ACC, **skew)
+            amt = jax.random.randint(kamt, (W,), -sd.TS_AMT_MAX,
+                                     sd.TS_AMT_MAX + 1, dtype=jnp.int32)
+            out.append([np.asarray(x) for x in (ttype, a1, a2, amt)])
+    return out
+
+
+def _engine_and_oracle(seed, phases, blocks, max_slots, **skew):
+    """Runs ``phases`` x (``blocks`` blocks + a drain) through the engine
+    and the same cohorts through the oracle: (stats rows of both, db,
+    oracle). A cohort's stats leave the engine one step after its
+    dispatch: the first row of a phase is the empty bootstrap cohort's,
+    the drain's row the last cohort's."""
+    sd.build_pipelined_runner.cache.clear()     # MAX_LOCK_SLOTS is no key
+    db = sd.create(N_ACC, log_capacity=1 << 12)
+    run, init, drain = sd.build_pipelined_runner(
+        N_ACC, w=W, cohorts_per_block=CPB, **ENGINE, **skew)
+    ref = oracle.SmallBankOracle(N_ACC, max_lock_slots=max_slots)
+    assert ref.n_slots == db.lock_slots
+    got, want = [], []
+    for phase in range(phases):
+        key = jax.random.PRNGKey(seed + phase)
+        carry = init(db)
+        for i in range(blocks):
+            carry, stats = run(carry, jax.random.fold_in(key, i))
+            got.append(np.asarray(stats, np.int64))
+        db, tail = drain(carry)
+        got.append(np.asarray(tail, np.int64))
+        want.append(np.zeros((1, sd.N_STATS), np.int64))
+        want += [ref.step(*c)[None] for c in _cohorts(key, blocks, **skew)]
+        ref.drain()
+    sd.build_pipelined_runner.cache.clear()
+    return np.concatenate(got), np.concatenate(want), db, ref
+
+
+def _assert_state_equals_oracle(db, ref):
+    rows, balances = ref.touched()
+    want = np.full(2 * N_ACC + 1, 1000, np.uint32)
+    want[-1] = 0
+    want[rows] = balances
+    np.testing.assert_array_equal(np.asarray(db.bal), want)
+    assert int(np.asarray(sd.total_balance(db))) == ref.total_balance()
+    heads = np.asarray(db.log.head)
+    for r in range(3):
+        ring = np.asarray(logring.replica_entries(db.log, r))
+        # (table, account, step, balance, magic) of every entry written
+        entries = sorted(
+            (int(e[0] >> 8), int(e[2]), int(e[3]), int(e[4]), int(e[5]))
+            for lane in range(ring.shape[0]) for e in ring[lane, :heads[lane]])
+        assert entries == sorted(ref.log)
+    assert int(np.asarray(db.step)) == ref.t
+
+
+# seed, phases, blocks, slot cap, skew; what the oracle's own tally must
+# have seen for the case to be the case
+ORACLE_CASES = {
+    "exact_slots": (0, 1, 3, 1 << 25, {}, ()),
+    # rows conflate in 2^10 slots, once within one transaction
+    "hashed_slots": (23, 1, 3, 1 << 10, {},
+                     ("x_rejected_own",)),
+    "contended": (2, 1, 3, 1 << 25, {"hot_frac": 0.01},
+                  ("s_shared", "x_rejected_prev_s", "x_rejected_prev_x",
+                   "x_rejected_cohort", "s_rejected_prev_x",
+                   "s_rejected_cohort")),
+    # two phases: a drain, then a fresh pipeline over the drained state
+    "drain_and_restart": (3, 2, 2, 1 << 25, {}, ()),
+}
+
+
+@pytest.mark.parametrize("case", ORACLE_CASES)
+def test_dense_equals_the_sequential_oracle(case, monkeypatch):
+    seed, phases, blocks, max_slots, skew, must_occur = ORACLE_CASES[case]
+    monkeypatch.setattr(sd, "MAX_LOCK_SLOTS", max_slots)
+    got, want, db, ref = _engine_and_oracle(seed, phases, blocks, max_slots,
+                                            **skew)
+    assert ref.hashed == (max_slots < 2 * N_ACC + 1)
+    for cause in must_occur:
+        assert ref.tally[cause] > 0, (cause, ref.tally)
+    assert want[:, sd.STAT_COMMITTED].sum() > 0
+    np.testing.assert_array_equal(got, want)
+    _assert_state_equals_oracle(db, ref)
+    if phases > 1:
+        # the drain's step let the last locks expire: the cohort after it
+        # met no lock of the cohort before it
+        per_phase = 1 + blocks * CPB
+        assert (got[::per_phase] == 0).all()
+
+
+def test_a_doctored_engine_fails_the_oracle(monkeypatch):
+    """One grant rule flipped: shared requests arbitrate as exclusive
+    ones, so sharers reject each other. The comparison must notice."""
+    real = sd._lock_slots
+
+    def no_sharing(ttype, a1, a2):
+        ops, tbl, acc = real(ttype, a1, a2)
+        return (jnp.where(ops == sd.Op.ACQ_S_READ, sd.Op.ACQ_X_READ, ops),
+                tbl, acc)
+
+    monkeypatch.setattr(sd, "_lock_slots", no_sharing)
+    got, want, _, ref = _engine_and_oracle(2, 1, 3, 1 << 25, hot_frac=0.01)
+    assert ref.tally["s_shared"] > 0
+    assert not np.array_equal(got, want)
+    assert got[:, sd.STAT_AB_LOCK].sum() > want[:, sd.STAT_AB_LOCK].sum()
