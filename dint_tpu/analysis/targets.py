@@ -1080,7 +1080,8 @@ _MH_EXPECT = {"dint.tatp_dense.log_append": "2*w*(20 + 4*vw)"}
 # the scan_requests/scan_rows/scan_delta_hits rows widen the device
 # Counters leaf by 12 B per device (3 x u32), +12 B single-chip, +12*d
 # on the sharded/mesh targets — a fleet-wide recalibration, not a leak.
-# PR 30's install_chunks row: +4 B per device in the same way.
+# PR 30's install_chunks row: +4 B per device in the same way; PR 34's
+# lock_chunks row: +4 B per device again.
 def _cost(geom, dispatches, footprint, *, steps=float(_BLK),
           bytes_budget="1.25*ledger", wave_expect=None):
     return dict(steps=float(steps), geom=dict(geom),
@@ -1101,8 +1102,8 @@ TARGET_COST.update({
     # slots: the scatters' bytes are the parent's
     "tatp_dense/block": _cost(_TD_GEOM, 12, 216844),
     "tatp_dense/block@pallas": _cost(_TD_GEOM, 10, 216844),
-    "tatp_dense/block@mon": _cost(_TD_GEOM, 14, 216996),
-    "tatp_dense/block@mon+pallas": _cost(_TD_GEOM, 13, 216996,
+    "tatp_dense/block@mon": _cost(_TD_GEOM, 14, 217000),
+    "tatp_dense/block@mon+pallas": _cost(_TD_GEOM, 13, 217000,
                                          wave_expect=_MONPL_TD),
     "tatp_dense/drain": _cost(_TD_GEOM, 12, 216836),
     "tatp_dense/block@hot": _cost(_TD_GEOM, 14, 216864,
@@ -1113,34 +1114,34 @@ TARGET_COST.update({
     # closed-loop rows above (the occupancy mask fuses into the gen
     # wave), footprint +16 B (@mon +28 B) for the occ/shed step inputs
     "tatp_dense/serve": _cost(_TD_GEOM, 12, 216860),
-    "tatp_dense/serve@mon": _cost(_TD_GEOM, 14, 217012),
+    "tatp_dense/serve@mon": _cost(_TD_GEOM, 14, 217016),
     "tatp_dense/block@fused": _cost(_TD_GEOM, 4, 216844),
     "tatp_dense/block@fused+hot": _cost(_TD_GEOM, 5, 216864,
                                         wave_expect=_TD_FUSED_HOT),
-    "tatp_dense/block@fused+mon": _cost(_TD_GEOM, 7, 216996),
+    "tatp_dense/block@fused+mon": _cost(_TD_GEOM, 7, 217000),
     # dense SmallBank: 8 -> 5 dispatches/step under the megakernels
     "smallbank_dense/block": _cost(_SB_GEOM, 8, 150984),
     "smallbank_dense/block@pallas": _cost(_SB_GEOM, 8, 150984),
-    "smallbank_dense/block@mon": _cost(_SB_GEOM, 10, 151136),
+    "smallbank_dense/block@mon": _cost(_SB_GEOM, 10, 151140),
     "smallbank_dense/block@hot": _cost(_SB_GEOM, 14, 151032,
                                        wave_expect=_HOT2_SB),
     "smallbank_dense/block@hot+pallas": _cost(_SB_GEOM, 10, 151032),
-    "smallbank_dense/block@hot+mon": _cost(_SB_GEOM, 16, 151184,
+    "smallbank_dense/block@hot+mon": _cost(_SB_GEOM, 16, 151188,
                                            wave_expect=_HOT2_SB),
     "smallbank_dense/serve": _cost(_SB_GEOM, 8, 151000),
-    "smallbank_dense/serve@mon": _cost(_SB_GEOM, 10, 151152),
+    "smallbank_dense/serve@mon": _cost(_SB_GEOM, 10, 151156),
     "smallbank_dense/block@fused": _cost(_SB_GEOM, 5, 150984),
     "smallbank_dense/block@fused+hot": _cost(_SB_GEOM, 7, 151032),
-    "smallbank_dense/block@fused+mon": _cost(_SB_GEOM, 7, 151136),
+    "smallbank_dense/block@fused+mon": _cost(_SB_GEOM, 7, 151140),
     # generic pipelines: sort-bound, no formula-backed waves -> absolute
     # bytes ceilings instead of a ledger multiple
     "tatp_pipeline/block": _cost(_TD_GEOM, 50, 1610736022,
                                  bytes_budget=256000),
-    "tatp_pipeline/block@mon": _cost(_TD_GEOM, 51, 1610736174,
+    "tatp_pipeline/block@mon": _cost(_TD_GEOM, 51, 1610736178,
                                      bytes_budget=256000),
     "smallbank_pipeline/block": _cost(_SB_GEOM, 36, 1207967480,
                                       bytes_budget=72000),
-    "smallbank_pipeline/block@mon": _cost(_SB_GEOM, 37, 1207967632,
+    "smallbank_pipeline/block@mon": _cost(_SB_GEOM, 37, 1207967636,
                                           bytes_budget=72000),
     # generic replicated shard step: one engine step per trace
     "sharded/tatp": _cost(_DS_GEOM, 62, 4295279296, steps=1.0,
@@ -1152,21 +1153,21 @@ TARGET_COST.update({
                                  wave_expect=_DS_EXPECT),
     "dense_sharded/block@pallas": _cost(_DS_GEOM, 34, 459240,
                                         wave_expect=_DS_EXPECT),
-    "dense_sharded/block@mon": _cost(_DS_GEOM, 40, 459848,
+    "dense_sharded/block@mon": _cost(_DS_GEOM, 40, 459864,
                                      wave_expect=_DS_EXPECT),
     "dense_sharded/block@fused": _cost(_DS_GEOM, 28, 459240,
                                        wave_expect=_DS_EXPECT_FUSED),
-    "dense_sharded/block@fused+mon": _cost(_DS_GEOM, 33, 459848,
+    "dense_sharded/block@fused+mon": _cost(_DS_GEOM, 33, 459864,
                                            wave_expect=_DS_EXPECT_FUSED),
     # dense multi-chip SmallBank: 33 -> 30 dispatches/step fused
     "dense_sharded_sb/block": _cost(_DSB_GEOM, 33, 100676560),
-    "dense_sharded_sb/block@mon": _cost(_DSB_GEOM, 37, 100677168),
+    "dense_sharded_sb/block@mon": _cost(_DSB_GEOM, 37, 100677184),
     "dense_sharded_sb/block@hot": _cost(_DSB_GEOM, 39, 100676848,
                                         wave_expect=_DSB_HOT),
     "dense_sharded_sb/block@fused": _cost(_DSB_GEOM, 30, 100676560),
     "dense_sharded_sb/block@fused+hot": _cost(
         _DSB_GEOM, 32, 100676848, wave_expect=_DSB_FUSED_HOT),
-    "dense_sharded_sb/block@fused+mon": _cost(_DSB_GEOM, 34, 100677168),
+    "dense_sharded_sb/block@fused+mon": _cost(_DSB_GEOM, 34, 100677184),
     # 2-D (dcn x ici) SmallBank: the hierarchical route pays +9
     # dispatches/step (each exchange runs ici + dcn stages) to move
     # strictly fewer DCN-axis link bytes than its flat twin — the
@@ -1175,7 +1176,7 @@ TARGET_COST.update({
     "multihost_sb/block": _cost(_MHSB_GEOM, 42, 201353056),
     "multihost_sb/block@flat": _cost(_MHSB_GEOM, 33, 201353056,
                                      wave_expect=_MHSB_FLAT),
-    "multihost_sb/block@mon": _cost(_MHSB_GEOM, 46, 201354272),
+    "multihost_sb/block@mon": _cost(_MHSB_GEOM, 46, 201354304),
     "multihost_sb/block@h3": _cost(_MHSB_GEOM_H3, 42, 151014808),
     "multihost_sb/block@h3+flat": _cost(_MHSB_GEOM_H3, 33, 151014808,
                                         wave_expect=_MHSB_FLAT),
@@ -1188,9 +1189,9 @@ TARGET_COST.update({
     "multihost_sb/serve": _cost(_MHSB_GEOM, 42, 201353184),
     "multihost_sb/serve@flat": _cost(_MHSB_GEOM, 33, 201353184,
                                      wave_expect=_MHSB_FLAT),
-    "multihost_sb/serve@mon": _cost(_MHSB_GEOM, 47, 201354400),
+    "multihost_sb/serve@mon": _cost(_MHSB_GEOM, 47, 201354432),
     "multihost_sb/serve@overlap": _cost(_MHSB_GEOM, 44, 201359424),
-    "multihost_sb/serve@overlap+mon": _cost(_MHSB_GEOM, 50, 201360640),
+    "multihost_sb/serve@overlap+mon": _cost(_MHSB_GEOM, 50, 201360672),
     # 2-D TATP (parallel/multihost.py, flat tuple-axis collectives):
     # replication traffic pre-dates wave scoping -> absolute bytes
     # ceiling like the pipeline targets, not a ledger multiple
@@ -1342,7 +1343,7 @@ TARGET_COST.update({
     "store/block@scan+pallas": _cost(_ST_GEOM, 32.5, 4077,
                                      bytes_budget=11700),
     "store/serve@scan": _cost(_ST_GEOM, 35.5, 4093, bytes_budget=11700),
-    "store/serve@scan+mon": _cost(_ST_GEOM, 36.5, 4245,
+    "store/serve@scan+mon": _cost(_ST_GEOM, 36.5, 4249,
                                   bytes_budget=11750),
     "store/rebuild@scan": _cost(_ST_GEOM, 5, 6122, steps=1.0,
                                 bytes_budget=1950),

@@ -651,6 +651,7 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                 # BEFORE the kernel aliases arb in place (read-before-
                 # donate, same as the unfused pallas route)
                 held = (db.arb[flat_ws] >> K_ARB) == (t - 1)
+                n_held = (active & held).sum(dtype=I32)
             arb, grant_u, vbad, rmeta_f = pg.lock_validate(
                 db.arb, meta, c1.rows.reshape(-1), c1.vv1.reshape(-1),
                 rows.reshape(-1), flat_ws, active, t, K_ARB,
@@ -734,6 +735,22 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
     # Candidates for held rows are masked OUT of the scatter so rejected
     # attempts cannot keep a hot row stamped (no livelock). On the fused
     # route the whole chain already ran inside lock_validate above.
+    # On the XLA route the three table ops issue the ACTIVE slots only
+    # (TATP's mix leaves ~11 % of the 2w active, and a dropped scatter
+    # index, like a sentinel gather lane, costs what a live one costs):
+    # the slots are ranked once and run C lanes a chunk, in two loops,
+    # because the winner of a row is known only when every chunk's
+    # scatter-max has landed. The first loop reads the held stamps from
+    # the arb it carries, not from db.arb (closing over db.arb as well
+    # would keep a second copy of the table alive): a row held at the
+    # start of the step (stamp t-1) has every candidate masked out in
+    # every chunk, so its word never changes within the step; a row not
+    # held can only have gained a stamp t from an earlier chunk, and
+    # (x >> K_ARB) == t - 1 is as false for that as for what it had. So
+    # held, cand and arb are those of the full-width chain, and a held
+    # row's word (stamp t-1) never equals a packed word (stamp t): the
+    # read-back needs no `cand &`. A cohort that writes in every slot pays
+    # 2w / C trips of each loop: the trade the install took (PR 30).
     with waves.part("tatp_dense", "ws_pick"):
         ws_vv = jnp.take_along_axis(rmeta, ws_lane, axis=1)
     if not use_fused:
@@ -752,6 +769,7 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                     # gather)
                     held = ((pg.gather_rows(db.arb, flat_ws, 1) >> K_ARB)
                             == (t - 1))
+                    n_held = (active & held).sum(dtype=I32)
                 # fused kernel pass: gather + stamp compare + first-lane-
                 # wins scatter-max + winner read-back in ONE launch, arb
                 # updated in place (bit-identical to the XLA chain below —
@@ -763,17 +781,47 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                     hot_n=hn if use_hotset else 0)
                 grant = (grant_u != 0).reshape(w, 2)
             else:
-                with waves.part("tatp_dense", "lock_read"):
-                    arb_old = db.arb[flat_ws]   # [2w]; sentinel never stamped
-                    held = (arb_old >> K_ARB) == (t - 1)
-                with waves.part("tatp_dense", "lock_scatter_max"):
-                    inv_slot = U32(2 * w - 1) - jnp.arange(2 * w, dtype=U32)
-                    packed = (t << K_ARB) | inv_slot
-                    cand = active & ~held
-                    arb = db.arb.at[jnp.where(cand, flat_ws, oob)].max(
-                        packed, mode="drop")
-                with waves.part("tatp_dense", "lock_readback"):
-                    grant = (cand & (arb[flat_ws] == packed)).reshape(w, 2)
+                with waves.part("tatp_dense", "lock_compact"):
+                    a_ranks, n_act = compact.live_ranks(active)
+                    a_chunk = compact.chunk_lanes(2 * w)
+
+                    def packed(lanes):
+                        # the slot's own id, so the first slot still wins
+                        return ((t << K_ARB)
+                                | (U32(2 * w - 1) - lanes.astype(U32)))
+
+                    def stamp_chunk(state, lanes, ok):
+                        arb, n_held, held = state
+                        rows_c = flat_ws[lanes]
+                        with waves.part("tatp_dense", "lock_read"):
+                            held_c = ok & ((arb[rows_c] >> K_ARB) == t - 1)
+                        with waves.part("tatp_dense", "lock_scatter_max"):
+                            arb = arb.at[
+                                jnp.where(ok & ~held_c, rows_c, oob)].max(
+                                packed(lanes), mode="drop")
+                        if ring is not None:
+                            # per lane only for the flight recorder
+                            held = held | compact.lanes_mask(
+                                lanes, held_c, 2 * w)
+                        return arb, n_held + held_c.sum(dtype=I32), held
+
+                    def grant_chunk(grant, lanes, ok):
+                        rows_c = flat_ws[lanes]
+                        with waves.part("tatp_dense", "lock_readback"):
+                            won_c = ok & (arb[rows_c] == packed(lanes))
+                        return grant | compact.lanes_mask(lanes, won_c, 2 * w)
+
+                    # (a drain's cohort is constants, its arb a shard's)
+                    no_lanes = compact.varying_like(
+                        jnp.zeros_like(active), db.arb)
+                    (arb, n_held, held), lock_chunks = compact.for_chunks(
+                        a_ranks, n_act, a_chunk, stamp_chunk,
+                        (db.arb,
+                         compact.varying_like(jnp.zeros_like(n_act), db.arb),
+                         None if ring is None else no_lanes))
+                    grant, _ = compact.for_chunks(
+                        a_ranks, n_act, a_chunk, grant_chunk, no_lanes)
+                    grant = grant.reshape(w, 2)
 
     with waves.part("tatp_dense", "classify"):
         # reply types: reads from the gather; write-slot GRANT/REJECT direct
@@ -806,7 +854,10 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                         hot_meta=hot_meta, hot_val=hot_val)
     if counters is not None:
         with waves.part("tatp_dense", "monitor"):
-            grant_l = grant.reshape(-1)
+            # a grant is an active slot on a row not held, so the two
+            # kinds of rejection are what is left of the requests
+            n_req = active.sum(dtype=I32)
+            n_grant = (active & grant.reshape(-1)).sum(dtype=I32)
             hot_ctrs = {}
             if use_hotset:
                 # partition accounting over the meta + magic gathers (the arb
@@ -850,12 +901,11 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                 mon.CTR_AB_MISSING: c2.ab_missing,
                 mon.CTR_AB_VALIDATE: c2.ab_validate,
                 mon.CTR_MAGIC_BAD: c2.magic_bad,
-                mon.CTR_LOCK_REQUESTS: active.sum(dtype=I32),
-                mon.CTR_LOCK_GRANTED: (active & grant_l).sum(dtype=I32),
-                mon.CTR_LOCK_REJECTED: (active & ~grant_l).sum(dtype=I32),
-                mon.CTR_LOCK_REJECT_HELD: (active & held).sum(dtype=I32),
-                mon.CTR_LOCK_REJECT_ARB:
-                    (active & ~held & ~grant_l).sum(dtype=I32),
+                mon.CTR_LOCK_REQUESTS: n_req,
+                mon.CTR_LOCK_GRANTED: n_grant,
+                mon.CTR_LOCK_REJECTED: n_req - n_grant,
+                mon.CTR_LOCK_REJECT_HELD: n_held,
+                mon.CTR_LOCK_REJECT_ARB: n_req - n_grant - n_held,
                 mon.CTR_VALIDATE_LANES: v_lanes,
                 mon.CTR_VALIDATE_FAILED: v_failed,
                 mon.CTR_INSTALL_WRITES: wmask.sum(dtype=I32),
@@ -865,6 +915,8 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                 **({mon.CTR_FUSED_DISPATCH: 1} if use_fused else {}),
                 **({} if use_fused or use_hotset
                    else {mon.CTR_INSTALL_CHUNKS: chunks}),
+                **({} if use_fused or use_pallas
+                   else {mon.CTR_LOCK_CHUNKS: lock_chunks}),
             })
             counters = mon.gauge_max(
                 counters, {mon.CTR_RING_HWM: logs.head.max()})

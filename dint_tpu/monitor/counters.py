@@ -163,6 +163,13 @@ _REGISTRY: tuple[tuple[str, str, str], ...] = (
      "step under TATP's mix, none for a step with nothing to write; "
      "install_writes / (C x install_chunks) is the fill share of the "
      "indices the scatters issue. 0 on the fused and hot-tier routes"),
+    ("lock_chunks", FLOW,
+     "lock-wave compaction (ops/compact.py): chunk trips of the dense "
+     "TATP lock wave's first loop (the second makes as many), C = "
+     "chunk_lanes(2w) lanes a trip. lock_chunks == sum over steps of "
+     "ceil(lock_requests / C); lock_requests / (C x lock_chunks) is the "
+     "fill share of the lanes the stamp gather, the scatter-max and the "
+     "winner read-back issue. 0 on the fused and pallas routes"),
 )
 
 ALL_NAMES: tuple[str, ...] = tuple(n for n, _, _ in _REGISTRY)
@@ -211,6 +218,7 @@ CTR_SCAN_REQUESTS = COUNTER_INDEX["scan_requests"]
 CTR_SCAN_ROWS = COUNTER_INDEX["scan_rows"]
 CTR_SCAN_DELTA_HITS = COUNTER_INDEX["scan_delta_hits"]
 CTR_INSTALL_CHUNKS = COUNTER_INDEX["install_chunks"]
+CTR_LOCK_CHUNKS = COUNTER_INDEX["lock_chunks"]
 
 # the subset defined with IDENTICAL semantics by the dense engines and
 # the generic sort-based pipelines: on the parity workloads

@@ -76,8 +76,9 @@ _REGISTRY: tuple[tuple[str, str, str, str | None], ...] = (
      "single-word lanes; absent when check_magic=False)", "w*k*4"),
     ("tatp_dense", "lock",
      "lock arbitration on the arb array: stamp gather + masked "
-     "scatter-max + winner gather-back (2w write slots; ONE fused kernel "
-     "pass on the pallas route)", "3*2*w*4"),
+     "scatter-max + winner gather-back (the active ones of the 2w write "
+     "slots, C lanes a chunk, priced at all 2w active; ONE fused kernel "
+     "pass over all 2w on the pallas route)", "3*2*w*4"),
     ("tatp_dense", "rebase",
      "arb stamp rebase (full elementwise pass, once per ~16k steps — "
      "amortizes to noise; bytes unmodeled: streaming elementwise, not "
@@ -380,11 +381,14 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
      "a chunk into the 1-D val array, with its flat index "
      "(val_scatter_ms.* reads this)"),
     ("tatp_dense", "lock", "lock_read",
-     "gather of the 2w write slots' arb stamps + the held compare"),
+     "gather of the active write slots' arb stamps, C lanes a chunk, + "
+     "the held compare"),
     ("tatp_dense", "lock", "lock_scatter_max",
-     "packed stamps + masked scatter-max into arb"),
+     "packed stamps + masked scatter-max of a chunk's candidates into "
+     "arb"),
     ("tatp_dense", "lock", "lock_readback",
-     "winner gather-back + the grant compare"),
+     "winner gather-back of a chunk's slots (second loop) + the grant "
+     "compare"),
     ("tatp_dense", None, "step_frame",
      "the step's frame: its key split and the step counter's increment"),
     ("tatp_dense", None, "addr",
@@ -439,6 +443,12 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
     ("smallbank_dense", None, "sb_ctx",
      "the new cohort's context (its outcome sums) and the step counter's "
      "increment"),
+    # --- lock-wave compaction (ops/compact.py), appended in PR 34 -------
+    ("tatp_dense", "lock", "lock_compact",
+     "the running count of active write slots (one 2w-element cumsum), "
+     "the wave's two chunk loops, each chunk's lane search (C x 2w "
+     "compares) and gather of row ids, the held count, and the winners' "
+     "way back to lane space (C x 2w compares, OR-ed over the chunk)"),
 )
 
 # keyed on the part's name alone: the scope is `part.<name>`, so two

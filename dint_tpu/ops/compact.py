@@ -1,4 +1,4 @@
-"""Write-set compaction: a masked scatter issues its live lanes only.
+"""Write-set compaction: a masked table op issues its live lanes only.
 
 Measured on v5e (PERF.md §6, PR 30): a scatter index routed out of bounds
 under ``mode="drop"`` costs what a live one costs (81 ns a value word, 87 a
@@ -8,7 +8,13 @@ scatter: each lane's turn among the live ones (``live_ranks``: one cumsum),
 then the scatter runs over fixed chunks of the live lanes, as many as the
 live count needs (``for_chunks``: a ``while_loop`` whose trip count the
 step itself observes: 0 trips when nothing is live, the whole width when
-everything is). All of it vector work. Tried on the chip and left
+everything is). All of it vector work. Two users: the install and the log
+append of dense TATP under the write mask (PR 30; ``tables/log.
+append_rep_live``), and its lock wave under the mask of active write
+slots (PR 34: ~11 % of the 2w; the stamp gather and the winner read-back
+are chunked with the scatter-max, since a gather lane on the sentinel row
+costs what a live one costs, and a chunk's verdicts go back to lane space
+through ``lanes_mask``). Tried on the chip and left
 (PERF.md §6, PR 30): one sort of the lane ids, 0.11 ms a step faster in
 ``tatp7m-sat``, but the protocol proofs read a sort as the generic
 engines' segment evidence (analysis/dataflow.py SORTED) and would have
@@ -65,3 +71,21 @@ def for_chunks(ranks, n_live, chunk: int, body, carry):
 
     trips, carry = jax.lax.while_loop(more, one, (jnp.asarray(0, I32), carry))
     return carry, trips
+
+
+def lanes_mask(lanes, on, r: int):
+    """bool [r]: the lanes of a chunk that ``on`` marks, back in lane space
+    (``lanes`` i32 [chunk] as a ``for_chunks`` body gets them): a chunk x r
+    compare OR-ed over the chunk, what the search costs. No scatter of
+    lane ids, no r-lane gather out of a compact buffer."""
+    return ((lanes[:, None] == jnp.arange(r, dtype=I32))
+            & on[:, None]).any(axis=0)
+
+
+def varying_like(x, ref):
+    """``x`` marked varying over the mesh axes ``ref`` varies over and it
+    does not (shard_map's types; ``x`` itself outside one): a loop's first
+    carry, born of constants, has to close over what the body makes of it
+    out of a sharded table."""
+    missing = tuple(jax.typeof(ref).vma - jax.typeof(x).vma)
+    return jax.lax.pcast(x, missing, to="varying") if missing else x

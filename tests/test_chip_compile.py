@@ -116,17 +116,29 @@ def test_tatp7m_populate_fits_one_chip(one_chip):
     assert ma.output_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
 
 
+def _count(shape: str) -> int:
+    return int(np.prod([int(d) for d in shape.split(",") if d]))
+
+
+def _shapes(hlo: str) -> dict:
+    return dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", hlo))
+
+
 def scatter_index_counts(hlo: str, table_words: int) -> list:
     """How many indices each native scatter into a u32[table_words] table
     issues (its index operand's element count), from compiled HLO text."""
-    shape_of = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", hlo))
-    counts = []
-    for idx in re.findall(
-            rf"u32\[{table_words}\]\S* scatter\(%[\w.\-]+, %([\w.\-]+),",
-            hlo):
-        counts.append(int(np.prod([int(d) for d in
-                                   shape_of[idx].split(",") if d])))
-    return counts
+    shape_of = _shapes(hlo)
+    return [_count(shape_of[idx]) for idx in re.findall(
+        rf"u32\[{table_words}\]\S* scatter\(%[\w.\-]+, %([\w.\-]+),", hlo)]
+
+
+def gather_lane_counts(hlo: str, table_words: int) -> list:
+    """How many lanes each native gather out of a u32[table_words] table
+    issues (its output's element count), from compiled HLO text."""
+    shape_of = _shapes(hlo)
+    return [_count(out) for out, table in re.findall(
+        r"%[\w.\-]+ = u32\[([\d,]*)\]\S* gather\(%([\w.\-]+),", hlo)
+        if shape_of.get(table) == str(table_words)]
 
 
 def test_tatp7m_block_program_fits_one_chip(one_chip):
@@ -141,7 +153,9 @@ def test_tatp7m_block_program_fits_one_chip(one_chip):
         assert ma.argument_size_in_bytes + ma.temp_size_in_bytes \
             < HBM_BYTES
         # the carry is donated: the tables update in place, through the
-        # chunk loops of the compacted install as well
+        # chunk loops of the compacted install and of the lock wave as
+        # well (its first loop carries arb and reads the held stamps from
+        # that carry: a second, closed-over arb would be a 0.6 GB copy)
         assert ma.alias_size_in_bytes > 0.99 * ma.argument_size_in_bytes
         assert ma.temp_size_in_bytes < 1e9
         # the install issues a chunk of the live write slots, not all 2w
@@ -149,11 +163,18 @@ def test_tatp7m_block_program_fits_one_chip(one_chip):
         chunk = compact.chunk_lanes(2 * W)
         assert chunk == 512
         hlo = c.as_text()
-        assert " sort(" not in hlo      # no sort: the proofs read one
+        # no sort: the proofs read one, and the compiler puts none before
+        # the lock wave's 512-index scatter-max (as it does before
+        # smallbank_dense's 24,576-index ones, below)
+        assert " sort(" not in hlo
         assert set(scatter_index_counts(hlo, N1 * VW)) == {chunk * VW}
-        # meta's install; the lock wave's scatter-max over arb, the same
-        # shape, still issues all 2w (ROADMAP Queue 1)
-        assert sorted(set(scatter_index_counts(hlo, N1))) == [chunk, 2 * W]
+        # meta's install, and the lock wave's scatter-max over arb (the
+        # same shape): a chunk of the active write slots, not all 2w
+        assert set(scatter_index_counts(hlo, N1)) == {chunk}
+        assert "part.lock_scatter_max/scatter-max" in hlo
+        # nor does a gather out of arb or meta issue 2w lanes: the stamp
+        # read and the winner read-back issue a chunk each
+        assert set(gather_lane_counts(hlo, N1)) == {chunk, 2 * W * K}
 
 
 def test_smallbank24m_block_program_fits_one_chip(one_chip):

@@ -136,22 +136,30 @@ def test_tatp_dense_reconciles_with_stats():
     assert snap["repl_push_hop1"] == 0      # single chip: no ICI pushes
 
 
+def _updates_only_one_cohort_a_block():
+    """(run, carry, drain, C) of dense TATP at 2w = 512 slots (C = 128)
+    under an update-only mix, one cohort a block: a window's delta is one
+    step's, and a step asks for, and writes, more than one chunk holds."""
+    from dint_tpu.engines import tatp_dense as td
+    from dint_tpu.ops import compact
+
+    chunk = compact.chunk_lanes(512)
+    assert chunk == 128
+    mix = np.array([0, 0, 0, 50, 50, 0, 0], np.float64) / 100.0
+    run, init, drain = td.build_pipelined_runner(
+        N_SUB * 8, w=256, val_words=VW, cohorts_per_block=1, mix=mix,
+        monitor=True)
+    carry = init(td.populate(np.random.default_rng(0), N_SUB * 8,
+                             val_words=VW))
+    return run, carry, drain, chunk
+
+
 def test_install_chunks_reconcile_with_install_writes_step_by_step():
     """Write-set compaction (ops/compact.py): a step's install loop makes
     ceil(install_writes / C) trips. One cohort a block, so a window's
     delta is one step's; an update-only mix, so that a step can write
     more than one chunk holds (2w = 512 slots, C = 128)."""
-    from dint_tpu.engines import tatp_dense as td
-    from dint_tpu.ops import compact
-
-    w, chunk = 256, compact.chunk_lanes(512)
-    assert chunk == 128
-    mix = np.array([0, 0, 0, 50, 50, 0, 0], np.float64) / 100.0
-    run, init, drain = td.build_pipelined_runner(
-        N_SUB * 8, w=w, val_words=VW, cohorts_per_block=1, mix=mix,
-        monitor=True)
-    carry = init(td.populate(np.random.default_rng(0), N_SUB * 8,
-                             val_words=VW))
+    run, carry, drain, chunk = _updates_only_one_cohort_a_block()
     prev, per_step = None, []
     for i in range(6):
         carry, _ = run(carry, jax.random.fold_in(KEY(0), i))
@@ -166,6 +174,28 @@ def test_install_chunks_reconcile_with_install_writes_step_by_step():
     d = mc.delta(M.snapshot(drain(carry)[2]), prev)     # two steps
     low = -(-d["install_writes"] // chunk)
     assert d["steps"] == 2 and low <= d["install_chunks"] <= low + 1
+
+
+def test_lock_chunks_reconcile_with_lock_requests_step_by_step():
+    """Lock-wave compaction: a step's first lock loop makes
+    ceil(lock_requests / C) trips (the second makes as many); the drain
+    generates no cohort, so it asks for nothing."""
+    run, carry, drain, chunk = _updates_only_one_cohort_a_block()
+    prev, per_step = None, []
+    for i in range(4):
+        carry, _ = run(carry, jax.random.fold_in(KEY(0), i))
+        snap = M.snapshot(carry[-1])
+        d = mc.delta(snap, prev)
+        prev = snap
+        assert d["steps"] == 1 and d["lock_requests"] > chunk
+        assert d["lock_chunks"] == -(-d["lock_requests"] // chunk)
+        assert d["lock_requests"] == d["lock_granted"] + d["lock_rejected"]
+        assert d["lock_rejected"] == \
+            d["lock_reject_held"] + d["lock_reject_arb"]
+        per_step.append(d["lock_chunks"])
+    assert min(per_step) >= 2           # more than one chunk's worth
+    d = mc.delta(M.snapshot(drain(carry)[2]), prev)     # two steps
+    assert d["steps"] == 2 and d["lock_requests"] == d["lock_chunks"] == 0
 
 
 def test_tatp_dense_monitoring_off_is_bit_identical():
@@ -195,9 +225,11 @@ def test_tatp_dense_counters_bit_identical_xla_vs_pallas():
     assert tot_x.tolist() == tot_p.tolist()
     assert a["dispatch_xla"] == b["dispatch_pallas"] == a["steps"]
     assert a["dispatch_pallas"] == b["dispatch_xla"] == 0
-    drop = ("dispatch_xla", "dispatch_pallas")
+    # lock_chunks: the fused lock kernel runs no chunk loop
+    drop = ("dispatch_xla", "dispatch_pallas", "lock_chunks")
     assert {k: v for k, v in a.items() if k not in drop} == \
         {k: v for k, v in b.items() if k not in drop}
+    assert b["lock_chunks"] == 0 < a["lock_chunks"]
 
 
 def _run_sb_dense(monitor, blocks=3, seed=1, use_pallas=False,
@@ -299,11 +331,12 @@ def test_fused_dispatch_counter_reconciles():
     assert fus_t["fused_dispatch"] == fus_t["steps"] == steps_t
     assert fus_t["dispatch_xla"] == steps_t  # the split stays total
     assert fus_t["dispatch_pallas"] == 0
-    # install_chunks: the megakernel route runs no chunk loop
-    drop = ("fused_dispatch", "install_chunks")
+    # install_chunks, lock_chunks: the megakernel route runs no chunk loop
+    drop = ("fused_dispatch", "install_chunks", "lock_chunks")
     assert {k: v for k, v in base_t.items() if k not in drop} == \
         {k: v for k, v in fus_t.items() if k not in drop}
     assert fus_t["install_chunks"] == 0 < base_t["install_chunks"]
+    assert fus_t["lock_chunks"] == 0 < base_t["lock_chunks"]
 
     _, tot_s, base_s = _run_sb_dense(True, blocks=blocks)
     _, tot_sf, fus_s = _run_sb_dense(True, blocks=blocks,

@@ -9,6 +9,8 @@ import pytest
 from dint_tpu.clients import tatp_client as tc
 from dint_tpu.engines import tatp, tatp_dense as td, tatp_pipeline as tp
 from dint_tpu.monitor import counters as mon
+from dint_tpu.monitor import txnevents as txe
+from dint_tpu.monitor import waves
 from dint_tpu.ops import compact
 from dint_tpu.tables import log as logring
 
@@ -343,6 +345,128 @@ def test_compacted_install_and_log_equal_full_width_reference(w, n_live):
     assert snap["install_chunks"] == -(-n_live // chunk)
 
 
+def _lock_wave(monkeypatch, w, n_sub, t, arb0, active, keys):
+    """One pipe_step at step ``t`` over the stamp table ``arb0`` whose
+    new cohort is forced: write slot j is ``active[j]`` and asks for row
+    ``keys[j]`` of the SUBSCRIBER table (base 0), no reads. Returns (arb',
+    granted [2w], held [2w] as the flight recorder saw it, counters)."""
+    ws = (jnp.asarray(active.reshape(w, 2)),
+          jnp.zeros((w, 2), jnp.int32), jnp.zeros((w, 2), jnp.int32),
+          jnp.asarray(keys.reshape(w, 2), jnp.int32),
+          jnp.zeros((w, 2), jnp.int32))
+    monkeypatch.setattr(
+        td, "gen_cohort", lambda key, w_, n_sub_, mix=None: (
+            jnp.zeros(w, jnp.int32), jnp.zeros((w, td.K), jnp.int32),
+            jnp.zeros((w, td.K), jnp.int32), jnp.zeros((w, td.K), jnp.int32),
+            ws))
+    cap = 8 * w
+    tcfg = txe.TraceCfg(rate=1.0, cap=cap,
+                        wave=waves.full_name("tatp_dense", "trace"))
+    db = td.create(n_sub, val_words=VW, log_capacity=1 << 8)
+    db = db.replace(arb=jnp.asarray(arb0), step=jnp.asarray(t, db.step.dtype))
+    got, ctx, _, _, cnt, ring = jax.jit(functools.partial(
+        td.pipe_step, w=w, n_sub=n_sub, val_words=VW, tcfg=tcfg))(
+        db, td.empty_ctx(w), td.empty_ctx(w), jax.random.PRNGKey(0),
+        counters=mon.create(), ring=txe.create_ring(cap))
+    ev = txe.decode(ring.buf, ring.head, cap)
+    ev = ev[(ev[:, 1] >> 24) == txe.EV_LOCK]
+    assert sorted(ev[:, 3].tolist()) == np.nonzero(active)[0].tolist()
+    aux = np.zeros(2 * w, np.uint32)
+    aux[ev[:, 3]] = ev[:, 1] & 0xFF
+    granted = np.asarray(ctx.granted).reshape(-1)
+    assert np.array_equal(granted, (aux & txe.LOCK_GRANTED) != 0)
+    return (np.asarray(got.arb), granted, (aux & txe.LOCK_HELD) != 0,
+            mon.snapshot(cnt))
+
+
+def _full_width_lock(w, n_sub, t, arb0, active, keys):
+    """The wave as it ran before it was compacted: a stamp gather, a
+    masked scatter-max and a winner read-back over all 2w slots."""
+    rows = np.where(active, keys, td.n_rows(n_sub))    # NOP: the sentinel
+    held = active & ((arb0[rows] >> td.K_ARB) == t - 1)
+    packed = ((np.uint32(t) << np.uint32(td.K_ARB))
+              | (2 * w - 1 - np.arange(2 * w)).astype(np.uint32))
+    cand = active & ~held
+    arb = arb0.copy()
+    np.maximum.at(arb, rows[cand], packed[cand])
+    return arb, cand & (arb[rows] == packed), held
+
+
+@pytest.mark.parametrize("w,n_active", [
+    (512, 0), (512, 1), (512, 127), (512, 128), (512, 129), (512, 1024),
+    (100, 150)], ids=lambda v: str(v))
+def test_compacted_lock_wave_equals_full_width_reference(
+        monkeypatch, w, n_active):
+    """C = 128 at both widths: 0, 1, C-1, C, C+1 and 2w active slots, and a
+    width that is no multiple of C. The requests fall on half as many
+    rows as there are requests (so that rows are fought over within a
+    chunk and across chunks), a third of them held from step t-1, the
+    rest bearing older stamps."""
+    n_sub, t, chunk = 2000, 40, compact.chunk_lanes(2 * w)
+    assert chunk == 128
+    rng = np.random.default_rng(n_active)
+    active = np.zeros(2 * w, bool)
+    active[rng.choice(2 * w, n_active, replace=False)] = True
+    pool = rng.choice(n_sub, max(4, n_active // 2), replace=False)
+    keys = rng.choice(pool, 2 * w)
+    arb0 = np.zeros(td.n_rows(n_sub) + 1, np.uint32)
+    arb0[pool] = (rng.integers(t - 5, t - 1, len(pool)) << td.K_ARB) \
+        | rng.integers(0, 2 * w, len(pool))
+    was_held = pool[: len(pool) // 3]
+    arb0[was_held] = ((t - 1) << td.K_ARB) | rng.integers(
+        0, 2 * w, len(was_held))
+
+    arb, granted, held, snap = _lock_wave(
+        monkeypatch, w, n_sub, t, arb0, active, keys)
+    want_arb, want_grant, want_held = _full_width_lock(
+        w, n_sub, t, arb0, active, keys)
+    assert np.array_equal(arb, want_arb)
+    assert np.array_equal(granted, want_grant)
+    assert np.array_equal(held, want_held)
+    if n_active > 8:
+        assert want_held.any() and want_grant.any() \
+            and (active & ~want_held & ~want_grant).any()
+    assert snap["lock_requests"] == n_active
+    assert snap["lock_granted"] == want_grant.sum()
+    assert snap["lock_rejected"] == n_active - want_grant.sum()
+    assert snap["lock_reject_held"] == want_held.sum()
+    assert snap["lock_reject_arb"] == \
+        (active & ~want_held & ~want_grant).sum()
+    assert snap["lock_chunks"] == -(-n_active // chunk)
+
+
+@pytest.mark.parametrize("held_before", [False, True],
+                         ids=["free_row", "held_row"])
+def test_one_row_requested_from_two_chunks(monkeypatch, held_before):
+    """Slots 3 and 299 of 300 active ask for row 7: their turns are 3 and
+    299, chunks 0 and 2 of 128. On a free row the first slot wins and the
+    later chunk's scatter-max cannot take the row from it; on a row held
+    from step t-1 both are rejected and the word stays as it was, so the
+    stamp expires and the row is free at t+1 (no livelock)."""
+    w, n_sub, t = 512, 2000, 40
+    active = np.arange(2 * w) < 300
+    keys = 100 + np.arange(2 * w)
+    keys[[3, 299]] = 7
+    arb0 = np.zeros(td.n_rows(n_sub) + 1, np.uint32)
+    arb0[7] = ((t - 1 if held_before else t - 2) << td.K_ARB) | 11
+    arb, granted, held, snap = _lock_wave(
+        monkeypatch, w, n_sub, t, arb0, active, keys)
+    assert snap["lock_chunks"] == 3
+    assert held[[3, 299]].tolist() == [held_before] * 2
+    if held_before:
+        assert granted[[3, 299]].tolist() == [False, False]
+        assert arb[7] == arb0[7]
+        assert snap["lock_reject_held"] == 2 and snap["lock_granted"] == 298
+        arb, granted, held, _ = _lock_wave(
+            monkeypatch, w, n_sub, t + 1, arb, active, keys)
+        assert not held[[3, 299]].any()
+    assert granted[[3, 299]].tolist() == [True, False]
+    step = t + 1 if held_before else t
+    assert arb[7] == (step << td.K_ARB) | (2 * w - 1 - 3)
+    # (at t+1 the 298 other rows are held by the grants of step t)
+    assert granted.sum() == (1 if held_before else 299)
+
+
 @pytest.mark.parametrize("r,p,chunk", [
     (1, 1.0, 1), (7, 0.5, 4), (256, 0.0, 128), (256, 0.1, 128),
     (256, 0.6, 128), (256, 1.0, 128), (200, 0.9, 128)],
@@ -421,3 +545,7 @@ def test_write_heavy_mix_runs_several_chunks_and_matches_generic_engine():
     assert snap["install_chunks"] >= 2 * writing_steps
     assert snap["install_chunks"] <= \
         -(-snap["install_writes"] // chunk) + writing_steps
+    # every generating step asks for more locks than a chunk holds
+    assert snap["lock_requests"] > blocks * 2 * chunk
+    assert 2 * blocks * 2 <= snap["lock_chunks"] <= \
+        -(-snap["lock_requests"] // chunk) + blocks * 2
