@@ -94,26 +94,18 @@ def main():
     # default) the engines run the untraced jaxpr and the artifact
     # records dinttrace: null.
     trace_on = os.environ.get("DINT_TRACE") == "1"
-    # DINT_USE_PALLAS=1 routes the step's random-access hot ops through the
-    # DMA-ring kernels (ops/pallas_gather); a kernel that was asked for and
-    # that Mosaic refuses raises (pg.KernelRefused) — it never becomes the
-    # XLA route under the kernel's name
-    from dint_tpu.ops import pallas_gather as pg
+    from dint_tpu.ops import hotset
 
     # plan-resolved knobs replace the env-flag default path (ISSUE 17):
-    # the pinned PLAN.json decides use_pallas / use_hotset / use_fused for
-    # the headline config; ambient DINT_* flags win only under
+    # the pinned PLAN.json decides use_hotset for the headline config; ambient DINT_* flags win only under
     # DINT_PLAN_OVERRIDE=1 and the artifact records which knobs the
     # override changed. Without a readable plan, behaviour is exactly the
     # old env resolution and the artifact records "plan": null.
     plan_knobs, plan_meta = _plan_resolve("tatp_uniform")
-    use_pallas = pg.resolve_use_pallas(
-        plan_knobs.get("use_pallas") if plan_meta else None,
-        n_idx=2 * WIDTH * td.K, m_lock=2 * WIDTH, k_arb=td.K_ARB)
-    plan_kw = {k: plan_knobs[k] for k in ("use_hotset", "use_fused")
+    plan_kw = {k: plan_knobs[k] for k in ("use_hotset",)
                if k in plan_knobs} if plan_meta else {}
 
-    def build_and_warm(use_pallas):
+    def build_and_warm():
         t0 = _time.time()
         # on-device populate: at 7M subscribers the val array is ~6.2 GB —
         # generate it in HBM instead of building it in host numpy
@@ -122,7 +114,7 @@ def main():
         run, init, drain = td.build_pipelined_runner(
             N_SUBSCRIBERS, w=WIDTH, val_words=VAL_WORDS,
             cohorts_per_block=BLOCK, check_magic=check_magic,
-            use_pallas=use_pallas, monitor=monitor_on, trace=trace_on,
+            monitor=monitor_on, trace=trace_on,
             **plan_kw)
         carry = init(db)
         populate_s = _time.time() - t0
@@ -139,7 +131,7 @@ def main():
             init.trace_cfg
 
     (run, drain, carry, stats0,
-     populate_s, compile_s, trace_cfg) = build_and_warm(use_pallas)
+     populate_s, compile_s, trace_cfg) = build_and_warm()
 
     # dintmon drain loop: per-block wave events when a JSONL path is set
     # (the per-block counter fetch synchronizes the stream — an accepted
@@ -151,8 +143,7 @@ def main():
         jsonl = os.environ.get("DINT_MONITOR_JSONL")
         writer = dm.TraceWriter(jsonl, meta={
             "name": "bench_tatp", "width": WIDTH, "block": BLOCK,
-            "n_subscribers": N_SUBSCRIBERS,
-            "use_pallas": bool(use_pallas)}) if jsonl else None
+            "n_subscribers": N_SUBSCRIBERS}) if jsonl else None
         monitor_obj = dm.Monitor(writer)
         if writer is not None:
             bare_run, t_prev = run, [_time.time()]
@@ -267,7 +258,7 @@ def main():
             "tatp_dense", N_SUBSCRIBERS,
             cfg=ControllerCfg(widths=(WIDTH,)),
             cohorts_per_block=BLOCK, val_words=VAL_WORDS,
-            monitor=True, runner_kw={"use_pallas": use_pallas})
+            monitor=True)
         s_eng.warmup()
         s_eng.run(np.zeros(WIDTH * BLOCK * 8))
         s_eng.close()
@@ -317,13 +308,10 @@ def main():
         # {n_hosts, n_ici, axes} parsed from DINT_BENCH_MESH
         "n_shards": None,
         "mesh": None,
-        # which random-access backend ran — A/B artifacts must be
-        # distinguishable
-        "use_pallas": bool(use_pallas),
         # dintcache hot tier + skew provenance (TATP itself keeps the hot
         # tier off — uniform NURand; the flag records the env so the
         # SmallBank leg's A/B state is readable from the headline line)
-        "use_hotset": pg.env_use_hotset(),
+        "use_hotset": hotset.env_use_hotset(),
         "hot_frac": HOT_FRAC,
         "hot_prob": HOT_PROB,
         # which pinned plan resolved the build knobs, schema-stable:

@@ -203,7 +203,7 @@ def phase_tatp(size: Size, seed: int, snapshot: bool):
 
     run, init, drain = td.build_pipelined_runner(
         n_sub, w=w, val_words=VAL_WORDS, cohorts_per_block=cpb,
-        monitor=True, use_pallas=False, use_fused=False, trace=False)
+        monitor=True, trace=False)
     carry = init(db)
     del db
     run_x, run_s, run_hit = compile_timed(run, carry, key)
@@ -268,7 +268,7 @@ def compare_small(seed: int) -> None:
     fresh = jax.tree.map(np.array, db0)      # the runner donates db0
     run_d, init_d, drain_d = td.build_pipelined_runner(
         n_sub, w=w, val_words=VAL_WORDS, cohorts_per_block=cpb,
-        use_pallas=False, use_fused=False, trace=False)
+        trace=False)
     (db, _), tot_d, _ = drive(run_d, drain_d, init_d(db0), key, blocks)
 
     shards, _ = tc.populate_shards(np.random.default_rng(seed), n_sub,
@@ -323,7 +323,7 @@ def phase_sharded(size: Size, seed: int, devices):
 
     run, init, drain = ds.build_sharded_pipelined_runner(
         mesh, n, n_sub, w=w, val_words=VAL_WORDS, cohorts_per_block=cpb,
-        monitor=True, use_pallas=False, use_fused=False)
+        monitor=True)
     carry = init(state)
     del state
     t0 = time.perf_counter()

@@ -17,17 +17,14 @@ traversal discipline as analysis/dataflow.py — and derive three numbers:
   from persistent state counts its output bytes (random row reads);
   every scatter-family eqn over state counts its update bytes (row
   writes); `ppermute`/`all_to_all` count their operand bytes once (the
-  ICI move — the same convention the waves.py formulas use); Pallas
-  kernels are costed by per-kernel rules keyed on the kernel name
-  (ops/pallas_gather calling conventions, listed in _pallas_bytes).
+  ICI move — the same convention the waves.py formulas use); a Pallas
+  kernel counts the rows it produces (_pallas_bytes).
   Elementwise/VPU traffic is deliberately NOT modeled — formulas and
   derivation both measure the random-access row traffic that dominates
   the engines (PERF.md round 3), not XLA padding or fusion residue.
 * **Dispatch count per step.** One per counted gather/scatter site, one
   per collective, one per `pallas_call` — the length of the dependency
-  chain of non-fusable memory ops, the quantity the round-12 megakernels
-  exist to shrink (~6 -> ~4; passes/cost_budget.py proves the fused
-  targets dominate their unfused twins on exactly this number).
+  chain of non-fusable memory ops.
 * **Persistent footprint.** Input bytes of the jitted step plus every
   output buffer NOT matched (shape+dtype) to a donated input — the
   donation-aware live-state size. Dropping a `donate_argnums` doubles
@@ -246,62 +243,18 @@ class CostModel:
         }
 
 
-# ------------------------------------------------- per-kernel byte rules
-#
-# Pallas kernels move their traffic inside one dispatch; the jaxpr only
-# shows the call, so bytes come from the calling conventions in
-# ops/pallas_gather.py (matched on the kernel name exactly like
-# dataflow._kernel_name). Each rule reproduces the logical row traffic
-# of the XLA chain the kernel replaces — that is the invariant the
-# kernels themselves pin (bit-identical outputs), so the rules cannot
-# drift without the kernel contract drifting too.
-
-
-def _kernel_name(eqn) -> str:
-    return str(eqn.params.get("name") or "")
+# ----------------------------------------------------- kernel byte rule
 
 
 def _pallas_bytes(eqn) -> float:
-    name = _kernel_name(eqn)
-    ins, outs = eqn.invars, eqn.outvars
+    """A Pallas kernel moves its traffic inside one dispatch; the jaxpr
+    only shows the call. Non-aliased outputs are rows the kernel
+    produced; aliased outputs are in-place updates whose row traffic the
+    call does not show."""
     aliases = dict(eqn.params.get("input_output_aliases") or {})
-    if "lock_validate" in name:
-        # (arb', grant[m], vbad[v], rmeta[r]): 3 arb passes (gather +
-        # scatter-max + gather-back) over m lanes + v validate-read +
-        # r fresh-meta-read words, 4 B each — waves.py lock_validate.
-        m = _aval_size(outs[1]) if len(outs) > 1 else 0
-        v = _aval_size(outs[2]) if len(outs) > 2 else 0
-        r = _aval_size(outs[3]) if len(outs) > 3 else 0
-        return float(4 * (3 * m + v + r))
-    if "arbitrate" in name:
-        # (arb', grant[m]): the 3-pass RMW over m lanes — waves.py lock.
-        m = _aval_size(outs[1]) if len(outs) > 1 else 0
-        return float(4 * 3 * m)
-    if "scatter_streams" in name:
-        # S idx arrays, S value arrays, S aliased tables: each stream
-        # writes its value array's rows.
-        s_n = len(aliases)
-        if s_n and len(ins) >= 3 * s_n:
-            return float(sum(_aval_bytes(v.aval)
-                             for v in ins[s_n:2 * s_n]))
-        return 0.0
-    if "gather_streams" in name:
-        return float(sum(_aval_bytes(o.aval) for o in outs))
-    if "scatter" in name:
-        # single-target row scatter (scatter_rows / scatter_rows_hot /
-        # hot_scatter): vals operand = the non-index, non-aliased input
-        # matching no output alias; conservatively the largest
-        # non-aliased input that is smaller than the table.
-        aliased_in = set(int(i) for i in aliases)
-        cands = [_aval_bytes(v.aval) for i, v in enumerate(ins)
-                 if i not in aliased_in]
-        cands = [c for c in cands if c > 0]
-        return float(max(cands)) if cands else 0.0
-    # gather-family kernels (gather_rows / gather_rows_hot / hot_gather):
-    # non-aliased outputs are the gathered rows; aliased outputs are
-    # in-place mirror refreshes (bulk sequential DMA, not row traffic).
     aliased_out = set(int(v) for v in aliases.values())
-    return float(sum(_aval_bytes(o.aval) for i, o in enumerate(outs)
+    return float(sum(_aval_bytes(o.aval)
+                     for i, o in enumerate(eqn.outvars)
                      if i not in aliased_out))
 
 
@@ -588,7 +541,7 @@ def model_for(name: str, trace: TargetTrace | None = None) -> CostModel:
 
 @dataclasses.dataclass
 class WaveCheck:
-    """One wave's derived-vs-declared comparison (after fused-group
+    """One wave's derived-vs-declared comparison (after alias-group
     folding and wave_expect adjustment)."""
     wave: str                   # the formula-bearing wave name
     members: tuple[str, ...]    # observed waves folded into it
@@ -627,11 +580,10 @@ def reconcile(model: CostModel,
               tol_overrides: dict[str, float] | None = None,
               default_tol: float = DEFAULT_TOL) -> list[WaveCheck]:
     """Compare the derived per-wave bytes against every declared waves.py
-    formula the target exercises. Fused megakernel waves absorb their
-    swallowed constituents first (attrib.WAVE_ALIASES — the same folding
-    dintscope uses for fused-vs-unfused A/Bs), so residual unfused scopes
-    (e.g. SmallBank's XLA scatter-mins) reconcile against the group
-    formula, not their pre-fusion one. `wave_expect` carries the target's
+    formula the target exercises. A wave that another scope takes over
+    on one route folds into its successor first (attrib.WAVE_ALIASES —
+    the same folding dintscope uses for its A/Bs), so it reconciles
+    against the group formula. `wave_expect` carries the target's
     declared layout deviations from the base formula (targets.py cost=):
     derived is compared against the ADJUSTED expectation."""
     tols = tol_overrides or {}
@@ -701,17 +653,6 @@ def ledger_bytes(model: CostModel,
     wave_expect adjustment): the budget formulas' `ledger` variable."""
     return float(sum(c.declared
                      for c in reconcile(model, wave_expect=wave_expect)))
-
-
-def fused_twin(name: str) -> str | None:
-    """The unfused registry twin of an @fused target (dominance check)."""
-    if "@fused" not in name:
-        return None
-    for a, b in (("@fused+hot", "@hot"), ("@fused+mon", "@mon"),
-                 ("@fused", "")):
-        if a in name:
-            return name.replace(a, b)
-    return None
 
 
 def iter_models(names: Iterable[str]) -> Iterable[CostModel]:

@@ -37,8 +37,7 @@ Facts (a small powerset lattice, may-analysis: a fact on a value means
     LOCK_WIN    data-dependent on winning lock arbitration. Seeded at
                 eq/ne compares with an ARB-carrying input (the batched-
                 CAS grant compare `arb' == packed` / `first_x[slot] ==
-                lane` / the expiring-stamp held test) and at the outputs
-                of the `lock_arbitrate` Pallas kernel.
+                lane` / the expiring-stamp held test).
     VALIDATED   data-dependent on an OCC stamp-equality check. Seeded at
                 eq/ne compares where an input carries TBL_READ, no input
                 carries ARB (that is lock arbitration, not validation),
@@ -65,9 +64,8 @@ Facts (a small powerset lattice, may-analysis: a fact on a value means
                 machinery. Seeded at `rem` eqns whose source site lies in
                 tables/log.py — the `pos % capacity` of `append`/
                 `plan_rep` — so any scatter whose INDICES carry LOG_SLOT
-                is a log append, on the XLA route (append/append_rep),
-                the forwarded-backup route (_apply_backup), and the fused
-                route (plan_rep's `flat` rides into scatter_streams).
+                is a log append (append/append_rep) or the
+                forwarded-backup route (_apply_backup).
     LOGGED      (protocol) written by a log-append scatter: seeded at
                 scatter eqns whose index operand carries LOG_SLOT. The
                 wal-order check pairs these appends against the
@@ -93,8 +91,7 @@ collectives) are recorded only on phase 2's final converged pass.
 
 The result (`Dataflow`) is an inventory the protocol pass consumes:
 per-scatter fact summaries with operand roots (which persistent array a
-scatter chain writes), seed sites, ppermute sites, and detected Pallas
-lock kernels. `analyze()` memoizes per TargetTrace, so the full target
+scatter chain writes), seed sites and ppermute sites. `analyze()` memoizes per TargetTrace, so the full target
 matrix pays one dataflow per trace however many checks read it.
 """
 from __future__ import annotations
@@ -188,7 +185,6 @@ class ScatterRec:
     idx_nonconst: bool             # indices are a traced (non-const) value
     idx_rows: int = 0              # index batch width (0 = unknown)
     trips: float = 1.0             # product of enclosing scan lengths
-    fused: bool = False            # synthetic scatter_streams record
     unique_indices: bool = False   # the eqn's uniqueness certification
 
     @property
@@ -216,7 +212,6 @@ class Dataflow:
     seeds: list[SeedSite]
     scatters: list[ScatterRec]
     ppermutes: list[SeedSite]          # fact == REPL_PUSHED sites
-    pallas_locks: list[SeedSite]       # detected lock_arbitrate calls
     perms: list[PermRec] = dataclasses.field(default_factory=list)
     # the vars that ARE persistent state (phase-1 STATE provenance): a
     # gather out of one is a table read, out of anything else it is not
@@ -227,7 +222,7 @@ class Dataflow:
 
     def log_appends(self) -> list[ScatterRec]:
         """Scatters whose indices descend from the log slot math — the
-        LOGGED sites, fused and unfused routes alike."""
+        LOGGED sites."""
         return [r for r in self.scatters if LOG_SLOT in r.index_facts]
 
     def quorum_dests(self) -> dict[int, set[int]]:
@@ -288,7 +283,6 @@ class _Analyzer:
         self._scatters: dict = {}           # id(eqn) -> ScatterRec
         self._ppermutes: dict = {}
         self._perms: dict = {}              # id(eqn) -> PermRec
-        self._pallas: dict = {}
         self._mult = 1.0                    # product of enclosing scan trips
 
     # -- env helpers ------------------------------------------------------
@@ -340,7 +334,6 @@ class _Analyzer:
             seeds=list(self._seeds.values()),
             scatters=list(self._scatters.values()),
             ppermutes=list(self._ppermutes.values()),
-            pallas_locks=list(self._pallas.values()),
             perms=list(self._perms.values()),
             state_vars=frozenset(v for v, fs in self.prov.items()
                                  if STATE in fs))
@@ -386,7 +379,7 @@ class _Analyzer:
             if sub is not None and len(sub.invars) == len(eqn.invars):
                 return self._call(eqn, sub, path + (prim,), in_pallas)
         if prim == "pallas_call":
-            return self._pallas_call(eqn, defs, path)
+            return self._pallas_call(eqn)
         if prim in _CALL_PRIMS:
             sub = _sub_jaxpr(eqn.params.get("jaxpr")
                              or eqn.params.get("call_jaxpr"))
@@ -488,127 +481,11 @@ class _Analyzer:
 
     # -- pallas -----------------------------------------------------------
 
-    @staticmethod
-    def _kernel_name(eqn) -> str:
-        return str(eqn.params.get("name") or "")
-
-    def _pallas_lock_kernel(self, eqn) -> bool:
-        """The fused lock pass (ops/pallas_gather.lock_arbitrate): named
-        after its kernel, or recognizable as an aliased kernel whose body
-        unpacks stamps with shifts (the gather kernel has neither). The
-        round-12 stream kernels are explicitly NOT lock kernels — their
-        aliased outputs are installs (and lock_validate has a dedicated
-        handler before this one runs)."""
-        name = self._kernel_name(eqn)
-        if "scatter_streams" in name or "gather_streams" in name:
-            return False
-        if "arbitrate" in name:
-            return True
-        aliases = eqn.params.get("input_output_aliases") or ()
-        if not aliases:
-            return False
-        sub = _sub_jaxpr(eqn.params.get("jaxpr"))
-        if sub is None:
-            return False
-        stack, seen = [sub], 0
-        while stack and seen < 4000:
-            j = stack.pop()
-            for ie in j.eqns:
-                seen += 1
-                if ie.primitive.name in ("shift_right_logical",
-                                         "shift_left"):
-                    return True
-                for v in ie.params.values():
-                    s = _sub_jaxpr(v)
-                    if s is not None:
-                        stack.append(s)
-        return False
-
-    def _pallas_lock_validate(self, eqn, path):
-        """The round-12 lock_validate megakernel (ops/pallas_gather):
-        operands = 6 scalar-prefetch args (vidx, vv1, ridx, rows, active,
-        step) + meta + arb (aliased to out 0); outputs = (arb', grant,
-        vbad, rmeta). The kernel is BOTH the lock-arbitration RMW and the
-        OCC validate read, so its outputs carry split roles: the arb-side
-        outputs keep the lock character (grant seeds LOCK_WIN exactly
-        like lock_arbitrate's) while the meta-read outputs are table
-        reads — and the in-kernel verdict means the validate compare the
-        protocol pass needs no longer exists as an XLA eqn, so vbad
-        seeds VALIDATED here directly."""
+    def _pallas_call(self, eqn):
         merged = set()
         for a in eqn.invars:
             merged |= self.facts(a)
         merged.discard(STATE)
-        aliases = dict(eqn.params.get("input_output_aliases") or {})
-        state_in = [STATE in self.pfacts(a) for a in eqn.invars]
-        if not self.protocol_phase:
-            arb_side = (merged | {ARB})
-            read_side = (merged - {ARB}) | (
-                {TBL_READ} if any(state_in) else set())
-            for oi, ov in enumerate(eqn.outvars):
-                fs = set(arb_side if oi in (0, 1) else read_side)
-                for ii, out_idx in aliases.items():
-                    if int(out_idx) == oi and 0 <= int(ii) < len(state_in) \
-                            and state_in[int(ii)]:
-                        fs.add(STATE)   # in-place arb RMW
-                self.bind(ov, fs)
-            return
-        if self.recording:
-            self._pallas[id(eqn)] = SeedSite(
-                LOCK_WIN, "pallas_call", site_of(eqn), path)
-            self._seeds[(VALIDATED, id(eqn))] = SeedSite(
-                VALIDATED, "pallas_call", site_of(eqn), path)
-        for oi, ov in enumerate(eqn.outvars):
-            fs = set(merged)
-            if oi in (0, 1):
-                fs.add(LOCK_WIN)
-            if oi == 2:
-                fs.add(VALIDATED)
-            self.bind(ov, fs)
-
-    def _record_scatter_streams(self, eqn, defs, path):
-        """Record the round-12 install_log megakernel's aliased streams
-        as synthetic ScatterRecs — one per (idx, vals, tab) triple — so
-        the protocol pass sees the fused installs on the same terms as
-        the unfused 1-D unique-index scatters they replace. Operand
-        layout (ops/pallas_gather.scatter_streams): S scalar-prefetch
-        index arrays, S value arrays, S aliased tables; masked lanes ride
-        idx = -1, so the mask facts arrive via index_facts exactly like
-        the unfused `where(mask, idx, oob)` routing."""
-        aliases = dict(eqn.params.get("input_output_aliases") or {})
-        s_n = len(aliases)
-        ins = eqn.invars
-        if not s_n or len(ins) < 3 * s_n:
-            return
-        for s in range(s_n):
-            idx, vals, tab = ins[s], ins[s_n + s], ins[2 * s_n + s]
-            shp = getattr(idx.aval, "shape", ())
-            self._scatters[(id(eqn), s)] = ScatterRec(
-                prim="scatter", site=site_of(eqn), path=path,
-                in_pallas=False,
-                is_state=STATE in self.pfacts(tab),
-                operand_facts=frozenset(self.allfacts(tab)),
-                index_facts=frozenset(self.allfacts(idx)),
-                update_facts=frozenset(self.allfacts(vals)),
-                root=self._operand_root(tab, defs),
-                idx_nonconst=not self.is_const(idx),
-                idx_rows=int(shp[0]) if shp else 1, trips=self._mult,
-                fused=True, unique_indices=True)
-
-    def _pallas_call(self, eqn, defs, path):
-        name = self._kernel_name(eqn)
-        if "lock_validate" in name:
-            return self._pallas_lock_validate(eqn, path)
-        if "scatter_streams" in name and self.recording:
-            self._record_scatter_streams(eqn, defs, path)
-            # fall through: the generic aliased-non-lock transfer below
-            # already binds the outputs correctly (ARB killed, STATE
-            # forwarded through the aliases)
-        merged = set()
-        for a in eqn.invars:
-            merged |= self.facts(a)
-        merged.discard(STATE)
-        is_lock = self._pallas_lock_kernel(eqn)
         aliases = dict(eqn.params.get("input_output_aliases") or {})
         state_in = [STATE in self.pfacts(a) for a in eqn.invars]
         if not self.protocol_phase:
@@ -616,23 +493,14 @@ class _Analyzer:
             # are table reads on the same terms as an XLA gather
             if any(state_in):
                 merged.add(TBL_READ)
-            if is_lock:
-                merged.add(ARB)
-            elif aliases:
-                # an aliased NON-lock kernel is an in-place overwrite
-                # install (ops/pallas_gather.scatter_rows_hot): it kills
-                # the arb character of the buffer exactly like an XLA
-                # overwrite scatter — otherwise ARB picked up from a
+            if aliases:
+                # an aliased kernel is an in-place overwrite install: it
+                # kills the arb character of the buffer exactly like an
+                # XLA overwrite scatter — otherwise ARB picked up from a
                 # grant-derived mask would ride the installed table
                 # around the carry and turn the next validate compare
                 # into a spurious LOCK_WIN seed
                 merged.discard(ARB)
-        else:
-            if is_lock:
-                merged.add(LOCK_WIN)
-                if self.recording:
-                    self._pallas[id(eqn)] = SeedSite(
-                        LOCK_WIN, "pallas_call", site_of(eqn), path)
         for oi, ov in enumerate(eqn.outvars):
             fs = set(merged)
             if not self.protocol_phase:
@@ -696,8 +564,7 @@ class _Analyzer:
                 extra.add(SORTED)
             elif prim == "rem":
                 # the slot math of tables/log.append / plan_rep: anything
-                # this feeds (the flat row ids, fused or unfused) is log-
-                # append indexing. Monotone (site test is constant), so
+                # this feeds (the flat row ids) is log-append indexing. Monotone (site test is constant), so
                 # safe inside the phase-1 fixpoint.
                 if _LOG_MODULE in site_of(eqn):
                     extra.add(LOG_SLOT)

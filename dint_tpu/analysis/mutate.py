@@ -287,8 +287,8 @@ def _install_sites(flow: df.Dataflow) -> set[str]:
 
 
 def _log_sites(flow: df.Dataflow) -> dict[str, object]:
-    """site -> root for the unfused log-append scatters."""
-    return {r.site: r.root for r in flow.log_appends() if not r.fused}
+    """site -> root for the log-append scatters."""
+    return {r.site: r.root for r in flow.log_appends()}
 
 
 def _find_drop_eqn(trace, flow):
@@ -496,7 +496,7 @@ def _find_drop_donation(trace, flow):
 
 
 def _find_ring_shrink(trace, flow):
-    """Shrink the log ring array feeding an unfused append to 2 slots (in
+    """Shrink the log ring array feeding an append to 2 slots (in
     the append's ENCLOSING jaxpr — the ring root there is the scan-body
     carry var, resolved exactly like dataflow's _operand_root)."""
     logs = _log_sites(flow)

@@ -13,9 +13,8 @@ walking machinery and ANALYSIS.md for the invariant catalogue):
                      abort-implies-unlock / commit-after-replication,
                      proven by the dataflow layer (analysis/dataflow.py)
   cost_budget        derived bytes/dispatches/footprint reconcile with
-                     the waves.py ledger, stay under the registered
-                     budgets, and @fused dominates its unfused twin
-                     (analysis/cost.py — the dintcost gate)
+                     the waves.py ledger and stay under the registered
+                     budgets (analysis/cost.py — the dintcost gate)
   durability         log-before-visible, replica quorum on distinct
                      fault domains, bounded rings, replay coverage,
                      in-doubt totality (analysis/dataflow.py's LOGGED/

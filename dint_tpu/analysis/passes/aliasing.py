@@ -1,9 +1,8 @@
 """Aliasing/donation pass: in-place buffers must really be dead.
 
 Two machineries update tables in place: Pallas kernels with
-``input_output_aliases`` (ops/pallas_gather.lock_arbitrate donates the
-0.6 GB arb array) and jitted steps with ``donate_argnums`` (every runner
-donates its carry so HBM tables update in place). Both are unchecked
+``input_output_aliases`` and jitted steps with ``donate_argnums`` (every
+runner donates its carry so HBM tables update in place). Both are unchecked
 promises at the JAX level on the paths we care about: read the donated
 buffer after the call and you observe torn state — exactly the
 use-after-free class the reference avoids by construction with its

@@ -11,18 +11,12 @@ pass fails closed on three checks (ANALYSIS.md "Static cost model"):
                           the hand ledger and the code disagree, one of
                           them rotted
   over-dispatch-budget    more memory-op dispatches per step than the
-                          target's registered budget: an extra unfused
+                          target's registered budget: an extra
                           gather/scatter slipped into the chain
   over-bytes-budget       derived bytes/step above the budget formula
                           (typically "1.25*ledger"): doubled traffic
   over-footprint-budget   donation-aware live state grew past budget: a
                           dropped donate_argnums doubles a table
-  fused-dispatch-dominance  an @fused target no longer strictly beats
-                          its unfused twin on dispatches/step — the
-                          megakernels' whole reason to exist
-  fused-bytes-dominance   an @fused target moves >5% more bytes than its
-                          twin (the 5% rides the counter-plane deltas:
-                          held-stamp pre-read + fused_dispatch bump)
   hier-dcn-dominance      a hierarchical 2-D mesh target no longer
                           schedules STRICTLY fewer DCN-axis link bytes
                           per step than its flat tuple-axis collective
@@ -61,11 +55,6 @@ from .. import cost
 from ..core import (Finding, SEV_ERROR, SEV_WARNING, TargetTrace,
                     register_pass)
 
-# fused targets may exceed their twin's bytes by this much: the
-# monitored variants pay the held-stamp pre-read + fused_dispatch
-# counter bump (~3% at lint geometry), which buys the dispatch win
-DOM_BYTES_EPS = 0.05
-
 
 def _budget_findings(trace: TargetTrace, meta: dict,
                      model: cost.CostModel) -> list[Finding]:
@@ -79,7 +68,7 @@ def _budget_findings(trace: TargetTrace, meta: dict,
         out.append(Finding(
             "cost_budget", "over-dispatch-budget", SEV_ERROR, trace.name,
             f"{disp:g} memory-op dispatches/step, budget {b_disp:g}: an "
-            "extra unfused gather/scatter/collective entered the chain",
+            "extra gather/scatter/collective entered the chain",
             site="(per-step)",
             suggestion="fuse the new op into an existing wave or "
                        "recalibrate the budget in targets.TARGET_COST "
@@ -131,45 +120,6 @@ def _reconcile_findings(trace: TargetTrace, meta: dict,
                        "is right, or the code if the ledger is; document "
                        "a real layout deviation as wave_expect in "
                        "targets.TARGET_COST"))
-    return out
-
-
-def _dominance_findings(trace: TargetTrace,
-                        model: cost.CostModel) -> list[Finding]:
-    twin = cost.fused_twin(trace.name)
-    if not twin:
-        return []
-    from .. import targets as T
-    if twin not in T.TARGETS:
-        return []
-    try:
-        twin_model = cost.model_for(twin)
-    except Exception:  # noqa: BLE001 — twin untraceable here (topology)
-        return []
-    if twin_model.error:
-        return []
-    out: list[Finding] = []
-    d, dt = model.dispatches_per_step, twin_model.dispatches_per_step
-    if d >= dt:
-        out.append(Finding(
-            "cost_budget", "fused-dispatch-dominance", SEV_ERROR,
-            trace.name,
-            f"{d:g} dispatches/step vs unfused twin {twin} at {dt:g}: "
-            "the megakernels no longer shrink the dispatch chain",
-            site=twin,
-            suggestion="a wave fell out of the fused kernels — diff "
-                       f"`tools/dintcost.py report {trace.name}` against "
-                       f"the twin"))
-    b, bt = model.bytes_per_step, twin_model.bytes_per_step
-    if b > bt * (1.0 + DOM_BYTES_EPS):
-        out.append(Finding(
-            "cost_budget", "fused-bytes-dominance", SEV_ERROR, trace.name,
-            f"{b:g} B/step vs unfused twin {twin} at {bt:g}: the fused "
-            f"path moves >{DOM_BYTES_EPS:.0%} more bytes than the chain "
-            "it replaces",
-            site=twin,
-            suggestion="the fused kernels should move the SAME logical "
-                       "rows — look for a widened stream operand"))
     return out
 
 
@@ -285,7 +235,7 @@ def _scan_dominance_findings(trace: TargetTrace,
 @register_pass("cost_budget")
 def cost_budget(trace: TargetTrace) -> list[Finding]:
     """Derives the target's static cost model and enforces ledger
-    reconciliation, registered budgets and fused dominance."""
+    reconciliation, registered budgets and the twin dominance checks."""
     from .. import targets as T
     meta = T.TARGET_COST.get(trace.name)
     if meta is None:
@@ -303,7 +253,6 @@ def cost_budget(trace: TargetTrace) -> list[Finding]:
             f"cost derivation failed: {model.error}")]
     out = _reconcile_findings(trace, meta, model)
     out += _budget_findings(trace, meta, model)
-    out += _dominance_findings(trace, model)
     out += _hier_dominance_findings(trace, model)
     out += _overlap_findings(trace, model)
     out += _scan_dominance_findings(trace, model)

@@ -176,7 +176,7 @@ def _ring_slots(root) -> int | None:
     """Slot count of a ring from its operand root's aval: LogRing
     entries are [L, CAP, words] (slots = L*CAP), RepLog entries are
     [L*CAP, S*words] (slots = rows). Other shapes are not rings we can
-    size (the fused 1-D reshape route is skipped by the caller)."""
+    size."""
     shape = getattr(getattr(root, "aval", None), "shape", ())
     if len(shape) == 3:
         return int(shape[0]) * int(shape[1])
@@ -211,12 +211,12 @@ def _ring_bounds(trace: TargetTrace, flow: df.Dataflow) -> list[Finding]:
             by_root.setdefault(id(r.root), (r.root, []))[1].append(r)
     for root, recs in by_root.values():
         slots = _ring_slots(root)
-        unfused = [r for r in recs if not r.fused and r.idx_rows]
-        if slots is None or not unfused:
+        sized = [r for r in recs if r.idx_rows]
+        if slots is None or not sized:
             continue
-        rows = sum(int(r.idx_rows * r.trips) for r in unfused)
+        rows = sum(int(r.idx_rows * r.trips) for r in sized)
         if rows > slots:
-            worst = max(unfused, key=lambda r: r.idx_rows * r.trips)
+            worst = max(sized, key=lambda r: r.idx_rows * r.trips)
             out.append(Finding(
                 "durability", "unbounded-ring", SEV_ERROR, trace.name,
                 f"static appends/trace ({rows} = sum of index width x "

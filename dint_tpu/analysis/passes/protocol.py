@@ -29,8 +29,7 @@ per-target protocol flags declared in analysis/targets.py:
       scatter whose write facts carry LOCK_WIN) must also release:
       (a) expiring stamps — some scatter on that array stamps the step
           counter into it (updates carry STAMP; the dense engines'
-          step-stamp design, where release is stamp expiry), or the
-          arbitration runs in the lock_arbitrate Pallas kernel; or
+          step-stamp design, where release is stamp expiry); or
       (b) a release write — a scatter on the same array whose write
           facts carry ABORT_MASK (the generic engines' combined
           release+acquire value `locked' = held & ~unlock | grant`,
@@ -163,15 +162,14 @@ def protocol(trace: TargetTrace) -> list[Finding]:
     if flags & {FLAG_CERTIFIED, FLAG_DRAIN}:
         aborts = flow.seeded(df.ABORT_MASK)
         roots = _lock_roots(flow)
-        if aborts and (roots or flow.pallas_locks):
+        if aborts:
             for recs in roots:
                 expiring = any(df.STAMP in r.update_facts for r in recs)
                 releasing = any(df.ABORT_MASK in r.write_facts
                                 for r in recs)
                 two_site = len({r.site for r in recs}) >= 2 \
                     or len(recs) >= 2
-                if not (expiring or releasing or two_site
-                        or flow.pallas_locks):
+                if not (expiring or releasing or two_site):
                     grant_site = next(
                         (r for r in recs
                          if df.LOCK_WIN in r.write_facts), recs[0])

@@ -19,9 +19,7 @@ Checks, walking shard_map bodies with the eqn's mesh in scope:
     into one receiver lane: the backend keeps an unspecified one) or
     duplicate source -> ERROR;
   * `shard_map` with `check_vma=False` -> INFO: varying-manual-axes
-    typing is off (ops/pallas_gather.shard_map_check_vma: a body that
-    runs Pallas kernels in interpret mode, whose interpreter drops the
-    types) and this pass's axis checks are what remains.
+    typing is off and this pass's axis checks are what remains.
 """
 from __future__ import annotations
 
@@ -55,10 +53,9 @@ def shard_consistency(trace: TargetTrace) -> list[Finding]:
                 out.append(Finding(
                     "shard_consistency", "check-vma-disabled", SEV_INFO,
                     trace.name,
-                    "shard_map runs with check_vma=False (interpret-mode "
-                    "Pallas kernels in the body): built-in varying-axes "
-                    "typing is off, this pass's axis checks are the "
-                    "standing substitute",
+                    "shard_map runs with check_vma=False: built-in "
+                    "varying-axes typing is off, this pass's axis checks "
+                    "are the standing substitute",
                     primitive=ctx.prim, site=site, path=path))
             continue
 

@@ -2,9 +2,9 @@
 
 DINT's design point is that the SYSTEM decides what lives in the fast
 tier (the kernel cache admits and evicts on its own — PAPER.md); our
-reproduction grew an operator-driven knob matrix instead: `use_pallas`,
-`use_hotset`, `use_fused`, `hierarchical`, `overlap`, the serve width
-menu, a per-round manual decision rule buried in PERF.md. This module is
+reproduction grew an operator-driven knob matrix instead: `use_hotset`,
+`hierarchical`, `overlap`, the serve width menu, a per-round manual
+decision rule buried in PERF.md. This module is
 the static half of closing that loop. It declares the knob space as a
 first-class registry (`KNOBS` — each knob knows its env var, its legal
 values, the engines it applies to and the registered target variant it
@@ -34,9 +34,8 @@ carries a `pinned` config per workload — what production actually runs —
 and when pinned != predicted, an explicit per-knob override with a
 written reason (`MEASURED_OVERRIDES`, quoting the PERF.md round). The
 honest cases are structural: the static model prices SCHEDULED work, so
-the hot tier (whose win is VMEM locality, invisible to a bytes ledger)
-prices as a regression, and the round-6/12 kernels' dispatch wins await
-their armed hardware A/Bs. passes/plan_check.py fails CI when the pinned
+the hot tier (whose win is locality, invisible to a bytes ledger)
+prices as a regression. passes/plan_check.py fails CI when the pinned
 plan drifts from this module's view of the world; bench.py / exp.py /
 the serving plane resolve their knob defaults FROM the plan
 (`resolve_for`), with env flags demoted to an explicit
@@ -44,7 +43,7 @@ the serving plane resolve their knob defaults FROM the plan
 
 `resolve_knobs()` is also the single point of env-knob truth: it
 replicates, exactly, the resolution semantics of
-ops/pallas_gather.env_use_* / use_interpret, monitor/txnevents
+ops/hotset.env_use_hotset, tables/run.env_use_scan, monitor/txnevents
 trace_enabled/trace_rate and the bench DINT_MONITOR gate, and
 engines/_memo.py folds `env_knob_signature()` (the canonicalized
 resolution, not raw strings) into its builder memo keys — the memo key,
@@ -97,17 +96,16 @@ def override_active(environ=None) -> bool:
 # Every ambient configuration flag the engines/bench/serve planes consult,
 # declared ONCE: env var, resolution semantics (`kind`), legal values, the
 # engines it applies to, and the registered target variant token it maps
-# to (use_fused=True => the "@fused" target). `planned` knobs span the
+# to (use_hotset=True => the "@hot" target). `planned` knobs span the
 # priced lattice; the rest (observability and debug knobs) are registered
 # so resolution and memo keys cover them, but the planner holds them at
 # their default — tracing and counters are priced by their OWN calibrated
 # @mon/@trace targets, not chosen by the planner.
 
-# token order inside registered names ("@fused+hot", "@hot+pallas",
-# "@overlap+mon", "@h3+flat"): rank sorts tokens into the registry's
-# canonical spelling
-_TOKEN_RANK = {"fused": 0, "hot": 1, "h3": 2, "overlap": 3, "scan": 4,
-               "mon": 5, "pallas": 6, "flat": 7, "trace": 8}
+# token order inside registered names ("@hot+mon", "@overlap+mon",
+# "@h3+flat"): rank sorts tokens into the registry's canonical spelling
+_TOKEN_RANK = {"hot": 0, "h3": 1, "overlap": 2, "scan": 3,
+               "mon": 4, "flat": 5, "trace": 6}
 
 _DENSE = ("tatp_dense", "smallbank_dense")
 _SHARDED = ("dense_sharded", "dense_sharded_sb")
@@ -117,7 +115,7 @@ _MESH = ("multihost_sb",)
 @dataclasses.dataclass(frozen=True)
 class Knob:
     """One ambient configuration knob, declared once."""
-    name: str                     # canonical name ("use_pallas")
+    name: str                     # canonical name ("use_hotset")
     env: str | None               # env var; None = CLI/constructor only
     kind: str                     # resolution semantics, see _resolve_one
     default: object
@@ -140,21 +138,11 @@ class Knob:
 
 
 _KNOB_LIST = (
-    Knob("use_pallas", "DINT_USE_PALLAS", "flag01", False, (False, True),
-         _DENSE + ("dense_sharded",), token="pallas", planned=True,
-         build_identity=True,
-         doc="route gathers/scatters through the round-6 Pallas DMA-ring "
-             "kernels instead of the XLA op chain"),
     Knob("use_hotset", "DINT_USE_HOTSET", "flag01", False, (False, True),
          _DENSE + ("dense_sharded_sb",), token="hot", planned=True,
          build_identity=True,
-         doc="keep the round-10 VMEM-resident hot-prefix mirror "
-             "(write-through on install, bulk-DMA on serve)"),
-    Knob("use_fused", "DINT_USE_FUSED", "flag01", False, (False, True),
-         _DENSE + _SHARDED, token="fused", planned=True,
-         build_identity=True,
-         doc="fuse lock+validate and install+log-append into the "
-             "round-12 megakernels (~6 -> ~4 dispatches/step)"),
+         doc="keep the round-10 hot-prefix mirror (write-through on "
+             "install)"),
     Knob("hierarchical", None, "bool", True, (False, True),
          _MESH, token="flat", token_when=False, planned=True,
          doc="decompose cross-host collectives ici-then-dcn (round 14) "
@@ -186,11 +174,6 @@ _KNOB_LIST = (
          _DENSE + _SHARDED + _MESH, build_identity=True,
          doc="reserved trace-ring capacity override (memo-key only; no "
              "consumer yet)"),
-    Knob("pallas_interpret", "DINT_PALLAS_INTERPRET", "tri", None,
-         (None, False, True), _DENSE + _SHARDED + _MESH,
-         build_identity=True,
-         doc="force Pallas interpret mode; unset = interpret off-TPU "
-             "(ops/pallas_gather.use_interpret's tri-state)"),
     Knob("hot_frac", "DINT_BENCH_HOT_FRAC", "optfloat", None,
          (None, 1 / 64, 0.5), ("smallbank_dense", "dense_sharded_sb",
                                "multihost_sb"),
@@ -204,12 +187,12 @@ KNOBS: dict[str, Knob] = {k.name: k for k in _KNOB_LIST}
 
 def _resolve_one(knob: Knob, environ) -> object:
     """One knob's env resolution — replicating the consumer's exact
-    semantics (pallas_gather.env_use_*, txnevents.trace_enabled/rate,
+    semantics (hotset.env_use_hotset, txnevents.trace_enabled/rate,
     bench's DINT_MONITOR gate). THE single point of env-knob truth."""
     if knob.env is None:
         return knob.default
     raw = environ.get(knob.env)
-    if knob.kind == "flag01":       # set-and-not-"0"/"": pallas/hot/fused
+    if knob.kind == "flag01":       # set-and-not-"0"/"": hot/scan
         return (raw or "0") not in ("", "0")
     if knob.kind == "flag1":        # exactly "1": DINT_MONITOR, DINT_TRACE
         return (raw or "0") == "1"
@@ -223,8 +206,6 @@ def _resolve_one(knob: Knob, environ) -> object:
             return float(raw) if raw is not None else knob.default
         except ValueError:
             return knob.default
-    if knob.kind == "tri":          # unset => backend-dependent (None)
-        return None if raw is None else raw != "0"
     return raw                      # "raw" / "bool": no env semantics
 
 
@@ -241,8 +222,7 @@ def env_knob_signature(environ=None) -> tuple:
     folds into builder memo keys: (name, resolved value) for every
     build_identity knob. Canonicalized resolution — not raw strings — so
     unset, "" and "0" (all meaning False to the builders) share one memo
-    entry, while the tri-state interpret knob keeps unset distinct from
-    an explicit "0"."""
+    entry."""
     env = os.environ if environ is None else environ
     return tuple((k.name, _resolve_one(k, env))
                  for k in _KNOB_LIST if k.build_identity)
@@ -275,17 +255,17 @@ class Workload:
 
 WORKLOADS: tuple[Workload, ...] = (
     Workload("tatp_uniform", "tatp_dense", "block",
-             ("use_pallas", "use_hotset", "use_fused"),
+             ("use_hotset",),
              doc="single-device TATP, uniform subscriber draw"),
     Workload("smallbank_skewed", "smallbank_dense", "block",
-             ("use_pallas", "use_hotset", "use_fused"), skew="hot-90/4",
+             ("use_hotset",), skew="hot-90/4",
              doc="single-device SmallBank, 90% of txns on the 4% hot "
                  "prefix (clients/workloads.py)"),
     Workload("tatp_sharded", "dense_sharded", "block",
-             ("use_pallas", "use_fused"), mesh="d=4",
+             (), mesh="d=4",
              doc="4-shard ICI TATP (parallel/dense_sharded)"),
     Workload("smallbank_sharded", "dense_sharded_sb", "block",
-             ("use_hotset", "use_fused"), mesh="d=4", skew="hot-90/4",
+             ("use_hotset",), mesh="d=4", skew="hot-90/4",
              doc="4-shard ICI SmallBank"),
     Workload("multihost_4x2", "multihost_sb", "block",
              ("hierarchical",), mesh="4x2", skew="hot-90/4",
@@ -345,8 +325,7 @@ def target_name(workload: Workload, values: dict[str, object]) -> str:
 def enumerate_candidates(workload: Workload) -> list[dict]:
     """The workload's full knob lattice: every assignment of its planned
     knobs, each mapped to a target name and marked feasible iff that
-    target is registered (an unregistered combination — e.g. fused+pallas,
-    whose megakernels subsume the standalone kernels — is structurally
+    target is registered (an unregistered combination is structurally
     infeasible, never silently priced)."""
     from . import targets as T
     assigns: list[dict] = [{}]
@@ -372,19 +351,9 @@ def pinned_knobs(workload: Workload) -> dict[str, object]:
 # (PERF.md) — the plan records these verbatim so `dintplan check` can
 # demand that every divergence is acknowledged, not drifted into.
 MEASURED_OVERRIDES: dict[str, str] = {
-    "use_fused": (
-        "PERF.md round 12: the megakernels shrink the dispatch chain "
-        "~6->4 statically (the planner's pick), but the wall-clock win "
-        "rides dispatch overhead only a TPU can measure — the hardware "
-        "A/B is armed, fused stays opt-in (DINT_USE_FUSED=1) until it "
-        "lands"),
-    "use_pallas": (
-        "PERF.md round 6: the DMA-ring kernels trim dispatches "
-        "statically but their latency-overlap win is unmeasured off-TPU; "
-        "opt-in (DINT_USE_PALLAS=1) until the armed A/B lands"),
     "use_hotset": (
         "PERF.md round 10: the hot tier prices as MORE scheduled work "
-        "(write-through double-pass) — its win is VMEM locality, which "
+        "(write-through double-pass) — its win is locality, which "
         "a static bytes ledger cannot see; opt-in until measured"),
     "overlap": (
         "PERF.md round 18: overlap exists to HIDE the exchange under "

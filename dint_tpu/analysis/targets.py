@@ -9,16 +9,12 @@ CPU in seconds. The jaxpr of a w=16 step is the same eqn stream as the
 production w=8192 one.
 
 Coverage contract (ANALYSIS.md): every production entry point that bench.py
-or exp.py can dispatch appears here — both dense engines (XLA and Pallas
-routes), the dense pipeline drain, both generic fused pipelines, the
-generic replicated shard step, and both dense multi-chip runners. The
-Pallas variants force ``use_pallas=True`` so the aliasing pass sees real
-``pallas_call`` input_output_aliases; on CPU the kernels trace in
-interpret mode (ops/pallas_gather.use_interpret). The ``@mon`` variants
+or exp.py can dispatch appears here — both dense engines, the dense
+pipeline drain, both generic fused pipelines, the generic replicated
+shard step, and both dense multi-chip runners. The ``@mon`` variants
 re-register every dintmon-instrumented step with the counter plane
 threaded (OBSERVABILITY.md): the counter scatter-adds must themselves
-pass scatter_race, and the monitored pallas route proves the pre-kernel
-held-stamp read clears the aliasing pass.
+pass scatter_race.
 
 Mesh targets need >= `_MESH_SHARDS` devices; the dintlint CLI forces an
 8-device virtual CPU topology exactly like tests/conftest.py, and targets
@@ -148,9 +144,8 @@ OVERLAP_FOOTPRINT = "d*(8*d*(2*((w*l+d-1)//d)) + 12)"
 # ------------------------------------------------------------ dense TATP
 
 
-def _tatp_dense(name: str, use_pallas: bool, monitor: bool = False,
+def _tatp_dense(name: str, monitor: bool = False,
                 use_hotset: bool = False,
-                use_fused: bool = False,
                 trace: bool = False,
                 serve: bool = False) -> TargetTrace:
     from ..engines import tatp_dense as td
@@ -158,9 +153,7 @@ def _tatp_dense(name: str, use_pallas: bool, monitor: bool = False,
     from ..monitor import txnevents as txe
     run, init, _ = td.build_pipelined_runner(_N_SUB, w=_W, val_words=_VW,
                                              cohorts_per_block=_BLK,
-                                             use_pallas=use_pallas,
                                              use_hotset=use_hotset,
-                                             use_fused=use_fused,
                                              monitor=monitor, trace=trace,
                                              serve=serve)
     if use_hotset:
@@ -180,34 +173,17 @@ def _tatp_dense(name: str, use_pallas: bool, monitor: bool = False,
 
 
 @register_target("tatp_dense/block",
-                 "flagship dense TATP fused 3-wave pipeline (XLA route)",
+                 "flagship dense TATP fused 3-wave pipeline",
                  protocol=('certified', 'occ'))
 def _t_tatp_dense() -> TargetTrace:
-    return _tatp_dense("tatp_dense/block", use_pallas=False)
-
-
-@register_target("tatp_dense/block@pallas",
-                 "dense TATP with the DMA-ring kernels (DINT_USE_PALLAS=1)",
-                 protocol=('certified', 'occ'))
-def _t_tatp_dense_pl() -> TargetTrace:
-    return _tatp_dense("tatp_dense/block@pallas", use_pallas=True)
+    return _tatp_dense("tatp_dense/block")
 
 
 @register_target("tatp_dense/block@mon",
                  "dense TATP with the dintmon counter plane threaded",
                  protocol=('certified', 'occ'))
 def _t_tatp_dense_mon() -> TargetTrace:
-    return _tatp_dense("tatp_dense/block@mon", use_pallas=False,
-                       monitor=True)
-
-
-@register_target("tatp_dense/block@mon+pallas",
-                 "dense TATP: counter plane + DMA-ring kernels (proves the "
-                 "pre-kernel held-stamp read passes the aliasing pass)",
-                 protocol=('certified', 'occ'))
-def _t_tatp_dense_mon_pl() -> TargetTrace:
-    return _tatp_dense("tatp_dense/block@mon+pallas", use_pallas=True,
-                       monitor=True)
+    return _tatp_dense("tatp_dense/block@mon", monitor=True)
 
 
 @register_target("tatp_dense/drain",
@@ -216,8 +192,7 @@ def _t_tatp_dense_mon_pl() -> TargetTrace:
 def _t_tatp_dense_drain() -> TargetTrace:
     from ..engines import tatp_dense as td
     drain = td.build_pipelined_runner(_N_SUB, w=_W, val_words=_VW,
-                                      cohorts_per_block=_BLK,
-                                      use_pallas=False)[2]
+                                      cohorts_per_block=_BLK)[2]
     carry = _abstract(lambda: (td.create(_N_SUB, val_words=_VW,
                                          log_capacity=_LOGCAP),
                                td.empty_ctx(_W), td.empty_ctx(_W)))
@@ -227,17 +202,14 @@ def _t_tatp_dense_drain() -> TargetTrace:
 # ------------------------------------------------------- dense SmallBank
 
 
-def _sb_dense(name: str, use_pallas: bool, monitor: bool = False,
+def _sb_dense(name: str, monitor: bool = False,
               use_hotset: bool = False,
-              use_fused: bool = False,
               trace: bool = False,
               serve: bool = False) -> TargetTrace:
     from ..engines import smallbank_dense as sd
     run, init, _ = sd.build_pipelined_runner(_N_ACCT, w=_W,
                                              cohorts_per_block=_BLK,
-                                             use_pallas=use_pallas,
                                              use_hotset=use_hotset,
-                                             use_fused=use_fused,
                                              monitor=monitor, trace=trace,
                                              serve=serve)
     # carry via the runner's own init so the @hot variants get the hot
@@ -251,45 +223,26 @@ def _sb_dense(name: str, use_pallas: bool, monitor: bool = False,
 
 
 @register_target("smallbank_dense/block",
-                 "dense SmallBank fused 2-wave pipeline (XLA route)",
+                 "dense SmallBank fused 2-wave pipeline",
                  protocol=('certified',))
 def _t_sb_dense() -> TargetTrace:
-    return _sb_dense("smallbank_dense/block", use_pallas=False)
-
-
-@register_target("smallbank_dense/block@pallas",
-                 "dense SmallBank with the DMA-ring gathers",
-                 protocol=('certified',))
-def _t_sb_dense_pl() -> TargetTrace:
-    return _sb_dense("smallbank_dense/block@pallas", use_pallas=True)
+    return _sb_dense("smallbank_dense/block")
 
 
 @register_target("smallbank_dense/block@mon",
                  "dense SmallBank with the dintmon counter plane threaded",
                  protocol=('certified',))
 def _t_sb_dense_mon() -> TargetTrace:
-    return _sb_dense("smallbank_dense/block@mon", use_pallas=False,
-                     monitor=True)
+    return _sb_dense("smallbank_dense/block@mon", monitor=True)
 
 
 @register_target("smallbank_dense/block@hot",
-                 "dense SmallBank with the dintcache hot-set partition "
-                 "(XLA index-compare route): lock-dominates-write proven "
+                 "dense SmallBank with the dintcache hot-set partition: "
+                 "lock-dominates-write proven "
                  "through the partitioned write-through install",
                  protocol=('certified',))
 def _t_sb_dense_hot() -> TargetTrace:
-    return _sb_dense("smallbank_dense/block@hot", use_pallas=False,
-                     use_hotset=True)
-
-
-@register_target("smallbank_dense/block@hot+pallas",
-                 "dense SmallBank: hot-set partition served by the VMEM "
-                 "kernels (gather_rows_hot + fused scatter_rows_hot, "
-                 "double-donated aliasing)",
-                 protocol=('certified',))
-def _t_sb_dense_hot_pl() -> TargetTrace:
-    return _sb_dense("smallbank_dense/block@hot+pallas", use_pallas=True,
-                     use_hotset=True)
+    return _sb_dense("smallbank_dense/block@hot", use_hotset=True)
 
 
 @register_target("smallbank_dense/block@hot+mon",
@@ -297,8 +250,8 @@ def _t_sb_dense_hot_pl() -> TargetTrace:
                  "(hot_hits/hot_cold_rows/hot_refresh_bytes scatter-adds)",
                  protocol=('certified',))
 def _t_sb_dense_hot_mon() -> TargetTrace:
-    return _sb_dense("smallbank_dense/block@hot+mon", use_pallas=False,
-                     use_hotset=True, monitor=True)
+    return _sb_dense("smallbank_dense/block@hot+mon", use_hotset=True,
+                     monitor=True)
 
 
 # ---------------------------------------------------- generic pipelines
@@ -407,14 +360,12 @@ def _t_sharded_sb() -> TargetTrace:
 # --------------------------------------------------- dense multi-chip
 
 
-def _dense_sharded(name: str, use_pallas: bool, monitor: bool = False,
-                   use_fused: bool = False) -> TargetTrace:
+def _dense_sharded(name: str, monitor: bool = False) -> TargetTrace:
     from ..parallel import dense_sharded as ds
     mesh = _mesh(_MESH_SHARDS)
     run, init, _ = ds.build_sharded_pipelined_runner(
         mesh, _MESH_SHARDS, _N_SUB * _MESH_SHARDS, w=_W, val_words=_VW,
-        cohorts_per_block=_BLK, use_pallas=use_pallas,
-        use_fused=use_fused, monitor=monitor)
+        cohorts_per_block=_BLK, monitor=monitor)
     carry = _abstract(lambda: init(ds.create_sharded(
         mesh, _MESH_SHARDS, _N_SUB * _MESH_SHARDS, val_words=_VW,
         log_capacity=_LOGCAP)))
@@ -427,35 +378,25 @@ def _dense_sharded(name: str, use_pallas: bool, monitor: bool = False,
                  "ppermute fan-out",
                  protocol=('certified', 'occ', 'replicated'))
 def _t_dense_sharded() -> TargetTrace:
-    return _dense_sharded("dense_sharded/block", use_pallas=False)
-
-
-@register_target("dense_sharded/block@pallas",
-                 "multi-chip dense TATP with DMA-ring kernels inside the "
-                 "shard_map body",
-                 protocol=('certified', 'occ', 'replicated'))
-def _t_dense_sharded_pl() -> TargetTrace:
-    return _dense_sharded("dense_sharded/block@pallas", use_pallas=True)
+    return _dense_sharded("dense_sharded/block")
 
 
 @register_target("dense_sharded/block@mon",
                  "multi-chip dense TATP with per-device counter planes",
                  protocol=('certified', 'occ', 'replicated'))
 def _t_dense_sharded_mon() -> TargetTrace:
-    return _dense_sharded("dense_sharded/block@mon", use_pallas=False,
-                          monitor=True)
+    return _dense_sharded("dense_sharded/block@mon", monitor=True)
 
 
 def _dense_sharded_sb(name: str, monitor: bool = False,
                       use_hotset: bool = False,
-                      use_fused: bool = False,
                       trace: bool = False) -> TargetTrace:
     from ..parallel import dense_sharded_sb as dsb
     mesh = _mesh(_MESH_SHARDS)
     run, init, _ = dsb.build_sharded_sb_runner(
         mesh, _MESH_SHARDS, _N_ACCT * _MESH_SHARDS, w=_W,
-        cohorts_per_block=_BLK, use_pallas=False, use_hotset=use_hotset,
-        use_fused=use_fused, monitor=monitor, trace=trace)
+        cohorts_per_block=_BLK, use_hotset=use_hotset, monitor=monitor,
+        trace=trace)
     carry = _abstract(lambda: init(dsb.create_sharded_sb(
         mesh, _MESH_SHARDS, _N_ACCT * _MESH_SHARDS)))
     return trace_target(name, run, (carry, _key_aval()),
@@ -499,146 +440,10 @@ def _t_tatp_dense_hot() -> TargetTrace:
     from ..engines import tatp_dense as td
     run, init, _ = td.build_pipelined_runner(_N_SUB, w=_W, val_words=_VW,
                                              cohorts_per_block=_BLK,
-                                             use_pallas=False,
                                              use_hotset=True)
     carry = _abstract(lambda: init(td.create(_N_SUB, val_words=_VW,
                                              log_capacity=_LOGCAP)))
     return trace_target("tatp_dense/block@hot", run, (carry, _key_aval()))
-
-
-@register_target("tatp_dense/block@hot+pallas",
-                 "dense TATP: row-prefix partition + VMEM kernels incl. "
-                 "the hot-prefix lock_arbitrate residency",
-                 protocol=('certified', 'occ'))
-def _t_tatp_dense_hot_pl() -> TargetTrace:
-    from ..engines import tatp_dense as td
-    run, init, _ = td.build_pipelined_runner(_N_SUB, w=_W, val_words=_VW,
-                                             cohorts_per_block=_BLK,
-                                             use_pallas=True,
-                                             use_hotset=True)
-    carry = _abstract(lambda: init(td.create(_N_SUB, val_words=_VW,
-                                             log_capacity=_LOGCAP)))
-    return trace_target("tatp_dense/block@hot+pallas", run,
-                        (carry, _key_aval()))
-
-
-# -------------------------------------------------- round-12 megakernels
-# Every engine that can dispatch the fused wave pairs (DINT_USE_FUSED=1)
-# re-registers here with ``use_fused=True`` forced, so the protocol pass
-# proves lock-dominates-write / validate-before-install THROUGH the
-# lock_validate and install_log megakernels (dataflow.py recognizes them
-# by kernel name: lock_validate seeds LOCK_WIN + VALIDATED on its own
-# outputs, scatter_streams records one synthetic install per aliased
-# stream). On CPU the kernels trace in interpret mode like @pallas.
-
-
-@register_target("tatp_dense/block@fused",
-                 "dense TATP with the round-12 megakernels: lock+validate "
-                 "and install+log-append each a single dispatch",
-                 protocol=('certified', 'occ'))
-def _t_tatp_dense_fused() -> TargetTrace:
-    return _tatp_dense("tatp_dense/block@fused", use_pallas=False,
-                       use_fused=True)
-
-
-@register_target("tatp_dense/block@fused+hot",
-                 "dense TATP: megakernels over the dintcache row-prefix "
-                 "partition (lock_validate keeps the hot_n VMEM arb "
-                 "prefix; install_log scatters the hot mirrors as extra "
-                 "aliased streams)",
-                 protocol=('certified', 'occ'))
-def _t_tatp_dense_fused_hot() -> TargetTrace:
-    return _tatp_dense("tatp_dense/block@fused+hot", use_pallas=False,
-                       use_hotset=True, use_fused=True)
-
-
-@register_target("tatp_dense/block@fused+mon",
-                 "dense TATP: megakernels + counter plane (fused_dispatch "
-                 "bump and the pre-kernel held-stamp read both certified)",
-                 protocol=('certified', 'occ'))
-def _t_tatp_dense_fused_mon() -> TargetTrace:
-    return _tatp_dense("tatp_dense/block@fused+mon", use_pallas=False,
-                       use_fused=True, monitor=True)
-
-
-@register_target("smallbank_dense/block@fused",
-                 "dense SmallBank with the round-12 megakernels (gather "
-                 "streams feed the XLA scatter-min arbitration; install + "
-                 "log ride one scatter_streams dispatch)",
-                 protocol=('certified',))
-def _t_sb_dense_fused() -> TargetTrace:
-    return _sb_dense("smallbank_dense/block@fused", use_pallas=False,
-                     use_fused=True)
-
-
-@register_target("smallbank_dense/block@fused+hot",
-                 "dense SmallBank: megakernels + dintcache mirror (fused "
-                 "gathers read main arrays by the mirror invariant; the "
-                 "hot mirror is a third aliased install stream)",
-                 protocol=('certified',))
-def _t_sb_dense_fused_hot() -> TargetTrace:
-    return _sb_dense("smallbank_dense/block@fused+hot", use_pallas=False,
-                     use_hotset=True, use_fused=True)
-
-
-@register_target("smallbank_dense/block@fused+mon",
-                 "dense SmallBank: megakernels + counter plane",
-                 protocol=('certified',))
-def _t_sb_dense_fused_mon() -> TargetTrace:
-    return _sb_dense("smallbank_dense/block@fused+mon", use_pallas=False,
-                     use_fused=True, monitor=True)
-
-
-@register_target("dense_sharded/block@fused",
-                 "multi-chip dense TATP with the megakernels inside the "
-                 "shard_map body (replicate fan-out stays ppermute + XLA "
-                 "so REPL_PUSHED provenance is unchanged)",
-                 protocol=('certified', 'occ', 'replicated'))
-def _t_dense_sharded_fused() -> TargetTrace:
-    return _dense_sharded("dense_sharded/block@fused", use_pallas=False,
-                          use_fused=True)
-
-
-# no dense_sharded/block@fused+hot: build_sharded_pipelined_runner has no
-# hot-set partition (the TATP sharded path shards by subscriber, so the
-# skewed prefix never concentrates on one device — see PERF.md round 10)
-
-
-@register_target("dense_sharded/block@fused+mon",
-                 "multi-chip dense TATP: megakernels + per-device counter "
-                 "planes",
-                 protocol=('certified', 'occ', 'replicated'))
-def _t_dense_sharded_fused_mon() -> TargetTrace:
-    return _dense_sharded("dense_sharded/block@fused+mon",
-                          use_pallas=False, use_fused=True, monitor=True)
-
-
-@register_target("dense_sharded_sb/block@fused",
-                 "multi-chip dense SmallBank: owner-routed step with the "
-                 "megakernels (all_to_all routing and the replica "
-                 "ppermute stay XLA)",
-                 protocol=('certified', 'replicated'))
-def _t_dense_sharded_sb_fused() -> TargetTrace:
-    return _dense_sharded_sb("dense_sharded_sb/block@fused",
-                             use_fused=True)
-
-
-@register_target("dense_sharded_sb/block@fused+hot",
-                 "multi-chip dense SmallBank: megakernels + per-device "
-                 "dintcache mirrors",
-                 protocol=('certified', 'replicated'))
-def _t_dense_sharded_sb_fused_hot() -> TargetTrace:
-    return _dense_sharded_sb("dense_sharded_sb/block@fused+hot",
-                             use_hotset=True, use_fused=True)
-
-
-@register_target("dense_sharded_sb/block@fused+mon",
-                 "multi-chip dense SmallBank: megakernels + per-device "
-                 "counter planes",
-                 protocol=('certified', 'replicated'))
-def _t_dense_sharded_sb_fused_mon() -> TargetTrace:
-    return _dense_sharded_sb("dense_sharded_sb/block@fused+mon",
-                             use_fused=True, monitor=True)
 
 
 # ------------------------------------------- round-14 2-D (dcn x ici)
@@ -755,8 +560,7 @@ def _t_multihost() -> TargetTrace:
                  "(lock/validate/install/outcome events, full rate)",
                  protocol=('certified', 'occ'))
 def _t_tatp_dense_trace() -> TargetTrace:
-    return _tatp_dense("tatp_dense/block@trace", use_pallas=False,
-                       trace=True)
+    return _tatp_dense("tatp_dense/block@trace", trace=True)
 
 
 @register_target("smallbank_dense/block@trace",
@@ -764,8 +568,7 @@ def _t_tatp_dense_trace() -> TargetTrace:
                  "ring (lock/install/outcome events, full rate)",
                  protocol=('certified',))
 def _t_sb_dense_trace() -> TargetTrace:
-    return _sb_dense("smallbank_dense/block@trace", use_pallas=False,
-                     trace=True)
+    return _sb_dense("smallbank_dense/block@trace", trace=True)
 
 
 @register_target("dense_sharded_sb/block@trace",
@@ -800,7 +603,7 @@ def _t_multihost_sb_trace() -> TargetTrace:
                  "over the fused 3-wave pipeline (dintserve steady state)",
                  protocol=('certified', 'occ'))
 def _t_tatp_dense_serve() -> TargetTrace:
-    return _tatp_dense("tatp_dense/serve", use_pallas=False, serve=True)
+    return _tatp_dense("tatp_dense/serve", serve=True)
 
 
 @register_target("tatp_dense/serve@mon",
@@ -808,8 +611,7 @@ def _t_tatp_dense_serve() -> TargetTrace:
                  "occupancy/padded/shed lanes land on the device ledger",
                  protocol=('certified', 'occ'))
 def _t_tatp_dense_serve_mon() -> TargetTrace:
-    return _tatp_dense("tatp_dense/serve@mon", use_pallas=False,
-                       monitor=True, serve=True)
+    return _tatp_dense("tatp_dense/serve@mon", monitor=True, serve=True)
 
 
 @register_target("smallbank_dense/serve",
@@ -817,7 +619,7 @@ def _t_tatp_dense_serve_mon() -> TargetTrace:
                  "lock-slot mask over the 2-wave pipeline",
                  protocol=('certified',))
 def _t_sb_dense_serve() -> TargetTrace:
-    return _sb_dense("smallbank_dense/serve", use_pallas=False, serve=True)
+    return _sb_dense("smallbank_dense/serve", serve=True)
 
 
 @register_target("smallbank_dense/serve@mon",
@@ -825,8 +627,7 @@ def _t_sb_dense_serve() -> TargetTrace:
                  "plane: occupancy/padded/shed lanes on the ledger",
                  protocol=('certified',))
 def _t_sb_dense_serve_mon() -> TargetTrace:
-    return _sb_dense("smallbank_dense/serve@mon", use_pallas=False,
-                     monitor=True, serve=True)
+    return _sb_dense("smallbank_dense/serve@mon", monitor=True, serve=True)
 
 
 # --------------------------------------- dintmesh serving plane (round 18)
@@ -1014,42 +815,25 @@ _DSB_GEOM = dict(w=_W, l=3, vw=2, d=_MESH_SHARDS)
 
 # wave_expect: documented layout deviations from the base formula.
 #
-# The XLA-route dintcache variants serve every partitioned table wave as
-# TWO masked full-width passes (hot partition + cold partition): logical
+# The dintcache variants serve every partitioned table wave as TWO
+# masked full-width passes (hot partition + cold partition): logical
 # lanes stay w, but the static walker sees both gathers/scatters (the
-# hot install is not compacted: no chunk gathers in its formula). The
-# VMEM-kernel hot variants (@hot+pallas, @fused+hot) do NOT double — one
-# kernel serves both partitions per wave.
+# hot install is not compacted: no chunk gathers in its formula).
 _HOT2_TD = {"dint.tatp_dense.meta_gather": 2.0,
             "dint.tatp_dense.magic_gather": 2.0,
             "dint.tatp_dense.install": "2*2*w*(4 + 4*vw)"}
 _HOT2_SB = {"dint.smallbank_dense.read": 2.0,
             "dint.smallbank_dense.lock": 2.0,
             "dint.smallbank_dense.install": 2.0}
-# ... and its install is that one kernel's, not the compacted one.
-_HOTPL_TD = {"dint.tatp_dense.install": "2*w*(4 + 4*vw)"}
-# The monitored pallas route adds the pre-kernel held-stamp read: one
-# extra full arb pass before lock_arbitrate (4 passes, not 3).
-_MONPL_TD = {"dint.tatp_dense.lock": "4*2*w*4"}
 # The sharded dense runner keeps ONE local log replica (the other two
 # ride the CommitBck/Log hops accounted under replicate), and
 # replicate's two ppermute hops each move the wL balance rows plus a
 # log append the hand formula counts once.
 _DS_EXPECT = {"dint.tatp_dense.log_append": "2*w*(20 + 4*vw)",
               "dint.dense_sharded.replicate": 1.75}
-_DS_EXPECT_FUSED = {
-    "dint.tatp_dense.install_log": "2*w*(4 + 4*vw) + 2*w*(20 + 4*vw)",
-    "dint.dense_sharded.replicate": 1.75}
 # The dsb owner step with dintcache mirrors doubles the owner-side
-# arbitration passes (hot + cold partition of the routed slots) ...
+# arbitration passes (hot + cold partition of the routed slots).
 _DSB_HOT = {"dint.dense_sharded_sb.arbitrate": 2.0}
-# ... and the fused+hot megakernel adds hot/cold split gather streams
-# for the two balance reads (7 passes over the routed slots, not 5).
-_DSB_FUSED_HOT = {"dint.dense_sharded_sb.lock_validate": "7*2*w*l*4"}
-# The TATP fused+hot target still runs the magic read as the XLA
-# hot/cold double pass (the megakernels fuse lock+validate and
-# install+log only; meta rides lock_validate's gather streams).
-_TD_FUSED_HOT = {"dint.tatp_dense.magic_gather": 2.0}
 # 2-D mesh geometries (parallel/multihost_sb.py): d is the GLOBAL
 # device count n_hosts*n_ici — the per-step lane math is identical to
 # dense_sharded_sb at the same d, only the transport differs.
@@ -1091,48 +875,32 @@ def _cost(geom, dispatches, footprint, *, steps=float(_BLK),
 
 
 TARGET_COST.update({
-    # dense TATP — the fused ladder the round-12 claim rides: 9 (XLA)
-    # -> 7 (@pallas) -> 4 (@fused) dispatches/step, bytes flat. PR 30's
-    # write-set compaction (ops/compact.py) adds 3 to every unfused XLA
-    # install + log: a chunk's gathers of row ids, meta words and ring
+    # dense TATP. PR 30's write-set compaction (ops/compact.py) adds 3
+    # to every install + log: a chunk's gathers of row ids, meta words and ring
     # slots out of the 2w-wide operands (the value and entry rows' gathers
     # read temporaries, which the walker does not price); the hot tier's
     # install is its own, so only its log's gather is new (+1). One trip
     # of a chunk loop is priced, at a geometry where a chunk is all 2w
     # slots: the scatters' bytes are the parent's
     "tatp_dense/block": _cost(_TD_GEOM, 12, 216844),
-    "tatp_dense/block@pallas": _cost(_TD_GEOM, 10, 216844),
     "tatp_dense/block@mon": _cost(_TD_GEOM, 14, 217000),
-    "tatp_dense/block@mon+pallas": _cost(_TD_GEOM, 13, 217000,
-                                         wave_expect=_MONPL_TD),
     "tatp_dense/drain": _cost(_TD_GEOM, 12, 216836),
     "tatp_dense/block@hot": _cost(_TD_GEOM, 14, 216864,
                                   wave_expect=_HOT2_TD),
-    "tatp_dense/block@hot+pallas": _cost(_TD_GEOM, 8, 216864,
-                                         wave_expect=_HOTPL_TD),
     # dintserve serve-mode blocks: dispatches/step identical to the
     # closed-loop rows above (the occupancy mask fuses into the gen
     # wave), footprint +16 B (@mon +28 B) for the occ/shed step inputs
     "tatp_dense/serve": _cost(_TD_GEOM, 12, 216860),
     "tatp_dense/serve@mon": _cost(_TD_GEOM, 14, 217016),
-    "tatp_dense/block@fused": _cost(_TD_GEOM, 4, 216844),
-    "tatp_dense/block@fused+hot": _cost(_TD_GEOM, 5, 216864,
-                                        wave_expect=_TD_FUSED_HOT),
-    "tatp_dense/block@fused+mon": _cost(_TD_GEOM, 7, 217000),
-    # dense SmallBank: 8 -> 5 dispatches/step under the megakernels
+    # dense SmallBank
     "smallbank_dense/block": _cost(_SB_GEOM, 8, 150984),
-    "smallbank_dense/block@pallas": _cost(_SB_GEOM, 8, 150984),
     "smallbank_dense/block@mon": _cost(_SB_GEOM, 10, 151140),
     "smallbank_dense/block@hot": _cost(_SB_GEOM, 14, 151032,
                                        wave_expect=_HOT2_SB),
-    "smallbank_dense/block@hot+pallas": _cost(_SB_GEOM, 10, 151032),
     "smallbank_dense/block@hot+mon": _cost(_SB_GEOM, 16, 151188,
                                            wave_expect=_HOT2_SB),
     "smallbank_dense/serve": _cost(_SB_GEOM, 8, 151000),
     "smallbank_dense/serve@mon": _cost(_SB_GEOM, 10, 151156),
-    "smallbank_dense/block@fused": _cost(_SB_GEOM, 5, 150984),
-    "smallbank_dense/block@fused+hot": _cost(_SB_GEOM, 7, 151032),
-    "smallbank_dense/block@fused+mon": _cost(_SB_GEOM, 7, 151140),
     # generic pipelines: sort-bound, no formula-backed waves -> absolute
     # bytes ceilings instead of a ledger multiple
     "tatp_pipeline/block": _cost(_TD_GEOM, 50, 1610736022,
@@ -1148,26 +916,16 @@ TARGET_COST.update({
                           bytes_budget=12000),
     "sharded/smallbank": _cost(_DSB_GEOM, 30, 3221242768, steps=1.0,
                                bytes_budget=4000),
-    # dense multi-chip TATP: 33 -> 28 dispatches/step fused
+    # dense multi-chip TATP
     "dense_sharded/block": _cost(_DS_GEOM, 36, 459240,
                                  wave_expect=_DS_EXPECT),
-    "dense_sharded/block@pallas": _cost(_DS_GEOM, 34, 459240,
-                                        wave_expect=_DS_EXPECT),
     "dense_sharded/block@mon": _cost(_DS_GEOM, 40, 459864,
                                      wave_expect=_DS_EXPECT),
-    "dense_sharded/block@fused": _cost(_DS_GEOM, 28, 459240,
-                                       wave_expect=_DS_EXPECT_FUSED),
-    "dense_sharded/block@fused+mon": _cost(_DS_GEOM, 33, 459864,
-                                           wave_expect=_DS_EXPECT_FUSED),
-    # dense multi-chip SmallBank: 33 -> 30 dispatches/step fused
+    # dense multi-chip SmallBank
     "dense_sharded_sb/block": _cost(_DSB_GEOM, 33, 100676560),
     "dense_sharded_sb/block@mon": _cost(_DSB_GEOM, 37, 100677184),
     "dense_sharded_sb/block@hot": _cost(_DSB_GEOM, 39, 100676848,
                                         wave_expect=_DSB_HOT),
-    "dense_sharded_sb/block@fused": _cost(_DSB_GEOM, 30, 100676560),
-    "dense_sharded_sb/block@fused+hot": _cost(
-        _DSB_GEOM, 32, 100676848, wave_expect=_DSB_FUSED_HOT),
-    "dense_sharded_sb/block@fused+mon": _cost(_DSB_GEOM, 34, 100677184),
     # 2-D (dcn x ici) SmallBank: the hierarchical route pays +9
     # dispatches/step (each exchange runs ici + dcn stages) to move
     # strictly fewer DCN-axis link bytes than its flat twin — the
@@ -1239,16 +997,15 @@ _ST_DCAP = 8           # delta overlay capacity (window = sl + dc rows)
 _ST_GEOM = dict(w=_W, vw=_VW, sl=_ST_SMAX, dc=_ST_DCAP, lg=7)
 
 
-def _store_runner(name: str, use_scan: bool, use_pallas: bool = False,
-                  monitor: bool = False, serve: bool = False
-                  ) -> TargetTrace:
+def _store_runner(name: str, use_scan: bool, monitor: bool = False,
+                  serve: bool = False) -> TargetTrace:
     from ..engines import store
     from ..tables import kv
     run, init, _ = store.build_serve_runner(
         _N_ACCT, w=_W, cohorts_per_block=_BLK, val_words=_VW,
         scan_frac=0.5 if use_scan else 0.0, max_scan_len=_ST_SMAX,
         scan_max=_ST_SMAX, delta_cap=_ST_DCAP, use_scan=use_scan,
-        use_pallas=use_pallas, monitor=monitor, serve=serve)
+        monitor=monitor, serve=serve)
     carry = _abstract(lambda: init(kv.create(_ST_NB, val_words=_VW)))
     args = (carry, _key_aval())
     if serve:
@@ -1266,19 +1023,10 @@ def _t_store_block() -> TargetTrace:
 
 @register_target("store/block@scan",
                  "KV store block with the ordered-run scan path: locate "
-                 "+ sequential slab + run∪delta merge, XLA slab route",
+                 "+ sequential slab + run∪delta merge",
                  protocol=('server', 'elected'))
 def _t_store_block_scan() -> TargetTrace:
     return _store_runner("store/block@scan", use_scan=True)
-
-
-@register_target("store/block@scan+pallas",
-                 "KV store scans through the sequential-DMA scan_rows "
-                 "kernel (offset-sorted double-buffered row streams)",
-                 protocol=('server', 'elected'))
-def _t_store_block_scan_pl() -> TargetTrace:
-    return _store_runner("store/block@scan+pallas", use_scan=True,
-                         use_pallas=True)
 
 
 @register_target("store/serve@scan",
@@ -1322,7 +1070,6 @@ def _t_store_rebuild() -> TargetTrace:
 # must arrive cheaper than probes, the dintscan bandwidth claim
 TARGET_SCAN_TWIN: dict[str, str] = {
     "store/block@scan": "store/block",
-    "store/block@scan+pallas": "store/block",
     "store/serve@scan": "store/block",
 }
 
@@ -1333,15 +1080,11 @@ TARGET_SCAN_TWIN: dict[str, str] = {
 # w*(sl+dc)*(12+4*vw) = 7168 B/step, scan_locate = w*lg*8 = 896 B/step
 # (zero wave_expect entries, zero allowlist entries — ISSUE 20's
 # acceptance). The run_rebuild wave bills once per BLOCK (the drain
-# boundary), attribution-only. @scan+pallas keeps the identical bytes
-# (same logical rows) and drops 3 dispatches/step: the 4 slab gathers
-# fuse into 1 scan_rows kernel (+1 offset argsort feed). The mon row
+# boundary), attribution-only. The mon row
 # pays +1 dispatch and +32 B/step for the counter scatter-add.
 TARGET_COST.update({
     "store/block": _cost(_ST_GEOM, 15, 2008, bytes_budget=2200),
     "store/block@scan": _cost(_ST_GEOM, 35.5, 4077, bytes_budget=11700),
-    "store/block@scan+pallas": _cost(_ST_GEOM, 32.5, 4077,
-                                     bytes_budget=11700),
     "store/serve@scan": _cost(_ST_GEOM, 35.5, 4093, bytes_budget=11700),
     "store/serve@scan+mon": _cost(_ST_GEOM, 36.5, 4249,
                                   bytes_budget=11750),
@@ -1356,7 +1099,7 @@ TARGET_COST.update({
 # and with which operators. One representative per engine family — the
 # operator set per target reflects what the engine actually contains
 # (e.g. axis-swap needs live ppermutes, ring-shrink needs the durable
-# unfused log ring, drop-donation needs a top-level donated pjit) so
+# log ring, drop-donation needs a top-level donated pjit) so
 # "no sites found" stays a loud mut_check error (operator-dormant), not
 # an expected blank. Kept here (not in mutate.py) because mutability is
 # a property of the TARGET: adding an engine family means deciding which

@@ -41,9 +41,8 @@ def run(window_s: float = 10.0, n_accounts: int = N_ACCOUNTS,
 
     ``hot_frac``/``hot_prob`` override the workload's 90%/4% skew (the
     bench.py --hot-frac/--hot-prob knobs). ``knobs`` carries the
-    plan-resolved builder knobs (use_pallas / use_hotset / use_fused —
-    bench.py's _plan_resolve); None falls back to the builder's env
-    resolution (DINT_USE_HOTSET etc.)."""
+    plan-resolved builder knobs (use_hotset — bench.py's _plan_resolve);
+    None falls back to the builder's env resolution (DINT_USE_HOTSET)."""
     points = [_run_one(window_s, n_accounts, w, block, hot_frac, hot_prob,
                        knobs)
               for w in widths]
@@ -64,7 +63,7 @@ def _run_one(window_s: float, n_accounts: int, width: int, block: int,
              hot_frac: float | None = None,
              hot_prob: float | None = None,
              knobs: dict | None = None) -> dict:
-    from ..ops import pallas_gather as pg
+    from ..ops import hotset
     from . import workloads as wl
 
     db = sd.create(n_accounts)
@@ -107,11 +106,10 @@ def _run_one(window_s: float, n_accounts: int, width: int, block: int,
         "committed_tps": round(committed / dt, 1),
         "abort_rate": round(1 - committed / max(attempted, 1), 5),
         # skew + hot-tier provenance: A/B artifacts must be
-        # distinguishable (same rule as bench.py's "use_pallas"); a
-        # plan-resolved knob records the value that actually built
+        # distinguishable; a plan-resolved knob records the value that actually built
         "use_hotset": (knobs["use_hotset"]
                        if knobs and "use_hotset" in knobs
-                       else pg.resolve_use_hotset(None)),
+                       else hotset.resolve_use_hotset(None)),
         "hot_frac": wl.SB_HOT_FRAC if hot_frac is None else float(hot_frac),
         "hot_prob": wl.SB_HOT_PROB if hot_prob is None else float(hot_prob),
     }

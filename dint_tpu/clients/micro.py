@@ -87,8 +87,7 @@ class StoreClient(_SteppedClient):
     skewed store benchmark. ``use_hotset`` (None = DINT_USE_HOTSET env)
     attaches the dintcache mirror for the first ``hot_frac`` of the
     keyspace and threads it through every step (write-through,
-    bit-identical replies); DINT_USE_PALLAS additionally serves the
-    partition with the VMEM hot kernels.
+    bit-identical replies).
 
     ``use_scan`` (None = DINT_USE_SCAN env) attaches the dintscan ordered
     run and lets waves carry Op.SCAN lanes (``scan_frac`` of the mix,
@@ -104,33 +103,26 @@ class StoreClient(_SteppedClient):
                  val_words: int = 10, read_frac: float = 0.5,
                  key_dist: str = "uniform", zipf_theta: float = wl.ZIPF_THETA,
                  hot_frac: float | None = None, use_hotset=None,
-                 use_pallas=None, use_scan=None, scan_frac: float = 0.0,
+                 use_scan=None, scan_frac: float = 0.0,
                  scan_max: int = 8, max_scan_len: int | None = None,
                  delta_cap: int = 64, rebuild_every: int = 8):
-        from ..ops import pallas_gather as pg
+        from ..ops import hotset
         from ..tables import run as run_mod
 
         assert key_dist in ("uniform", "zipfian")
-        self.use_hotset = pg.resolve_use_hotset(use_hotset)
-        self.use_scan = pg.resolve_use_scan(use_scan)
+        self.use_hotset = hotset.resolve_use_hotset(use_hotset)
+        self.use_scan = run_mod.resolve_use_scan(use_scan)
         self.scan_max = int(scan_max)
         self.scan_frac = float(scan_frac) if self.use_scan else 0.0
         self.max_scan_len = int(max_scan_len or scan_max)
         self.delta_cap = int(delta_cap)
         self.rebuild_every = max(int(rebuild_every), 1)
         self._waves_since_rebuild = 0
-        up = pg.resolve_use_pallas(use_pallas, n_idx=width, m_lock=None)
         run0 = None
         if self.use_scan:
             run0 = run_mod.from_table(table, delta_cap=int(delta_cap))
-            if up:
-                pg.scan_kernels_available(
-                    n_idx=width, lg=self.scan_max + run0.delta_cap,
-                    vw=val_words)
         hot = None
         if self.use_hotset:
-            if up:
-                pg.hot_kernels_available(n_idx=width)
             frac = 0.04 if hot_frac is None else float(hot_frac)
             # mirror ids are key_lo < hot_n; keys are 1-based, so cover
             # keys 1..frac*n with hot_n = frac*n + 1
@@ -139,25 +131,25 @@ class StoreClient(_SteppedClient):
 
         smax = self.scan_max
         if self.use_scan and self.use_hotset:
-            def step_fn(state, batch, _up=up):
+            def step_fn(state, batch):
                 t, h, rn = state
                 t, rep, h, rn, srep = store.step(
-                    t, batch, hot=h, use_pallas=_up, run=rn, scan_max=smax)
+                    t, batch, hot=h, run=rn, scan_max=smax)
                 return (t, h, rn), (rep, srep)
 
             state = (table, hot, run0)
         elif self.use_scan:
-            def step_fn(state, batch, _up=up):
+            def step_fn(state, batch):
                 t, rn = state
                 t, rep, rn, srep = store.step(
-                    t, batch, use_pallas=_up, run=rn, scan_max=smax)
+                    t, batch, run=rn, scan_max=smax)
                 return (t, rn), (rep, srep)
 
             state = (table, run0)
         elif self.use_hotset:
-            def step_fn(state, batch, _up=up):
+            def step_fn(state, batch):
                 t, h = state
-                t, rep, h = store.step(t, batch, hot=h, use_pallas=_up)
+                t, rep, h = store.step(t, batch, hot=h)
                 return (t, h), rep
 
             state = (table, hot)
@@ -174,7 +166,6 @@ class StoreClient(_SteppedClient):
         self.read_frac = read_frac
         self.key_dist = key_dist
         self.zipf_theta = zipf_theta
-        self.use_pallas = up
 
     @classmethod
     def populated(cls, n_keys: int, *, n_buckets: int | None = None,

@@ -16,7 +16,7 @@ from __future__ import annotations
 import functools
 
 # builders resolve None-valued knobs from the ambient environment at
-# BUILD time (ops/pallas_gather.resolve_use_*, monitor/txnevents trace
+# BUILD time (ops/hotset.resolve_use_hotset, monitor/txnevents trace
 # defaults), so those values are part of the compiled program's
 # identity — fold a snapshot into the key or a monkeypatched env would
 # hit a stale entry. The snapshot is analysis/plan.env_knob_signature():
@@ -48,3 +48,11 @@ def memoize_builder(fn):
 
     wrapped.cache = cache        # introspection / explicit clears in tests
     return wrapped
+
+
+def refuse_kernel_flags(use_pallas, use_fused):
+    # benchmarks/deployments/*.py, benchmarks/checks.py and
+    # tests/bench/test_bench_harness.py still pass both as False
+    if use_pallas or use_fused:
+        raise ValueError("use_pallas / use_fused: the Pallas kernels were "
+                         "deleted in PR 35; the engines have one route")

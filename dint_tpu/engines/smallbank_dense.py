@@ -76,11 +76,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ._memo import memoize_builder
+from ._memo import memoize_builder, refuse_kernel_flags
 from ..monitor import counters as mon
 from ..monitor import txnevents as txe
 from ..monitor import waves
-from ..ops import pallas_gather as pg
+from ..ops import hotset
 from ..tables import log as logring
 from .types import Op
 from .smallbank_pipeline import (AMT, L, MAGIC, N_SHARDS, TS_AMT_MAX, VW,     # noqa: F401 (re-exported)
@@ -237,8 +237,7 @@ def _stats_of(c: BankCtx):
 
 def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
               gen_new: bool = True, hot_frac=None, hot_prob=None, mix=None,
-              use_pallas: bool = False, use_hotset: bool = False,
-              use_fused: bool = False,
+              use_hotset: bool = False,
               occupancy: jax.Array | None = None,
               shed: jax.Array | None = None,
               counters: mon.Counters | None = None,
@@ -248,36 +247,14 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
     STILL-HELD stamps (stamp == step-1), then wave 2 installs c1's writes.
     Returns (db', new_ctx, stats-of-c1).
 
-    ``use_pallas`` (static) routes the step's random single-word gathers —
+    ``use_hotset`` (static) serves the step's random single-word gathers —
     the held-stamp reads on x_step/s_step and the fused balance read —
-    through the DMA-ring kernel (ops/pallas_gather.gather_rows),
-    bit-identical to the XLA gathers; the scatter-min arbitration and the
-    install scatters stay XLA (they are already 1-D unique-index fast
-    paths).
-
-    ``use_hotset`` (static) serves those same gathers through the
-    dintcache partition instead (db must carry the hot mirror —
+    through the dintcache partition (db must carry the hot mirror —
     attach_hotset): hot lanes (account < hot_n) read the compact mirror
-    (VMEM-resident inside the pallas kernel, a small-array gather on the
-    XLA route) while cold lanes walk the full tables, and the wave-2
-    install writes through to the mirror (the fused
-    ops/pallas_gather.scatter_rows_hot kernel on the pallas route, a
-    double 1-D unique-index scatter on XLA). At the workload's 90%/4%
-    skew this converts the dominant random-HBM row DMAs into VMEM
-    accesses; outputs stay bit-identical to the default path (pinned in
-    tests/test_hotset.py).
-
-    ``use_fused`` (static; OFF by default) swallows the step's wave pairs
-    into the round-12 megakernels: the held-stamp gathers + the fused
-    balance read become gather streams of ONE lock_validate dispatch
-    (the scatter-min arbitration and grant compares stay XLA — LOCK_WIN
-    still seeds at the compare), and the balance install + log x3 append
-    (+ hot-mirror write-through) become scatter streams of ONE
-    install_log dispatch. Bit-identical to the unfused path
-    (tests/test_fused_ops.py); independent of ``use_pallas``. With
-    ``use_hotset`` the fused gathers read the main arrays directly
-    (bit-identical by the mirror invariant) while installs keep the
-    write-through, so the mirror stays coherent.
+    (a small-array gather) while cold lanes walk the full tables, and the
+    wave-2 install writes through to the mirror (a double 1-D
+    unique-index scatter). Outputs stay bit-identical to the default path
+    (pinned in tests/test_hotset.py).
 
     ``occupancy``/``shed`` (device i32 scalars, or None = off): the
     dintserve variable-occupancy plane — lanes >= occupancy have their
@@ -357,18 +334,6 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
             hot_lane = (active & (l_ac < hn)).reshape(-1)
             midx = jnp.where(hot_lane, (l_tb * hn + l_ac).reshape(-1), -1)
 
-    if use_fused:
-        # lock_validate megakernel: both held-stamp gathers AND the wave-1
-        # balance read ride ONE gather_streams dispatch. All three read
-        # pre-install state (the balance rows c1 installs below were
-        # X-stamped by c1, so this cohort never granted them), and the
-        # fused route reads the main arrays directly — bit-identical to
-        # the hot-partitioned serving by the mirror invariant
-        with waves.scope("smallbank_dense", "lock_validate"):
-            hx_raw, hs_raw, fused_bal = pg.gather_streams(
-                (db.x_step, db.s_step, db.bal),
-                (slot, slot, flat_rows), (1, 1, 1))
-
     with waves.scope("smallbank_dense", "lock"):
         with waves.part("smallbank_dense", "lock_arb"):
             first_x = jnp.full((h,), BIG, I32).at[
@@ -378,17 +343,11 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
         # held = stamped by the previous step's cohort (released implicitly
         # one step later; acquire-before-release semantics preserved)
         with waves.part("smallbank_dense", "lock_held_read"):
-            if use_fused:
-                held_x = hx_raw == t - 1
-                held_s = hs_raw == t - 1
-            elif stamp_hot:
-                held_x = pg.hot_gather(db.x_step, db.hot_x, slot, midx, 1,
-                                       use_pallas=use_pallas) == t - 1
-                held_s = pg.hot_gather(db.s_step, db.hot_s, slot, midx, 1,
-                                       use_pallas=use_pallas) == t - 1
-            elif use_pallas:
-                held_x = pg.gather_rows(db.x_step, slot, 1) == t - 1
-                held_s = pg.gather_rows(db.s_step, slot, 1) == t - 1
+            if stamp_hot:
+                held_x = hotset.hot_gather(db.x_step, db.hot_x, slot, midx,
+                                           1) == t - 1
+                held_s = hotset.hot_gather(db.s_step, db.hot_s, slot, midx,
+                                           1) == t - 1
             else:
                 held_x = db.x_step[slot] == t - 1
                 held_s = db.s_step[slot] == t - 1
@@ -424,14 +383,11 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
     # fused reads from the pre-install table: rows c1 installs below were
     # X-stamped by c1, so this cohort never granted (or consumed) them
     with waves.scope("smallbank_dense", "read"):
-        if use_fused:
-            raw_bal = fused_bal     # already gathered in lock_validate
-        elif use_hotset:
-            raw_bal = pg.hot_gather(db.bal, db.hot_bal, flat_rows, midx, 1,
-                                    use_pallas=use_pallas)
+        if use_hotset:
+            raw_bal = hotset.hot_gather(db.bal, db.hot_bal, flat_rows, midx,
+                                        1)
         else:
-            raw_bal = (pg.gather_rows(db.bal, flat_rows, 1) if use_pallas
-                       else db.bal[flat_rows])
+            raw_bal = db.bal[flat_rows]
         bal = jnp.where(granted, raw_bal.astype(I32).reshape(w, L), 0)
 
     with waves.scope("smallbank_dense", "compute"):
@@ -456,78 +412,41 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
     # the S/X grants (lock-dominates-write), and the x_step/s_step writes
     # stamp the step scalar — the expiring-lock witness that discharges
     # abort-implies-unlock for this engine's release-free design.
-    with waves.scope("smallbank_dense",
-                     "install_log" if use_fused else "install"):
+    with waves.scope("smallbank_dense", "install"):
         dwf = c1.do_write.reshape(-1)
         wrows = jnp.where(dwf, c1.rows.reshape(-1), oob)       # [wL]
         newbal = c1.nw.reshape(-1)
-        if use_fused:
-            # install_log megakernel: balance install, log x3 append, and
-            # (hotset) the mirror write-through as masked row-scatter
-            # streams of ONE dispatch. The log plan is the exact
-            # append_rep plan (tables/log.plan_rep), so ring bytes match
-            # the unfused path bit for bit
-            newval = jnp.zeros((wrows.shape[0], VW), U32)
-            newval = newval.at[:, 0].set(newbal.astype(U32))
-            newval = newval.at[:, 1].set(
-                jnp.where(dwf, U32(MAGIC), U32(0)))
-            zero = jnp.zeros_like(newbal, U32)
-            stepv = jnp.broadcast_to(t, newbal.shape)
-            lflat, entry3, lane_counts = logring.plan_rep(
-                db.log, dwf, c1.tbl.reshape(-1), jnp.zeros_like(newbal),
-                zero, c1.acc.reshape(-1).astype(U32), stepv, newval)
-            widx = jnp.where(dwf, c1.rows.reshape(-1), -1)
-            tabs = [db.bal, db.log.entries.reshape(-1)]
-            idxs = [widx, lflat]
-            vals = [newbal.astype(U32), entry3.reshape(-1)]
-            vws = [1, db.log.entries.shape[1]]
-            if use_hotset:
-                w_acc = c1.acc.reshape(-1)
-                w_midx = jnp.where(dwf & (w_acc < hn),
-                                   c1.tbl.reshape(-1) * hn + w_acc, -1)
-                tabs += [db.hot_bal]
-                idxs += [w_midx]
-                vals += [newbal.astype(U32)]
-                vws += [1]
-            outs = pg.scatter_streams(tuple(tabs), tuple(idxs),
-                                      tuple(vals), tuple(vws))
-            bal_new = outs[0]
-            logs = db.log.replace(
-                entries=outs[1].reshape(db.log.entries.shape),
-                head=db.log.head + lane_counts)
-            hot_bal = outs[2] if use_hotset else db.hot_bal
-        elif use_hotset:
+        if use_hotset:
             # partitioned install: the full table AND the hot mirror take
-            # the write (one fused kernel on the pallas route, a double
-            # 1-D unique-index scatter on XLA) — the write-through that
-            # keeps mirror == table prefix an invariant, not a protocol
+            # the write (a double 1-D unique-index scatter) — the
+            # write-through that keeps mirror == table prefix an
+            # invariant, not a protocol
             w_acc = c1.acc.reshape(-1)
             w_midx = jnp.where(dwf & (w_acc < hn),
                                c1.tbl.reshape(-1) * hn + w_acc, -1)
-            bal_new, hot_bal = pg.hot_scatter(
+            bal_new, hot_bal = hotset.hot_scatter(
                 db.bal, db.hot_bal, c1.rows.reshape(-1), w_midx, dwf,
-                newbal.astype(U32), 1, use_pallas=use_pallas)
+                newbal.astype(U32), 1)
         else:
             hot_bal = db.hot_bal
             bal_new = db.bal.at[wrows].set(newbal.astype(U32), mode="drop",
                                            unique_indices=True)
 
-    if not use_fused:
-        with waves.scope("smallbank_dense", "log_append"):
-            with waves.part("smallbank_dense", "log_build"):
-                newval = jnp.zeros((wrows.shape[0], VW), U32)
-                newval = newval.at[:, 0].set(newbal.astype(U32))
-                newval = newval.at[:, 1].set(jnp.where(dwf, U32(MAGIC),
-                                                       U32(0)))
-                zero = jnp.zeros_like(newbal, U32)
-                # log ver = step index: monotonic per row (one X-writer
-                # per row per step), all recovery's max-ver-per-row rule
-                # needs
-                stepv = jnp.broadcast_to(t, newbal.shape)
-            logs = logring.append_rep(db.log, dwf, c1.tbl.reshape(-1),
-                                      jnp.zeros_like(newbal), zero,
-                                      c1.acc.reshape(-1).astype(U32),
-                                      stepv, newval)
+    with waves.scope("smallbank_dense", "log_append"):
+        with waves.part("smallbank_dense", "log_build"):
+            newval = jnp.zeros((wrows.shape[0], VW), U32)
+            newval = newval.at[:, 0].set(newbal.astype(U32))
+            newval = newval.at[:, 1].set(jnp.where(dwf, U32(MAGIC),
+                                                   U32(0)))
+            zero = jnp.zeros_like(newbal, U32)
+            # log ver = step index: monotonic per row (one X-writer
+            # per row per step), all recovery's max-ver-per-row rule
+            # needs
+            stepv = jnp.broadcast_to(t, newbal.shape)
+        logs = logring.append_rep(db.log, dwf, c1.tbl.reshape(-1),
+                                  jnp.zeros_like(newbal), zero,
+                                  c1.acc.reshape(-1).astype(U32),
+                                  stepv, newval)
 
     with waves.part("smallbank_dense", "sb_ctx"):
         db = db.replace(bal=bal_new, x_step=x_step, s_step=s_step,
@@ -578,18 +497,14 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
             hot_ctrs = {}
             if use_hotset:
                 # partition accounting: every hot-partitioned gather serves
-                # (midx >= 0) lanes from the mirror and the rest via cold row
-                # DMAs; the mirror refresh is one bulk DMA per pallas gather
-                # invocation (0 on the XLA partition route). The fused route
-                # reads the main arrays directly (no gather is partitioned),
-                # so its partition counters are structurally zero
-                n_g = 0 if use_fused else 1 + (2 if stamp_hot else 0)
+                # (midx >= 0) lanes from the mirror and the rest from the
+                # full tables
+                n_g = 1 + (2 if stamp_hot else 0)
                 hits = (midx >= 0).sum(dtype=I32)
                 hot_ctrs = {
                     mon.CTR_HOT_HITS: n_g * hits,
                     mon.CTR_HOT_COLD_ROWS: n_g * (w * L) - n_g * hits,
-                    mon.CTR_HOT_REFRESH_BYTES:
-                        (n_g * 2 * hn * 4) if use_pallas else 0,
+                    mon.CTR_HOT_REFRESH_BYTES: 0,
                 }
             serve_ctrs = {}
             if occupancy is not None:
@@ -615,9 +530,7 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
                 mon.CTR_LOCK_REJECT_ARB: (rej_l & ~held_l).sum(dtype=I32),
                 mon.CTR_INSTALL_WRITES: dwf.sum(dtype=I32),
                 mon.CTR_LOG_APPENDS: dwf.sum(dtype=I32),
-                (mon.CTR_DISPATCH_PALLAS if use_pallas
-                 else mon.CTR_DISPATCH_XLA): 1,
-                **({mon.CTR_FUSED_DISPATCH: 1} if use_fused else {}),
+                mon.CTR_DISPATCH_XLA: 1,
             })
             counters = mon.gauge_max(
                 counters, {mon.CTR_RING_HWM: logs.head.max()})
@@ -638,22 +551,11 @@ def build_pipelined_runner(n_accounts: int, w: int = 8192,
       init(db)        -> carry with one bootstrap cohort in flight
       drain(carry)    -> (db, stats [1, N_STATS]) flushing the pipeline
 
-    ``use_pallas``: None = honor DINT_USE_PALLAS env; a Mosaic refusal
-    raises pg.KernelRefused (ops/pallas_gather.resolve_use_pallas).
-
     ``use_hotset``: None = honor DINT_USE_HOTSET env. Serves the step's
     random gathers through the dintcache hot/cold partition; the hot set
     defaults to the WORKLOAD's hot set (``hot_frac``, else the SmallBank
     90%/4% skew constant) so the mirror covers exactly the keys the skew
     concentrates on. init() attaches the mirror to a db that lacks one.
-    Without use_pallas the XLA index-compare partition serves the split;
-    with it, a Mosaic refusal of the hot kernels raises.
-
-    ``use_fused``: None = honor DINT_USE_FUSED env; True/False forces.
-    Routes the step through the round-12 megakernels (gather-stream
-    lock_validate + scatter-stream install_log) after probing them at
-    this runner's geometry; a probe failure raises pg.KernelRefused
-    (pg.resolve_use_fused).
 
     ``monitor``: thread the dintmon counter plane — the carry grows a
     trailing monitor.Counters leaf and drain returns (db, stats,
@@ -674,24 +576,13 @@ def build_pipelined_runner(n_accounts: int, w: int = 8192,
     unchanged.
     """
     from ..clients import workloads as wl
-    use_hotset = pg.resolve_use_hotset(use_hotset)
-    use_pallas = pg.resolve_use_pallas(use_pallas, n_idx=w * L, m_lock=None)
+    refuse_kernel_flags(use_pallas, use_fused)
+    use_hotset = hotset.resolve_use_hotset(use_hotset)
     hot_n = 0
     if use_hotset:
         frac = wl.SB_HOT_FRAC if hot_frac is None else float(hot_frac)
         hot_n = max(1, min(int(n_accounts * frac), n_accounts))
-        if use_pallas:
-            pg.hot_kernels_available(n_idx=w * L)
-    ew3 = N_SHARDS * (logring.HDR_WORDS + VW)
-    scat_geoms = ((w * L, 1), (w * L, ew3))
-    if use_hotset:
-        scat_geoms = scat_geoms + ((w * L, 1),)
-    use_fused = pg.resolve_use_fused(
-        use_fused,
-        gathers=((w * L, 1), (w * L, 1), (w * L, 1)),
-        scatters=scat_geoms)
-    kw = dict(w=w, n_accounts=n_accounts, use_pallas=use_pallas,
-              use_hotset=use_hotset, use_fused=use_fused)
+    kw = dict(w=w, n_accounts=n_accounts, use_hotset=use_hotset)
     kw_gen = dict(kw, hot_frac=hot_frac, hot_prob=hot_prob, mix=mix)
     trace_on = txe.trace_enabled(trace)
     tcfg = None

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from ..monitor import waves
 from ..ops import hashing, segments
-from ..ops import pallas_gather as pg
+from ..ops import hotset
 from ..tables import kv
 from ..tables import run as run_mod
 from .types import Batch, Op, Replies, Reply, ScanReplies
@@ -45,9 +45,8 @@ class HotKV:
     mirror of keys (0, k) with key_lo < hot_n and key_hi == 0 — the head
     of the store benchmark's Zipfian distribution, whose rank IS the key
     id (clients/workloads.zipf_keys). The mirror replaces the val/ver
-    gathers of the probe for hot lanes (a VMEM-resident small array in
-    the pallas kernel, a small-array gather on XLA); installs write
-    through, so mirror == table for every key the probe can hit. Mirror
+    gathers of the probe for hot lanes (a small-array gather); installs
+    write through, so mirror == table for every key the probe can hit. Mirror
     entries of ABSENT keys are stale by design: every consumer of
     val0/ver0 in step() is masked by hit0."""
     val: jax.Array    # u32 [hot_n * VW]
@@ -71,7 +70,7 @@ def attach_hot(table: kv.KVTable, hot_n: int) -> HotKV:
 
 
 def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
-         hot: HotKV | None = None, use_pallas: bool = False,
+         hot: HotKV | None = None,
          run: run_mod.OrderedRun | None = None, scan_max: int = 8):
     """One server step: certify and apply a batch. Returns (table', replies)
     — plus `hot'` when the dintcache hot tier is threaded, plus
@@ -87,9 +86,6 @@ def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
     ``hot`` (a HotKV, or None = off): serve hot keys' val/ver reads from
     the mirror and write installs through to it — replies and table are
     bit-identical to the default path (tests/test_hotset.py).
-    ``use_pallas`` (static) routes the partitioned gathers/install
-    through the ops/pallas_gather hot kernels, and the scan window
-    through the streaming scan_rows kernel.
 
     ``run`` (a tables.run.OrderedRun, or None = off): serve Op.SCAN lanes
     from the ordered run's merged run∪delta view — scans are phase-1
@@ -118,10 +114,9 @@ def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
             kmidx = jnp.where((sb.key_hi == U32(0))
                               & (sb.key_lo < U32(hot_n)),
                               sb.key_lo.astype(I32), -1)
-            val0 = pg.hot_gather(table.val, hot.val, eidx0, kmidx, vw,
-                                 use_pallas=use_pallas).reshape(r, vw)
-            ver0 = pg.hot_gather(table.ver, hot.ver, eidx0, kmidx, 1,
-                                 use_pallas=use_pallas)
+            val0 = hotset.hot_gather(table.val, hot.val, eidx0, kmidx,
+                                     vw).reshape(r, vw)
+            ver0 = hotset.hot_gather(table.ver, hot.ver, eidx0, kmidx, 1)
     # insert destination: the emptier of the two candidate buckets
     dest = jnp.where(free2 > free1, b2, b1)
     bkt = jnp.where(hit0, fbkt, dest)
@@ -236,19 +231,17 @@ def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
             ver_new = table.ver.at[e_v].set(o_ver, mode="drop",
                                             unique_indices=True)
         else:
-            # write-through install: table entry AND key-indexed mirror (one
-            # fused kernel on the pallas route). One writer per key segment,
+            # write-through install: table entry AND key-indexed mirror.
+            # One writer per key segment,
             # so entry AND mirror indices are unique among masked lanes.
             w_midx = jnp.where(wv & (o_khi == U32(0))
                                & (o_klo < U32(hot_n)),
                                o_klo.astype(I32), -1)
             e_w = o_bkt * s + sl_v
-            val_new, hot_val = pg.hot_scatter(
-                table.val, hot.val, e_w, w_midx, wv, o_val.reshape(-1), vw,
-                use_pallas=use_pallas)
-            ver_new, hot_ver = pg.hot_scatter(
-                table.ver, hot.ver, e_w, w_midx, wv, o_ver, 1,
-                use_pallas=use_pallas)
+            val_new, hot_val = hotset.hot_scatter(
+                table.val, hot.val, e_w, w_midx, wv, o_val.reshape(-1), vw)
+            ver_new, hot_ver = hotset.hot_scatter(
+                table.ver, hot.ver, e_w, w_midx, wv, o_ver, 1)
             hot = hot.replace(val=hot_val, ver=hot_ver)
         table = table.replace(
             key_hi=table.key_hi.at[e_v].set(o_khi, mode="drop",
@@ -286,9 +279,9 @@ def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
         # below the lower bound are filtered by the >= start-key check)
         off_c = jnp.clip(off, 0, ne - lg_win)
         with waves.scope("store", "scan"):
-            s_hi, s_lo, s_ver, s_val = pg.scan_slab(
+            s_hi, s_lo, s_ver, s_val = run_mod.scan_slab(
                 run.key_hi, run.key_lo, run.ver, run.val, off_c, lg_win,
-                vw, use_pallas=use_pallas)
+                vw)
             # stale overlay => overflowed => the merged view may be missing
             # writes: answer no rows, reply RETRY (re-send after rebuild)
             slen = jnp.where(is_scan & ~run.stale,
@@ -338,7 +331,7 @@ def build_serve_runner(n_keys: int, w: int = 4096,
                        delta_cap: int | None = None,
                        hot_frac: float | None = None,
                        hot_prob: float | None = None,
-                       use_pallas=None, use_scan=None,
+                       use_scan=None,
                        monitor: bool = False, trace=None,
                        serve: bool = False):
     """Serve-plane runner for the store engine (dintscan's host workload):
@@ -363,11 +356,6 @@ def build_serve_runner(n_keys: int, w: int = 4096,
     stays sorted without ever stalling the step. Off: Op.SCAN is never
     generated and the carry/jaxpr are unchanged from the point engine.
 
-    ``use_pallas``: None = honor DINT_USE_PALLAS; gates BOTH the point
-    gathers and the sequential-DMA scan_rows kernel (bit-identical to
-    the XLA slab route by contract; a Mosaic refusal raises
-    pg.KernelRefused).
-
     ``serve``: variable-occupancy mode — run takes occ/shed i32
     [cohorts_per_block]; lanes >= occ are masked to NOP/PAD before the
     step (padded lanes, the serve reconciliation identity).
@@ -377,8 +365,7 @@ def build_serve_runner(n_keys: int, w: int = 4096,
     del trace
     from ..clients import workloads as wl
     from ..monitor import counters as mon
-    use_scan = pg.resolve_use_scan(use_scan)
-    use_pallas = pg.resolve_use_pallas(use_pallas, n_idx=w, m_lock=None)
+    use_scan = run_mod.resolve_use_scan(use_scan)
     hfrac = wl.SB_HOT_FRAC if hot_frac is None else float(hot_frac)
     hprob = wl.SB_HOT_PROB if hot_prob is None else float(hot_prob)
     hot_n = max(1, min(int(n_keys * hfrac), n_keys))
@@ -420,10 +407,9 @@ def build_serve_runner(n_keys: int, w: int = 4096,
         batch, admitted, scan_lanes = gen(key, occ)
         if use_scan:
             table, rep, run, srep = step(table, batch, run=run,
-                                         scan_max=scan_max,
-                                         use_pallas=use_pallas)
+                                         scan_max=scan_max)
         else:
-            table, rep = step(table, batch, use_pallas=use_pallas)
+            table, rep = step(table, batch)
             srep = None
         committed = (admitted
                      & ((rep.rtype == Reply.VAL)
@@ -434,8 +420,7 @@ def build_serve_runner(n_keys: int, w: int = 4096,
             mon.CTR_SERVE_OCC_LANES: occ,
             mon.CTR_SERVE_PAD_LANES: jnp.asarray(w, I32) - occ,
             mon.CTR_SERVE_SHED_LANES: shed,
-            (mon.CTR_DISPATCH_PALLAS if use_pallas
-             else mon.CTR_DISPATCH_XLA): 1,
+            mon.CTR_DISPATCH_XLA: 1,
             **({mon.CTR_SCAN_REQUESTS: scan_lanes.sum(dtype=I32),
                 mon.CTR_SCAN_ROWS: srep.count.sum(dtype=I32),
                 mon.CTR_SCAN_DELTA_HITS: srep.delta_hits.sum(dtype=I32)}

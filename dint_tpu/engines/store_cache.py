@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import hashing, segments
-from ..ops import pallas_gather as pg
+from ..ops import hotset
 from ..tables import kv
 from .types import Batch, Op, Replies, Reply
 
@@ -105,8 +105,7 @@ def _probe1_loc(t: kv.KVTable, key_hi, key_lo, bkt):
     return hit, slot, bkt * t.slots + slot
 
 
-def cache_step(cache: CacheTable, batch: Batch, *, policy: str = WB_BLOOM,
-               use_pallas: bool = False):
+def cache_step(cache: CacheTable, batch: Batch, *, policy: str = WB_BLOOM):
     """Certify a batch against the cache.
 
     Returns (cache', replies, miss, flush):
@@ -130,15 +129,13 @@ def cache_step(cache: CacheTable, batch: Batch, *, policy: str = WB_BLOOM,
     hn = _hot_n(cache)
     if hn:
         # dintcache partition: hot keys' val/ver from the mirror, cold
-        # from the cache entries (``use_pallas`` = the VMEM hot kernel)
+        # from the cache entries
         hit0, slot0, eidx0 = _probe1_loc(t, sb.key_hi, sb.key_lo, bkt)
         kmidx = jnp.where((sb.key_hi == U32(0)) & (sb.key_lo < U32(hn)),
                           sb.key_lo.astype(I32), -1)
-        val0 = pg.hot_gather(t.val, cache.hot_val, eidx0, kmidx,
-                             t.val_words,
-                             use_pallas=use_pallas).reshape(r, t.val_words)
-        ver0 = pg.hot_gather(t.ver, cache.hot_ver, eidx0, kmidx, 1,
-                             use_pallas=use_pallas)
+        val0 = hotset.hot_gather(t.val, cache.hot_val, eidx0, kmidx,
+                                 t.val_words).reshape(r, t.val_words)
+        ver0 = hotset.hot_gather(t.ver, cache.hot_ver, eidx0, kmidx, 1)
     else:
         hit0, slot0, val0, ver0 = _probe1(t, sb.key_hi, sb.key_lo, bkt)
 
@@ -213,13 +210,11 @@ def cache_step(cache: CacheTable, batch: Batch, *, policy: str = WB_BLOOM,
             # per key segment, distinct entries AND distinct key ids)
             w_midx = jnp.where(writer & (kmidx >= 0), kmidx, -1)
             e_raw = bkt * t2.slots + slot0
-            val_new, hot_val = pg.hot_scatter(
+            val_new, hot_val = hotset.hot_scatter(
                 t2.val, cache.hot_val, e_raw, w_midx, writer,
-                val_in[pos_last].reshape(-1), t2.val_words,
-                use_pallas=use_pallas)
-            ver_new, hot_ver = pg.hot_scatter(
-                t2.ver, cache.hot_ver, e_raw, w_midx, writer, new_ver, 1,
-                use_pallas=use_pallas)
+                val_in[pos_last].reshape(-1), t2.val_words)
+            ver_new, hot_ver = hotset.hot_scatter(
+                t2.ver, cache.hot_ver, e_raw, w_midx, writer, new_ver, 1)
             cache = cache.replace(
                 kv=t2.replace(val=val_new, ver=ver_new),
                 dirty=cache.dirty.at[e_w].set(True, mode="drop"),
@@ -292,12 +287,11 @@ def refill(cache: CacheTable, key_hi, key_lo, val, ver, bloom_hi, bloom_lo,
         midx = jnp.where(has_rec & (key_hi.astype(U32) == U32(0))
                          & (key_lo.astype(U32) < U32(hn)),
                          key_lo.astype(I32), -1)
-        val_new, hot_val = pg.hot_scatter(
+        val_new, hot_val = hotset.hot_scatter(
             t.val, cache.hot_val, e_vic, midx, has_rec, val.reshape(-1),
-            t.val_words, use_pallas=False)
-        ver_new, hot_ver = pg.hot_scatter(
-            t.ver, cache.hot_ver, e_vic, midx, has_rec, ver, 1,
-            use_pallas=False)
+            t.val_words)
+        ver_new, hot_ver = hotset.hot_scatter(
+            t.ver, cache.hot_ver, e_vic, midx, has_rec, ver, 1)
         cache = cache.replace(hot_val=hot_val, hot_ver=hot_ver)
     else:
         val_new = t.val.at[kv.val_word_idx(t, e_r)].set(

@@ -104,12 +104,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ._memo import memoize_builder
+from ._memo import memoize_builder, refuse_kernel_flags
 from ..monitor import counters as mon
 from ..monitor import txnevents as txe
 from ..monitor import waves
 from ..ops import compact
-from ..ops import pallas_gather as pg
+from ..ops import hotset
 from ..tables import log as logring
 from . import tatp
 from .types import Op, Reply
@@ -160,8 +160,8 @@ class DenseDB:
     # partition is exposed for skewed-TATP experiments): the hot set is
     # the flat ROW prefix [0, hot_n), which covers the subscriber-table
     # prefix — the table every transaction touches. hot_meta/hot_val are
-    # physical write-through mirrors of that prefix; the arb prefix needs
-    # no mirror (lock_arbitrate caches it in VMEM for the pass).
+    # physical write-through mirrors of that prefix; the arb prefix has
+    # no mirror.
     hot_meta: jax.Array | None = None   # u32 [hot_n]
     hot_val: jax.Array | None = None    # u32 [hot_n * VW]
     hot_n: int = flax.struct.field(pytree_node=False, default=0)
@@ -404,8 +404,7 @@ class Installs:
 def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
               n_sub: int, val_words: int, gen_new: bool = True, mix=None,
               emit_installs: bool = False, check_magic: bool = True,
-              use_pallas: bool = False, use_hotset: bool = False,
-              use_fused: bool = False,
+              use_hotset: bool = False,
               occupancy: jax.Array | None = None,
               shed: jax.Array | None = None,
               counters: mon.Counters | None = None,
@@ -419,33 +418,11 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
     acquires. Returns (db', new_ctx, c1', stats-of-c2), plus the Installs
     record when ``emit_installs`` (static) is set.
 
-    ``use_pallas`` (static) routes the step's random-access hot ops through
-    the Pallas DMA-ring kernels (ops/pallas_gather): the fused meta gather
-    and the magic-word gather become ring gathers, and the 3-op lock chain
-    (arb gather -> masked scatter-max -> winner gather-back) collapses into
-    ONE fused kernel pass — shortening the step's random-access dependency
-    chain from ~5 chained XLA ops to ~3. Outputs are bit-identical to the
-    XLA path (tests/test_pallas_ops.py); builders resolve the flag via
-    pg.resolve_use_pallas, which raises pg.KernelRefused when Mosaic
-    refuses a kernel that was asked for.
-
     ``use_hotset`` (static; OFF by default — TATP is uniform) serves the
     meta/magic gathers through the dintcache row-prefix partition (db must
     carry the mirror — attach_hotset), write-through at the wave-3
-    installs, and caches the arb prefix in VMEM inside the fused lock
-    pass. Bit-identical to the default path (tests/test_hotset.py);
+    installs. Bit-identical to the default path (tests/test_hotset.py);
     exposed for skewed-TATP experiments.
-
-    ``use_fused`` (static; OFF by default) swallows wave pairs into the
-    round-12 megakernels: lock arbitration + OCC validate-gather run as
-    ONE lock_validate dispatch, and the install scatter + replication-log
-    append run as ONE install_log scatter_streams dispatch — shortening
-    the chain from ~6 dispatches to ~4. Bit-identical to the unfused path
-    (tests/test_fused_ops.py); independent of ``use_pallas`` (the magic
-    gather still dispatches by use_pallas) and composes with
-    ``use_hotset`` (arb prefix stays VMEM-resident inside lock_validate;
-    installs write through the mirrors as extra streams). Builders
-    resolve via pg.resolve_use_fused (probe, or KernelRefused).
 
     ``occupancy``/``shed`` (device i32 scalars, or None = off): the
     dintserve variable-occupancy plane. Lanes >= occupancy of the freshly
@@ -493,8 +470,7 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
     # stay data-dependent on c2.alive — the chain grant -> alive ->
     # ~changed -> wmask is what proves lock-dominates-write and
     # validate-before-install; severing it fails the tier-1 gate.
-    with waves.scope("tatp_dense",
-                     "install_log" if use_fused else "install"):
+    with waves.scope("tatp_dense", "install"):
         with waves.part("tatp_dense", "install_build"):
             do_write = c2.ws_active & c2.alive[:, None]             # [w, 2]
             wmask = do_write.reshape(-1)
@@ -519,55 +495,24 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
             log_tbl = c2.ws_tbl.reshape(-1)
             log_key = c2.ws_key.reshape(-1).astype(U32)
             zero_hi = jnp.zeros_like(log_key)
-        if not use_fused:
-            # the 2w slots are ~9 % live (TATP writes 0.22 slots a txn) and
-            # a dropped index costs what a live one costs: the slots are
-            # ranked once, for the scatters of this step (meta and val
-            # here, the log's below), which issue the live ones C lanes a
-            # chunk, as many chunks as this step's live count needs
-            with waves.part("tatp_dense", "ws_compact"):
-                ranks, n_live = compact.live_ranks(wmask)
-        if use_fused:
-            # install_log megakernel: the val + meta installs, the
-            # replicated log append, and (hotset) the mirror write-through
-            # are N masked row-scatter streams of ONE dispatch. The log
-            # plan (lane/rank/slot + replica-packed rows) is the exact
-            # append_rep plan, so ring bytes match the unfused path
-            lflat, entry3, lane_counts = logring.plan_rep(
-                db.log, wmask, log_tbl, flags_del, zero_hi, log_key,
-                newver, newval)
-            wsr = c2.ws_rows.reshape(-1)
-            widx = jnp.where(wmask, wsr, -1)
-            tabs = [db.val, db.meta, db.log.entries.reshape(-1)]
-            idxs = [widx, widx, lflat]
-            vals = [newval.reshape(-1), meta_new, entry3.reshape(-1)]
-            vws = [val_words, 1, db.log.entries.shape[1]]
-            if use_hotset:
-                w_midx = jnp.where(wmask & (wsr < hn), wsr, -1)
-                tabs += [hot_val, hot_meta]
-                idxs += [w_midx, w_midx]
-                vals += [newval.reshape(-1), meta_new]
-                vws += [val_words, 1]
-            outs = pg.scatter_streams(tuple(tabs), tuple(idxs),
-                                      tuple(vals), tuple(vws))
-            val, meta = outs[0], outs[1]
-            logs = db.log.replace(
-                entries=outs[2].reshape(db.log.entries.shape),
-                head=db.log.head + lane_counts)
-            if use_hotset:
-                hot_val, hot_meta = outs[3], outs[4]
-        elif use_hotset:
+        # the 2w slots are ~9 % live (TATP writes 0.22 slots a txn) and
+        # a dropped index costs what a live one costs: the slots are
+        # ranked once, for the scatters of this step (meta and val
+        # here, the log's below), which issue the live ones C lanes a
+        # chunk, as many chunks as this step's live count needs
+        with waves.part("tatp_dense", "ws_compact"):
+            ranks, n_live = compact.live_ranks(wmask)
+        if use_hotset:
             # partitioned write-through install: the row prefix is the hot
-            # set, so mirror index == row for hot rows (fused kernel on the
-            # pallas route, double 1-D unique-index scatters on XLA)
+            # set, so mirror index == row for hot rows (double 1-D
+            # unique-index scatters)
             wsr = c2.ws_rows.reshape(-1)
             w_midx = jnp.where(wmask & (wsr < hn), wsr, -1)
-            meta, hot_meta = pg.hot_scatter(db.meta, hot_meta, wsr, w_midx,
-                                            wmask, meta_new, 1,
-                                            use_pallas=use_pallas)
-            val, hot_val = pg.hot_scatter(db.val, hot_val, wsr, w_midx,
-                                          wmask, newval.reshape(-1),
-                                          val_words, use_pallas=use_pallas)
+            meta, hot_meta = hotset.hot_scatter(db.meta, hot_meta, wsr,
+                                                w_midx, wmask, meta_new, 1)
+            val, hot_val = hotset.hot_scatter(db.val, hot_val, wsr, w_midx,
+                                              wmask, newval.reshape(-1),
+                                              val_words)
         else:
             with waves.part("tatp_dense", "ws_compact"):
                 def install_chunk(tabs, lanes, ok):
@@ -594,11 +539,10 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                     ranks, n_live, compact.chunk_lanes(2 * w), install_chunk,
                     (db.meta, db.val))
 
-    if not use_fused:
-        with waves.scope("tatp_dense", "log_append"):
-            logs = logring.append_rep_live(
-                db.log, ranks, n_live, wmask, log_tbl, flags_del, zero_hi,
-                log_key, newver, newval)
+    with waves.scope("tatp_dense", "log_append"):
+        logs = logring.append_rep_live(
+            db.log, ranks, n_live, wmask, log_tbl, flags_del, zero_hi,
+            log_key, newver, newval)
 
     # ---- wave 1: new cohort read + lock -----------------------------------
     if gen_new:
@@ -633,55 +577,23 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
         rows = jnp.where(used, base[tbl] + kk, sent)                # [w, K]
         is_read = ops == Op.OCC_READ
 
-    if use_fused:
-        # lock_validate megakernel: c1's validate re-read + verdict, the
-        # new cohort's fresh meta read, and the whole lock-arbitration RMW
-        # (hot_n arb-prefix residency included) in ONE dispatch. The meta
-        # reads ride the same kernel as the arb write-back; outputs are
-        # bit-identical to the unfused pair (tests/test_fused_ops.py).
-        # The lock chain runs on the arb array, independent of meta, so
-        # hoisting it into this wave cannot change any output.
-        with waves.scope("tatp_dense", "lock_validate"):
-            ws_rows = jnp.where(ws_active, base[ws_tbl] + ws_key,
-                                sent)                           # [w, 2]
-            flat_ws = ws_rows.reshape(-1)
-            active = ws_active.reshape(-1)
-            if counters is not None or ring is not None:
-                # won-vs-lost split needs the pre-arbitration stamps, read
-                # BEFORE the kernel aliases arb in place (read-before-
-                # donate, same as the unfused pallas route)
-                held = (db.arb[flat_ws] >> K_ARB) == (t - 1)
-                n_held = (active & held).sum(dtype=I32)
-            arb, grant_u, vbad, rmeta_f = pg.lock_validate(
-                db.arb, meta, c1.rows.reshape(-1), c1.vv1.reshape(-1),
-                rows.reshape(-1), flat_ws, active, t, K_ARB,
-                hot_n=hn if use_hotset else 0)
-            grant = (grant_u != 0).reshape(w, 2)
-            rmeta = rmeta_f.reshape(w, K)                       # [w, K]
-        # in-kernel verdict == (meta[vidx] != vv1); the is_read mask is
-        # applied here exactly as the unfused compare applied it
-        with waves.part("tatp_dense", "validate"):
-            bad = c1.is_read & (vbad.reshape(w, K) != 0)
-    else:
-        # ONE fused meta gather serves wave 2 (c1's validate re-read) AND
-        # wave 1 (the new cohort's reads). Both gathers depend on the same
-        # install scatter and on nothing else of each other, so XLA could
-        # overlap their DMAs (PERF.md round-3 finding 3) — the fusion still
-        # halves per-op launch/descriptor overhead on ops measured at
-        # 0.6-0.9 ms per 16-32k random indices
-        with waves.scope("tatp_dense", "meta_gather"):
-            gidx = jnp.concatenate([c1.rows.reshape(-1), rows.reshape(-1)])
-            if use_hotset:
-                g_midx = jnp.where(gidx < hn, gidx, -1)
-                g = pg.hot_gather(meta, hot_meta, gidx, g_midx, 1,
-                                  use_pallas=use_pallas)
-            else:
-                g = (pg.gather_rows(meta, gidx, 1) if use_pallas
-                     else meta[gidx])
-            vvB = g[: w * K].reshape(w, K)                      # [w, K]
-            rmeta = g[w * K:].reshape(w, K)                     # [w, K]
-        with waves.part("tatp_dense", "validate"):
-            bad = c1.is_read & (vvB != c1.vv1)
+    # ONE fused meta gather serves wave 2 (c1's validate re-read) AND
+    # wave 1 (the new cohort's reads). Both gathers depend on the same
+    # install scatter and on nothing else of each other, so XLA could
+    # overlap their DMAs (PERF.md round-3 finding 3) — the fusion still
+    # halves per-op launch/descriptor overhead on ops measured at
+    # 0.6-0.9 ms per 16-32k random indices
+    with waves.scope("tatp_dense", "meta_gather"):
+        gidx = jnp.concatenate([c1.rows.reshape(-1), rows.reshape(-1)])
+        if use_hotset:
+            g_midx = jnp.where(gidx < hn, gidx, -1)
+            g = hotset.hot_gather(meta, hot_meta, gidx, g_midx, 1)
+        else:
+            g = meta[gidx]
+        vvB = g[: w * K].reshape(w, K)                      # [w, K]
+        rmeta = g[w * K:].reshape(w, K)                     # [w, K]
+    with waves.part("tatp_dense", "validate"):
+        bad = c1.is_read & (vvB != c1.vv1)
 
     # ---- wave 2 of c1: validate read-set version compare ------------------
     with waves.part("tatp_dense", "validate"):
@@ -716,11 +628,10 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                 # the mirror is the flat word prefix [0, hn*VW): a hot
                 # row's magic word sits at the same flat offset in it
                 mg_midx = jnp.where((rows < hn).reshape(-1), midx, -1)
-                rmagic = pg.hot_gather(val, hot_val, midx, mg_midx, 1,
-                                       use_pallas=use_pallas).reshape(w, K)
+                rmagic = hotset.hot_gather(val, hot_val, midx, mg_midx,
+                                           1).reshape(w, K)
             else:
-                rmagic = (pg.gather_rows(val, midx, 1).reshape(w, K)
-                          if use_pallas else val[midx].reshape(w, K))
+                rmagic = val[midx].reshape(w, K)
             magic_bad = jnp.sum(is_read & rex & (rmagic != MAGIC),
                                 dtype=I32)
     else:
@@ -733,9 +644,8 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
     # install chain. held = stamped by the previous step; c2's stamps
     # (t-2) expired this step, matching the wave-3 release timing above.
     # Candidates for held rows are masked OUT of the scatter so rejected
-    # attempts cannot keep a hot row stamped (no livelock). On the fused
-    # route the whole chain already ran inside lock_validate above.
-    # On the XLA route the three table ops issue the ACTIVE slots only
+    # attempts cannot keep a hot row stamped (no livelock).
+    # The three table ops issue the ACTIVE slots only
     # (TATP's mix leaves ~11 % of the 2w active, and a dropped scatter
     # index, like a sentinel gather lane, costs what a live one costs):
     # the slots are ranked once and run C lanes a chunk, in two loops,
@@ -753,75 +663,52 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
     # 2w / C trips of each loop: the trade the install took (PR 30).
     with waves.part("tatp_dense", "ws_pick"):
         ws_vv = jnp.take_along_axis(rmeta, ws_lane, axis=1)
-    if not use_fused:
-        with waves.scope("tatp_dense", "lock"):
-            ws_rows = jnp.where(ws_active, base[ws_tbl] + ws_key,
-                                sent)                           # [w, 2]
-            flat_ws = ws_rows.reshape(-1)
-            active = ws_active.reshape(-1)
-            if use_pallas:
-                if counters is not None or ring is not None:
-                    # the fused kernel only exposes winners; the
-                    # won-vs-lost split needs the pre-arbitration stamps,
-                    # read BEFORE the kernel aliases arb in place (a
-                    # read-before-donate, which the dintlint aliasing pass
-                    # permits; bit-identical to the XLA path's arb_old
-                    # gather)
-                    held = ((pg.gather_rows(db.arb, flat_ws, 1) >> K_ARB)
-                            == (t - 1))
-                    n_held = (active & held).sum(dtype=I32)
-                # fused kernel pass: gather + stamp compare + first-lane-
-                # wins scatter-max + winner read-back in ONE launch, arb
-                # updated in place (bit-identical to the XLA chain below —
-                # pinned in tests/test_pallas_ops.py)
-                # hot_n > 0 caches the arb prefix in VMEM for the pass
-                # (dintcache); outputs bit-identical either way
-                arb, grant_u = pg.lock_arbitrate(
-                    db.arb, flat_ws, active, t, K_ARB,
-                    hot_n=hn if use_hotset else 0)
-                grant = (grant_u != 0).reshape(w, 2)
-            else:
-                with waves.part("tatp_dense", "lock_compact"):
-                    a_ranks, n_act = compact.live_ranks(active)
-                    a_chunk = compact.chunk_lanes(2 * w)
+    with waves.scope("tatp_dense", "lock"):
+        ws_rows = jnp.where(ws_active, base[ws_tbl] + ws_key,
+                            sent)                               # [w, 2]
+        flat_ws = ws_rows.reshape(-1)
+        active = ws_active.reshape(-1)
+        with waves.part("tatp_dense", "lock_compact"):
+            a_ranks, n_act = compact.live_ranks(active)
+            a_chunk = compact.chunk_lanes(2 * w)
 
-                    def packed(lanes):
-                        # the slot's own id, so the first slot still wins
-                        return ((t << K_ARB)
-                                | (U32(2 * w - 1) - lanes.astype(U32)))
+            def packed(lanes):
+                # the slot's own id, so the first slot still wins
+                return ((t << K_ARB)
+                        | (U32(2 * w - 1) - lanes.astype(U32)))
 
-                    def stamp_chunk(state, lanes, ok):
-                        arb, n_held, held = state
-                        rows_c = flat_ws[lanes]
-                        with waves.part("tatp_dense", "lock_read"):
-                            held_c = ok & ((arb[rows_c] >> K_ARB) == t - 1)
-                        with waves.part("tatp_dense", "lock_scatter_max"):
-                            arb = arb.at[
-                                jnp.where(ok & ~held_c, rows_c, oob)].max(
-                                packed(lanes), mode="drop")
-                        if ring is not None:
-                            # per lane only for the flight recorder
-                            held = held | compact.lanes_mask(
-                                lanes, held_c, 2 * w)
-                        return arb, n_held + held_c.sum(dtype=I32), held
+            def stamp_chunk(state, lanes, ok):
+                arb, n_held, held = state
+                rows_c = flat_ws[lanes]
+                with waves.part("tatp_dense", "lock_read"):
+                    held_c = ok & ((arb[rows_c] >> K_ARB) == t - 1)
+                with waves.part("tatp_dense", "lock_scatter_max"):
+                    arb = arb.at[
+                        jnp.where(ok & ~held_c, rows_c, oob)].max(
+                        packed(lanes), mode="drop")
+                if ring is not None:
+                    # per lane only for the flight recorder
+                    held = held | compact.lanes_mask(
+                        lanes, held_c, 2 * w)
+                return arb, n_held + held_c.sum(dtype=I32), held
 
-                    def grant_chunk(grant, lanes, ok):
-                        rows_c = flat_ws[lanes]
-                        with waves.part("tatp_dense", "lock_readback"):
-                            won_c = ok & (arb[rows_c] == packed(lanes))
-                        return grant | compact.lanes_mask(lanes, won_c, 2 * w)
+            def grant_chunk(grant, lanes, ok):
+                rows_c = flat_ws[lanes]
+                with waves.part("tatp_dense", "lock_readback"):
+                    won_c = ok & (arb[rows_c] == packed(lanes))
+                return grant | compact.lanes_mask(lanes, won_c, 2 * w)
 
-                    # (a drain's cohort is constants, its arb a shard's)
-                    no_lanes = compact.varying_like(
-                        jnp.zeros_like(active), db.arb)
-                    (arb, n_held, held), lock_chunks = compact.for_chunks(
-                        a_ranks, n_act, a_chunk, stamp_chunk,
-                        (db.arb,
-                         compact.varying_like(jnp.zeros_like(n_act), db.arb),
-                         None if ring is None else no_lanes))
-                    grant, _ = compact.for_chunks(
-                        a_ranks, n_act, a_chunk, grant_chunk, no_lanes)
-                    grant = grant.reshape(w, 2)
+            # (a drain's cohort is constants, its arb a shard's)
+            no_lanes = compact.varying_like(
+                jnp.zeros_like(active), db.arb)
+            (arb, n_held, held), lock_chunks = compact.for_chunks(
+                a_ranks, n_act, a_chunk, stamp_chunk,
+                (db.arb,
+                 compact.varying_like(jnp.zeros_like(n_act), db.arb),
+                 None if ring is None else no_lanes))
+            grant, _ = compact.for_chunks(
+                a_ranks, n_act, a_chunk, grant_chunk, no_lanes)
+            grant = grant.reshape(w, 2)
 
     with waves.part("tatp_dense", "classify"):
         # reply types: reads from the gather; write-slot GRANT/REJECT direct
@@ -860,27 +747,16 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
             n_grant = (active & grant.reshape(-1)).sum(dtype=I32)
             hot_ctrs = {}
             if use_hotset:
-                # partition accounting over the meta + magic gathers (the arb
-                # prefix residency has no per-lane split to count). The fused
-                # lock_validate reads the main meta table directly (bit-
-                # identical by the mirror invariant), so its lanes are not
-                # partitioned and only the magic gather counts there
-                if use_fused:
-                    hits = jnp.asarray(0, I32)
-                    lanes = 0
-                    refresh = 0
-                else:
-                    hits = (g_midx >= 0).sum(dtype=I32)
-                    lanes = 2 * w * K
-                    refresh = hn * 4
+                # partition accounting over the meta + magic gathers
+                hits = (g_midx >= 0).sum(dtype=I32)
+                lanes = 2 * w * K
                 if check_magic:
                     hits = hits + (mg_midx >= 0).sum(dtype=I32)
                     lanes += w * K
-                    refresh += hn * val_words * 4
                 hot_ctrs = {
                     mon.CTR_HOT_HITS: hits,
                     mon.CTR_HOT_COLD_ROWS: lanes - hits,
-                    mon.CTR_HOT_REFRESH_BYTES: refresh if use_pallas else 0,
+                    mon.CTR_HOT_REFRESH_BYTES: 0,
                 }
             serve_ctrs = {}
             if occupancy is not None:
@@ -910,13 +786,10 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                 mon.CTR_VALIDATE_FAILED: v_failed,
                 mon.CTR_INSTALL_WRITES: wmask.sum(dtype=I32),
                 mon.CTR_LOG_APPENDS: wmask.sum(dtype=I32),
-                (mon.CTR_DISPATCH_PALLAS if use_pallas
-                 else mon.CTR_DISPATCH_XLA): 1,
-                **({mon.CTR_FUSED_DISPATCH: 1} if use_fused else {}),
-                **({} if use_fused or use_hotset
+                mon.CTR_DISPATCH_XLA: 1,
+                **({} if use_hotset
                    else {mon.CTR_INSTALL_CHUNKS: chunks}),
-                **({} if use_fused or use_pallas
-                   else {mon.CTR_LOCK_CHUNKS: lock_chunks}),
+                mon.CTR_LOCK_CHUNKS: lock_chunks,
             })
             counters = mon.gauge_max(
                 counters, {mon.CTR_RING_HWM: logs.head.max()})
@@ -1002,8 +875,7 @@ def build_pipelined_runner(n_sub: int, w: int = 8192, val_words: int = 10,
                            cohorts_per_block: int = 8, mix=None,
                            check_magic: bool = True, use_pallas=None,
                            use_hotset: bool = False, hot_frac=None,
-                           use_fused=None, log_replicas: int = N_SHARDS,
-                           monitor: bool = False, trace=None,
+                           use_fused=None, monitor: bool = False, trace=None,
                            trace_rate=None, trace_cap=None,
                            serve: bool = False):
     """jit(scan(pipe_step)) over carry (db, c1, c2); same contract as
@@ -1017,24 +889,12 @@ def build_pipelined_runner(n_sub: int, w: int = 8192, val_words: int = 10,
     drain are unchanged, so the serving engine reuses the closed-loop
     drain verbatim.
 
-    ``use_pallas``: None = honor DINT_USE_PALLAS env; True/False forces.
-    When requested, the Pallas kernels are probed at this runner's lane
-    geometry and a Mosaic refusal raises pg.KernelRefused
-    (ops/pallas_gather.resolve_use_pallas).
-
     ``use_hotset`` / ``hot_frac``: the dintcache row-prefix partition,
     OFF by default and deliberately NOT env-driven here — TATP's NURand
     workload is near-uniform, so the hot tier only pays at this engine
     unless the experiment skews it; pass use_hotset=True (hot_frac = the
     mirrored fraction of the subscriber prefix, default 4%) for
     skewed-TATP experiments. init() attaches the mirror.
-
-    ``use_fused``: None = honor DINT_USE_FUSED env; True/False forces.
-    Routes the step through the round-12 megakernels (lock_validate +
-    install_log) after probing them at this runner's geometry —
-    ``log_replicas`` must match the DenseDB's log (it sizes the log
-    stream's row width for the probe). A probe failure raises
-    pg.KernelRefused (pg.resolve_use_fused).
 
     ``monitor``: thread the dintmon counter plane through the carry. The
     carry grows a trailing monitor.Counters leaf (init creates it; read
@@ -1053,27 +913,13 @@ def build_pipelined_runner(n_sub: int, w: int = 8192, val_words: int = 10,
     engine outputs bit-identical, not one extra jaxpr eqn."""
     assert 2 * w <= (1 << K_ARB), f"w={w} exceeds the arb slot field"
     use_hotset = bool(use_hotset)
-    use_pallas = pg.resolve_use_pallas(use_pallas, n_idx=2 * w * K,
-                                       m_lock=2 * w, k_arb=K_ARB)
+    refuse_kernel_flags(use_pallas, use_fused)
     hot_rows = 0
     if use_hotset:
         frac = 0.04 if hot_frac is None else float(hot_frac)
         hot_rows = max(1, min(int((n_sub + 1) * frac), n_rows(n_sub)))
-        if use_pallas:
-            pg.hot_kernels_available(n_idx=2 * w * K, m_lock=2 * w,
-                                     k_arb=K_ARB)
-    ew3 = int(log_replicas) * (logring.HDR_WORDS + val_words)
-    scat_geoms = ((2 * w, val_words), (2 * w, 1), (2 * w, ew3))
-    if use_hotset:
-        scat_geoms = scat_geoms + ((2 * w, val_words), (2 * w, 1))
-    use_fused = pg.resolve_use_fused(
-        use_fused,
-        lockv=(w * K, w * K, 2 * w, K_ARB,
-               hot_rows if use_hotset else 0),
-        scatters=scat_geoms)
     kw = dict(w=w, n_sub=n_sub, val_words=val_words,
-              check_magic=check_magic, use_pallas=use_pallas,
-              use_hotset=use_hotset, use_fused=use_fused)
+              check_magic=check_magic, use_hotset=use_hotset)
     trace_on = txe.trace_enabled(trace)
     tcfg = None
     if trace_on:

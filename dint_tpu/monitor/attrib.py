@@ -52,32 +52,17 @@ DEFAULT_STEP_PCT = 10.0
 DEFAULT_RATE_PCT = 10.0
 DEFAULT_MIN_MS = 0.05
 
-# Round-12 fused megakernels: each swallows a PAIR of unfused waves, so a
-# fused-vs-unfused A/B sees the constituents vanish on one side. Without
-# folding, the diff reports them under "missing" and the fused successor
-# as an infinite regression — both meaningless. This map sends each
-# swallowed constituent to its fused successor; diff_breakdowns folds the
-# constituents' time into the successor on BOTH sides whenever either
-# side observed the fused wave, so the gate compares like against like
-# (the unfused side's lock + meta_gather total vs the fused side's one
-# lock_validate dispatch). `tools/dintscope.py diff --no-alias` disables
-# the fold for debugging raw per-scope time. Waves that only SHRINK under
-# fusion (smallbank's lock scope keeps its XLA scatter-mins; the sharded
-# install_route keeps its all_to_all) still alias: their remaining time
-# plus the megakernel is exactly what the unfused scope used to cover.
+# A route that runs a wave's work under another scope makes an A/B see the
+# wave vanish on one side. Without folding, the diff reports it under
+# "missing" and the successor as an infinite regression — both
+# meaningless. This map sends each such wave to its successor;
+# diff_breakdowns folds its time into the successor on BOTH sides
+# whenever the two sides observed the pair differently, so the gate
+# compares like against like. `tools/dintscope.py diff --no-alias`
+# disables the fold for debugging raw per-scope time.
 WAVE_ALIASES: dict[str, str] = {
     waves.full_name(e, src): waves.full_name(e, dst)
     for e, src, dst in (
-        ("tatp_dense", "lock", "lock_validate"),
-        ("tatp_dense", "meta_gather", "lock_validate"),
-        ("tatp_dense", "install", "install_log"),
-        ("tatp_dense", "log_append", "install_log"),
-        ("smallbank_dense", "lock", "lock_validate"),
-        ("smallbank_dense", "read", "lock_validate"),
-        ("smallbank_dense", "install", "install_log"),
-        ("smallbank_dense", "log_append", "install_log"),
-        ("dense_sharded_sb", "arbitrate", "lock_validate"),
-        ("dense_sharded_sb", "install_route", "install_log"),
         # overlap=True moves the mesh route's exchange one step early
         # under its own scope — an overlap-on vs overlap-off A/B sees
         # `route` vanish on one side; fold it into route_prefetch so the
@@ -285,15 +270,14 @@ def _wave_observed(w: dict, name: str) -> bool:
 
 
 def _fold_aliases(wa: dict, wb: dict) -> tuple[dict, dict, dict]:
-    """Fold WAVE_ALIASES constituents into their fused successor on both
+    """Fold WAVE_ALIASES constituents into their successor on both
     sides of a diff — but ONLY for successors whose observation pattern
-    is asymmetric between the sides (one side dispatched the megakernel,
-    the other ran the unfused pair). A symmetric diff (unfused vs
-    unfused, fused vs fused, or the all-waves synthetic fixture) never
-    folds: its per-wave rows are already like-for-like and folding would
-    only blur which wave moved. Returns (wa', wb', folded) where folded
-    maps each triggered fused wave to the sorted constituents merged
-    into it."""
+    is asymmetric between the sides (one side ran the successor's
+    scope, the other the constituent's). A symmetric diff (or the
+    all-waves synthetic fixture) never folds: its per-wave rows are
+    already like-for-like and folding would only blur which wave moved.
+    Returns (wa', wb', folded) where folded maps each triggered
+    successor to the sorted constituents merged into it."""
     targets: dict[str, list[str]] = {}
     for src, dst in WAVE_ALIASES.items():
         oa, ob = _wave_observed(wa, dst), _wave_observed(wb, dst)
@@ -340,10 +324,9 @@ def diff_breakdowns(a: dict, b: dict, *, wave_pct: float = DEFAULT_WAVE_PCT,
     under ``min_ms`` on both sides — dispatch noise), the attributed step
     time growing past ``step_pct`` %, committed throughput falling past
     ``rate_pct`` % (when both artifacts carry rates). With ``alias``
-    (default), WAVE_ALIASES folds the round-12 megakernels' swallowed
-    constituents into the fused wave on both sides before comparing, so a
-    fused-vs-unfused A/B attributes removed waves to their fused
-    successor instead of reporting them missing. Returns a dict with
+    (default), WAVE_ALIASES folds a wave another scope took over into
+    that successor on both sides before comparing, so the A/B attributes
+    the removed wave to its successor instead of reporting it missing. Returns a dict with
     ``regressions`` (list of {kind, wave?, a, b, pct} — empty = gate
     passes); `tools/dintscope.py diff` exits 1 when it is non-empty."""
     regressions = []

@@ -91,24 +91,21 @@ _REGISTRY: tuple[tuple[str, str, str], ...] = (
      "log-ring high-water mark: max monotonic lane head observed "
      "(occupancy = min(ring_hwm, capacity))"),
     ("dispatch_xla", FLOW,
-     "steps whose random-access ops ran the XLA path"),
+     "steps run (the engines have one route: equals steps)"),
     ("dispatch_pallas", FLOW,
-     "steps whose random-access ops ran the Pallas DMA-ring kernels"),
+     "reads 0: no engine bumps it (kept because the registry is "
+     "append-only and recorded JSONL indexes it)"),
     ("hot_hits", FLOW,
      "hot-partition gather lanes served from the dintcache mirror "
      "(DINT_USE_HOTSET; hot_hits + hot_cold_rows = partitioned lanes)"),
     ("hot_cold_rows", FLOW,
      "hot-partition gather lanes that fell through to cold full-table "
-     "row access (the DMA ring on pallas, the big-array gather on XLA)"),
+     "row access (the big-array gather)"),
     ("hot_refresh_bytes", FLOW,
-     "bytes of hot-mirror bulk refresh DMA'd to VMEM by the pallas hot "
-     "kernels (one mirror copy per partitioned gather; 0 on the XLA "
-     "partition route, which has no residency to refresh)"),
+     "reads 0: the index-compare partition has no residency to refresh "
+     "(kept: append-only registry)"),
     ("fused_dispatch", FLOW,
-     "steps whose paired waves ran the round-12 megakernels "
-     "(lock_validate + install_log); counted ALONGSIDE dispatch_xla/"
-     "dispatch_pallas — the magic gather still dispatches by use_pallas, "
-     "so fused_dispatch <= steps and the xla/pallas split stays total"),
+     "reads 0: no engine bumps it (kept: append-only registry)"),
     ("route_ici_lanes", FLOW,
      "routed lanes (lock requests + installs) whose owner lives on the "
      "SAME host: the exchange crosses only the ICI axis (2-D sharded "
@@ -162,14 +159,14 @@ _REGISTRY: tuple[tuple[str, str, str], ...] = (
      "install_chunks == sum over steps of ceil(install_writes / C): one a "
      "step under TATP's mix, none for a step with nothing to write; "
      "install_writes / (C x install_chunks) is the fill share of the "
-     "indices the scatters issue. 0 on the fused and hot-tier routes"),
+     "indices the scatters issue. 0 on the hot-tier route"),
     ("lock_chunks", FLOW,
      "lock-wave compaction (ops/compact.py): chunk trips of the dense "
      "TATP lock wave's first loop (the second makes as many), C = "
      "chunk_lanes(2w) lanes a trip. lock_chunks == sum over steps of "
      "ceil(lock_requests / C); lock_requests / (C x lock_chunks) is the "
      "fill share of the lanes the stamp gather, the scatter-max and the "
-     "winner read-back issue. 0 on the fused and pallas routes"),
+     "winner read-back issue"),
 )
 
 ALL_NAMES: tuple[str, ...] = tuple(n for n, _, _ in _REGISTRY)

@@ -77,8 +77,7 @@ _REGISTRY: tuple[tuple[str, str, str, str | None], ...] = (
     ("tatp_dense", "lock",
      "lock arbitration on the arb array: stamp gather + masked "
      "scatter-max + winner gather-back (the active ones of the 2w write "
-     "slots, C lanes a chunk, priced at all 2w active; ONE fused kernel "
-     "pass over all 2w on the pallas route)", "3*2*w*4"),
+     "slots, C lanes a chunk, priced at all 2w active)", "3*2*w*4"),
     ("tatp_dense", "rebase",
      "arb stamp rebase (full elementwise pass, once per ~16k steps — "
      "amortizes to noise; bytes unmodeled: streaming elementwise, not "
@@ -161,37 +160,6 @@ _REGISTRY: tuple[tuple[str, str, str, str | None], ...] = (
      "backup fan-out: ppermute applied installs to owner+1/+2, apply to "
      "backup copies + append local logs (2 hops x wL balance rows + a "
      "log append each)", "2*(w*l*4 + w*l*3*(20 + 4*vw))"),
-    # --- round-12 fused megakernels (ops/pallas_gather.lock_validate +
-    # --- scatter_streams); each swallows a PAIR of the waves above.
-    # --- tools/dintscope.py maps the swallowed constituents onto these
-    # --- successors in fused-vs-unfused A/Bs (WAVE_ALIASES, attrib.py) --
-    ("tatp_dense", "lock_validate",
-     "megakernel: c1's validate ring-read + verdict, the new cohort's "
-     "fresh meta gather, and the whole lock-arbitration RMW in ONE "
-     "dispatch (swallows meta_gather + lock)", "3*2*w*4 + 2*w*k*4"),
-    ("tatp_dense", "install_log",
-     "megakernel: meta + val installs, the replicated log append, and "
-     "the hot-mirror write-through as N masked row-scatter streams of "
-     "ONE dispatch (swallows install + log_append)",
-     "2*w*(4 + 4*vw) + 2*w*3*(20 + 4*vw)"),
-    ("smallbank_dense", "lock_validate",
-     "megakernel: the lock wave's held-stamp gathers + the balance read "
-     "as gather streams of ONE dispatch (swallows lock's gathers + "
-     "read; the scatter-mins and grant compare stay XLA)", "6*w*l*4"),
-    ("smallbank_dense", "install_log",
-     "megakernel: balance install + log x3 append (+ hot-mirror "
-     "write-through) as scatter streams of ONE dispatch (swallows "
-     "install + log_append)", "w*l*4 + w*l*3*(20 + 4*vw)"),
-    ("dense_sharded_sb", "lock_validate",
-     "owner-side megakernel: arbitration stamp/balance gathers as "
-     "gather streams of ONE dispatch (swallows arbitrate's gathers; "
-     "5 passes over the 2wL routed slots, like arbitrate)",
-     "5*2*w*l*4"),
-    ("dense_sharded_sb", "install_log",
-     "owner-side megakernel: primary balance install + owner CommitLog "
-     "append as scatter streams of ONE dispatch (swallows "
-     "install_route's writes; routing stays all_to_all)",
-     "w*l*8 + w*l*3*(20 + 4*vw)"),
     # --- 2-D multi-host SmallBank (parallel/multihost_sb.py): the same
     # --- cross-shard step over the (dcn x ici) mesh. Hierarchical
     # --- routing runs each exchange TWICE (ici stage + host-aggregated
@@ -291,8 +259,8 @@ _REGISTRY: tuple[tuple[str, str, str, str | None], ...] = (
      "point gathers per lane per round over lg rounds", "w*lg*8"),
     ("store", "scan",
      "sequential window slab over the ordered run: per lane sl+dc "
-     "contiguous rows of (key_hi,key_lo,ver,val[vw]) = 12+4vw B/row, "
-     "one DMA stream per lane on the pallas route", "w*(sl+dc)*(12+4*vw)"),
+     "contiguous rows of (key_hi,key_lo,ver,val[vw]) = 12+4vw B/row",
+     "w*(sl+dc)*(12+4*vw)"),
     ("store", "delta_append",
      "write-through overlay append + latest-wins re-sort of the dc-row "
      "delta — sort-bound, bytes unmodeled", None),
@@ -370,7 +338,7 @@ _DENSE = ("tatp_dense", "smallbank_dense")    # the engine-neutral parts
 # op's name stack is the one its time is booked to
 # (benchmarks/part_times.py).
 _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
-    # --- dense TATP (engines/tatp_dense.py), the XLA route --------------
+    # --- dense TATP (engines/tatp_dense.py) -----------------------------
     ("tatp_dense", "install", "install_build",
      "masks, new meta words, payload draw and the [2w, VW] new rows"),
     ("tatp_dense", "install", "meta_scatter",
@@ -421,7 +389,7 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
      "the chunk loop of the install, each chunk's lane search (C x 2w "
      "compares) and its gathers of row ids, meta words and value rows "
      "out of the 2w-wide operands"),
-    # --- dense SmallBank (engines/smallbank_dense.py), the XLA route ----
+    # --- dense SmallBank (engines/smallbank_dense.py) -------------------
     ("smallbank_dense", "lock", "lock_arb",
      "the arbitration proper: two fresh slot-table-wide arrays filled "
      "and scatter-min'ed with the lane index over the X and the S "

@@ -1,1 +1,1 @@
-from . import u64, hashing, segments, pallas_gather  # noqa: F401
+from . import u64, hashing, segments  # noqa: F401

@@ -45,10 +45,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..engines import tatp_dense as td
-from ..engines._memo import memoize_builder
+from ..engines._memo import memoize_builder, refuse_kernel_flags
 from ..monitor import counters as mon
 from ..monitor import waves
-from ..ops import pallas_gather as pg
 from ..tables import log as logring
 from .sharded import (SHARD_AXIS, make_mesh, pcast_varying,   # noqa: F401 (re-exported)
                       stack_on_mesh)
@@ -163,20 +162,6 @@ def build_sharded_pipelined_runner(mesh: Mesh, n_shards: int,
       init(state)     -> carry with two bootstrap cohorts per device
       drain(carry)    -> (state, stats [2, N_STATS]) flushing pipelines
 
-    ``use_pallas``: None = honor DINT_USE_PALLAS env; the per-device
-    pipe_step then runs the DMA-ring kernels on ITS shard's local arrays
-    (shard_map bodies see local shapes, so the kernels drop straight in).
-    The probe runs once outside shard_map; a Mosaic refusal raises
-    pg.KernelRefused.
-
-    ``use_fused``: None = honor DINT_USE_FUSED env. Routes each device's
-    local pipe_step through the round-12 megakernels (lock_validate +
-    install_log) at the shard-local geometry (log stream width uses this
-    path's log_replicas=1 rings); the replicate fan-out stays the
-    ppermute + XLA backup apply, so REPL_PUSHED provenance is unchanged.
-    Probed once outside shard_map like use_pallas; a probe failure
-    raises.
-
     ``monitor``: thread the dintmon counter plane PER DEVICE — the carry
     grows a trailing stacked monitor.Counters (buf [D, N_COUNTERS]; each
     device bumps its own slice inside shard_map, with the replication
@@ -185,17 +170,10 @@ def build_sharded_pipelined_runner(mesh: Mesh, n_shards: int,
     psummed stats totals (monitor.snapshot does that reduction); off
     (default) = contract and jaxpr unchanged."""
     assert 2 * w <= (1 << td.K_ARB), f"w={w} exceeds the arb slot field"
-    use_pallas = pg.resolve_use_pallas(
-        use_pallas, n_idx=2 * w * td.K, m_lock=2 * w, k_arb=td.K_ARB)
+    refuse_kernel_flags(use_pallas, use_fused)
     n_loc = n_sub_local(n_sub_global, n_shards)
     n1 = td.n_rows(n_loc) + 1
-    ew1 = logring.HDR_WORDS + val_words          # log_replicas=1 rings
-    use_fused = pg.resolve_use_fused(
-        use_fused,
-        lockv=(w * td.K, w * td.K, 2 * w, td.K_ARB, 0),
-        scatters=((2 * w, val_words), (2 * w, 1), (2 * w, ew1)))
-    kw = dict(w=w, n_sub=n_loc, val_words=val_words,
-              use_pallas=use_pallas, use_fused=use_fused)
+    kw = dict(w=w, n_sub=n_loc, val_words=val_words)
 
     def local_step(state, c1, c2, key, cnt, gen_new=True):
         dev = jax.lax.axis_index(SHARD_AXIS)
@@ -271,14 +249,11 @@ def build_sharded_pipelined_runner(mesh: Mesh, n_shards: int,
 
     n_carry = 4 if monitor else 3
     spec = (P(SHARD_AXIS),) * n_carry + (P(),)
-    check_vma = pg.shard_map_check_vma(use_pallas or use_fused)
     block = jax.shard_map(block_local, mesh=mesh, in_specs=spec,
-                          out_specs=(P(SHARD_AXIS),) * n_carry + (P(),),
-                          check_vma=check_vma)
+                          out_specs=(P(SHARD_AXIS),) * n_carry + (P(),))
     drain_m = jax.shard_map(
         drain_local, mesh=mesh, in_specs=spec,
-        out_specs=(P(SHARD_AXIS),) * (2 if monitor else 1) + (P(),),
-        check_vma=check_vma)
+        out_specs=(P(SHARD_AXIS),) * (2 if monitor else 1) + (P(),))
 
     donate = tuple(range(n_carry))
     jit_block = jax.jit(block, donate_argnums=donate)

@@ -67,7 +67,7 @@ from ..engines._memo import memoize_builder
 from ..monitor import counters as mon
 from ..monitor import txnevents as txe
 from ..monitor import waves
-from ..ops import pallas_gather as pg
+from ..ops import hotset
 from ..tables import log as logring
 from .sharded import SHARD_AXIS, make_mesh, pcast_varying   # noqa: F401 (re-exported)
 
@@ -221,30 +221,16 @@ def _stats_of(c: SBCtx):
 def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
                             w: int = 2048, cohorts_per_block: int = 8,
                             hot_frac=None, hot_prob=None, mix=None,
-                            use_pallas=None, use_hotset=None,
-                            use_fused=None, monitor: bool = False,
+                            use_hotset=None, monitor: bool = False,
                             trace=None, trace_rate=None, trace_cap=None):
     """jit(shard_map(scan(step))). Contract mirrors the single-chip dense
     runner: (run, init, drain); stats are psummed across the mesh.
-
-    ``use_pallas``: None = honor DINT_USE_PALLAS env; routes the owner-side
-    held-stamp and balance gathers through the DMA-ring kernel
-    (ops/pallas_gather.gather_rows) on each device's local arrays; a
-    Mosaic refusal raises pg.KernelRefused.
 
     ``use_hotset``: None = honor DINT_USE_HOTSET env. Per-device dintcache
     partition over the owner-side gathers (SBShard docstring): hot lanes
     read the local mirror, installs write through; init() attaches the
     mirror. Hot set defaults to the workload's (``hot_frac``). Outputs
     bit-identical to the default path (tests/test_hotset.py).
-
-    ``use_fused``: None = honor DINT_USE_FUSED env. Routes each owner's
-    stamp/balance gathers through ONE gather-stream lock_validate
-    dispatch and its primary install + CommitLog append through ONE
-    scatter-stream install_log dispatch (round-12 megakernels); the
-    all_to_all routing and the ppermute replicate fan-out stay
-    collective + XLA. Probed once outside shard_map; a probe failure
-    raises (pg.resolve_use_fused).
 
     ``monitor``: thread the dintmon counter plane PER DEVICE. Txn
     outcomes count at the source device (where the cohort completes);
@@ -271,25 +257,13 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
     sent = m1 - 1
     oob = m1
     cap = 2 * ((w * L + d - 1) // d)
-    use_hotset = pg.resolve_use_hotset(use_hotset)
-    use_pallas = pg.resolve_use_pallas(use_pallas, n_idx=d * cap,
-                                       m_lock=None)
+    use_hotset = hotset.resolve_use_hotset(use_hotset)
     hot_loc = 0
     if use_hotset:
         from ..clients import workloads as wl
         frac = wl.SB_HOT_FRAC if hot_frac is None else float(hot_frac)
         hot_n = max(1, min(int(n_accounts * frac), n_accounts))
         hot_loc = min((hot_n + d - 1) // d, n_loc)
-        if use_pallas:
-            pg.hot_kernels_available(n_idx=d * cap)
-    ew1 = logring.HDR_WORDS + VW                 # replicas=1 rings
-    scat_geoms = ((d * cap, 1), (d * cap, ew1))
-    if use_hotset:
-        scat_geoms = scat_geoms + ((d * cap, 1),)
-    use_fused = pg.resolve_use_fused(
-        use_fused,
-        gathers=((d * cap, 1), (d * cap, 1), (d * cap, 1)),
-        scatters=scat_geoms)
     kw_gen = {}
     if hot_frac is not None:
         kw_gen["hot_frac"] = hot_frac
@@ -357,16 +331,6 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
         is_x = r_op == Op.ACQ_X_READ
         is_s = r_op == Op.ACQ_S_READ
         rows = jnp.where(r_op != 0, r_row, sent)
-        if use_fused:
-            # lock_validate megakernel: both held-stamp gathers AND the
-            # owner-side balance read as gather streams of ONE dispatch,
-            # reading the main local arrays directly (bit-identical to
-            # the hot-partitioned serving by the mirror invariant); the
-            # scatter-min arbitration below stays XLA
-            with waves.scope("dense_sharded_sb", "lock_validate"):
-                hx_raw, hs_raw, fused_bal = pg.gather_streams(
-                    (state.x_step, state.s_step, state.bal),
-                    (rows, rows, rows), (1, 1, 1))
         with waves.scope("dense_sharded_sb", "arbitrate"):
 
             def mirror_idx(rr, mask):
@@ -384,19 +348,11 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
                 jnp.where(is_x, rows, oob)].min(lanes, mode="drop")
             first_s = jnp.full((m1,), BIG, I32).at[
                 jnp.where(is_s, rows, oob)].min(lanes, mode="drop")
-            if use_fused:
-                held_x = hx_raw == t - 1
-                held_s = hs_raw == t - 1
-            elif use_hotset:
-                held_x = pg.hot_gather(state.x_step, state.hot_x, rows,
-                                       midx, 1,
-                                       use_pallas=use_pallas) == t - 1
-                held_s = pg.hot_gather(state.s_step, state.hot_s, rows,
-                                       midx, 1,
-                                       use_pallas=use_pallas) == t - 1
-            elif use_pallas:
-                held_x = pg.gather_rows(state.x_step, rows, 1) == t - 1
-                held_s = pg.gather_rows(state.s_step, rows, 1) == t - 1
+            if use_hotset:
+                held_x = hotset.hot_gather(state.x_step, state.hot_x, rows,
+                                           midx, 1) == t - 1
+                held_s = hotset.hot_gather(state.s_step, state.hot_s, rows,
+                                           midx, 1) == t - 1
             else:
                 held_x = state.x_step[rows] == t - 1
                 held_s = state.s_step[rows] == t - 1
@@ -420,14 +376,11 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
                 hot_s = hot_s.at[jnp.where(s_writer & (midx >= 0), midx,
                                            2 * hot_loc)].set(
                     t, mode="drop", unique_indices=True)
-            if use_fused:
-                raw_bal = fused_bal   # gathered in lock_validate above
-            elif use_hotset:
-                raw_bal = pg.hot_gather(state.bal, state.hot_bal, rows,
-                                        midx, 1, use_pallas=use_pallas)
+            if use_hotset:
+                raw_bal = hotset.hot_gather(state.bal, state.hot_bal, rows,
+                                            midx, 1)
             else:
-                raw_bal = (pg.gather_rows(state.bal, rows, 1) if use_pallas
-                           else state.bal[rows])
+                raw_bal = state.bal[rows]
             g_bal = jnp.where(grant_x | grant_s, raw_bal.astype(I32), 0)
 
         # ---- replies back to sources + classify -----------------------
@@ -480,15 +433,13 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
 
             irows = jnp.where(i_mask, i_row, oob)
             hot_bal = state.hot_bal
-            if use_fused:
-                pass    # install + log land in install_log below
-            elif use_hotset:
-                # partitioned write-through install (fused kernel on
-                # pallas, double 1-D unique-index scatter on XLA)
+            if use_hotset:
+                # partitioned write-through install (double 1-D
+                # unique-index scatter)
                 i_midx = mirror_idx(i_row, i_mask)
-                bal_new, hot_bal = pg.hot_scatter(
+                bal_new, hot_bal = hotset.hot_scatter(
                     state.bal, hot_bal, i_row, i_midx, i_mask,
-                    i_bal.astype(U32), 1, use_pallas=use_pallas)
+                    i_bal.astype(U32), 1)
             else:
                 bal_new = state.bal.at[irows].set(i_bal.astype(U32),
                                                   mode="drop",
@@ -512,50 +463,15 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
             return ring, bck
 
         # owner logs its installs (CommitLog at the primary)
-        if use_fused:
-            # install_log megakernel: primary balance install, the
-            # owner's CommitLog append, and (hotset) the mirror
-            # write-through as masked row-scatter streams of ONE
-            # dispatch; the log plan is the exact append_rep plan
-            # (tables/log.plan_rep), so ring bytes match the unfused
-            # path bit for bit. Routing stays all_to_all above; the
-            # replicate fan-out below stays ppermute + XLA
-            with waves.scope("dense_sharded_sb", "install_log"):
-                newval = jnp.zeros((d * cap, VW), U32).at[:, 0].set(
-                    i_bal.astype(U32))
-                lflat, entry3, lane_counts = logring.plan_rep(
-                    state.log, i_mask, i_tbl, jnp.zeros_like(i_bal),
-                    jnp.zeros_like(i_bal, U32), i_acc.astype(U32),
-                    jnp.broadcast_to(t, i_mask.shape), newval)
-                widx = jnp.where(i_mask, i_row, -1)
-                tabs = [state.bal, state.log.entries.reshape(-1)]
-                idxs = [widx, lflat]
-                vals = [i_bal.astype(U32), entry3.reshape(-1)]
-                vws = [1, state.log.entries.shape[1]]
-                if use_hotset:
-                    i_midx = mirror_idx(i_row, i_mask)
-                    tabs += [state.hot_bal]
-                    idxs += [i_midx]
-                    vals += [i_bal.astype(U32)]
-                    vws += [1]
-                outs = pg.scatter_streams(tuple(tabs), tuple(idxs),
-                                          tuple(vals), tuple(vws))
-                bal_new = outs[0]
-                log = state.log.replace(
-                    entries=outs[1].reshape(state.log.entries.shape),
-                    head=state.log.head + lane_counts)
-                if use_hotset:
-                    hot_bal = outs[2]
-        else:
-            with waves.scope("dense_sharded_sb", "install_route"):
-                newval = jnp.zeros((d * cap, VW), U32).at[:, 0].set(
-                    i_bal.astype(U32))
-                log = logring.append_rep(state.log, i_mask, i_tbl,
-                                         jnp.zeros_like(i_bal),
-                                         jnp.zeros_like(i_bal, U32),
-                                         i_acc.astype(U32),
-                                         jnp.broadcast_to(t, i_mask.shape),
-                                         newval)
+        with waves.scope("dense_sharded_sb", "install_route"):
+            newval = jnp.zeros((d * cap, VW), U32).at[:, 0].set(
+                i_bal.astype(U32))
+            log = logring.append_rep(state.log, i_mask, i_tbl,
+                                     jnp.zeros_like(i_bal),
+                                     jnp.zeros_like(i_bal, U32),
+                                     i_acc.astype(U32),
+                                     jnp.broadcast_to(t, i_mask.shape),
+                                     newval)
         # CommitBck x2 + CommitLog at the backups: forward applied installs
         with waves.scope("dense_sharded_sb", "replicate"):
             bck = state.bck_bal
@@ -588,16 +504,13 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
         if cnt is not None and use_hotset:
             # partition accounting: 3 hot-partitioned gathers per step
             # (x/s stamps + balances), each serving (midx >= 0) lanes
-            # from the mirror; refresh = one bulk DMA per pallas gather.
-            # The fused route reads the main arrays directly (no gather
-            # is partitioned), so its partition counters are zero
-            n_g = 0 if use_fused else 3
+            # from the mirror
+            n_g = 3
             hits = (midx >= 0).sum(dtype=I32)
             cnt = mon.bump(cnt, {
                 mon.CTR_HOT_HITS: n_g * hits,
                 mon.CTR_HOT_COLD_ROWS: n_g * (d * cap) - n_g * hits,
-                mon.CTR_HOT_REFRESH_BYTES:
-                    (n_g * 2 * hot_loc * 4) if use_pallas else 0,
+                mon.CTR_HOT_REFRESH_BYTES: 0,
             })
         if cnt is not None:
             # txn outcomes + overflow at the SOURCE (c1 completes here);
@@ -623,9 +536,7 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
                 mon.CTR_LOCK_REJECT_ARB: (rej & ~held).sum(dtype=I32),
                 mon.CTR_INSTALL_WRITES: i_mask.sum(dtype=I32),
                 mon.CTR_LOG_APPENDS: i_mask.sum(dtype=I32),
-                (mon.CTR_DISPATCH_PALLAS if use_pallas
-                 else mon.CTR_DISPATCH_XLA): 1,
-                **({mon.CTR_FUSED_DISPATCH: 1} if use_fused else {}),
+                mon.CTR_DISPATCH_XLA: 1,
             })
             cnt = mon.gauge_max(cnt, {mon.CTR_RING_HWM: log.head.max()})
 
@@ -714,14 +625,11 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
 
     n_carry = 2 + int(trace_on) + int(monitor)
     spec = (P(AXIS),) * n_carry + (P(),)
-    check_vma = pg.shard_map_check_vma(use_pallas or use_fused)
     block = jax.shard_map(block_local, mesh=mesh, in_specs=spec,
-                          out_specs=(P(AXIS),) * n_carry + (P(),),
-                          check_vma=check_vma)
+                          out_specs=(P(AXIS),) * n_carry + (P(),))
     drain_m = jax.shard_map(
         drain_local, mesh=mesh, in_specs=spec,
-        out_specs=(P(AXIS),) * (n_carry - 1) + (P(),),
-        check_vma=check_vma)
+        out_specs=(P(AXIS),) * (n_carry - 1) + (P(),))
     donate = tuple(range(n_carry))
     jit_block = jax.jit(block, donate_argnums=donate)
     jit_drain = jax.jit(drain_m, donate_argnums=donate)

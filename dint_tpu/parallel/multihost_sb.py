@@ -40,9 +40,9 @@ are the budget, so route so only truly-remote lanes pay them):
     traffic is observable, not just priced.
 
 Requires n_hosts >= 3 (the +2 dcn hop would alias the source on a
-2-host mesh and double-log — same rule as multihost.py). XLA-only step:
-the pallas/hotset/fused levers of the 1-D runner are orthogonal to the
-transport and stay on the flat-axis path (PERF.md round 14).
+2-host mesh and double-log — same rule as multihost.py). The hotset
+lever of the 1-D runner is orthogonal to the transport and stays on the
+flat-axis path (PERF.md round 14).
 """
 from __future__ import annotations
 

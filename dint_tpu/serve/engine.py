@@ -131,8 +131,8 @@ class ServeEngine:
     clock : RealClock (default) or VirtualClock (deterministic tests)
     monitor : thread the dintmon counter plane (needed for the serve
         counter reconciliation identity and hot_frac auto-sizing)
-    runner_kw : forwarded to build_pipelined_runner (use_pallas, mix,
-        use_hotset, hot_frac, ...) — always wins over the plan
+    runner_kw : forwarded to build_pipelined_runner (mix, use_hotset,
+        hot_frac, ...) — always wins over the plan
     plan : "auto" (default) reads the pinned PLAN.json (analysis/plan):
         the width menu + SLO come from the plan's serve priors when
         ``cfg`` is None, build knobs the plan pins for this engine's

@@ -386,11 +386,9 @@ class CachedStore:
 
     def __init__(self, cache_buckets: int, val_words: int = 10,
                  slots: int = 4, policy: str = store_cache.WB_BLOOM,
-                 width: int = 4096, hot_keys: int = 0,
-                 use_pallas: bool = False):
+                 width: int = 4096, hot_keys: int = 0):
         """``hot_keys`` > 0 attaches the dintcache mirror for key ids
-        [0, hot_keys) inside the device cache (store_cache.CacheTable);
-        ``use_pallas`` serves its partition with the VMEM hot kernels."""
+        [0, hot_keys) inside the device cache (store_cache.CacheTable)."""
         self.cache = store_cache.create(cache_buckets, slots, val_words,
                                         hot_keys=hot_keys)
         self.kvs = HostKVS(cache_buckets, val_words)
@@ -399,8 +397,7 @@ class CachedStore:
         self.width = width
         self.stats = CacheStats()
         self._step = jax.jit(
-            lambda c, b: store_cache.cache_step(c, b, policy=policy,
-                                                use_pallas=use_pallas),
+            lambda c, b: store_cache.cache_step(c, b, policy=policy),
             donate_argnums=0)
         self._refill = jax.jit(store_cache.refill, donate_argnums=0)
         self._pending: dict[int, bool] = {}    # refill keys (bloom-only if False)

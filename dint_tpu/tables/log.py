@@ -102,10 +102,7 @@ def append(ring: LogRing, do_append, table_id, is_del, key_hi, key_lo, ver, val)
 #     pairs provably distinct, which the flat row id turns into a plain
 #     `unique_indices=True` declaration (round 7) — ~2 ms per 16 K appends
 #     on v5e, where the historical [L, CAP] 2-D index form cost ~15 ms
-#     before it carried the uniqueness declaration. The same flat layout
-#     is what lets round 12's install_log megakernel take the append as
-#     one more masked row-scatter stream (`plan_rep` below exposes the
-#     planned rows; ops/pallas_gather.scatter_streams does the write);
+#     before it carried the uniqueness declaration;
 #   * a [slots, 3, EW] u32 array is tiled T(4,128) over its minor dims, so
 #     each slot physically occupies 2 KB — 34 GB at 16M slots (observed
 #     OOM). Packing replicas into the word axis pays the 128-lane padding
@@ -143,10 +140,7 @@ def plan_rep(ring: RepLog, do_append, table_id, is_del, key_hi, key_lo,
     """Plan a replicated append without writing: returns
     (flat [R] i32 row ids with -1 for masked lanes, entry3 [R, S*(HDR+VW)]
     u32 replica-packed rows, lane_counts u32 [L]). `append_rep` is exactly
-    this plan + one unique-index row scatter + the head advance; the
-    fused install_log path feeds the SAME plan to
-    ops/pallas_gather.scatter_streams instead, so the ring bytes are
-    bit-identical on both routes."""
+    this plan + one unique-index row scatter + the head advance."""
     r = do_append.shape[0]
     lanes = ring.lanes
     cap = ring.capacity

@@ -12,8 +12,8 @@ scan-serving companion of `tables.kv.KVTable`:
     struct-of-arrays and FLAT like the table itself (key_hi/key_lo/ver
     u32 [cap], val u32 [cap*VW] interleaved); rows past `n` keep the
     reserved PAD key 0xFFFFFFFF:FFFFFFFF so binary search needs no
-    bounds plumbing. Contiguous key-adjacent rows are what turns a scan
-    into a sequential DMA (ops/pallas_gather.scan_rows).
+    bounds plumbing. Contiguous key-adjacent rows are what a scan reads
+    as one window (`scan_slab`).
   * **delta overlay** — a small key-sorted write-through buffer fed by
     `store.step`'s installs/deletes (upserts + tombstones, at most one
     entry per key, latest write wins). Scans merge run ∪ delta so the
@@ -34,6 +34,8 @@ first `scan_max` live keys of the merged view — the static price of
 answering scans between rebuilds without dynamic shapes.
 """
 from __future__ import annotations
+
+import os
 
 import flax.struct
 import jax
@@ -253,6 +255,29 @@ def locate(run: OrderedRun, q_hi, q_lo):
         less = (kh < q_hi) | ((kh == q_hi) & (kl < q_lo))
         pos = jnp.where((cand <= cap) & less, cand, pos)
     return pos
+
+
+def env_use_scan() -> bool:
+    return os.environ.get("DINT_USE_SCAN", "0") not in ("", "0")
+
+
+def resolve_use_scan(explicit: bool | None = None) -> bool:
+    """Engine-builder gate for the dintscan ordered-run scan path:
+    explicit kwarg wins, else the DINT_USE_SCAN env."""
+    if explicit is None:
+        return env_use_scan()
+    return bool(explicit)
+
+
+def scan_slab(run_hi, run_lo, run_ver, run_val, off, lg: int, vw: int):
+    """The dintscan window gather: per-lane dynamic-slice-shaped gathers
+    of `lg` contiguous run rows from offset `off`. Returns (hi, lo, ver
+    [K, lg], val [K, lg, vw])."""
+    off = off.astype(I32)
+    idx = off[:, None] + jnp.arange(lg, dtype=I32)[None, :]
+    widx = (idx * vw)[:, :, None] + jnp.arange(vw, dtype=I32)[None, None, :]
+    return (run_hi[idx], run_lo[idx], run_ver[idx],
+            run_val[widx])
 
 
 def merge_scan(run: OrderedRun, slab_hi, slab_lo, slab_ver, slab_val,

@@ -267,40 +267,17 @@ def _tatp_runner(n_sub, w, cpb, seed=0):
     import jax
 
     from dint_tpu.engines import tatp_dense as td
-    from dint_tpu.ops import pallas_gather as pg
 
     knobs = _plan_knobs("tatp_uniform")
-    use_pallas = pg.resolve_use_pallas(knobs.get("use_pallas"),
-                                       n_idx=2 * w * td.K,
-                                       m_lock=2 * w, k_arb=td.K_ARB)
-    kb = {k: knobs[k] for k in ("use_hotset", "use_fused") if k in knobs}
-
-    def build(up):
-        # on-device populate: the full sweep runs at the reference's 7M
-        # subscribers (~6.2 GB) — generated in HBM, not via the host
-        db = td.populate_device(jax.random.PRNGKey(seed), n_sub,
-                                val_words=10)
-        run, init, drain = td.build_pipelined_runner(
-            n_sub, w=w, val_words=10, cohorts_per_block=cpb, use_pallas=up,
-            monitor=_monitor_on(), trace=_trace_on(), **kb)
-        run = _wrap_trace(run, init)
-        carry = init(db)
-        if up:
-            # force the full-geometry compile NOW: a Mosaic failure the
-            # small-table probe missed must degrade to the XLA path here,
-            # not void the sweep point (run donates carry -> rebuild)
-            carry, s = run(carry, jax.random.PRNGKey(seed + 7))
-            np.asarray(s)
-        return run, carry, drain
-
-    try:
-        return build(use_pallas)
-    except Exception as e:
-        if not use_pallas:
-            raise
-        print("pallas kernel path failed at full geometry; XLA fallback: "
-              f"{e!r}"[:300], flush=True)
-        return build(False)
+    kb = {k: knobs[k] for k in ("use_hotset",) if k in knobs}
+    # on-device populate: the full sweep runs at the reference's 7M
+    # subscribers (~6.2 GB) — generated in HBM, not via the host
+    db = td.populate_device(jax.random.PRNGKey(seed), n_sub, val_words=10)
+    run, init, drain = td.build_pipelined_runner(
+        n_sub, w=w, val_words=10, cohorts_per_block=cpb,
+        monitor=_monitor_on(), trace=_trace_on(), **kb)
+    run = _wrap_trace(run, init)
+    return run, init(db), drain
 
 
 def _tatp_extras(total):
@@ -321,35 +298,16 @@ def _sb_runner(n_acc, w, cpb, hot_frac=None, hot_prob=None):
     import jax
 
     from dint_tpu.engines import smallbank_dense as sd
-    from dint_tpu.ops import pallas_gather as pg
 
     knobs = _plan_knobs("smallbank_skewed")
-    use_pallas = pg.resolve_use_pallas(knobs.get("use_pallas"),
-                                       n_idx=w * sd.L, m_lock=None)
-    kb = {k: knobs[k] for k in ("use_hotset", "use_fused") if k in knobs}
-
-    def build(up):
-        db = sd.create(n_acc)
-        run, init, drain = sd.build_pipelined_runner(
-            n_acc, w=w, cohorts_per_block=cpb, use_pallas=up,
-            hot_frac=hot_frac, hot_prob=hot_prob,
-            monitor=_monitor_on(), trace=_trace_on(), **kb)
-        run = _wrap_trace(run, init)
-        carry = init(db)
-        if up:
-            # same full-geometry degrade rule as _tatp_runner
-            carry, s = run(carry, jax.random.PRNGKey(13))
-            np.asarray(s)
-        return run, carry, drain
-
-    try:
-        return build(use_pallas)
-    except Exception as e:
-        if not use_pallas:
-            raise
-        print("pallas kernel path failed at full geometry; XLA fallback: "
-              f"{e!r}"[:300], flush=True)
-        return build(False)
+    kb = {k: knobs[k] for k in ("use_hotset",) if k in knobs}
+    db = sd.create(n_acc)
+    run, init, drain = sd.build_pipelined_runner(
+        n_acc, w=w, cohorts_per_block=cpb,
+        hot_frac=hot_frac, hot_prob=hot_prob,
+        monitor=_monitor_on(), trace=_trace_on(), **kb)
+    run = _wrap_trace(run, init)
+    return run, init(db), drain
 
 
 def _sb_extras(total):
@@ -758,7 +716,7 @@ def sweep_micro(window_s, quick, results, want=lambda name: True):
             return _timed_client(c, lambda: c.run_wave(rng), window_s) | {
                 "width": w, "key_dist": "zipfian",
                 "zipf_theta": wl.ZIPF_THETA,
-                "use_hotset": c.use_hotset, "use_pallas": c.use_pallas,
+                "use_hotset": c.use_hotset,
                 "scan": None}
 
         run_point(results, name, zipf_fn)
@@ -789,8 +747,7 @@ def sweep_micro(window_s, quick, results, want=lambda name: True):
                          "scan_max": scan_max,
                          "max_scan_len": c.max_scan_len,
                          "delta_cap": c.delta_cap,
-                         "rebuild_every": c.rebuild_every,
-                         "use_pallas": c.use_pallas}}
+                         "rebuild_every": c.rebuild_every}}
 
         run_point(results, name, scan_fn)
 
@@ -1251,7 +1208,7 @@ def run_all(out: str, window_s: float = 10.0, quick: bool = False,
     if want("smallbank") and not skew_preset:
         from dint_tpu.clients import workloads as wl
         from dint_tpu.engines import smallbank_dense as sd
-        from dint_tpu.ops import pallas_gather as pg
+        from dint_tpu.ops import hotset
 
         skew_extra = {
             "hot_frac": (wl.SB_HOT_FRAC if hot_frac is None
@@ -1261,7 +1218,7 @@ def run_all(out: str, window_s: float = 10.0, quick: bool = False,
             # the value that actually built: plan-pinned when a plan is
             # readable, env-resolved otherwise (matches _sb_runner)
             "use_hotset": _plan_knobs("smallbank_skewed").get(
-                "use_hotset", pg.resolve_use_hotset(None)),
+                "use_hotset", hotset.resolve_use_hotset(None)),
         }
         sweep_pipeline("smallbank",
                        lambda w, b: _sb_runner(n_acc, w, b, hot_frac,
@@ -1310,7 +1267,7 @@ def run_all(out: str, window_s: float = 10.0, quick: bool = False,
         # swept across the 90%-hot workload — the dintcache decision curve
         # (arm DINT_USE_HOTSET=0/1 runs to A/B the hot tier at each skew)
         from dint_tpu.engines import smallbank_dense as sd
-        from dint_tpu.ops import pallas_gather as pg
+        from dint_tpu.ops import hotset
 
         skew_w = 256 if quick else 8192
         for frac in (0.01, 0.04, 0.16, 0.5):
@@ -1326,7 +1283,7 @@ def run_all(out: str, window_s: float = 10.0, quick: bool = False,
                              "use_hotset": _plan_knobs(
                                  "smallbank_skewed").get(
                                  "use_hotset",
-                                 pg.resolve_use_hotset(None))},
+                                 hotset.resolve_use_hotset(None))},
                 geom={"l": sd.L, "vw": sd.VW})
     # --only serve_mesh is a preset (like skew): the bidirectional
     # substring filter would also fire the single-device serve legs

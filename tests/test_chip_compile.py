@@ -8,14 +8,6 @@ time — only "compiles, and fits" or the compiler's refusal.
 Geometry is the flagship deployment's (chip_smoke.py): TATP at 7 000 000
 subscribers, val_words=10, w=8192, 16 cohorts per block.
 
-The Pallas kernel cases ASSERT THE REFUSAL. Mosaic on v5e requires a row
-slice of a 1-D HBM table to be a multiple of 1024 words and the engines'
-rows are 1, 10 or 42 words (PERF.md "Round 25"), so every kernel family in
-ops/pallas_gather.py is refused, and asking for one raises
-pg.KernelRefused on the chip instead of quietly running XLA. A case fails
-the day its kernel compiles: flip it to assert the compile, and give
-chip_smoke.py its `--kernels` phase.
-
 The topology is described inside a module-scoped fixture (never at
 import, never in conftest.py: only one process may hold the TPU library,
 and every xdist worker imports every test file)."""
@@ -32,17 +24,12 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 from dint_tpu.engines import smallbank_dense as sd
 from dint_tpu.engines import tatp_dense as td
 from dint_tpu.ops import compact
-from dint_tpu.ops import pallas_gather as pg
 from dint_tpu.parallel import dense_sharded as ds
-from dint_tpu.tables import log as logring
 
 HBM_BYTES = 16e9                 # one v5e chip
 N_SUB, W, CPB, VW = 7_000_000, 8192, 16, 10
 K = td.K
 N1 = td.n_rows(N_SUB) + 1        # table rows incl. the sentinel
-EW3 = 3 * (logring.HDR_WORDS + VW)   # one log slot: 3 packed replicas
-LOG_WORDS = 16 * (1 << 16) * EW3     # tatp_dense.create's default rings
-HOT = 1 << 16                    # mirror rows of the hot-tier cases
 U32, I32 = jnp.uint32, jnp.int32
 
 
@@ -103,7 +90,7 @@ def compiled_bytes(jitted, *args):
 def _runner():
     return td.build_pipelined_runner(
         N_SUB, w=W, val_words=VW, cohorts_per_block=CPB, monitor=True,
-        use_pallas=False, use_fused=False, trace=False)
+        trace=False)
 
 
 def test_tatp7m_populate_fits_one_chip(one_chip):
@@ -185,8 +172,8 @@ def test_smallbank24m_block_program_fits_one_chip(one_chip):
     each) one live at a time as the step's temporaries."""
     n_acc = 24_000_000
     run, init, drain = sd.build_pipelined_runner(
-        n_acc, w=W, cohorts_per_block=CPB, monitor=True, use_pallas=False,
-        use_fused=False, use_hotset=False, trace=False)
+        n_acc, w=W, cohorts_per_block=CPB, monitor=True,
+        use_hotset=False, trace=False)
     carry = placed(jax.eval_shape(lambda: init(sd.create(n_acc))), one_chip)
     key = placed(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
     db = carry[0]
@@ -242,58 +229,6 @@ def test_windowed_row_scatter_is_expanded_to_a_loop_on_v5e(one_chip):
     assert f"constant({2 * W})" in hlo            # the loop's trip count
 
 
-# ------------------------------------------------- the Pallas kernels
-
-
-# (id, fn, argument shapes) at the geometry the TATP-7M builders pass;
-# interpret=False is explicit because use_interpret() sees the CPU here
-KERNELS = (
-    ("gather_rows-vw1-meta", lambda t, i: pg.gather_rows(t, i, 1, False),
-     (_s((N1,)), _s((2 * W * K,), I32))),
-    ("gather_rows-vw10-val", lambda t, i: pg.gather_rows(t, i, VW, False),
-     (_s((N1 * VW,)), _s((W * K,), I32))),
-    ("scatter_streams-install_log",
-     lambda tabs, idxs, vals: pg.scatter_streams(
-         tabs, idxs, vals, (VW, 1, EW3), False),
-     ((_s((N1 * VW,)), _s((N1,)), _s((LOG_WORDS,))),
-      (_s((2 * W,), I32),) * 3,
-      (_s((2 * W * VW,)), _s((2 * W,)), _s((2 * W * EW3,))))),
-    ("scatter_rows_hot-install",
-     lambda t, m, i, mi, mk, v: pg.scatter_rows_hot(
-         t, m, i, mi, mk, v, VW, False),
-     (_s((N1 * VW,)), _s((HOT * VW,)), _s((2 * W,), I32),
-      _s((2 * W,), I32), _s((2 * W,), I32), _s((2 * W * VW,)))),
-    ("gather_rows_hot-meta",
-     lambda t, m, i, mi: pg.gather_rows_hot(t, m, i, mi, 1, False),
-     (_s((N1,)), _s((HOT,)), _s((2 * W * K,), I32),
-      _s((2 * W * K,), I32))),
-    ("lock_arbitrate",
-     lambda a, r, act, s: pg.lock_arbitrate(a, r, act, s, td.K_ARB, False),
-     (_s((N1,)), _s((2 * W,), I32), _s((2 * W,), jnp.bool_), _s(()))),
-    ("lock_validate",
-     lambda a, m, vi, vv, ri, r, act, s: pg.lock_validate(
-         a, m, vi, vv, ri, r, act, s, td.K_ARB, False),
-     (_s((N1,)), _s((N1,)), _s((W * K,), I32), _s((W * K,)),
-      _s((W * K,), I32), _s((2 * W,), I32), _s((2 * W,), jnp.bool_),
-      _s(()))),
-    ("gather_streams",
-     lambda tabs, idxs: pg.gather_streams(tabs, idxs, (1, 1, 1), False),
-     ((_s((N1,)),) * 3, (_s((W * K,), I32),) * 3)),
-    ("scan_rows-lg16",
-     lambda hi, lo, ver, val, off, order: pg.scan_rows(
-         hi, lo, ver, val, off, order, 16, VW, False),
-     (_s((1 << 20,)), _s((1 << 20,)), _s((1 << 20,)),
-      _s(((1 << 20) * VW,)), _s((512,), I32), _s((512,), I32))),
-)
-
-
-@pytest.mark.parametrize("fn,shapes", [k[1:] for k in KERNELS],
-                         ids=[k[0] for k in KERNELS])
-def test_mosaic_refuses_the_kernel_on_v5e(one_chip, fn, shapes):
-    with pytest.raises(Exception, match="aligned to tiling"):
-        jax.jit(fn).lower(*placed(shapes, one_chip)).compile()
-
-
 # ------------------------------------------------------ four chips
 
 
@@ -305,7 +240,7 @@ def test_dense_sharded_block_program_on_four_chips(topo):
     n = mesh.size
     run, init, drain = ds.build_sharded_pipelined_runner(
         mesh, n, N_SUB, w=W, val_words=VW, cohorts_per_block=CPB,
-        monitor=True, use_pallas=False, use_fused=False)
+        monitor=True)
 
     def create():
         return ds.create_sharded(mesh, n, N_SUB, val_words=VW, seed=0)

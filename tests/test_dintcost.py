@@ -1,14 +1,12 @@
 """dintcost: the static cost model and its CI gate.
 
-Liveness: mutated mini-engine fixtures — an extra unfused scatter
+Liveness: mutated mini-engine fixtures — an extra scatter
 dispatch, a doubled gather width, a dropped donation — prove each
 cost_budget check fires (naming the offending wave/target) and is
-silenceable by a scoped allowlist entry; fused-pair fixtures prove the
-dominance checks in both directions. Soundness: the full 36-target
-matrix reconciles against every declared waves.py formula, stays inside
-its registered budgets with ZERO cost_budget allowlist entries, and
-every @fused target strictly dominates its unfused twin on dispatches —
-the round-12 claim as a standing CPU-only assertion. The geometry pins
+silenceable by a scoped allowlist entry. Soundness: the full target
+matrix reconciles against every declared waves.py formula and stays
+inside its registered budgets with ZERO cost_budget allowlist entries.
+The geometry pins
 at the bottom keep the budget ledger's formula variables honest against
 the engine modules' real constants.
 """
@@ -60,7 +58,7 @@ def _mini_step(wide=False, extra=False, donate=True):
         s = got.sum(dtype=U32)
         tab2 = tab.at[idx[:NE]].set(vals + s, mode="drop",
                                     unique_indices=True)
-        if extra:                               # the unfused regression
+        if extra:                               # the extra dispatch
             tab2 = tab2.at[idx[:NE]].set(vals ^ s, mode="drop",
                                          unique_indices=True)
         return tab2
@@ -183,43 +181,6 @@ def test_dropped_donation_fires_footprint_budget():
         assert not analysis.has_errors(fs2)
 
 
-def test_fused_dominance_fires_when_fused_loses():
-    disp, nbytes, fp = _clean_numbers()
-    twin_fn, twin_args = _mini_step()               # 2 dispatches
-    fused_fn, fused_args = _mini_step(extra=True)   # 3 dispatches: WORSE
-    twin, fused = "fixture_cost/mini", "fixture_cost/mini@fused"
-    fused_model = cost.derive(
-        core.trace_target("fixture_cost/_probe_fused", fused_fn,
-                          fused_args), steps=1.0, geom=GEOM)
-    meta = _meta({"dispatches": fused_model.dispatches_per_step,
-                  "bytes": None, "footprint": fp})
-    with _registered(twin, twin_fn, twin_args, None), \
-            _registered(fused, fused_fn, fused_args, meta):
-        fs = _run(fused)
-        assert {"fused-dispatch-dominance",
-                "fused-bytes-dominance"} <= _err_codes(fs), \
-            [str(f) for f in fs]
-        dom = [f for f in fs if f.code == "fused-dispatch-dominance"]
-        assert dom[0].site == twin         # the twin is named
-        fs2 = _run(fused, allowlist_entries=[
-            {"pass": "cost_budget", "code": "fused-dispatch-dominance",
-             "target": fused, "reason": "fixture: regression on purpose"},
-            {"pass": "cost_budget", "code": "fused-bytes-dominance",
-             "target": fused, "reason": "fixture: regression on purpose"}])
-        assert not analysis.has_errors(fs2)
-
-
-def test_fused_dominance_clean_when_fused_wins():
-    _, nbytes, fp = _clean_numbers()
-    fused_fn, fused_args = _mini_step()             # 2 dispatches: wins
-    twin_fn, twin_args = _mini_step(extra=True)     # 3 dispatches
-    twin, fused = "fixture_cost/mini2", "fixture_cost/mini2@fused"
-    meta = _meta({"dispatches": 2, "bytes": nbytes, "footprint": fp})
-    with _registered(twin, twin_fn, twin_args, None), \
-            _registered(fused, fused_fn, fused_args, meta):
-        assert not _err_codes(_run(fused))
-
-
 # ------------------------------------------------------ full-matrix gate
 
 
@@ -236,28 +197,6 @@ def test_cost_gate_full_matrix_clean_with_zero_allowlist_entries():
     entries = al.load(ALLOW) if os.path.exists(ALLOW) else []
     assert not [e for e in entries if e["pass"] == "cost_budget"], \
         "the dintcost gate must hold without allowlist exceptions"
-
-
-def test_every_fused_target_dominates_its_twin():
-    """The round-12 fusion claim, statically: strictly fewer dispatches
-    per step than the unfused twin, never >5% more bytes."""
-    from dint_tpu.analysis.passes.cost_budget import DOM_BYTES_EPS
-    pairs = 0
-    for name in sorted(T.TARGETS):
-        twin = cost.fused_twin(name)
-        if not twin or twin not in T.TARGETS:
-            continue
-        try:
-            mf, mt = cost.model_for(name), cost.model_for(twin)
-        except T.SkipTarget:
-            continue
-        assert not mf.error and not mt.error, (name, mf.error, mt.error)
-        assert mf.dispatches_per_step < mt.dispatches_per_step, \
-            (name, mf.dispatches_per_step, twin, mt.dispatches_per_step)
-        assert mf.bytes_per_step <= mt.bytes_per_step \
-            * (1 + DOM_BYTES_EPS), (name, mf.bytes_per_step, twin)
-        pairs += 1
-    assert pairs >= 10        # tatp x3, sb x3, ds x2, dsb x3
 
 
 def test_reconciliation_full_matrix():
@@ -327,19 +266,18 @@ def test_cli_report_check_and_diff(tmp_path, capsys):
     exit 0, and diff catching an injected regression by name."""
     main = _dintcost_main()
     art = tmp_path / "cost.json"
-    assert main(["report", "tatp_dense/block", "tatp_dense/block@fused",
+    assert main(["report", "tatp_dense/block", "tatp_dense/block@hot",
                  "--json", "-o", str(art)]) == 0
     payload = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert payload["metric"] == "dintcost"
     assert isinstance(payload["schema"], int)
-    e = payload["targets"]["tatp_dense/block@fused"]
+    e = payload["targets"]["tatp_dense/block@hot"]
     for k in ("bytes_per_step", "dispatches_per_step", "footprint_bytes",
               "waves", "reconcile", "budget", "ledger_bytes"):
         assert k in e
-    assert e["fused_twin"] == "tatp_dense/block"
     assert all(c["ok"] for c in e["reconcile"])
 
-    assert main(["check", "--target", "tatp_dense/block@fused",
+    assert main(["check", "--target", "tatp_dense/block@hot",
                  "--json"]) == 0
     out = capsys.readouterr().out
     assert json.loads(out.strip().splitlines()[-1])["ok"] is True
@@ -531,4 +469,4 @@ def test_every_scan_target_beats_point_probes_per_row():
         per_row, per_probe = scan_b / (w * sl), probe_b / w
         assert per_row < per_probe, (name, per_row, twin, per_probe)
         pairs += 1
-    assert pairs >= 3     # block@scan, block@scan+pallas, serve@scan
+    assert pairs >= 2     # block@scan, serve@scan

@@ -5,8 +5,8 @@ The contract under test, per acceptance criteria:
     fetches (committed/aborted by cause), drains included, on both dense
     engines, both generic pipelines, and both sharded paths;
   * counters are reproducible (same seed -> same values), bit-identical
-    between the XLA and Pallas random-access backends and between the
-    generic and dense engines on the parity workloads (PARITY_NAMES);
+    between the generic and dense engines on the parity workloads
+    (PARITY_NAMES);
   * per-device counters sum across shards to the psummed stats totals;
   * monitoring OFF (the default) changes no engine output;
   * the JSONL trace schema is stable and the dintmon CLI works end to end.
@@ -68,22 +68,20 @@ def test_delta_wraps_u32():
 
 
 @functools.lru_cache(maxsize=None)
-def _td_build(monitor, use_pallas=False, use_fused=False):
+def _td_build(monitor):
     from dint_tpu.engines import tatp_dense as td
 
     return td.build_pipelined_runner(
-        N_SUB, w=W, val_words=VW, cohorts_per_block=CPB,
-        use_pallas=use_pallas, use_fused=use_fused, monitor=monitor)
+        N_SUB, w=W, val_words=VW, cohorts_per_block=CPB, monitor=monitor)
 
 
 @functools.lru_cache(maxsize=None)
-def _sb_build(monitor, use_pallas=False, use_hotset=False,
-              use_fused=False):
+def _sb_build(monitor, use_hotset=False):
     from dint_tpu.engines import smallbank_dense as sd
 
     return sd.build_pipelined_runner(
-        N_ACC, w=W, cohorts_per_block=CPB, use_pallas=use_pallas,
-        use_hotset=use_hotset, use_fused=use_fused, monitor=monitor)
+        N_ACC, w=W, cohorts_per_block=CPB, use_hotset=use_hotset,
+        monitor=monitor)
 
 
 @functools.lru_cache(maxsize=None)
@@ -97,12 +95,11 @@ def _tp_build(monitor):
 # ---------------------------------------------------------- dense engines
 
 
-def _run_tatp_dense(monitor, blocks=3, seed=0, use_pallas=False,
-                    use_fused=False):
+def _run_tatp_dense(monitor, blocks=3, seed=0):
     from dint_tpu.engines import tatp_dense as td
 
     db = td.populate(np.random.default_rng(seed), N_SUB, val_words=VW)
-    run, init, drain = _td_build(monitor, use_pallas, use_fused)
+    run, init, drain = _td_build(monitor)
     carry = init(db)
     tot = np.zeros(td.N_STATS, np.int64)
     for i in range(blocks):
@@ -216,29 +213,11 @@ def test_tatp_dense_counters_reproducible_across_runs():
     assert a != c           # and they are not trivially constant
 
 
-def test_tatp_dense_counters_bit_identical_xla_vs_pallas():
-    # CPU runs the kernels in interpret mode (ops/pallas_gather); the
-    # counter plane must not observe the backend apart from the dispatch
-    # accounting counters themselves
-    _, tot_x, a = _run_tatp_dense(True, use_pallas=False)
-    _, tot_p, b = _run_tatp_dense(True, use_pallas=True)
-    assert tot_x.tolist() == tot_p.tolist()
-    assert a["dispatch_xla"] == b["dispatch_pallas"] == a["steps"]
-    assert a["dispatch_pallas"] == b["dispatch_xla"] == 0
-    # lock_chunks: the fused lock kernel runs no chunk loop
-    drop = ("dispatch_xla", "dispatch_pallas", "lock_chunks")
-    assert {k: v for k, v in a.items() if k not in drop} == \
-        {k: v for k, v in b.items() if k not in drop}
-    assert b["lock_chunks"] == 0 < a["lock_chunks"]
-
-
-def _run_sb_dense(monitor, blocks=3, seed=1, use_pallas=False,
-                  use_hotset=False, use_fused=False):
+def _run_sb_dense(monitor, blocks=3, seed=1, use_hotset=False):
     from dint_tpu.engines import smallbank_dense as sd
 
     db = sd.create(N_ACC)
-    run, init, drain = _sb_build(monitor, use_pallas, use_hotset,
-                                 use_fused)
+    run, init, drain = _sb_build(monitor, use_hotset)
     carry = init(db)
     tot = np.zeros(sd.N_STATS, np.int64)
     for i in range(blocks):
@@ -269,19 +248,10 @@ def test_sb_dense_reconciles_and_off_identical():
     assert np.array_equal(np.asarray(db_off.bal), np.asarray(db_on.bal))
 
 
-def test_sb_dense_counters_bit_identical_xla_vs_pallas():
-    _, _, a = _run_sb_dense(True, use_pallas=False)
-    _, _, b = _run_sb_dense(True, use_pallas=True)
-    drop = ("dispatch_xla", "dispatch_pallas")
-    assert {k: v for k, v in a.items() if k not in drop} == \
-        {k: v for k, v in b.items() if k not in drop}
-
-
 def test_sb_dense_hot_counters_reconcile():
     """dintcache counters (round 10): hot_hits + hot_cold_rows accounts
     every partitioned gather lane (3 gathers x w*L lanes per step at this
-    exact-lock geometry), refresh bytes bill the VMEM mirror copies on
-    the pallas route only, and every pre-round-10 counter is untouched
+    exact-lock geometry), and every pre-round-10 counter is untouched
     by the hot tier (it changes WHERE bytes come from, not outcomes)."""
     from dint_tpu.engines import smallbank_dense as sd
 
@@ -290,71 +260,16 @@ def test_sb_dense_hot_counters_reconcile():
     lanes = W * sd.L
     _, tot, base = _run_sb_dense(True)
     db, tot_h, x = _run_sb_dense(True, use_hotset=True)
-    _, tot_p, p = _run_sb_dense(True, use_pallas=True, use_hotset=True)
-    assert tot.tolist() == tot_h.tolist() == tot_p.tolist()
+    assert tot.tolist() == tot_h.tolist()
 
-    hn = db.hot_n
-    assert hn == max(1, int(N_ACC * 0.04))
-    for snap in (x, p):
-        assert snap["hot_hits"] + snap["hot_cold_rows"] == 3 * steps * lanes
-        assert snap["hot_hits"] > 0          # the skew really lands hot
-    assert x["hot_refresh_bytes"] == 0       # XLA partition: no residency
-    assert p["hot_refresh_bytes"] == steps * 3 * 2 * hn * 4
-    # the hot split itself is backend-independent
-    assert x["hot_hits"] == p["hot_hits"]
-    drop = ("dispatch_xla", "dispatch_pallas", "hot_hits",
-            "hot_cold_rows", "hot_refresh_bytes")
+    assert db.hot_n == max(1, int(N_ACC * 0.04))
+    assert x["hot_hits"] + x["hot_cold_rows"] == 3 * steps * lanes
+    assert x["hot_hits"] > 0                 # the skew really lands hot
+    assert x["hot_refresh_bytes"] == 0       # no residency to refresh
+    drop = ("hot_hits", "hot_cold_rows")
     assert {k: v for k, v in base.items() if k not in drop} == \
-        {k: v for k, v in x.items() if k not in drop} == \
-        {k: v for k, v in p.items() if k not in drop}
+        {k: v for k, v in x.items() if k not in drop}
     assert base["hot_hits"] == base["hot_cold_rows"] == 0
-
-
-@pytest.mark.slow  # ~18s; fused parity itself is pinned in test_fused_ops
-def test_fused_dispatch_counter_reconciles():
-    """Round-12 accounting: fused_dispatch counts every step whose paired
-    waves ran the megakernels — equal to steps on the fused route, zero
-    elsewhere — and it is counted ALONGSIDE the dispatch_xla/pallas
-    split, which must stay total (the magic gather still dispatches by
-    use_pallas). Every other counter is untouched by fusion: the
-    megakernels change dispatch boundaries, not outcomes."""
-    from dint_tpu.engines import smallbank_dense as sd  # noqa: F401
-
-    blocks = 2                       # interpret-mode steps: tier-1 budget
-    steps_t = blocks * CPB + 2       # 3-stage pipeline: 2 drain steps
-    steps_s = blocks * CPB + 1       # 2-stage pipeline: 1 drain step
-    _, tot_t, base_t = _run_tatp_dense(True, blocks=blocks)
-    _, tot_tf, fus_t = _run_tatp_dense(True, blocks=blocks,
-                                       use_fused=True)
-    assert tot_t.tolist() == tot_tf.tolist()
-    assert base_t["fused_dispatch"] == 0
-    assert fus_t["fused_dispatch"] == fus_t["steps"] == steps_t
-    assert fus_t["dispatch_xla"] == steps_t  # the split stays total
-    assert fus_t["dispatch_pallas"] == 0
-    # install_chunks, lock_chunks: the megakernel route runs no chunk loop
-    drop = ("fused_dispatch", "install_chunks", "lock_chunks")
-    assert {k: v for k, v in base_t.items() if k not in drop} == \
-        {k: v for k, v in fus_t.items() if k not in drop}
-    assert fus_t["install_chunks"] == 0 < base_t["install_chunks"]
-    assert fus_t["lock_chunks"] == 0 < base_t["lock_chunks"]
-
-    _, tot_s, base_s = _run_sb_dense(True, blocks=blocks)
-    _, tot_sf, fus_s = _run_sb_dense(True, blocks=blocks,
-                                     use_fused=True)
-    assert tot_s.tolist() == tot_sf.tolist()
-    assert base_s["fused_dispatch"] == 0
-    assert fus_s["fused_dispatch"] == fus_s["steps"] == steps_s
-    assert {k: v for k, v in base_s.items() if k not in drop} == \
-        {k: v for k, v in fus_s.items() if k not in drop}
-
-    # fused x hotset: the dintcache accounting knows the fused gathers
-    # read the main arrays directly (hot_hits stays 0; only the magic /
-    # unfused lanes would count) while outcomes stay bit-identical
-    _, tot_sh, hot_s = _run_sb_dense(True, blocks=blocks,
-                                     use_hotset=True, use_fused=True)
-    assert tot_s.tolist() == tot_sh.tolist()
-    assert hot_s["fused_dispatch"] == steps_s
-    assert hot_s["hot_hits"] == hot_s["hot_cold_rows"] == 0
 
 
 # -------------------------------------------------------- serve counters

@@ -3,7 +3,7 @@
 
 The acceptance pins, per ISSUE.md:
   * the knob registry is first-class: values, env semantics, and the
-    target-variant mapping (use_fused=True => the @fused twin) are
+    target-variant mapping (use_hotset=True => the @hot twin) are
     declared once in analysis/plan.KNOBS and the lattice enumeration /
     pricing / domination pruning all read from it;
   * `dintplan check` exits 0 on the pinned PLAN.json with ZERO
@@ -71,17 +71,18 @@ def codes(findings):
 
 def test_knob_registry_declares_target_variants():
     """Satellite (1): the registry is the single source of knob ->
-    target-variant truth: use_fused=True maps to the @fused twin,
+    target-variant truth: use_hotset=True maps to the @hot twin,
     hierarchical=False to @flat, and the planned knobs span the lattice."""
-    assert P.KNOBS["use_fused"].token == "fused"
-    assert P.KNOBS["use_fused"].token_when is True
+    assert P.KNOBS["use_hotset"].token == "hot"
+    assert P.KNOBS["use_hotset"].token_when is True
     assert P.KNOBS["hierarchical"].token == "flat"
     assert P.KNOBS["hierarchical"].token_when is False
     wl = P._WORKLOADS_BY_NAME["tatp_uniform"]
-    assert P.target_name(wl, {"use_fused": True}) == "tatp_dense/block@fused"
-    assert P.target_name(wl, {"use_fused": False}) == "tatp_dense/block"
-    assert P.target_name(wl, {"use_hotset": True, "use_pallas": True}) \
-        == "tatp_dense/block@hot+pallas"      # canonical token order
+    assert P.target_name(wl, {"use_hotset": True}) == "tatp_dense/block@hot"
+    assert P.target_name(wl, {"use_hotset": False}) == "tatp_dense/block"
+    ms = P._WORKLOADS_BY_NAME["multihost_serve"]
+    assert P.target_name(ms, {"hierarchical": False, "overlap": True}) \
+        == "multihost_sb/serve@overlap+flat"  # canonical token order
     mh = P._WORKLOADS_BY_NAME["multihost_4x2"]
     assert P.target_name(mh, {"hierarchical": False}) \
         == "multihost_sb/block@flat"
@@ -90,18 +91,18 @@ def test_knob_registry_declares_target_variants():
 
 def test_enumerate_candidates_flags_infeasible_combos():
     """The lattice is exhaustive over each workload's planned knobs and
-    an unregistered combination (fused+pallas: the megakernels subsume
-    the standalone kernels) is marked infeasible, never silently priced."""
-    wl = P._WORKLOADS_BY_NAME["tatp_uniform"]
+    an unregistered combination (overlap over the flat transport) is
+    marked infeasible, never silently priced."""
+    wl = P._WORKLOADS_BY_NAME["multihost_serve"]
     cands = P.enumerate_candidates(wl)
     assert len(cands) == 2 ** len(wl.knobs)
     by_target = {c["target"]: c for c in cands}
-    assert by_target["tatp_dense/block"]["feasible"]
-    assert by_target["tatp_dense/block@fused"]["feasible"]
-    fused_pallas = [c for c in cands
-                    if c["knobs"].get("use_fused")
-                    and c["knobs"].get("use_pallas")]
-    assert fused_pallas and not any(c["feasible"] for c in fused_pallas)
+    assert by_target["multihost_sb/serve"]["feasible"]
+    assert by_target["multihost_sb/serve@overlap"]["feasible"]
+    flat_overlap = [c for c in cands
+                    if c["knobs"].get("overlap")
+                    and not c["knobs"].get("hierarchical")]
+    assert flat_overlap and not any(c["feasible"] for c in flat_overlap)
     # every feasible candidate names a registered target
     for c in cands:
         assert c["feasible"] == (c["target"] in T.TARGETS)
@@ -109,30 +110,26 @@ def test_enumerate_candidates_flags_infeasible_combos():
 
 def test_resolve_knobs_env_semantics():
     """The registry replicates each consumer's exact env semantics:
-    flag01 (set-and-not-0) vs flag1 (exactly "1") vs tri-state."""
+    flag01 (set-and-not-0) vs flag1 (exactly "1")."""
     r = P.resolve_knobs({})
-    assert r["use_pallas"] is False and r["monitor"] is False
-    assert r["pallas_interpret"] is None
-    assert P.resolve_knobs({"DINT_USE_PALLAS": "0"})["use_pallas"] is False
-    assert P.resolve_knobs({"DINT_USE_PALLAS": ""})["use_pallas"] is False
-    assert P.resolve_knobs({"DINT_USE_PALLAS": "2"})["use_pallas"] is True
+    assert r["use_hotset"] is False and r["monitor"] is False
+    assert P.resolve_knobs({"DINT_USE_HOTSET": "0"})["use_hotset"] is False
+    assert P.resolve_knobs({"DINT_USE_HOTSET": ""})["use_hotset"] is False
+    assert P.resolve_knobs({"DINT_USE_HOTSET": "2"})["use_hotset"] is True
     assert P.resolve_knobs({"DINT_MONITOR": "1"})["monitor"] is True
     assert P.resolve_knobs({"DINT_MONITOR": "2"})["monitor"] is False
-    assert P.resolve_knobs({"DINT_PALLAS_INTERPRET": "0"})[
-        "pallas_interpret"] is False
 
 
 def test_env_knob_signature_canonicalizes():
     """Satellite (2): the memo-key signature engines/_memo.py folds into
     builder identity canonicalizes unset == "" == "0" for the flag
-    knobs, while the tri-state interpret knob keeps unset distinct."""
+    knobs."""
     base = P.env_knob_signature({})
-    assert base == P.env_knob_signature({"DINT_USE_FUSED": "0"})
-    assert base == P.env_knob_signature({"DINT_USE_FUSED": ""})
-    assert base != P.env_knob_signature({"DINT_USE_FUSED": "1"})
-    assert base != P.env_knob_signature({"DINT_PALLAS_INTERPRET": "0"})
+    assert base == P.env_knob_signature({"DINT_USE_HOTSET": "0"})
+    assert base == P.env_knob_signature({"DINT_USE_HOTSET": ""})
+    assert base != P.env_knob_signature({"DINT_USE_HOTSET": "1"})
     names = [n for n, _ in base]
-    assert "use_fused" in names and "trace" in names
+    assert "use_hotset" in names and "trace" in names
     assert "monitor" not in names        # not part of compiled identity
 
 
@@ -141,11 +138,11 @@ def test_memo_routes_through_shared_signature(monkeypatch):
     registry resolution — flipping a build-identity flag changes the
     memo key, flipping an equivalent spelling does not."""
     from dint_tpu.engines import _memo
-    monkeypatch.delenv("DINT_USE_FUSED", raising=False)
+    monkeypatch.delenv("DINT_USE_HOTSET", raising=False)
     k0 = _memo._env_signature()
-    monkeypatch.setenv("DINT_USE_FUSED", "0")
+    monkeypatch.setenv("DINT_USE_HOTSET", "0")
     assert _memo._env_signature() == k0
-    monkeypatch.setenv("DINT_USE_FUSED", "1")
+    monkeypatch.setenv("DINT_USE_HOTSET", "1")
     assert _memo._env_signature() != k0
 
 
@@ -219,7 +216,7 @@ def broken_plan_findings():
     liveness parametrization. Findings anchor to fixture/plan_check."""
     doc = _doc()
     rows = [r for r in doc["frontier"]
-            if r["workload"] == "tatp_uniform" and not r["dominated"]]
+            if r["workload"] == "multihost_serve" and not r["dominated"]]
     assert len(rows) >= 2
     rows[0]["rank"], rows[1]["rank"] = rows[1]["rank"], rows[0]["rank"]
     return _check(doc)
@@ -229,7 +226,8 @@ def _mutate(code):
     doc = _doc()
     if code == "flipped-ordering":
         rows = [r for r in doc["frontier"]
-                if r["workload"] == "tatp_uniform" and not r["dominated"]]
+                if r["workload"] == "multihost_serve"
+                and not r["dominated"]]
         rows[0]["rank"], rows[1]["rank"] = rows[1]["rank"], rows[0]["rank"]
         return _check(doc)
     if code == "dominated-pin":
@@ -256,10 +254,11 @@ def _mutate(code):
         doc["provenance"]["calibration_hash"] = "0" * 16
         return _check(doc)
     if code == "unjustified-pin":
-        doc["workloads"]["tatp_uniform"]["overrides"] = []
+        entry = doc["workloads"]["tatp_uniform"]
+        entry["pinned"]["use_hotset"] = not entry["predicted"]["use_hotset"]
         return _check(doc)
     if code == "env-override":
-        return _check(doc, environ={"DINT_USE_FUSED": "1"})
+        return _check(doc, environ={"DINT_USE_HOTSET": "1"})
     if code == "malformed-plan":
         del doc["frontier"]
         return _check(doc)
@@ -295,7 +294,7 @@ def test_mutated_price_flips_ordering_and_provenance():
     either check."""
     doc = _doc()
     rows = [r for r in doc["frontier"]
-            if r["workload"] == "tatp_uniform" and not r["dominated"]]
+            if r["workload"] == "multihost_serve" and not r["dominated"]]
     best = next(r for r in rows if r["rank"] == 0)
     best["dcn_bytes_per_step"] = 1e12      # push the pick off rank 0
     fs = _check(doc)
@@ -323,14 +322,14 @@ def test_env_override_flag_acknowledges_contradiction():
     contradict the plan — with it the gate is silent, without it every
     contradicting workload is named."""
     doc = _doc()
-    fs = _check(doc, environ={"DINT_USE_FUSED": "1"})
+    fs = _check(doc, environ={"DINT_USE_HOTSET": "1"})
     hit = [f for f in fs if f.code == "env-override"]
-    assert hit and all("DINT_USE_FUSED" in f.message for f in hit)
-    assert _check(doc, environ={"DINT_USE_FUSED": "1",
+    assert hit and all("DINT_USE_HOTSET" in f.message for f in hit)
+    assert _check(doc, environ={"DINT_USE_HOTSET": "1",
                                 "DINT_PLAN_OVERRIDE": "1"}) == []
     # contradictions() names (workload, knob, pinned, env value)
-    cons = P.contradictions(doc, {"DINT_USE_FUSED": "1"})
-    assert ("tatp_uniform", "use_fused", False, True) in cons
+    cons = P.contradictions(doc, {"DINT_USE_HOTSET": "1"})
+    assert ("tatp_uniform", "use_hotset", False, True) in cons
     assert P.contradictions(doc, {}) == []
 
 
@@ -358,27 +357,29 @@ def test_resolve_for_plan_pins_beat_env():
     records exactly which."""
     doc = _doc()
     knobs, meta = P.resolve_for("tatp_uniform",
-                                environ={"DINT_USE_FUSED": "1"}, plan=doc)
-    assert knobs["use_fused"] is False and meta["overridden"] == []
+                                environ={"DINT_USE_HOTSET": "1"}, plan=doc)
+    assert knobs["use_hotset"] is False and meta["overridden"] == []
     assert meta["source"] and meta["hash"] == \
         doc["provenance"]["cost_model_hash"]
 
     knobs, meta = P.resolve_for(
         "tatp_uniform", plan=doc,
-        environ={"DINT_USE_FUSED": "1", "DINT_PLAN_OVERRIDE": "1"})
-    assert knobs["use_fused"] is True
-    assert meta["overridden"] == ["use_fused"]
+        environ={"DINT_USE_HOTSET": "1", "DINT_PLAN_OVERRIDE": "1"})
+    assert knobs["use_hotset"] is True
+    assert meta["overridden"] == ["use_hotset"]
     # an UNSET flag never flips a pin, even under the override
-    assert knobs["use_pallas"] is False
+    knobs, meta = P.resolve_for("tatp_uniform", plan=doc,
+                                environ={"DINT_PLAN_OVERRIDE": "1"})
+    assert knobs["use_hotset"] is False and meta["overridden"] == []
 
 
 def test_resolve_for_without_plan_falls_back_to_env(monkeypatch,
                                                     tmp_path):
     monkeypatch.setenv(P.ENV_PLAN_PATH, str(tmp_path / "none.json"))
     knobs, meta = P.resolve_for("tatp_uniform",
-                                environ={"DINT_USE_FUSED": "1"})
+                                environ={"DINT_USE_HOTSET": "1"})
     assert meta == {"source": None, "hash": None, "overridden": []}
-    assert knobs["use_fused"] is True          # plain env resolution
+    assert knobs["use_hotset"] is True         # plain env resolution
     assert set(knobs) == set(
         P._WORKLOADS_BY_NAME["tatp_uniform"].knobs)
 
@@ -437,7 +438,7 @@ def test_dintplan_check_mutated_plan_fails(tmp_path, monkeypatch,
     flipped-ordering."""
     doc = _doc()
     rows = [r for r in doc["frontier"]
-            if r["workload"] == "tatp_uniform" and not r["dominated"]]
+            if r["workload"] == "multihost_serve" and not r["dominated"]]
     rows[0]["rank"], rows[1]["rank"] = rows[1]["rank"], rows[0]["rank"]
     path = tmp_path / "broken_plan.json"
     path.write_text(json.dumps(doc))
@@ -459,8 +460,8 @@ def test_dintplan_cli_describe_and_sarif(tmp_path, capsys, monkeypatch):
     assert main(["describe", "--json"]) == 0
     payload = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert payload["metric"] == "dintplan"
-    assert payload["knobs"]["use_fused"]["token"] == "fused"
-    assert payload["knobs"]["use_fused"]["env"] == "DINT_USE_FUSED"
+    assert payload["knobs"]["use_hotset"]["token"] == "hot"
+    assert payload["knobs"]["use_hotset"]["env"] == "DINT_USE_HOTSET"
     assert "tatp_uniform" in payload["workloads"]
     assert payload["decision_rule"]
 
@@ -481,7 +482,7 @@ def test_bench_and_exp_route_through_resolve_for():
     import exp
     knobs, meta = bench._plan_resolve("tatp_uniform")
     assert meta is not None and meta["overridden"] == []
-    assert set(knobs) >= {"use_pallas", "use_hotset", "use_fused"}
+    assert set(knobs) >= {"use_hotset"}
     assert exp._plan_knobs("smallbank_skewed").keys() == \
         set(P._WORKLOADS_BY_NAME["smallbank_skewed"].knobs)
     m = exp._plan_meta()

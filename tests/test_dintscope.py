@@ -9,6 +9,7 @@ The named-scope annotations themselves are pinned semantics-neutral:
 engine outputs bit-identical with scopes present vs replaced by null
 contexts.
 """
+import copy
 import json
 import os
 import subprocess
@@ -73,7 +74,7 @@ def test_scope_annotation_is_semantics_neutral(monkeypatch):
 
     def run_once():
         run, init, drain = sd.build_pipelined_runner(
-            512, w=64, cohorts_per_block=2, use_pallas=False)
+            512, w=64, cohorts_per_block=2)
         carry = init(sd.create(512))
         carry, stats = run(carry, jax.random.PRNGKey(3))
         db, tail = drain(carry)
@@ -186,6 +187,87 @@ def test_diff_names_overlap_route_prefetch_regression(tmp_path):
     assert not d["ok"]
     assert any(r.get("wave") == "dint.multihost_sb.route_prefetch"
                for r in d["regressions"])
+
+
+# ------------------------------------------------- the aliased diff gate
+
+
+def _zero_row():
+    return {"ms": 0.0, "slices": 0, "ms_per_step": None, "pct": 0.0,
+            "bytes_per_step": None, "gbps": None}
+
+
+def _taken_over_ab_artifacts():
+    """An A/B pair built from the checked-in fixture: A ran every aliased
+    wave under its own scope (successors unobserved), B ran them under
+    their successors (constituents unobserved, each successor carrying
+    its own time plus its constituents') — the equal-work case the
+    aliased gate must pass."""
+    base = attrib.report(FIXTURE, geometry=GEOM)
+    a, b = copy.deepcopy(base), copy.deepcopy(base)
+    dsts = sorted(set(attrib.WAVE_ALIASES.values()))
+    for dst in dsts:
+        srcs = [s for s, d in attrib.WAVE_ALIASES.items() if d == dst]
+        for src in srcs:
+            a["waves"][src] = dict(a["waves"][src], **{
+                k: round(base["waves"][src][k] + base["waves"][dst][k], 6)
+                for k in ("ms", "ms_per_step", "pct")})
+            a["waves"][src]["slices"] += base["waves"][dst]["slices"]
+            b["waves"][src] = _zero_row()
+        a["waves"][dst] = _zero_row()
+        b["waves"][dst] = dict(
+            _zero_row(), slices=a["waves"][srcs[0]]["slices"],
+            **{k: round(sum(a["waves"][s][k] for s in srcs), 6)
+               for k in ("ms", "ms_per_step", "pct")})
+    return a, b
+
+
+def test_aliased_fold_merges_constituents():
+    for src, dst in attrib.WAVE_ALIASES.items():
+        assert src in waves.ALL_WAVES and dst in waves.ALL_WAVES
+        assert src.split(".")[1] == dst.split(".")[1]   # same engine
+    a, b = _taken_over_ab_artifacts()
+    d = attrib.diff_breakdowns(a, b)
+    assert d["ok"], d["regressions"]
+    assert set(d["aliased"]) == set(attrib.WAVE_ALIASES.values())
+    rows = {r["wave"]: r for r in d["rows"]}
+    for src, dst in attrib.WAVE_ALIASES.items():
+        assert src not in rows                  # merged away
+        assert rows[dst]["includes"] == sorted(
+            s for s, t in attrib.WAVE_ALIASES.items() if t == dst)
+        # folding conserves time
+        assert abs(rows[dst]["a_ms_per_step"]
+                   - rows[dst]["b_ms_per_step"]) < 1e-6
+    # symmetric sides never fold
+    assert attrib.diff_breakdowns(a, a)["aliased"] == {}
+    assert attrib.diff_breakdowns(b, b)["aliased"] == {}
+    assert attrib.diff_breakdowns(a, b, alias=False)["aliased"] == {}
+
+
+def test_aliased_diff_cli_gate_names_regressed_wave(tmp_path):
+    """The CLI gate folds the alias map, passes the equal-work A/B, and
+    exits 1 NAMING the successor when it regresses past threshold — a
+    regression --no-alias provably cannot see (the raw rows have no
+    common observed wave)."""
+    a, b = _taken_over_ab_artifacts()
+    dst = "dint.multihost_sb.route_prefetch"
+    b2 = copy.deepcopy(b)
+    for k in ("ms", "ms_per_step"):
+        b2["waves"][dst][k] = round(b2["waves"][dst][k] * 1.6, 6)
+    pa, pb, pb2 = (str(tmp_path / f"{n}.json") for n in ("a", "b", "b2"))
+    for p, obj in ((pa, a), (pb, b), (pb2, b2)):
+        with open(p, "w") as f:
+            json.dump(obj, f)
+    c = _cli(["diff", pa, pb])
+    assert c.returncode == 0, (c.stdout, c.stderr)
+    assert "aliased:" in c.stdout                # the fold is announced
+    c = _cli(["diff", pa, pb2, "--json"])
+    assert c.returncode == 1, (c.stdout, c.stderr)
+    d = json.loads(c.stdout.strip().splitlines()[-1])
+    assert any(r.get("wave") == dst for r in d["regressions"])
+    assert d["aliased"][dst] == sorted(
+        s for s, t in attrib.WAVE_ALIASES.items() if t == dst)
+    assert _cli(["diff", pa, pb2, "--no-alias"]).returncode == 0
 
 
 def test_diff_ignores_sub_noise_waves():

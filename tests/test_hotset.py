@@ -1,19 +1,16 @@
-"""dintcache (round 10): VMEM-resident hot-set serving for the skewed
-random-access hot path.
+"""dintcache (round 10): hot-set serving for the skewed random-access hot
+path.
 
 The acceptance bar of ISSUE 5: `DINT_USE_HOTSET=1` must be BIT-IDENTICAL
 to the default path on every integrated engine — the hot mirror is a pure
 acceleration structure (write-through keeps mirror == table prefix an
 invariant), so stats, tables, arb stamps, and log rings cannot move. These
-tests pin (a) each hot kernel against its XLA partition AND the plain
-round-6 path, including an adversarial batch with duplicate indices
-straddling the hot_n boundary; (b) the write-through coherence invariant;
-(c) SmallBank dense + sharded, the store engine (Zipfian micro), the
-cached store, and skewed-TATP end-to-end bit-identical under the hot tier
-on BOTH serving routes (XLA partition and pallas VMEM kernels); (d) the
-env/resolve plumbing and the per-kernel probe cache (the round-10 probe
-recompile fix); (e) the degrade contract — a broken hot kernel costs the
-VMEM residency, never the partition or the measurement."""
+tests pin (a) the partitioned gather and scatter (ops/hotset.py) against
+the plain take / double scatter, including an adversarial batch with
+duplicate indices straddling the hot_n boundary; (b) the write-through
+coherence invariant; (c) SmallBank dense + sharded, the store engine
+(Zipfian micro), the cached store, and skewed-TATP end-to-end
+bit-identical under the hot tier; (d) the env/resolve plumbing."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,19 +18,23 @@ import pytest
 
 from dint_tpu.clients import workloads as wl
 from dint_tpu.engines import smallbank_dense as sd, tatp_dense as td
-from dint_tpu.ops import pallas_gather as pg
+from dint_tpu.ops import hotset
 
 U32 = jnp.uint32
 I32 = jnp.int32
 
 
-# -------------------------------------------------------- hot kernels
+# ------------------------------------------------------ the partition
+
+
+def _plain_take(tab, idx, vw):
+    return np.asarray(tab).reshape(-1, vw)[np.asarray(idx)].reshape(-1)
 
 
 @pytest.mark.parametrize("n,hot,vw,k", [
     (1000, 40, 10, 333),     # val-style wide rows, 4% hot
     (512, 300, 1, 700),      # single words, most of the table mirrored
-    (37, 5, 4, 5),           # K below the DMA ring depth
+    (37, 5, 4, 5),           # a handful of lanes
     (64, 1, 2, 64),          # single-row mirror
 ])
 def test_gather_rows_hot_matches_plain_and_xla(rng, n, hot, vw, k):
@@ -42,18 +43,14 @@ def test_gather_rows_hot_matches_plain_and_xla(rng, n, hot, vw, k):
     mirror = tab[:hot * vw]
     idx = jnp.asarray(rng.integers(0, n, k).astype(np.int32))
     midx = jnp.where(idx < hot, idx, -1)
-    got = pg.gather_rows_hot(tab, mirror, idx, midx, vw)
-    assert np.array_equal(np.asarray(got),
-                          np.asarray(pg.gather_rows(tab, idx, vw)))
-    assert np.array_equal(
-        np.asarray(got),
-        np.asarray(pg._xla_hot_gather(tab, mirror, idx, midx, vw)))
+    got = hotset.hot_gather(tab, mirror, idx, midx, vw)
+    assert np.array_equal(np.asarray(got), _plain_take(tab, idx, vw))
 
 
 def test_gather_rows_hot_duplicates_straddle_boundary(rng):
     """The adversarial batch: heavy duplication of the two rows on either
     side of hot_n — the exact lanes where a partition bug would read the
-    wrong tier — interleaved so hot/cold alternate within the ring."""
+    wrong tier — interleaved so hot/cold alternate."""
     n, hot, vw = 100, 50, 3
     tab = jnp.asarray(rng.integers(0, 1 << 32, n * vw, np.int64)
                       .astype(np.uint32))
@@ -61,9 +58,8 @@ def test_gather_rows_hot_duplicates_straddle_boundary(rng):
     idx = jnp.asarray(np.tile([hot - 1, hot, hot - 1, hot - 1, hot, hot],
                               32).astype(np.int32))
     midx = jnp.where(idx < hot, idx, -1)
-    got = pg.gather_rows_hot(tab, mirror, idx, midx, vw)
-    assert np.array_equal(np.asarray(got),
-                          np.asarray(pg.gather_rows(tab, idx, vw)))
+    got = hotset.hot_gather(tab, mirror, idx, midx, vw)
+    assert np.array_equal(np.asarray(got), _plain_take(tab, idx, vw))
 
 
 def test_scatter_rows_hot_matches_double_scatter(rng):
@@ -83,117 +79,40 @@ def test_scatter_rows_hot_matches_double_scatter(rng):
     mask_j = jnp.asarray(mask)
     vals = jnp.asarray(rng.integers(0, 1 << 32, k * vw, np.int64)
                        .astype(np.uint32))
-    t_p, m_p = pg.scatter_rows_hot(jnp.array(tab), jnp.array(mirror),
-                                   rows_j, midx, mask_j, vals, vw)
-    t_x, m_x = pg.hot_scatter(jnp.array(tab), jnp.array(mirror), rows_j,
-                              midx, mask_j, vals, vw, use_pallas=False)
-    assert np.array_equal(np.asarray(t_p), np.asarray(t_x))
-    assert np.array_equal(np.asarray(m_p), np.asarray(m_x))
+    t_p, m_p = hotset.hot_scatter(jnp.array(tab), jnp.array(mirror),
+                                  rows_j, midx, mask_j, vals, vw)
+    # the plain double scatter, row by row
+    t_x = np.asarray(tab).reshape(n, vw).copy()
+    m_x = np.asarray(mirror).reshape(hot, vw).copy()
+    v = np.asarray(vals).reshape(k, vw)
+    t_x[rows[mask]] = v[mask]
+    m_x[rows[mask & (rows < hot)]] = v[mask & (rows < hot)]
+    assert np.array_equal(np.asarray(t_p), t_x.reshape(-1))
+    assert np.array_equal(np.asarray(m_p), m_x.reshape(-1))
     # write-through coherence: the mirror IS the table prefix afterwards
     assert np.array_equal(np.asarray(t_p)[: hot * vw], np.asarray(m_p))
 
 
-@pytest.mark.parametrize("m,row_space,hot_n,seed", [
-    (64, 8, 4, 0),     # brutal duplication, boundary inside the row set
-    (64, 1000, 40, 1),  # mostly conflict-free, 4%-style prefix
-    (10, 3, 1, 2),      # m > ring depth barely
-    (130, 16, 8, 4),    # several ring wraps, half the rows hot
-])
-def test_lock_arbitrate_hot_prefix_bit_identical(m, row_space, hot_n,
-                                                 seed):
-    """The VMEM arb-prefix residency changes only DMA endpoints: grants
-    and stamps must match both the hot_n=0 kernel and the XLA chain on
-    adversarial duplicate/held batches straddling the prefix."""
-    r = np.random.default_rng(seed)
-    n1 = max(row_space + 1, 32)
-    arb0 = np.zeros(n1, np.uint32)
-    for row in r.choice(row_space, max(1, row_space // 3), replace=False):
-        step = r.choice([3, 4])
-        arb0[row] = np.uint32((step << td.K_ARB) | r.integers(0, 100))
-    t = jnp.asarray(5, U32)
-    rows = jnp.asarray(r.integers(0, row_space, m).astype(np.int32))
-    act = jnp.asarray(r.random(m) < 0.75)
-    a_0, g_0 = pg.lock_arbitrate(jnp.asarray(arb0), rows, act, t,
-                                 td.K_ARB)
-    a_h, g_h = pg.lock_arbitrate(jnp.asarray(arb0), rows, act, t,
-                                 td.K_ARB, hot_n=hot_n)
-    assert np.array_equal(np.asarray(a_0), np.asarray(a_h))
-    assert np.array_equal(np.asarray(g_0), np.asarray(g_h))
-
-
-# ------------------------------------------------- resolve + probe cache
+# ------------------------------------------------------------ resolve
 
 
 def test_resolve_use_hotset_env(monkeypatch):
     monkeypatch.delenv("DINT_USE_HOTSET", raising=False)
-    assert pg.resolve_use_hotset(None) is False
+    assert hotset.resolve_use_hotset(None) is False
     monkeypatch.setenv("DINT_USE_HOTSET", "0")
-    assert pg.resolve_use_hotset(None) is False
+    assert hotset.resolve_use_hotset(None) is False
     monkeypatch.setenv("DINT_USE_HOTSET", "1")
-    assert pg.resolve_use_hotset(None) is True
-    assert pg.resolve_use_hotset(False) is False      # explicit wins
-
-
-def test_probe_cache_is_per_kernel(monkeypatch):
-    """The round-10 probe fix: a second kernels_available call that only
-    changes the OTHER kernel's geometry must hit the gather probe's
-    cache — proven by breaking gather_rows after the first call."""
-    pg._probe_cache.clear()
-    assert pg.kernels_available(n_idx=96, m_lock=24) is True
-
-    def boom(*a, **k):
-        raise RuntimeError("probe must not re-run (simulated)")
-
-    monkeypatch.setattr(pg, "gather_rows", boom)
-    # same gather geometry, no lock probe requested: pure cache hit
-    assert pg.kernels_available(n_idx=96, m_lock=None) is True
-    # same gather geometry, NEW lock geometry: only the lock re-probes
-    assert pg.kernels_available(n_idx=96, m_lock=12) is True
-    pg._probe_cache.clear()
-
-
-def test_broken_hot_kernel_raises_and_xla_partition_still_serves(monkeypatch):
-    """A Mosaic refusal of the hot kernels raises for the caller that
-    asked for them (use_pallas + use_hotset) — resolver and builder both,
-    never cached — while the partition itself has an XLA form that a
-    caller who does NOT ask for the kernels still gets."""
-    pg._probe_cache.clear()
-
-    def boom(*a, **k):
-        raise RuntimeError("Mosaic lowering failed (simulated)")
-
-    monkeypatch.setattr(pg, "gather_rows_hot", boom)
-    for _ in range(2):
-        with pytest.raises(pg.KernelRefused,
-                           match=r"'hot'.*Mosaic lowering failed"):
-            pg.hot_kernels_available(n_idx=64)
-    assert not any(k[0] == "hot" for k in pg._probe_cache)
-    # bypass the builder memo: this build must see the broken kernel
-    sd.build_pipelined_runner.cache.clear()
-    with pytest.raises(pg.KernelRefused, match="'hot'"):
-        sd.build_pipelined_runner(100, w=16, cohorts_per_block=2,
-                                  use_pallas=True, use_hotset=True)
-    run_f, init, drain = sd.build_pipelined_runner(
-        100, w=16, cohorts_per_block=2, use_pallas=False, use_hotset=True)
-    carry = init(sd.create(100))
-    carry, s = run_f(carry, jax.random.PRNGKey(0))
-    db, tail = drain(carry)
-    tot = (np.asarray(s, np.int64).sum(axis=0)
-           + np.asarray(tail, np.int64).sum(axis=0))
-    assert int(tot[sd.STAT_ATTEMPTED]) == 2 * 16
-    assert db.hot_n > 0                       # the partition still ran
-    pg._probe_cache.clear()
-    sd.build_pipelined_runner.cache.clear()
+    assert hotset.resolve_use_hotset(None) is True
+    assert hotset.resolve_use_hotset(False) is False      # explicit wins
 
 
 # --------------------------------------------- end-to-end: smallbank
 
 
-def _run_sb(use_hotset, use_pallas, n=300, blocks=3):
+def _run_sb(use_hotset, n=300, blocks=3):
     db = sd.create(n)
     run_f, init, drain = sd.build_pipelined_runner(
-        n, w=64, cohorts_per_block=2, use_pallas=use_pallas,
-        use_hotset=use_hotset)
+        n, w=64, cohorts_per_block=2, use_hotset=use_hotset)
     carry = init(db)
     tot = np.zeros(sd.N_STATS, np.int64)
     for i in range(blocks):
@@ -218,29 +137,26 @@ def _same_shared_state(db0, db1, leaves, log=True):
 def test_smallbank_dense_hotset_bit_identical(monkeypatch):
     """ISSUE 5 acceptance pin: DINT_USE_HOTSET=1 (env route, the exact
     production spelling, at the workload's hot_frac=0.04) reproduces the
-    default path's stats, balances, stamps, and log rings bit for bit on
-    BOTH serving routes, and the mirror coherence invariant holds."""
-    db0, t0 = _run_sb(False, False)
+    default path's stats, balances, stamps, and log rings bit for bit,
+    and the mirror coherence invariant holds."""
+    db0, t0 = _run_sb(False)
     monkeypatch.setenv("DINT_USE_HOTSET", "1")
-    db1, t1 = _run_sb(None, False)            # env route
-    db2, t2 = _run_sb(None, True)             # + VMEM kernels
-    assert t0.tolist() == t1.tolist() == t2.tolist()
+    db1, t1 = _run_sb(None)                   # env route
+    assert t0.tolist() == t1.tolist()
     assert int(t0[sd.STAT_COMMITTED]) > 0
-    for db in (db1, db2):
-        _same_shared_state(db0, db, ("bal", "x_step", "s_step", "step"))
-        hn, n = db.hot_n, db.n_accounts
-        assert hn == max(1, int(n * wl.SB_HOT_FRAC))
-        idx = np.concatenate([np.arange(hn), n + np.arange(hn)])
-        assert np.array_equal(np.asarray(db.bal)[idx],
-                              np.asarray(db.hot_bal))
-        assert np.array_equal(np.asarray(db.x_step)[idx],
-                              np.asarray(db.hot_x))
-        assert np.array_equal(np.asarray(db.s_step)[idx],
-                              np.asarray(db.hot_s))
+    _same_shared_state(db0, db1, ("bal", "x_step", "s_step", "step"))
+    hn, n = db1.hot_n, db1.n_accounts
+    assert hn == max(1, int(n * wl.SB_HOT_FRAC))
+    idx = np.concatenate([np.arange(hn), n + np.arange(hn)])
+    assert np.array_equal(np.asarray(db1.bal)[idx], np.asarray(db1.hot_bal))
+    assert np.array_equal(np.asarray(db1.x_step)[idx],
+                          np.asarray(db1.hot_x))
+    assert np.array_equal(np.asarray(db1.s_step)[idx],
+                          np.asarray(db1.hot_s))
     # conservation on the hot path
     start = 2 * 300 * 1000
-    assert int(np.asarray(sd.total_balance(db2))) \
-        == start + int(t2[sd.STAT_BAL_DELTA])
+    assert int(np.asarray(sd.total_balance(db1))) \
+        == start + int(t1[sd.STAT_BAL_DELTA])
 
 
 def test_smallbank_hashed_locks_skip_stamp_mirror(monkeypatch):
@@ -248,8 +164,8 @@ def test_smallbank_hashed_locks_skip_stamp_mirror(monkeypatch):
     onto hot slots), so the stamp mirror must NOT exist — only balances
     mirror — and outputs stay bit-identical."""
     monkeypatch.setattr(sd, "MAX_LOCK_SLOTS", 128)
-    db0, t0 = _run_sb(False, False, n=200)
-    db1, t1 = _run_sb(True, False, n=200)
+    db0, t0 = _run_sb(False, n=200)
+    db1, t1 = _run_sb(True, n=200)
     assert db1.hot_x is None and db1.hot_s is None
     assert db1.hot_bal is not None
     assert t0.tolist() == t1.tolist()
@@ -261,17 +177,15 @@ def test_smallbank_hashed_locks_skip_stamp_mirror(monkeypatch):
 
 @pytest.mark.slow  # ~11s; the round-10 rule — dense + store hot pins stay tier-1
 def test_dense_sharded_sb_hotset_bit_identical():
-    """Two configs in tier-1 (baseline vs hot tier on the VMEM kernels —
-    the XLA-partition route is pinned on single-chip above); one shard_map
-    compile per config keeps the test inside the tier-1 budget."""
+    """Two configs (baseline vs hot tier); one shard_map compile per
+    config."""
     from dint_tpu.parallel import dense_sharded_sb as dsb
 
-    def run(uh, up):
+    def run(uh):
         mesh = dsb.make_mesh(8)
         state = dsb.create_sharded_sb(mesh, 8, 400)
         run_f, init, drain = dsb.build_sharded_sb_runner(
-            mesh, 8, 400, w=32, cohorts_per_block=2, use_pallas=up,
-            use_hotset=uh)
+            mesh, 8, 400, w=32, cohorts_per_block=2, use_hotset=uh)
         carry = init(state)
         tot = np.zeros(dsb.N_STATS, np.int64)
         for i in range(2):
@@ -281,8 +195,8 @@ def test_dense_sharded_sb_hotset_bit_identical():
         state, tail = drain(carry)
         return state, tot + np.asarray(tail, np.int64).sum(axis=0)
 
-    s0, t0 = run(False, False)
-    s2, t2 = run(True, True)
+    s0, t0 = run(False)
+    s2, t2 = run(True)
     assert t0.tolist() == t2.tolist()
     assert int(t0[1]) > 0                      # committed
     for s in (s2,):
@@ -305,15 +219,14 @@ def test_dense_sharded_sb_hotset_bit_identical():
 @pytest.mark.slow
 def test_tatp_dense_hotset_bit_identical():
     """Skewed-TATP experiment route (builder kwarg; off by default):
-    meta/magic gathers, write-through installs, and the VMEM arb-prefix
-    lock pass — bit-identical stats, tables, stamps, logs. slow-marked:
-    TATP's hot tier is the off-by-default experiment route, and its
-    kernel mechanics (hot gather/scatter parity, the arb-prefix lock
-    pass) are pinned by the tier-1 kernel tests above."""
-    def run(uh, up):
+    meta/magic gathers and write-through installs — bit-identical stats,
+    tables, stamps, logs. slow-marked: TATP's hot tier is the
+    off-by-default experiment route, and the partition's mechanics (hot
+    gather/scatter parity) are pinned by the tier-1 tests above."""
+    def run(uh):
         db = td.populate(np.random.default_rng(0), 200, val_words=4)
         run_f, init, drain = td.build_pipelined_runner(
-            200, w=64, val_words=4, cohorts_per_block=2, use_pallas=up,
+            200, w=64, val_words=4, cohorts_per_block=2,
             use_hotset=uh, hot_frac=0.2)
         carry = init(db)
         tot = np.zeros(td.N_STATS, np.int64)
@@ -324,18 +237,16 @@ def test_tatp_dense_hotset_bit_identical():
         db, tail = drain(carry)
         return db, tot + np.asarray(tail, np.int64).sum(axis=0)
 
-    db0, t0 = run(False, False)
-    db1, t1 = run(True, False)
-    db2, t2 = run(True, True)
-    assert t0.tolist() == t1.tolist() == t2.tolist()
+    db0, t0 = run(False)
+    db1, t1 = run(True)
+    assert t0.tolist() == t1.tolist()
     assert int(t0[td.STAT_COMMITTED]) > 0
-    for db in (db1, db2):
-        _same_shared_state(db0, db, ("val", "meta", "arb", "step"))
-        hn = db.hot_n
-        assert np.array_equal(np.asarray(db.meta)[:hn],
-                              np.asarray(db.hot_meta))
-        assert np.array_equal(np.asarray(db.val)[: hn * 4],
-                              np.asarray(db.hot_val))
+    _same_shared_state(db0, db1, ("val", "meta", "arb", "step"))
+    hn = db1.hot_n
+    assert np.array_equal(np.asarray(db1.meta)[:hn],
+                          np.asarray(db1.hot_meta))
+    assert np.array_equal(np.asarray(db1.val)[: hn * 4],
+                          np.asarray(db1.hot_val))
 
 
 def test_tatp_dense_hotset_off_by_default(monkeypatch):
@@ -353,7 +264,7 @@ def test_tatp_dense_hotset_off_by_default(monkeypatch):
 
 def test_store_hotset_bit_identical(rng):
     """The Zipfian store micro's engine: replies and table bit-identical
-    with the hot tier threaded (both routes), mirror coherent with every
+    with the hot tier threaded, mirror coherent with every
     currently-present hot key."""
     from dint_tpu.clients.micro import STORE_MAGIC, make_store_table
     from dint_tpu.engines import store
@@ -363,7 +274,7 @@ def test_store_hotset_bit_identical(rng):
 
     n_keys, width, vw, hot_n = 2000, 256, 10, 500
 
-    def run(hot_on, up):
+    def run(hot_on):
         r = np.random.default_rng(7)
         table = make_store_table(n_keys)
         hot = store.attach_hot(table, hot_n) if hot_on else None
@@ -382,22 +293,18 @@ def test_store_hotset_bit_identical(rng):
             if hot is None:
                 table, rep = store.step(table, batch)
             else:
-                table, rep, hot = store.step(table, batch, hot=hot,
-                                             use_pallas=up)
+                table, rep, hot = store.step(table, batch, hot=hot)
             reps.append(jax.tree.map(np.asarray, rep))
         return table, hot, reps
 
-    t0, _, r0 = run(False, False)
-    t1, h1, r1 = run(True, False)
-    t2, h2, r2 = run(True, True)
-    for other in (r1, r2):
-        for a, b in zip(r0, other):
-            for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-                assert np.array_equal(la, lb)
-    for t in (t1, t2):
-        for leaf in ("key_hi", "key_lo", "val", "ver", "valid"):
-            assert np.array_equal(np.asarray(getattr(t0, leaf)),
-                                  np.asarray(getattr(t, leaf))), leaf
+    t0, _, r0 = run(False)
+    t1, h1, r1 = run(True)
+    for a, b in zip(r0, r1):
+        for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+            assert np.array_equal(la, lb)
+    for leaf in ("key_hi", "key_lo", "val", "ver", "valid"):
+        assert np.array_equal(np.asarray(getattr(t0, leaf)),
+                              np.asarray(getattr(t1, leaf))), leaf
     # mirror == table for every hot key the probe can hit
     klo = jnp.arange(hot_n, dtype=U32)
     khi = jnp.zeros((hot_n,), U32)
@@ -405,20 +312,18 @@ def test_store_hotset_bit_identical(rng):
     hit, _, _, val, ver, _, _ = kv.probe(t1, khi, klo, b1, b2)
     hitn = np.asarray(hit)
     assert hitn.any()
-    for h in (h1, h2):
-        assert np.array_equal(np.asarray(val)[hitn],
-                              np.asarray(h.val).reshape(hot_n, vw)[hitn])
-        assert np.array_equal(np.asarray(ver)[hitn],
-                              np.asarray(h.ver)[hitn])
+    assert np.array_equal(np.asarray(val)[hitn],
+                          np.asarray(h1.val).reshape(hot_n, vw)[hitn])
+    assert np.array_equal(np.asarray(ver)[hitn], np.asarray(h1.ver)[hitn])
 
 
 @pytest.mark.slow
 def test_store_cache_hotset_bit_identical():
     """Cache-mode store: replies, miss vector, MASKED flush/evicted
     records, and cache tables bit-identical across all three policies
-    with the in-cache hot tier on (both routes). Flush/evicted values of
+    with the in-cache hot tier on. Flush/evicted values of
     mask-False lanes are don't-cares by contract (the host applies only
-    masked lanes), so comparison is on the masked set. slow-marked (9
+    masked lanes), so comparison is on the masked set. slow-marked (6
     jitted configs): the full-table store engine's hot tier — the same
     HotKV partition — is pinned in tier-1 above."""
     from dint_tpu.engines import store_cache as sc
@@ -426,7 +331,7 @@ def test_store_cache_hotset_bit_identical():
 
     vw = 10
 
-    def run(hot_keys, up, policy):
+    def run(hot_keys, policy):
         cache = sc.create(64, val_words=vw, hot_keys=hot_keys)
         outs = []
         r = np.random.default_rng(3)
@@ -438,8 +343,7 @@ def test_store_cache_hotset_bit_identical():
             vals[:, 0] = r.integers(0, 99, 128)
             batch = make_batch(ops, keys, vals, width=128, val_words=vw)
             cache, rep, miss, flush = sc.cache_step(cache, batch,
-                                                    policy=policy,
-                                                    use_pallas=up)
+                                                    policy=policy)
             m = np.asarray(miss)
             rk = keys[m][:32]
             pad = 64
@@ -464,21 +368,17 @@ def test_store_cache_hotset_bit_identical():
         return cache, outs
 
     for pol in (sc.WB_BLOOM, sc.WB_NOBLOOM, sc.WT):
-        c0, o0 = run(0, False, pol)
-        c1, o1 = run(300, False, pol)
-        c2, o2 = run(300, True, pol)
-        for other in (o1, o2):
-            for oa, ob in zip(o0, other):
-                for la, lb in zip(jax.tree.leaves(oa),
-                                  jax.tree.leaves(ob)):
-                    assert np.array_equal(la, lb), pol
-        for c in (c1, c2):
-            for leaf in ("key_hi", "key_lo", "val", "ver", "valid"):
-                assert np.array_equal(np.asarray(getattr(c0.kv, leaf)),
-                                      np.asarray(getattr(c.kv, leaf))), \
-                    (pol, leaf)
-            assert np.array_equal(np.asarray(c0.dirty),
-                                  np.asarray(c.dirty)), pol
+        c0, o0 = run(0, pol)
+        c1, o1 = run(300, pol)
+        for oa, ob in zip(o0, o1):
+            for la, lb in zip(jax.tree.leaves(oa), jax.tree.leaves(ob)):
+                assert np.array_equal(la, lb), pol
+        for leaf in ("key_hi", "key_lo", "val", "ver", "valid"):
+            assert np.array_equal(np.asarray(getattr(c0.kv, leaf)),
+                                  np.asarray(getattr(c1.kv, leaf))), \
+                (pol, leaf)
+        assert np.array_equal(np.asarray(c0.dirty),
+                              np.asarray(c1.dirty)), pol
 
 
 # ------------------------------------------------------------ workload

@@ -40,7 +40,7 @@ def _dense(monitor=True):
 def _bank(monitor=True):
     run, init, drain = sd.build_pipelined_runner(
         N_SUB, w=W, cohorts_per_block=CPB, monitor=monitor,
-        use_pallas=False, use_fused=False, use_hotset=False, trace=False)
+        use_hotset=False, trace=False)
     return run, init(sd.create(N_SUB, log_capacity=1 << 10)), drain
 
 

@@ -5,9 +5,7 @@ invariants — sortedness, latest-wins dedupe, tombstone shadowing, the
 stale contract and the locate lower bound — directly."""
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-from dint_tpu.ops import pallas_gather as pg
 from dint_tpu.tables import kv, run as run_mod
 
 VW = 4
@@ -159,8 +157,7 @@ def _scan_oracle(items, start, slen):
     return rows[:slen]
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_merge_scan_matches_sorted_view(rng, use_pallas):
+def test_merge_scan_matches_sorted_view(rng):
     """locate → slab gather (either route) → merge_scan == the first
     slen live keys >= start of the merged dict, in key order."""
     scan_max, dcap = 6, 4
@@ -179,9 +176,8 @@ def test_merge_scan_matches_sorted_view(rng, use_pallas):
     q_hi = jnp.zeros(16, U32)
     q_lo = jnp.asarray(starts.astype(np.uint32))
     off = jnp.clip(run_mod.locate(run, q_hi, q_lo), 0, run.cap - lg)
-    s_hi, s_lo, s_ver, s_val = pg.scan_slab(
-        run.key_hi, run.key_lo, run.ver, run.val, off, lg, VW,
-        use_pallas=use_pallas)
+    s_hi, s_lo, s_ver, s_val = run_mod.scan_slab(
+        run.key_hi, run.key_lo, run.ver, run.val, off, lg, VW)
     count, k_hi, k_lo, k_ver, k_val, d_hits = run_mod.merge_scan(
         run, s_hi, s_lo, s_ver, s_val, off, q_hi, q_lo,
         jnp.asarray(slens, jnp.int32), scan_max)
@@ -199,22 +195,6 @@ def test_merge_scan_matches_sorted_view(rng, use_pallas):
         assert (k_lo[i, count[i]:] == 0).all()
         assert (k_ver[i, count[i]:] == 0).all()
     assert (np.asarray(d_hits) <= count).all()
-
-
-def test_scan_slab_routes_bit_identical(rng):
-    """The probe-and-degrade contract: the streaming kernel and the XLA
-    slab gather return bit-identical windows for in-bounds offsets."""
-    keys = rng.choice(500, size=80, replace=False)
-    table, _ = mk_table(rng, keys)
-    run = run_mod.from_table(table, delta_cap=8)
-    lg = 12
-    off = jnp.asarray(rng.integers(0, run.cap - lg, size=16), jnp.int32)
-    a = pg.scan_slab(run.key_hi, run.key_lo, run.ver, run.val, off, lg,
-                     VW, use_pallas=False)
-    b = pg.scan_slab(run.key_hi, run.key_lo, run.ver, run.val, off, lg,
-                     VW, use_pallas=True)
-    for x, y in zip(a, b):
-        assert np.array_equal(np.asarray(x), np.asarray(y))
 
 
 def test_locate_bits_matches_formula():

@@ -122,8 +122,7 @@ def test_hashed_lock_slots_conserve_balance(monkeypatch):
 
 # ------------------------------------------- against the sequential reference
 
-ENGINE = dict(use_pallas=False, use_fused=False, use_hotset=False,
-              trace=False)
+ENGINE = dict(use_hotset=False, trace=False)
 N_ACC, W, CPB = 2000, 128, 2
 
 

@@ -202,14 +202,13 @@ DCAP = 8
 
 
 def scan_step(table, run, ops, keys, vals, scan_lens, scan_max=SMAX,
-              width=None, use_pallas=False):
+              width=None):
     batch = make_batch(ops, keys, vals,
                        vers=np.asarray(scan_lens, np.uint32),
                        width=width or len(ops), val_words=VW)
     step = jax.jit(store.step, static_argnames=(
-        "maintain_bloom", "use_pallas", "scan_max"))
-    table, rep, run, srep = step(table, batch, use_pallas=use_pallas,
-                                 run=run, scan_max=scan_max)
+        "maintain_bloom", "scan_max"))
+    table, rep, run, srep = step(table, batch, run=run, scan_max=scan_max)
     return table, run, rep, srep
 
 
@@ -269,7 +268,7 @@ def test_scan_differential_vs_oracle(rng):
         lens = np.where(ops == Op.SCAN,
                         rng.integers(0, SMAX + 1, size=n), 0)
         table, run, rep, srep = scan_step(table, run, ops, keys, vals,
-                                          lens, use_pallas=bool(it % 2))
+                                          lens)
         rt = np.asarray(rep.rtype)[:n]
         rver = np.asarray(rep.ver)[:n]
         ot, ov, over, oscans = oracle.step(ops, keys, vals,
@@ -313,10 +312,10 @@ def test_scan_never_sees_spilled_insert(rng):
     assert k3 not in run_mod.to_items(run)
 
 
-def test_scan_three_routes_bit_identical(rng):
-    """Acceptance: identical ScanReplies from (a) the XLA slab-gather
-    fallback, (b) the pallas scan_rows kernel, and (c) the XLA route
-    after a drain-boundary rebuild_run folded the overlay."""
+def test_scan_two_routes_bit_identical(rng):
+    """Acceptance: identical ScanReplies from (a) the run with a pending
+    overlay and (b) the run after a drain-boundary rebuild_run folded
+    the overlay."""
     table = kv.create(1 << 6, slots=8, val_words=VW)
     keys = rng.choice(40, size=25, replace=False).astype(np.uint64)
     table = kv.populate(table, keys, rand_vals(rng, 25))
@@ -333,24 +332,20 @@ def test_scan_three_routes_bit_identical(rng):
     lens = np.array([SMAX, 3, 5, SMAX, 1, 4])
     svals = rand_vals(rng, 6)
 
-    def answer(t, rn, use_pallas):
-        _, _, rep, srep = scan_step(t, rn, sops, starts, svals, lens,
-                                    use_pallas=use_pallas)
+    def answer(t, rn):
+        _, _, rep, srep = scan_step(t, rn, sops, starts, svals, lens)
         return rep, srep
 
-    rep_a, srep_a = answer(table, run, False)
-    rep_b, srep_b = answer(table, run, True)
+    rep_a, srep_a = answer(table, run)
     rebuilt = store.rebuild_run(table, run)
     assert int(rebuilt.d_n) == 0
-    rep_c, srep_c = answer(table, rebuilt, False)
-    for rep, srep in ((rep_b, srep_b), (rep_c, srep_c)):
-        assert np.array_equal(np.asarray(rep.rtype),
-                              np.asarray(rep_a.rtype))
-        assert np.array_equal(np.asarray(rep.ver), np.asarray(rep_a.ver))
-        for f in ("key_hi", "key_lo", "ver", "val", "count"):
-            assert np.array_equal(np.asarray(getattr(srep, f)),
-                                  np.asarray(getattr(srep_a, f))), f
-    # the overlay-pending routes served rows from the delta...
+    rep_c, srep_c = answer(table, rebuilt)
+    assert np.array_equal(np.asarray(rep_c.rtype), np.asarray(rep_a.rtype))
+    assert np.array_equal(np.asarray(rep_c.ver), np.asarray(rep_a.ver))
+    for f in ("key_hi", "key_lo", "ver", "val", "count"):
+        assert np.array_equal(np.asarray(getattr(srep_c, f)),
+                              np.asarray(getattr(srep_a, f))), f
+    # the overlay-pending route served rows from the delta...
     assert int(np.asarray(srep_a.delta_hits).sum()) > 0
     # ...and the rebuilt run serves the same rows from the dense run
     assert int(np.asarray(srep_c.delta_hits).sum()) == 0

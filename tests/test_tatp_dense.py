@@ -392,22 +392,25 @@ def _full_width_lock(w, n_sub, t, arb0, active, keys):
     return arb, cand & (arb[rows] == packed), held
 
 
-@pytest.mark.parametrize("w,n_active", [
-    (512, 0), (512, 1), (512, 127), (512, 128), (512, 129), (512, 1024),
-    (100, 150)], ids=lambda v: str(v))
+@pytest.mark.parametrize("w,n_active,n_rows", [
+    (512, 0, None), (512, 1, None), (512, 127, None), (512, 128, None),
+    (512, 129, None), (512, 1024, None), (100, 150, None),
+    (512, 64, 8), (512, 2, 1), (512, 130, 16)], ids=lambda v: str(v))
 def test_compacted_lock_wave_equals_full_width_reference(
-        monkeypatch, w, n_active):
+        monkeypatch, w, n_active, n_rows):
     """C = 128 at both widths: 0, 1, C-1, C, C+1 and 2w active slots, and a
     width that is no multiple of C. The requests fall on half as many
     rows as there are requests (so that rows are fought over within a
-    chunk and across chunks), a third of them held from step t-1, the
-    rest bearing older stamps."""
+    chunk and across chunks), or on ``n_rows`` of them (64 requests on 8
+    rows, 2 on 1, 130 on 16 across two chunks: heavy duplication), a
+    third of the rows held from step t-1, the rest bearing older
+    stamps."""
     n_sub, t, chunk = 2000, 40, compact.chunk_lanes(2 * w)
     assert chunk == 128
     rng = np.random.default_rng(n_active)
     active = np.zeros(2 * w, bool)
     active[rng.choice(2 * w, n_active, replace=False)] = True
-    pool = rng.choice(n_sub, max(4, n_active // 2), replace=False)
+    pool = rng.choice(n_sub, n_rows or max(4, n_active // 2), replace=False)
     keys = rng.choice(pool, 2 * w)
     arb0 = np.zeros(td.n_rows(n_sub) + 1, np.uint32)
     arb0[pool] = (rng.integers(t - 5, t - 1, len(pool)) << td.K_ARB) \
@@ -549,3 +552,88 @@ def test_write_heavy_mix_runs_several_chunks_and_matches_generic_engine():
     assert snap["lock_requests"] > blocks * 2 * chunk
     assert 2 * blocks * 2 <= snap["lock_chunks"] <= \
         -(-snap["lock_requests"] // chunk) + blocks * 2
+
+
+# ------------------------------------- the builders' compatibility keywords
+
+
+def _small_builders():
+    """The three builders the benchmark's deployments call with
+    ``use_pallas=False, use_fused=False``, each at a tiny geometry:
+    name -> (builder, build(**kw), abstract (carry, key))."""
+    from dint_tpu.engines import smallbank_dense as sd
+    from dint_tpu.parallel import dense_sharded as ds
+
+    def tatp_args(init):
+        return init(td.create(200, val_words=VW, log_capacity=128))
+
+    def bank_args(init):
+        return init(sd.create(200, log_capacity=128))
+
+    def sharded_args(init):
+        return init(ds.create_sharded(ds.make_mesh(4), 4, 800, val_words=VW,
+                                      log_capacity=128))
+
+    return {
+        "tatp_dense": (
+            td.build_pipelined_runner,
+            lambda **kw: td.build_pipelined_runner(
+                200, w=16, val_words=VW, cohorts_per_block=2, **kw),
+            tatp_args),
+        "smallbank_dense": (
+            sd.build_pipelined_runner,
+            lambda **kw: sd.build_pipelined_runner(
+                200, w=16, cohorts_per_block=2, **kw),
+            bank_args),
+        "dense_sharded": (
+            ds.build_sharded_pipelined_runner,
+            lambda **kw: ds.build_sharded_pipelined_runner(
+                ds.make_mesh(4), 4, 800, w=16, val_words=VW,
+                cohorts_per_block=2, **kw),
+            sharded_args),
+    }
+
+
+BUILDERS = ("tatp_dense", "smallbank_dense", "dense_sharded")
+
+
+@pytest.mark.parametrize("name", BUILDERS)
+def test_kernel_keywords_are_accepted_only_as_off(name):
+    """``use_pallas`` / ``use_fused`` stay as keywords until the benchmark
+    stops passing them: False and None build, a truthy value raises."""
+    _, build, _ = _small_builders()[name]
+    for kw in ({"use_pallas": True}, {"use_fused": True},
+               {"use_pallas": 1, "use_fused": False}):
+        with pytest.raises(ValueError, match="PR 35"):
+            build(**kw)
+    for off in (False, None):
+        run, init, drain = build(use_pallas=off, use_fused=off)
+        assert callable(run) and callable(init) and callable(drain)
+
+
+@pytest.mark.parametrize("name", BUILDERS)
+def test_no_environment_variable_changes_the_block_program(
+        monkeypatch, name):
+    """With the three variables that used to route a builder to a kernel
+    set, the block's jaxpr is the one built without them, and holds no
+    pallas_call."""
+    builder, build, args = _small_builders()[name]
+
+    def block_text():
+        builder.cache.clear()       # a fresh build, not the memoised one
+        run, init, _ = build()      # None: the parent asked the environment
+        carry = jax.eval_shape(lambda: args(init))
+        key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+        return str(jax.make_jaxpr(run)(carry, key))
+
+    for var in ("DINT_USE_PALLAS", "DINT_USE_FUSED",
+                "DINT_PALLAS_INTERPRET"):
+        monkeypatch.delenv(var, raising=False)
+    plain = block_text()
+    for var in ("DINT_USE_PALLAS", "DINT_USE_FUSED",
+                "DINT_PALLAS_INTERPRET"):
+        monkeypatch.setenv(var, "1")
+    routed = block_text()
+    builder.cache.clear()
+    assert routed == plain
+    assert "pallas_call" not in plain and "scatter" in plain

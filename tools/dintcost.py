@@ -12,7 +12,7 @@ Usage:
     python tools/dintcost.py report TARGET [TARGET ...] [--json] [-o OUT]
     python tools/dintcost.py report --all
     python tools/dintcost.py check --all                 # the CI gate
-    python tools/dintcost.py check --target tatp_dense/block@fused
+    python tools/dintcost.py check --target tatp_dense/block@hot
         [--allowlist tools/dintlint_allow.json] [--json]
     python tools/dintcost.py check --all --sarif out.sarif  # SARIF 2.1.0
     python tools/dintcost.py check --prune-allowlist     # drop stale
@@ -22,7 +22,7 @@ Usage:
 
 `check` runs ONLY the cost_budget pass of the dintlint suite (same
 allowlist, same exit discipline) — `tools/dintlint.py --all` includes it
-too; this entry point exists for focused runs and the hw_round scripts.
+too; this entry point exists for focused runs.
 `diff` compares two `report -o` artifacts (e.g. across a PR) and fails
 on any dispatch/footprint growth or per-wave byte growth past the
 threshold, naming the wave and target.
@@ -48,11 +48,12 @@ from dint_tpu.analysis import targets as T  # noqa: E402
 DEFAULT_ALLOWLIST = cli.DEFAULT_ALLOWLIST
 
 # bumped when keys of the --json payload change shape; bench artifacts
-# embed the report payload and the hw_round scripts archive it
+# embed the report payload
 # schema 2: per-axis link bytes (ici_bytes_per_step / dcn_bytes_per_step
 # at top level and per wave) for the 2-D mesh targets
 # schema 3: check payload carries stale_allowlist (--prune-allowlist)
-JSON_SCHEMA = 3
+# schema 4: a target's report no longer carries fused_twin
+JSON_SCHEMA = 4
 
 DEFAULT_BYTES_PCT = 10.0
 
@@ -98,8 +99,6 @@ def _entry(name: str) -> dict | None:
                                         ledger),
         "footprint": bud.get("footprint"),
     }
-    twin = cost.fused_twin(name)
-    d["fused_twin"] = twin if twin in T.TARGETS else None
     return d
 
 

@@ -1,7 +1,6 @@
 """dintplan CLI: the static configuration planner + the fifth CI gate.
 
-The knob matrix (`use_pallas`, `use_hotset`, `use_fused`,
-`hierarchical`, `overlap`, serve widths) stops being operator folklore:
+The knob matrix (`use_hotset`, `hierarchical`, `overlap`, serve widths) stops being operator folklore:
 `plan` enumerates the feasible (engine x geometry x skew x mesh)
 candidate lattice from the first-class knob registry
 (analysis/plan.KNOBS), prices every candidate through the dintcost

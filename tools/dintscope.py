@@ -95,7 +95,7 @@ def cmd_diff(args) -> int:
         print(f"A = {args.a}\nB = {args.b}")
         for dst, srcs in (d.get("aliased") or {}).items():
             print(f"aliased: {' + '.join(srcs)} -> {dst} "
-                  "(fused megakernel; --no-alias for raw scopes)")
+                  "(--no-alias for raw scopes)")
         for r in d["rows"]:
             if r.get("a_ms_per_step") is None \
                     and r.get("b_ms_per_step") is None:
@@ -170,8 +170,8 @@ def main(argv=None) -> int:
     p.add_argument("--min-ms", type=float, default=attrib.DEFAULT_MIN_MS)
     p.add_argument("--no-alias", action="store_true",
                    help="compare raw per-scope time instead of folding "
-                        "the fused megakernels' swallowed waves into "
-                        "their successor (attrib.WAVE_ALIASES)")
+                        "taken-over waves into their successor "
+                        "(attrib.WAVE_ALIASES)")
     p.add_argument("--json", action="store_true")
     p.set_defaults(fn=cmd_diff)
 
