@@ -80,7 +80,7 @@ from ._memo import memoize_builder, refuse_kernel_flags
 from ..monitor import counters as mon
 from ..monitor import txnevents as txe
 from ..monitor import waves
-from ..ops import hotset
+from ..ops import compact, hotset
 from ..tables import log as logring
 from .types import Op
 from .smallbank_pipeline import (AMT, L, MAGIC, N_SHARDS, TS_AMT_MAX, VW,     # noqa: F401 (re-exported)
@@ -429,8 +429,14 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
                 newbal.astype(U32), 1)
         else:
             hot_bal = db.hot_bal
-            bal_new = db.bal.at[wrows].set(newbal.astype(U32), mode="drop",
-                                           unique_indices=True)
+            # as many lanes as it takes for the compiler to sort the
+            # scatter's indices (ops/compact.py), the added ones dropped
+            # as the dead ones are
+            lanes = compact.sorted_scatter_lanes(db.bal.shape[0],
+                                                 wrows.shape[0])
+            bal_new = db.bal.at[compact.filled(wrows, lanes, oob)].set(
+                compact.filled(newbal.astype(U32), lanes, 0),
+                mode="drop", unique_indices=True)
 
     with waves.scope("smallbank_dense", "log_append"):
         with waves.part("smallbank_dense", "log_build"):

@@ -22,6 +22,14 @@ passed a step whose lock arbitration was gone; log2(R) rounds of shifted
 selects, which slowed the lock wave's three ops by 0.38 ms. Never a
 scatter of lane ids (the R indices being saved) nor ``jnp.nonzero(size=)``
 (a duplicate-index scatter-add: those serialize).
+
+A third way, where neither pays (PERF.md §6, PR 36): the v5e compiler sorts
+a 1-D scatter's (index, value) pairs itself, and then issues a lane at
+18-24 ns, not 90, once the scatter issues about one index per 1,630 table
+words. So a scatter whose mask is half live (``smallbank_dense``'s
+install: compaction's lane search would cost what it saves) issues
+``sorted_scatter_lanes`` lanes, the added ones out of bounds: the sort is
+the compiler's, in no jaxpr, and the proofs read what they read.
 """
 from __future__ import annotations
 
@@ -40,6 +48,35 @@ def chunk_lanes(r: int) -> int:
     a step; a trip costs its lanes and a chunk x R compare, so small
     chunks waste fewer lanes and pay more trips."""
     return min(r, max(128, r // 32))
+
+
+# The v5e compiler was seen to sort a 1-D scatter at 1,616 table words an
+# index and not at 1,674 (AOT, PR 36; tests/test_chip_compile.py pins
+# it): a margin under the first.
+SORTED_SCATTER_WORDS_PER_LANE = 1536
+
+
+def sorted_scatter_lanes(table_words: int, lanes: int) -> int:
+    """Lanes a 1-D scatter of ``lanes`` indices into ``table_words`` words
+    issues so that the compiler sorts its indices first: ``lanes`` where
+    it is that dense already; else the least multiple of 128 with at most
+    SORTED_SCATTER_WORDS_PER_LANE words a lane (48,000,001 words at 24,576
+    lanes: 31,360); and ``lanes``, no fill, where that would more than
+    double them (a 512-lane chunk into a 70 M-word table is not this
+    case). The caller routes the added lanes out of bounds."""
+    if table_words <= lanes * SORTED_SCATTER_WORDS_PER_LANE:
+        return lanes
+    dense = -(-table_words // (SORTED_SCATTER_WORDS_PER_LANE * 128)) * 128
+    return dense if dense <= 2 * lanes else lanes
+
+
+def filled(x, lanes: int, value):
+    """``x`` [R] with ``lanes - R`` lanes of ``value`` appended (none to
+    add: ``x`` itself, no equation)."""
+    fill = lanes - x.shape[0]
+    if not fill:
+        return x
+    return jnp.concatenate([x, jnp.full((fill,), value, x.dtype)])
 
 
 def live_ranks(mask):

@@ -191,17 +191,59 @@ def test_smallbank24m_block_program_fits_one_chip(one_chip):
         # the program asks for no sort; the compiler puts one of wL =
         # 24,576 (index, value) pairs before each scatter of the lock wave
         # (the two scatter-mins, whose indices repeat, and the two stamp
-        # scatters), and before no other (PERF.md section 7)
-        sorts = re.findall(r' sort\([^\n]*op_name="([^"]*)"', c.as_text())
+        # scatters), and one of 31,360 before the install's, which issues
+        # that many lanes so that it does (compact.sorted_scatter_lanes;
+        # PERF.md section 6, PR 36); before no other
+        hlo = c.as_text()
+        sorts = re.findall(r' sort\([^\n]*op_name="([^"]*)"', hlo)
         assert all(name.endswith(("part.lock_arb/scatter-min",
-                                  "part.lock_stamp/scatter"))
+                                  "part.lock_stamp/scatter",
+                                  "dint.smallbank_dense.install/scatter"))
                    for name in sorts)
+        assert sum(name.endswith("dint.smallbank_dense.install/scatter")
+                   for name in sorts) == 1
+        install, = re.findall(
+            rf"u32\[{2 * n_acc + 1}\]\S* scatter\([^\n]*", hlo)
+        assert "indices_are_sorted=true" in install
+        assert scatter_index_counts(hlo, 2 * n_acc + 1) == [31_360]
         if fn is run:
-            assert len(sorts) == 4
+            assert len(sorts) == 5
             # the compiler gathers out of the first arbitration array
             # before it fills the second: one is live, not both (ISSUE 33
             # reckoned 268 MB); the drain has no requests to arbitrate
             assert ma.temp_size_in_bytes >= 4 * (1 << 25)
+
+
+@pytest.mark.parametrize("table_words, lanes, dense", [
+    (48_000_001, 24_576, 31_360),       # smallbank24m's balances
+    (40_000_001, 16_384, 26_112),
+    (1 << 26, 24_576, 43_776),
+])
+def test_compiler_sorts_a_scatter_at_the_lanes_the_rule_gives(
+        one_chip, table_words, lanes, dense):
+    """What compact.SORTED_SCATTER_WORDS_PER_LANE rests on: the v5e
+    compiler sorts the (index, value) pairs of a 1-D scatter, and marks
+    it `indices_are_sorted`, once it issues about one index per 1,630
+    table words. At the lane count the rule gives it does, at the
+    scatter's own it does not. This case fails the day a compiler moves
+    the threshold past the rule's margin."""
+    assert compact.sorted_scatter_lanes(table_words, lanes) == dense
+
+    def compiled(r):
+        c, _ = compiled_bytes(
+            jax.jit(lambda t, i, v: t.at[i].set(v, mode="drop",
+                                                unique_indices=True),
+                    donate_argnums=0),
+            *placed((_s((table_words,)), _s((r,), I32), _s((r,))),
+                    one_chip))
+        return c.as_text()
+
+    hlo = compiled(dense)
+    assert " sort(" in hlo
+    assert "indices_are_sorted=true" in hlo
+    hlo = compiled(lanes)
+    assert " sort(" not in hlo
+    assert "indices_are_sorted=true" not in hlo
 
 
 def test_windowed_row_scatter_is_expanded_to_a_loop_on_v5e(one_chip):
