@@ -333,10 +333,9 @@ _DENSE = ("tatp_dense", "smallbank_dense")    # the engine-neutral parts
 # its own step (`monitor`, `stats`, `block_pre`); the wave is
 # the one it lies under in that owner's step, None for what a step does
 # outside every wave. append_rep's parts also run under
-# `dense_sharded.replicate`, where a backup appends; that wave's own
-# parts come with the cell that reads them. The innermost part on an
-# op's name stack is the one its time is booked to
-# (benchmarks/part_times.py).
+# `dense_sharded.replicate`, where a backup appends, inside that wave's
+# own `bck_log_append`. The innermost part on an op's name stack is the
+# one its time is booked to (benchmarks/part_times.py).
 _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
     # --- dense TATP (engines/tatp_dense.py) -----------------------------
     ("tatp_dense", "install", "install_build",
@@ -417,6 +416,23 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
      "the wave's two chunk loops, each chunk's lane search (C x 2w "
      "compares) and gather of row ids, the held count, and the winners' "
      "way back to lane space (C x 2w compares, OR-ed over the chunk)"),
+    # --- replication over the mesh (parallel/dense_sharded.py), appended
+    # --- with the cell tatp7m-x4-sat (PR 37): every equation under
+    # --- `replicate` carries one of these four -------------------------
+    ("dense_sharded", "replicate", "repl_hop",
+     "one hop's ppermutes of the 8-leaf install record to device d + off, "
+     "the count of what arrived (repl_push_hop<off>) and the sender's "
+     "index"),
+    ("dense_sharded", "replicate", "bck_meta_scatter",
+     "the backup slot's row ids and the unique-index scatter of all 2w "
+     "lanes' meta words into it (masked lanes out of bounds)"),
+    ("dense_sharded", "replicate", "bck_val_scatter",
+     "the flat index and the unique-index scatter of all 2w x VW single "
+     "value words into the backup slot (bck_val_scatter_ms.* reads "
+     "this)"),
+    ("dense_sharded", "replicate", "bck_log_append",
+     "the forwarded stream's tag and append_rep of all 2w lanes into "
+     "this device's ring (its log_plan and log_scatter lie inside)"),
 )
 
 # keyed on the part's name alone: the scope is `part.<name>`, so two

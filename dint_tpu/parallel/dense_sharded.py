@@ -130,20 +130,25 @@ def _apply_backup(state: ShardState, inst: td.Installs, slot: int,
     (recovery.recover_tatp_dense with key_hi_filter)."""
     base = slot * n1
     oob = N_BCK * n1
-    rows = jnp.where(inst.wmask, base + inst.rows, oob)
-    meta = state.bck_meta.at[rows].set(inst.meta, mode="drop",
-                                       unique_indices=True)
-    # masked lanes ride the oob row: oob*val_words is already past the end
-    flat = (rows[:, None] * val_words
-            + jnp.arange(val_words, dtype=I32)).reshape(-1)
-    val = state.bck_val.at[flat].set(inst.val.reshape(-1), mode="drop",
-                                     unique_indices=True)
-    # 1-based so "own entry" (key_hi == 0, written by pipe_step's local
-    # append) can never collide with "forwarded from device 0"
-    src = jnp.broadcast_to(src_dev.astype(U32) + U32(1), inst.key.shape)
-    log = logring.append_rep(state.db.log, inst.wmask, inst.tbl,
-                             inst.is_del, src, inst.key, inst.ver,
-                             inst.val)
+    with waves.part("dense_sharded", "bck_meta_scatter"):
+        rows = jnp.where(inst.wmask, base + inst.rows, oob)
+        meta = state.bck_meta.at[rows].set(inst.meta, mode="drop",
+                                           unique_indices=True)
+    with waves.part("dense_sharded", "bck_val_scatter"):
+        # masked lanes ride the oob row: oob*val_words is already past
+        # the end
+        flat = (rows[:, None] * val_words
+                + jnp.arange(val_words, dtype=I32)).reshape(-1)
+        val = state.bck_val.at[flat].set(inst.val.reshape(-1), mode="drop",
+                                         unique_indices=True)
+    with waves.part("dense_sharded", "bck_log_append"):
+        # 1-based so "own entry" (key_hi == 0, written by pipe_step's
+        # local append) can never collide with "forwarded from device 0"
+        src = jnp.broadcast_to(src_dev.astype(U32) + U32(1),
+                               inst.key.shape)
+        log = logring.append_rep(state.db.log, inst.wmask, inst.tbl,
+                                 inst.is_del, src, inst.key, inst.ver,
+                                 inst.val)
     return state.replace(bck_val=val, bck_meta=meta,
                          db=state.db.replace(log=log))
 
@@ -197,18 +202,19 @@ def build_sharded_pipelined_runner(mesh: Mesh, n_shards: int,
         # hop's payload is dropped on the floor.
         with waves.scope("dense_sharded", "replicate"):
             for off in (1, 2):
-                fwd = jax.tree.map(functools.partial(
-                    jax.lax.ppermute, axis_name=SHARD_AXIS,
-                    perm=ring_perm(n_shards, off)), inst)
-                if cnt is not None:
-                    # replication pushes, counted where they are APPLIED
-                    # (the receiving backup — the reference's CommitBck
-                    # handler)
-                    hop = (mon.CTR_REPL_PUSH_HOP1 if off == 1
-                           else mon.CTR_REPL_PUSH_HOP2)
-                    cnt = mon.bump(cnt,
-                                   {hop: fwd.wmask.sum(dtype=jnp.int32)})
-                src_dev = (dev - off) % n_shards
+                with waves.part("dense_sharded", "repl_hop"):
+                    fwd = jax.tree.map(functools.partial(
+                        jax.lax.ppermute, axis_name=SHARD_AXIS,
+                        perm=ring_perm(n_shards, off)), inst)
+                    if cnt is not None:
+                        # replication pushes, counted where they are
+                        # APPLIED (the receiving backup — the reference's
+                        # CommitBck handler)
+                        hop = (mon.CTR_REPL_PUSH_HOP1 if off == 1
+                               else mon.CTR_REPL_PUSH_HOP2)
+                        cnt = mon.bump(
+                            cnt, {hop: fwd.wmask.sum(dtype=jnp.int32)})
+                    src_dev = (dev - off) % n_shards
                 state = _apply_backup(state, fwd, off - 1, n1, val_words,
                                       src_dev)
         return state, new_ctx, c1, jax.lax.psum(stats, SHARD_AXIS), cnt

@@ -299,7 +299,30 @@ def test_dense_sharded_block_program_on_four_chips(topo):
     assert abs(ma.output_size_in_bytes - total / n) < 0.01 * total / n
     assert ma.output_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
 
-    c, ma = compiled_bytes(jax.jit(run, donate_argnums=0), carry, key)
-    assert abs(ma.argument_size_in_bytes - total / n) < 0.01 * total / n
-    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
-    assert "collective-permute" in c.as_text()
+    n1 = td.n_rows(ds.n_sub_local(N_SUB, n)) + 1
+    # (program, ceiling on its temporaries): 7.794 GB the block and 8.867
+    # the drain (AOT, PR 37) beside 5.296 GB of donated state. Not under
+    # 1e9 as on one chip: `block_local` squeezes [1, N] leaves and the
+    # backup's full-width scatters copy; carrying the shards as [N] is
+    # ROADMAP Queue 2A item 2(a). The ceilings hold what fits today.
+    for fn, args, ceiling in (
+            (jax.jit(run, donate_argnums=0), (carry, key), 8.0e9),
+            (jax.jit(drain, donate_argnums=0), (carry,), 9.0e9)):
+        c, ma = compiled_bytes(fn, *args)
+        assert abs(ma.argument_size_in_bytes - total / n) < 0.01 * total / n
+        assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+        # the carry is donated: primaries, backups and rings update in
+        # place
+        assert ma.alias_size_in_bytes > 0.99 * ma.argument_size_in_bytes
+        assert ma.temp_size_in_bytes < ceiling
+        hlo = c.as_text()
+        assert "collective-permute" in hlo
+        # each hop's backup install issues ALL 2w lanes (2w x VW value
+        # words, 2w meta words), masked ones out of bounds: the number a
+        # compaction of `_apply_backup` changes (the primary's own install
+        # issues a chunk, as on one chip)
+        assert set(scatter_index_counts(hlo, ds.N_BCK * n1 * VW)) \
+            == {2 * W * VW}
+        assert set(scatter_index_counts(hlo, ds.N_BCK * n1)) == {2 * W}
+        assert set(scatter_index_counts(hlo, n1 * VW)) \
+            == {compact.chunk_lanes(2 * W) * VW}

@@ -1,6 +1,7 @@
 """Multi-chip dense TATP: device-local txns + ppermute'd replication."""
 import jax
 import numpy as np
+import pytest
 
 from dint_tpu.engines import tatp_dense as td
 from dint_tpu.parallel import dense_sharded as ds
@@ -156,3 +157,144 @@ def test_uneven_partition_rounds_up():
                 + int(total[td.STAT_AB_MISSING])
                 + int(total[td.STAT_AB_VALIDATE]))
     assert outcomes == int(total[td.STAT_ATTEMPTED])
+
+
+# ------------------------- the replicated program against a plain reference
+
+
+def _lane_stream(ring, heads, tag):
+    """One stream of an unwrapped ring [L, CAP, EW], lane by lane, oldest
+    first."""
+    return [ring[lane, :int(heads[lane])][ring[lane, :int(heads[lane]), 1]
+                                          == tag]
+            for lane in range(ring.shape[0])]
+
+
+def _acked(ring, heads, tag):
+    """The stream in an order that is the order of acknowledgement for
+    every row: stably by version (a row's versions rise with time)."""
+    e = np.concatenate(_lane_stream(ring, heads, tag))
+    return e[np.argsort(e[:, 3], kind="stable")]
+
+
+@pytest.mark.parametrize("seed", [0, 11, 2**31 + 5])
+def test_every_replica_equals_the_sequential_reference(seed):
+    """Four devices: each primary, each backup slot (row for row over the
+    whole table) and each of the three rings that carry a device's stream
+    (entry for entry, in order) equal what dint_tpu/testing/
+    replication.py makes of the primary's acknowledged installs."""
+    from dint_tpu.testing import replication as ref
+
+    d, n_glob, w = 4, 4 * 300, 64
+    mesh = ds.make_mesh(d)
+    state = ds.create_sharded(mesh, d, n_glob, val_words=VW,
+                              seed=seed % (1 << 31), log_capacity=1 << 10)
+    fresh = jax.tree.map(np.array, state)
+    run, init, drain = ds.build_sharded_pipelined_runner(
+        mesh, d, n_glob, w=w, val_words=VW, cohorts_per_block=2)
+    carry = init(state)
+    for i in range(3):
+        carry, _ = run(carry, jax.random.fold_in(jax.random.PRNGKey(seed), i))
+    live = jax.tree.map(np.asarray, drain(carry)[0])
+
+    n_loc = ds.n_sub_local(n_glob, d)
+    n1 = td.n_rows(n_loc) + 1
+    p1 = n_loc + 1
+    table_rows = (p1, p1, 4 * p1, 4 * p1, 12 * p1)
+    lanes = state.db.log.lanes
+    rings = live.db.log.entries.reshape(d, lanes, -1, 4 + VW)
+    heads = live.db.log.head
+    assert (heads <= rings.shape[2]).all() and heads.sum() > 0
+    where = ref.placement(d)
+    for dev in range(d):
+        acked = _acked(rings[dev], heads[dev], 0)
+        assert len(acked) > 20
+        stream = [(int(e[0] >> 8), int(e[2]), int(e[0] & 0xFF), int(e[3]),
+                   e[4:]) for e in acked]
+        for ring, tag in where[dev]["streams"]:
+            meta, val, entries = ref.replay(
+                fresh.db.meta[dev], fresh.db.val[dev].reshape(n1, VW),
+                table_rows, stream, tag)
+            np.testing.assert_array_equal(
+                _acked(rings[ring], heads[ring], tag), entries)
+            # and lane by lane in the primary's own order
+            for a, b in zip(_lane_stream(rings[ring], heads[ring], tag),
+                            _lane_stream(rings[dev], heads[dev], 0)):
+                np.testing.assert_array_equal(np.delete(a, 1, 1),
+                                              np.delete(b, 1, 1))
+        np.testing.assert_array_equal(live.db.meta[dev], meta)
+        np.testing.assert_array_equal(live.db.val[dev], val.reshape(-1))
+        for holder, slot in where[dev]["backups"]:
+            np.testing.assert_array_equal(
+                live.bck_meta[holder, slot * n1:(slot + 1) * n1], meta)
+            np.testing.assert_array_equal(
+                live.bck_val[holder, slot * n1 * VW:(slot + 1) * n1 * VW],
+                val.reshape(-1))
+        # a ring holds nothing but the three streams it carries
+        tags = {0} | {t for _, t in ref.carried(d, dev)}
+        written = np.concatenate([rings[dev, lane, :int(heads[dev, lane])]
+                                  for lane in range(lanes)])
+        assert set(np.unique(written[:, 1]).tolist()) == tags
+
+
+_CACHE_CHILD = '''
+import collections, json, sys
+import jax, numpy as np
+sys.path.insert(0, sys.argv[1])
+from dint_tpu import _runtime
+from dint_tpu.parallel import dense_sharded as ds
+_runtime.enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+events = collections.Counter()
+jax.monitoring.register_event_listener(
+    lambda name, **kw: events.update((name,)))
+mesh = ds.make_mesh(4)
+state = ds.create_sharded(mesh, 4, 4 * 512, val_words=4, seed=0)
+run, init, drain = ds.build_sharded_pipelined_runner(
+    mesh, 4, 4 * 512, w=16, val_words=4, cohorts_per_block=2, monitor=True)
+carry, stats = init(state), []
+for i in range(3):
+    carry, s = run(carry, jax.random.PRNGKey(i))
+    stats.append(np.asarray(s).tolist())
+state, tail, counters = drain(carry)
+print(json.dumps({
+    "stats": stats + [np.asarray(tail).tolist()],
+    "counters": np.asarray(counters.buf).tolist(),
+    "heads": np.asarray(state.db.log.head).tolist(),
+    "meta": int(np.asarray(state.db.meta, np.int64).sum()),
+    "bck_meta": int(np.asarray(state.bck_meta, np.int64).sum()),
+    "hits": events["/jax/compilation_cache/cache_hits"],
+    "misses": events["/jax/compilation_cache/cache_misses"]}))
+'''
+
+
+def test_a_second_process_loads_the_sharded_programs_from_the_cache(
+        tmp_path):
+    """Two processes in a row, one compile cache directory: the second
+    compiles nothing (the sharded, donated block and drain among what it
+    loads) and gives the first's stats, counters and tables bit for bit.
+    The first suspect for the four-chip cell's old exit 1 (PERF.md, PR
+    37), and what the benchmark's warm runs rest on."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env.update(JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(
+        tmp_path / "cache"),
+        XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    lines = []
+    for _ in range(2):
+        c = subprocess.run([sys.executable, "-c", _CACHE_CHILD, repo],
+                           env=env, capture_output=True, text=True,
+                           timeout=600)
+        assert c.returncode == 0, c.stderr[-2000:]
+        lines.append(json.loads(c.stdout.strip().splitlines()[-1]))
+    first, second = lines
+    assert first["hits"] == 0 and first["misses"] >= 3   # populate, block, drain
+    assert second["misses"] == 0 and second["hits"] == first["misses"]
+    for k in ("stats", "counters", "heads", "meta", "bck_meta"):
+        assert first[k] == second[k], k
+    assert sum(first["heads"][0]) > 0

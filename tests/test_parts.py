@@ -123,9 +123,8 @@ def test_every_smallbank_part_reaches_compiled_hlo_under_its_wave():
 
 def test_the_sharded_block_carries_the_dense_steps_parts():
     """The four-device block runs the same `pipe_step`, so its trace
-    splits the same way; `replicate` has no parts of its own until a
-    cell reads them, and `append_rep`'s ride under it where a backup
-    appends."""
+    splits the same way; `replicate` has four parts of its own (below),
+    and `append_rep`'s ride under it where a backup appends."""
     d = 4
     mesh = ds.make_mesh(d)
     state = ds.create_sharded(mesh, d, 4 * 512, val_words=4, seed=0)
@@ -260,3 +259,95 @@ def test_compile_cache_key_holds_the_names_and_no_call_stack(monkeypatch):
         update(limit, before)
     assert _lower_there(_scoped("part.one"), x) \
         != _lower_here(_scoped("part.one"), x)       # the default: frames
+
+
+# ------------------------------------------- the parts under `replicate`
+
+REPLICATE = "dint.dense_sharded.replicate"
+REPL_PARTS = ("repl_hop", "bck_meta_scatter", "bck_val_scatter",
+              "bck_log_append")
+
+
+def _sharded(d=4, monitor=True):
+    mesh = ds.make_mesh(d)
+    state = ds.create_sharded(mesh, d, 4 * 512, val_words=4, seed=0)
+    run, init, drain = ds.build_sharded_pipelined_runner(
+        mesh, d, 4 * 512, w=16, val_words=4, cohorts_per_block=2,
+        monitor=monitor)
+    return run, init(state), drain
+
+
+def test_the_four_replicate_parts_are_registered_under_their_wave():
+    rows = {p: (o, w) for o, w, p, _ in waves._PARTS}
+    for part in REPL_PARTS:
+        assert rows[part] == ("dense_sharded", "replicate")
+        assert waves.PART_OWNERS[part] == ("dense_sharded",)
+    with pytest.raises(KeyError, match="part registry"):
+        waves.part("tatp_dense", "repl_hop")
+
+
+def _equations_under(jaxpr, wave: str, stack: str, out: list) -> list:
+    """(primitive, full name stack) of every leaf equation whose stack,
+    its holders' included, carries ``wave``."""
+    for eqn in jaxpr.eqns:
+        here = f"{stack}/{eqn.source_info.name_stack}"
+        inner = [j for v in eqn.params.values()
+                 for x in (v if isinstance(v, (list, tuple)) else (v,))
+                 for j in (getattr(x, "jaxpr", x),)
+                 if hasattr(j, "eqns")]
+        for j in inner:
+            _equations_under(j, wave, here, out)
+        if not inner and wave in here:
+            out.append((eqn.primitive.name, here))
+    return out
+
+
+@pytest.mark.parametrize("monitor", [True, False])
+def test_every_equation_under_replicate_carries_one_of_the_four_parts(
+        monitor):
+    run, carry, _ = _sharded(monitor=monitor)
+    closed = jax.make_jaxpr(run)(carry, jax.random.PRNGKey(0))
+    under = _equations_under(closed.jaxpr, REPLICATE, "", [])
+    assert len(under) > 40              # two hops of 8 leaves, two applies
+    seen = set()
+    for prim, stack in under:
+        after = stack[stack.index(REPLICATE):].split("/")
+        mine = [p for p in REPL_PARTS if waves.part_name(p) in after]
+        assert len(mine) == 1, (prim, stack)
+        seen.add(mine[0])
+        # the collectives are the hop's, the scatters the backup's
+        if prim == "ppermute":
+            assert mine == ["repl_hop"]
+    assert seen == set(REPL_PARTS)
+    assert sum(p == "ppermute" for p, _ in under) == 2 * 8
+
+
+def test_the_replicate_parts_reach_compiled_hlo_under_their_wave():
+    run, carry, _ = _sharded()
+    names = _op_names(jax.jit(run).lower(
+        carry, jax.random.PRNGKey(0)).compile().as_text())
+    _assert_parts_under_their_waves(names, ("dense_sharded",))
+    # append_rep's own parts lie inside the backup's append
+    assert any(re.search(rf"{re.escape(REPLICATE)}/.*part\.bck_log_append/"
+                         r".*part\.log_scatter", n) for n in names)
+
+
+def test_replicate_parts_are_semantics_neutral(monkeypatch):
+    def run_once():
+        run, carry, drain = _sharded()
+        carry, stats = run(carry, jax.random.PRNGKey(3))
+        state, tail, counters = drain(carry)
+        return [np.asarray(x) for x in (
+            stats, tail, counters.buf, state.db.val, state.db.meta,
+            state.bck_val, state.bck_meta, state.db.log.entries,
+            state.db.log.head)]
+
+    a = run_once()
+    ds.build_sharded_pipelined_runner.cache.clear()     # not in its key
+    monkeypatch.setattr(waves, "part",
+                        lambda owner, name: contextlib.nullcontext())
+    b = run_once()
+    ds.build_sharded_pipelined_runner.cache.clear()
+    td.build_pipelined_runner.cache.clear()
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(x, y)
