@@ -865,7 +865,7 @@ _MH_EXPECT = {"dint.tatp_dense.log_append": "2*w*(20 + 4*vw)"}
 # Counters leaf by 12 B per device (3 x u32), +12 B single-chip, +12*d
 # on the sharded/mesh targets — a fleet-wide recalibration, not a leak.
 # PR 30's install_chunks row: +4 B per device in the same way; PR 34's
-# lock_chunks row: +4 B per device again.
+# lock_chunks row and PR 38's bck_chunks row: +4 B per device again.
 def _cost(geom, dispatches, footprint, *, steps=float(_BLK),
           bytes_budget="1.25*ledger", wave_expect=None):
     return dict(steps=float(steps), geom=dict(geom),
@@ -883,7 +883,7 @@ TARGET_COST.update({
     # of a chunk loop is priced, at a geometry where a chunk is all 2w
     # slots: the scatters' bytes are the parent's
     "tatp_dense/block": _cost(_TD_GEOM, 12, 216844),
-    "tatp_dense/block@mon": _cost(_TD_GEOM, 14, 217000),
+    "tatp_dense/block@mon": _cost(_TD_GEOM, 14, 217004),
     "tatp_dense/drain": _cost(_TD_GEOM, 12, 216836),
     "tatp_dense/block@hot": _cost(_TD_GEOM, 14, 216864,
                                   wave_expect=_HOT2_TD),
@@ -891,39 +891,42 @@ TARGET_COST.update({
     # closed-loop rows above (the occupancy mask fuses into the gen
     # wave), footprint +16 B (@mon +28 B) for the occ/shed step inputs
     "tatp_dense/serve": _cost(_TD_GEOM, 12, 216860),
-    "tatp_dense/serve@mon": _cost(_TD_GEOM, 14, 217016),
+    "tatp_dense/serve@mon": _cost(_TD_GEOM, 14, 217020),
     # dense SmallBank
     "smallbank_dense/block": _cost(_SB_GEOM, 8, 150984),
-    "smallbank_dense/block@mon": _cost(_SB_GEOM, 10, 151140),
+    "smallbank_dense/block@mon": _cost(_SB_GEOM, 10, 151144),
     "smallbank_dense/block@hot": _cost(_SB_GEOM, 14, 151032,
                                        wave_expect=_HOT2_SB),
-    "smallbank_dense/block@hot+mon": _cost(_SB_GEOM, 16, 151188,
+    "smallbank_dense/block@hot+mon": _cost(_SB_GEOM, 16, 151192,
                                            wave_expect=_HOT2_SB),
     "smallbank_dense/serve": _cost(_SB_GEOM, 8, 151000),
-    "smallbank_dense/serve@mon": _cost(_SB_GEOM, 10, 151156),
+    "smallbank_dense/serve@mon": _cost(_SB_GEOM, 10, 151160),
     # generic pipelines: sort-bound, no formula-backed waves -> absolute
     # bytes ceilings instead of a ledger multiple
     "tatp_pipeline/block": _cost(_TD_GEOM, 50, 1610736022,
                                  bytes_budget=256000),
-    "tatp_pipeline/block@mon": _cost(_TD_GEOM, 51, 1610736178,
+    "tatp_pipeline/block@mon": _cost(_TD_GEOM, 51, 1610736182,
                                      bytes_budget=256000),
     "smallbank_pipeline/block": _cost(_SB_GEOM, 36, 1207967480,
                                       bytes_budget=72000),
-    "smallbank_pipeline/block@mon": _cost(_SB_GEOM, 37, 1207967636,
+    "smallbank_pipeline/block@mon": _cost(_SB_GEOM, 37, 1207967640,
                                           bytes_budget=72000),
     # generic replicated shard step: one engine step per trace
     "sharded/tatp": _cost(_DS_GEOM, 62, 4295279296, steps=1.0,
                           bytes_budget=12000),
     "sharded/smallbank": _cost(_DSB_GEOM, 30, 3221242768, steps=1.0,
                                bytes_budget=4000),
-    # dense multi-chip TATP
-    "dense_sharded/block": _cost(_DS_GEOM, 36, 459240,
+    # dense multi-chip TATP. PR 38's compacted backup apply adds 3 a hop
+    # (6 a step; the same 6 in multihost/block below): a chunk's gathers
+    # of row ids, meta words and ring slots out of the forwarded 2w-wide
+    # record, priced as one trip at a geometry where a chunk is all 2w
+    "dense_sharded/block": _cost(_DS_GEOM, 42, 459240,
                                  wave_expect=_DS_EXPECT),
-    "dense_sharded/block@mon": _cost(_DS_GEOM, 40, 459864,
+    "dense_sharded/block@mon": _cost(_DS_GEOM, 46, 459880,
                                      wave_expect=_DS_EXPECT),
     # dense multi-chip SmallBank
     "dense_sharded_sb/block": _cost(_DSB_GEOM, 33, 100676560),
-    "dense_sharded_sb/block@mon": _cost(_DSB_GEOM, 37, 100677184),
+    "dense_sharded_sb/block@mon": _cost(_DSB_GEOM, 37, 100677200),
     "dense_sharded_sb/block@hot": _cost(_DSB_GEOM, 39, 100676848,
                                         wave_expect=_DSB_HOT),
     # 2-D (dcn x ici) SmallBank: the hierarchical route pays +9
@@ -934,7 +937,7 @@ TARGET_COST.update({
     "multihost_sb/block": _cost(_MHSB_GEOM, 42, 201353056),
     "multihost_sb/block@flat": _cost(_MHSB_GEOM, 33, 201353056,
                                      wave_expect=_MHSB_FLAT),
-    "multihost_sb/block@mon": _cost(_MHSB_GEOM, 46, 201354304),
+    "multihost_sb/block@mon": _cost(_MHSB_GEOM, 46, 201354336),
     "multihost_sb/block@h3": _cost(_MHSB_GEOM_H3, 42, 151014808),
     "multihost_sb/block@h3+flat": _cost(_MHSB_GEOM_H3, 33, 151014808,
                                         wave_expect=_MHSB_FLAT),
@@ -947,13 +950,13 @@ TARGET_COST.update({
     "multihost_sb/serve": _cost(_MHSB_GEOM, 42, 201353184),
     "multihost_sb/serve@flat": _cost(_MHSB_GEOM, 33, 201353184,
                                      wave_expect=_MHSB_FLAT),
-    "multihost_sb/serve@mon": _cost(_MHSB_GEOM, 47, 201354432),
+    "multihost_sb/serve@mon": _cost(_MHSB_GEOM, 47, 201354464),
     "multihost_sb/serve@overlap": _cost(_MHSB_GEOM, 44, 201359424),
-    "multihost_sb/serve@overlap+mon": _cost(_MHSB_GEOM, 50, 201360672),
+    "multihost_sb/serve@overlap+mon": _cost(_MHSB_GEOM, 50, 201360704),
     # 2-D TATP (parallel/multihost.py, flat tuple-axis collectives):
     # replication traffic pre-dates wave scoping -> absolute bytes
     # ceiling like the pipeline targets, not a ledger multiple
-    "multihost/block": _cost(dict(w=_W, k=4, vw=_VW, d=8, h=4), 36,
+    "multihost/block": _cost(dict(w=_W, k=4, vw=_VW, d=8, h=4), 42,
                              918424, bytes_budget=11000,
                              wave_expect=_MH_EXPECT),
     # dinttrace flight-recorder variants: the ring scatter-add adds one
@@ -1086,7 +1089,7 @@ TARGET_COST.update({
     "store/block": _cost(_ST_GEOM, 15, 2008, bytes_budget=2200),
     "store/block@scan": _cost(_ST_GEOM, 35.5, 4077, bytes_budget=11700),
     "store/serve@scan": _cost(_ST_GEOM, 35.5, 4093, bytes_budget=11700),
-    "store/serve@scan+mon": _cost(_ST_GEOM, 36.5, 4249,
+    "store/serve@scan+mon": _cost(_ST_GEOM, 36.5, 4253,
                                   bytes_budget=11750),
     "store/rebuild@scan": _cost(_ST_GEOM, 5, 6122, steps=1.0,
                                 bytes_budget=1950),

@@ -523,9 +523,10 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                         meta = meta.at[rows_c].set(meta_c, mode="drop",
                                                    unique_indices=True)
                     # interleaved-1-D install: row r's words live at
-                    # [r*VW, (r+1)*VW); the masked-lane oob row lands at
-                    # n1*VW >= len and drops (same discipline as
-                    # parallel/dense_sharded._apply_backup)
+                    # [r*VW, (r+1)*VW); a position past the live count
+                    # rides the oob row, which lands at n1*VW >= len and
+                    # drops (the same chunked discipline as the backups'
+                    # install, parallel/dense_sharded._apply_backup)
                     with waves.part("tatp_dense", "val_scatter"):
                         wflat = (rows_c[:, None] * val_words
                                  + jnp.arange(val_words, dtype=I32)

@@ -167,6 +167,16 @@ _REGISTRY: tuple[tuple[str, str, str], ...] = (
      "ceil(lock_requests / C); lock_requests / (C x lock_chunks) is the "
      "fill share of the lanes the stamp gather, the scatter-max and the "
      "winner read-back issue"),
+    ("bck_chunks", FLOW,
+     "backup-apply compaction (ops/compact.py): chunk trips of the "
+     "install loop of parallel/dense_sharded._apply_backup, both hops, "
+     "counted at the receiving device, C = chunk_lanes(2w) lanes a trip "
+     "(the forwarded append makes as many). bck_chunks == sum over steps "
+     "and hops of ceil(the hop's live lanes / C); (repl_push_hop1 + "
+     "repl_push_hop2) / (C x bck_chunks) is the fill share of the lanes "
+     "the backups' scatters issue. Summed over the mesh it is 2 x "
+     "install_chunks (a receiver makes its sender's trips). 0 off the "
+     "mesh"),
 )
 
 ALL_NAMES: tuple[str, ...] = tuple(n for n, _, _ in _REGISTRY)
@@ -216,6 +226,7 @@ CTR_SCAN_ROWS = COUNTER_INDEX["scan_rows"]
 CTR_SCAN_DELTA_HITS = COUNTER_INDEX["scan_delta_hits"]
 CTR_INSTALL_CHUNKS = COUNTER_INDEX["install_chunks"]
 CTR_LOCK_CHUNKS = COUNTER_INDEX["lock_chunks"]
+CTR_BCK_CHUNKS = COUNTER_INDEX["bck_chunks"]
 
 # the subset defined with IDENTICAL semantics by the dense engines and
 # the generic sort-based pipelines: on the parity workloads

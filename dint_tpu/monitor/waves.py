@@ -128,7 +128,8 @@ _REGISTRY: tuple[tuple[str, str, str, str | None], ...] = (
     ("dense_sharded", "replicate",
      "CommitBck x2 + CommitLog fan-out: ppermute the install record to "
      "devices +1/+2 and apply to backup tables + local logs (2 hops x "
-     "2w records of meta+val plus a log append each)",
+     "2w records of meta+val plus a log append each; the receiver issues "
+     "the live ones, C lanes a chunk, priced at all 2w)",
      "2*(2*w*(4 + 4*vw) + 2*w*(20 + 4*vw))"),
     # --- multi-chip dense SmallBank (parallel/dense_sharded_sb.py) -----
     ("dense_sharded_sb", "gen",
@@ -332,7 +333,7 @@ _DENSE = ("tatp_dense", "smallbank_dense")    # the engine-neutral parts
 # engines where the part is engine-neutral and each of them opens it in
 # its own step (`monitor`, `stats`, `block_pre`); the wave is
 # the one it lies under in that owner's step, None for what a step does
-# outside every wave. append_rep's parts also run under
+# outside every wave. append_rep_live's parts also run under
 # `dense_sharded.replicate`, where a backup appends, inside that wave's
 # own `bck_log_append`. The innermost part on an op's name stack is the
 # one its time is booked to (benchmarks/part_times.py).
@@ -418,21 +419,29 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
      "way back to lane space (C x 2w compares, OR-ed over the chunk)"),
     # --- replication over the mesh (parallel/dense_sharded.py), appended
     # --- with the cell tatp7m-x4-sat (PR 37): every equation under
-    # --- `replicate` carries one of these four -------------------------
+    # --- `replicate` carries one of these (bck_compact: PR 38) ----------
     ("dense_sharded", "replicate", "repl_hop",
      "one hop's ppermutes of the 8-leaf install record to device d + off, "
-     "the count of what arrived (repl_push_hop<off>) and the sender's "
-     "index"),
+     "the sender's index, and the counts of what arrived "
+     "(repl_push_hop<off>) and of the chunks its install took "
+     "(bck_chunks)"),
     ("dense_sharded", "replicate", "bck_meta_scatter",
-     "the backup slot's row ids and the unique-index scatter of all 2w "
-     "lanes' meta words into it (masked lanes out of bounds)"),
+     "the backup slot's row ids and the unique-index scatter of a chunk "
+     "of the live lanes' meta words into it, C lanes a chunk"),
     ("dense_sharded", "replicate", "bck_val_scatter",
-     "the flat index and the unique-index scatter of all 2w x VW single "
-     "value words into the backup slot (bck_val_scatter_ms.* reads "
-     "this)"),
+     "the flat index and the unique-index scatter of a chunk of the live "
+     "lanes' C x VW single value words into the backup slot "
+     "(bck_val_scatter_ms.* reads this)"),
     ("dense_sharded", "replicate", "bck_log_append",
-     "the forwarded stream's tag and append_rep of all 2w lanes into "
-     "this device's ring (its log_plan and log_scatter lie inside)"),
+     "the forwarded stream's tag and append_rep_live of a chunk of the "
+     "live lanes at a time into this device's ring (its log_plan, at "
+     "full width, and log_scatter lie inside)"),
+    ("dense_sharded", "replicate", "bck_compact",
+     "the receiver's running count of the forwarded record's live lanes "
+     "(one 2w-element cumsum a hop, shared by the install and the "
+     "append), the install's chunk loop, each chunk's lane search (C x "
+     "2w compares) and its gathers of row ids, meta words and value rows "
+     "out of the 2w-wide record"),
 )
 
 # keyed on the part's name alone: the scope is `part.<name>`, so two

@@ -8,13 +8,17 @@ scatter: each lane's turn among the live ones (``live_ranks``: one cumsum),
 then the scatter runs over fixed chunks of the live lanes, as many as the
 live count needs (``for_chunks``: a ``while_loop`` whose trip count the
 step itself observes: 0 trips when nothing is live, the whole width when
-everything is). All of it vector work. Two users: the install and the log
-append of dense TATP under the write mask (PR 30; ``tables/log.
-append_rep_live``), and its lock wave under the mask of active write
-slots (PR 34: ~11 % of the 2w; the stamp gather and the winner read-back
-are chunked with the scatter-max, since a gather lane on the sentinel row
+everything is). All of it vector work. Three users: the install and the
+log append of dense TATP under the write mask (PR 30; ``tables/log.
+append_rep_live``), its lock wave under the mask of active write slots
+(PR 34: ~11 % of the 2w; the stamp gather and the winner read-back are
+chunked with the scatter-max, since a gather lane on the sentinel row
 costs what a live one costs, and a chunk's verdicts go back to lane space
-through ``lanes_mask``). Tried on the chip and left
+through ``lanes_mask``), and the backups' apply of ``parallel/
+dense_sharded`` under the forwarded record's write mask (PR 38: the
+RECEIVER ranks the 2w lanes that arrive, once a hop, for the install into
+its backup slot and the append into its ring; the record itself crosses
+the mesh at full width). Tried on the chip and left
 (PERF.md §6, PR 30): one sort of the lane ids, 0.11 ms a step faster in
 ``tatp7m-sat``, but the protocol proofs read a sort as the generic
 engines' segment evidence (analysis/dataflow.py SORTED) and would have

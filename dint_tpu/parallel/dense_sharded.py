@@ -48,6 +48,7 @@ from ..engines import tatp_dense as td
 from ..engines._memo import memoize_builder, refuse_kernel_flags
 from ..monitor import counters as mon
 from ..monitor import waves
+from ..ops import compact
 from ..tables import log as logring
 from .sharded import (SHARD_AXIS, make_mesh, pcast_varying,   # noqa: F401 (re-exported)
                       stack_on_mesh)
@@ -121,34 +122,56 @@ def create_sharded(mesh: Mesh, n_shards: int, n_sub_global: int,
 
 
 def _apply_backup(state: ShardState, inst: td.Installs, slot: int,
-                  n1: int, val_words: int, src_dev):
+                  n1: int, val_words: int, src_dev,
+                  chunks: list | None = None):
     """Install a forwarded record into backup copy `slot` + log it locally
     (the backup server's COMMIT_BCK + COMMIT_LOG handling,
     tatp/ebpf/shard_kern.c:659-939). Entries log key_hi = the SOURCE
     device: rows are source-local ids, and a log that mixes 3 devices'
     entries must stay separable for cross-device recovery
-    (recovery.recover_tatp_dense with key_hi_filter)."""
+    (recovery.recover_tatp_dense with key_hi_filter).
+
+    The record arrives at full width (2w lanes, ~9 % of them live under
+    TATP's mix) and a dropped scatter lane costs what a live one costs,
+    so the RECEIVER ranks its lanes once a hop (ops/compact.py) and the
+    install and the append issue the live ones, C = chunk_lanes(2w) lanes
+    a chunk, as the primary does with its own (tatp_dense.pipe_step).
+    ``chunks``: a list that gets the install loop's trip count (the
+    counter plane's bck_chunks)."""
     base = slot * n1
     oob = N_BCK * n1
-    with waves.part("dense_sharded", "bck_meta_scatter"):
-        rows = jnp.where(inst.wmask, base + inst.rows, oob)
-        meta = state.bck_meta.at[rows].set(inst.meta, mode="drop",
+    with waves.part("dense_sharded", "bck_compact"):
+        ranks, n_live = compact.live_ranks(inst.wmask)
+
+        def install_chunk(tabs, lanes, ok):
+            meta, val = tabs
+            rows_c = jnp.where(ok, base + inst.rows[lanes], oob)
+            meta_c, val_c = inst.meta[lanes], inst.val[lanes]
+            with waves.part("dense_sharded", "bck_meta_scatter"):
+                meta = meta.at[rows_c].set(meta_c, mode="drop",
                                            unique_indices=True)
-    with waves.part("dense_sharded", "bck_val_scatter"):
-        # masked lanes ride the oob row: oob*val_words is already past
-        # the end
-        flat = (rows[:, None] * val_words
-                + jnp.arange(val_words, dtype=I32)).reshape(-1)
-        val = state.bck_val.at[flat].set(inst.val.reshape(-1), mode="drop",
-                                         unique_indices=True)
+            with waves.part("dense_sharded", "bck_val_scatter"):
+                # a position past the live count rides the oob row:
+                # oob*val_words is already past the end
+                flat = (rows_c[:, None] * val_words
+                        + jnp.arange(val_words, dtype=I32)).reshape(-1)
+                val = val.at[flat].set(val_c.reshape(-1), mode="drop",
+                                       unique_indices=True)
+            return meta, val
+
+        (meta, val), trips = compact.for_chunks(
+            ranks, n_live, compact.chunk_lanes(inst.wmask.shape[0]),
+            install_chunk, (state.bck_meta, state.bck_val))
+        if chunks is not None:
+            chunks.append(trips)
     with waves.part("dense_sharded", "bck_log_append"):
         # 1-based so "own entry" (key_hi == 0, written by pipe_step's
         # local append) can never collide with "forwarded from device 0"
         src = jnp.broadcast_to(src_dev.astype(U32) + U32(1),
                                inst.key.shape)
-        log = logring.append_rep(state.db.log, inst.wmask, inst.tbl,
-                                 inst.is_del, src, inst.key, inst.ver,
-                                 inst.val)
+        log = logring.append_rep_live(state.db.log, ranks, n_live,
+                                      inst.wmask, inst.tbl, inst.is_del,
+                                      src, inst.key, inst.ver, inst.val)
     return state.replace(bck_val=val, bck_meta=meta,
                          db=state.db.replace(log=log))
 
@@ -206,17 +229,20 @@ def build_sharded_pipelined_runner(mesh: Mesh, n_shards: int,
                     fwd = jax.tree.map(functools.partial(
                         jax.lax.ppermute, axis_name=SHARD_AXIS,
                         perm=ring_perm(n_shards, off)), inst)
-                    if cnt is not None:
-                        # replication pushes, counted where they are
-                        # APPLIED (the receiving backup — the reference's
-                        # CommitBck handler)
-                        hop = (mon.CTR_REPL_PUSH_HOP1 if off == 1
-                               else mon.CTR_REPL_PUSH_HOP2)
-                        cnt = mon.bump(
-                            cnt, {hop: fwd.wmask.sum(dtype=jnp.int32)})
                     src_dev = (dev - off) % n_shards
+                trips = []
                 state = _apply_backup(state, fwd, off - 1, n1, val_words,
-                                      src_dev)
+                                      src_dev, trips)
+                if cnt is not None:
+                    # replication pushes, and the chunks their install
+                    # took, counted where they are APPLIED (the receiving
+                    # backup — the reference's CommitBck handler)
+                    hop = (mon.CTR_REPL_PUSH_HOP1 if off == 1
+                           else mon.CTR_REPL_PUSH_HOP2)
+                    with waves.part("dense_sharded", "repl_hop"):
+                        cnt = mon.bump(cnt, {
+                            hop: fwd.wmask.sum(dtype=jnp.int32),
+                            mon.CTR_BCK_CHUNKS: trips[0]})
         return state, new_ctx, c1, jax.lax.psum(stats, SHARD_AXIS), cnt
 
     def scan_fn(carry, key, gen_new=True):

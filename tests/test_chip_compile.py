@@ -119,6 +119,14 @@ def scatter_index_counts(hlo: str, table_words: int) -> list:
         rf"u32\[{table_words}\]\S* scatter\(%[\w.\-]+, %([\w.\-]+),", hlo)]
 
 
+def all_scatter_index_counts(hlo: str) -> set:
+    """The index counts of every native scatter in compiled HLO text,
+    whatever it writes."""
+    shape_of = _shapes(hlo)
+    return {_count(shape_of[idx]) for idx in re.findall(
+        r" scatter\(%[\w.\-]+, %([\w.\-]+),", hlo)}
+
+
 def gather_lane_counts(hlo: str, table_words: int) -> list:
     """How many lanes each native gather out of a u32[table_words] table
     issues (its output's element count), from compiled HLO text."""
@@ -300,11 +308,14 @@ def test_dense_sharded_block_program_on_four_chips(topo):
     assert ma.output_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
 
     n1 = td.n_rows(ds.n_sub_local(N_SUB, n)) + 1
-    # (program, ceiling on its temporaries): 7.794 GB the block and 8.867
-    # the drain (AOT, PR 37) beside 5.296 GB of donated state. Not under
-    # 1e9 as on one chip: `block_local` squeezes [1, N] leaves and the
-    # backup's full-width scatters copy; carrying the shards as [N] is
-    # ROADMAP Queue 2A item 2(a). The ceilings hold what fits today.
+    # (program, ceiling on its temporaries): 7.794 GB the block and 8.868
+    # the drain (AOT, PR 38) beside 5.296 GB of donated state: what they
+    # were with the backup's scatters at full width (7.794 / 8.867, PR
+    # 37), so those scatters' copies were not it. Not under 1e9 as on one
+    # chip: `block_local` squeezes [1, N] leaves; carrying the shards as
+    # [N] is ROADMAP Queue 2A item 2(a). On the chip the peak is 5.4 GB
+    # (PERF.md §5). The ceilings hold what fits today.
+    chunk = compact.chunk_lanes(2 * W)
     for fn, args, ceiling in (
             (jax.jit(run, donate_argnums=0), (carry, key), 8.0e9),
             (jax.jit(drain, donate_argnums=0), (carry,), 9.0e9)):
@@ -317,12 +328,12 @@ def test_dense_sharded_block_program_on_four_chips(topo):
         assert ma.temp_size_in_bytes < ceiling
         hlo = c.as_text()
         assert "collective-permute" in hlo
-        # each hop's backup install issues ALL 2w lanes (2w x VW value
-        # words, 2w meta words), masked ones out of bounds: the number a
-        # compaction of `_apply_backup` changes (the primary's own install
-        # issues a chunk, as on one chip)
+        # each hop's backup install issues a chunk of the forwarded
+        # record's live lanes (C x VW value words, C meta words), as the
+        # primary's own install does, as on one chip; nothing in the
+        # program issues all 2w x VW indices any more
         assert set(scatter_index_counts(hlo, ds.N_BCK * n1 * VW)) \
-            == {2 * W * VW}
-        assert set(scatter_index_counts(hlo, ds.N_BCK * n1)) == {2 * W}
-        assert set(scatter_index_counts(hlo, n1 * VW)) \
-            == {compact.chunk_lanes(2 * W) * VW}
+            == {chunk * VW}
+        assert set(scatter_index_counts(hlo, ds.N_BCK * n1)) == {chunk}
+        assert set(scatter_index_counts(hlo, n1 * VW)) == {chunk * VW}
+        assert 2 * W * VW not in all_scatter_index_counts(hlo)

@@ -1,10 +1,16 @@
 """Multi-chip dense TATP: device-local txns + ppermute'd replication."""
+import functools
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dint_tpu import monitor as M
 from dint_tpu.engines import tatp_dense as td
+from dint_tpu.ops import compact
 from dint_tpu.parallel import dense_sharded as ds
+from dint_tpu.tables import log as logring
 
 VW = 4
 D = 8
@@ -177,25 +183,28 @@ def _acked(ring, heads, tag):
     return e[np.argsort(e[:, 3], kind="stable")]
 
 
-@pytest.mark.parametrize("seed", [0, 11, 2**31 + 5])
-def test_every_replica_equals_the_sequential_reference(seed):
-    """Four devices: each primary, each backup slot (row for row over the
-    whole table) and each of the three rings that carry a device's stream
-    (entry for entry, in order) equal what dint_tpu/testing/
-    replication.py makes of the primary's acknowledged installs."""
+def _replicas_equal_reference(seed, w, n_glob=4 * 300, mix=None,
+                              monitor=False):
+    """Four devices, three blocks and the drain: each primary, each backup
+    slot (row for row over the whole table) and each of the three rings
+    that carry a device's stream (entry for entry, in order) equal what
+    dint_tpu/testing/replication.py makes of the primary's acknowledged
+    installs. Returns the run's counter snapshot under ``monitor``."""
     from dint_tpu.testing import replication as ref
 
-    d, n_glob, w = 4, 4 * 300, 64
+    d = 4
     mesh = ds.make_mesh(d)
     state = ds.create_sharded(mesh, d, n_glob, val_words=VW,
                               seed=seed % (1 << 31), log_capacity=1 << 10)
     fresh = jax.tree.map(np.array, state)
     run, init, drain = ds.build_sharded_pipelined_runner(
-        mesh, d, n_glob, w=w, val_words=VW, cohorts_per_block=2)
+        mesh, d, n_glob, w=w, val_words=VW, cohorts_per_block=2, mix=mix,
+        monitor=monitor)
     carry = init(state)
     for i in range(3):
         carry, _ = run(carry, jax.random.fold_in(jax.random.PRNGKey(seed), i))
-    live = jax.tree.map(np.asarray, drain(carry)[0])
+    out = drain(carry)
+    live = jax.tree.map(np.asarray, out[0])
 
     n_loc = ds.n_sub_local(n_glob, d)
     n1 = td.n_rows(n_loc) + 1
@@ -235,6 +244,139 @@ def test_every_replica_equals_the_sequential_reference(seed):
         written = np.concatenate([rings[dev, lane, :int(heads[dev, lane])]
                                   for lane in range(lanes)])
         assert set(np.unique(written[:, 1]).tolist()) == tags
+    return M.snapshot(out[2]) if monitor else None
+
+
+@pytest.mark.parametrize("seed", [0, 11, 2**31 + 5])
+def test_every_replica_equals_the_sequential_reference(seed):
+    """At TATP's mix and w = 64 (C = 128 = all 2w lanes): a hop's install
+    never leaves one chunk."""
+    _replicas_equal_reference(seed, w=64)
+
+
+def test_replicas_equal_the_reference_when_a_hop_takes_several_chunks():
+    """An update-only mix at w = 256 (2w = 512 slots, C = 128) over
+    subscribers enough that most transactions commit: a hop forwards
+    more live lanes than one chunk holds, so the receivers'
+    install and append loops take two or more trips, and every replica
+    still equals the sequential reference."""
+    mix = (0.0, 0.0, 0.0, 0.5, 0.5, 0.0, 0.0)
+    snap = _replicas_equal_reference(7, w=256, n_glob=4 * 4000, mix=mix,
+                                     monitor=True)
+    chunk = compact.chunk_lanes(2 * 256)
+    assert chunk == 128
+    hops = snap["repl_push_hop1"] + snap["repl_push_hop2"]
+    assert hops == 2 * snap["install_writes"] > 0
+    # `steps` is summed over the devices, two hops a step and device:
+    # more trips than hops, so some hop took at least two
+    assert snap["bck_chunks"] > 2 * snap["steps"]
+    assert snap["bck_chunks"] >= -(-hops // chunk)
+
+
+# --------------------- the compacted apply against the full-width form
+
+
+def _apply_backup_full_width(state, inst, slot, n1, val_words, src_dev):
+    """`ds._apply_backup` as it was before the receiver compacted the
+    record (PR 37): every one of the 2w lanes issued, masked ones out of
+    bounds, and `append_rep` of all of them. The reference the chunked
+    form has to equal bit for bit."""
+    base, oob = slot * n1, ds.N_BCK * n1
+    rows = jnp.where(inst.wmask, base + inst.rows, oob)
+    meta = state.bck_meta.at[rows].set(inst.meta, mode="drop",
+                                       unique_indices=True)
+    flat = (rows[:, None] * val_words
+            + jnp.arange(val_words, dtype=jnp.int32)).reshape(-1)
+    val = state.bck_val.at[flat].set(inst.val.reshape(-1), mode="drop",
+                                     unique_indices=True)
+    src = jnp.broadcast_to(src_dev.astype(jnp.uint32) + jnp.uint32(1),
+                           inst.key.shape)
+    log = logring.append_rep(state.db.log, inst.wmask, inst.tbl,
+                             inst.is_del, src, inst.key, inst.ver, inst.val)
+    return state.replace(bck_val=val, bck_meta=meta,
+                         db=state.db.replace(log=log))
+
+
+_AW = 256                                   # 2w = 512 lanes
+_AC = compact.chunk_lanes(2 * _AW)          # C = 128
+_AN_SUB = 60
+
+
+@functools.cache
+def _apply_pair():
+    """(state, chunked, full): one shard's state at a tiny size (16 rings
+    of 32 slots: a second record of 512 live lanes overwrites the first)
+    and the two forms jitted once for every case below."""
+    n1 = td.n_rows(_AN_SUB) + 1
+    db = td.populate(np.random.default_rng(3), _AN_SUB, val_words=VW,
+                     log_replicas=1, log_capacity=32)
+    rng = np.random.default_rng(4)
+    state = ds.ShardState(
+        db=db,
+        bck_val=jnp.asarray(rng.integers(
+            0, 1 << 32, ds.N_BCK * n1 * VW, dtype=np.uint32)),
+        bck_meta=jnp.asarray(rng.integers(
+            0, 1 << 32, ds.N_BCK * n1, dtype=np.uint32)))
+
+    def chunked(state, inst, src_dev, slot):
+        trips = []
+        out = ds._apply_backup(state, inst, slot, n1, VW, src_dev, trips)
+        return out, trips[0]
+
+    def full(state, inst, src_dev, slot):
+        return _apply_backup_full_width(state, inst, slot, n1, VW, src_dev)
+
+    return (state, jax.jit(chunked, static_argnums=3),
+            jax.jit(full, static_argnums=3))
+
+
+def _forced_record(rng, n_live, n1):
+    """An install record of 2w lanes with ``n_live`` live ones at random
+    lanes: distinct rows among the live, row ids that would land IN
+    bounds on the masked (a mask that leaks shows), deletes among them."""
+    r = 2 * _AW
+    wmask = np.zeros(r, bool)
+    wmask[rng.choice(r, n_live, replace=False)] = True
+    rows = rng.integers(0, n1 - 1, r)
+    rows[wmask] = rng.choice(n1 - 1, n_live, replace=False)
+    u32 = functools.partial(rng.integers, 0, 1 << 32, dtype=np.uint32)
+    return td.Installs(
+        wmask=jnp.asarray(wmask), rows=jnp.asarray(rows, jnp.int32),
+        meta=jnp.asarray(u32(r)), val=jnp.asarray(u32((r, VW))),
+        tbl=jnp.asarray(rng.integers(0, 5, r), jnp.int32),
+        key=jnp.asarray(u32(r)),
+        is_del=jnp.asarray(rng.integers(0, 2, r), jnp.int32),
+        ver=jnp.asarray(u32(r)))
+
+
+@pytest.mark.parametrize("n_live", [0, 1, _AC - 1, _AC, _AC + 1,
+                                    2 * _AC + 3, 2 * _AW])
+def test_compacted_apply_equals_the_full_width_form(n_live):
+    """`_apply_backup` issues the live lanes of a forwarded record in
+    chunks; the backup tables, every ring's entries and every head are
+    bit for bit what the full-width form writes, over two hops in a row
+    (the second on heads the first has moved, into rings that wrap), and
+    the install loop makes ceil(live / C) trips."""
+    state, chunked, full = _apply_pair()
+    n1 = td.n_rows(_AN_SUB) + 1
+    assert n1 - 1 >= 2 * _AW            # room for 2w distinct rows
+    rng = np.random.default_rng(100 + n_live)
+    a = b = state
+    for slot, src in ((0, 3), (1, 2)):
+        inst = _forced_record(rng, n_live, n1)
+        a, trips = chunked(a, inst, jnp.asarray(src, jnp.int32), slot)
+        b = full(b, inst, jnp.asarray(src, jnp.int32), slot)
+        assert int(trips) == -(-n_live // _AC)
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    heads = np.asarray(a.db.log.head)
+    assert heads.sum() == 2 * n_live
+    if n_live:
+        changed = np.asarray(a.bck_meta) != np.asarray(state.bck_meta)
+        assert 0 < changed.sum() <= 2 * n_live
+    else:
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(state)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 _CACHE_CHILD = '''

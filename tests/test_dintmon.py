@@ -477,6 +477,49 @@ def test_dense_sharded_counters_sum_across_shards():
     assert snap["repl_push_hop2"] == snap["install_writes"]
 
 
+def test_bck_chunks_reconcile_with_the_hops_step_by_step():
+    """Backup-apply compaction: at each receiving device a step's two
+    install loops make ceil(hop 1's live / C) + ceil(hop 2's live / C)
+    trips. One cohort a block, so a window's delta is one step's; an
+    update-only mix at 2w = 512 slots (C = 128), so that a hop forwards
+    more than one chunk holds."""
+    from dint_tpu.ops import compact
+    from dint_tpu.parallel import dense_sharded as ds
+
+    chunk = compact.chunk_lanes(512)
+    mesh = ds.make_mesh(4)
+    run, init, drain = ds.build_sharded_pipelined_runner(
+        mesh, 4, 4 * 4000, w=256, val_words=4, cohorts_per_block=1,
+        mix=(0.0, 0.0, 0.0, 0.5, 0.5, 0.0, 0.0), monitor=True)
+    carry = init(ds.create_sharded(mesh, 4, 4 * 4000, val_words=4,
+                                   log_capacity=256))
+
+    def trips(d):
+        return (-(-d[:, mc.CTR_REPL_PUSH_HOP1] // chunk)
+                - (-d[:, mc.CTR_REPL_PUSH_HOP2] // chunk))
+
+    prev, most = np.zeros((4, mc.N_COUNTERS), np.int64), 0
+    for i in range(5):
+        carry, _ = run(carry, jax.random.fold_in(KEY(4), i))
+        buf = np.asarray(carry[-1].buf, np.int64)       # [D, N] a device
+        d, prev = buf - prev, buf
+        assert (d[:, mc.CTR_STEPS] == 1).all()
+        assert (d[:, mc.CTR_BCK_CHUNKS] == trips(d)).all()
+        most = max(most, int(d[:, mc.CTR_BCK_CHUNKS].max()))
+        if i < 2:                       # an empty c2: nothing forwarded
+            assert not d[:, mc.CTR_BCK_CHUNKS].any()
+    assert most >= 4                    # two trips or more a hop
+    _, _, cnt = drain(carry)
+    d = np.asarray(cnt.buf, np.int64) - prev            # two steps
+    assert (d[:, mc.CTR_BCK_CHUNKS] >= trips(d)).all()
+    assert (d[:, mc.CTR_BCK_CHUNKS] <= trips(d) + 2).all()
+    snap = M.snapshot(cnt)
+    assert snap["repl_push_hop1"] == snap["repl_push_hop2"] \
+        == snap["install_writes"]
+    # a hop's receiver makes the trips its sender's own install made
+    assert snap["bck_chunks"] == 2 * snap["install_chunks"] > 0
+
+
 def test_dense_sharded_sb_counters_sum_across_shards():
     from dint_tpu.parallel import dense_sharded_sb as dsb
 

@@ -264,8 +264,8 @@ def test_compile_cache_key_holds_the_names_and_no_call_stack(monkeypatch):
 # ------------------------------------------- the parts under `replicate`
 
 REPLICATE = "dint.dense_sharded.replicate"
-REPL_PARTS = ("repl_hop", "bck_meta_scatter", "bck_val_scatter",
-              "bck_log_append")
+REPL_PARTS = ("repl_hop", "bck_compact", "bck_meta_scatter",
+              "bck_val_scatter", "bck_log_append")
 
 
 def _sharded(d=4, monitor=True):
@@ -277,7 +277,7 @@ def _sharded(d=4, monitor=True):
     return run, init(state), drain
 
 
-def test_the_four_replicate_parts_are_registered_under_their_wave():
+def test_the_replicate_parts_are_registered_under_their_wave():
     rows = {p: (o, w) for o, w, p, _ in waves._PARTS}
     for part in REPL_PARTS:
         assert rows[part] == ("dense_sharded", "replicate")
@@ -303,8 +303,11 @@ def _equations_under(jaxpr, wave: str, stack: str, out: list) -> list:
 
 
 @pytest.mark.parametrize("monitor", [True, False])
-def test_every_equation_under_replicate_carries_one_of_the_four_parts(
-        monitor):
+def test_every_equation_under_replicate_carries_a_part(monitor):
+    """Each leaf equation under the wave is booked to one of its parts:
+    the LAST `part.` on its stack (benchmarks/part_times.py). Two parts
+    on one stack only where the two scatters lie inside the chunk loop of
+    `bck_compact` (as `val_scatter` inside `ws_compact` on one chip)."""
     run, carry, _ = _sharded(monitor=monitor)
     closed = jax.make_jaxpr(run)(carry, jax.random.PRNGKey(0))
     under = _equations_under(closed.jaxpr, REPLICATE, "", [])
@@ -312,12 +315,20 @@ def test_every_equation_under_replicate_carries_one_of_the_four_parts(
     seen = set()
     for prim, stack in under:
         after = stack[stack.index(REPLICATE):].split("/")
-        mine = [p for p in REPL_PARTS if waves.part_name(p) in after]
-        assert len(mine) == 1, (prim, stack)
-        seen.add(mine[0])
-        # the collectives are the hop's, the scatters the backup's
+        mine = [p for n in after for p in REPL_PARTS
+                if n == waves.part_name(p)]
+        assert mine, (prim, stack)
+        assert len(mine) == 1 or (
+            mine[0] == "bck_compact" and len(mine) == 2
+            and mine[1] in ("bck_meta_scatter", "bck_val_scatter")), stack
+        seen.add(mine[-1])
+        # the collectives are the hop's, the scatters the backup's, and
+        # each scatter of a backup table is a chunk's, under its own part
         if prim == "ppermute":
             assert mine == ["repl_hop"]
+        if prim == "scatter":
+            assert mine[-1] in ("bck_meta_scatter", "bck_val_scatter",
+                                "bck_log_append"), stack
     assert seen == set(REPL_PARTS)
     assert sum(p == "ppermute" for p, _ in under) == 2 * 8
 
@@ -327,7 +338,7 @@ def test_the_replicate_parts_reach_compiled_hlo_under_their_wave():
     names = _op_names(jax.jit(run).lower(
         carry, jax.random.PRNGKey(0)).compile().as_text())
     _assert_parts_under_their_waves(names, ("dense_sharded",))
-    # append_rep's own parts lie inside the backup's append
+    # append_rep_live's own parts lie inside the backup's append
     assert any(re.search(rf"{re.escape(REPLICATE)}/.*part\.bck_log_append/"
                          r".*part\.log_scatter", n) for n in names)
 
