@@ -27,6 +27,7 @@ import functools
 import flax.struct
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..monitor import waves
 from ..ops import hashing, segments
@@ -96,15 +97,26 @@ def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
     ``scan_max`` (static) per lane, request length in ``batch.ver``.
     """
     r = batch.width
-    sb = segments.sort_batch(batch.key_hi, batch.key_lo)
-    op = batch.op[sb.perm]
-    val_in = batch.val[sb.perm]
+    with waves.part("store", "key_sort"):
+        sb = segments.sort_batch(batch.key_hi, batch.key_lo)
+        op = batch.op[sb.perm]
+        val_in = batch.val[sb.perm]
 
-    b1, b2 = hashing.bucket_pair(sb.key_hi, sb.key_lo, table.n_buckets)
     with waves.scope("store", "probe"):
+        with waves.part("store", "probe_keys"):
+            b1, b2 = hashing.bucket_pair(sb.key_hi, sb.key_lo,
+                                         table.n_buckets)
         if hot is None:
-            hit0, fbkt, slot0, val0, ver0, free1, free2 = kv.probe(
-                table, sb.key_hi, sb.key_lo, b1, b2)
+            # kv.probe's own body, under the two parts the trace splits
+            # the wave by: the [r, S] key / valid gathers of both
+            # candidate buckets, then the hit entry's value and version
+            with waves.part("store", "probe_keys"):
+                hit0, fbkt, slot0, free1, free2 = kv.probe_loc(
+                    table, sb.key_hi, sb.key_lo, b1, b2)
+            with waves.part("store", "probe_val"):
+                eidx0 = fbkt * table.slots + slot0
+                val0 = kv.entry_val(table, eidx0)
+                ver0 = table.ver[eidx0]
         else:
             hot_n = hot.hot_n
             vw = table.val_words
@@ -117,92 +129,97 @@ def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
             val0 = hotset.hot_gather(table.val, hot.val, eidx0, kmidx,
                                      vw).reshape(r, vw)
             ver0 = hotset.hot_gather(table.ver, hot.ver, eidx0, kmidx, 1)
-    # insert destination: the emptier of the two candidate buckets
-    dest = jnp.where(free2 > free1, b2, b1)
-    bkt = jnp.where(hit0, fbkt, dest)
-    alt = jnp.where(hit0, fbkt, b1 + b2 - dest)   # the other candidate
+    with waves.part("store", "key_sort"):
+        # insert destination: the emptier of the two candidate buckets
+        dest = jnp.where(free2 > free1, b2, b1)
+        bkt = jnp.where(hit0, fbkt, dest)
+        alt = jnp.where(hit0, fbkt, b1 + b2 - dest)   # the other candidate
 
-    is_get = op == Op.GET
-    is_install = (op == Op.SET) | (op == Op.INSERT)
-    is_delete = op == Op.DELETE
-    is_write = is_install | is_delete
+        is_get = op == Op.GET
+        is_install = (op == Op.SET) | (op == Op.INSERT)
+        is_delete = op == Op.DELETE
+        is_write = is_install | is_delete
 
-    n_inst_before = segments.seg_cumsum_excl(sb, is_install.astype(I32))
-    n_inst_total = segments.seg_sum(sb, is_install.astype(I32))
-    last_w_rank = segments.seg_max_where(sb, is_write, sb.rank, I32(-1))
-    pos_last = jnp.clip(sb.head_pos + last_w_rank, 0, r - 1)
-    last_is_del = is_delete[pos_last]
-    last_val = val_in[pos_last]
+        n_inst_before = segments.seg_cumsum_excl(sb, is_install.astype(I32))
+        n_inst_total = segments.seg_sum(sb, is_install.astype(I32))
+        last_w_rank = segments.seg_max_where(sb, is_write, sb.rank, I32(-1))
+        pos_last = jnp.clip(sb.head_pos + last_w_rank, 0, r - 1)
+        last_is_del = is_delete[pos_last]
+        last_val = val_in[pos_last]
 
-    ver0_eff = jnp.where(hit0, ver0, U32(0))
-    any_write = last_w_rank >= 0
-    final_exists = jnp.where(any_write, ~last_is_del, hit0)
-    final_ver = ver0_eff + n_inst_total.astype(U32)
+        ver0_eff = jnp.where(hit0, ver0, U32(0))
+        any_write = last_w_rank >= 0
+        final_exists = jnp.where(any_write, ~last_is_del, hit0)
+        final_ver = ver0_eff + n_inst_total.astype(U32)
 
-    # ---- replies (sorted space) -------------------------------------------
-    # exact sequential existence at each write's point: the latest write
-    # before me in my segment decides, else pre-batch state
-    idx = jnp.arange(r, dtype=I32)
-    w_pos = jax.lax.cummax(jnp.where(is_write, idx, I32(-1)))
-    prev_w_pos = jnp.concatenate([jnp.full((1,), -1, I32), w_pos[:-1]])
-    in_seg = prev_w_pos >= sb.head_pos
-    existed_here = jnp.where(in_seg, is_install[jnp.clip(prev_w_pos, 0, r - 1)], hit0)
-    rtype = jnp.full((r,), Reply.NONE, I32)
-    rtype = jnp.where(is_get, jnp.where(hit0, Reply.VAL, Reply.NOT_EXIST), rtype)
-    rtype = jnp.where(is_install, Reply.ACK, rtype)
-    rtype = jnp.where(is_delete,
-                      jnp.where(existed_here, Reply.ACK, Reply.NOT_EXIST), rtype)
-    rval = jnp.where(is_get[:, None] & hit0[:, None], val0, jnp.zeros_like(val0))
-    rver = jnp.where(is_get, jnp.where(hit0, ver0, U32(0)), U32(0))
-    rver = jnp.where(is_install, ver0_eff + (n_inst_before + 1).astype(U32), rver)
+    with waves.part("store", "reply_build"):
+        # ---- replies (sorted space) ---------------------------------------
+        # exact sequential existence at each write's point: the latest write
+        # before me in my segment decides, else pre-batch state
+        idx = jnp.arange(r, dtype=I32)
+        w_pos = jax.lax.cummax(jnp.where(is_write, idx, I32(-1)))
+        prev_w_pos = jnp.concatenate([jnp.full((1,), -1, I32), w_pos[:-1]])
+        in_seg = prev_w_pos >= sb.head_pos
+        existed_here = jnp.where(in_seg, is_install[jnp.clip(prev_w_pos, 0, r - 1)], hit0)
+        rtype = jnp.full((r,), Reply.NONE, I32)
+        rtype = jnp.where(is_get, jnp.where(hit0, Reply.VAL, Reply.NOT_EXIST), rtype)
+        rtype = jnp.where(is_install, Reply.ACK, rtype)
+        rtype = jnp.where(is_delete,
+                          jnp.where(existed_here, Reply.ACK, Reply.NOT_EXIST), rtype)
+        rval = jnp.where(is_get[:, None] & hit0[:, None], val0, jnp.zeros_like(val0))
+        rver = jnp.where(is_get, jnp.where(hit0, ver0, U32(0)), U32(0))
+        rver = jnp.where(is_install, ver0_eff + (n_inst_before + 1).astype(U32), rver)
 
-    # ---- writer election: segment-last lane acts for its key -------------
-    writer = sb.last & any_write
-    w_upd = writer & final_exists & hit0
-    w_alloc = writer & final_exists & ~hit0
-    w_del = writer & ~final_exists & hit0
+    with waves.part("store", "key_sort"):
+        # ---- writer election: segment-last lane acts for its key ---------
+        writer = sb.last & any_write
+        w_upd = writer & final_exists & hit0
+        w_alloc = writer & final_exists & ~hit0
+        w_del = writer & ~final_exists & hit0
 
-    # back to original order for phase B + scatters
-    (o_upd, o_alloc, o_del, o_bkt, o_alt, o_slot0, o_ver) = segments.unsort(
-        sb, w_upd, w_alloc, w_del, bkt, alt, slot0, final_ver)
-    o_val = segments.unsort(sb, last_val)
-    o_khi, o_klo = segments.unsort(sb, sb.key_hi, sb.key_lo)
+        # back to original order for phase B + scatters
+        (o_upd, o_alloc, o_del, o_bkt, o_alt, o_slot0, o_ver) = segments.unsort(
+            sb, w_upd, w_alloc, w_del, bkt, alt, slot0, final_ver)
+        o_val = segments.unsort(sb, last_val)
+        o_khi, o_klo = segments.unsort(sb, sb.key_hi, sb.key_lo)
 
-    # ---- phase B: slot allocation for inserts, per destination bucket ----
-    sb2 = segments.sort_batch(jnp.zeros((r,), U32), o_bkt.astype(U32))
-    alloc2 = o_alloc[sb2.perm]
-    rank_alloc = segments.seg_cumsum_excl(sb2, alloc2.astype(I32))
-    bkt2 = o_bkt[sb2.perm]
-    has2, slot_new2 = kv.nth_free_slot(
-        table.valid[kv.bucket_rows(table, bkt2)], rank_alloc)
-    ok2 = alloc2 & has2
-    spill2 = alloc2 & ~has2
-    ok, spill1, slot_new = segments.unsort(sb2, ok2, spill2, slot_new2)
+    with waves.part("store", "slot_alloc"):
+        # ---- phase B: slot allocation for inserts, per destination bucket
+        sb2 = segments.sort_batch(jnp.zeros((r,), U32), o_bkt.astype(U32))
+        alloc2 = o_alloc[sb2.perm]
+        rank_alloc = segments.seg_cumsum_excl(sb2, alloc2.astype(I32))
+        bkt2 = o_bkt[sb2.perm]
+        has2, slot_new2 = kv.nth_free_slot(
+            table.valid[kv.bucket_rows(table, bkt2)], rank_alloc)
+        ok2 = alloc2 & has2
+        spill2 = alloc2 & ~has2
+        ok, spill1, slot_new = segments.unsort(sb2, ok2, spill2, slot_new2)
 
-    # ---- phase B2: overflow retries its ALTERNATE candidate bucket --------
-    # (two-choice insert: only give up when both buckets are full). Ranks in
-    # the alternate must skip slots phase B just handed out there.
-    taken = jnp.zeros((table.n_buckets + 1,), I32).at[
-        jnp.where(ok, o_bkt, table.n_buckets)].add(1, mode="drop")
-    sb3 = segments.sort_batch(jnp.zeros((r,), U32), o_alt.astype(U32))
-    retry3 = spill1[sb3.perm]
-    rank3 = segments.seg_cumsum_excl(sb3, retry3.astype(I32)) + taken[o_alt[sb3.perm]]
-    has3, slot_new3 = kv.nth_free_slot(
-        table.valid[kv.bucket_rows(table, o_alt[sb3.perm])], rank3)
-    ok3_s = retry3 & has3
-    ok_alt, slot_alt = segments.unsort(sb3, ok3_s, slot_new3)
-    spill = spill1 & ~ok_alt
-    ok = ok | ok_alt
-    o_bkt = jnp.where(ok_alt, o_alt, o_bkt)
-    slot_new = jnp.where(ok_alt, slot_alt, slot_new)
+        # ---- phase B2: overflow retries its ALTERNATE candidate bucket ----
+        # (two-choice insert: only give up when both buckets are full). Ranks
+        # in the alternate must skip slots phase B just handed out there.
+        taken = jnp.zeros((table.n_buckets + 1,), I32).at[
+            jnp.where(ok, o_bkt, table.n_buckets)].add(1, mode="drop")
+        sb3 = segments.sort_batch(jnp.zeros((r,), U32), o_alt.astype(U32))
+        retry3 = spill1[sb3.perm]
+        rank3 = segments.seg_cumsum_excl(sb3, retry3.astype(I32)) + taken[o_alt[sb3.perm]]
+        has3, slot_new3 = kv.nth_free_slot(
+            table.valid[kv.bucket_rows(table, o_alt[sb3.perm])], rank3)
+        ok3_s = retry3 & has3
+        ok_alt, slot_alt = segments.unsort(sb3, ok3_s, slot_new3)
+        spill = spill1 & ~ok_alt
+        ok = ok | ok_alt
+        o_bkt = jnp.where(ok_alt, o_alt, o_bkt)
+        slot_new = jnp.where(ok_alt, slot_alt, slot_new)
 
-    # spill => every install of that key failed: fix up replies for the whole
-    # key segment (installs -> SPILL, deletes -> NOT_EXIST since nothing was
-    # ever installed; GETs already answered from pre-state)
-    seg_spill = segments.seg_any(sb, spill[sb.perm])
-    rtype = jnp.where(seg_spill & is_install, I32(Reply.SPILL), rtype)
-    rtype = jnp.where(seg_spill & is_delete, I32(Reply.NOT_EXIST), rtype)
-    rver = jnp.where(seg_spill & is_install, U32(0), rver)
+    with waves.part("store", "reply_build"):
+        # spill => every install of that key failed: fix up replies for the
+        # whole key segment (installs -> SPILL, deletes -> NOT_EXIST since
+        # nothing was ever installed; GETs already answered from pre-state)
+        seg_spill = segments.seg_any(sb, spill[sb.perm])
+        rtype = jnp.where(seg_spill & is_install, I32(Reply.SPILL), rtype)
+        rtype = jnp.where(seg_spill & is_delete, I32(Reply.NOT_EXIST), rtype)
+        rver = jnp.where(seg_spill & is_install, U32(0), rver)
 
     # ---- scatters (flat 1-D unique-index: one writer per entry) ----------
     # NOTE on unique_indices=True + the OOB sentinel: every MASKED lane is
@@ -216,20 +233,23 @@ def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
     # wflat / populate_device idx, smallbank_dense scatters.)
     ne = table.n_buckets * table.slots
     s = table.slots
-    w_any_slot = o_upd | ok | o_del
-    t_slot = jnp.where(o_upd | o_del, o_slot0, slot_new)
     with waves.scope("store", "install"):
-        e_any = jnp.where(w_any_slot, o_bkt * s + t_slot, ne)
-        new_valid = table.valid.at[e_any].set(~o_del, mode="drop",
-                                              unique_indices=True)
-        wv = (o_upd | ok)
-        sl_v = jnp.where(o_upd, o_slot0, slot_new)
-        e_v = jnp.where(wv, o_bkt * s + sl_v, ne)
+        with waves.part("store", "kv_meta_scatter"):
+            w_any_slot = o_upd | ok | o_del
+            t_slot = jnp.where(o_upd | o_del, o_slot0, slot_new)
+            e_any = jnp.where(w_any_slot, o_bkt * s + t_slot, ne)
+            new_valid = table.valid.at[e_any].set(~o_del, mode="drop",
+                                                  unique_indices=True)
+            wv = (o_upd | ok)
+            sl_v = jnp.where(o_upd, o_slot0, slot_new)
+            e_v = jnp.where(wv, o_bkt * s + sl_v, ne)
         if hot is None:
-            val_new = table.val.at[kv.val_word_idx(table, e_v)].set(
-                o_val.reshape(-1), mode="drop", unique_indices=True)
-            ver_new = table.ver.at[e_v].set(o_ver, mode="drop",
-                                            unique_indices=True)
+            with waves.part("store", "kv_val_scatter"):
+                val_new = table.val.at[kv.val_word_idx(table, e_v)].set(
+                    o_val.reshape(-1), mode="drop", unique_indices=True)
+            with waves.part("store", "kv_meta_scatter"):
+                ver_new = table.ver.at[e_v].set(o_ver, mode="drop",
+                                                unique_indices=True)
         else:
             # write-through install: table entry AND key-indexed mirror.
             # One writer per key segment,
@@ -243,21 +263,23 @@ def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
             ver_new, hot_ver = hotset.hot_scatter(
                 table.ver, hot.ver, e_w, w_midx, wv, o_ver, 1)
             hot = hot.replace(val=hot_val, ver=hot_ver)
-        table = table.replace(
-            key_hi=table.key_hi.at[e_v].set(o_khi, mode="drop",
-                                            unique_indices=True),
-            key_lo=table.key_lo.at[e_v].set(o_klo, mode="drop",
-                                            unique_indices=True),
-            val=val_new,
-            ver=ver_new,
-            valid=new_valid,
-        )
+        with waves.part("store", "kv_meta_scatter"):
+            table = table.replace(
+                key_hi=table.key_hi.at[e_v].set(o_khi, mode="drop",
+                                                unique_indices=True),
+                key_lo=table.key_lo.at[e_v].set(o_klo, mode="drop",
+                                                unique_indices=True),
+                val=val_new,
+                ver=ver_new,
+                valid=new_valid,
+            )
     if maintain_bloom:
         # recompute exactly for buckets whose membership changed
         table = kv.recompute_bloom(table, o_bkt, ok | o_del)
 
-    o_rtype, o_rver = segments.unsort(sb, rtype, rver)
-    o_rval = segments.unsort(sb, rval)
+    with waves.part("store", "key_sort"):
+        o_rtype, o_rver = segments.unsort(sb, rtype, rver)
+        o_rval = segments.unsort(sb, rval)
 
     # ---- dintscan: Op.SCAN lanes answered from the PRE-batch run∪delta ----
     # view (a valid serial order: scans sit in phase 1 with the GETs), then
@@ -323,6 +345,174 @@ def rebuild_run(table: kv.KVTable, run: run_mod.OrderedRun):
 
 STORE_MAGIC = 0x55AA   # val word1 of populated rows (clients/micro.py)
 
+# One stats row a step. ``attempted`` and ``committed`` lead, so that a
+# caller that reads two columns (the serve plane) reads them as before.
+# The lawful outcomes of a lane are ``committed`` (VAL + ACK) and
+# ``not_exist``; ``spill``, ``retry`` and ``magic_bad`` are faults of a
+# deployment whose table holds its key space. ``ver_sum`` / ``val_sum``
+# are checksums mod 2^32 (u32 sums carried as i32 bit patterns) over the
+# VAL and ACK lanes: of the reply versions, and of every reply value word,
+# so that every returned value and version can be held to a reference
+# without a reply leaving the device.
+STAT_NAMES = ("attempted", "committed", "not_exist", "spill", "retry",
+              "magic_bad", "gets", "updates", "ver_sum", "val_sum")
+(STAT_ATTEMPTED, STAT_COMMITTED, STAT_NOT_EXIST, STAT_SPILL, STAT_RETRY,
+ STAT_MAGIC_BAD, STAT_GETS, STAT_UPDATES, STAT_VER_SUM,
+ STAT_VAL_SUM) = range(len(STAT_NAMES))
+N_STATS = len(STAT_NAMES)
+
+_ZETA_CHUNK = 1 << 20
+
+
+@functools.lru_cache(maxsize=None)
+def zipf_constants(n: int, theta: float) -> tuple:
+    """(zetan, eta, alpha, zeta2) of YCSB's ZipfianGenerator over n items
+    (Gray et al., "Quickly Generating Billion-Record Synthetic
+    Databases", SIGMOD'94), in float64 on the host. ``zetan`` is summed
+    a chunk at a time: nothing n-sized is made."""
+    zetan = 0.0
+    for lo in range(1, n + 1, _ZETA_CHUNK):
+        i = np.arange(lo, min(lo + _ZETA_CHUNK, n + 1), dtype=np.float64)
+        zetan += float((i ** -theta).sum())
+    zeta2 = 1.0 + 0.5 ** theta
+    alpha = 1.0 / (1.0 - theta)
+    eta = (1.0 - (2.0 / n) ** (1.0 - theta)) / (1.0 - zeta2 / zetan)
+    return zetan, eta, alpha, zeta2
+
+
+def zipf_keys(key, shape, n: int, theta: float):
+    """YCSB's Zipfian over [1, n] on the device, in closed form: with u
+    uniform in [0, 1), ``u * zetan < 1 -> 1``, ``< 1 + 0.5^theta -> 2``,
+    else ``1 + floor(n * (eta * u - eta + 1) ^ alpha)``; rank == key id.
+
+    float32 throughout. The draw is v = 1 - u in (0, 1] from 32 random
+    bits, and the power is ``exp(alpha * log1p(-eta * v))``: towards the
+    tail v is small and keeps its relative precision, which ``eta * u -
+    eta + 1`` (a number near 1, 6e-8 apart) would lose to a comb of ~23
+    keys at alpha = 100 and n = 24 M. A rank that rounds to n + 1 is
+    clipped to n."""
+    zetan, eta, alpha, zeta2 = zipf_constants(int(n), float(theta))
+    bits = jax.random.bits(key, shape, U32)
+    v = (bits.astype(jnp.float32) + 1.0) * jnp.float32(2.0 ** -32)
+    uz = (1.0 - v) * jnp.float32(zetan)
+    tail = jnp.float32(n) * jnp.exp(
+        jnp.float32(alpha) * jnp.log1p(-jnp.float32(eta) * v))
+    rank = jnp.where(uz < 1.0, U32(1), jnp.where(
+        uz < jnp.float32(zeta2), U32(2),
+        U32(1) + jnp.floor(tail).astype(U32)))
+    return jnp.clip(rank, U32(1), U32(n))
+
+
+def _fmix32(h):
+    """murmur3's 32-bit finalizer."""
+    h = h ^ (h >> U32(16))
+    h = h * U32(0x85EBCA6B)
+    h = h ^ (h >> U32(13))
+    h = h * U32(0xC2B2AE35)
+    return h ^ (h >> U32(16))
+
+
+def step_stamp(key):
+    """32 bits of a step's key (a raw ``PRNGKey``, as everywhere in this
+    repo): the stamp of the values it writes."""
+    return key[-1].astype(U32)
+
+
+def stamped_value(klo, stamp, val_words: int):
+    """The whole record an update of key ``klo`` writes in the step whose
+    stamp is ``stamp``: word 0 the key, word 1 STORE_MAGIC, word 2 the
+    stamp, word j >= 3 ``fmix32(key * 0x9E3779B1 + stamp * 0x7FEB352D +
+    j)``. A function of (key, stamp) alone, so every lane of a step that
+    names a key writes the same value, and a torn or misplaced value is
+    recognisable from the record itself."""
+    j = jnp.arange(val_words, dtype=U32)[None]
+    stamp = jnp.asarray(stamp, U32)
+    val = _fmix32(klo[:, None] * U32(0x9E3779B1)
+                  + stamp * U32(0x7FEB352D) + j)
+    val = val.at[:, 0].set(klo).at[:, 1].set(U32(STORE_MAGIC))
+    return val.at[:, 2].set(stamp) if val_words > 2 else val
+
+
+def build_generator(n_keys: int, w: int, val_words: int = 10,
+                    read_frac: float = 0.5, theta: float | None = None,
+                    scan_frac: float = 0.0, max_scan_len: int = 8,
+                    hot_frac: float | None = None,
+                    hot_prob: float | None = None):
+    """``gen(key, occ=None) -> Batch``: one on-device cohort of ``w``
+    lanes from a step's key, the function ``build_serve_runner`` composes
+    with ``step`` (and that a caller may run alone: the same key gives
+    the same batch). Lanes >= ``occ`` are NOP / PAD.
+
+    ``theta`` None: the hot-prefix skew (``hot_frac`` / ``hot_prob``; hot
+    head == smallest ids), SETs of {key, magic, 0...}. ``theta`` a float:
+    YCSB's Zipfian with that constant over [1, n_keys] (``zipf_keys``),
+    ``read_frac`` GET and the rest updates of the whole record
+    (``stamped_value``; the stamp is a word of the step's key, so the
+    generator needs no counter)."""
+    from ..clients import workloads as wl
+    hfrac = wl.SB_HOT_FRAC if hot_frac is None else float(hot_frac)
+    hprob = wl.SB_HOT_PROB if hot_prob is None else float(hot_prob)
+    hot_n = max(1, min(int(n_keys * hfrac), n_keys))
+
+    def gen(key, occ=None):
+        ks = jax.random.split(key, 6)
+        lane = jnp.arange(w, dtype=I32)
+        admitted = lane < (jnp.asarray(w, I32) if occ is None else occ)
+        is_scan = (jax.random.uniform(ks[0], (w,)) < scan_frac) \
+            if scan_frac > 0.0 else jnp.zeros((w,), bool)
+        is_get = ~is_scan & (jax.random.uniform(ks[1], (w,)) < read_frac)
+        if theta is None:
+            hot = jax.random.uniform(ks[2], (w,)) < hprob
+            klo = jnp.where(
+                hot, jax.random.randint(ks[3], (w,), 1, hot_n + 1),
+                jax.random.randint(ks[4], (w,), 1, n_keys + 1)).astype(U32)
+        else:
+            klo = zipf_keys(ks[3], (w,), n_keys, theta)
+        op = jnp.where(is_scan, I32(Op.SCAN),
+                       jnp.where(is_get, I32(Op.GET), I32(Op.SET)))
+        op = jnp.where(admitted, op, I32(Op.NOP))
+        klo = jnp.where(admitted, klo, U32(0xFFFFFFFF))
+        khi = jnp.where(admitted, U32(0), U32(0xFFFFFFFF))
+        if theta is None:
+            val = jnp.zeros((w, val_words), U32)
+            val = val.at[:, 0].set(klo).at[:, 1].set(U32(STORE_MAGIC))
+        else:
+            val = stamped_value(klo, step_stamp(key), val_words)
+        slen = jax.random.randint(ks[5], (w,), 1, max_scan_len + 1)
+        ver = jnp.where(admitted & is_scan, slen.astype(U32), U32(0))
+        return Batch(op=op, table=jnp.zeros((w,), I32), key_hi=khi,
+                     key_lo=klo, val=val, ver=ver)
+
+    return gen
+
+
+def reply_stats(batch: Batch, rep: Replies):
+    """The stats row (i32 [N_STATS], ``STAT_NAMES``) of one step's batch
+    and replies. ``magic_bad``: VAL replies of GET lanes whose word 0 is
+    not the key or word 1 not STORE_MAGIC, the reference client's assert
+    on every read (store/caladan/client_caladan.cc:160)."""
+    admitted = batch.op != Op.NOP
+    is_get = batch.op == Op.GET
+    is_val = rep.rtype == Reply.VAL
+    done = admitted & (is_val | (rep.rtype == Reply.ACK))
+    bad = is_get & is_val & ((rep.val[:, 0] != batch.key_lo)
+                             | (rep.val[:, 1] != U32(STORE_MAGIC)))
+
+    def count(mask):
+        return mask.sum(dtype=I32)
+
+    def checksum(x):
+        return jax.lax.bitcast_convert_type(x.sum(dtype=U32), I32)
+
+    return jnp.stack([
+        count(admitted), count(done),
+        count(admitted & (rep.rtype == Reply.NOT_EXIST)),
+        count(admitted & (rep.rtype == Reply.SPILL)),
+        count(admitted & (rep.rtype == Reply.RETRY)),
+        count(bad), count(is_get), count(batch.op == Op.SET),
+        checksum(jnp.where(done, rep.ver, U32(0))),
+        checksum(jnp.where(done[:, None], rep.val, U32(0)))])
+
 
 def build_serve_runner(n_keys: int, w: int = 4096,
                        cohorts_per_block: int = 8, val_words: int = 10,
@@ -331,22 +521,24 @@ def build_serve_runner(n_keys: int, w: int = 4096,
                        delta_cap: int | None = None,
                        hot_frac: float | None = None,
                        hot_prob: float | None = None,
+                       theta: float | None = None,
                        use_scan=None,
                        monitor: bool = False, trace=None,
                        serve: bool = False):
-    """Serve-plane runner for the store engine (dintscan's host workload):
-    jit(scan(step)) over carry (table[, run][, counters]). Returns
+    """Serve-plane runner for the store engine, its normal path:
+    jit(scan(step . gen)) over carry (table[, run][, counters]). Returns
     (run, init, drain) under the ServeEngine contract:
-      run(carry, key[, occ, shed]) -> (carry', stats [cohorts_per_block, 2])
+      run(carry, key[, occ, shed]) -> (carry', stats [cohorts_per_block,
+                                                      N_STATS])
       init(db)   -> carry (attaches the ordered run when use_scan)
-      drain(carry) -> (db, stats [1, 2][, counters])
+      drain(carry) -> (db, stats [1, N_STATS][, counters])
 
-    Cohorts are generated ON DEVICE from the block key: YCSB-E-shaped —
-    ``scan_frac`` of lanes issue Op.SCAN with uniform lengths in
-    [1, max_scan_len] (engine clips to ``scan_max``); the rest split
-    ``read_frac`` GET / else SET, keys drawn with the store benchmark's
-    hot-prefix skew (hot head == smallest ids, the zipf_keys alignment).
-    Stats rows are (attempted, committed): attempted = admitted lanes,
+    Cohorts are generated ON DEVICE from the block key by
+    ``build_generator`` (which see: ``read_frac``, ``theta``, the
+    hot-prefix skew, YCSB-E-shaped scans of ``scan_frac`` of the lanes
+    with uniform lengths in [1, max_scan_len], clipped by the engine to
+    ``scan_max``); a block splits its key into one per step. Stats rows
+    are ``STAT_NAMES`` (``reply_stats``): attempted = admitted lanes,
     committed = VAL/ACK replies (stale-scan RETRYs are NOT committed —
     the client re-sends after the rebuild).
 
@@ -363,69 +555,63 @@ def build_serve_runner(n_keys: int, w: int = 4096,
     store engine has no txn ring.
     """
     del trace
-    from ..clients import workloads as wl
     from ..monitor import counters as mon
     use_scan = run_mod.resolve_use_scan(use_scan)
-    hfrac = wl.SB_HOT_FRAC if hot_frac is None else float(hot_frac)
-    hprob = wl.SB_HOT_PROB if hot_prob is None else float(hot_prob)
-    hot_n = max(1, min(int(n_keys * hfrac), n_keys))
     if not use_scan:
         scan_frac = 0.0
-
-    def gen(key, occ):
-        """One on-device cohort: (Batch, admitted, n_scan_lanes)."""
-        ks = jax.random.split(key, 6)
-        lane = jnp.arange(w, dtype=I32)
-        admitted = lane < occ
-        is_scan = (jax.random.uniform(ks[0], (w,)) < scan_frac) \
-            if scan_frac > 0.0 else jnp.zeros((w,), bool)
-        is_get = ~is_scan & (jax.random.uniform(ks[1], (w,)) < read_frac)
-        hot = jax.random.uniform(ks[2], (w,)) < hprob
-        klo = jnp.where(
-            hot, jax.random.randint(ks[3], (w,), 1, hot_n + 1),
-            jax.random.randint(ks[4], (w,), 1, n_keys + 1)).astype(U32)
-        op = jnp.where(is_scan, I32(Op.SCAN),
-                       jnp.where(is_get, I32(Op.GET), I32(Op.SET)))
-        op = jnp.where(admitted, op, I32(Op.NOP))
-        klo = jnp.where(admitted, klo, U32(0xFFFFFFFF))
-        khi = jnp.where(admitted, U32(0), U32(0xFFFFFFFF))
-        val = jnp.zeros((w, val_words), U32)
-        val = val.at[:, 0].set(klo).at[:, 1].set(U32(STORE_MAGIC))
-        slen = jax.random.randint(ks[5], (w,), 1, max_scan_len + 1)
-        ver = jnp.where(admitted & is_scan, slen.astype(U32), U32(0))
-        batch = Batch(op=op, table=jnp.zeros((w,), I32), key_hi=khi,
-                      key_lo=klo, val=val, ver=ver)
-        return batch, admitted, (admitted & is_scan)
+    gen = build_generator(n_keys, w, val_words=val_words,
+                          read_frac=read_frac, theta=theta,
+                          scan_frac=scan_frac, max_scan_len=max_scan_len,
+                          hot_frac=hot_frac, hot_prob=hot_prob)
 
     def scan_fn(carry, x):
         key, occ, shed = x if serve else (x, None, None)
-        occ = jnp.asarray(w, I32) if occ is None else occ
-        shed = I32(0) if shed is None else shed
         table = carry[0]
         run = carry[1] if use_scan else None
         cnt = carry[-1] if monitor else None
-        batch, admitted, scan_lanes = gen(key, occ)
+        with waves.part("store", "store_gen"):
+            occ = jnp.asarray(w, I32) if occ is None else occ
+            shed = I32(0) if shed is None else shed
+            batch = gen(key, occ)
         if use_scan:
             table, rep, run, srep = step(table, batch, run=run,
                                          scan_max=scan_max)
         else:
             table, rep = step(table, batch)
             srep = None
-        committed = (admitted
-                     & ((rep.rtype == Reply.VAL)
-                        | (rep.rtype == Reply.ACK))).sum(dtype=I32)
-        stats = jnp.stack([occ, committed])
-        cnt = mon.bump(cnt, {
-            mon.CTR_STEPS: 1,
-            mon.CTR_SERVE_OCC_LANES: occ,
-            mon.CTR_SERVE_PAD_LANES: jnp.asarray(w, I32) - occ,
-            mon.CTR_SERVE_SHED_LANES: shed,
-            mon.CTR_DISPATCH_XLA: 1,
-            **({mon.CTR_SCAN_REQUESTS: scan_lanes.sum(dtype=I32),
-                mon.CTR_SCAN_ROWS: srep.count.sum(dtype=I32),
-                mon.CTR_SCAN_DELTA_HITS: srep.delta_hits.sum(dtype=I32)}
-               if use_scan else {}),
-        })
+        with waves.part("store", "stats"):
+            stats = reply_stats(batch, rep)
+        if monitor:
+            with waves.part("store", "monitor"):
+                # lanes whose key an earlier lane of the step carries: the
+                # admitted lanes less their distinct keys (admitted keys
+                # have key_hi == 0 and sort before the padding's)
+                skey = jnp.sort(batch.key_lo)
+                fresh = jnp.concatenate(
+                    [jnp.ones((1,), bool), skey[1:] != skey[:-1]])
+                distinct = (fresh & (jnp.arange(w, dtype=I32) < occ)
+                            ).sum(dtype=I32)
+                cnt = mon.bump(cnt, {
+                    mon.CTR_STEPS: 1,
+                    mon.CTR_TXN_ATTEMPTED: stats[STAT_ATTEMPTED],
+                    mon.CTR_TXN_COMMITTED: stats[STAT_COMMITTED],
+                    mon.CTR_MAGIC_BAD: stats[STAT_MAGIC_BAD],
+                    mon.CTR_SERVE_OCC_LANES: occ,
+                    mon.CTR_SERVE_PAD_LANES: jnp.asarray(w, I32) - occ,
+                    mon.CTR_SERVE_SHED_LANES: shed,
+                    mon.CTR_DISPATCH_XLA: 1,
+                    mon.CTR_STORE_GETS: stats[STAT_GETS],
+                    mon.CTR_STORE_UPDATES: stats[STAT_UPDATES],
+                    mon.CTR_STORE_NOT_EXIST: stats[STAT_NOT_EXIST],
+                    mon.CTR_STORE_SPILL: stats[STAT_SPILL],
+                    mon.CTR_STORE_DUP_LANES: occ - distinct,
+                    **({mon.CTR_SCAN_REQUESTS:
+                        (batch.op == Op.SCAN).sum(dtype=I32),
+                        mon.CTR_SCAN_ROWS: srep.count.sum(dtype=I32),
+                        mon.CTR_SCAN_DELTA_HITS:
+                        srep.delta_hits.sum(dtype=I32)}
+                       if use_scan else {}),
+                })
         out = (table,) + ((run,) if use_scan else ()) \
             + ((cnt,) if monitor else ())
         return out, stats
@@ -438,15 +624,18 @@ def build_serve_runner(n_keys: int, w: int = 4096,
                      + carry[2:])
         return carry
 
+    def _keys(key):
+        with waves.part("store", "block_pre"):
+            return jax.random.split(key, cohorts_per_block)
+
     if serve:
         def block(carry, key, occ, shed):
-            keys = jax.random.split(key, cohorts_per_block)
-            carry, stats = jax.lax.scan(scan_fn, carry, (keys, occ, shed))
+            carry, stats = jax.lax.scan(scan_fn, carry,
+                                        (_keys(key), occ, shed))
             return _post(carry), stats
     else:
         def block(carry, key):
-            keys = jax.random.split(key, cohorts_per_block)
-            carry, stats = jax.lax.scan(scan_fn, carry, keys)
+            carry, stats = jax.lax.scan(scan_fn, carry, _keys(key))
             return _post(carry), stats
 
     def init(db):
@@ -470,8 +659,54 @@ def build_serve_runner(n_keys: int, w: int = 4096,
         # is derived state — dropped here, re-snapshot at next attach
         table = carry[0]
         cnt = carry[-1] if monitor else None
-        zero = jnp.zeros((1, 2), I32)
+        zero = jnp.zeros((1, N_STATS), I32)
         return (table, zero) + ((cnt,) if monitor else ())
 
     init.trace_cfg = None
     return jax.jit(block, donate_argnums=0), init, drain
+
+
+# ------------------------------------------------- populate on the device
+
+
+def build_populate(n_keys: int, n_buckets: int, w: int,
+                   val_words: int = 10, slots: int = 4):
+    """``populate() -> (table, spilled)``: keys 1..n_keys loaded on the
+    device through the engine's own INSERT path, as the reference loads
+    its server (store/ebpf/client_ebpf.cc:137 PopulateThread: INSERTs
+    over the network, each thread its own contiguous range). Value word 0
+    the key, word 1 STORE_MAGIC, version 1. Lane j is a populate thread
+    over keys [j * steps + 1, (j + 1) * steps], ``steps = ceil(n_keys /
+    w)``, so step i inserts key ``j * steps + i + 1`` in lane j (NOP past
+    n_keys). Nothing table-sized is made on, or crosses to, the host.
+
+    ``spilled`` (i32 scalar) counts INSERTs that found both candidate
+    buckets full: such a key is in no table, so a deployment must hold
+    it to zero. The greedy two-choice insert moves no resident key, and
+    at load 0.36 of 2^24 x 4 slots about one key in 24 M finds both its
+    buckets full, which one depending on the order of arrival (PERF.md
+    section 6, PR 39)."""
+    steps = -(-n_keys // w)
+
+    def insert(table, i):
+        klo = jnp.arange(w, dtype=U32) * U32(steps) + i.astype(U32) + U32(1)
+        live = klo <= U32(n_keys)
+        val = jnp.zeros((w, val_words), U32)
+        val = val.at[:, 0].set(klo).at[:, 1].set(U32(STORE_MAGIC))
+        batch = Batch(
+            op=jnp.where(live, I32(Op.INSERT), I32(Op.NOP)),
+            table=jnp.zeros((w,), I32),
+            key_hi=jnp.where(live, U32(0), U32(0xFFFFFFFF)),
+            key_lo=jnp.where(live, klo, U32(0xFFFFFFFF)),
+            val=val, ver=jnp.zeros((w,), U32))
+        table, rep = step(table, batch)
+        return table, (live & (rep.rtype != Reply.ACK)).sum(dtype=I32)
+
+    @jax.jit
+    def populate():
+        table = kv.create(n_buckets, slots=slots, val_words=val_words)
+        table, bad = jax.lax.scan(insert, table,
+                                  jnp.arange(steps, dtype=I32))
+        return table, bad.sum()
+
+    return populate

@@ -326,7 +326,10 @@ def scope(engine: str, wave: str):
 
 # --------------------------------------------------------------- the parts
 PART_PREFIX = "part"
-_DENSE = ("tatp_dense", "smallbank_dense")    # the engine-neutral parts
+_DENSE = ("tatp_dense", "smallbank_dense")
+# the engine-neutral parts: each engine whose runner steps a block opens
+# them in its own step
+_STEPPED = _DENSE + ("store",)
 
 # (owner, wave | None, part, doc). The owner is the engine, or the shared
 # module ("log" = tables/log.py), whose code opens the part, or a tuple of
@@ -368,13 +371,13 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
      "out of the [w, K] meta words read"),
     ("tatp_dense", None, "classify",
      "reply types, classify_wave1 and the new cohort's context"),
-    (_DENSE, None, "monitor",
+    (_STEPPED, None, "monitor",
      "everything the step does only because the counter plane (or the "
      "flight recorder) is threaded: the reductions, the scatter-add, "
      "the gauge max (monitor_ms.* reads this)"),
-    (_DENSE, None, "stats",
+    (_STEPPED, None, "stats",
      "the completing cohort's stats vector"),
-    (_DENSE, None, "block_pre",
+    (_STEPPED, None, "block_pre",
      "block prologue: per-step key split, the stamp-rebase cond, the "
      "flight recorder's ring reset"),
     # --- tables/log.py append_rep, under whichever wave calls it --------
@@ -442,6 +445,36 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
      "append), the install's chunk loop, each chunk's lane search (C x "
      "2w compares) and its gathers of row ids, meta words and value rows "
      "out of the 2w-wide record"),
+    # --- the KV store (engines/store.py step + build_serve_runner),
+    # --- appended with the cell store-ycsb-b (PR 39): every equation of
+    # --- the point block carries a wave or one of these ------------------
+    ("store", None, "store_gen",
+     "on-device cohort generation: the op mix, the key draw (YCSB's "
+     "Zipfian in closed form, or the hot-prefix skew) and the [w, VW] "
+     "values an update writes"),
+    ("store", None, "key_sort",
+     "the same-key serialisation: sort_batch (one 3-key lax.sort of w "
+     "lanes), the segment sums / maxes / cummax that resolve a key's "
+     "lanes in arrival order, the writer election, and the unsorts back "
+     "to lane order (key_sort_ms.* reads this)"),
+    ("store", None, "slot_alloc",
+     "slot allocation for inserts, phases B and B2: two more sorts (by "
+     "destination and by alternate bucket), the [w, S] `valid` gathers, "
+     "the n_buckets-wide `taken` fill + scatter-add"),
+    ("store", None, "reply_build",
+     "reply types, values and versions in sorted space, and the spill "
+     "fix-up of a key's whole segment"),
+    ("store", "probe", "probe_keys",
+     "the bucket hash and the [w, S] key_hi / key_lo / valid gathers of "
+     "both candidate buckets, with the match and the free counts"),
+    ("store", "probe", "probe_val",
+     "the hit entry's VW value words and its version, gathered"),
+    ("store", "install", "kv_val_scatter",
+     "unique-index scatter of the writers' w x VW single value words "
+     "into the 1-D val array, with its flat index"),
+    ("store", "install", "kv_meta_scatter",
+     "the entry index and the unique-index scatters of valid, version, "
+     "key_hi and key_lo, w lanes each"),
 )
 
 # keyed on the part's name alone: the scope is `part.<name>`, so two

@@ -22,9 +22,11 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from dint_tpu.engines import smallbank_dense as sd
+from dint_tpu.engines import store
 from dint_tpu.engines import tatp_dense as td
 from dint_tpu.ops import compact
 from dint_tpu.parallel import dense_sharded as ds
+from dint_tpu.tables import kv
 
 HBM_BYTES = 16e9                 # one v5e chip
 N_SUB, W, CPB, VW = 7_000_000, 8192, 16, 10
@@ -220,6 +222,79 @@ def test_smallbank24m_block_program_fits_one_chip(one_chip):
             # before it fills the second: one is live, not both (ISSUE 33
             # reckoned 268 MB); the drain has no requests to arbitrate
             assert ma.temp_size_in_bytes >= 4 * (1 << 25)
+
+
+def test_store24m_block_program_fits_one_chip(one_chip):
+    """The `store-ycsb-b` cell's programs at the deployment's scale:
+    24,000,000 keys of 10 words in 2^24 buckets x 4 slots, w=8192 x 16
+    steps, YCSB-B's mix and Zipfian. Pins what PERF.md reckons with: 3.69
+    GB of table donated and updated in place, next to no temporaries (the
+    n_buckets-wide `taken` fill is one), and which scatters the compiler
+    sorts: the fact the first `perf_opt` PR in this cell starts from."""
+    n_keys, nb, slots = 24_000_000, 1 << 24, 4
+    ne = nb * slots
+    run, init, drain = store.build_serve_runner(
+        n_keys, w=W, cohorts_per_block=CPB, val_words=VW, read_frac=0.95,
+        theta=0.99, monitor=True)
+    carry = placed(jax.eval_shape(lambda: init(kv.create(nb))), one_chip)
+    key = placed(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+    # key_hi, key_lo, version, VW value words, a valid byte an entry; two
+    # bloom words a bucket
+    state = ne * (4 + 4 + 4 + 4 * VW + 1) + nb * 8
+    assert 3.69e9 < state < 3.70e9
+    for fn, args in ((run, (carry, key)), (drain, (carry,))):
+        c, ma = compiled_bytes(fn, *args)
+        assert state <= ma.argument_size_in_bytes < state + 2e6
+        assert ma.alias_size_in_bytes > 0.99 * ma.argument_size_in_bytes
+        assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+        assert ma.temp_size_in_bytes < 0.2e9
+        if fn is drain:
+            continue
+        hlo = c.as_text()
+        sorts = re.findall(r' sort\([^\n]*op_name="([^"]*)"', hlo)
+        # the program's own four: the batch by key, twice by bucket for
+        # the slot allocation, and the counter plane's distinct-key count
+        own = [n for n in sorts if n.endswith("/sort")]
+        assert sorted(n.split("closed_call/")[-1] for n in own) == [
+            "part.key_sort/sort", "part.monitor/jit(sort)/sort",
+            "part.slot_alloc/sort", "part.slot_alloc/sort"]
+        # the compiler's: before the unsorts (w indices into w words, as
+        # dense as a scatter gets), and before NONE of the five install
+        # scatters (w indices, or w x VW, into 67 M / 671 M words: one
+        # index per 8,192 words, against the ~1,630 at which it sorts)
+        # nor the `taken` scatter-add, whose indices repeat
+        assert len(sorts) > len(own)
+        assert all(n.endswith(("part.key_sort/scatter",
+                               "part.slot_alloc/scatter"))
+                   for n in sorts if n not in own)
+        installs = re.findall(
+            rf"(?:u32|pred)\[(?:{ne}|{ne * VW})\]\S* scatter\([^\n]*", hlo)
+        assert len(installs) == 5
+        assert not any("indices_are_sorted=true" in x for x in installs)
+        assert sum("part.kv_val_scatter" in x for x in installs) == 1
+        assert sum("part.kv_meta_scatter" in x for x in installs) == 4
+        assert scatter_index_counts(hlo, ne) == [W] * 3
+        assert scatter_index_counts(hlo, ne * VW) == [W * VW]
+        taken, = re.findall(rf"s32\[{nb + 1}\]\S* scatter\([^\n]*", hlo)
+        assert "part.slot_alloc/scatter-add" in taken
+        assert "indices_are_sorted=true" not in taken
+        # the probe reads both buckets' four slots of three arrays, then
+        # one value and one version a lane
+        assert sorted(gather_lane_counts(hlo, ne)) == [W] + [W * slots] * 4
+        assert gather_lane_counts(hlo, ne * VW) == [W * VW]
+
+
+def test_store24m_populate_fits_one_chip(one_chip):
+    """The populate through the INSERT path at the configuration's 65,536
+    lanes a step: the whole table is its output, made on the device."""
+    n_keys, nb = 24_000_000, 1 << 24
+    populate = store.build_populate(n_keys, nb, 65_536, val_words=VW)
+    # no argument to place: the outputs' sharding names the device
+    c, ma = compiled_bytes(jax.jit(populate.__wrapped__,
+                                   out_shardings=one_chip))
+    assert 3.69e9 < ma.output_size_in_bytes < 3.70e9
+    assert ma.output_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+    assert ma.temp_size_in_bytes < 0.5e9
 
 
 @pytest.mark.parametrize("table_words, lanes, dense", [

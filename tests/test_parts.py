@@ -20,9 +20,11 @@ from benchmarks import trace_reduce
 from dint_tpu import _runtime
 from dint_tpu.analysis import cost
 from dint_tpu.engines import smallbank_dense as sd
+from dint_tpu.engines import store
 from dint_tpu.engines import tatp_dense as td
 from dint_tpu.monitor import waves
 from dint_tpu.parallel import dense_sharded as ds
+from dint_tpu.tables import kv
 
 pytestmark = pytest.mark.scope
 
@@ -97,8 +99,11 @@ def test_part_rejects_unregistered_name():
 
 
 def test_an_engine_neutral_part_is_shared_by_the_dense_engines_alone():
+    """... and, since the cell store-ycsb-b, by the KV store's runner,
+    which steps a block as they do (engines/store.py)."""
     for name in ("monitor", "stats", "block_pre"):
-        assert waves.PART_OWNERS[name] == ("tatp_dense", "smallbank_dense")
+        assert waves.PART_OWNERS[name] == ("tatp_dense", "smallbank_dense",
+                                           "store")
         for owner in waves.PART_OWNERS[name]:
             with waves.part(owner, name):
                 pass
@@ -360,5 +365,66 @@ def test_replicate_parts_are_semantics_neutral(monkeypatch):
     b = run_once()
     ds.build_sharded_pipelined_runner.cache.clear()
     td.build_pipelined_runner.cache.clear()
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(x, y)
+
+
+# ------------------------------------------------ the KV store's block
+
+STORE_PARTS = ("store_gen", "key_sort", "slot_alloc", "reply_build",
+               "probe_keys", "probe_val", "kv_val_scatter",
+               "kv_meta_scatter")
+
+
+def _store(monitor=True, theta=0.99):
+    run, init, drain = store.build_serve_runner(
+        N_SUB, w=W, cohorts_per_block=CPB, val_words=VW, read_frac=0.95,
+        theta=theta, use_scan=False, monitor=monitor)
+    table, spilled = store.build_populate(N_SUB, 2048, 256,
+                                          val_words=VW)()
+    assert int(spilled) == 0
+    return run, init(table), drain
+
+
+def test_the_store_parts_are_registered_under_their_waves():
+    rows = {p: (o, w) for o, w, p, _ in waves._PARTS}
+    assert [rows[p] for p in STORE_PARTS] == [
+        ("store", None)] * 4 + [("store", "probe")] * 2 + [
+        ("store", "install")] * 2
+    with pytest.raises(KeyError, match="part registry"):
+        waves.part("tatp_dense", "key_sort")
+
+
+def test_every_store_part_reaches_compiled_hlo_under_its_wave():
+    run, carry, _ = _store()
+    names = _op_names(run.lower(carry,
+                                jax.random.PRNGKey(0)).compile().as_text())
+    _assert_parts_under_their_waves(names, ("store",))
+
+
+@pytest.mark.parametrize("theta", [0.99, None], ids=["zipfian", "hot"])
+@pytest.mark.parametrize("monitor", [True, False])
+def test_every_equation_of_the_store_block_carries_a_wave_or_a_part(
+        monitor, theta):
+    """The point block (``scan(step . gen)``, no scans, no hot tier), with
+    either generator: ``unnamed_ms.kv`` is left what XLA inserts."""
+    run, carry, _ = _store(monitor, theta)
+    closed = jax.make_jaxpr(run)(carry, jax.random.PRNGKey(0))
+    assert _unnamed_equations(closed.jaxpr, False, (), []) == []
+
+
+def test_store_parts_are_semantics_neutral(monkeypatch):
+    def run_once():
+        run, carry, drain = _store()
+        carry, stats = run(carry, jax.random.PRNGKey(3))
+        table, tail, counters = drain(carry)
+        return [np.asarray(x) for x in (
+            stats, tail, counters.buf, *jax.tree.leaves(table))]
+
+    a = run_once()
+    monkeypatch.setattr(waves, "part",
+                        lambda owner, name: contextlib.nullcontext())
+    b = run_once()
+    assert a[0][:, 1].sum() > 0
     for x, y in zip(a, b):
         np.testing.assert_array_equal(x, y)
