@@ -1000,8 +1000,17 @@ _ST_DCAP = 8           # delta overlay capacity (window = sl + dc rows)
 _ST_GEOM = dict(w=_W, vw=_VW, sl=_ST_SMAX, dc=_ST_DCAP, lg=7)
 
 
+# 2^14 buckets x 4 slots = 4,096 entries a lane of the block's 16: past
+# the density at which a full-width scatter is sorted, so this block's
+# install is the compacted one (engines/store.install_is_compacted), as
+# the store-ycsb-b cell's is; the five targets above trace 16 buckets and
+# the full-width install. Abstract: nothing table-sized is made
+_ST_NB_COMPACT = 1 << 14
+
+
 def _store_runner(name: str, use_scan: bool, monitor: bool = False,
-                  serve: bool = False) -> TargetTrace:
+                  serve: bool = False, n_buckets: int = _ST_NB
+                  ) -> TargetTrace:
     from ..engines import store
     from ..tables import kv
     run, init, _ = store.build_serve_runner(
@@ -1009,7 +1018,7 @@ def _store_runner(name: str, use_scan: bool, monitor: bool = False,
         scan_frac=0.5 if use_scan else 0.0, max_scan_len=_ST_SMAX,
         scan_max=_ST_SMAX, delta_cap=_ST_DCAP, use_scan=use_scan,
         monitor=monitor, serve=serve)
-    carry = _abstract(lambda: init(kv.create(_ST_NB, val_words=_VW)))
+    carry = _abstract(lambda: init(kv.create(n_buckets, val_words=_VW)))
     args = (carry, _key_aval())
     if serve:
         args += (_occ_aval(), _occ_aval())
@@ -1022,6 +1031,17 @@ def _store_runner(name: str, use_scan: bool, monitor: bool = False,
                  protocol=('server', 'elected'))
 def _t_store_block() -> TargetTrace:
     return _store_runner("store/block", use_scan=False)
+
+
+@register_target("store/block@compact",
+                 "KV store block, point ops, at a table sparse enough "
+                 "that the install issues the elected writers in chunks "
+                 "(ops/compact.py): the in-loop scatters must still "
+                 "descend from the election and certify unique indices",
+                 protocol=('server', 'elected'))
+def _t_store_block_compact() -> TargetTrace:
+    return _store_runner("store/block@compact", use_scan=False,
+                         n_buckets=_ST_NB_COMPACT)
 
 
 @register_target("store/block@scan",
@@ -1087,6 +1107,13 @@ TARGET_SCAN_TWIN: dict[str, str] = {
 # pays +1 dispatch and +32 B/step for the counter scatter-add.
 TARGET_COST.update({
     "store/block": _cost(_ST_GEOM, 15, 2072, bytes_budget=2200),
+    # the compacted install (PR 40): one trip of each chunk loop is
+    # priced, at a geometry where a chunk is all w lanes, so dispatches
+    # and bytes are store/block's (a chunk's gathers out of lane space
+    # read temporaries, which the walker does not price). The footprint
+    # is the 2^14-bucket table's
+    "store/block@compact": _cost(_ST_GEOM, 15, 2031704,
+                                 bytes_budget=2200),
     "store/block@scan": _cost(_ST_GEOM, 35.5, 4141, bytes_budget=11700),
     "store/serve@scan": _cost(_ST_GEOM, 35.5, 4157, bytes_budget=11700),
     "store/serve@scan+mon": _cost(_ST_GEOM, 36.5, 4337,

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..monitor import waves
-from ..ops import hashing, segments
+from ..ops import compact, hashing, segments
 from ..ops import hotset
 from ..tables import kv
 from ..tables import run as run_mod
@@ -70,6 +70,20 @@ def attach_hot(table: kv.KVTable, hot_n: int) -> HotKV:
                  ver=jnp.where(hit, ver, U32(0)))
 
 
+def install_is_compacted(table: kv.KVTable, r: int) -> bool:
+    """Whether ``step`` compacts the install of an ``r``-lane batch into
+    ``table`` (no hot tier): where the full-width scatters are too sparse
+    for the compiler to sort them first (``compact.compiler_sorts``: more
+    than SORTED_SCATTER_WORDS_PER_LANE entries a lane; the ratio is the
+    same for all five arrays). A rule in the shapes alone. The
+    populate's 65,536 lanes into 2^26 entries are dense and keep the
+    full-width scatters, sorted at ~20 ns a lane; the serve block's 8,192
+    are not, and a lane there costs ~88 ns landed or dropped (PERF.md
+    section 6, PR 39, PR 40)."""
+    ne = table.n_buckets * table.slots
+    return not compact.compiler_sorts(ne, r)
+
+
 def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
          hot: HotKV | None = None,
          run: run_mod.OrderedRun | None = None, scan_max: int = 8):
@@ -95,7 +109,86 @@ def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
     lane's Replies slot carries VAL + the row count in `ver` (RETRY when
     the run is stale); rows land in the ScanReplies slab, at most
     ``scan_max`` (static) per lane, request length in ``batch.ver``.
+
+    The install issues the step's elected writers, not its lanes, where
+    the shapes make a lane dear (``install_is_compacted``; the same table
+    and replies either way, tests/test_store_compact.py).
     """
+    return _step(table, batch, maintain_bloom=maintain_bloom, hot=hot,
+                 run=run, scan_max=scan_max)[0]
+
+
+def _install_live(table: kv.KVTable, o_upd, ok, o_del, o_bkt, o_slot0,
+                  slot_new, o_khi, o_klo, o_val, o_ver):
+    """The install over the elected writers alone (ops/compact.py): the
+    table the five full-width scatters of ``_step`` leave, bit for bit,
+    and (trips, writers) of the first of two chunk loops.
+
+    The first loop carries ``val`` and ``ver`` and issues the lanes that
+    install a record (``o_upd | ok``), C = ``chunk_lanes(r)`` a trip. The
+    second carries ``valid``, ``key_hi`` and ``key_lo`` and issues the
+    lanes that allocate or free a slot (``ok | o_del``): an update hits an
+    entry that is valid and holds its key already (``kv.probe_loc``: a hit
+    is valid AND key-equal), so the full-width path writes those three
+    words onto themselves there. Under a GET / SET mix over resident keys
+    it makes no trip. A loop carries the arrays it writes and closes over
+    lane-space vectors only: no second copy of a table."""
+    r = o_upd.shape[0]
+    s = table.slots
+    ne = table.n_buckets * s
+    chunk = compact.chunk_lanes(r)
+    with waves.part("store", "kv_meta_scatter"):
+        wv = o_upd | ok
+        slot_w = ok | o_del
+        e_w = o_bkt * s + jnp.where(ok, slot_new, o_slot0)
+    with waves.part("store", "kv_compact"):
+        ranks, n_live = compact.live_ranks(wv)
+
+        def record_chunk(tabs, lanes, on):
+            val, ver = tabs
+            e_c = jnp.where(on, e_w[lanes], ne)
+            ver_c, val_c = o_ver[lanes], o_val[lanes]
+            with waves.part("store", "kv_val_scatter"):
+                val = val.at[kv.val_word_idx(table, e_c)].set(
+                    val_c.reshape(-1), mode="drop", unique_indices=True)
+            with waves.part("store", "kv_meta_scatter"):
+                ver = ver.at[e_c].set(ver_c, mode="drop",
+                                      unique_indices=True)
+            return val, ver
+
+        (val, ver), trips = compact.for_chunks(
+            ranks, n_live, chunk, record_chunk, (table.val, table.ver))
+
+        s_ranks, s_live = compact.live_ranks(slot_w)
+
+        def slot_chunk(tabs, lanes, on):
+            valid, key_hi, key_lo = tabs
+            e_c = jnp.where(on, e_w[lanes], ne)
+            alloc_c = ok[lanes]
+            e_k = jnp.where(alloc_c, e_c, ne)      # a delete's ride ne
+            khi_c, klo_c = o_khi[lanes], o_klo[lanes]
+            with waves.part("store", "kv_meta_scatter"):
+                valid = valid.at[e_c].set(alloc_c, mode="drop",
+                                          unique_indices=True)
+                key_hi = key_hi.at[e_k].set(khi_c, mode="drop",
+                                            unique_indices=True)
+                key_lo = key_lo.at[e_k].set(klo_c, mode="drop",
+                                            unique_indices=True)
+            return valid, key_hi, key_lo
+
+        (valid, key_hi, key_lo), _ = compact.for_chunks(
+            s_ranks, s_live, chunk, slot_chunk,
+            (table.valid, table.key_hi, table.key_lo))
+    return table.replace(key_hi=key_hi, key_lo=key_lo, val=val, ver=ver,
+                         valid=valid), (trips, n_live)
+
+
+def _step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool,
+          hot: HotKV | None, run: run_mod.OrderedRun | None,
+          scan_max: int):
+    """``step``, and beside its result the compacted install's (chunk
+    trips, elected writers) as i32 scalars for the counter plane: None
+    where the install runs at full width."""
     r = batch.width
     with waves.part("store", "key_sort"):
         sb = segments.sort_batch(batch.key_hi, batch.key_lo)
@@ -233,46 +326,56 @@ def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
     # wflat / populate_device idx, smallbank_dense scatters.)
     ne = table.n_buckets * table.slots
     s = table.slots
+    live = None
     with waves.scope("store", "install"):
-        with waves.part("store", "kv_meta_scatter"):
-            w_any_slot = o_upd | ok | o_del
-            t_slot = jnp.where(o_upd | o_del, o_slot0, slot_new)
-            e_any = jnp.where(w_any_slot, o_bkt * s + t_slot, ne)
-            new_valid = table.valid.at[e_any].set(~o_del, mode="drop",
-                                                  unique_indices=True)
-            wv = (o_upd | ok)
-            sl_v = jnp.where(o_upd, o_slot0, slot_new)
-            e_v = jnp.where(wv, o_bkt * s + sl_v, ne)
-        if hot is None:
-            with waves.part("store", "kv_val_scatter"):
-                val_new = table.val.at[kv.val_word_idx(table, e_v)].set(
-                    o_val.reshape(-1), mode="drop", unique_indices=True)
-            with waves.part("store", "kv_meta_scatter"):
-                ver_new = table.ver.at[e_v].set(o_ver, mode="drop",
-                                                unique_indices=True)
+        if hot is None and install_is_compacted(table, r):
+            # the serve block's shapes: the elected writers in chunks
+            table, live = _install_live(
+                table, o_upd, ok, o_del, o_bkt, o_slot0, slot_new, o_khi,
+                o_klo, o_val, o_ver)
         else:
-            # write-through install: table entry AND key-indexed mirror.
-            # One writer per key segment,
-            # so entry AND mirror indices are unique among masked lanes.
-            w_midx = jnp.where(wv & (o_khi == U32(0))
-                               & (o_klo < U32(hot_n)),
-                               o_klo.astype(I32), -1)
-            e_w = o_bkt * s + sl_v
-            val_new, hot_val = hotset.hot_scatter(
-                table.val, hot.val, e_w, w_midx, wv, o_val.reshape(-1), vw)
-            ver_new, hot_ver = hotset.hot_scatter(
-                table.ver, hot.ver, e_w, w_midx, wv, o_ver, 1)
-            hot = hot.replace(val=hot_val, ver=hot_ver)
-        with waves.part("store", "kv_meta_scatter"):
-            table = table.replace(
-                key_hi=table.key_hi.at[e_v].set(o_khi, mode="drop",
-                                                unique_indices=True),
-                key_lo=table.key_lo.at[e_v].set(o_klo, mode="drop",
-                                                unique_indices=True),
-                val=val_new,
-                ver=ver_new,
-                valid=new_valid,
-            )
+            # every lane issued: the populate's shapes and the small
+            # ones (the compiler sorts these scatters, and a dropped lane
+            # is cheap then), and the hot tier's write-through
+            with waves.part("store", "kv_meta_scatter"):
+                w_any_slot = o_upd | ok | o_del
+                t_slot = jnp.where(o_upd | o_del, o_slot0, slot_new)
+                e_any = jnp.where(w_any_slot, o_bkt * s + t_slot, ne)
+                new_valid = table.valid.at[e_any].set(~o_del, mode="drop",
+                                                      unique_indices=True)
+                wv = (o_upd | ok)
+                sl_v = jnp.where(o_upd, o_slot0, slot_new)
+                e_v = jnp.where(wv, o_bkt * s + sl_v, ne)
+            if hot is None:
+                with waves.part("store", "kv_val_scatter"):
+                    val_new = table.val.at[kv.val_word_idx(table, e_v)].set(
+                        o_val.reshape(-1), mode="drop", unique_indices=True)
+                with waves.part("store", "kv_meta_scatter"):
+                    ver_new = table.ver.at[e_v].set(o_ver, mode="drop",
+                                                    unique_indices=True)
+            else:
+                # write-through install: table entry AND key-indexed mirror.
+                # One writer per key segment,
+                # so entry AND mirror indices are unique among masked lanes.
+                w_midx = jnp.where(wv & (o_khi == U32(0))
+                                   & (o_klo < U32(hot_n)),
+                                   o_klo.astype(I32), -1)
+                e_w = o_bkt * s + sl_v
+                val_new, hot_val = hotset.hot_scatter(
+                    table.val, hot.val, e_w, w_midx, wv, o_val.reshape(-1), vw)
+                ver_new, hot_ver = hotset.hot_scatter(
+                    table.ver, hot.ver, e_w, w_midx, wv, o_ver, 1)
+                hot = hot.replace(val=hot_val, ver=hot_ver)
+            with waves.part("store", "kv_meta_scatter"):
+                table = table.replace(
+                    key_hi=table.key_hi.at[e_v].set(o_khi, mode="drop",
+                                                    unique_indices=True),
+                    key_lo=table.key_lo.at[e_v].set(o_klo, mode="drop",
+                                                    unique_indices=True),
+                    val=val_new,
+                    ver=ver_new,
+                    valid=new_valid,
+                )
     if maintain_bloom:
         # recompute exactly for buckets whose membership changed
         table = kv.recompute_bloom(table, o_bkt, ok | o_del)
@@ -330,7 +433,7 @@ def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
         out = out + (hot,)
     if run is not None:
         out = out + (run, scan_rep)
-    return out
+    return out, live
 
 
 def rebuild_run(table: kv.KVTable, run: run_mod.OrderedRun):
@@ -573,12 +676,10 @@ def build_serve_runner(n_keys: int, w: int = 4096,
             occ = jnp.asarray(w, I32) if occ is None else occ
             shed = I32(0) if shed is None else shed
             batch = gen(key, occ)
-        if use_scan:
-            table, rep, run, srep = step(table, batch, run=run,
-                                         scan_max=scan_max)
-        else:
-            table, rep = step(table, batch)
-            srep = None
+        (table, rep, *scanned), live = _step(
+            table, batch, maintain_bloom=False, hot=None, run=run,
+            scan_max=scan_max)
+        run, srep = scanned if use_scan else (None, None)
         with waves.part("store", "stats"):
             stats = reply_stats(batch, rep)
         if monitor:
@@ -611,6 +712,11 @@ def build_serve_runner(n_keys: int, w: int = 4096,
                         mon.CTR_SCAN_DELTA_HITS:
                         srep.delta_hits.sum(dtype=I32)}
                        if use_scan else {}),
+                    # the compacted install's first loop (where the shapes
+                    # compact it): its trips, and the lanes it was for
+                    **({mon.CTR_INSTALL_CHUNKS: live[0],
+                        mon.CTR_INSTALL_WRITES: live[1]}
+                       if live is not None else {}),
                 })
         out = (table,) + ((run,) if use_scan else ()) \
             + ((cnt,) if monitor else ())

@@ -77,7 +77,14 @@ _REGISTRY: tuple[tuple[str, str, str], ...] = (
     ("validate_failed", FLOW,
      "validate lanes whose version compare failed"),
     ("install_writes", FLOW,
-     "rows installed at the commit wave (commit/insert/delete lanes)"),
+     "rows installed at the commit wave (commit/insert/delete lanes). "
+     "A second owner since PR 40: the KV store's runner (engines/store.py "
+     "build_serve_runner), where its install is compacted "
+     "(install_is_compacted), bumps it with the step's elected writers "
+     "that install a record (one a key written: updates and inserts that "
+     "found a slot), the lanes its first chunk loop issues; at the "
+     "shapes that keep the full-width install it bumps neither this nor "
+     "install_chunks"),
     ("log_appends", FLOW,
      "log entries appended (one per logical install; replicas not "
      "multiplied)"),
@@ -159,7 +166,12 @@ _REGISTRY: tuple[tuple[str, str, str], ...] = (
      "install_chunks == sum over steps of ceil(install_writes / C): one a "
      "step under TATP's mix, none for a step with nothing to write; "
      "install_writes / (C x install_chunks) is the fill share of the "
-     "indices the scatters issue. 0 on the hot-tier route"),
+     "indices the scatters issue. 0 on the hot-tier route. The KV "
+     "store's compacted install (PR 40) is the second owner: the trips "
+     "of its first loop (value words and versions), C = chunk_lanes(w), "
+     "with the same identity and fill share; its second loop (valid and "
+     "key words, for inserts and deletes only) is not counted, and makes "
+     "no trip under a GET / SET mix over resident keys"),
     ("lock_chunks", FLOW,
      "lock-wave compaction (ops/compact.py): chunk trips of the dense "
      "TATP lock wave's first loop (the second makes as many), C = "

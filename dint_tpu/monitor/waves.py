@@ -470,11 +470,21 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
     ("store", "probe", "probe_val",
      "the hit entry's VW value words and its version, gathered"),
     ("store", "install", "kv_val_scatter",
-     "unique-index scatter of the writers' w x VW single value words "
-     "into the 1-D val array, with its flat index"),
+     "unique-index scatter of the writers' single value words into the "
+     "1-D val array, with its flat index: w x VW at full width, C x VW a "
+     "chunk where the install is compacted"),
     ("store", "install", "kv_meta_scatter",
      "the entry index and the unique-index scatters of valid, version, "
-     "key_hi and key_lo, w lanes each"),
+     "key_hi and key_lo: w lanes each at full width, C a chunk where the "
+     "install is compacted"),
+    ("store", "install", "kv_compact",
+     "the compacted install (PR 40; where engines/store."
+     "install_is_compacted says so): the running counts of the elected "
+     "writers and of the lanes that allocate or free a slot (two "
+     "w-element cumsums), the two chunk loops, each chunk's lane search "
+     "(C x w compares) and its gathers of entry indices, versions, value "
+     "rows and key words out of lane space; kv_val_scatter and "
+     "kv_meta_scatter, C lanes a chunk, lie inside its loops"),
 )
 
 # keyed on the part's name alone: the scope is `part.<name>`, so two

@@ -8,17 +8,21 @@ scatter: each lane's turn among the live ones (``live_ranks``: one cumsum),
 then the scatter runs over fixed chunks of the live lanes, as many as the
 live count needs (``for_chunks``: a ``while_loop`` whose trip count the
 step itself observes: 0 trips when nothing is live, the whole width when
-everything is). All of it vector work. Three users: the install and the
+everything is). All of it vector work. Four users: the install and the
 log append of dense TATP under the write mask (PR 30; ``tables/log.
 append_rep_live``), its lock wave under the mask of active write slots
 (PR 34: ~11 % of the 2w; the stamp gather and the winner read-back are
 chunked with the scatter-max, since a gather lane on the sentinel row
 costs what a live one costs, and a chunk's verdicts go back to lane space
-through ``lanes_mask``), and the backups' apply of ``parallel/
+through ``lanes_mask``), the backups' apply of ``parallel/
 dense_sharded`` under the forwarded record's write mask (PR 38: the
 RECEIVER ranks the 2w lanes that arrive, once a hop, for the install into
 its backup slot and the append into its ring; the record itself crosses
-the mesh at full width). Tried on the chip and left
+the mesh at full width), and the KV store's install under the mask of a
+step's elected writers (PR 40, ``engines/store._install_live``: ~5 % of
+the lanes under YCSB-B; one loop for value words and versions, a second,
+idle under a GET / SET mix, for the words that change only when a slot is
+allocated or freed). Tried on the chip and left
 (PERF.md §6, PR 30): one sort of the lane ids, 0.11 ms a step faster in
 ``tatp7m-sat``, but the protocol proofs read a sort as the generic
 engines' segment evidence (analysis/dataflow.py SORTED) and would have
@@ -33,7 +37,12 @@ a 1-D scatter's (index, value) pairs itself, and then issues a lane at
 words. So a scatter whose mask is half live (``smallbank_dense``'s
 install: compaction's lane search would cost what it saves) issues
 ``sorted_scatter_lanes`` lanes, the added ones out of bounds: the sort is
-the compiler's, in no jaxpr, and the proofs read what they read.
+the compiler's, in no jaxpr, and the proofs read what they read. The same
+density decides, for a second user, which form one algorithm takes
+(``compiler_sorts``; PR 40): the KV store's ``step`` keeps its full-width
+scatters where they are that dense already (the populate: 65,536 all-live
+lanes into 2^26 entries, 0.90 us a key) and compacts them where they are
+not (the serve block: 8,192 lanes, 95 % of them dead).
 """
 from __future__ import annotations
 
@@ -60,6 +69,13 @@ def chunk_lanes(r: int) -> int:
 SORTED_SCATTER_WORDS_PER_LANE = 1536
 
 
+def compiler_sorts(table_words: int, lanes: int) -> bool:
+    """Whether a 1-D scatter of ``lanes`` indices into ``table_words``
+    words is dense enough, as it stands, that the compiler sorts its
+    indices first."""
+    return table_words <= lanes * SORTED_SCATTER_WORDS_PER_LANE
+
+
 def sorted_scatter_lanes(table_words: int, lanes: int) -> int:
     """Lanes a 1-D scatter of ``lanes`` indices into ``table_words`` words
     issues so that the compiler sorts its indices first: ``lanes`` where
@@ -68,7 +84,7 @@ def sorted_scatter_lanes(table_words: int, lanes: int) -> int:
     lanes: 31,360); and ``lanes``, no fill, where that would more than
     double them (a 512-lane chunk into a 70 M-word table is not this
     case). The caller routes the added lanes out of bounds."""
-    if table_words <= lanes * SORTED_SCATTER_WORDS_PER_LANE:
+    if compiler_sorts(table_words, lanes):
         return lanes
     dense = -(-table_words // (SORTED_SCATTER_WORDS_PER_LANE * 128)) * 128
     return dense if dense <= 2 * lanes else lanes

@@ -229,8 +229,11 @@ def test_store24m_block_program_fits_one_chip(one_chip):
     24,000,000 keys of 10 words in 2^24 buckets x 4 slots, w=8192 x 16
     steps, YCSB-B's mix and Zipfian. Pins what PERF.md reckons with: 3.69
     GB of table donated and updated in place, next to no temporaries (the
-    n_buckets-wide `taken` fill is one), and which scatters the compiler
-    sorts: the fact the first `perf_opt` PR in this cell starts from."""
+    n_buckets-wide `taken` fill is one), which scatters the compiler
+    sorts, and, since PR 40, that the install issues a chunk of the
+    step's elected writers from inside its loops, not all w lanes: the
+    three bounds on arguments, aliases and temporaries are what would
+    catch a loop that copied a table."""
     n_keys, nb, slots = 24_000_000, 1 << 24, 4
     ne = nb * slots
     run, init, drain = store.build_serve_runner(
@@ -260,21 +263,30 @@ def test_store24m_block_program_fits_one_chip(one_chip):
             "part.slot_alloc/sort", "part.slot_alloc/sort"]
         # the compiler's: before the unsorts (w indices into w words, as
         # dense as a scatter gets), and before NONE of the five install
-        # scatters (w indices, or w x VW, into 67 M / 671 M words: one
-        # index per 8,192 words, against the ~1,630 at which it sorts)
-        # nor the `taken` scatter-add, whose indices repeat
+        # scatters nor the `taken` scatter-add, whose indices repeat. At
+        # full width the five issued w indices, or w x VW, into 67 M /
+        # 671 M words: one per 8,192 words, against the ~1,630 at which
+        # the compiler sorts, so the rule compacts them
+        # (store.install_is_compacted): each sits in the body of a chunk
+        # loop and issues C lanes, C x VW value words, of the elected
+        # writers
         assert len(sorts) > len(own)
         assert all(n.endswith(("part.key_sort/scatter",
                                "part.slot_alloc/scatter"))
                    for n in sorts if n not in own)
+        assert not compact.compiler_sorts(ne, W)
+        chunk = compact.chunk_lanes(W)
+        assert chunk == 256
         installs = re.findall(
             rf"(?:u32|pred)\[(?:{ne}|{ne * VW})\]\S* scatter\([^\n]*", hlo)
         assert len(installs) == 5
         assert not any("indices_are_sorted=true" in x for x in installs)
+        assert all("dint.store.install/part.kv_compact/while/body/" in x
+                   for x in installs)
         assert sum("part.kv_val_scatter" in x for x in installs) == 1
         assert sum("part.kv_meta_scatter" in x for x in installs) == 4
-        assert scatter_index_counts(hlo, ne) == [W] * 3
-        assert scatter_index_counts(hlo, ne * VW) == [W * VW]
+        assert scatter_index_counts(hlo, ne) == [chunk] * 3
+        assert scatter_index_counts(hlo, ne * VW) == [chunk * VW]
         taken, = re.findall(rf"s32\[{nb + 1}\]\S* scatter\([^\n]*", hlo)
         assert "part.slot_alloc/scatter-add" in taken
         assert "indices_are_sorted=true" not in taken

@@ -373,16 +373,22 @@ def test_replicate_parts_are_semantics_neutral(monkeypatch):
 
 STORE_PARTS = ("store_gen", "key_sort", "slot_alloc", "reply_build",
                "probe_keys", "probe_val", "kv_val_scatter",
-               "kv_meta_scatter")
+               "kv_meta_scatter", "kv_compact")
+# 2^15 buckets x 4 slots = 2,048 entries a lane of the block's 64: the
+# block's install is compacted (`kv_compact` is in the program), the
+# populate's, at 256 lanes, is not
+STORE_BUCKETS = 1 << 15
 
 
 def _store(monitor=True, theta=0.99):
     run, init, drain = store.build_serve_runner(
         N_SUB, w=W, cohorts_per_block=CPB, val_words=VW, read_frac=0.95,
         theta=theta, use_scan=False, monitor=monitor)
-    table, spilled = store.build_populate(N_SUB, 2048, 256,
+    table, spilled = store.build_populate(N_SUB, STORE_BUCKETS, 256,
                                           val_words=VW)()
     assert int(spilled) == 0
+    assert store.install_is_compacted(table, W)
+    assert not store.install_is_compacted(table, 256)
     return run, init(table), drain
 
 
@@ -390,7 +396,7 @@ def test_the_store_parts_are_registered_under_their_waves():
     rows = {p: (o, w) for o, w, p, _ in waves._PARTS}
     assert [rows[p] for p in STORE_PARTS] == [
         ("store", None)] * 4 + [("store", "probe")] * 2 + [
-        ("store", "install")] * 2
+        ("store", "install")] * 3
     with pytest.raises(KeyError, match="part registry"):
         waves.part("tatp_dense", "key_sort")
 
