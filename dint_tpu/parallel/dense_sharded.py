@@ -32,8 +32,20 @@ what pushes per-device HBM over the edge (SURVEY.md §6; two backup ranges
 per device). Backups hold val + ver:exists only — locks are volatile
 primary-side state, exactly like the reference's backup servers.
 
-Runs under one jitted shard_map step; tested on the virtual 8-device CPU
-mesh and exercised by __graft_entry__.dryrun_multichip.
+Runs under one jitted shard_map block, jit(shard_map(scan(step))). Between
+dispatches a device holds its ShardState as the scan carries it: every
+table, ring and backup leaf is a global [D * N, ...] array sharded over
+axis 0 (a device's [N, ...]), with NO stacked axis, so the block's
+parameters and results are the scan's own carry buffers. (A stacked
+[1, N] parameter is tiled otherwise than the scan's [N] carry: squeezing
+and unsqueezing it copied every table at the block's entry and exit, 5 ms
+of a 12.9 ms step on four chips, PERF.md PR 42.) `create_sharded`, the
+state `init` takes and the state `drain` returns are stacked [D, N, ...];
+`init` and `drain` convert. A flat leaf's global length passes 2^31 at 7 M
+subscribers: it is only ever touched inside a shard_map.
+
+Tested on the virtual 8-device CPU mesh and exercised by
+__graft_entry__.dryrun_multichip.
 """
 from __future__ import annotations
 
@@ -121,6 +133,17 @@ def create_sharded(mesh: Mesh, n_shards: int, n_sub_global: int,
                                  out_specs=P(SHARD_AXIS)))()
 
 
+def _with_step(state: ShardState, step) -> ShardState:
+    return state.replace(db=state.db.replace(step=step))
+
+
+def _map_tables(f, state: ShardState) -> ShardState:
+    """``f`` over every leaf of a ShardState but `db.step`, the one
+    per-device scalar: [D] (a device's [1]) in both forms of the carry."""
+    return _with_step(jax.tree.map(f, _with_step(state, None)),
+                      state.db.step)
+
+
 def _apply_backup(state: ShardState, inst: td.Installs, slot: int,
                   n1: int, val_words: int, src_dev,
                   chunks: list | None = None):
@@ -183,12 +206,23 @@ def build_sharded_pipelined_runner(mesh: Mesh, n_shards: int,
                                    cohorts_per_block: int = 8, mix=None,
                                    use_pallas=None, use_fused=None,
                                    monitor: bool = False):
-    """jit(shard_map(scan(step)))) over stacked carry. Same contract shape
+    """jit(shard_map(scan(step)))) over the carry. Same contract shape
     as the single-chip runner: returns (run, init, drain) where
       run(carry, key) -> (carry', stats [cohorts_per_block, N_STATS]
                           psummed across the mesh)
-      init(state)     -> carry with two bootstrap cohorts per device
-      drain(carry)    -> (state, stats [2, N_STATS]) flushing pipelines
+      init(state)     -> carry with two bootstrap cohorts per device;
+                         consumes the stacked ``state``
+      drain(carry)    -> (state, stats [2, N_STATS]) flushing pipelines;
+                         ``state`` stacked again, fit for another init
+
+    The carry is opaque to callers. `carry[0]` is the ShardState with no
+    stacked axis (module docstring): a leaf is [D * N, ...] sharded over
+    axis 0, `db.step` [D]. The contexts and the counters (`carry[1:]`,
+    kilobytes) stay stacked [D, ...]. `init` and `drain` convert between
+    the forms LEAF BY LEAF, one donated copy program a leaf shape: the
+    relayout cannot alias, so one program over the whole state would hold
+    it twice (10.6 GB a device at 7 M subscribers) where a leaf at a time
+    peaks at the state plus `bck_val` (8.4 GB).
 
     ``monitor``: thread the dintmon counter plane PER DEVICE — the carry
     grows a trailing stacked monitor.Counters (buf [D, N_COUNTERS]; each
@@ -259,24 +293,37 @@ def build_sharded_pipelined_runner(mesh: Mesh, n_shards: int,
     def unsq(tree):
         return jax.tree.map(lambda x: x[None], tree)
 
+    def enter(args):
+        """A device's carry as the scan takes it: the ShardState's leaves
+        as they come (`db.step` [1] as the scalar it is), the small
+        stacked rest squeezed."""
+        state = args[0]
+        return (_with_step(state, state.db.step[0]),) + tuple(
+            sq(a) for a in args[1:])
+
+    def leave_state(state):
+        return _with_step(state, state.db.step[None])
+
     def block_local(*args):
         key = args[-1]
-        state0 = sq(args[0])
+        carry0 = enter(args[:-1])
+        state0 = carry0[0]
         db = jax.lax.cond(state0.db.step >= jnp.uint32(td.REBASE_AT),
                           td.rebase_stamps, lambda d: d, state0.db)
         keys = jax.random.split(key, cohorts_per_block)
-        carry0 = (state0.replace(db=db),) + tuple(
-            sq(a) for a in args[1:-1])
-        carry, stats = jax.lax.scan(scan_fn, carry0, keys)
-        return tuple(unsq(x) for x in carry) + (stats,)
+        carry, stats = jax.lax.scan(
+            scan_fn, (state0.replace(db=db),) + carry0[1:], keys)
+        return (leave_state(carry[0]),) + tuple(
+            unsq(x) for x in carry[1:]) + (stats,)
 
     def drain_local(*args):
         key = args[-1]
-        carry = tuple(sq(a) for a in args[:-1])
+        carry = enter(args[:-1])
         carry, s1 = scan_fn(carry, key, gen_new=False)
         carry, s2 = scan_fn(carry, jax.random.fold_in(key, 1),
                             gen_new=False)
-        out = (unsq(carry[0]),) + ((unsq(carry[3]),) if monitor else ())
+        out = (leave_state(carry[0]),) + (
+            (unsq(carry[3]),) if monitor else ())
         return out + (jnp.stack([s1, s2]),)
 
     n_carry = 4 if monitor else 3
@@ -291,6 +338,31 @@ def build_sharded_pipelined_runner(mesh: Mesh, n_shards: int,
     jit_block = jax.jit(block, donate_argnums=donate)
     jit_drain = jax.jit(drain_m, donate_argnums=donate)
 
+    def relayout(local):
+        copy = jax.jit(jax.shard_map(local, mesh=mesh,
+                                     in_specs=P(SHARD_AXIS),
+                                     out_specs=P(SHARD_AXIS)),
+                       donate_argnums=0)
+
+        def one_leaf(x):
+            # a leaf at a time in earnest (my chip runs, PR 42: without
+            # these lines the allocator's peak is twice the state, 10.6
+            # GB; the wait alone left it there). The runtime allocates a
+            # result when its program is enqueued: wait for this leaf's
+            # before the next. And the copy aliases nothing, so the
+            # donation frees nothing by itself: the argument lives as
+            # long as a reference to it, and the caller's pytree holds
+            # one until every leaf is through.
+            y = jax.block_until_ready(copy(x))
+            if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+                x.delete()
+            return y
+
+        return one_leaf
+
+    drop_axis = relayout(lambda x: x[0])        # [D, N, ...] -> [D * N, ...]
+    add_axis = relayout(lambda x: x[None])      # and back
+
     def run(carry, key):
         out = jit_block(*carry, key)
         return out[:-1], out[-1]
@@ -298,12 +370,13 @@ def build_sharded_pipelined_runner(mesh: Mesh, n_shards: int,
     def init(state):
         fresh = (td.empty_ctx(w), td.empty_ctx(w)) + (
             (mon.create(),) if monitor else ())
-        return (state,) + stack_on_mesh(mesh, fresh)
+        return (_map_tables(drop_axis, state),) + stack_on_mesh(mesh, fresh)
 
     def drain(carry):
         out = jit_drain(*carry, jax.random.PRNGKey(0))
+        state = _map_tables(add_axis, out[0])
         if monitor:
-            return out[0], out[2], out[1]
-        return out
+            return state, out[2], out[1]
+        return state, out[1]
 
     return run, init, drain

@@ -15,6 +15,7 @@ import os
 import re
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -369,10 +370,52 @@ def test_windowed_row_scatter_is_expanded_to_a_loop_on_v5e(one_chip):
 # ------------------------------------------------------ four chips
 
 
+def dispatches(fn, *args, also=()) -> list:
+    """The programs ``fn`` dispatches: each jitted call at the top level
+    of its jaxpr compiled apart, donation and all, as at run time
+    (`jax.jit(fn)` would inline them into one program that never runs).
+    An operand takes the sharding of the abstract argument of its shape
+    (``also``: operands ``fn`` makes itself). Returns [(name, compiled)]."""
+    pool = {(s.shape, s.dtype): s for s in jax.tree.leaves((args, also))}
+    out = []
+    for eqn in jax.make_jaxpr(fn)(*args).eqns:
+        if eqn.primitive.name != "jit":
+            continue
+        operands = [pool[v.aval.shape, v.aval.dtype] for v in eqn.invars]
+        donated = tuple(i for i, d in enumerate(
+            eqn.params["donated_invars"]) if d)
+        jitted = jax.jit(jax.extend.core.jaxpr_as_fun(eqn.params["jaxpr"]),
+                         donate_argnums=donated)
+        out.append((eqn.params["name"], jitted.lower(*operands).compile()))
+    return out
+
+
+# an ENTRY instruction that hands a buffer on and moves no element of it
+_PLUMBING = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+             "conditional"}
+
+
+def entry_ops_of_size(hlo: str, counts: set) -> list:
+    """(opcode, result type) of every instruction of compiled HLO text's
+    ENTRY computation, plumbing apart, whose result (or a member of its
+    tuple) has as many elements as one of ``counts``, leading 1s or not."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    found = []
+    for line in entry[:entry.index("\n}")].splitlines():
+        # "%name = <type or (tuple of types)> opcode(operands...", and
+        # not the computation's own header
+        m = re.match(r".*? = (\(.*?\)|\S+) ([\w\-]+)\(", line)
+        if m and m[2] not in _PLUMBING and counts & {
+                _count(dims) for dims in re.findall(r"\w\[([\d,]*)\]", m[1])}:
+            found.append((m[2], m[1]))
+    return found
+
+
 def test_dense_sharded_block_program_on_four_chips(topo):
     """The sharded TATP-7M programs compile for a 4-device v5e mesh, each
     device holds a quarter of the state (created sharded: nothing global
-    on one chip), and the replication rides collective-permutes."""
+    on one chip), the replication rides collective-permutes, and the
+    block's parameters and results are its scan's own carry buffers."""
     mesh = Mesh(np.array(topo.devices), (ds.SHARD_AXIS,))
     n = mesh.size
     run, init, drain = ds.build_sharded_pipelined_runner(
@@ -382,31 +425,47 @@ def test_dense_sharded_block_program_on_four_chips(topo):
     def create():
         return ds.create_sharded(mesh, n, N_SUB, val_words=VW, seed=0)
 
-    shapes = jax.eval_shape(lambda: init(create()))
-    total = sum(int(np.prod(s.shape)) * s.dtype.itemsize
-                for s in jax.tree.leaves(shapes))
-    assert total > 20e9          # primary + 2 backups: more than one chip
-    carry = placed(shapes, NamedSharding(mesh, P(ds.SHARD_AXIS)))
+    by_device = NamedSharding(mesh, P(ds.SHARD_AXIS))
+    stacked = placed(jax.eval_shape(create), by_device)
     key = placed(jax.eval_shape(lambda: jax.random.PRNGKey(0)),
                  NamedSharding(mesh, P()))
-
     _, ma = compiled_bytes(jax.jit(create))
-    assert abs(ma.output_size_in_bytes - total / n) < 0.01 * total / n
+    total = n * ma.output_size_in_bytes
+    assert total > 20e9          # primary + 2 backups: more than one chip
     assert ma.output_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
 
+    # the carry as `init` hands it over: its shapes, and the shardings its
+    # conversion compiles to. The way in is one copy a leaf and no
+    # temporary (leaf by leaf at run time: the state and one leaf)
+    made, ma = compiled_bytes(jax.jit(init, donate_argnums=0), stacked)
+    assert ma.temp_size_in_bytes < 1e6
+    carry = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(init, stacked), made.output_shardings)
     n1 = td.n_rows(ds.n_sub_local(N_SUB, n)) + 1
-    # (program, ceiling on its temporaries): 7.794 GB the block and 8.868
-    # the drain (AOT, PR 38) beside 5.296 GB of donated state: what they
-    # were with the backup's scatters at full width (7.794 / 8.867, PR
-    # 37), so those scatters' copies were not it. Not under 1e9 as on one
-    # chip: `block_local` squeezes [1, N] leaves; carrying the shards as
-    # [N] is ROADMAP Queue 2A item 2(a). On the chip the peak is 5.4 GB
-    # (PERF.md §5). The ceilings hold what fits today.
+    table_words = {n1, n1 * VW, ds.N_BCK * n1, ds.N_BCK * n1 * VW}
+    for leaf in jax.tree.leaves(carry[0]):
+        assert leaf.sharding.is_equivalent_to(by_device, leaf.ndim)
+        # no stacked axis: a device's shard is the table itself
+        assert leaf.sharding.shard_shape(leaf.shape) == (
+            leaf.shape[0] // n,) + leaf.shape[1:]
+    assert table_words <= {int(np.prod(x.shape)) // n
+                           for x in jax.tree.leaves(carry[0])}
+
+    (_, block), = dispatches(run, carry, key)
+    (_, steps), *back = dispatches(drain, carry, also=key)
+    assert len(back) == len(jax.tree.leaves(carry[0])) - 1   # `db.step`
+
+    # (program, ceiling on its temporaries). The block 0.020 GB and the
+    # drain's two steps 0.011 beside 5.305 GB of donated state (AOT, PR
+    # 42). With the shards carried stacked, [1, N] a device, they were
+    # 7.794 and 8.868 (AOT, PR 38): `block_local` squeezed every leaf into
+    # the scan's carry and unsqueezed it out again, a copy of each table
+    # at the entry and three passes over it at the exit, 5.0 ms of the
+    # 12.9 ms step on the chip (PERF.md §6, PR 42).
     chunk = compact.chunk_lanes(2 * W)
-    for fn, args, ceiling in (
-            (jax.jit(run, donate_argnums=0), (carry, key), 8.0e9),
-            (jax.jit(drain, donate_argnums=0), (carry,), 9.0e9)):
-        c, ma = compiled_bytes(fn, *args)
+    for c, ceiling in ((block, 0.1e9), (steps, 0.012e9)):
+        ma = c.memory_analysis()
         assert abs(ma.argument_size_in_bytes - total / n) < 0.01 * total / n
         assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
         # the carry is donated: primaries, backups and rings update in
@@ -415,6 +474,9 @@ def test_dense_sharded_block_program_on_four_chips(topo):
         assert ma.temp_size_in_bytes < ceiling
         hlo = c.as_text()
         assert "collective-permute" in hlo
+        # outside the scan nothing moves a table: parameter -> the loop
+        # -> result
+        assert entry_ops_of_size(hlo, table_words) == []
         # each hop's backup install issues a chunk of the forwarded
         # record's live lanes (C x VW value words, C meta words), as the
         # primary's own install does, as on one chip; nothing in the
@@ -424,3 +486,17 @@ def test_dense_sharded_block_program_on_four_chips(topo):
         assert set(scatter_index_counts(hlo, ds.N_BCK * n1)) == {chunk}
         assert set(scatter_index_counts(hlo, n1 * VW)) == {chunk * VW}
         assert 2 * W * VW not in all_scatter_index_counts(hlo)
+
+    # the way back to the stacked state, once a drain and a leaf at a
+    # time: the compiler writes [N] -> [1, N] as a zero fill and a one-trip
+    # loop of a slice and an update, so a leaf is held three times
+    # (argument, temporary, result); beside the rest of the state that
+    # peaks at `bck_val`: 5.3 + 2 x 3.08 = 11.5 GB (AOT, PR 42)
+    worst = 0
+    for _, c in back:
+        ma = c.memory_analysis()
+        new = (ma.temp_size_in_bytes + ma.output_size_in_bytes
+               - ma.alias_size_in_bytes)
+        assert new < 2.01 * ma.argument_size_in_bytes + 1e6
+        worst = max(worst, new)
+    assert 6.1e9 < worst and total / n + worst < 11.6e9 < HBM_BYTES

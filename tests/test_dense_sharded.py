@@ -184,12 +184,14 @@ def _acked(ring, heads, tag):
 
 
 def _replicas_equal_reference(seed, w, n_glob=4 * 300, mix=None,
-                              monitor=False):
+                              monitor=False, restart_after=None):
     """Four devices, three blocks and the drain: each primary, each backup
     slot (row for row over the whole table) and each of the three rings
     that carry a device's stream (entry for entry, in order) equal what
     dint_tpu/testing/replication.py makes of the primary's acknowledged
-    installs. Returns the run's counter snapshot under ``monitor``."""
+    installs. ``restart_after``: drain after that block and `init` again
+    on the drained state. Returns the run's counter snapshot under
+    ``monitor``."""
     from dint_tpu.testing import replication as ref
 
     d = 4
@@ -203,6 +205,8 @@ def _replicas_equal_reference(seed, w, n_glob=4 * 300, mix=None,
     carry = init(state)
     for i in range(3):
         carry, _ = run(carry, jax.random.fold_in(jax.random.PRNGKey(seed), i))
+        if i == restart_after:
+            carry = init(drain(carry)[0])
     out = drain(carry)
     live = jax.tree.map(np.asarray, out[0])
 
@@ -440,3 +444,107 @@ def test_a_second_process_loads_the_sharded_programs_from_the_cache(
     for k in ("stats", "counters", "heads", "meta", "bck_meta"):
         assert first[k] == second[k], k
     assert sum(first["heads"][0]) > 0
+
+
+# ------------------------------------ the carry's form between dispatches
+
+
+def _noisy_state(mesh, d, n_glob, seed):
+    """A stacked state no leaf of which is all zero or equal on two
+    devices: a few blocks run and drained, so that rings, heads and
+    backups carry writes."""
+    state = ds.create_sharded(mesh, d, n_glob, val_words=VW, seed=seed,
+                              log_capacity=1 << 10)
+    run, init, drain = ds.build_sharded_pipelined_runner(
+        mesh, d, n_glob, w=64, val_words=VW, cohorts_per_block=2)
+    carry = init(state)
+    for i in range(2):
+        carry, _ = run(carry, jax.random.fold_in(jax.random.PRNGKey(seed), i))
+    return drain(carry)[0]
+
+
+def test_init_and_the_way_back_keep_every_leaf_bit_for_bit():
+    """Stacked -> the carry's form -> stacked is the identity on every
+    leaf of a state whose rings and backups hold writes (a drain with an
+    empty pipeline installs and appends nothing)."""
+    d, n_glob = 4, 4 * 300
+    mesh = ds.make_mesh(d)
+    state = _noisy_state(mesh, d, n_glob, seed=3)
+    before = jax.tree.map(np.array, state)
+    assert before.db.log.head.any() and before.bck_meta.any()
+    assert (before.bck_val[0] != before.bck_val[1]).any()
+    _, init, drain = ds.build_sharded_pipelined_runner(
+        mesh, d, n_glob, w=64, val_words=VW, cohorts_per_block=2)
+    after, tail = drain(init(state))
+    assert not np.asarray(tail).any()
+    for (path, x), y in zip(jax.tree_util.tree_leaves_with_path(before),
+                            jax.tree.leaves(after)):
+        assert y.shape == x.shape and y.shape[0] == d
+        # two empty steps: the step counter moved, nothing else did
+        moved = 2 if jax.tree_util.keystr(path) == ".db.step" else 0
+        np.testing.assert_array_equal(np.asarray(y), x + np.uint32(moved),
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+def test_the_carry_holds_each_shard_without_a_stacked_axis():
+    """Between dispatches a device's shard of every ShardState leaf is
+    the table as the scan carries it, [N, ...] and no [1, N, ...]
+    (`db.step`: [1], a scalar a device); the contexts and the counters
+    stay stacked, the counters [D, N_COUNTERS]."""
+    from dint_tpu.monitor import counters as mc
+
+    d, n_glob = 4, 4 * 300
+    mesh = ds.make_mesh(d)
+    state = ds.create_sharded(mesh, d, n_glob, val_words=VW,
+                              log_capacity=1 << 10)
+    stacked = [x.shape for x in jax.tree.leaves(state)]
+    run, init, _ = ds.build_sharded_pipelined_runner(
+        mesh, d, n_glob, w=64, val_words=VW, cohorts_per_block=2,
+        monitor=True)
+    def held_as_the_scan_carries_it(carry):
+        for leaf, was in zip(jax.tree.leaves(carry[0]), stacked):
+            local = was[1:] or (1,)
+            assert leaf.shape == (d * local[0],) + local[1:]
+            shards = leaf.addressable_shards
+            assert {s.device for s in shards} == set(mesh.devices.flat)
+            assert all(s.data.shape == local for s in shards)
+        assert carry[-1].buf.shape == (d, mc.N_COUNTERS)
+        for leaf in jax.tree.leaves(carry[1:]):
+            assert leaf.shape[0] == d
+            assert all(s.data.shape == (1,) + leaf.shape[1:]
+                       for s in leaf.addressable_shards)
+
+    carry = init(state)
+    held_as_the_scan_carries_it(carry)
+    held_as_the_scan_carries_it(run(carry, jax.random.PRNGKey(0))[0])
+
+
+def test_run_and_init_donate_what_they_are_given():
+    """`init` consumes the stacked state and `run` the carry: every table
+    leaf handed in is deleted after the call (the block updates the
+    carry's own buffers; a caller that kept the old carry would read
+    freed memory, so JAX refuses)."""
+    d, n_glob = 4, 4 * 300
+    mesh = ds.make_mesh(d)
+    state = ds.create_sharded(mesh, d, n_glob, val_words=VW,
+                              log_capacity=1 << 10)
+    run, init, drain = ds.build_sharded_pipelined_runner(
+        mesh, d, n_glob, w=64, val_words=VW, cohorts_per_block=2,
+        monitor=True)
+    carry = init(state)
+    assert all(x.is_deleted() for x in jax.tree.leaves(
+        state.replace(db=state.db.replace(step=None))))
+    after, _ = run(carry, jax.random.PRNGKey(1))
+    assert all(x.is_deleted() for x in jax.tree.leaves(carry))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(after))
+    drained = drain(after)
+    assert all(x.is_deleted() for x in jax.tree.leaves(after[0]))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(drained))
+
+
+@pytest.mark.parametrize("seed", [1, 2**31 + 9])
+def test_every_replica_equals_the_reference_across_a_restart(seed):
+    """The harness's `restart`: init -> run -> drain -> init(the drained
+    state) -> run x 2 -> drain, both passes' writes in every replica and
+    every ring."""
+    _replicas_equal_reference(seed, w=64, restart_after=0)
