@@ -883,7 +883,7 @@ TARGET_COST.update({
     # of a chunk loop is priced, at a geometry where a chunk is all 2w
     # slots: the scatters' bytes are the parent's
     "tatp_dense/block": _cost(_TD_GEOM, 12, 216844),
-    "tatp_dense/block@mon": _cost(_TD_GEOM, 14, 217024),
+    "tatp_dense/block@mon": _cost(_TD_GEOM, 14, 217032),
     "tatp_dense/drain": _cost(_TD_GEOM, 12, 216836),
     "tatp_dense/block@hot": _cost(_TD_GEOM, 14, 216864,
                                   wave_expect=_HOT2_TD),
@@ -891,25 +891,25 @@ TARGET_COST.update({
     # closed-loop rows above (the occupancy mask fuses into the gen
     # wave), footprint +16 B (@mon +28 B) for the occ/shed step inputs
     "tatp_dense/serve": _cost(_TD_GEOM, 12, 216860),
-    "tatp_dense/serve@mon": _cost(_TD_GEOM, 14, 217040),
+    "tatp_dense/serve@mon": _cost(_TD_GEOM, 14, 217048),
     # dense SmallBank
     "smallbank_dense/block": _cost(_SB_GEOM, 8, 150984),
-    "smallbank_dense/block@mon": _cost(_SB_GEOM, 10, 151164),
+    "smallbank_dense/block@mon": _cost(_SB_GEOM, 10, 151172),
     "smallbank_dense/block@hot": _cost(_SB_GEOM, 14, 151032,
                                        wave_expect=_HOT2_SB),
-    "smallbank_dense/block@hot+mon": _cost(_SB_GEOM, 16, 151212,
+    "smallbank_dense/block@hot+mon": _cost(_SB_GEOM, 16, 151220,
                                            wave_expect=_HOT2_SB),
     "smallbank_dense/serve": _cost(_SB_GEOM, 8, 151000),
-    "smallbank_dense/serve@mon": _cost(_SB_GEOM, 10, 151180),
+    "smallbank_dense/serve@mon": _cost(_SB_GEOM, 10, 151188),
     # generic pipelines: sort-bound, no formula-backed waves -> absolute
     # bytes ceilings instead of a ledger multiple
     "tatp_pipeline/block": _cost(_TD_GEOM, 50, 1610736022,
                                  bytes_budget=256000),
-    "tatp_pipeline/block@mon": _cost(_TD_GEOM, 51, 1610736202,
+    "tatp_pipeline/block@mon": _cost(_TD_GEOM, 51, 1610736210,
                                      bytes_budget=256000),
     "smallbank_pipeline/block": _cost(_SB_GEOM, 36, 1207967480,
                                       bytes_budget=72000),
-    "smallbank_pipeline/block@mon": _cost(_SB_GEOM, 37, 1207967660,
+    "smallbank_pipeline/block@mon": _cost(_SB_GEOM, 37, 1207967668,
                                           bytes_budget=72000),
     # generic replicated shard step: one engine step per trace
     "sharded/tatp": _cost(_DS_GEOM, 62, 4295279296, steps=1.0,
@@ -922,11 +922,11 @@ TARGET_COST.update({
     # record, priced as one trip at a geometry where a chunk is all 2w
     "dense_sharded/block": _cost(_DS_GEOM, 42, 459240,
                                  wave_expect=_DS_EXPECT),
-    "dense_sharded/block@mon": _cost(_DS_GEOM, 46, 459960,
+    "dense_sharded/block@mon": _cost(_DS_GEOM, 46, 459992,
                                      wave_expect=_DS_EXPECT),
     # dense multi-chip SmallBank
     "dense_sharded_sb/block": _cost(_DSB_GEOM, 33, 100676560),
-    "dense_sharded_sb/block@mon": _cost(_DSB_GEOM, 37, 100677280),
+    "dense_sharded_sb/block@mon": _cost(_DSB_GEOM, 37, 100677312),
     "dense_sharded_sb/block@hot": _cost(_DSB_GEOM, 39, 100676848,
                                         wave_expect=_DSB_HOT),
     # 2-D (dcn x ici) SmallBank: the hierarchical route pays +9
@@ -937,7 +937,7 @@ TARGET_COST.update({
     "multihost_sb/block": _cost(_MHSB_GEOM, 42, 201353056),
     "multihost_sb/block@flat": _cost(_MHSB_GEOM, 33, 201353056,
                                      wave_expect=_MHSB_FLAT),
-    "multihost_sb/block@mon": _cost(_MHSB_GEOM, 46, 201354496),
+    "multihost_sb/block@mon": _cost(_MHSB_GEOM, 46, 201354560),
     "multihost_sb/block@h3": _cost(_MHSB_GEOM_H3, 42, 151014808),
     "multihost_sb/block@h3+flat": _cost(_MHSB_GEOM_H3, 33, 151014808,
                                         wave_expect=_MHSB_FLAT),
@@ -950,9 +950,9 @@ TARGET_COST.update({
     "multihost_sb/serve": _cost(_MHSB_GEOM, 42, 201353184),
     "multihost_sb/serve@flat": _cost(_MHSB_GEOM, 33, 201353184,
                                      wave_expect=_MHSB_FLAT),
-    "multihost_sb/serve@mon": _cost(_MHSB_GEOM, 47, 201354624),
+    "multihost_sb/serve@mon": _cost(_MHSB_GEOM, 47, 201354688),
     "multihost_sb/serve@overlap": _cost(_MHSB_GEOM, 44, 201359424),
-    "multihost_sb/serve@overlap+mon": _cost(_MHSB_GEOM, 50, 201360864),
+    "multihost_sb/serve@overlap+mon": _cost(_MHSB_GEOM, 50, 201360928),
     # 2-D TATP (parallel/multihost.py, flat tuple-axis collectives):
     # replication traffic pre-dates wave scoping -> absolute bytes
     # ceiling like the pipeline targets, not a ledger multiple
@@ -1116,7 +1116,7 @@ TARGET_COST.update({
                                  bytes_budget=2200),
     "store/block@scan": _cost(_ST_GEOM, 35.5, 4141, bytes_budget=11700),
     "store/serve@scan": _cost(_ST_GEOM, 35.5, 4157, bytes_budget=11700),
-    "store/serve@scan+mon": _cost(_ST_GEOM, 36.5, 4337,
+    "store/serve@scan+mon": _cost(_ST_GEOM, 36.5, 4345,
                                   bytes_budget=11750),
     "store/rebuild@scan": _cost(_ST_GEOM, 5, 6122, steps=1.0,
                                 bytes_budget=1950),

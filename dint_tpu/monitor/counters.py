@@ -210,6 +210,19 @@ _REGISTRY: tuple[tuple[str, str, str], ...] = (
      "a sort of its own beside the engine's): the skew's pressure on the "
      "same-key serialisation (ops/segments.py). ~2,830 of 8,192 under "
      "Zipfian 0.99 over 24 M keys, ~1.4 under uniform draws"),
+    ("xshard_txns", FLOW,
+     "sharded SmallBank (parallel/dense_sharded_sb.py): generated "
+     "transactions whose lock set names rows of more than one owner "
+     "device, counted at the SOURCE device in the step that routes their "
+     "requests (a drain generates none). Under the reference's mix and "
+     "owner = account % 4: AMALGAMATE + SEND_PAYMENT (40 %), three "
+     "quarters of them on two owners, ~30 % of txn_attempted"),
+    ("remote_lock_lanes", FLOW,
+     "sharded SmallBank: generated lock requests whose owner is not the "
+     "source device (the lanes a lock grant and a fused read cross the "
+     "interconnect for), counted at the SOURCE with xshard_txns; bucket "
+     "overflow apart it is the share (D - 1) / D of lock_requests, ~75 % "
+     "on four devices"),
 )
 
 ALL_NAMES: tuple[str, ...] = tuple(n for n, _, _ in _REGISTRY)
@@ -265,6 +278,8 @@ CTR_STORE_UPDATES = COUNTER_INDEX["store_updates"]
 CTR_STORE_NOT_EXIST = COUNTER_INDEX["store_not_exist"]
 CTR_STORE_SPILL = COUNTER_INDEX["store_spill"]
 CTR_STORE_DUP_LANES = COUNTER_INDEX["store_dup_lanes"]
+CTR_XSHARD_TXNS = COUNTER_INDEX["xshard_txns"]
+CTR_REMOTE_LOCK_LANES = COUNTER_INDEX["remote_lock_lanes"]
 
 # the subset defined with IDENTICAL semantics by the dense engines and
 # the generic sort-based pipelines: on the parity workloads

@@ -329,7 +329,7 @@ PART_PREFIX = "part"
 _DENSE = ("tatp_dense", "smallbank_dense")
 # the engine-neutral parts: each engine whose runner steps a block opens
 # them in its own step
-_STEPPED = _DENSE + ("store",)
+_STEPPED = _DENSE + ("store", "dense_sharded_sb")
 
 # (owner, wave | None, part, doc). The owner is the engine, or the shared
 # module ("log" = tables/log.py), whose code opens the part, or a tuple of
@@ -485,6 +485,80 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
      "(C x w compares) and its gathers of entry indices, versions, value "
      "rows and key words out of lane space; kv_val_scatter and "
      "kv_meta_scatter, C lanes a chunk, lie inside its loops"),
+    # --- sharded SmallBank (parallel/dense_sharded_sb.py), appended with
+    # --- the cell smallbank24m-x4-sat (PR 43): every equation of its block
+    # --- carries a wave or a part. A part whose wave is None here and
+    # --- whose doc names two waves is opened under both (the same code
+    # --- serves the lock requests and the installs) ----------------------
+    ("dense_sharded_sb", None, "sbx_frame",
+     "the step's frame: the device's index, its key fold and split, the "
+     "TRANSACT_SAVING amount draw, a drain's empty cohort, the new "
+     "cohort's context (its outcome sums, cast varying) and the state's "
+     "reassembly with the step counter's increment"),
+    ("dense_sharded_sb", None, "sbx_carry",
+     "the stacked [D, ...] carry: every leaf's `x[0]` at the block's "
+     "entry and `x[None]` at its exit (the form PR 42 took out of "
+     "dense_sharded: on v5e a whole-table reduce in, a zero fill and a "
+     "one-trip update loop out)"),
+    ("dense_sharded_sb", None, "route_addr",
+     "under route and install_route: a lane's owner (account % D), its "
+     "local row, the active / valid masks and the fields to exchange"),
+    ("dense_sharded_sb", None, "a2a_rank",
+     "under route and install_route: `_positions`, a lane's arrival rank "
+     "at its owner (a [wL, D] one-hot, its exclusive cumsum and the "
+     "take_along_axis)"),
+    ("dense_sharded_sb", None, "a2a_pack",
+     "under route and install_route: `_route`, the bucket index and one "
+     "unique-index scatter a field of the wL lanes into D buckets of "
+     "`cap` slots (2 fields of requests, 5 of installs; a2a_pack_ms.* "
+     "reads this)"),
+    ("dense_sharded_sb", "route", "a2a_requests",
+     "the all_to_alls of the lock + read requests (op, local row)"),
+    ("dense_sharded_sb", None, "owner_addr",
+     "the owner's view of what arrived: lane numbers, the X / S masks, "
+     "rows with the sentinel for empty slots (and the hot mirror's index)"),
+    ("dense_sharded_sb", "arbitrate", "owner_arb",
+     "the arbitration proper: two fresh shard-table-wide arrays filled "
+     "and scatter-min'ed with the arrival number over the X and the S "
+     "requests of the D x cap slots"),
+    ("dense_sharded_sb", "arbitrate", "owner_held_read",
+     "gathers of the D x cap slots' X and S stamps + the held compares"),
+    ("dense_sharded_sb", "arbitrate", "owner_grant",
+     "gathers back out of the two arbitration arrays and the grant masks"),
+    ("dense_sharded_sb", "arbitrate", "owner_stamp",
+     "the two unique-index stamp scatters of the granted slots (and the "
+     "hot mirror's write-through)"),
+    ("dense_sharded_sb", "arbitrate", "owner_bal_read",
+     "the fused balance gather of the D x cap slots, masked by the grant"),
+    ("dense_sharded_sb", "reply", "a2a_replies",
+     "the all_to_alls of the replies (grant bit, balance)"),
+    ("dense_sharded_sb", "reply", "reply_unpack",
+     "the source's gathers of its lanes' replies out of the returned "
+     "buckets"),
+    ("dense_sharded_sb", "reply", "reply_classify",
+     "lock verdicts per transaction, compute_phase, the write mask and "
+     "the balance delta"),
+    ("dense_sharded_sb", "install_route", "a2a_installs",
+     "the all_to_alls of the committed writes (mask, local row, balance, "
+     "table, account)"),
+    ("dense_sharded_sb", "install_route", "owner_install",
+     "the owner's unique-index scatter of the arrived balances into its "
+     "primary table"),
+    ("dense_sharded_sb", "install_route", "owner_log_append",
+     "the [D x cap, VW] value rows {balance, magic} and append_rep at "
+     "full width into the owner's ring, tag 0 (log_plan and log_scatter "
+     "lie inside)"),
+    ("dense_sharded_sb", "replicate", "sb_repl_hop",
+     "one hop's five ppermutes of the applied installs (mask, row, "
+     "balance, table, account) to device d + off, the sender's index and "
+     "the count of what arrived (repl_push_hop<off>)"),
+    ("dense_sharded_sb", "replicate", "sb_bck_scatter",
+     "the backup slot's row ids and the unique-index scatter of the "
+     "forwarded balances into it, D x cap lanes"),
+    ("dense_sharded_sb", "replicate", "sb_bck_log_append",
+     "the forwarded stream's tag (source + 1), the {balance, magic} rows "
+     "and append_rep at full width into this device's ring (log_plan and "
+     "log_scatter lie inside)"),
 )
 
 # keyed on the part's name alone: the scope is `part.<name>`, so two

@@ -57,7 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..engines.smallbank_pipeline import (L, TS_AMT_MAX, VW, N_STATS,
+from ..engines.smallbank_pipeline import (L, MAGIC, TS_AMT_MAX, VW, N_STATS,
                                           STAT_ATTEMPTED, STAT_COMMITTED,
                                           STAT_AB_LOCK, STAT_AB_LOGIC,
                                           STAT_BAL_DELTA, compute_phase,
@@ -283,9 +283,10 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
 
     def local_step(state: SBShard, c1: SBCtx, key, cnt, ring,
                    gen_new=True):
-        dev = jax.lax.axis_index(AXIS)
-        t = state.step
-        kgen, kamt = jax.random.split(jax.random.fold_in(key, dev))
+        with waves.part("dense_sharded_sb", "sbx_frame"):
+            dev = jax.lax.axis_index(AXIS)
+            t = state.step
+            kgen, kamt = jax.random.split(jax.random.fold_in(key, dev))
 
         # ---- wave 1: generate + route lock/read requests to owners ----
         if gen_new:
@@ -294,43 +295,52 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
                                            **kw_gen)
                 l_op, l_tb, l_ac = _lock_slots(ttype, a1, a2)
         else:
-            ttype = jnp.zeros((w,), I32)
-            l_op = jnp.zeros((w, L), I32)
-            l_tb = jnp.zeros((w, L), I32)
-            l_ac = jnp.zeros((w, L), I32)
-        ts_amt = jax.random.randint(kamt, (w,), -TS_AMT_MAX,
-                                    TS_AMT_MAX + 1, dtype=I32)
+            with waves.part("dense_sharded_sb", "sbx_frame"):
+                ttype = jnp.zeros((w,), I32)
+                l_op = jnp.zeros((w, L), I32)
+                l_tb = jnp.zeros((w, L), I32)
+                l_ac = jnp.zeros((w, L), I32)
+        with waves.part("dense_sharded_sb", "sbx_frame"):
+            ts_amt = jax.random.randint(kamt, (w,), -TS_AMT_MAX,
+                                        TS_AMT_MAX + 1, dtype=I32)
 
         if ring is not None:
             # dinttrace ids: one per generated txn, identical on every
             # device that touches it (the routed copies below carry it)
-            tu = jnp.asarray(t).astype(U32)
-            du = dev.astype(U32)
-            lane_w = jnp.arange(w, dtype=U32)
-            txn_new = (tu * U32(d) + du) * U32(w) + lane_w
-            txn_c1 = ((tu - U32(1)) * U32(d) + du) * U32(w) + lane_w
+            with waves.part("dense_sharded_sb", "sbx_frame"):
+                tu = jnp.asarray(t).astype(U32)
+                du = dev.astype(U32)
+                lane_w = jnp.arange(w, dtype=U32)
+                txn_new = (tu * U32(d) + du) * U32(w) + lane_w
+                txn_c1 = ((tu - U32(1)) * U32(d) + du) * U32(w) + lane_w
 
         with waves.scope("dense_sharded_sb", "route"):
-            active = (l_op != 0).reshape(-1)
-            dest = (l_ac.reshape(-1) % d).astype(I32)
-            row_loc = (l_tb.reshape(-1) * n_loc
-                       + l_ac.reshape(-1) // d).astype(I32)
-            pos = _positions(dest, active, d)
-            valid = active & (pos < cap)
+            with waves.part("dense_sharded_sb", "route_addr"):
+                active = (l_op != 0).reshape(-1)
+                dest = (l_ac.reshape(-1) % d).astype(I32)
+                row_loc = (l_tb.reshape(-1) * n_loc
+                           + l_ac.reshape(-1) // d).astype(I32)
+            with waves.part("dense_sharded_sb", "a2a_rank"):
+                pos = _positions(dest, active, d)
+            with waves.part("dense_sharded_sb", "route_addr"):
+                valid = active & (pos < cap)
 
-            fields = [l_op.reshape(-1), row_loc]
-            if ring is not None:
-                fields.append(jnp.repeat(txn_new, L))
-            routed = [_a2a(x, d, cap)
-                      for x in _route(dest, pos, valid, cap, d, fields)]
+                fields = [l_op.reshape(-1), row_loc]
+                if ring is not None:
+                    fields.append(jnp.repeat(txn_new, L))
+            with waves.part("dense_sharded_sb", "a2a_pack"):
+                packed = _route(dest, pos, valid, cap, d, fields)
+            with waves.part("dense_sharded_sb", "a2a_requests"):
+                routed = [_a2a(x, d, cap) for x in packed]
             r_op, r_row = routed[:2]
             r_txn = routed[2] if ring is not None else None
 
         # ---- owner side: no-wait S/X arbitration + fused read ---------
-        lanes = jnp.arange(d * cap, dtype=I32)
-        is_x = r_op == Op.ACQ_X_READ
-        is_s = r_op == Op.ACQ_S_READ
-        rows = jnp.where(r_op != 0, r_row, sent)
+        with waves.part("dense_sharded_sb", "owner_addr"):
+            lanes = jnp.arange(d * cap, dtype=I32)
+            is_x = r_op == Op.ACQ_X_READ
+            is_s = r_op == Op.ACQ_S_READ
+            rows = jnp.where(r_op != 0, r_row, sent)
         with waves.scope("dense_sharded_sb", "arbitrate"):
 
             def mirror_idx(rr, mask):
@@ -343,202 +353,247 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
                                  tb * hot_loc + q, -1)
 
             if use_hotset:
-                midx = mirror_idx(rows, r_op != 0)
-            first_x = jnp.full((m1,), BIG, I32).at[
-                jnp.where(is_x, rows, oob)].min(lanes, mode="drop")
-            first_s = jnp.full((m1,), BIG, I32).at[
-                jnp.where(is_s, rows, oob)].min(lanes, mode="drop")
-            if use_hotset:
-                held_x = hotset.hot_gather(state.x_step, state.hot_x, rows,
-                                           midx, 1) == t - 1
-                held_s = hotset.hot_gather(state.s_step, state.hot_s, rows,
-                                           midx, 1) == t - 1
-            else:
-                held_x = state.x_step[rows] == t - 1
-                held_s = state.s_step[rows] == t - 1
-            slot_free = ~held_x & ~held_s
-            x_wins = (first_x[rows] < first_s[rows]) & slot_free
-            grant_x = is_x & x_wins & (first_x[rows] == lanes)
-            grant_s = is_s & ~held_x & ~x_wins
-            s_writer = grant_s & (first_s[rows] == lanes)
-            x_step = state.x_step.at[jnp.where(grant_x, rows, oob)].set(
-                t, mode="drop", unique_indices=True)
-            s_step = state.s_step.at[
-                jnp.where(s_writer, rows, oob)].set(
-                t, mode="drop", unique_indices=True)
-            hot_x, hot_s = state.hot_x, state.hot_s
-            if use_hotset:
-                # stamp write-through (one-writer grant masks stay unique
-                # on the mirror's index subset)
-                hot_x = hot_x.at[jnp.where(grant_x & (midx >= 0), midx,
-                                           2 * hot_loc)].set(
+                with waves.part("dense_sharded_sb", "owner_addr"):
+                    midx = mirror_idx(rows, r_op != 0)
+            with waves.part("dense_sharded_sb", "owner_arb"):
+                first_x = jnp.full((m1,), BIG, I32).at[
+                    jnp.where(is_x, rows, oob)].min(lanes, mode="drop")
+                first_s = jnp.full((m1,), BIG, I32).at[
+                    jnp.where(is_s, rows, oob)].min(lanes, mode="drop")
+            with waves.part("dense_sharded_sb", "owner_held_read"):
+                if use_hotset:
+                    held_x = hotset.hot_gather(state.x_step, state.hot_x,
+                                               rows, midx, 1) == t - 1
+                    held_s = hotset.hot_gather(state.s_step, state.hot_s,
+                                               rows, midx, 1) == t - 1
+                else:
+                    held_x = state.x_step[rows] == t - 1
+                    held_s = state.s_step[rows] == t - 1
+                slot_free = ~held_x & ~held_s
+            with waves.part("dense_sharded_sb", "owner_grant"):
+                x_wins = (first_x[rows] < first_s[rows]) & slot_free
+                grant_x = is_x & x_wins & (first_x[rows] == lanes)
+                grant_s = is_s & ~held_x & ~x_wins
+                s_writer = grant_s & (first_s[rows] == lanes)
+            with waves.part("dense_sharded_sb", "owner_stamp"):
+                x_step = state.x_step.at[
+                    jnp.where(grant_x, rows, oob)].set(
                     t, mode="drop", unique_indices=True)
-                hot_s = hot_s.at[jnp.where(s_writer & (midx >= 0), midx,
-                                           2 * hot_loc)].set(
+                s_step = state.s_step.at[
+                    jnp.where(s_writer, rows, oob)].set(
                     t, mode="drop", unique_indices=True)
-            if use_hotset:
-                raw_bal = hotset.hot_gather(state.bal, state.hot_bal, rows,
-                                            midx, 1)
-            else:
-                raw_bal = state.bal[rows]
-            g_bal = jnp.where(grant_x | grant_s, raw_bal.astype(I32), 0)
+                hot_x, hot_s = state.hot_x, state.hot_s
+                if use_hotset:
+                    # stamp write-through (one-writer grant masks stay
+                    # unique on the mirror's index subset)
+                    hot_x = hot_x.at[jnp.where(grant_x & (midx >= 0), midx,
+                                               2 * hot_loc)].set(
+                        t, mode="drop", unique_indices=True)
+                    hot_s = hot_s.at[jnp.where(s_writer & (midx >= 0),
+                                               midx, 2 * hot_loc)].set(
+                        t, mode="drop", unique_indices=True)
+            with waves.part("dense_sharded_sb", "owner_bal_read"):
+                if use_hotset:
+                    raw_bal = hotset.hot_gather(state.bal, state.hot_bal,
+                                                rows, midx, 1)
+                else:
+                    raw_bal = state.bal[rows]
+                g_bal = jnp.where(grant_x | grant_s, raw_bal.astype(I32),
+                                  0)
 
         # ---- replies back to sources + classify -----------------------
         with waves.scope("dense_sharded_sb", "reply"):
-            rep_g = _a2a((grant_x | grant_s), d, cap)
-            rep_b = _a2a(g_bal, d, cap)
-            back = jnp.where(valid, dest * cap + pos, 0)
-            granted = (jnp.where(valid, rep_g[back], False)
-                       .reshape(w, L))
-            bal = jnp.where(granted, rep_b[back].reshape(w, L), 0)
-            # overflowed lanes have valid=False -> granted=False, so the
-            # no-wait reject covers them (the reference client's retry
-            # under overload, here a bounded no-wait reject)
-            lock_rejected = ((l_op != 0) & ~granted).any(axis=1)
-            alive = ~lock_rejected & (l_op[:, 0] != 0)
+            with waves.part("dense_sharded_sb", "a2a_replies"):
+                rep_g = _a2a((grant_x | grant_s), d, cap)
+                rep_b = _a2a(g_bal, d, cap)
+            with waves.part("dense_sharded_sb", "reply_unpack"):
+                back = jnp.where(valid, dest * cap + pos, 0)
+                granted = (jnp.where(valid, rep_g[back], False)
+                           .reshape(w, L))
+                bal = jnp.where(granted, rep_b[back].reshape(w, L), 0)
+            with waves.part("dense_sharded_sb", "reply_classify"):
+                # overflowed lanes have valid=False -> granted=False, so
+                # the no-wait reject covers them (the reference client's
+                # retry under overload, here a bounded no-wait reject)
+                lock_rejected = ((l_op != 0) & ~granted).any(axis=1)
+                alive = ~lock_rejected & (l_op[:, 0] != 0)
 
-            nw, do, logic_abort, commit, committed = compute_phase(
-                ttype, bal, alive, ts_amt)
-            do_write = do & commit[:, None] & (l_op != 0)
-            bal_delta = jnp.sum(jnp.where(do_write, nw - bal, 0),
-                                dtype=I32)
+                nw, do, logic_abort, commit, committed = compute_phase(
+                    ttype, bal, alive, ts_amt)
+                do_write = do & commit[:, None] & (l_op != 0)
+                bal_delta = jnp.sum(jnp.where(do_write, nw - bal, 0),
+                                    dtype=I32)
 
-        new_ctx = SBCtx(
-            acc=l_ac, tbl=l_tb, do_write=do_write, nw=nw,
-            attempted=jnp.asarray(w if gen_new else 0, I32),
-            committed=committed.sum(dtype=I32),
-            ab_lock=(lock_rejected & (l_op[:, 0] != 0)).sum(dtype=I32),
-            ab_logic=logic_abort.sum(dtype=I32),
-            magic_bad=jnp.asarray(0, I32),
-            bal_delta=bal_delta,
-            overflow=(active & ~valid).sum(dtype=I32))
+        with waves.part("dense_sharded_sb", "sbx_frame"):
+            new_ctx = SBCtx(
+                acc=l_ac, tbl=l_tb, do_write=do_write, nw=nw,
+                attempted=jnp.asarray(w if gen_new else 0, I32),
+                committed=committed.sum(dtype=I32),
+                ab_lock=(lock_rejected & (l_op[:, 0] != 0)).sum(dtype=I32),
+                ab_logic=logic_abort.sum(dtype=I32),
+                magic_bad=jnp.asarray(0, I32),
+                bal_delta=bal_delta,
+                overflow=(active & ~valid).sum(dtype=I32))
 
         # ---- wave 2 of c1: route installs to owners -------------------
         with waves.scope("dense_sharded_sb", "install_route"):
-            wmask = c1.do_write.reshape(-1)
-            wdest = (c1.acc.reshape(-1) % d).astype(I32)
-            wrow = (c1.tbl.reshape(-1) * n_loc
-                    + c1.acc.reshape(-1) // d).astype(I32)
-            wpos = _positions(wdest, wmask, d)
-            wvalid = wmask & (wpos < cap)   # no overflow: writes <= locks
-            ifields = [wmask.astype(I32), wrow, c1.nw.reshape(-1),
-                       c1.tbl.reshape(-1), c1.acc.reshape(-1)]
-            if ring is not None:
-                ifields.append(jnp.repeat(txn_c1, L))
-            inst = [_a2a(x, d, cap)
-                    for x in _route(wdest, wpos, wvalid, cap, d, ifields)]
+            with waves.part("dense_sharded_sb", "route_addr"):
+                wmask = c1.do_write.reshape(-1)
+                wdest = (c1.acc.reshape(-1) % d).astype(I32)
+                wrow = (c1.tbl.reshape(-1) * n_loc
+                        + c1.acc.reshape(-1) // d).astype(I32)
+            with waves.part("dense_sharded_sb", "a2a_rank"):
+                wpos = _positions(wdest, wmask, d)
+            with waves.part("dense_sharded_sb", "route_addr"):
+                wvalid = wmask & (wpos < cap)  # no overflow: writes <= locks
+                ifields = [wmask.astype(I32), wrow, c1.nw.reshape(-1),
+                           c1.tbl.reshape(-1), c1.acc.reshape(-1)]
+                if ring is not None:
+                    ifields.append(jnp.repeat(txn_c1, L))
+            with waves.part("dense_sharded_sb", "a2a_pack"):
+                ipacked = _route(wdest, wpos, wvalid, cap, d, ifields)
+            with waves.part("dense_sharded_sb", "a2a_installs"):
+                inst = [_a2a(x, d, cap) for x in ipacked]
             i_m, i_row, i_bal, i_tbl, i_acc = inst[:5]
             i_txn = inst[5] if ring is not None else None
-            i_mask = i_m != 0
 
-            irows = jnp.where(i_mask, i_row, oob)
-            hot_bal = state.hot_bal
-            if use_hotset:
-                # partitioned write-through install (double 1-D
-                # unique-index scatter)
-                i_midx = mirror_idx(i_row, i_mask)
-                bal_new, hot_bal = hotset.hot_scatter(
-                    state.bal, hot_bal, i_row, i_midx, i_mask,
-                    i_bal.astype(U32), 1)
-            else:
-                bal_new = state.bal.at[irows].set(i_bal.astype(U32),
-                                                  mode="drop",
-                                                  unique_indices=True)
+            with waves.part("dense_sharded_sb", "owner_install"):
+                i_mask = i_m != 0
+                irows = jnp.where(i_mask, i_row, oob)
+                hot_bal = state.hot_bal
+                if use_hotset:
+                    # partitioned write-through install (double 1-D
+                    # unique-index scatter)
+                    i_midx = mirror_idx(i_row, i_mask)
+                    bal_new, hot_bal = hotset.hot_scatter(
+                        state.bal, hot_bal, i_row, i_midx, i_mask,
+                        i_bal.astype(U32), 1)
+                else:
+                    bal_new = state.bal.at[irows].set(i_bal.astype(U32),
+                                                      mode="drop",
+                                                      unique_indices=True)
+
+        def log_value(mask, balv):
+            # the value a ring names: {balance, magic}, as
+            # engines/smallbank_dense.py logs it (the table keeps the
+            # balance alone)
+            newval = jnp.zeros((mask.shape[0], VW), U32)
+            newval = newval.at[:, 0].set(balv.astype(U32))
+            return newval.at[:, 1].set(jnp.where(mask, U32(MAGIC), U32(0)))
 
         def mk_entry(mask, row, balv, tblv, accv, ring, bck, slot, src_dev):
             # forwarded entries tag key_hi = SOURCE device + 1 (own entries
             # log 0, below) — same separable-stream convention as the TATP
             # path (parallel/dense_sharded._apply_backup), so recovery can
             # verify a ring's streams against acct % n_shards geometry
-            rr = jnp.where(mask, slot * m1 + row, N_BCK * m1)
-            bck = bck.at[rr].set(balv.astype(U32), mode="drop",
-                                 unique_indices=True)
-            newval = jnp.zeros((mask.shape[0], VW), U32)
-            newval = newval.at[:, 0].set(balv.astype(U32))
-            stepv = jnp.broadcast_to(t, mask.shape)
-            src = jnp.broadcast_to(src_dev.astype(U32) + U32(1), mask.shape)
-            ring = logring.append_rep(ring, mask, tblv,
-                                      jnp.zeros_like(balv),
-                                      src, accv.astype(U32), stepv, newval)
+            with waves.part("dense_sharded_sb", "sb_bck_scatter"):
+                rr = jnp.where(mask, slot * m1 + row, N_BCK * m1)
+                bck = bck.at[rr].set(balv.astype(U32), mode="drop",
+                                     unique_indices=True)
+            with waves.part("dense_sharded_sb", "sb_bck_log_append"):
+                ring = logring.append_rep(
+                    ring, mask, tblv, jnp.zeros_like(balv),
+                    jnp.broadcast_to(src_dev.astype(U32) + U32(1),
+                                     mask.shape),
+                    accv.astype(U32), jnp.broadcast_to(t, mask.shape),
+                    log_value(mask, balv))
             return ring, bck
 
         # owner logs its installs (CommitLog at the primary)
         with waves.scope("dense_sharded_sb", "install_route"):
-            newval = jnp.zeros((d * cap, VW), U32).at[:, 0].set(
-                i_bal.astype(U32))
-            log = logring.append_rep(state.log, i_mask, i_tbl,
-                                     jnp.zeros_like(i_bal),
-                                     jnp.zeros_like(i_bal, U32),
-                                     i_acc.astype(U32),
-                                     jnp.broadcast_to(t, i_mask.shape),
-                                     newval)
+            with waves.part("dense_sharded_sb", "owner_log_append"):
+                log = logring.append_rep(state.log, i_mask, i_tbl,
+                                         jnp.zeros_like(i_bal),
+                                         jnp.zeros_like(i_bal, U32),
+                                         i_acc.astype(U32),
+                                         jnp.broadcast_to(t, i_mask.shape),
+                                         log_value(i_mask, i_bal))
         # CommitBck x2 + CommitLog at the backups: forward applied installs
         with waves.scope("dense_sharded_sb", "replicate"):
             bck = state.bck_bal
             repl_groups = []
             for off in (1, 2):
-                perm = [(i, (i + off) % d) for i in range(d)]
-                pp = functools.partial(jax.lax.ppermute, axis_name=AXIS,
-                                       perm=perm)
-                fwd_mask = pp(i_mask)
-                if cnt is not None:
-                    # replication pushes, counted where they are APPLIED
-                    hop = (mon.CTR_REPL_PUSH_HOP1 if off == 1
-                           else mon.CTR_REPL_PUSH_HOP2)
-                    cnt = mon.bump(cnt, {hop: fwd_mask.sum(dtype=I32)})
-                if ring is not None:
-                    # the forwarded txn id makes the backup-side event
-                    # joinable: same id, shard = the APPLYING device
-                    repl_groups.append(txe.ev(
-                        fwd_mask, pp(i_txn), txe.EV_REPL,
-                        waves.full_name("dense_sharded_sb", "replicate"),
-                        shard=dev, aux=off, step=t.astype(U32)))
-                log, bck = mk_entry(fwd_mask, pp(i_row), pp(i_bal),
-                                    pp(i_tbl), pp(i_acc), log, bck,
-                                    off - 1, (dev - off) % d)
+                with waves.part("dense_sharded_sb", "sb_repl_hop"):
+                    perm = [(i, (i + off) % d) for i in range(d)]
+                    pp = functools.partial(jax.lax.ppermute,
+                                           axis_name=AXIS, perm=perm)
+                    fwd_mask = pp(i_mask)
+                    if cnt is not None:
+                        # replication pushes, counted where they are
+                        # APPLIED
+                        hop = (mon.CTR_REPL_PUSH_HOP1 if off == 1
+                               else mon.CTR_REPL_PUSH_HOP2)
+                        cnt = mon.bump(cnt, {hop: fwd_mask.sum(dtype=I32)})
+                    if ring is not None:
+                        # the forwarded txn id makes the backup-side event
+                        # joinable: same id, shard = the APPLYING device
+                        repl_groups.append(txe.ev(
+                            fwd_mask, pp(i_txn), txe.EV_REPL,
+                            waves.full_name("dense_sharded_sb",
+                                            "replicate"),
+                            shard=dev, aux=off, step=t.astype(U32)))
+                    fwd = (pp(i_row), pp(i_bal), pp(i_tbl), pp(i_acc))
+                    src_dev = (dev - off) % d
+                log, bck = mk_entry(fwd_mask, *fwd, log, bck, off - 1,
+                                    src_dev)
 
-        state = state.replace(bal=bal_new, bck_bal=bck, x_step=x_step,
-                              s_step=s_step, step=t + 1, log=log,
-                              hot_bal=hot_bal, hot_x=hot_x, hot_s=hot_s)
+        with waves.part("dense_sharded_sb", "sbx_frame"):
+            state = state.replace(bal=bal_new, bck_bal=bck, x_step=x_step,
+                                  s_step=s_step, step=t + 1, log=log,
+                                  hot_bal=hot_bal, hot_x=hot_x,
+                                  hot_s=hot_s)
 
         if cnt is not None and use_hotset:
             # partition accounting: 3 hot-partitioned gathers per step
             # (x/s stamps + balances), each serving (midx >= 0) lanes
             # from the mirror
-            n_g = 3
-            hits = (midx >= 0).sum(dtype=I32)
-            cnt = mon.bump(cnt, {
-                mon.CTR_HOT_HITS: n_g * hits,
-                mon.CTR_HOT_COLD_ROWS: n_g * (d * cap) - n_g * hits,
-                mon.CTR_HOT_REFRESH_BYTES: 0,
-            })
+            with waves.part("dense_sharded_sb", "monitor"):
+                n_g = 3
+                hits = (midx >= 0).sum(dtype=I32)
+                cnt = mon.bump(cnt, {
+                    mon.CTR_HOT_HITS: n_g * hits,
+                    mon.CTR_HOT_COLD_ROWS: n_g * (d * cap) - n_g * hits,
+                    mon.CTR_HOT_REFRESH_BYTES: 0,
+                })
         if cnt is not None:
             # txn outcomes + overflow at the SOURCE (c1 completes here);
             # lock arbitration + installs at the OWNER (they ran here) —
             # either way each event is counted on exactly one device, so
             # the device-axis sum reconciles with the psummed stats
-            req = r_op != 0
-            grant = grant_x | grant_s
-            rej = req & ~grant
-            held = held_x | held_s
-            cnt = mon.bump(cnt, {
-                mon.CTR_STEPS: 1,
-                mon.CTR_TXN_ATTEMPTED: c1.attempted,
-                mon.CTR_TXN_COMMITTED: c1.committed,
-                mon.CTR_AB_LOCK: c1.ab_lock,
-                mon.CTR_AB_LOGIC: c1.ab_logic,
-                mon.CTR_MAGIC_BAD: c1.magic_bad,
-                mon.CTR_ROUTE_OVERFLOW: c1.overflow,
-                mon.CTR_LOCK_REQUESTS: req.sum(dtype=I32),
-                mon.CTR_LOCK_GRANTED: grant.sum(dtype=I32),
-                mon.CTR_LOCK_REJECTED: rej.sum(dtype=I32),
-                mon.CTR_LOCK_REJECT_HELD: (rej & held).sum(dtype=I32),
-                mon.CTR_LOCK_REJECT_ARB: (rej & ~held).sum(dtype=I32),
-                mon.CTR_INSTALL_WRITES: i_mask.sum(dtype=I32),
-                mon.CTR_LOG_APPENDS: i_mask.sum(dtype=I32),
-                mon.CTR_DISPATCH_XLA: 1,
-            })
-            cnt = mon.gauge_max(cnt, {mon.CTR_RING_HWM: log.head.max()})
+            with waves.part("dense_sharded_sb", "monitor"):
+                req = r_op != 0
+                grant = grant_x | grant_s
+                rej = req & ~grant
+                held = held_x | held_s
+                # how much of the new cohort is distributed, at its
+                # SOURCE: transactions whose lock set names rows of more
+                # than one owner, lock requests whose owner is another
+                # device (a drain generates nothing and counts nothing)
+                act2 = active.reshape(w, L)
+                own2 = dest.reshape(w, L)
+                xshard = (act2 & (own2 != own2[:, :1])).any(axis=1)
+                cnt = mon.bump(cnt, {
+                    mon.CTR_STEPS: 1,
+                    mon.CTR_TXN_ATTEMPTED: c1.attempted,
+                    mon.CTR_TXN_COMMITTED: c1.committed,
+                    mon.CTR_AB_LOCK: c1.ab_lock,
+                    mon.CTR_AB_LOGIC: c1.ab_logic,
+                    mon.CTR_MAGIC_BAD: c1.magic_bad,
+                    mon.CTR_ROUTE_OVERFLOW: c1.overflow,
+                    mon.CTR_LOCK_REQUESTS: req.sum(dtype=I32),
+                    mon.CTR_LOCK_GRANTED: grant.sum(dtype=I32),
+                    mon.CTR_LOCK_REJECTED: rej.sum(dtype=I32),
+                    mon.CTR_LOCK_REJECT_HELD: (rej & held).sum(dtype=I32),
+                    mon.CTR_LOCK_REJECT_ARB: (rej & ~held).sum(dtype=I32),
+                    mon.CTR_INSTALL_WRITES: i_mask.sum(dtype=I32),
+                    mon.CTR_LOG_APPENDS: i_mask.sum(dtype=I32),
+                    mon.CTR_DISPATCH_XLA: 1,
+                    mon.CTR_XSHARD_TXNS: xshard.sum(dtype=I32),
+                    mon.CTR_REMOTE_LOCK_LANES:
+                        (active & (dest != dev)).sum(dtype=I32),
+                })
+                cnt = mon.gauge_max(cnt,
+                                    {mon.CTR_RING_HWM: log.head.max()})
 
         if ring is not None:
             # dinttrace: each event lands on exactly ONE device — ROUTE/
@@ -580,9 +635,12 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
                 )
                 ring, cnt = txe.emit(ring, tcfg, groups, cnt)
 
-        new_ctx = jax.tree.map(lambda x: pcast_varying(x, AXIS), new_ctx)
-        return (state, new_ctx, jax.lax.psum(_stats_of(c1), AXIS), cnt,
-                ring)
+        with waves.part("dense_sharded_sb", "sbx_frame"):
+            new_ctx = jax.tree.map(lambda x: pcast_varying(x, AXIS),
+                                   new_ctx)
+        with waves.part("dense_sharded_sb", "stats"):
+            stats = jax.lax.psum(_stats_of(c1), AXIS)
+        return state, new_ctx, stats, cnt, ring
 
     def scan_fn(carry, key, gen_new=True):
         state, c1 = carry[:2]
@@ -607,21 +665,30 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
 
     def block_local(*args):
         key = args[-1]
-        keys = jax.random.split(key, cohorts_per_block)
-        carry, stats = jax.lax.scan(
-            scan_fn, _reset_ring(tuple(sq(a) for a in args[:-1])), keys)
-        return tuple(unsq(x) for x in carry) + (stats,)
+        with waves.part("dense_sharded_sb", "block_pre"):
+            keys = jax.random.split(key, cohorts_per_block)
+        with waves.part("dense_sharded_sb", "sbx_carry"):
+            carry = tuple(sq(a) for a in args[:-1])
+        with waves.part("dense_sharded_sb", "block_pre"):
+            carry = _reset_ring(carry)
+        carry, stats = jax.lax.scan(scan_fn, carry, keys)
+        with waves.part("dense_sharded_sb", "sbx_carry"):
+            return tuple(unsq(x) for x in carry) + (stats,)
 
     def drain_local(*args):
         key = args[-1]
-        carry, s1 = scan_fn(_reset_ring(tuple(sq(a) for a in args[:-1])),
-                            key, gen_new=False)
-        out = (unsq(carry[0]),)
-        if trace_on:
-            out = out + (unsq(carry[2]),)
-        if monitor:
-            out = out + (unsq(carry[-1]),)
-        return out + (jnp.stack([s1]),)
+        with waves.part("dense_sharded_sb", "sbx_carry"):
+            carry = tuple(sq(a) for a in args[:-1])
+        with waves.part("dense_sharded_sb", "block_pre"):
+            carry = _reset_ring(carry)
+        carry, s1 = scan_fn(carry, key, gen_new=False)
+        with waves.part("dense_sharded_sb", "sbx_carry"):
+            out = (unsq(carry[0]),)
+            if trace_on:
+                out = out + (unsq(carry[2]),)
+            if monitor:
+                out = out + (unsq(carry[-1]),)
+            return out + (jnp.stack([s1]),)
 
     n_carry = 2 + int(trace_on) + int(monitor)
     spec = (P(AXIS),) * n_carry + (P(),)

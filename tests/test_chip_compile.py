@@ -27,6 +27,7 @@ from dint_tpu.engines import store
 from dint_tpu.engines import tatp_dense as td
 from dint_tpu.ops import compact
 from dint_tpu.parallel import dense_sharded as ds
+from dint_tpu.parallel import dense_sharded_sb as dsb
 from dint_tpu.tables import kv
 
 HBM_BYTES = 16e9                 # one v5e chip
@@ -500,3 +501,51 @@ def test_dense_sharded_block_program_on_four_chips(topo):
         assert new < 2.01 * ma.argument_size_in_bytes + 1e6
         worst = max(worst, new)
     assert 6.1e9 < worst and total / n + worst < 11.6e9 < HBM_BYTES
+
+
+def test_dense_sharded_sb_block_program_on_four_chips(topo):
+    """`smallbank24m-x4r3` as the benchmark builds it: 24 M accounts over
+    a 4-device v5e mesh, w = 8,192 x 16 cohorts, counters on. The block
+    and the drain compile, the donated carry is updated in place, a
+    step's exchange is nine all-to-alls and its replication ten
+    collective-permutes, and the temporaries are the two arbitration
+    arrays and the stacked carry's copies, not a second state."""
+    n_acc = 24_000_000
+    mesh = Mesh(np.array(topo.devices), (dsb.AXIS,))
+    n = mesh.size
+    run, init, drain = dsb.build_sharded_sb_runner(
+        mesh, n, n_acc, w=W, cohorts_per_block=CPB, monitor=True,
+        use_hotset=False, trace=False, mix=(15, 15, 15, 25, 15, 15),
+        hot_frac=0.04, hot_prob=0.9)
+    by_device = NamedSharding(mesh, P(dsb.AXIS))
+    state = placed(jax.eval_shape(
+        lambda: dsb.create_sharded_sb(mesh, n, n_acc)), by_device)
+    key = placed(jax.eval_shape(lambda: jax.random.PRNGKey(0)),
+                 NamedSharding(mesh, P()))
+    carry = placed(jax.eval_shape(init, state), by_device)
+    m1 = dsb.m1_local(n_acc, n)
+    assert m1 == 12_000_001
+    a_device = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                   for x in jax.tree.leaves(carry)) / n
+    # primary 48 MB, two backups 96, two stamp tables 96, the ring 25
+    assert 0.26e9 < a_device < 0.27e9
+
+    (_, block), = dispatches(run, carry, key)
+    (_, steps), = dispatches(drain, carry, also=key)
+    # (program, ceiling on its temporaries, all-to-alls). 0.2866 GB and
+    # 0.1364 (AOT, PR 43): two fresh 48 MB arbitration arrays a step and
+    # the `[1, N]` carry's copies at the block's entry and exit (the form
+    # PR 42 took out of dense_sharded; ROADMAP Queue 1). A drain generates
+    # no cohort: its request exchange is a constant's, folded to six.
+    for c, ceiling, a2a in ((block, 0.30e9, 9), (steps, 0.15e9, 6)):
+        ma = c.memory_analysis()
+        assert abs(ma.argument_size_in_bytes - a_device) < 0.01 * a_device
+        assert ma.alias_size_in_bytes > 0.99 * ma.argument_size_in_bytes
+        assert ma.temp_size_in_bytes < ceiling
+        assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+        hlo = c.as_text()
+        assert len(re.findall(r" all-to-all(?:-start)?\(", hlo)) == a2a
+        assert len(re.findall(r" collective-permute(?:-start)?\(",
+                              hlo)) == 10
+        # the stacked carry's squeeze and unsqueeze are real ops here
+        assert "part.sbx_carry" in hlo and "part.a2a_pack" in hlo

@@ -3,6 +3,7 @@ mesh — a SendPayment's two accounts land on different devices, its locks
 are granted remotely, and global balance conservation must still hold."""
 import jax
 import numpy as np
+import pytest
 
 from dint_tpu.engines import smallbank_dense as sd
 from dint_tpu.parallel import dense_sharded_sb as dsb
@@ -107,8 +108,6 @@ def test_lost_device_balance_range_recovers_from_any_ring():
     # geometry check: the key_hi source tags expose a ring replayed under
     # the wrong n_shards (here: wrong ring_owner stands in for geometry
     # drift — tags no longer match acct % D)
-    import pytest
-
     wrong = (1 + 3) % D
     with pytest.raises(ValueError, match="source tags"):
         recovery.recover_sb_shard(
@@ -155,3 +154,176 @@ def test_route_overflow_fires_and_reconciles_with_monitor():
     snap = mon.snapshot(cnt)
     assert snap["route_overflow"] == overflow
     assert snap["txn_attempted"] == attempted
+
+
+# ------------------------- against the sequential reference (PR 43) -------
+# dint_tpu/testing/smallbank_sharded.py: a step's cohorts taken as one in
+# (source device, lane, lock-set) order through the sequential oracle
+
+D4, N4, W4, CPB4, BLOCKS4 = 4, 4000, 96, 2, 3
+
+
+def _cohorts_again(n_accounts, w, cpb, d):
+    """block key -> (ttype, a1, a2, ts_amt), each [cpb, d, w]: what the d
+    devices generate from it (`block_local`'s split, `local_step`'s fold
+    of the device index and its second split)."""
+    import jax.numpy as jnp
+
+    from dint_tpu.engines import smallbank_pipeline as sp
+
+    def one(step_key, dev):
+        kgen, kamt = jax.random.split(jax.random.fold_in(step_key, dev))
+        amounts = jax.random.randint(kamt, (w,), -sp.TS_AMT_MAX,
+                                     sp.TS_AMT_MAX + 1, dtype=jnp.int32)
+        return (*sp.gen_cohort(kgen, w, n_accounts, hot_frac=0.05,
+                               hot_prob=0.9), amounts)
+
+    devs = jnp.arange(d, dtype=jnp.int32)
+    return jax.jit(lambda key: jax.vmap(
+        lambda k: jax.vmap(lambda i: one(k, i))(devs))(
+            jax.random.split(key, cpb)))
+
+
+def _stream(ring, heads, tag):
+    """Sorted (table, account, step, balance, magic) of one stream of an
+    unwrapped ring [L, CAP, EW]."""
+    rows = [ring[lane, :int(h)] for lane, h in enumerate(heads)]
+    e = np.concatenate(rows)
+    e = e[e[:, 1] == tag]
+    return sorted(zip((e[:, 0] >> 8).tolist(), e[:, 2].tolist(),
+                      e[:, 3].tolist(), e[:, 4].tolist(), e[:, 5].tolist()))
+
+
+@pytest.mark.parametrize("seed", [0, 11, 2**31 + 5])
+def test_every_replica_equals_the_sequential_reference(seed):
+    """Four devices, a hot set small enough that locks collide across
+    devices: every step's psummed stats row, every primary, both backup
+    slots of every device and all three streams of every device's log
+    (entries {balance, magic}) equal the reference's; the two counters of
+    distributed traffic equal a count made from the cohorts themselves;
+    a lost device's range comes back from each of its streams."""
+    from dint_tpu.monitor import counters as mon
+    from dint_tpu.testing import smallbank_sharded as ref
+
+    mesh = dsb.make_mesh(D4)
+    state = dsb.create_sharded_sb(mesh, D4, N4, log_capacity=1 << 9)
+    run, init, drain = dsb.build_sharded_sb_runner(
+        mesh, D4, N4, w=W4, cohorts_per_block=CPB4, hot_frac=0.05,
+        hot_prob=0.9, monitor=True)
+    again = _cohorts_again(N4, W4, CPB4, D4)
+    bank = ref.ShardedSmallBank(N4, D4, cap=ref.bucket_cap(W4, D4))
+    fast = ref.ShardedSmallBank(N4, D4, cap=ref.bucket_cap(W4, D4),
+                                by_cohort=True)
+    key = jax.random.PRNGKey(seed % (1 << 32))
+    carry, got, want = init(state), [], [np.zeros(dsb.N_STATS, np.int64)]
+    for i in range(BLOCKS4):
+        k = jax.random.fold_in(key, i)
+        carry, stats = run(carry, k)
+        got.append(np.asarray(stats, np.int64))
+        block = [np.asarray(x) for x in again(k)]
+        for j in range(CPB4):
+            cohorts = [tuple(x[j, d] for x in block) for d in range(D4)]
+            want.append(bank.step(cohorts))
+            np.testing.assert_array_equal(fast.step(cohorts), want[-1])
+    state, tail, cnt = drain(carry)
+    bank.drain(), fast.drain()
+    got = np.concatenate([*got, np.asarray(tail, np.int64)])
+    np.testing.assert_array_equal(got, np.stack(want))
+    total = got.sum(axis=0)
+    assert total[dsb.STAT_COMMITTED] > 0 and total[dsb.STAT_AB_LOCK] > 0
+    assert total[dsb.STAT_OVERFLOW] == 0
+    assert bank.bank.tally["x_rejected_prev_x"] > 0     # across steps
+
+    snap = mon.snapshot(cnt)
+    assert snap["xshard_txns"] == bank.distributed["xshard_txns"] > 0
+    assert snap["remote_lock_lanes"] \
+        == bank.distributed["remote_lock_lanes"] > 0
+    assert snap["lock_requests"] == bank.distributed["lock_lanes"]
+    assert snap["txn_attempted"] == bank.distributed["txns"]
+
+    bal, bck = np.asarray(state.bal), np.asarray(state.bck_bal)
+    m1 = bal.shape[1]
+    rings = np.asarray(state.log.entries).reshape(
+        D4, state.log.lanes, -1, state.log.entries.shape[-1])
+    heads = np.asarray(state.log.head)
+    assert (heads <= rings.shape[2]).all()              # none wrapped
+    for d in range(D4):
+        table = bank.table(d)
+        np.testing.assert_array_equal(bal[d], table)
+        np.testing.assert_array_equal(fast.table(d), table)
+        assert (table != ref.fresh_table(bank.n_loc, 1000)).any()
+        for holder, slot in bank.where[d]["backups"]:
+            np.testing.assert_array_equal(
+                bck[holder, slot * m1:(slot + 1) * m1], table)
+        for ring, tag in bank.where[d]["streams"]:
+            entries = _stream(rings[ring], heads[ring], tag)
+            assert entries == sorted(bank.stream(d)) and entries
+            assert {e[4] for e in entries} == {0x5B5B}
+            np.testing.assert_array_equal(
+                ref.replay(entries, d, N4, D4), bal[d])
+    assert dsb.total_balance_global(state) == bank.total_balance()
+
+
+_SB_CACHE_CHILD = r'''
+import collections, json, sys
+sys.path.insert(0, sys.argv[1])
+import jax
+import numpy as np
+from dint_tpu import _runtime
+from dint_tpu.parallel import dense_sharded_sb as dsb
+_runtime.enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+events = collections.Counter()
+jax.monitoring.register_event_listener(
+    lambda name, **kw: events.update((name,)))
+mesh = dsb.make_mesh(4)
+state = dsb.create_sharded_sb(mesh, 4, 4096, log_capacity=1 << 9)
+run, init, drain = dsb.build_sharded_sb_runner(
+    mesh, 4, 4096, w=32, cohorts_per_block=2, monitor=True)
+carry, stats = init(state), []
+for i in range(3):
+    carry, s = run(carry, jax.random.PRNGKey(i))
+    stats.append(np.asarray(s).tolist())
+state, tail, counters = drain(carry)
+print(json.dumps({
+    "stats": stats + [np.asarray(tail).tolist()],
+    "counters": np.asarray(counters.buf).tolist(),
+    "heads": np.asarray(state.log.head).tolist(),
+    "bal": int(np.asarray(state.bal, np.int64).sum()),
+    "bck": int(np.asarray(state.bck_bal, np.int64).sum()),
+    "log": int(np.asarray(state.log.entries, np.int64).sum()),
+    "hits": events["/jax/compilation_cache/cache_hits"],
+    "misses": events["/jax/compilation_cache/cache_misses"]}))
+'''
+
+
+def test_a_second_process_loads_the_sharded_programs_from_the_cache(
+        tmp_path):
+    """Two processes in a row, one compile cache directory: the second
+    compiles nothing (the sharded, donated block and drain, nine
+    all_to_alls and ten ppermutes each, among what it loads) and gives
+    the first's stats, counters, tables and rings bit for bit: what the
+    four-chip cell's warm runs rest on."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env.update(JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(
+        tmp_path / "cache"),
+        XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    lines = []
+    for _ in range(2):
+        c = subprocess.run([sys.executable, "-c", _SB_CACHE_CHILD, repo],
+                           env=env, capture_output=True, text=True,
+                           timeout=600)
+        assert c.returncode == 0, c.stderr[-2000:]
+        lines.append(json.loads(c.stdout.strip().splitlines()[-1]))
+    first, second = lines
+    assert first["hits"] == 0 and first["misses"] >= 2      # block, drain
+    assert second["misses"] == 0 and second["hits"] == first["misses"]
+    for k in ("stats", "counters", "heads", "bal", "bck", "log"):
+        assert first[k] == second[k], k
+    assert sum(first["heads"][0]) > 0

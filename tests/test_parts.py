@@ -24,6 +24,7 @@ from dint_tpu.engines import store
 from dint_tpu.engines import tatp_dense as td
 from dint_tpu.monitor import waves
 from dint_tpu.parallel import dense_sharded as ds
+from dint_tpu.parallel import dense_sharded_sb as dsb
 from dint_tpu.tables import kv
 
 pytestmark = pytest.mark.scope
@@ -100,10 +101,12 @@ def test_part_rejects_unregistered_name():
 
 def test_an_engine_neutral_part_is_shared_by_the_dense_engines_alone():
     """... and, since the cell store-ycsb-b, by the KV store's runner,
-    which steps a block as they do (engines/store.py)."""
+    which steps a block as they do (engines/store.py), and since the cell
+    smallbank24m-x4-sat by the sharded SmallBank's
+    (parallel/dense_sharded_sb.py)."""
     for name in ("monitor", "stats", "block_pre"):
         assert waves.PART_OWNERS[name] == ("tatp_dense", "smallbank_dense",
-                                           "store")
+                                           "store", "dense_sharded_sb")
         for owner in waves.PART_OWNERS[name]:
             with waves.part(owner, name):
                 pass
@@ -432,5 +435,127 @@ def test_store_parts_are_semantics_neutral(monkeypatch):
                         lambda owner, name: contextlib.nullcontext())
     b = run_once()
     assert a[0][:, 1].sum() > 0
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(x, y)
+
+
+# ------------------------------- the sharded SmallBank's block (PR 43)
+
+SBX = "dint.dense_sharded_sb."
+# (part, the waves it is opened under): a part the lock requests and the
+# installs share is registered with no wave and opened under both
+SBX_PARTS = {
+    "route_addr": ("route", "install_route"),
+    "a2a_rank": ("route", "install_route"),
+    "a2a_pack": ("route", "install_route"),
+    "a2a_requests": ("route",),
+    "owner_arb": ("arbitrate",), "owner_held_read": ("arbitrate",),
+    "owner_grant": ("arbitrate",), "owner_stamp": ("arbitrate",),
+    "owner_bal_read": ("arbitrate",),
+    "a2a_replies": ("reply",), "reply_unpack": ("reply",),
+    "reply_classify": ("reply",),
+    "a2a_installs": ("install_route",), "owner_install": ("install_route",),
+    "owner_log_append": ("install_route",),
+    "sb_repl_hop": ("replicate",), "sb_bck_scatter": ("replicate",),
+    "sb_bck_log_append": ("replicate",),
+    "sbx_frame": (), "sbx_carry": (), "owner_addr": ()}
+
+
+def _sharded_bank(monitor=True):
+    mesh = dsb.make_mesh(4)
+    state = dsb.create_sharded_sb(mesh, 4, 4096, log_capacity=1 << 9)
+    run, init, drain = dsb.build_sharded_sb_runner(
+        mesh, 4, 4096, w=32, cohorts_per_block=2, monitor=monitor)
+    return run, init(state), drain
+
+
+def test_the_sharded_smallbank_parts_are_registered_under_their_waves():
+    rows = {p: (o, w) for o, w, p, _ in waves._PARTS}
+    for part, under in SBX_PARTS.items():
+        assert rows[part] == ("dense_sharded_sb",
+                              under[0] if len(under) == 1 else None)
+        assert waves.PART_OWNERS[part] == ("dense_sharded_sb",)
+    with pytest.raises(KeyError, match="part registry"):
+        waves.part("dense_sharded", "sb_repl_hop")
+    with pytest.raises(KeyError, match="part registry"):
+        waves.part("dense_sharded_sb", "repl_hop")
+
+
+@pytest.mark.parametrize("monitor", [True, False])
+def test_every_equation_of_the_sharded_smallbank_block_carries_a_part(
+        monitor):
+    """No equation of the block without a wave or a part, and under each
+    of its six waves every leaf equation is booked to a part of that
+    wave (the LAST `part.` on its stack; `log_plan` / `log_scatter` inside
+    an append): the collectives to the exchange they belong to, the
+    scatters to the table they write."""
+    run, carry, _ = _sharded_bank(monitor)
+    closed = jax.make_jaxpr(run)(carry, jax.random.PRNGKey(0))
+    assert _unnamed_equations(closed.jaxpr, False, (), []) == []
+    collectives = {"route": ("a2a_requests", 2), "reply": ("a2a_replies", 2),
+                   "install_route": ("a2a_installs", 5)}
+    seen = set()
+    for wave in ("gen", "route", "arbitrate", "reply", "install_route",
+                 "replicate"):
+        under = _equations_under(closed.jaxpr, SBX + wave, "", [])
+        assert under, wave
+        mine = {p for p, ws in SBX_PARTS.items() if wave in ws}
+        for prim, stack in under:
+            after = stack[stack.index(SBX + wave):]
+            parts = [p for p in re.findall(r"part\.([a-z0-9_]+)", after)]
+            if wave == "gen":
+                assert not parts
+                continue
+            assert parts and parts[0] in mine, (prim, stack)
+            assert parts[1:] in ([], ["log_plan"], ["log_scatter"]), stack
+            if parts[1:]:
+                assert parts[0].endswith("log_append")
+            seen.add(parts[0])
+            if prim == "all_to_all":
+                assert parts == [collectives[wave][0]]
+            if prim == "ppermute":
+                assert parts == ["sb_repl_hop"]
+        if wave in collectives:
+            assert sum(p == "all_to_all" for p, _ in under) \
+                == collectives[wave][1]
+    assert seen == {p for p, ws in SBX_PARTS.items() if ws}
+    under = _equations_under(closed.jaxpr, SBX + "replicate", "", [])
+    assert sum(p == "ppermute" for p, _ in under) == 2 * 5
+
+
+def test_the_sharded_smallbank_parts_reach_compiled_hlo_under_their_waves():
+    run, carry, _ = _sharded_bank()
+    names = _op_names(jax.jit(run).lower(
+        carry, jax.random.PRNGKey(0)).compile().as_text())
+    # the CPU compiles the carry's `x[0]` / `x[None]` to bitcasts, which
+    # keep no name; the v5e's copies do (tests/test_chip_compile.py)
+    _assert_parts_under_their_waves(names, ("dense_sharded_sb",),
+                                    skip=("sbx_carry",))
+    for part, under in SBX_PARTS.items():
+        for wave in under:
+            assert any(re.search(rf"{re.escape(SBX + wave)}/.*part\.{part}"
+                                 r"(/|$)", n) for n in names), (part, wave)
+    # append_rep's own parts lie inside the owner's and the backups' appends
+    for outer in ("owner_log_append", "sb_bck_log_append"):
+        assert any(re.search(rf"part\.{outer}/.*part\.log_scatter", n)
+                   for n in names), outer
+
+
+def test_sharded_smallbank_parts_are_semantics_neutral(monkeypatch):
+    def run_once():
+        run, carry, drain = _sharded_bank()
+        carry, stats = run(carry, jax.random.PRNGKey(3))
+        state, tail, counters = drain(carry)
+        return [np.asarray(x) for x in (
+            stats, tail, counters.buf, state.bal, state.bck_bal,
+            state.x_step, state.s_step, state.log.entries, state.log.head)]
+
+    a = run_once()
+    dsb.build_sharded_sb_runner.cache.clear()       # not in the memo's key
+    monkeypatch.setattr(waves, "part",
+                        lambda owner, name: contextlib.nullcontext())
+    b = run_once()
+    dsb.build_sharded_sb_runner.cache.clear()
+    assert a[0][:, 1].sum() > 0 and a[7].any()
     for x, y in zip(a, b):
         np.testing.assert_array_equal(x, y)
