@@ -171,7 +171,11 @@ _REGISTRY: tuple[tuple[str, str, str], ...] = (
      "of its first loop (value words and versions), C = chunk_lanes(w), "
      "with the same identity and fill share; its second loop (valid and "
      "key words, for inserts and deletes only) is not counted, and makes "
-     "no trip under a GET / SET mix over resident keys"),
+     "no trip under a GET / SET mix over resident keys. The sharded "
+     "SmallBank (PR 44) is the third: the trips of the owner's ring "
+     "append over its inbox, C = chunk_lanes(D x cap), same identity and "
+     "fill share (its install into the primary stays one full-width "
+     "scatter)"),
     ("lock_chunks", FLOW,
      "lock-wave compaction (ops/compact.py): chunk trips of the dense "
      "TATP lock wave's first loop (the second makes as many), C = "
@@ -188,7 +192,9 @@ _REGISTRY: tuple[tuple[str, str, str], ...] = (
      "repl_push_hop2) / (C x bck_chunks) is the fill share of the lanes "
      "the backups' scatters issue. Summed over the mesh it is 2 x "
      "install_chunks (a receiver makes its sender's trips). 0 off the "
-     "mesh"),
+     "mesh. The sharded SmallBank (PR 44) is the second owner: the trips "
+     "of its two forwarded ring appends, at the receiving device, with "
+     "the same two identities"),
     ("store_gets", FLOW,
      "KV store (engines/store.py build_serve_runner): admitted GET lanes "
      "— reconciles with the runner's stats column `gets`"),

@@ -336,7 +336,7 @@ _STEPPED = _DENSE + ("store", "dense_sharded_sb")
 # engines where the part is engine-neutral and each of them opens it in
 # its own step (`monitor`, `stats`, `block_pre`); the wave is
 # the one it lies under in that owner's step, None for what a step does
-# outside every wave. append_rep_live's parts also run under
+# outside every wave. append_rep's parts also run under
 # `dense_sharded.replicate`, where a backup appends, inside that wave's
 # own `bck_log_append`. The innermost part on an op's name stack is the
 # one its time is booked to (benchmarks/part_times.py).
@@ -385,7 +385,8 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
      "lane / rank / slot plan and the replica-packed entry rows"),
     ("log", "log_append", "log_scatter",
      "unique-index row scatter of the live entries into the rings, C "
-     "lanes a chunk (append_rep: all R at once) + the head advance"),
+     "lanes a chunk, each chunk's lanes and its gathers out of lane "
+     "space (under a plain mask: all R at once) + the head advance"),
     # --- write-set compaction (ops/compact.py), appended in PR 30 -------
     ("tatp_dense", "install", "ws_compact",
      "the running count of live write slots (one 2w-element cumsum), "
@@ -545,9 +546,12 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
      "the owner's unique-index scatter of the arrived balances into its "
      "primary table"),
     ("dense_sharded_sb", "install_route", "owner_log_append",
-     "the [D x cap, VW] value rows {balance, magic} and append_rep at "
-     "full width into the owner's ring, tag 0 (log_plan and log_scatter "
-     "lie inside)"),
+     "the [D x cap, VW] value rows {balance, magic}, the inbox's segment "
+     "counts (compact.prefixed: its install mask is D segments of cap "
+     "slots, each live in a prefix, so no lane search) and append_rep "
+     "into the owner's ring, tag 0: log_plan at full width, log_scatter "
+     "the live rows, C a trip of its chunk loop, lie inside "
+     "(install_chunks counts the trips)"),
     ("dense_sharded_sb", "replicate", "sb_repl_hop",
      "one hop's five ppermutes of the applied installs (mask, row, "
      "balance, table, account) to device d + off, the sender's index and "
@@ -556,9 +560,10 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
      "the backup slot's row ids and the unique-index scatter of the "
      "forwarded balances into it, D x cap lanes"),
     ("dense_sharded_sb", "replicate", "sb_bck_log_append",
-     "the forwarded stream's tag (source + 1), the {balance, magic} rows "
-     "and append_rep at full width into this device's ring (log_plan and "
-     "log_scatter lie inside)"),
+     "the forwarded stream's tag (source + 1), the {balance, magic} rows, "
+     "the forwarded mask's segment counts and append_rep into this "
+     "device's ring: log_plan at full width, log_scatter the live rows, C "
+     "a trip, lie inside (bck_chunks counts both hops' trips)"),
 )
 
 # keyed on the part's name alone: the scope is `part.<name>`, so two

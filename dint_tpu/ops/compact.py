@@ -8,9 +8,10 @@ scatter: each lane's turn among the live ones (``live_ranks``: one cumsum),
 then the scatter runs over fixed chunks of the live lanes, as many as the
 live count needs (``for_chunks``: a ``while_loop`` whose trip count the
 step itself observes: 0 trips when nothing is live, the whole width when
-everything is). All of it vector work. Four users: the install and the
+everything is). All of it vector work. Five users: the install and the
 log append of dense TATP under the write mask (PR 30; ``tables/log.
-append_rep_live``), its lock wave under the mask of active write slots
+append_rep`` under a ``Ranked`` mask), its lock wave under the mask of
+active write slots
 (PR 34: ~11 % of the 2w; the stamp gather and the winner read-back are
 chunked with the scatter-max, since a gather lane on the sentinel row
 costs what a live one costs, and a chunk's verdicts go back to lane space
@@ -22,7 +23,15 @@ the mesh at full width), and the KV store's install under the mask of a
 step's elected writers (PR 40, ``engines/store._install_live``: ~5 % of
 the lanes under YCSB-B; one loop for value words and versions, a second,
 idle under a GET / SET mix, for the words that change only when a slot is
-allocated or freed). Tried on the chip and left
+allocated or freed), and the three ring appends of ``parallel/
+dense_sharded_sb`` under an inbox's install mask (PR 44: ~17 % of the
+D x cap slots). That one is different: the mask is D segments of ``cap``
+slots, each live in a PREFIX (a source fills a destination's bucket in
+arrival order, the all_to_all moves whole buckets), so ``prefixed`` counts
+the segments and a chunk's lanes follow from the D counts, D compares a
+position: no running count over the lanes and no chunk x R search, which
+at R = 49,152 would cost what a third of the dropped rows do. Tried on
+the chip and left
 (PERF.md §6, PR 30): one sort of the lane ids, 0.11 ms a step faster in
 ``tatp7m-sat``, but the protocol proofs read a sort as the generic
 engines' segment evidence (analysis/dataflow.py SORTED) and would have
@@ -46,6 +55,7 @@ not (the serve block: 8,192 lanes, 95 % of them dead).
 """
 from __future__ import annotations
 
+import flax.struct
 import jax
 import jax.numpy as jnp
 
@@ -106,14 +116,14 @@ def live_ranks(mask):
     return ranks, ranks[-1]
 
 
-def for_chunks(ranks, n_live, chunk: int, body, carry):
-    """``carry = body(carry, lanes, ok)`` over the live lanes in lane
-    order, ``chunk`` of them a trip: ``lanes`` i32 [chunk] are lane ids
-    (the chunk's positions compared with every rank: chunk x R compares,
-    no gather), ``ok`` marks the positions below ``n_live`` (a body routes
-    the others out of bounds). Returns (carry, trips);
-    trips = ceil(n_live / chunk)."""
-    last = ranks.shape[0] - 1
+def _ranked_lane(ranks, at):
+    return jnp.searchsorted(ranks, at + 1, method="compare_all")
+
+
+def _chunk_loop(lane_of, r: int, n_live, chunk: int, body, carry):
+    """The loop of ``for_chunks`` over a mask of ``r`` lanes whose j-th
+    live lane (from 0) is ``lane_of(j)``."""
+    last = r - 1
     pos = jnp.arange(chunk, dtype=I32)
 
     def more(state):
@@ -122,12 +132,88 @@ def for_chunks(ranks, n_live, chunk: int, body, carry):
     def one(state):
         i, carry = state
         at = i * chunk + pos
-        lanes = jnp.searchsorted(ranks, at + 1, method="compare_all")
+        lanes = lane_of(at)
         return i + 1, body(carry, jnp.minimum(lanes.astype(I32), last),
                            at < n_live)
 
     trips, carry = jax.lax.while_loop(more, one, (jnp.asarray(0, I32), carry))
     return carry, trips
+
+
+def for_chunks(ranks, n_live, chunk: int, body, carry):
+    """``carry = body(carry, lanes, ok)`` over the live lanes in lane
+    order, ``chunk`` of them a trip: ``lanes`` i32 [chunk] are lane ids
+    (the chunk's positions compared with every rank: chunk x R compares,
+    no gather), ``ok`` marks the positions below ``n_live`` (a body routes
+    the others out of bounds). Returns (carry, trips);
+    trips = ceil(n_live / chunk)."""
+    return _chunk_loop(lambda at: _ranked_lane(ranks, at), ranks.shape[0],
+                       n_live, chunk, body, carry)
+
+
+class Live:
+    """A mask together with what enumerates its live lanes, made once and
+    handed to a masked op IN PLACE of the plain mask: the op then issues
+    the live lanes only, ``chunk`` of them a trip (tables/log.append_rep).
+    Two forms, by what the maker knows of the mask: ``Ranked`` (nothing:
+    the running count, a search a chunk) and ``Prefixed`` (equal segments,
+    each live in a prefix: no search). Both have ``mask`` bool [R],
+    ``n_live`` i32 and ``lane_of(at)``, the lane of the at-th live one."""
+
+    @property
+    def chunk(self) -> int:
+        return chunk_lanes(self.mask.shape[0])
+
+    @property
+    def trips(self):
+        """The trips ``for_chunks`` makes: ceil(n_live / chunk)."""
+        return (self.n_live + (self.chunk - 1)) // self.chunk
+
+    def for_chunks(self, body, carry):
+        """``for_chunks`` of the module over this mask's live lanes."""
+        return _chunk_loop(self.lane_of, self.mask.shape[0], self.n_live,
+                           self.chunk, body, carry)
+
+
+@flax.struct.dataclass
+class Ranked(Live):
+    """``Ranked(mask, *live_ranks(mask))``: any mask; a chunk's lanes by
+    search (chunk x R compares a trip, R x n_live in all)."""
+    mask: jax.Array      # bool [R]
+    ranks: jax.Array     # i32 [R]
+    n_live: jax.Array    # i32
+
+    def lane_of(self, at):
+        return _ranked_lane(self.ranks, at)
+
+
+@flax.struct.dataclass
+class Prefixed(Live):
+    """``prefixed(mask, S)``: a mask of S equal segments, each live in a
+    PREFIX (mask[s * cap + p] == (p < counts[s])): what a bucket exchange
+    delivers when every source fills a destination's bucket in arrival
+    order (parallel/dense_sharded_sb._route). The j-th live lane is then
+    j plus the dead tails of the segments that end at or before j: S
+    compares a position, no search. A live lane outside its segment's
+    prefix would never be issued: the maker holds the invariant."""
+    mask: jax.Array      # bool [S * cap]
+    counts: jax.Array    # i32 [S]: live lanes of each segment
+    ends: jax.Array      # i32 [S]: their running sum
+
+    @property
+    def n_live(self):
+        return self.ends[-1]
+
+    def lane_of(self, at):
+        cap = self.mask.shape[0] // self.counts.shape[0]
+        passed = self.ends[None, :] <= at[:, None]
+        return at + jnp.where(passed, cap - self.counts[None, :],
+                              0).sum(axis=1)
+
+
+def prefixed(mask, segments: int) -> Prefixed:
+    counts = mask.reshape(segments, -1).sum(axis=1, dtype=I32)
+    return Prefixed(mask=mask, counts=counts, ends=jnp.cumsum(counts))
 
 
 def lanes_mask(lanes, on, r: int):

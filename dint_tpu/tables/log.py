@@ -168,44 +168,41 @@ def append_rep(ring: RepLog, do_append, table_id, is_del, key_hi, key_lo,
                ver, val) -> RepLog:
     """Batched replicated append; same slot assignment as `append` (lane =
     round-robin, slot = head[lane] + arrival rank within the lane, rings
-    wrap). One unique-index row scatter installs all replicas."""
+    wrap). One unique-index row scatter installs all replicas.
+
+    ``do_append``: bool [R], or a ``compact.Live`` of it from a caller that
+    has compacted the mask (ops/compact.py). The plan is made at full
+    width either way, so lane, rank and slot, and with them the rings'
+    bytes, are the same; under a ``Live`` the row scatter issues the live
+    entries only, C lanes a chunk."""
+    live = do_append if isinstance(do_append, compact.Live) else None
     with waves.part("log", "log_plan"):
-        flat, entry3, lane_counts = plan_rep(ring, do_append, table_id,
-                                             is_del, key_hi, key_lo, ver,
-                                             val)
+        flat, entry3, lane_counts = plan_rep(
+            ring, do_append if live is None else live.mask, table_id,
+            is_del, key_hi, key_lo, ver, val)
     with waves.part("log", "log_scatter"):
-        lanes = ring.lanes
-        cap = ring.capacity
-        widx = jnp.where(flat >= 0, flat, lanes * cap)
-        new_entries = ring.entries.at[widx].set(entry3, mode="drop",
-                                                unique_indices=True)
+        oob = ring.lanes * ring.capacity
+        if live is None:
+            widx = jnp.where(flat >= 0, flat, oob)
+            new_entries = ring.entries.at[widx].set(entry3, mode="drop",
+                                                    unique_indices=True)
+        else:
+            def scatter(entries, lanes, ok):
+                return entries.at[jnp.where(ok, flat[lanes], oob)].set(
+                    entry3[lanes], mode="drop", unique_indices=True)
+
+            new_entries, _ = live.for_chunks(scatter, ring.entries)
         return ring.replace(entries=new_entries,
                             head=ring.head + lane_counts)
 
 
 def append_rep_live(ring: RepLog, ranks, n_live, do_append, table_id, is_del,
                     key_hi, key_lo, ver, val) -> RepLog:
-    """`append_rep` for a caller that has compacted ``do_append``
-    (``ranks, n_live = compact.live_ranks(do_append)``): the plan is made at
-    full width, so lane, rank and slot, and with them the rings' bytes,
-    are `append_rep`'s; the row scatter issues the live entries only, C
-    lanes a chunk (ops/compact.py)."""
-    with waves.part("log", "log_plan"):
-        flat, entry3, lane_counts = plan_rep(ring, do_append, table_id,
-                                             is_del, key_hi, key_lo, ver,
-                                             val)
-    with waves.part("log", "log_scatter"):
-        oob = ring.lanes * ring.capacity
-
-        def scatter(entries, lanes, ok):
-            return entries.at[jnp.where(ok, flat[lanes], oob)].set(
-                entry3[lanes], mode="drop", unique_indices=True)
-
-        new_entries, _ = compact.for_chunks(
-            ranks, n_live, compact.chunk_lanes(do_append.shape[0]), scatter,
-            ring.entries)
-        return ring.replace(entries=new_entries,
-                            head=ring.head + lane_counts)
+    """`append_rep` for a caller that has ranked ``do_append``
+    (``ranks, n_live = compact.live_ranks(do_append)``) for its own chunk
+    loops too."""
+    return append_rep(ring, compact.Ranked(do_append, ranks, n_live),
+                      table_id, is_del, key_hi, key_lo, ver, val)
 
 
 def advance_watermark(ring: LogRing | RepLog, watermark, consumed):

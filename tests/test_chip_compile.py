@@ -525,6 +525,8 @@ def test_dense_sharded_sb_block_program_on_four_chips(topo):
     carry = placed(jax.eval_shape(init, state), by_device)
     m1 = dsb.m1_local(n_acc, n)
     assert m1 == 12_000_001
+    cap = 2 * (W * dsb.L // n)
+    assert n * cap == 49_152
     a_device = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                    for x in jax.tree.leaves(carry)) / n
     # primary 48 MB, two backups 96, two stamp tables 96, the ring 25
@@ -549,3 +551,10 @@ def test_dense_sharded_sb_block_program_on_four_chips(topo):
                               hlo)) == 10
         # the stacked carry's squeeze and unsqueeze are real ops here
         assert "part.sbx_carry" in hlo and "part.a2a_pack" in hlo
+        # the owner's append and the two forwarded ones issue a chunk of
+        # the inbox's live rows a trip, not its D x cap slots (PR 44)
+        ring = carry[0].log.entries.shape[1:]
+        shape_of = _shapes(hlo)
+        assert [_count(shape_of[idx]) for idx in re.findall(
+            rf"u32\[{ring[0]},{ring[1]}\]\S* scatter\(%[\w.\-]+, "
+            r"%([\w.\-]+),", hlo)] == [compact.chunk_lanes(n * cap)] * 3

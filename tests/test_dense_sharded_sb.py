@@ -1,12 +1,17 @@
 """Multi-chip dense SmallBank: TRUE cross-device transactions over the
 mesh — a SendPayment's two accounts land on different devices, its locks
 are granted remotely, and global balance conservation must still hold."""
+import functools
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from dint_tpu.engines import smallbank_dense as sd
+from dint_tpu.ops import compact
 from dint_tpu.parallel import dense_sharded_sb as dsb
+from dint_tpu.tables import log as logring
 
 D = 8
 
@@ -327,3 +332,243 @@ def test_a_second_process_loads_the_sharded_programs_from_the_cache(
     for k in ("stats", "counters", "heads", "bal", "bck", "log"):
         assert first[k] == second[k], k
     assert sum(first["heads"][0]) > 0
+
+
+# ------------- the appends' compacted form against the full width (PR 44) --
+# An inbox's install mask is D segments of `cap` slots, each live in a
+# prefix; the three appends of a step issue the live rows only
+# (`compact.prefixed` + `logring.append_rep`), and the rings have to be
+# what the full-width append writes, bit for bit.
+
+_PD, _PCAP = 4, 384                        # an inbox of 1,536 slots ...
+_PC = 128                                  # ... issued 128 rows a trip
+
+
+def _routed_inbox(per_segment, rng):
+    """(i_mask, fields) of an inbox as `_route` fills it: segment s gets
+    ``per_segment[s]`` installs, in arrival order, from a source whose
+    lanes aim at the D buckets in a shuffled order; what does not fit a
+    bucket is dropped by `_route`'s own `valid` rule."""
+    dest = np.repeat(np.arange(_PD), per_segment)
+    rng.shuffle(dest)
+    n = len(dest)
+    active = jnp.ones((n,), bool)
+    dest = jnp.asarray(dest, jnp.int32)
+    pos = dsb._positions(dest, active, _PD)
+    valid = active & (pos < _PCAP)
+    vals = [jnp.asarray(rng.integers(1, 1 << 30, n), jnp.int32)
+            for _ in range(3)]
+    routed = dsb._route(dest, pos, valid, _PCAP, _PD,
+                        [valid.astype(jnp.int32), *vals])
+    return routed[0] != 0, routed[1:]
+
+
+@functools.cache
+def _append_pair():
+    """(a fresh ring, the compacted append, the full-width one), the two
+    jitted once for every case below."""
+    def append(ring, mask, live, bal, tbl, acc, t):
+        val = jnp.zeros((mask.shape[0], dsb.VW), jnp.uint32)
+        val = val.at[:, 0].set(bal.astype(jnp.uint32)).at[:, 1].set(
+            jnp.where(mask, jnp.uint32(dsb.MAGIC), jnp.uint32(0)))
+        return logring.append_rep(
+            ring, live, tbl & 1, jnp.zeros_like(bal),
+            jnp.zeros_like(bal, jnp.uint32), acc.astype(jnp.uint32),
+            jnp.broadcast_to(t, mask.shape), val)
+
+    @jax.jit
+    def compacted(ring, mask, bal, tbl, acc, t):
+        live = compact.prefixed(mask, _PD)
+        return append(ring, mask, live, bal, tbl, acc, t), live.trips
+
+    @jax.jit
+    def full(ring, mask, bal, tbl, acc, t):
+        return append(ring, mask, mask, bal, tbl, acc, t)
+
+    # 16 rings of 64 slots: a full inbox wraps every ring
+    make = functools.partial(logring.create_rep, 16, 64, dsb.VW, replicas=1)
+    return make, compacted, full
+
+
+_APPEND_CASES = {
+    "nothing_live": (0, 0, 0, 0),
+    "one_lane": (0, 0, 1, 0),
+    "one_segment_empty": (90, 0, 130, 55),
+    "only_the_last_segment": (0, 0, 0, 200),
+    "a_segment_full_and_one_lane_dropped": (_PCAP + 1, 20, 0, 77),
+    "an_exact_multiple_of_the_chunk": (_PC, 100, 2 * _PC - 100, _PC),
+    "one_short_of_a_chunk": (_PC - 1, 0, 0, 0),
+    "one_over_a_chunk": (60, 60, 9, 0),
+    "every_slot_live": (_PCAP,) * _PD,
+}
+
+
+@pytest.mark.parametrize("case", list(_APPEND_CASES))
+def test_the_compacted_append_equals_the_full_width_append(case):
+    """`append_rep` under `compact.prefixed(i_mask, D)` against
+    `append_rep` under the plain mask, on inboxes that `_route` filled:
+    `entries` and `head` bit for bit over two appends in a row (the
+    second on heads the first has moved, into rings that wrap), and the
+    loop makes ceil(live / C) trips, none where nothing is live."""
+    assert compact.chunk_lanes(_PD * _PCAP) == _PC
+    per_segment = _APPEND_CASES[case]
+    make, compacted, full = _append_pair()
+    rng = np.random.default_rng(sorted(_APPEND_CASES).index(case))
+    a, b = make(), make()
+    for t in (2, 3):
+        mask, (bal, tbl, acc) = _routed_inbox(per_segment, rng)
+        n_live = int(np.asarray(mask).sum())
+        assert n_live == sum(min(n, _PCAP) for n in per_segment)
+        a, trips = compacted(a, mask, bal, tbl, acc, np.uint32(t))
+        b = full(b, mask, bal, tbl, acc, np.uint32(t))
+        assert int(trips) == -(-n_live // _PC)
+        np.testing.assert_array_equal(np.asarray(a.entries),
+                                      np.asarray(b.entries))
+        np.testing.assert_array_equal(np.asarray(a.head),
+                                      np.asarray(b.head))
+    assert int(np.asarray(a.head).sum()) == 2 * n_live
+    assert bool(np.asarray(a.entries).any()) == bool(n_live)
+
+
+@pytest.mark.parametrize("counts", [
+    (0, 0, 0, 0), (5, 0, 0, 0), (0, 0, 0, 7), (3, 0, 9, 1), (96, 96, 96, 96),
+    (96, 0, 96, 1), (40, 41, 42, 5), (128,), (17, 111)], ids=str)
+def test_both_forms_of_a_live_mask_visit_the_live_lanes_in_lane_order(
+        counts):
+    """`compact.prefixed` (segment counts, no search) and `compact.Ranked`
+    (running count, a search a chunk) over the same prefix-live mask:
+    same lanes, same order, same trips, the live lanes and nothing else."""
+    cap = max(96, max(counts))
+    mask = (np.arange(cap) < np.asarray(counts)[:, None]).reshape(-1)
+    want = np.nonzero(mask)[0]
+    forms = (compact.prefixed(jnp.asarray(mask), len(counts)),
+             compact.Ranked(jnp.asarray(mask),
+                            *compact.live_ranks(jnp.asarray(mask))))
+    chunk = forms[0].chunk
+    assert chunk == forms[1].chunk == compact.chunk_lanes(mask.size)
+    room = -(-mask.size // chunk) * chunk
+
+    def visit(state, lanes, ok):
+        seen, k = state
+        return (jax.lax.dynamic_update_slice(
+            seen, jnp.where(ok, lanes, -1), (k,)), k + chunk)
+
+    for live in forms:
+        assert int(live.n_live) == len(want)
+        (seen, _), trips = live.for_chunks(
+            visit, (jnp.full(room, -1, jnp.int32), jnp.asarray(0, jnp.int32)))
+        assert int(trips) == int(live.trips) == -(-len(want) // chunk)
+        assert np.asarray(seen).tolist() == \
+            want.tolist() + [-1] * (room - len(want))
+
+
+def test_every_segment_of_an_inbox_is_live_in_a_prefix():
+    """What `compact.prefixed` stands on: after `_positions` + `_route` +
+    `_a2a`, as `local_step` composes them for the installs, segment s of
+    a device's inbox holds source s's installs at slots 0..n-1 and
+    nothing behind them, one bucket overflowing included; a `ppermute`
+    forwards the mask as it is. A `_route` that placed a live slot behind
+    a dead one would leave its row out of all three rings."""
+    from jax.sharding import PartitionSpec as P
+
+    d, n, cap = 4, 96, 32
+    mesh = dsb.make_mesh(d)
+    rng = np.random.default_rng(5)
+    dest = rng.integers(0, d, (d, n))
+    dest[1, :60] = 2                        # source 1 overflows bucket 2
+    wmask = rng.random((d, n)) < 0.6
+    wmask[3] = False                        # a source with nothing to send
+    wmask[1, :60] = True
+
+    def local(dest, wmask):
+        dest, wmask = dest[0], wmask[0]
+        pos = dsb._positions(dest, wmask, d)
+        valid = wmask & (pos < cap)
+        packed, = dsb._route(dest, pos, valid, cap, d,
+                             [wmask.astype(jnp.int32)])
+        i_mask = dsb._a2a(packed, d, cap) != 0
+        fwd = jax.lax.ppermute(i_mask, dsb.AXIS,
+                               [(i, (i + 1) % d) for i in range(d)])
+        return i_mask[None], fwd[None]
+
+    i_mask, fwd = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P(dsb.AXIS),) * 2,
+        out_specs=(P(dsb.AXIS),) * 2))(
+            jnp.asarray(dest, jnp.int32), jnp.asarray(wmask))
+    i_mask = np.asarray(i_mask).reshape(d, d, cap)      # [owner, source]
+    counts = i_mask.sum(axis=2)
+    for owner in range(d):
+        for src in range(d):
+            sent = int((wmask[src] & (dest[src] == owner)).sum())
+            assert counts[owner, src] == min(sent, cap)
+    assert counts[2, 1] == cap and counts[:, 3].sum() == 0
+    np.testing.assert_array_equal(
+        i_mask, np.arange(cap) < counts[:, :, None])
+    np.testing.assert_array_equal(np.asarray(fwd).reshape(d, d, cap),
+                                  np.roll(i_mask, 1, axis=0))
+
+
+def _full_width(real):
+    """`append_rep` as the three appends called it before they compacted
+    their masks: the plain mask, every inbox slot issued."""
+    def append_rep(ring, mask, *fields):
+        return real(ring, getattr(mask, "mask", mask), *fields)
+    return append_rep
+
+
+_RUNNER_CASES = {
+    "plain": {},
+    "monitor": {"monitor": True},
+    "trace": {"trace": True},
+    "hotset": {"use_hotset": True},
+    "all_three": {"monitor": True, "trace": True, "use_hotset": True},
+}
+
+
+@pytest.mark.parametrize("case", list(_RUNNER_CASES))
+def test_rings_equal_the_full_width_program_after_block_and_drain(
+        case, monkeypatch):
+    """The four-device runner with its appends compacted against the same
+    runner with `append_rep` handed the plain masks (the parent's
+    program), same seeds: every ring's `entries` and `head`, the tables
+    and the stats after two blocks and a drain, with `monitor`, `trace`
+    and `use_hotset` on and off. w = 256 (an inbox of 1,536 slots, C =
+    128) at a mix where a step's installs take more than one trip."""
+    kw = _RUNNER_CASES[case]
+    mesh = dsb.make_mesh(D4)
+
+    def go():
+        dsb.build_sharded_sb_runner.cache.clear()   # not in the memo's key
+        state = dsb.create_sharded_sb(mesh, D4, 40_000, log_capacity=1 << 9)
+        run, init, drain = dsb.build_sharded_sb_runner(
+            mesh, D4, 40_000, w=256, cohorts_per_block=3, **kw)
+        carry, stats = init(state), []
+        for i in range(2):
+            carry, s = run(carry, jax.random.PRNGKey(20 + i))
+            stats.append(np.asarray(s))
+        out = drain(carry)
+        state = out[0]
+        return [np.concatenate([*stats, np.asarray(out[1])]),
+                *(np.asarray(x) for x in (
+                    state.log.entries, state.log.head, state.bal,
+                    state.bck_bal))], out
+
+    got, out = go()
+    monkeypatch.setattr(dsb.logring, "append_rep",
+                        _full_width(dsb.logring.append_rep))
+    want, _ = go()
+    monkeypatch.undo()
+    dsb.build_sharded_sb_runner.cache.clear()
+    for x, y in zip(got, want):
+        np.testing.assert_array_equal(x, y)
+    heads = got[2]
+    assert heads.sum() > 0
+    if kw.get("monitor"):
+        from dint_tpu.monitor import counters as mon
+
+        snap = mon.snapshot(out[-1])
+        assert heads.sum() == 3 * snap["install_writes"]
+        # `steps` is summed over the devices: more trips than steps, so
+        # some owner's installs took a second trip
+        assert snap["install_chunks"] > snap["steps"]
+        assert snap["bck_chunks"] == 2 * snap["install_chunks"]

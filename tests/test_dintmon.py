@@ -543,6 +543,53 @@ def test_dense_sharded_sb_counters_sum_across_shards():
     assert snap["repl_push_hop2"] == snap["install_writes"]
 
 
+def test_sharded_smallbank_chunks_reconcile_with_the_installs_step_by_step():
+    """The sharded SmallBank's three appends (PR 44): at each device a
+    step's owner append makes ceil(install_writes / C) trips
+    (`install_chunks`) and its two forwarded appends ceil(hop 1's live /
+    C) + ceil(hop 2's live / C) (`bck_chunks`). One cohort a block, so a
+    window's delta is one step's; w = 256 (an inbox of D x cap = 1,536
+    slots, C = 128) over accounts enough that an owner installs more than
+    one chunk holds."""
+    from dint_tpu.ops import compact
+    from dint_tpu.parallel import dense_sharded_sb as dsb
+
+    mesh = dsb.make_mesh(4)
+    run, init, drain = dsb.build_sharded_sb_runner(
+        mesh, 4, 40_000, w=256, cohorts_per_block=1, monitor=True)
+    carry = init(dsb.create_sharded_sb(mesh, 4, 40_000,
+                                       log_capacity=1 << 9))
+    chunk = compact.chunk_lanes(4 * 2 * (256 * dsb.L // 4))
+    assert chunk == 128
+
+    def ceil(x):
+        return -(-x // chunk)
+
+    prev, most = np.zeros((4, mc.N_COUNTERS), np.int64), 0
+    for i in range(5):
+        carry, _ = run(carry, jax.random.fold_in(KEY(5), i))
+        buf = np.asarray(carry[-1].buf, np.int64)       # [D, N] a device
+        d, prev = buf - prev, buf
+        assert (d[:, mc.CTR_STEPS] == 1).all()
+        assert (d[:, mc.CTR_INSTALL_CHUNKS]
+                == ceil(d[:, mc.CTR_INSTALL_WRITES])).all()
+        assert (d[:, mc.CTR_BCK_CHUNKS]
+                == ceil(d[:, mc.CTR_REPL_PUSH_HOP1])
+                + ceil(d[:, mc.CTR_REPL_PUSH_HOP2])).all()
+        most = max(most, int(d[:, mc.CTR_INSTALL_CHUNKS].max()))
+        if i == 0:                      # an empty c1: nothing to install
+            assert not d[:, mc.CTR_INSTALL_CHUNKS].any()
+            assert not d[:, mc.CTR_BCK_CHUNKS].any()
+    assert most >= 2                    # more than one chunk's worth
+    _, _, cnt = drain(carry)
+    d = np.asarray(cnt.buf, np.int64) - prev            # the drain's step
+    assert (d[:, mc.CTR_INSTALL_CHUNKS]
+            == ceil(d[:, mc.CTR_INSTALL_WRITES])).all()
+    snap = M.snapshot(cnt)
+    # a hop's receiver makes the trips its sender's own append made
+    assert snap["bck_chunks"] == 2 * snap["install_chunks"] > 0
+
+
 # ------------------------------------------------------------ trace layer
 
 
