@@ -182,7 +182,13 @@ _REGISTRY: tuple[tuple[str, str, str], ...] = (
      "chunk_lanes(2w) lanes a trip. lock_chunks == sum over steps of "
      "ceil(lock_requests / C); lock_requests / (C x lock_chunks) is the "
      "fill share of the lanes the stamp gather, the scatter-max and the "
-     "winner read-back issue"),
+     "winner read-back issue. The sharded SmallBank's owner arbitration "
+     "is the second owner: the trips of its first loop over the live "
+     "requests of its inbox (the other two make as many), counted at the "
+     "owner, C = compact.sorted_chunk_lanes(m1, D x cap), from the "
+     "table's words and not from the inbox's width so that the compiler "
+     "still sorts each chunk's scatters (7,936 at 24 M accounts over "
+     "four devices); same identity and fill share"),
     ("bck_chunks", FLOW,
      "backup-apply compaction (ops/compact.py): chunk trips of the "
      "install loop of parallel/dense_sharded._apply_backup, both hops, "

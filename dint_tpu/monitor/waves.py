@@ -546,21 +546,26 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
     ("dense_sharded_sb", "route", "a2a_requests",
      "the all_to_alls of the lock + read requests (op, local row)"),
     ("dense_sharded_sb", None, "owner_addr",
-     "the owner's view of what arrived: lane numbers, the X / S masks, "
-     "rows with the sentinel for empty slots (and the hot mirror's index)"),
+     "the owner's view of what arrived: its inbox's D segment counts and "
+     "their running ends (compact.prefixed: every segment is live in a "
+     "prefix)"),
     ("dense_sharded_sb", "arbitrate", "owner_arb",
      "the arbitration proper: two fresh shard-table-wide arrays filled "
      "and scatter-min'ed with the arrival number over the X and the S "
-     "requests of the D x cap slots"),
+     "requests, C live slots a trip of the first loop"),
     ("dense_sharded_sb", "arbitrate", "owner_held_read",
-     "gathers of the D x cap slots' X and S stamps + the held compares"),
+     "gathers of the live slots' X and S stamps + the held compares, C a "
+     "trip of the third loop (an S stamp of this step read back as the "
+     "held flag its row's first S read)"),
     ("dense_sharded_sb", "arbitrate", "owner_grant",
-     "gathers back out of the two arbitration arrays and the grant masks"),
+     "gathers back out of the two arbitration arrays (second loop) and "
+     "the grant masks (third loop), C live slots a trip"),
     ("dense_sharded_sb", "arbitrate", "owner_stamp",
-     "the two unique-index stamp scatters of the granted slots (and the "
-     "hot mirror's write-through)"),
+     "the two unique-index stamp scatters of the granted slots, C a trip "
+     "of the third loop (and the hot mirror's write-through)"),
     ("dense_sharded_sb", "arbitrate", "owner_bal_read",
-     "the fused balance gather of the D x cap slots, masked by the grant"),
+     "the fused balance gather of the live slots, masked by the grant, C "
+     "a trip of the third loop"),
     ("dense_sharded_sb", "reply", "a2a_replies",
      "the all_to_alls of the replies (grant bit, balance)"),
     ("dense_sharded_sb", "reply", "reply_unpack",
@@ -594,6 +599,15 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
      "the forwarded mask's segment counts and append_rep into this "
      "device's ring: log_plan at full width, log_scatter the live rows, C "
      "a trip, lie inside (bck_chunks counts both hops' trips)"),
+    # --- the sharded SmallBank owner's compacted arbitration -----------
+    ("dense_sharded_sb", "arbitrate", "owner_compact",
+     "the inbox's live slots in live order (D dense slices a field: op, "
+     "row), the X / S masks and rows with the sentinel past the live "
+     "count (and the hot mirror's index), the three chunk loops, C = "
+     "compact.sorted_chunk_lanes(m1, D x cap) positions a trip, each "
+     "trip's slices, and the verdicts' way back to slot layout (D dense "
+     "slices each); the other arbitrate parts lie inside its loops "
+     "(lock_chunks counts the first loop's trips)"),
 )
 
 # keyed on the part's name alone: the scope is `part.<name>`, so two

@@ -30,7 +30,17 @@ slots, each live in a PREFIX (a source fills a destination's bucket in
 arrival order, the all_to_all moves whole buckets), so ``prefixed`` counts
 the segments and a chunk's lanes follow from the D counts, D compares a
 position: no running count over the lanes and no chunk x R search, which
-at R = 49,152 would cost what a third of the dropped rows do. Tried on
+at R = 49,152 would cost what a third of the dropped rows do. The sixth
+user is the same module's owner arbitration over its request inbox
+(``dense_sharded_sb.arbitrate``: ~31 % of the D x cap slots hold a
+request at the benchmark's width), whose inbox is prefix-live the same
+way: ``Prefixed.pack`` moves the live slots into live order by D dense
+slices and ``unpack`` takes the verdicts back, and ``for_live_chunks``
+slices the packed buffers. Its chunk follows from the table's words
+(``sorted_chunk_lanes``), not from the inbox's width: each trip
+scatter-mins and stamps into tables of m1 words, and a chunk of
+``chunk_lanes(D x cap)`` lanes would be too sparse there for the compiler
+to sort it (the third way, below), a lane at ~90 ns and not ~15. Tried on
 the chip and left
 (PERF.md §6, PR 30): one sort of the lane ids, 0.11 ms a step faster in
 ``tatp7m-sat``, but the protocol proofs read a sort as the generic
@@ -86,6 +96,12 @@ def compiler_sorts(table_words: int, lanes: int) -> bool:
     return table_words <= lanes * SORTED_SCATTER_WORDS_PER_LANE
 
 
+def _sorted_lanes(table_words: int) -> int:
+    """The least multiple of 128 lanes with at most
+    SORTED_SCATTER_WORDS_PER_LANE table words a lane."""
+    return -(-table_words // (SORTED_SCATTER_WORDS_PER_LANE * 128)) * 128
+
+
 def sorted_scatter_lanes(table_words: int, lanes: int) -> int:
     """Lanes a 1-D scatter of ``lanes`` indices into ``table_words`` words
     issues so that the compiler sorts its indices first: ``lanes`` where
@@ -96,8 +112,41 @@ def sorted_scatter_lanes(table_words: int, lanes: int) -> int:
     case). The caller routes the added lanes out of bounds."""
     if compiler_sorts(table_words, lanes):
         return lanes
-    dense = -(-table_words // (SORTED_SCATTER_WORDS_PER_LANE * 128)) * 128
+    dense = _sorted_lanes(table_words)
     return dense if dense <= 2 * lanes else lanes
+
+
+def sorted_chunk_lanes(table_words: int, r: int) -> int:
+    """Lanes a chunk issues where each trip scatters into ``table_words``
+    words out of a mask of ``r`` lanes: the least multiple of 128 with at
+    most SORTED_SCATTER_WORDS_PER_LANE words a lane, so that the compiler
+    still sorts every chunk's indices, and at most ``r``. ``chunk_lanes``
+    follows from the mask alone, and its chunk can fall below that
+    density: at 12,000,001 words (a device's arbitration arrays in the
+    sharded SmallBank at 24 M accounts) it gives 1,536 lanes of the 49,152
+    inbox slots, which the compiler does not sort, where this gives 7,936,
+    which it does."""
+    return min(r, _sorted_lanes(table_words))
+
+
+def for_live_chunks(n_live, chunk: int, body, carry):
+    """``carry = body(carry, start)`` for ``start`` = 0, chunk, 2 chunk, ...
+    below ``n_live``: a loop over buffers in live order (``Prefixed.pack``)
+    that a body slices ``chunk`` positions at a time. Returns (carry,
+    trips); trips = ceil(n_live / chunk), none where nothing is live. The
+    loop of ``_chunk_loop`` over start positions in place of lane ids: it
+    stays a copy, because building that loop on this one reorders its
+    body's equations and so changes the lowered text of every program
+    that compacts a mask (tatp_dense, dense_sharded, store, the rings)."""
+    def more(state):
+        return state[0] * chunk < n_live
+
+    def one(state):
+        i, carry = state
+        return i + 1, body(carry, i * chunk)
+
+    trips, carry = jax.lax.while_loop(more, one, (jnp.asarray(0, I32), carry))
+    return carry, trips
 
 
 def filled(x, lanes: int, value):
@@ -209,6 +258,36 @@ class Prefixed(Live):
         passed = self.ends[None, :] <= at[:, None]
         return at + jnp.where(passed, cap - self.counts[None, :],
                               0).sum(axis=1)
+
+    def pack(self, x, size: int):
+        """``x`` [S * cap] in lane order -> [size] in live order: position j
+        holds ``x[lane_of(j)]``. One dense slice a segment, written in
+        segment order at the segment's first live position, so that each
+        overwrites the dead tail of the one before: no gather, no scatter.
+        ``size`` >= S * cap; positions from ``n_live`` on hold what no
+        reader may use."""
+        s_n = self.counts.shape[0]
+        cap = x.shape[0] // s_n
+        starts = self.ends - self.counts
+        out = jnp.zeros((size,), x.dtype)
+        for s in range(s_n):
+            out = jax.lax.dynamic_update_slice(
+                out, x[s * cap:(s + 1) * cap], (starts[s],))
+        return out
+
+    def unpack(self, buf):
+        """The way back: ``buf`` in live order (at least S * cap long) ->
+        [S * cap] in lane order, zero at every dead lane. One dense slice a
+        segment, masked to its live prefix."""
+        s_n = self.counts.shape[0]
+        cap = self.mask.shape[0] // s_n
+        starts = self.ends - self.counts
+        p = jnp.arange(cap, dtype=I32)
+        return jnp.concatenate([
+            jnp.where(p < self.counts[s],
+                      jax.lax.dynamic_slice(buf, (starts[s],), (cap,)),
+                      jnp.zeros((), buf.dtype))
+            for s in range(s_n)])
 
 
 def prefixed(mask, segments: int) -> Prefixed:

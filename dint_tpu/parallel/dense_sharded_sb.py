@@ -50,6 +50,7 @@ cross-device install bug cannot hide.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import flax.struct
 import jax
@@ -184,6 +185,165 @@ def _positions(dest, active, n_shards):
     return jnp.take_along_axis(excl, dest[:, None], axis=1)[:, 0]
 
 
+def mirror_index(rows, mask, n_loc: int, hot_loc: int):
+    """Local row -> hot mirror index (tbl*hot_loc + q), -1 cold. The
+    sentinel row (q == n_loc) is never hot: hot_loc <= n_loc."""
+    tb = (rows >= n_loc).astype(I32)
+    q = rows - tb * n_loc
+    return jnp.where(mask & (q < hot_loc), tb * hot_loc + q, -1)
+
+
+class Arbitration(NamedTuple):
+    """What the owner's arbitration of its inbox gives (``arbitrate``): the
+    verdicts in slot layout, as the replies' all_to_all takes them, and
+    the stamp tables with this step's grants."""
+    grant: jax.Array         # bool [D*cap]: an X or an S lock granted
+    bal: jax.Array           # i32 [D*cap]: the row's balance where granted
+    held: jax.Array          # bool [D*cap]: the row's X or S held from t-1
+    x_step: jax.Array
+    s_step: jax.Array
+    hot_x: jax.Array | None
+    hot_s: jax.Array | None
+    hot_hits: jax.Array      # i32: live slots whose row the mirror serves
+    chunks: jax.Array        # i32: the trips of each of its three loops
+
+
+def arbitrate(state: SBShard, live: compact.Prefixed, r_op, r_row,
+              hot_loc: int = 0) -> Arbitration:
+    """The owner's no-wait S/X arbitration of its request inbox and the
+    fused balance read (the closed form of engines/smallbank_dense.py),
+    over the inbox's live slots only. ``live`` =
+    ``compact.prefixed(r_op != 0, D)``: every segment of the inbox is
+    live in a prefix (`_route`), so the live slots move into live order
+    and back by D dense slices each. Slot order is kept, so the live
+    position serves as the arrival number of the scatter-mins and the
+    same first X / first S win. Chunks of ``compact.sorted_chunk_lanes``
+    positions: its scatters into the m1-word tables stay dense enough
+    that the compiler sorts them (a narrower chunk would not be). Three
+    loops: the scatter-mins; each position's first X and first S, out of
+    the two m1-word arrays, which then die; the held reads, grants,
+    balance read and stamps. The arrays and the state's tables never
+    share a loop: where they do, the compiler keeps a table in its
+    alternate memory and puts an array in HBM, 92 MB more temporaries a
+    device at 24 M accounts over four (AOT, v5e). The third loop reads
+    the stamps it writes, and every read must give the lock held as the
+    step began: an S grant stamps t over a row's t - 1, so a later chunk
+    that reads t there takes the held flag its row's first S read (its
+    stamp's writer, which read the row before any stamp). An X stamp
+    needs no such care: an X is granted only on a row neither lock
+    holds, and t reads as not held. ``hot_loc`` > 0: the hot mirror
+    serves the reads and takes the stamps' write-through."""
+    own = "dense_sharded_sb"
+    m1 = state.x_step.shape[0]
+    n_loc, sent, oob = (m1 - 1) // 2, m1 - 1, m1
+    t = state.step
+    r = r_op.shape[0]
+    chunk = compact.sorted_chunk_lanes(m1, r)
+    size = -(-r // chunk) * chunk
+
+    def varying(x):
+        return compact.varying_like(x, state.x_step)
+
+    def sl(x, start):
+        return jax.lax.dynamic_slice(x, (start,), (chunk,))
+
+    with waves.part(own, "owner_compact"):
+        at = jnp.arange(size, dtype=I32)
+        ok = at < live.n_live
+        op = live.pack(r_op, size)
+        rows = jnp.where(ok, live.pack(r_row, size), sent)
+        is_x = ok & (op == Op.ACQ_X_READ)
+        is_s = ok & (op == Op.ACQ_S_READ)
+        if hot_loc:
+            midx = mirror_index(rows, ok, n_loc, hot_loc)
+
+    def gather(tab, mirror, c_rows, start):
+        if hot_loc:
+            return hotset.hot_gather(tab, mirror, c_rows, sl(midx, start), 1)
+        return tab[c_rows]
+
+    def arrive(carry, start):
+        first_x, first_s = carry
+        c_rows, c_at = sl(rows, start), sl(at, start)
+        with waves.part(own, "owner_arb"):
+            first_x = first_x.at[jnp.where(sl(is_x, start), c_rows, oob)] \
+                .min(c_at, mode="drop")
+            first_s = first_s.at[jnp.where(sl(is_s, start), c_rows, oob)] \
+                .min(c_at, mode="drop")
+        return first_x, first_s
+
+    def first_of_row(carry, start):
+        fx, fs = carry
+        c_rows = sl(rows, start)
+        with waves.part(own, "owner_grant"):
+            fx = jax.lax.dynamic_update_slice(fx, first_x[c_rows], (start,))
+            fs = jax.lax.dynamic_update_slice(fs, first_s[c_rows], (start,))
+        return fx, fs
+
+    def grant(carry, start):
+        x_step, s_step, hot_x, hot_s, held_x, held_s, granted, g_bal = carry
+        c_rows, c_at = sl(rows, start), sl(at, start)
+        fx, fs = sl(fx_buf, start), sl(fs_buf, start)
+        with waves.part(own, "owner_held_read"):
+            hx = gather(x_step, hot_x, c_rows, start) == t - 1
+            s_at = gather(s_step, hot_s, c_rows, start)
+            # t: an earlier chunk's first S on the row stamped it
+            hs = jnp.where(s_at == t, held_s[jnp.minimum(fs, size - 1)],
+                           s_at == t - 1)
+            held_x = jax.lax.dynamic_update_slice(held_x, hx, (start,))
+            held_s = jax.lax.dynamic_update_slice(held_s, hs, (start,))
+        with waves.part(own, "owner_grant"):
+            x_wins = (fx < fs) & ~hx & ~hs
+            grant_x = sl(is_x, start) & x_wins & (fx == c_at)
+            grant_s = sl(is_s, start) & ~hx & ~x_wins
+            s_writer = grant_s & (fs == c_at)
+            g = grant_x | grant_s
+            granted = jax.lax.dynamic_update_slice(granted, g, (start,))
+        with waves.part(own, "owner_bal_read"):
+            g_bal = jax.lax.dynamic_update_slice(
+                g_bal, jnp.where(
+                    g, gather(state.bal, state.hot_bal, c_rows, start)
+                    .astype(I32), 0), (start,))
+        with waves.part(own, "owner_stamp"):
+            x_step = x_step.at[jnp.where(grant_x, c_rows, oob)].set(
+                t, mode="drop", unique_indices=True)
+            s_step = s_step.at[jnp.where(s_writer, c_rows, oob)].set(
+                t, mode="drop", unique_indices=True)
+            if hot_loc:
+                # stamp write-through (one-writer grant masks stay unique
+                # on the mirror's index subset)
+                c_midx = sl(midx, start)
+                hot_x = hot_x.at[jnp.where(grant_x & (c_midx >= 0), c_midx,
+                                           2 * hot_loc)].set(
+                    t, mode="drop", unique_indices=True)
+                hot_s = hot_s.at[jnp.where(s_writer & (c_midx >= 0),
+                                           c_midx, 2 * hot_loc)].set(
+                    t, mode="drop", unique_indices=True)
+        return x_step, s_step, hot_x, hot_s, held_x, held_s, granted, g_bal
+
+    with waves.part(own, "owner_arb"):
+        fill = varying(jnp.full((m1,), BIG, I32))
+    with waves.part(own, "owner_compact"):
+        no = varying(jnp.zeros((size,), bool))
+        (first_x, first_s), chunks = compact.for_live_chunks(
+            live.n_live, chunk, arrive, (fill, fill))
+        zero = varying(jnp.zeros((size,), I32))
+        (fx_buf, fs_buf), _ = compact.for_live_chunks(
+            live.n_live, chunk, first_of_row, (zero, zero))
+        (x_step, s_step, hot_x, hot_s, held_x, held_s, granted, g_bal), _ = \
+            compact.for_live_chunks(
+                live.n_live, chunk, grant,
+                (state.x_step, state.s_step, state.hot_x, state.hot_s, no,
+                 no, no, varying(jnp.zeros((size,), I32))))
+        return Arbitration(
+            grant=live.unpack(granted), bal=live.unpack(g_bal),
+            held=live.unpack(held_x | held_s), x_step=x_step, s_step=s_step,
+            hot_x=hot_x, hot_s=hot_s,
+            hot_hits=((midx >= 0).sum(dtype=I32) if hot_loc
+                      else jnp.asarray(0, I32)),
+            chunks=chunks)
+
+
 @flax.struct.dataclass
 class SBCtx:
     """A cohort between cross-device lock+compute and install."""
@@ -254,7 +414,6 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
     d = n_shards
     n_loc = n_acct_local(n_accounts, d)
     m1 = m1_local(n_accounts, d)
-    sent = m1 - 1
     oob = m1
     cap = 2 * ((w * L + d - 1) // d)
     use_hotset = hotset.resolve_use_hotset(use_hotset)
@@ -337,75 +496,15 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
 
         # ---- owner side: no-wait S/X arbitration + fused read ---------
         with waves.part("dense_sharded_sb", "owner_addr"):
-            lanes = jnp.arange(d * cap, dtype=I32)
-            is_x = r_op == Op.ACQ_X_READ
-            is_s = r_op == Op.ACQ_S_READ
-            rows = jnp.where(r_op != 0, r_row, sent)
+            live = compact.prefixed(r_op != 0, d)
         with waves.scope("dense_sharded_sb", "arbitrate"):
-
-            def mirror_idx(rr, mask):
-                """Local row -> hot mirror index (tbl*hot_loc + q), -1
-                cold. The sentinel row (q == n_loc) is never hot:
-                hot_loc <= n_loc."""
-                tb = (rr >= n_loc).astype(I32)
-                q = rr - tb * n_loc
-                return jnp.where(mask & (q < hot_loc),
-                                 tb * hot_loc + q, -1)
-
-            if use_hotset:
-                with waves.part("dense_sharded_sb", "owner_addr"):
-                    midx = mirror_idx(rows, r_op != 0)
-            with waves.part("dense_sharded_sb", "owner_arb"):
-                first_x = jnp.full((m1,), BIG, I32).at[
-                    jnp.where(is_x, rows, oob)].min(lanes, mode="drop")
-                first_s = jnp.full((m1,), BIG, I32).at[
-                    jnp.where(is_s, rows, oob)].min(lanes, mode="drop")
-            with waves.part("dense_sharded_sb", "owner_held_read"):
-                if use_hotset:
-                    held_x = hotset.hot_gather(state.x_step, state.hot_x,
-                                               rows, midx, 1) == t - 1
-                    held_s = hotset.hot_gather(state.s_step, state.hot_s,
-                                               rows, midx, 1) == t - 1
-                else:
-                    held_x = state.x_step[rows] == t - 1
-                    held_s = state.s_step[rows] == t - 1
-                slot_free = ~held_x & ~held_s
-            with waves.part("dense_sharded_sb", "owner_grant"):
-                x_wins = (first_x[rows] < first_s[rows]) & slot_free
-                grant_x = is_x & x_wins & (first_x[rows] == lanes)
-                grant_s = is_s & ~held_x & ~x_wins
-                s_writer = grant_s & (first_s[rows] == lanes)
-            with waves.part("dense_sharded_sb", "owner_stamp"):
-                x_step = state.x_step.at[
-                    jnp.where(grant_x, rows, oob)].set(
-                    t, mode="drop", unique_indices=True)
-                s_step = state.s_step.at[
-                    jnp.where(s_writer, rows, oob)].set(
-                    t, mode="drop", unique_indices=True)
-                hot_x, hot_s = state.hot_x, state.hot_s
-                if use_hotset:
-                    # stamp write-through (one-writer grant masks stay
-                    # unique on the mirror's index subset)
-                    hot_x = hot_x.at[jnp.where(grant_x & (midx >= 0), midx,
-                                               2 * hot_loc)].set(
-                        t, mode="drop", unique_indices=True)
-                    hot_s = hot_s.at[jnp.where(s_writer & (midx >= 0),
-                                               midx, 2 * hot_loc)].set(
-                        t, mode="drop", unique_indices=True)
-            with waves.part("dense_sharded_sb", "owner_bal_read"):
-                if use_hotset:
-                    raw_bal = hotset.hot_gather(state.bal, state.hot_bal,
-                                                rows, midx, 1)
-                else:
-                    raw_bal = state.bal[rows]
-                g_bal = jnp.where(grant_x | grant_s, raw_bal.astype(I32),
-                                  0)
+            arb = arbitrate(state, live, r_op, r_row, hot_loc)
 
         # ---- replies back to sources + classify -----------------------
         with waves.scope("dense_sharded_sb", "reply"):
             with waves.part("dense_sharded_sb", "a2a_replies"):
-                rep_g = _a2a((grant_x | grant_s), d, cap)
-                rep_b = _a2a(g_bal, d, cap)
+                rep_g = _a2a(arb.grant, d, cap)
+                rep_b = _a2a(arb.bal, d, cap)
             with waves.part("dense_sharded_sb", "reply_unpack"):
                 back = jnp.where(valid, dest * cap + pos, 0)
                 granted = (jnp.where(valid, rep_g[back], False)
@@ -464,7 +563,7 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
                 if use_hotset:
                     # partitioned write-through install (double 1-D
                     # unique-index scatter)
-                    i_midx = mirror_idx(i_row, i_mask)
+                    i_midx = mirror_index(i_row, i_mask, n_loc, hot_loc)
                     bal_new, hot_bal = hotset.hot_scatter(
                         state.bal, hot_bal, i_row, i_midx, i_mask,
                         i_bal.astype(U32), 1)
@@ -548,10 +647,10 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
                 fwd_lives.append(fwd_live)
 
         with waves.part("dense_sharded_sb", "sbx_frame"):
-            state = state.replace(bal=bal_new, bck_bal=bck, x_step=x_step,
-                                  s_step=s_step, step=t + 1, log=log,
-                                  hot_bal=hot_bal, hot_x=hot_x,
-                                  hot_s=hot_s)
+            state = state.replace(bal=bal_new, bck_bal=bck,
+                                  x_step=arb.x_step, s_step=arb.s_step,
+                                  step=t + 1, log=log, hot_bal=hot_bal,
+                                  hot_x=arb.hot_x, hot_s=arb.hot_s)
 
         if cnt is not None and use_hotset:
             # partition accounting: 3 hot-partitioned gathers per step
@@ -559,7 +658,7 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
             # from the mirror
             with waves.part("dense_sharded_sb", "monitor"):
                 n_g = 3
-                hits = (midx >= 0).sum(dtype=I32)
+                hits = arb.hot_hits
                 cnt = mon.bump(cnt, {
                     mon.CTR_HOT_HITS: n_g * hits,
                     mon.CTR_HOT_COLD_ROWS: n_g * (d * cap) - n_g * hits,
@@ -572,9 +671,8 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
             # the device-axis sum reconciles with the psummed stats
             with waves.part("dense_sharded_sb", "monitor"):
                 req = r_op != 0
-                grant = grant_x | grant_s
-                rej = req & ~grant
-                held = held_x | held_s
+                rej = req & ~arb.grant
+                held = arb.held
                 # how much of the new cohort is distributed, at its
                 # SOURCE: transactions whose lock set names rows of more
                 # than one owner, lock requests whose owner is another
@@ -591,10 +689,13 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
                     mon.CTR_MAGIC_BAD: c1.magic_bad,
                     mon.CTR_ROUTE_OVERFLOW: c1.overflow,
                     mon.CTR_LOCK_REQUESTS: req.sum(dtype=I32),
-                    mon.CTR_LOCK_GRANTED: grant.sum(dtype=I32),
+                    mon.CTR_LOCK_GRANTED: arb.grant.sum(dtype=I32),
                     mon.CTR_LOCK_REJECTED: rej.sum(dtype=I32),
                     mon.CTR_LOCK_REJECT_HELD: (rej & held).sum(dtype=I32),
                     mon.CTR_LOCK_REJECT_ARB: (rej & ~held).sum(dtype=I32),
+                    # the trips of the arbitration's first loop (its other
+                    # two make as many)
+                    mon.CTR_LOCK_CHUNKS: arb.chunks,
                     mon.CTR_INSTALL_WRITES: i_mask.sum(dtype=I32),
                     mon.CTR_LOG_APPENDS: i_mask.sum(dtype=I32),
                     # the trips of the three appends' chunk loops, where
@@ -618,10 +719,8 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
             # device-axis event sum reconciles with the summed ledger.
             with waves.scope("dense_sharded_sb", "trace"):
                 req = r_op != 0
-                grant_l = grant_x | grant_s
-                held_l = held_x | held_s
-                lock_aux = (jnp.where(grant_l, txe.LOCK_GRANTED, 0)
-                            | jnp.where(held_l, txe.LOCK_HELD, 0))
+                lock_aux = (jnp.where(arb.grant, txe.LOCK_GRANTED, 0)
+                            | jnp.where(arb.held, txe.LOCK_HELD, 0))
                 ab_lock_m = lock_rejected & (l_op[:, 0] != 0)
                 out_mask = committed | ab_lock_m | logic_abort
                 cause = jnp.where(

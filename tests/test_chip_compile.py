@@ -508,8 +508,10 @@ def test_dense_sharded_sb_block_program_on_four_chips(topo):
     a 4-device v5e mesh, w = 8,192 x 16 cohorts, counters on. The block
     and the drain compile, the donated carry is updated in place, a
     step's exchange is nine all-to-alls and its replication ten
-    collective-permutes, and the temporaries are the two arbitration
-    arrays and the stacked carry's copies, not a second state."""
+    collective-permutes, the owner's arbitration issues chunks of its
+    inbox's live requests that the compiler sorts, and the temporaries
+    are the two arbitration arrays and the stacked carry's copies, not a
+    second state."""
     n_acc = 24_000_000
     mesh = Mesh(np.array(topo.devices), (dsb.AXIS,))
     n = mesh.size
@@ -539,6 +541,12 @@ def test_dense_sharded_sb_block_program_on_four_chips(topo):
     # the `[1, N]` carry's copies at the block's entry and exit (the form
     # PR 42 took out of dense_sharded; ROADMAP Queue 1). A drain generates
     # no cohort: its request exchange is a constant's, folded to six.
+    # The arbitration's chunk loops keep the block's at 0.2884: its two
+    # arrays and the state's tables never share a loop (where they did,
+    # the compiler kept a table in its alternate memory and an array in
+    # HBM: 0.3826, AOT).
+    chunk = compact.sorted_chunk_lanes(m1, n * cap)
+    assert chunk == 7_936
     for c, ceiling, a2a in ((block, 0.30e9, 9), (steps, 0.15e9, 6)):
         ma = c.memory_analysis()
         assert abs(ma.argument_size_in_bytes - a_device) < 0.01 * a_device
@@ -558,3 +566,47 @@ def test_dense_sharded_sb_block_program_on_four_chips(topo):
         assert [_count(shape_of[idx]) for idx in re.findall(
             rf"u32\[{ring[0]},{ring[1]}\]\S* scatter\(%[\w.\-]+, "
             r"%([\w.\-]+),", hlo)] == [compact.chunk_lanes(n * cap)] * 3
+        # the owner's arbitration issues a chunk of its inbox's live
+        # requests a trip: the two scatter-mins into the fresh
+        # arrays and the two stamp scatters, C indices each, all four
+        # sorted by the compiler; beside them only the install's scatter
+        # into the primary writes an m1-word table, at the inbox's width
+        arb = re.findall(rf"[su]32\[{m1}\]\S* scatter\([^\n]*", hlo)
+        assert sorted(_count(shape_of[i]) for i in re.findall(
+            r" scatter\(%[\w.\-]+, %([\w.\-]+),", "\n".join(arb))) \
+            == [chunk] * 4 + [n * cap]
+        assert all("indices_are_sorted=true" in x for x in arb)
+        assert sum("part.owner_compact/while/body/part.owner_arb/scatter-min"
+                   in x for x in arb) == 2
+        assert sum("part.owner_compact/while/body/part.owner_stamp/scatter"
+                   in x for x in arb) == 2
+        # and no gather of the stamps or the balances issues the inbox's
+        # D x cap slots: they read a chunk each (three in the block; the
+        # drain's constant empty cohort lets the compiler drop one)
+        assert set(gather_lane_counts(hlo, m1)) == {chunk}
+
+
+def test_the_arbitration_chunk_is_one_the_compiler_sorts(one_chip):
+    """Why the owner's arbitration chunks by the table's words and not by
+    the inbox's width: into a device's 12,000,001-word arbitration array
+    a scatter-min of compact.sorted_chunk_lanes = 7,936 indices is sorted
+    by the compiler first, one of chunk_lanes(49,152) = 1,536 is not (it
+    would issue its lanes at the ~90 ns unsorted price)."""
+    m1, slots = 12_000_001, 49_152
+    chunk = compact.sorted_chunk_lanes(m1, slots)
+    assert chunk == 7_936 and compact.chunk_lanes(slots) == 1_536
+    assert compact.compiler_sorts(m1, chunk)
+    assert not compact.compiler_sorts(m1, chunk - 128)
+
+    def compiled(r):
+        c, _ = compiled_bytes(
+            jax.jit(lambda t, i, v: t.at[i].min(v, mode="drop"),
+                    donate_argnums=0),
+            *placed((_s((m1,), I32), _s((r,), I32), _s((r,), I32)),
+                    one_chip))
+        return c.as_text()
+
+    hlo = compiled(chunk)
+    assert " sort(" in hlo and "indices_are_sorted=true" in hlo
+    hlo = compiled(compact.chunk_lanes(slots))
+    assert " sort(" not in hlo and "indices_are_sorted=true" not in hlo

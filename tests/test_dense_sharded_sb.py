@@ -572,3 +572,227 @@ def test_rings_equal_the_full_width_program_after_block_and_drain(
         # some owner's installs took a second trip
         assert snap["install_chunks"] > snap["steps"]
         assert snap["bck_chunks"] == 2 * snap["install_chunks"]
+
+
+# ----------- the owner's arbitration over its live requests only -----------
+# The request inbox is D segments of `cap` slots, each live in a prefix, as
+# the install inbox is. `dsb.arbitrate` issues the live slots only, chunk by
+# chunk, and has to give what the wave over every slot gave, bit for bit.
+
+_AD, _ACAP, _AN = 4, 384, 2_000          # 1,536 slots; m1 = 1,001 words
+_AC = compact.sorted_chunk_lanes(2 * (_AN // _AD) + 1, _AD * _ACAP)
+_ABIG = jnp.int32(1 << 30)
+
+
+def _full_width_arbitrate(x_step, s_step, bal, t, r_op, r_row):
+    """The wave as it ran over every slot of the inbox: the reference."""
+    m1 = x_step.shape[0]
+    sent, oob = m1 - 1, m1
+    lanes = jnp.arange(r_op.shape[0], dtype=jnp.int32)
+    is_x = r_op == dsb.Op.ACQ_X_READ
+    is_s = r_op == dsb.Op.ACQ_S_READ
+    rows = jnp.where(r_op != 0, r_row, sent)
+    first_x = jnp.full((m1,), _ABIG).at[jnp.where(is_x, rows, oob)].min(
+        lanes, mode="drop")
+    first_s = jnp.full((m1,), _ABIG).at[jnp.where(is_s, rows, oob)].min(
+        lanes, mode="drop")
+    held_x = x_step[rows] == t - 1
+    held_s = s_step[rows] == t - 1
+    x_wins = (first_x[rows] < first_s[rows]) & ~held_x & ~held_s
+    grant_x = is_x & x_wins & (first_x[rows] == lanes)
+    grant_s = is_s & ~held_x & ~x_wins
+    s_writer = grant_s & (first_s[rows] == lanes)
+    x_step = x_step.at[jnp.where(grant_x, rows, oob)].set(
+        t, mode="drop", unique_indices=True)
+    s_step = s_step.at[jnp.where(s_writer, rows, oob)].set(
+        t, mode="drop", unique_indices=True)
+    grant = grant_x | grant_s
+    return (grant, jnp.where(grant, bal[rows].astype(jnp.int32), 0),
+            held_x | held_s, x_step, s_step)
+
+
+@functools.cache
+def _arbitrate_pair(hot_loc):
+    def compacted(x_step, s_step, bal, t, r_op, r_row):
+        state = dsb.SBShard(bal=bal, bck_bal=bal, x_step=x_step,
+                            s_step=s_step, step=t, log=None)
+        if hot_loc:
+            n_loc = (x_step.shape[0] - 1) // 2
+            idx = jnp.concatenate([jnp.arange(hot_loc),
+                                   n_loc + jnp.arange(hot_loc)])
+            state = state.replace(hot_bal=bal[idx], hot_x=x_step[idx],
+                                  hot_s=s_step[idx], hot_loc=hot_loc)
+        arb = dsb.arbitrate(state, compact.prefixed(r_op != 0, _AD), r_op,
+                            r_row, hot_loc)
+        return ((arb.grant, arb.bal, arb.held, arb.x_step, arb.s_step),
+                arb.chunks, (arb.hot_x, arb.hot_s, arb.hot_hits))
+
+    return jax.jit(compacted), jax.jit(_full_width_arbitrate)
+
+
+def _request_inbox(per_segment, rng, n_rows):
+    """(r_op, r_row) of a request inbox as `_route` fills it: segment s
+    holds ``per_segment[s]`` X or S requests in its prefix, on rows drawn
+    from the first ``n_rows`` of each table (collisions on purpose)."""
+    r_op = np.zeros((_AD, _ACAP), np.int32)
+    r_row = np.zeros((_AD, _ACAP), np.int32)
+    n_loc = _AN // _AD
+    for s, n in enumerate(per_segment):
+        r_op[s, :n] = rng.choice([int(dsb.Op.ACQ_X_READ),
+                                  int(dsb.Op.ACQ_S_READ)], n)
+        r_row[s, :n] = (rng.integers(0, 2, n) * n_loc
+                        + rng.integers(0, n_rows, n))
+    return r_op.reshape(-1), r_row.reshape(-1)
+
+
+def _tables(rng, t):
+    """Stamp tables where a row's X or S lock is held from step t - 1, was
+    held before, or never was; balances of every size. The sentinel row,
+    last, is never stamped, as in the engine."""
+    m1 = 2 * (_AN // _AD) + 1
+    x_step = rng.choice([0, t - 2, t - 1], m1, p=[0.6, 0.2, 0.2])
+    s_step = rng.choice([0, t - 2, t - 1], m1, p=[0.6, 0.2, 0.2])
+    x_step[-1] = s_step[-1] = 0
+    bal = rng.integers(0, 1 << 32, m1, dtype=np.uint64)
+    return [jnp.asarray(x, jnp.uint32) for x in (x_step, s_step, bal)]
+
+
+_ARB_CASES = {
+    "nothing_live": (0, 0, 0, 0),
+    "one_request": (0, 0, 1, 0),
+    "one_segment_empty": (90, 0, 130, 55),
+    "a_segment_full": (_ACAP, 20, 0, 77),
+    "exactly_one_chunk": (40, 0, 50, _AC - 90),
+    "one_over_a_chunk": (40, 0, 50, _AC - 89),
+    "several_chunks": (300, 250, 0, 301),
+    "every_slot_live": (_ACAP,) * _AD,
+}
+
+
+@pytest.mark.parametrize("hot_loc", [0, 25], ids=["cold", "hot"])
+@pytest.mark.parametrize("seed", [0, 7, 2**31 + 3])
+@pytest.mark.parametrize("case", list(_ARB_CASES))
+def test_the_compacted_arbitration_equals_the_full_width_wave(
+        case, seed, hot_loc):
+    """`dsb.arbitrate` over the live requests against the wave over every
+    slot, on inboxes that hit the edges (an empty segment, a full one, 0
+    live, exactly C, C + 1, several chunks, every slot): grants, balances
+    read, held flags, both stamp tables, bit for bit; the loop makes
+    ceil(live / C) trips. With the hot mirror the same verdicts and
+    stamps, and the mirror's stamps those of its rows."""
+    assert _AC == 128
+    per_segment = _ARB_CASES[case]
+    rng = np.random.default_rng([seed, sorted(_ARB_CASES).index(case)])
+    t = np.uint32(5)
+    x_step, s_step, bal = _tables(rng, int(t))
+    r_op, r_row = _request_inbox(per_segment, rng, 40)
+    compacted, full = _arbitrate_pair(hot_loc)
+    got, chunks, (hot_x, hot_s, hits) = compacted(
+        x_step, s_step, bal, t, jnp.asarray(r_op), jnp.asarray(r_row))
+    want = full(x_step, s_step, bal, t, jnp.asarray(r_op),
+                jnp.asarray(r_row))
+    for g, w_, name in zip(got, want, ("grant", "bal", "held", "x_step",
+                                       "s_step")):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w_), name)
+    n_live = sum(per_segment)
+    assert int(chunks) == -(-n_live // _AC)
+    grant = np.asarray(got[0])
+    assert grant.sum() <= n_live and not grant[r_op == 0].any()
+    if n_live > 50:
+        assert 0 < grant.sum() < n_live          # conflicts were resolved
+    if hot_loc:
+        n_loc = _AN // _AD
+        idx = np.concatenate([np.arange(hot_loc), n_loc + np.arange(hot_loc)])
+        np.testing.assert_array_equal(np.asarray(hot_x),
+                                      np.asarray(got[3])[idx])
+        np.testing.assert_array_equal(np.asarray(hot_s),
+                                      np.asarray(got[4])[idx])
+        q = r_row % n_loc
+        assert int(hits) == int(((r_op != 0) & (q < hot_loc)).sum())
+
+
+def test_a_later_chunk_reads_the_s_locks_as_the_step_began():
+    """Rows whose S lock is held from step t - 1 get their first S request
+    (which stamps t) in the first chunk, and more requests in the second,
+    whose trip reads the stamp t. Every lane must see the locks as the
+    step began, as the full-width wave does: on rows 1..20, named by an X
+    before their first S, the second chunk's S lanes are granted (the held
+    S keeps the X from winning), and on rows 21..40 the second chunk's X
+    lanes are refused as held, not by arbitration."""
+    t = np.uint32(9)
+    m1 = 2 * (_AN // _AD) + 1
+    first, second = np.arange(1, 21), np.arange(21, 41)
+    x_step = jnp.zeros((m1,), jnp.uint32)
+    s_step = jnp.zeros((m1,), jnp.uint32).at[np.arange(1, 41)].set(t - 1)
+    bal = jnp.arange(m1, dtype=jnp.uint32)
+    X, S = int(dsb.Op.ACQ_X_READ), int(dsb.Op.ACQ_S_READ)
+    r_op = np.zeros((_AD, _ACAP), np.int32)
+    r_row = np.zeros((_AD, _ACAP), np.int32)
+    r_op[0, :60] = [X] * 20 + [S] * 40
+    r_row[0, :60] = np.concatenate([first, first, second])
+    r_op[1, :_AC - 60], r_row[1, :_AC - 60] = S, 100   # the chunk's rest
+    r_op[2, :40] = [X] * 20 + [S] * 20
+    r_row[2, :40] = np.concatenate([second, first])
+    r_op, r_row = jnp.asarray(r_op.reshape(-1)), jnp.asarray(r_row.reshape(-1))
+    compacted, full = _arbitrate_pair(0)
+    got, chunks, _ = compacted(x_step, s_step, bal, t, r_op, r_row)
+    want = full(x_step, s_step, bal, t, r_op, r_row)
+    assert int(chunks) == 2              # segment 2 lies in chunk 2
+    for g, w_ in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w_))
+    grant, _, held, _, s_new = (np.asarray(x) for x in got)
+    late = 2 * _ACAP + np.arange(40)
+    assert not grant[:20].any() and grant[20:60].all()
+    assert (s_new[np.arange(1, 41)] == t).all()
+    assert not grant[late[:20]].any() and held[late[:20]].all()
+    assert grant[late[20:]].all()
+
+
+def test_lock_chunks_count_the_arbitration_trips_of_each_owner():
+    """`lock_chunks` at each device and step == ceil(its inbox's live
+    requests / C), C = sorted_chunk_lanes(m1, D x cap), where the live
+    requests are counted from the cohorts the devices generate (every
+    lock slot that names the owner, `_route` dropping nothing), and equal
+    to that step's `lock_requests`."""
+    from dint_tpu.monitor import counters as mc
+
+    n_acc, w = 40_000, 256
+    mesh = dsb.make_mesh(D4)
+    run, init, drain = dsb.build_sharded_sb_runner(
+        mesh, D4, n_acc, w=w, cohorts_per_block=1, monitor=True)
+    carry = init(dsb.create_sharded_sb(mesh, D4, n_acc, log_capacity=1 << 9))
+    chunk = compact.sorted_chunk_lanes(dsb.m1_local(n_acc, D4),
+                                       D4 * 2 * (w * dsb.L // D4))
+    assert chunk == 128
+    again = jax.jit(lambda key: jax.vmap(lambda dev: sd_lock_slots(
+        jax.random.split(jax.random.fold_in(key, dev))[0], w, n_acc))(
+            jnp.arange(D4)))
+    prev, most = np.zeros((D4, mc.N_COUNTERS), np.int64), 0
+    for i in range(3):
+        key = jax.random.PRNGKey(40 + i)
+        carry, stats = run(carry, key)
+        assert int(np.asarray(stats)[:, dsb.STAT_OVERFLOW].sum()) == 0
+        op, acc = (np.asarray(x) for x in again(
+            jax.random.split(key, 1)[0]))
+        requests = np.array([((op != 0) & (acc % D4 == owner)).sum()
+                             for owner in range(D4)])
+        buf = np.asarray(carry[-1].buf, np.int64)
+        d, prev = buf - prev, buf
+        np.testing.assert_array_equal(d[:, mc.CTR_LOCK_REQUESTS], requests)
+        np.testing.assert_array_equal(d[:, mc.CTR_LOCK_CHUNKS],
+                                      -(-requests // chunk))
+        most = max(most, int(d[:, mc.CTR_LOCK_CHUNKS].max()))
+    assert most >= 2
+    _, _, cnt = drain(carry)
+    d = np.asarray(cnt.buf, np.int64) - prev           # a drain: no cohort
+    assert not d[:, mc.CTR_LOCK_CHUNKS].any()
+    assert not d[:, mc.CTR_LOCK_REQUESTS].any()
+
+
+def sd_lock_slots(kgen, w, n_accounts):
+    """The lock slots (op, account) [w, L] a device's cohort asks for."""
+    from dint_tpu.engines import smallbank_pipeline as sp
+
+    ttype, a1, a2 = sp.gen_cohort(kgen, w, n_accounts)
+    op, _, acc = sp._lock_slots(ttype, a1, a2)
+    return op, acc
