@@ -243,7 +243,8 @@ def main():
     if trace_dir:
         breakdown = attrib.report(
             trace_dir, jsonl=os.environ.get("DINT_MONITOR_JSONL"),
-            geometry={"w": WIDTH, "k": td.K, "vw": VAL_WORDS})
+            geometry={"w": WIDTH, "r": td.READ_LANES,
+                      "vw": VAL_WORDS})
 
     # dintserve saturation probe (round 17, opt-in): a short open-loop
     # burst through the serving plane at the bench width records serving

@@ -798,7 +798,7 @@ def _t_recovery_sb_shard() -> TargetTrace:
 #
 # The dintcost ledger (analysis/cost.py, gated by passes/cost_budget.py).
 # Geometry mirrors the tiny-trace knobs above and pins the engine
-# constants the waves.py formulas assume (tatp_pipeline.K = 4,
+# constants the waves.py formulas assume (tatp_pipeline.READ_LANES = 2,
 # smallbank_pipeline.L = 3 / .VW = 2 — tests/test_dintcost.py
 # cross-checks them against the engine modules). Budgets are ceilings
 # calibrated once against the derivation at this geometry: dispatches
@@ -808,9 +808,9 @@ def _t_recovery_sb_shard() -> TargetTrace:
 # `python tools/dintcost.py report <target>` and justify the diff in
 # the PR; silence a reviewed exception via the dintlint allowlist.
 
-_TD_GEOM = dict(w=_W, k=4, vw=_VW)
+_TD_GEOM = dict(w=_W, r=2, vw=_VW)
 _SB_GEOM = dict(w=_W, l=3, vw=2)
-_DS_GEOM = dict(w=_W, k=4, vw=_VW, d=_MESH_SHARDS)
+_DS_GEOM = dict(w=_W, r=2, vw=_VW, d=_MESH_SHARDS)
 _DSB_GEOM = dict(w=_W, l=3, vw=2, d=_MESH_SHARDS)
 
 # wave_expect: documented layout deviations from the base formula.
@@ -956,7 +956,7 @@ TARGET_COST.update({
     # 2-D TATP (parallel/multihost.py, flat tuple-axis collectives):
     # replication traffic pre-dates wave scoping -> absolute bytes
     # ceiling like the pipeline targets, not a ledger multiple
-    "multihost/block": _cost(dict(w=_W, k=4, vw=_VW, d=8, h=4), 42,
+    "multihost/block": _cost(dict(w=_W, r=2, vw=_VW, d=8, h=4), 42,
                              918424, bytes_budget=11000,
                              wave_expect=_MH_EXPECT),
     # dinttrace flight-recorder variants: the ring scatter-add adds one
@@ -973,7 +973,7 @@ TARGET_COST.update({
     # budget exists so replay cannot silently grow a per-entry dispatch
     # loop): no waves.py formulas, absolute bytes ceilings like the
     # pipeline targets
-    "recovery/tatp_dense": _cost(dict(w=_W, k=4, vw=_VW), 2, 493848,
+    "recovery/tatp_dense": _cost(dict(w=_W, r=2, vw=_VW), 2, 493848,
                                  steps=1.0, bytes_budget=51200),
     "recovery/smallbank_dense": _cost(dict(w=_W, l=3, vw=2), 1, 349392,
                                       steps=1.0, bytes_budget=10240),

@@ -113,7 +113,8 @@ from ..ops import hotset
 from ..tables import log as logring
 from . import tatp
 from .types import Op, Reply
-from .tatp_pipeline import K, MAGIC, N_SHARDS, classify_wave1, gen_cohort
+from .tatp_pipeline import (K, MAGIC, N_SHARDS, READ_LANES, classify_wave1,
+                            gen_cohort)
 from .tatp_pipeline import (STAT_ATTEMPTED, STAT_COMMITTED, STAT_AB_LOCK,     # noqa: F401 (re-exported)
                             STAT_AB_MISSING, STAT_AB_VALIDATE, STAT_MAGIC_BAD,
                             N_STATS)
@@ -342,9 +343,10 @@ class DenseCtx:
     """An in-flight cohort between pipeline stages (cf. tatp_pipeline.PipeCtx
     — row ids and versions are captured once at wave 1). Bootstrap cohorts
     have attempted == 0 and all-False masks."""
-    rows: jax.Array       # i32 [w, K] flat row ids (sentinel for NOP lanes)
-    is_read: jax.Array    # bool [w, K] OCC_READ lanes
-    vv1: jax.Array        # u32 [w, K] meta (ver<<1|exists) at wave 1
+    rows: jax.Array       # i32 [w, R] flat row ids of the read lanes
+    #                       (R = READ_LANES; sentinel for NOP lanes)
+    is_read: jax.Array    # bool [w, R] OCC_READ lanes
+    vv1: jax.Array        # u32 [w, R] meta (ver<<1|exists) at wave 1
     alive: jax.Array      # bool [w]
     ro_commit: jax.Array  # bool [w]
     granted: jax.Array    # bool [w, 2]
@@ -366,8 +368,9 @@ def empty_ctx(w: int) -> DenseCtx:
         return jnp.asarray(np.zeros(shape, dt))
 
     return DenseCtx(
-        rows=z((w, K), np.int32), is_read=z((w, K), bool),
-        vv1=z((w, K), np.uint32), alive=z((w,), bool),
+        rows=z((w, READ_LANES), np.int32),
+        is_read=z((w, READ_LANES), bool),
+        vv1=z((w, READ_LANES), np.uint32), alive=z((w,), bool),
         ro_commit=z((w,), bool), granted=z((w, 2), bool),
         ws_rows=z((w, 2), np.int32), ws_vv=z((w, 2), np.uint32),
         ws_tbl=z((w, 2), np.int32),
@@ -573,10 +576,17 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
             ops = jnp.where(lane_ok[:, None], ops, Op.NOP)
             ws_active = ws_active & lane_ok[:, None]
 
+    # the gathers issue the read lanes alone: no OCC_READ sits in a lane
+    # >= READ_LANES, and a write slot's row is one of its txn's read rows
+    # (gen_cohort), so its wave-1 meta is that read lane's (ws_pick)
     with waves.part("tatp_dense", "addr"):
-        used = ops != Op.NOP
-        rows = jnp.where(used, base[tbl] + kk, sent)                # [w, K]
-        is_read = ops == Op.OCC_READ
+        r_ops = ops[:, :READ_LANES]
+        used = r_ops != Op.NOP
+        rows = jnp.where(used, base[tbl[:, :READ_LANES]]
+                         + kk[:, :READ_LANES], sent)                # [w, R]
+        is_read = r_ops == Op.OCC_READ
+        ws_rows = jnp.where(ws_active, base[ws_tbl] + ws_key,
+                            sent)                                   # [w, 2]
 
     # ONE fused meta gather serves wave 2 (c1's validate re-read) AND
     # wave 1 (the new cohort's reads). Both gathers depend on the same
@@ -591,8 +601,8 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
             g = hotset.hot_gather(meta, hot_meta, gidx, g_midx, 1)
         else:
             g = meta[gidx]
-        vvB = g[: w * K].reshape(w, K)                      # [w, K]
-        rmeta = g[w * K:].reshape(w, K)                     # [w, K]
+        vvB = g[: w * READ_LANES].reshape(w, READ_LANES)    # [w, R]
+        rmeta = g[w * READ_LANES:].reshape(w, READ_LANES)   # [w, R]
     with waves.part("tatp_dense", "validate"):
         bad = c1.is_read & (vvB != c1.vv1)
 
@@ -609,8 +619,8 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                 v_alive = c1.alive[:, None]
                 v_lanes = (c1.is_read & v_alive).sum(dtype=I32)
                 v_failed = (bad & v_alive).sum(dtype=I32)
-                val_mask = (c1.is_read & v_alive).reshape(-1)       # [wK]
-                val_bad = (bad & v_alive).reshape(-1)               # [wK]
+                val_mask = (c1.is_read & v_alive).reshape(-1)       # [wR]
+                val_bad = (bad & v_alive).reshape(-1)               # [wR]
                 c1_alive_pre = c1.alive
         c1 = c1.replace(alive=c1.alive & ~changed,
                         ab_validate=(c1.alive & changed).sum(dtype=I32))
@@ -619,7 +629,7 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
         vv1 = rmeta                 # ver<<1|exists — locks live elsewhere
         rex = (rmeta & 1) != 0
     if check_magic:
-        # the magic-parity oracle costs one [w,K] single-word gather over
+        # the magic-parity oracle costs one [w,R] single-word gather over
         # the 6.2 GB val array per step; check_magic=False is an A/B
         # measurement knob (DINT_BENCH_CHECK_MAGIC=0) quantifying it —
         # the default keeps the reference's every-read integrity check
@@ -630,9 +640,9 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                 # row's magic word sits at the same flat offset in it
                 mg_midx = jnp.where((rows < hn).reshape(-1), midx, -1)
                 rmagic = hotset.hot_gather(val, hot_val, midx, mg_midx,
-                                           1).reshape(w, K)
+                                           1).reshape(w, READ_LANES)
             else:
-                rmagic = val[midx].reshape(w, K)
+                rmagic = val[midx].reshape(w, READ_LANES)
             magic_bad = jnp.sum(is_read & rex & (rmagic != MAGIC),
                                 dtype=I32)
     else:
@@ -663,10 +673,13 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
     # read-back needs no `cand &`. A cohort that writes in every slot pays
     # 2w / C trips of each loop: the trade the install took (PR 30).
     with waves.part("tatp_dense", "ws_pick"):
-        ws_vv = jnp.take_along_axis(rmeta, ws_lane, axis=1)
+        # the meta of the read lane whose row the slot names; an inactive
+        # slot names the sentinel, whose meta (never written) is 0
+        ws_vv = jnp.zeros((w, 2), U32)
+        for j in reversed(range(READ_LANES)):
+            ws_vv = jnp.where(ws_rows == rows[:, j:j + 1],
+                              rmeta[:, j:j + 1], ws_vv)
     with waves.scope("tatp_dense", "lock"):
-        ws_rows = jnp.where(ws_active, base[ws_tbl] + ws_key,
-                            sent)                               # [w, 2]
         flat_ws = ws_rows.reshape(-1)
         active = ws_active.reshape(-1)
         with waves.part("tatp_dense", "lock_compact"):
@@ -750,10 +763,10 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
             if use_hotset:
                 # partition accounting over the meta + magic gathers
                 hits = (g_midx >= 0).sum(dtype=I32)
-                lanes = 2 * w * K
+                lanes = 2 * w * READ_LANES
                 if check_magic:
                     hits = hits + (mg_midx >= 0).sum(dtype=I32)
-                    lanes += w * K
+                    lanes += w * READ_LANES
                 hot_ctrs = {
                     mon.CTR_HOT_HITS: hits,
                     mon.CTR_HOT_COLD_ROWS: lanes - hits,
@@ -823,7 +836,8 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                 txe.ev(active, jnp.repeat(txn_new, 2), txe.EV_LOCK,
                        waves.full_name("tatp_dense", "lock"),
                        aux=lock_aux, step=tu),
-                txe.ev(val_mask, jnp.repeat(txn_c1, K), txe.EV_VALIDATE,
+                txe.ev(val_mask, jnp.repeat(txn_c1, READ_LANES),
+                       txe.EV_VALIDATE,
                        waves.full_name("tatp_dense", "meta_gather"),
                        aux=val_bad, step=tu),
                 txe.ev(wmask, jnp.repeat(txn_c2, 2), txe.EV_INSTALL,
@@ -909,7 +923,7 @@ def build_pipelined_runner(n_sub: int, w: int = 8192, val_words: int = 10,
     (so counters stay carry[-1]); each block starts from a zeroed ring and
     the caller drains it between dispatches with monitor.txnevents
     .TxnMonitor.observe. ``trace_cap`` defaults to one full block of
-    candidates (w*(K+6) per step) so nothing drops at rate 1.0; the
+    candidates (w*(READ_LANES+6) per step) so nothing drops at rate 1.0; the
     resolved txnevents.TraceCfg hangs off ``init.trace_cfg``. Off =
     engine outputs bit-identical, not one extra jaxpr eqn."""
     assert 2 * w <= (1 << K_ARB), f"w={w} exceeds the arb slot field"
@@ -924,9 +938,9 @@ def build_pipelined_runner(n_sub: int, w: int = 8192, val_words: int = 10,
     trace_on = txe.trace_enabled(trace)
     tcfg = None
     if trace_on:
-        # candidates/step: LOCK [2w] + VALIDATE [wK] + INSTALL [2w] +
+        # candidates/step: LOCK [2w] + VALIDATE [wR] + INSTALL [2w] +
         # OUTCOME x2 [2w] — default cap holds a full block at rate 1.0
-        n_step = w * (K + 6)
+        n_step = w * (READ_LANES + 6)
         cap = int(trace_cap) if trace_cap else n_step * cohorts_per_block
         tcfg = txe.TraceCfg(rate=txe.trace_rate(trace_rate), cap=cap,
                             wave=waves.full_name("tatp_dense", "trace"))

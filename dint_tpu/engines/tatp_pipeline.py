@@ -52,6 +52,7 @@ U32 = jnp.uint32
 
 N_SHARDS = 3
 K = 4                  # wave-1 lanes per txn
+READ_LANES = 2         # lanes 0..READ_LANES-1 hold every OCC_READ
 MAGIC = 0x7A79         # parity with clients/tatp_client.py
 
 # stats vector layout
@@ -100,6 +101,10 @@ def gen_cohort(key, w: int, n_sub: int, mix=None):
     this is ~6x cheaper.
 
     Returns (ttype [w], lane ops/tbl/keys [w, K], write-slot arrays [w, 2]).
+    Every OCC_READ sits in a lane < READ_LANES, and every active write
+    slot names the row of one of its txn's read lanes
+    (tests/test_tatp_read_lanes.py), so engines/tatp_dense.py gathers the
+    read lanes alone.
     """
     bits = jax.random.bits(key, (w, 4), U32)
     thresh = jnp.asarray(wl.mix_thresholds(
@@ -375,9 +380,11 @@ def classify_wave1(ttype, rt, ops, ws_active, ws_lane, ws_rt=None):
     """Per-txn-type wave-1 outcome rules, shared by every TATP engine.
 
     Given reply types rt [w, K] (VAL/NOT_EXIST for reads, GRANT/REJECT for
-    locks), classifies each txn exactly like the reference coordinator
-    (read-only commit on success, REJECT -> lock abort, required-row
-    absence / insert-exists -> missing abort; client_ebpf_shard.cc:608-703).
+    locks; with ``ws_rt``, rt may hold the READ_LANES read lanes alone:
+    only lanes 0 and 1 are looked at then), classifies each txn exactly
+    like the reference coordinator (read-only commit on success, REJECT ->
+    lock abort, required-row absence / insert-exists -> missing abort;
+    client_ebpf_shard.cc:608-703).
     Returns (is_ro, rw, granted [w,2], lock_rejected, missing), all masked
     to lanes that exist (ops[:,0] != NOP for bootstrap/drain cohorts).
 

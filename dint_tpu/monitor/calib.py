@@ -470,7 +470,7 @@ def synthesize_evidence() -> dict:
             resid = 0.25 * ((i * 7) % 5 - 2)     # in [-0.5, +0.5], mean-free-ish
             samples.append([w, round(true.service_us(w) + resid, 6)])
             i += 1
-    geometry = {"w": 1024, "k": 2, "vw": 4}
+    geometry = {"w": 1024, "r": 2, "vw": 4}
     ev_waves = {}
     idx = 0
     for name in W.WAVES_BY_ENGINE["tatp_dense"]:

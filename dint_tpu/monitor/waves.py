@@ -24,7 +24,7 @@ that survives fusion, so it is schema:
   reads them.
 * **Bytes formulas are declared, not measured.** Each wave may carry an
   expected-bytes-per-step formula (a string evaluated against the run's
-  geometry: w, k, l, vw, d, ...), the same hand accounting PERF.md's
+  geometry: w, r, l, vw, d, ...), the same hand accounting PERF.md's
   closing ledger was built from — attribution divides measured time into
   it to report effective HBM bandwidth per wave, which is how "this wave
   is dispatch-bound, not bandwidth-bound" becomes machine-readable.
@@ -49,7 +49,7 @@ PREFIX = "dint"
 
 # ------------------------------------------------------------ the registry
 # (engine, wave, doc, bytes-per-step formula | None). APPEND ONLY.
-# Formula variables: w = cohort width, k = TATP wave-1 lanes per txn,
+# Formula variables: w = cohort width, r = TATP read lanes per txn,
 # l = SmallBank lock lanes per txn, vw = val words, d = mesh devices.
 # Log-entry estimate: ~20 B header + 4*vw payload, x3 replicas.
 _REGISTRY: tuple[tuple[str, str, str, str | None], ...] = (
@@ -69,11 +69,12 @@ _REGISTRY: tuple[tuple[str, str, str, str | None], ...] = (
      "2*w*3*(20 + 4*vw)"),
     ("tatp_dense", "meta_gather",
      "fused meta gather serving c1's validate re-read AND the new "
-     "cohort's reads (2wK random lanes over the meta array)",
-     "2*w*k*4"),
+     "cohort's reads (2wR random lanes over the meta array: the R read "
+     "lanes of a txn)",
+     "2*w*r*4"),
     ("tatp_dense", "magic_gather",
-     "magic-word integrity gather over the val array (wK random "
-     "single-word lanes; absent when check_magic=False)", "w*k*4"),
+     "magic-word integrity gather over the val array (wR random "
+     "single-word lanes; absent when check_magic=False)", "w*r*4"),
     ("tatp_dense", "lock",
      "lock arbitration on the arb array: stamp gather + masked "
      "scatter-max + winner gather-back (the active ones of the 2w write "
@@ -198,9 +199,9 @@ _REGISTRY: tuple[tuple[str, str, str, str | None], ...] = (
     # --- (sampling masks lanes out of the scatter but the update
     # --- operand — what dintcost prices — stays full-width) ------------
     ("tatp_dense", "trace",
-     "flight-recorder event scatter: LOCK (2w) + VALIDATE (wK) + "
+     "flight-recorder event scatter: LOCK (2w) + VALIDATE (wR) + "
      "INSTALL (2w) + OUTCOME x2 (2w) candidate records per step",
-     "16*(w*(k+6))"),
+     "16*(w*(r+6))"),
     ("smallbank_dense", "trace",
      "flight-recorder event scatter: LOCK (wL) + INSTALL (wL) + "
      "OUTCOME (w) candidate records per step", "16*(w*(2*l+1))"),
@@ -298,7 +299,7 @@ assert N_WAVES == len(set(ALL_WAVES)), "duplicate wave name in registry"
 
 def wave_bytes(name: str, **geometry) -> int | None:
     """Evaluate a wave's expected-bytes-per-step formula against run
-    geometry (w=, k=, l=, vw=, d=, lg=, ld=, sl=, dc=...). Returns None for compute-only
+    geometry (w=, r=, l=, vw=, d=, lg=, ld=, sl=, dc=...). Returns None for compute-only
     waves and for formulas whose variables the caller did not supply —
     attribution then reports time without a bandwidth figure instead of
     inventing one."""
@@ -373,8 +374,8 @@ _PARTS: tuple[tuple[str | tuple[str, ...], str | None, str, str], ...] = (
     ("tatp_dense", None, "validate",
      "wave 2: c1's version compare, its reductions, the new alive mask"),
     ("tatp_dense", None, "ws_pick",
-     "the write slots' version pick: a take_along_axis gather of [w, 2] "
-     "out of the [w, K] meta words read"),
+     "the write slots' version pick: each slot takes the meta word of "
+     "the read lane that names its row ([w, 2] selects over [w, R])"),
     ("tatp_dense", None, "classify",
      "reply types, classify_wave1 and the new cohort's context"),
     (_STEPPED, None, "monitor",

@@ -1203,7 +1203,7 @@ def run_all(out: str, window_s: float = 10.0, quick: bool = False,
                        depth=3, magic_idx=td.STAT_MAGIC_BAD,
                        window_s=window_s, open_rates=rates, results=results,
                        lat_widths=lat_widths,
-                       geom={"k": td.K, "vw": 10})
+                       geom={"r": td.READ_LANES, "vw": 10})
     skew_preset = only is not None and "skew" in only
     if want("smallbank") and not skew_preset:
         from dint_tpu.clients import workloads as wl

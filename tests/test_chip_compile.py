@@ -32,7 +32,7 @@ from dint_tpu.tables import kv
 
 HBM_BYTES = 16e9                 # one v5e chip
 N_SUB, W, CPB, VW = 7_000_000, 8192, 16, 10
-K = td.K
+R = td.READ_LANES
 N1 = td.n_rows(N_SUB) + 1        # table rows incl. the sentinel
 U32, I32 = jnp.uint32, jnp.int32
 
@@ -172,8 +172,14 @@ def test_tatp7m_block_program_fits_one_chip(one_chip):
         assert set(scatter_index_counts(hlo, N1)) == {chunk}
         assert "part.lock_scatter_max/scatter-max" in hlo
         # nor does a gather out of arb or meta issue 2w lanes: the stamp
-        # read and the winner read-back issue a chunk each
-        assert set(gather_lane_counts(hlo, N1)) == {chunk, 2 * W * K}
+        # read and the winner read-back issue a chunk each, and the fused
+        # meta gather the R read lanes of c1 and of the new cohort, not
+        # all K = 4 lanes of a txn
+        assert set(gather_lane_counts(hlo, N1)) == {chunk, 2 * W * R}
+        # the magic gather, the one gather out of val: R lanes a txn (the
+        # drain's new cohorts are constants: its magic gather folds away)
+        assert set(gather_lane_counts(hlo, N1 * VW)) == (
+            {W * R} if fn is run else set())
 
 
 def test_smallbank24m_block_program_fits_one_chip(one_chip):
@@ -465,7 +471,8 @@ def test_dense_sharded_block_program_on_four_chips(topo):
     # at the entry and three passes over it at the exit, 5.0 ms of the
     # 12.9 ms step on the chip (PERF.md §6, PR 42).
     chunk = compact.chunk_lanes(2 * W)
-    for c, ceiling in ((block, 0.1e9), (steps, 0.012e9)):
+    for c, ceiling, magic in ((block, 0.1e9, {W * R}),
+                              (steps, 0.012e9, set())):
         ma = c.memory_analysis()
         assert abs(ma.argument_size_in_bytes - total / n) < 0.01 * total / n
         assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
@@ -487,6 +494,10 @@ def test_dense_sharded_block_program_on_four_chips(topo):
         assert set(scatter_index_counts(hlo, ds.N_BCK * n1)) == {chunk}
         assert set(scatter_index_counts(hlo, n1 * VW)) == {chunk * VW}
         assert 2 * W * VW not in all_scatter_index_counts(hlo)
+        # the primary's gathers, as on one chip: meta the R read lanes of
+        # c1 and of the new cohort (arb a chunk), val the R magic words
+        assert set(gather_lane_counts(hlo, n1)) == {chunk, 2 * W * R}
+        assert set(gather_lane_counts(hlo, n1 * VW)) == magic
 
     # the way back to the stacked state, once a drain and a leaf at a
     # time: the compiler writes [N] -> [1, N] as a zero fill and a one-trip

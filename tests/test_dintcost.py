@@ -43,8 +43,8 @@ ALLOW = os.path.join(REPO, "tools", "dintlint_allow.json")
 # model, then each mutation regresses exactly one number.
 
 WAVE = "dint.tatp_dense.magic_gather"
-GEOM = dict(w=8, k=4, vw=2)
-DECL = waves.wave_bytes(WAVE, **GEOM)          # = w*k*4 = 128 B
+GEOM = dict(w=16, r=2, vw=2)
+DECL = waves.wave_bytes(WAVE, **GEOM)          # = w*r*4 = 128 B
 NE = DECL // 4                                  # gather elements
 N = 512
 
@@ -229,9 +229,9 @@ def test_wave_registry_complete():
 
 def test_budget_geometry_pins_engine_constants():
     """The ledger's formula variables against the engine modules' real
-    constants — a drifted K/L/VW would silently skew every budget."""
+    constants — a drifted R/L/VW would silently skew every budget."""
     from dint_tpu.engines import smallbank_pipeline, tatp_pipeline
-    assert T._TD_GEOM["k"] == tatp_pipeline.K
+    assert T._TD_GEOM["r"] == tatp_pipeline.READ_LANES
     assert T._TD_GEOM["w"] == T._W and T._TD_GEOM["vw"] == T._VW
     assert T._SB_GEOM["l"] == smallbank_pipeline.L
     assert T._SB_GEOM["vw"] == smallbank_pipeline.VW

@@ -24,7 +24,7 @@ pytestmark = pytest.mark.scope
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FIXTURE = os.path.join(REPO, "tests", "fixtures", "dintscope_trace.json")
-GEOM = {"w": 8192, "k": 4, "l": 3, "vw": 10, "d": 8,
+GEOM = {"w": 8192, "r": 2, "l": 3, "vw": 10, "d": 8,
         "lg": 13, "ld": 7, "sl": 8, "dc": 64}
 CLI = [sys.executable, os.path.join(REPO, "tools", "dintscope.py")]
 
@@ -287,7 +287,7 @@ def test_diff_ignores_sub_noise_waves():
 
 def test_report_cli_json_and_artifact(tmp_path):
     out = str(tmp_path / "bd.json")
-    c = _cli(["report", FIXTURE, "--geom", "w=8192", "k=4", "l=3",
+    c = _cli(["report", FIXTURE, "--geom", "w=8192", "r=2", "l=3",
               "vw=10", "d=8", "--json", "-o", out])
     assert c.returncode == 0, c.stderr
     bd = json.loads(c.stdout.strip().splitlines()[-1])

@@ -9,7 +9,7 @@ turns two of them into a CI gate.
 
 Usage:
     python tools/dintscope.py report TRACE [--jsonl RUN.jsonl]
-        [--geom w=8192 k=4 vw=10] [--steps N] [--json] [-o OUT.json]
+        [--geom w=8192 r=2 vw=10] [--steps N] [--json] [-o OUT.json]
     python tools/dintscope.py diff A B [--wave-pct 25] [--step-pct 10]
         [--rate-pct 10] [--min-ms 0.05] [--no-alias] [--json]
     python tools/dintscope.py describe [--json]
@@ -153,7 +153,7 @@ def main(argv=None) -> int:
     p.add_argument("--jsonl", default=None,
                    help="dintmon JSONL stream (steps + throughput)")
     p.add_argument("--geom", nargs="*", default=[],
-                   help="formula vars, e.g. w=8192 k=4 vw=10")
+                   help="formula vars, e.g. w=8192 r=2 vw=10")
     p.add_argument("--steps", type=int, default=None)
     p.add_argument("--json", action="store_true")
     p.add_argument("-o", "--out", default=None,

@@ -27,7 +27,7 @@ if plat:
     jax.config.update("jax_platforms", plat)
 
 from dint_tpu.engines import tatp_dense as td
-from dint_tpu.engines.tatp_pipeline import K, gen_cohort
+from dint_tpu.engines.tatp_pipeline import READ_LANES as R, gen_cohort
 from dint_tpu.tables import log as logring
 
 I32 = jnp.int32
@@ -71,7 +71,7 @@ def timeit(name, fn, *args, reps: int = 3):
 
 def main():
     n1 = td.n_rows(N_SUB) + 1
-    r = W * K
+    r = W * R
     print(f"w={W} n_sub={N_SUB} rows={n1} lanes={r} iters={ITERS}",
           flush=True)
     rng = np.random.default_rng(0)
@@ -98,14 +98,14 @@ def main():
 
     timeit("gen_cohort", gen, jax.random.PRNGKey(0))
 
-    # 2. wave-1 gather: meta [w,K] + magic word
+    # 2. wave-1 gather: meta [w,R] + magic word (the read lanes)
     def gathers(c):
         db_, rws = c
-        m = db_.meta[rws.reshape(W, K)]
-        g = db_.val[rws.reshape(W, K) * VW + 1]
+        m = db_.meta[rws.reshape(W, R)]
+        g = db_.val[rws.reshape(W, R) * VW + 1]
         return (db_, rws + (m.sum() + g.sum()).astype(I32) * 0)
 
-    timeit("gathers meta+magic [wK]", gathers, (db, rows))
+    timeit("gathers meta+magic [wR]", gathers, (db, rows))
 
     # 3. install scatters: meta [2w] + val rows [2w, VW]
     def installs(c):
