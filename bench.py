@@ -53,8 +53,7 @@ BLOCK = int(os.environ.get("DINT_BENCH_BLOCK", 16))     # cohorts per dispatch
 VAL_WORDS = 10
 WINDOW_S = float(os.environ.get("DINT_BENCH_WINDOW_S", 10.0))
 # SmallBank skew knobs (the --hot-frac/--hot-prob of the sweep drivers,
-# env-style like every bench knob): None = the reference 90%/4% skew. The
-# dintcache hot tier (DINT_USE_HOTSET=1) aligns its mirror to HOT_FRAC.
+# env-style like every bench knob): None = the reference 90%/4% skew.
 HOT_FRAC = (float(os.environ["DINT_BENCH_HOT_FRAC"])
             if "DINT_BENCH_HOT_FRAC" in os.environ else None)
 HOT_PROB = (float(os.environ["DINT_BENCH_HOT_PROB"])
@@ -94,16 +93,7 @@ def main():
     # default) the engines run the untraced jaxpr and the artifact
     # records dinttrace: null.
     trace_on = os.environ.get("DINT_TRACE") == "1"
-    from dint_tpu.ops import hotset
-
-    # plan-resolved knobs replace the env-flag default path (ISSUE 17):
-    # the pinned PLAN.json decides use_hotset for the headline config; ambient DINT_* flags win only under
-    # DINT_PLAN_OVERRIDE=1 and the artifact records which knobs the
-    # override changed. Without a readable plan, behaviour is exactly the
-    # old env resolution and the artifact records "plan": null.
-    plan_knobs, plan_meta = _plan_resolve("tatp_uniform")
-    plan_kw = {k: plan_knobs[k] for k in ("use_hotset",)
-               if k in plan_knobs} if plan_meta else {}
+    trace_rate = float(os.environ.get("DINT_TRACE_RATE", "1.0"))
 
     def build_and_warm():
         t0 = _time.time()
@@ -114,8 +104,7 @@ def main():
         run, init, drain = td.build_pipelined_runner(
             N_SUBSCRIBERS, w=WIDTH, val_words=VAL_WORDS,
             cohorts_per_block=BLOCK, check_magic=check_magic,
-            monitor=monitor_on, trace=trace_on,
-            **plan_kw)
+            monitor=monitor_on, trace=trace_on, trace_rate=trace_rate)
         carry = init(db)
         populate_s = _time.time() - t0
 
@@ -267,7 +256,7 @@ def main():
         serve_out = {k: rep[k] for k in
                      ("offered", "admitted", "shed", "blocks",
                       "achieved_rate", "slo_us", "slo_met",
-                      "queue", "service", "controller", "plan")}
+                      "queue", "service", "controller")}
 
     out = {
         "schema": attrib.ARTIFACT_SCHEMA,
@@ -309,18 +298,9 @@ def main():
         # {n_hosts, n_ici, axes} parsed from DINT_BENCH_MESH
         "n_shards": None,
         "mesh": None,
-        # dintcache hot tier + skew provenance (TATP itself keeps the hot
-        # tier off — uniform NURand; the flag records the env so the
-        # SmallBank leg's A/B state is readable from the headline line)
-        "use_hotset": hotset.env_use_hotset(),
+        # the SmallBank leg's skew (None = the reference 90%/4%)
         "hot_frac": HOT_FRAC,
         "hot_prob": HOT_PROB,
-        # which pinned plan resolved the build knobs, schema-stable:
-        # {source, hash, overridden} when PLAN.json was readable (dintplan,
-        # ANALYSIS.md "Static configuration planning"), EXPLICIT null
-        # otherwise — an artifact can always prove whether its knobs were
-        # plan-resolved or ambient
-        "plan": plan_meta,
         # end-of-run dintmon snapshot, schema-stable: a {name: count}
         # object when DINT_MONITOR=1, EXPLICIT null otherwise — consumers
         # never need to distinguish "off" from "old artifact schema"
@@ -365,27 +345,6 @@ def main():
     print(json.dumps(out), flush=True)
 
 
-def _plan_resolve(workload):
-    """Plan-resolved build knobs for one workload from the pinned
-    PLAN.json (analysis/plan.resolve_for): the plan replaces the env-flag
-    default path, and ambient DINT_* flags win only under
-    DINT_PLAN_OVERRIDE=1 (meta["overridden"] records which knobs moved —
-    the plan_check gate makes any other contradiction an ERROR). Returns
-    ({}, None) when no plan is readable or DINT_BENCH_PLAN=0: knobs then
-    fall back to plain env resolution and the artifact records
-    "plan": null, never a silent default."""
-    if os.environ.get("DINT_BENCH_PLAN", "1") == "0":
-        return {}, None
-    try:
-        from dint_tpu.analysis import plan as dplan
-        knobs, meta = dplan.resolve_for(workload)
-        if meta.get("source") is None:
-            return {}, None
-        return knobs, meta
-    except Exception:  # noqa: BLE001 — a broken plan must not kill bench
-        return {}, None
-
-
 def _bench_smallbank():
     """Secondary metric: SmallBank committed txn/s (device-fused pipeline).
 
@@ -398,19 +357,14 @@ def _bench_smallbank():
     # quoted; the headline is the abort-matched point (bench_smallbank.run)
     env_w = os.environ.get("DINT_BENCH_SB_WIDTH")
     widths = (int(env_w),) if env_w else bench_smallbank.WIDTHS
-    sb_knobs, sb_meta = _plan_resolve("smallbank_skewed")
-    out = bench_smallbank.run(
+    return bench_smallbank.run(
         window_s=WINDOW_S,
         n_accounts=int(os.environ.get("DINT_BENCH_SB_ACCOUNTS",
                                       bench_smallbank.N_ACCOUNTS)),
         widths=widths,
         block=BLOCK,
         hot_frac=HOT_FRAC,
-        hot_prob=HOT_PROB,
-        knobs={k: v for k, v in sb_knobs.items()
-               if k.startswith("use_")} if sb_meta else None)
-    out["smallbank_plan"] = sb_meta
-    return out
+        hot_prob=HOT_PROB)
 
 
 if __name__ == "__main__":

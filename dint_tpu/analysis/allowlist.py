@@ -77,7 +77,7 @@ def prune_entries(entries: list[dict]) -> tuple[list[dict], list[dict]]:
 
 def prune_scoped(entries: list[dict], pass_name: str
                  ) -> tuple[list[dict], list[dict]]:
-    """Gate-scoped prune (dintcost/dintdur/dintplan --prune-allowlist):
+    """Gate-scoped prune (dintcost/dintdur --prune-allowlist):
     split (kept, dropped) considering ONLY entries pinned to
     ``pass_name``. Callers must have run apply() over that gate's FULL
     target matrix first. Entries for other passes — and wildcard-pass

@@ -14,7 +14,7 @@ findings as SARIF 2.1.0 through the one serializer, run the gate-scoped
     2  usage / artifact errors (argparse, OSError, ValueError)
 
 This module factors that scaffolding once. tools/dintmut.py is the first
-native client; dintlint/dintcost/dintdur/dintplan/dintmon/dintcal import
+native client; dintlint/dintcost/dintdur/dintmon/dintcal import
 the same helpers without any flag or exit-code change (their CLI
 contracts are pinned by the tests/test_dint*.py subprocess suites).
 

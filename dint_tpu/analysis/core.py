@@ -224,23 +224,31 @@ def walk(trace: TargetTrace) -> Iterator[EqnCtx]:
                 stack.append((sub, path + (name,), sub_mesh, sub_pl))
 
 
-def site_of(eqn: jcore.JaxprEqn) -> str:
-    """Best-effort user-code 'file.py:line' for an eqn (the deepest frame
-    outside jax itself); '' when source info was not recorded."""
+def user_frame_of(eqn: jcore.JaxprEqn) -> tuple[str, int, str] | None:
+    """Best-effort user-code (file, line, function) for an eqn (the
+    deepest frame outside jax itself, the file relative to the repo);
+    None when source info was not recorded."""
     try:
         frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is None:
-            return ""
+            return None
         fname = frame.file_name
         if "/analysis/" in fname:
-            return ""   # the harness's own trace call, not user provenance
+            return None  # the harness's own trace call, not user provenance
         for marker in ("/dint_tpu/", "/tests/", "/tools/"):
             if marker in fname:
                 fname = fname[fname.index(marker) + 1:]
                 break
-        return f"{fname}:{frame.start_line}"
+        return fname, frame.start_line, frame.function_name
     except Exception:               # noqa: BLE001 — provenance is best-effort
-        return ""
+        return None
+
+
+def site_of(eqn: jcore.JaxprEqn) -> str:
+    """Best-effort user-code 'file.py:line' for an eqn; '' when source
+    info was not recorded."""
+    frame = user_frame_of(eqn)
+    return f"{frame[0]}:{frame[1]}" if frame else ""
 
 
 def def_var(jaxpr: jcore.Jaxpr, var, upto: int) -> jcore.JaxprEqn | None:

@@ -11,8 +11,8 @@ mutants are pure jaxpr rewrites, nothing is ever executed), applies one
 semantic corruption from a first-class operator registry, re-runs the
 full structural pass matrix on the mutant, and attributes the kill to
 the specific pass/code that fired. The verdict matrix is pinned as a
-schema-versioned MUTCOV.json under the PLAN.json provenance-hash
-discipline; passes/mut_check.py is the standing gate over that artifact
+schema-versioned MUTCOV.json under a provenance-hash discipline;
+passes/mut_check.py is the standing gate over that artifact
 (kill-rate floor, survivor triage, killer-family coverage).
 
 Operator registry (OPERATORS):
@@ -50,7 +50,7 @@ eqn's consumers simply lose its facts — exactly the corruption the gates
 key on) and stale reducer params on a swapped scatter primitive.
 
 Kill attribution: the mutant runs MUT_PASSES (every structural pass —
-the artifact-anchored plan_check/calib_check/mut_check are excluded:
+the artifact-anchored calib_check/mut_check are excluded:
 they check pinned documents, not jaxprs) under the shared allowlist;
 `new_errors` is the mutant's unsuppressed ERROR (pass, code) set minus
 the base trace's, `killed` means it is non-empty, and `killer` is the
@@ -74,7 +74,8 @@ import jax._src.core as jcore
 from jax._src.lax import slicing as _lsl
 
 from . import dataflow as df
-from .core import Finding, PASSES, SEV_ERROR, TargetTrace, site_of
+from .core import (Finding, PASSES, SEV_ERROR, TargetTrace, site_of,
+                   user_frame_of)
 
 SCHEMA = 1
 ARTIFACT = "MUTCOV.json"
@@ -84,9 +85,9 @@ KILL_RATE_FLOOR = 0.90              # standing ERROR below this
 MAX_SITES = 2                       # per (target, operator) cell cap
 
 # the pass matrix mutants re-run: every structural pass; the
-# artifact-anchored checks (plan_check/calib_check/mut_check) verify
+# artifact-anchored checks (calib_check/mut_check) verify
 # pinned documents, not jaxprs, and would fire identically on mutants
-_ANCHORED = {"plan_check", "calib_check", "mut_check"}
+_ANCHORED = {"calib_check", "mut_check"}
 
 
 def mut_passes() -> list[str]:
@@ -235,7 +236,7 @@ class Mutant:
     target: str
     operator: str
     index: int                      # ordinal within (target, operator)
-    site: str                       # source provenance of the edited eqn
+    site: str                       # cell_site of the edited eqn
     note: str                       # which edit variant was applied
     addr: tuple                     # address of the enclosing jaxpr
     edit: Callable                  # Jaxpr -> Jaxpr
@@ -561,6 +562,14 @@ OPERATORS: dict[str, MutOp] = {op.name: op for op in [
 ]}
 
 
+def cell_site(eqn) -> str:
+    """A cell's source provenance: 'file.py:function' of the edited eqn.
+    No line number, so an edit elsewhere in the file leaves the pinned
+    cells (and their hash) as they are."""
+    frame = user_frame_of(eqn)
+    return f"{frame[0]}:{frame[2]}" if frame else ""
+
+
 def discover(trace: TargetTrace, operators) -> list[Mutant]:
     """Enumerate the mutant cells for one target, deterministically (walk
     order x registry order), capped at MAX_SITES per operator."""
@@ -572,7 +581,7 @@ def discover(trace: TargetTrace, operators) -> list[Mutant]:
         op = OPERATORS[opname]
         for idx, (addr, i, eqn, note, edit) in enumerate(
                 op.find(trace, flow)[:MAX_SITES]):
-            out.append(Mutant(trace.name, opname, idx, site_of(eqn),
+            out.append(Mutant(trace.name, opname, idx, cell_site(eqn),
                               note, addr, edit))
     return out
 

@@ -19,15 +19,9 @@ walking machinery and ANALYSIS.md for the invariant catalogue):
                      fault domains, bounded rings, replay coverage,
                      in-doubt totality (analysis/dataflow.py's LOGGED/
                      TRUNCATED facts — the dintdur gate)
-  plan_check         the pinned PLAN.json agrees with the knob registry,
-                     the calibration ledger and the dintcost-derived
-                     frontier; env flags cannot contradict it silently
-                     (analysis/plan.py — the dintplan gate)
   calib_check        the pinned CALIB.json reproduces its own fit from
-                     the embedded samples, its provenance hashes hold,
-                     and the plan's serve rows were priced with the
-                     model the resolver picks now (monitor/calib.py —
-                     the dintcal gate)
+                     the embedded samples and its provenance hashes
+                     hold (monitor/calib.py — the dintcal gate)
   mut_check          the pinned MUTCOV.json (machine-generated engine
                      mutants vs the pass matrix) stays provenance-true,
                      clears the kill-rate floor, triages every
@@ -38,5 +32,5 @@ Adding a pass: write `passes/<name>.py`, decorate the entry point with
 `@core.register_pass("<name>")`, import it here.
 """
 from . import (aliasing, calib_check, cost_budget,  # noqa: F401
-               durability, mut_check, plan_check, protocol, purity,
+               durability, mut_check, protocol, purity,
                scatter_race, shard_consistency, u64_overflow)

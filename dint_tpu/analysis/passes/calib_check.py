@@ -1,11 +1,10 @@
 """dintcal gate: the pinned CALIB.json must agree with its own evidence.
 
 The calibration plane (monitor/calib.py) fits ServiceModel coefficients
-from measured evidence and pins them as CALIB.json; PLAN.json's serve
-rows then price capacity with those coefficients. This pass fails
-closed when the pinned calibration drifts from the evidence that
-justified it, or when the plan and the calibration disagree about which
-model priced the serve rows (ANALYSIS.md "Calibration audit"):
+from measured evidence and pins them as CALIB.json; the serving plane
+takes them as its default service-time prior. This pass fails closed
+when the pinned calibration drifts from the evidence that justified it
+(ANALYSIS.md "Calibration audit"):
 
   malformed-calib     unparseable / wrong schema / missing sections /
                       non-finite coefficients
@@ -21,32 +20,22 @@ model priced the serve rows (ANALYSIS.md "Calibration audit"):
                       formula in monitor/waves.WAVE_BYTES, or its
                       pinned implied-GB/s disagrees with its own
                       (ms_per_step, bytes_per_step) row
-  plan-model-drift    PLAN.json serve rows were priced with a model
-                      other than the resolver would pick now: source
-                      "calib" with a different hash than the pinned
-                      CALIB.json, or source "defaults" while a valid
-                      CALIB.json exists
-  missing-calib       PLAN.json serve rows record source "calib" but no
-                      readable CALIB.json is present
 
-Anchored like plan_check: whole-artifact checks land on ONE registered
-target (plan.DEFAULT_ANCHOR / DINT_PLAN_ANCHOR) and return [] elsewhere.
-When NEITHER a CALIB.json nor a calib-sourced plan row exists, the pass
-returns [] — calibration is opt-in; the gate bites once you pin one.
+Whole-artifact checks land on ONE registered target (``ANCHOR``) and
+return [] elsewhere. Without a CALIB.json the pass returns [] —
+calibration is opt-in; the gate bites once you pin one.
 """
 from __future__ import annotations
 
 import math
-import os
 from pathlib import Path
 
 from ...monitor import calib as CAL
-from .. import plan as P
 from ..core import Finding, SEV_ERROR, TargetTrace, register_pass
 
+ANCHOR = "tatp_dense/block"      # the target the findings land on
 _SUGGEST_REFIT = ("refit with `python tools/dintcal.py fit <evidence> -o "
-                  "CALIB.json` and re-pin the plan with `python "
-                  "tools/dintplan.py plan --calib CALIB.json`")
+                  "CALIB.json`")
 
 
 def _err(code: str, target: str, message: str, site: str = "",
@@ -170,93 +159,28 @@ def _wave_findings(calib: dict, target: str) -> list[Finding]:
     return out
 
 
-def _plan_model_findings(calib: dict | None, target: str,
-                         plan: dict | None) -> list[Finding]:
-    """Cross-artifact: every serve row in the plan must have been priced
-    with the model the resolver picks NOW."""
-    if plan is None:
-        return []
-    pinned_hash = (calib or {}).get("provenance", {}).get("calib_hash")
-    out: list[Finding] = []
-    for wname, entry in sorted(plan.get("workloads", {}).items()):
-        serve = entry.get("serve")
-        if not isinstance(serve, dict):
-            continue
-        m = serve.get("model") or {}
-        src, h = m.get("source"), m.get("hash")
-        site = f"{wname}.serve.model"
-        if src == "calib":
-            if calib is None:
-                out.append(_err(
-                    "missing-calib", target,
-                    f"plan workload {wname}: serve priors were priced "
-                    f"with calib {h!r} but no readable CALIB.json is "
-                    "present — the plan's capacity claims are "
-                    "unattributable",
-                    site=site,
-                    suggestion="restore the CALIB.json the plan was "
-                               "pinned against, or re-pin with `python "
-                               "tools/dintplan.py plan`"))
-            elif h != pinned_hash:
-                out.append(_err(
-                    "plan-model-drift", target,
-                    f"plan workload {wname}: serve priors were priced "
-                    f"with calib {h!r} but the pinned CALIB.json is "
-                    f"{pinned_hash!r} — the calibration moved after the "
-                    "plan was pinned", site=site))
-        elif src == "defaults":
-            if calib is not None:
-                out.append(_err(
-                    "plan-model-drift", target,
-                    f"plan workload {wname}: serve priors were priced "
-                    "with the ServiceModel DEFAULTS while a pinned "
-                    f"CALIB.json ({pinned_hash!r}) exists — the plan "
-                    "ignores the calibration", site=site))
-        elif src is not None:
-            out.append(_err(
-                "plan-model-drift", target,
-                f"plan workload {wname}: serve model source {src!r} is "
-                "neither 'calib' nor 'defaults'", site=site))
-    return out
-
-
-def check_calib_doc(calib: dict | None, target: str, *,
-                    plan: dict | None = None,
+def check_calib_doc(calib: dict, target: str, *,
                     source_dir=None) -> list[Finding]:
-    """Every calib_check finding for parsed documents (the fixture tests
-    feed mutated documents straight in here). `calib=None` checks only
-    the cross-artifact plan side."""
-    out: list[Finding] = []
-    if calib is not None:
-        out += _structure_findings(calib, target)
-        if out:
-            return out
-        out += _provenance_findings(calib, target, source_dir=source_dir)
-        out += _fit_findings(calib, target)
-        out += _wave_findings(calib, target)
-    out += _plan_model_findings(calib, target, plan)
+    """Every calib_check finding for a parsed document (the fixture tests
+    feed mutated documents straight in here)."""
+    out = _structure_findings(calib, target)
+    if out:
+        return out
+    out += _provenance_findings(calib, target, source_dir=source_dir)
+    out += _fit_findings(calib, target)
+    out += _wave_findings(calib, target)
     return out
-
-
-def _anchor() -> str:
-    return os.environ.get(P.ENV_PLAN_ANCHOR, P.DEFAULT_ANCHOR)
 
 
 @register_pass("calib_check")
 def calib_check(trace: TargetTrace) -> list[Finding]:
-    """Verifies the pinned CALIB.json against its embedded evidence and
-    the plan's recorded model provenance (whole-artifact checks,
-    anchored to one target; [] when calibration is not in use)."""
-    if trace.name != _anchor():
+    """Verifies the pinned CALIB.json against its embedded evidence
+    (whole-artifact checks, anchored to one target; [] when calibration
+    is not in use)."""
+    if trace.name != ANCHOR:
         return []
     cpath = CAL.calib_path()
     calib, findings = load_calib_findings(trace.name, cpath)
-    try:
-        plan = P.load_plan(P.plan_path())
-    except (OSError, ValueError):
-        plan = None             # plan health is plan_check's job
-    if calib is None and not findings \
-            and not _plan_model_findings(None, trace.name, plan):
-        return []
-    return findings + check_calib_doc(
-        calib, trace.name, plan=plan, source_dir=cpath.parent)
+    if calib is None:
+        return findings
+    return check_calib_doc(calib, trace.name, source_dir=cpath.parent)

@@ -145,7 +145,6 @@ OVERLAP_FOOTPRINT = "d*(8*d*(2*((w*l+d-1)//d)) + 12)"
 
 
 def _tatp_dense(name: str, monitor: bool = False,
-                use_hotset: bool = False,
                 trace: bool = False,
                 serve: bool = False) -> TargetTrace:
     from ..engines import tatp_dense as td
@@ -153,19 +152,14 @@ def _tatp_dense(name: str, monitor: bool = False,
     from ..monitor import txnevents as txe
     run, init, _ = td.build_pipelined_runner(_N_SUB, w=_W, val_words=_VW,
                                              cohorts_per_block=_BLK,
-                                             use_hotset=use_hotset,
                                              monitor=monitor, trace=trace,
                                              serve=serve)
-    if use_hotset:
-        carry = _abstract(lambda: init(td.create(_N_SUB, val_words=_VW,
-                                                 log_capacity=_LOGCAP)))
-    else:
-        carry = _abstract(
-            lambda: (td.create(_N_SUB, val_words=_VW,
-                               log_capacity=_LOGCAP),
-                     td.empty_ctx(_W), td.empty_ctx(_W))
-            + ((txe.create_ring(init.trace_cfg.cap),) if trace else ())
-            + ((mn.create(),) if monitor else ()))
+    carry = _abstract(
+        lambda: (td.create(_N_SUB, val_words=_VW,
+                           log_capacity=_LOGCAP),
+                 td.empty_ctx(_W), td.empty_ctx(_W))
+        + ((txe.create_ring(init.trace_cfg.cap),) if trace else ())
+        + ((mn.create(),) if monitor else ()))
     args = (carry, _key_aval())
     if serve:
         args += (_occ_aval(), _occ_aval())
@@ -203,17 +197,13 @@ def _t_tatp_dense_drain() -> TargetTrace:
 
 
 def _sb_dense(name: str, monitor: bool = False,
-              use_hotset: bool = False,
               trace: bool = False,
               serve: bool = False) -> TargetTrace:
     from ..engines import smallbank_dense as sd
     run, init, _ = sd.build_pipelined_runner(_N_ACCT, w=_W,
                                              cohorts_per_block=_BLK,
-                                             use_hotset=use_hotset,
                                              monitor=monitor, trace=trace,
                                              serve=serve)
-    # carry via the runner's own init so the @hot variants get the hot
-    # mirror attached exactly as production does
     carry = _abstract(lambda: init(sd.create(_N_ACCT,
                                              log_capacity=_LOGCAP)))
     args = (carry, _key_aval())
@@ -234,24 +224,6 @@ def _t_sb_dense() -> TargetTrace:
                  protocol=('certified',))
 def _t_sb_dense_mon() -> TargetTrace:
     return _sb_dense("smallbank_dense/block@mon", monitor=True)
-
-
-@register_target("smallbank_dense/block@hot",
-                 "dense SmallBank with the dintcache hot-set partition: "
-                 "lock-dominates-write proven "
-                 "through the partitioned write-through install",
-                 protocol=('certified',))
-def _t_sb_dense_hot() -> TargetTrace:
-    return _sb_dense("smallbank_dense/block@hot", use_hotset=True)
-
-
-@register_target("smallbank_dense/block@hot+mon",
-                 "dense SmallBank: hot-set partition + counter plane "
-                 "(hot_hits/hot_cold_rows/hot_refresh_bytes scatter-adds)",
-                 protocol=('certified',))
-def _t_sb_dense_hot_mon() -> TargetTrace:
-    return _sb_dense("smallbank_dense/block@hot+mon", use_hotset=True,
-                     monitor=True)
 
 
 # ---------------------------------------------------- generic pipelines
@@ -389,14 +361,12 @@ def _t_dense_sharded_mon() -> TargetTrace:
 
 
 def _dense_sharded_sb(name: str, monitor: bool = False,
-                      use_hotset: bool = False,
                       trace: bool = False) -> TargetTrace:
     from ..parallel import dense_sharded_sb as dsb
     mesh = _mesh(_MESH_SHARDS)
     run, init, _ = dsb.build_sharded_sb_runner(
         mesh, _MESH_SHARDS, _N_ACCT * _MESH_SHARDS, w=_W,
-        cohorts_per_block=_BLK, use_hotset=use_hotset, monitor=monitor,
-        trace=trace)
+        cohorts_per_block=_BLK, monitor=monitor, trace=trace)
     carry = _abstract(lambda: init(dsb.create_sharded_sb(
         mesh, _MESH_SHARDS, _N_ACCT * _MESH_SHARDS)))
     return trace_target(name, run, (carry, _key_aval()),
@@ -416,34 +386,6 @@ def _t_dense_sharded_sb() -> TargetTrace:
                  protocol=('certified', 'replicated'))
 def _t_dense_sharded_sb_mon() -> TargetTrace:
     return _dense_sharded_sb("dense_sharded_sb/block@mon", monitor=True)
-
-
-@register_target("dense_sharded_sb/block@hot",
-                 "multi-chip dense SmallBank with per-device dintcache "
-                 "mirrors: certification + replication proven through "
-                 "the partitioned owner-side install",
-                 protocol=('certified', 'replicated'))
-def _t_dense_sharded_sb_hot() -> TargetTrace:
-    return _dense_sharded_sb("dense_sharded_sb/block@hot",
-                             use_hotset=True)
-
-
-# ------------------------------------------------------ hot-set TATP
-
-
-@register_target("tatp_dense/block@hot",
-                 "dense TATP with the dintcache row-prefix partition "
-                 "(skewed-TATP experiments; OCC chain proven through the "
-                 "partitioned meta/val write-through installs)",
-                 protocol=('certified', 'occ'))
-def _t_tatp_dense_hot() -> TargetTrace:
-    from ..engines import tatp_dense as td
-    run, init, _ = td.build_pipelined_runner(_N_SUB, w=_W, val_words=_VW,
-                                             cohorts_per_block=_BLK,
-                                             use_hotset=True)
-    carry = _abstract(lambda: init(td.create(_N_SUB, val_words=_VW,
-                                             log_capacity=_LOGCAP)))
-    return trace_target("tatp_dense/block@hot", run, (carry, _key_aval()))
 
 
 # ------------------------------------------- round-14 2-D (dcn x ici)
@@ -815,25 +757,12 @@ _DSB_GEOM = dict(w=_W, l=3, vw=2, d=_MESH_SHARDS)
 
 # wave_expect: documented layout deviations from the base formula.
 #
-# The dintcache variants serve every partitioned table wave as TWO
-# masked full-width passes (hot partition + cold partition): logical
-# lanes stay w, but the static walker sees both gathers/scatters (the
-# hot install is not compacted: no chunk gathers in its formula).
-_HOT2_TD = {"dint.tatp_dense.meta_gather": 2.0,
-            "dint.tatp_dense.magic_gather": 2.0,
-            "dint.tatp_dense.install": "2*2*w*(4 + 4*vw)"}
-_HOT2_SB = {"dint.smallbank_dense.read": 2.0,
-            "dint.smallbank_dense.lock": 2.0,
-            "dint.smallbank_dense.install": 2.0}
 # The sharded dense runner keeps ONE local log replica (the other two
 # ride the CommitBck/Log hops accounted under replicate), and
 # replicate's two ppermute hops each move the wL balance rows plus a
 # log append the hand formula counts once.
 _DS_EXPECT = {"dint.tatp_dense.log_append": "2*w*(20 + 4*vw)",
               "dint.dense_sharded.replicate": 1.75}
-# The dsb owner step with dintcache mirrors doubles the owner-side
-# arbitration passes (hot + cold partition of the routed slots).
-_DSB_HOT = {"dint.dense_sharded_sb.arbitrate": 2.0}
 # 2-D mesh geometries (parallel/multihost_sb.py): d is the GLOBAL
 # device count n_hosts*n_ici — the per-step lane math is identical to
 # dense_sharded_sb at the same d, only the transport differs.
@@ -866,6 +795,7 @@ _MH_EXPECT = {"dint.tatp_dense.log_append": "2*w*(20 + 4*vw)"}
 # on the sharded/mesh targets — a fleet-wide recalibration, not a leak.
 # PR 30's install_chunks row: +4 B per device in the same way; PR 34's
 # lock_chunks row and PR 38's bck_chunks row: +4 B per device again.
+# The resize that took six constant or hot-set rows out: -24 B a device.
 def _cost(geom, dispatches, footprint, *, steps=float(_BLK),
           bytes_budget="1.25*ledger", wave_expect=None):
     return dict(steps=float(steps), geom=dict(geom),
@@ -878,38 +808,31 @@ TARGET_COST.update({
     # dense TATP. PR 30's write-set compaction (ops/compact.py) adds 3
     # to every install + log: a chunk's gathers of row ids, meta words and ring
     # slots out of the 2w-wide operands (the value and entry rows' gathers
-    # read temporaries, which the walker does not price); the hot tier's
-    # install is its own, so only its log's gather is new (+1). One trip
+    # read temporaries, which the walker does not price). One trip
     # of a chunk loop is priced, at a geometry where a chunk is all 2w
     # slots: the scatters' bytes are the parent's
     "tatp_dense/block": _cost(_TD_GEOM, 12, 216844),
-    "tatp_dense/block@mon": _cost(_TD_GEOM, 14, 217044),
+    "tatp_dense/block@mon": _cost(_TD_GEOM, 14, 217020),
     "tatp_dense/drain": _cost(_TD_GEOM, 12, 216836),
-    "tatp_dense/block@hot": _cost(_TD_GEOM, 14, 216864,
-                                  wave_expect=_HOT2_TD),
     # dintserve serve-mode blocks: dispatches/step identical to the
     # closed-loop rows above (the occupancy mask fuses into the gen
     # wave), footprint +16 B (@mon +28 B) for the occ/shed step inputs
     "tatp_dense/serve": _cost(_TD_GEOM, 12, 216860),
-    "tatp_dense/serve@mon": _cost(_TD_GEOM, 14, 217060),
+    "tatp_dense/serve@mon": _cost(_TD_GEOM, 14, 217036),
     # dense SmallBank
     "smallbank_dense/block": _cost(_SB_GEOM, 8, 150984),
-    "smallbank_dense/block@mon": _cost(_SB_GEOM, 10, 151184),
-    "smallbank_dense/block@hot": _cost(_SB_GEOM, 14, 151032,
-                                       wave_expect=_HOT2_SB),
-    "smallbank_dense/block@hot+mon": _cost(_SB_GEOM, 16, 151232,
-                                           wave_expect=_HOT2_SB),
+    "smallbank_dense/block@mon": _cost(_SB_GEOM, 10, 151160),
     "smallbank_dense/serve": _cost(_SB_GEOM, 8, 151000),
-    "smallbank_dense/serve@mon": _cost(_SB_GEOM, 10, 151200),
+    "smallbank_dense/serve@mon": _cost(_SB_GEOM, 10, 151176),
     # generic pipelines: sort-bound, no formula-backed waves -> absolute
     # bytes ceilings instead of a ledger multiple
     "tatp_pipeline/block": _cost(_TD_GEOM, 50, 1610736022,
                                  bytes_budget=256000),
-    "tatp_pipeline/block@mon": _cost(_TD_GEOM, 51, 1610736222,
+    "tatp_pipeline/block@mon": _cost(_TD_GEOM, 51, 1610736198,
                                      bytes_budget=256000),
     "smallbank_pipeline/block": _cost(_SB_GEOM, 36, 1207967480,
                                       bytes_budget=72000),
-    "smallbank_pipeline/block@mon": _cost(_SB_GEOM, 37, 1207967680,
+    "smallbank_pipeline/block@mon": _cost(_SB_GEOM, 37, 1207967656,
                                           bytes_budget=72000),
     # generic replicated shard step: one engine step per trace
     "sharded/tatp": _cost(_DS_GEOM, 62, 4295279296, steps=1.0,
@@ -922,13 +845,11 @@ TARGET_COST.update({
     # record, priced as one trip at a geometry where a chunk is all 2w
     "dense_sharded/block": _cost(_DS_GEOM, 42, 459240,
                                  wave_expect=_DS_EXPECT),
-    "dense_sharded/block@mon": _cost(_DS_GEOM, 46, 460040,
+    "dense_sharded/block@mon": _cost(_DS_GEOM, 46, 459944,
                                      wave_expect=_DS_EXPECT),
     # dense multi-chip SmallBank
     "dense_sharded_sb/block": _cost(_DSB_GEOM, 33, 100676560),
-    "dense_sharded_sb/block@mon": _cost(_DSB_GEOM, 37, 100677360),
-    "dense_sharded_sb/block@hot": _cost(_DSB_GEOM, 39, 100676848,
-                                        wave_expect=_DSB_HOT),
+    "dense_sharded_sb/block@mon": _cost(_DSB_GEOM, 37, 100677264),
     # 2-D (dcn x ici) SmallBank: the hierarchical route pays +9
     # dispatches/step (each exchange runs ici + dcn stages) to move
     # strictly fewer DCN-axis link bytes than its flat twin — the
@@ -937,7 +858,7 @@ TARGET_COST.update({
     "multihost_sb/block": _cost(_MHSB_GEOM, 42, 201353056),
     "multihost_sb/block@flat": _cost(_MHSB_GEOM, 33, 201353056,
                                      wave_expect=_MHSB_FLAT),
-    "multihost_sb/block@mon": _cost(_MHSB_GEOM, 46, 201354656),
+    "multihost_sb/block@mon": _cost(_MHSB_GEOM, 46, 201354464),
     "multihost_sb/block@h3": _cost(_MHSB_GEOM_H3, 42, 151014808),
     "multihost_sb/block@h3+flat": _cost(_MHSB_GEOM_H3, 33, 151014808,
                                         wave_expect=_MHSB_FLAT),
@@ -950,9 +871,9 @@ TARGET_COST.update({
     "multihost_sb/serve": _cost(_MHSB_GEOM, 42, 201353184),
     "multihost_sb/serve@flat": _cost(_MHSB_GEOM, 33, 201353184,
                                      wave_expect=_MHSB_FLAT),
-    "multihost_sb/serve@mon": _cost(_MHSB_GEOM, 47, 201354784),
+    "multihost_sb/serve@mon": _cost(_MHSB_GEOM, 47, 201354592),
     "multihost_sb/serve@overlap": _cost(_MHSB_GEOM, 44, 201359424),
-    "multihost_sb/serve@overlap+mon": _cost(_MHSB_GEOM, 50, 201361024),
+    "multihost_sb/serve@overlap+mon": _cost(_MHSB_GEOM, 50, 201360832),
     # 2-D TATP (parallel/multihost.py, flat tuple-axis collectives):
     # replication traffic pre-dates wave scoping -> absolute bytes
     # ceiling like the pipeline targets, not a ledger multiple
@@ -1129,7 +1050,7 @@ TARGET_COST.update({
     # overlay's arrival stamps. The rebuild alone: 4 memory ops, 1,792 B
     "store/block@scan": _cost(_ST_GEOM, 58, 4157, bytes_budget=11970),
     "store/serve@scan": _cost(_ST_GEOM, 58, 4173, bytes_budget=11970),
-    "store/serve@scan+mon": _cost(_ST_GEOM, 59.5, 4373,
+    "store/serve@scan+mon": _cost(_ST_GEOM, 59.5, 4349,
                                   bytes_budget=12045),
     "store/rebuild@scan": _cost(_ST_GEOM, 4, 6050, steps=1.0,
                                 bytes_budget=1800),

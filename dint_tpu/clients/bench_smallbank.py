@@ -34,17 +34,13 @@ WIDTHS = (8192, 16384)
 
 def run(window_s: float = 10.0, n_accounts: int = N_ACCOUNTS,
         widths=WIDTHS, block: int = BLOCK, hot_frac: float | None = None,
-        hot_prob: float | None = None,
-        knobs: dict | None = None) -> dict:
+        hot_prob: float | None = None) -> dict:
     """Bench every width in ``widths``; headline the abort-matched point
     and quote all (width, tps, abort_rate) points.
 
     ``hot_frac``/``hot_prob`` override the workload's 90%/4% skew (the
-    bench.py --hot-frac/--hot-prob knobs). ``knobs`` carries the
-    plan-resolved builder knobs (use_hotset — bench.py's _plan_resolve);
-    None falls back to the builder's env resolution (DINT_USE_HOTSET)."""
-    points = [_run_one(window_s, n_accounts, w, block, hot_frac, hot_prob,
-                       knobs)
+    bench.py --hot-frac/--hot-prob knobs)."""
+    points = [_run_one(window_s, n_accounts, w, block, hot_frac, hot_prob)
               for w in widths]
     head = min(points, key=lambda p: p["abort_rate"])
     return {
@@ -52,7 +48,6 @@ def run(window_s: float = 10.0, n_accounts: int = N_ACCOUNTS,
         "smallbank_abort_rate": head["abort_rate"],
         "smallbank_width": head["width"],
         "smallbank_points": points,
-        "smallbank_use_hotset": head["use_hotset"],
         "smallbank_hot_frac": head["hot_frac"],
         "smallbank_hot_prob": head["hot_prob"],
         "smallbank_balance_conserved": True,
@@ -61,16 +56,14 @@ def run(window_s: float = 10.0, n_accounts: int = N_ACCOUNTS,
 
 def _run_one(window_s: float, n_accounts: int, width: int, block: int,
              hot_frac: float | None = None,
-             hot_prob: float | None = None,
-             knobs: dict | None = None) -> dict:
-    from ..ops import hotset
+             hot_prob: float | None = None) -> dict:
     from . import workloads as wl
 
     db = sd.create(n_accounts)
     base = int(np.asarray(sd.total_balance(db)))
     runner, init, drain = sd.build_pipelined_runner(
         n_accounts, w=width, cohorts_per_block=block, hot_frac=hot_frac,
-        hot_prob=hot_prob, **(knobs or {}))
+        hot_prob=hot_prob)
     carry = init(db)
     key = jax.random.PRNGKey(1)
 
@@ -105,11 +98,7 @@ def _run_one(window_s: float, n_accounts: int, width: int, block: int,
         "width": width,
         "committed_tps": round(committed / dt, 1),
         "abort_rate": round(1 - committed / max(attempted, 1), 5),
-        # skew + hot-tier provenance: A/B artifacts must be
-        # distinguishable; a plan-resolved knob records the value that actually built
-        "use_hotset": (knobs["use_hotset"]
-                       if knobs and "use_hotset" in knobs
-                       else hotset.resolve_use_hotset(None)),
+        # skew provenance: A/B artifacts must be distinguishable
         "hot_frac": wl.SB_HOT_FRAC if hot_frac is None else float(hot_frac),
         "hot_prob": wl.SB_HOT_PROB if hot_prob is None else float(hot_prob),
     }

@@ -100,16 +100,13 @@ class StoreClient(_SteppedClient):
 
     ``key_dist="zipfian"`` draws keys from the YCSB-style Zipfian whose
     hot head is the smallest key ids (workloads.zipf_keys) — DINT's
-    skewed store benchmark. ``use_hotset`` (None = DINT_USE_HOTSET env)
-    attaches the dintcache mirror for the first ``hot_frac`` of the
-    keyspace and threads it through every step (write-through,
-    bit-identical replies).
+    skewed store benchmark.
 
-    ``use_scan`` (None = DINT_USE_SCAN env) attaches the dintscan ordered
-    run and lets waves carry Op.SCAN lanes (``scan_frac`` of the mix,
-    zipfian start keys + uniform lengths clipped to ``scan_max``); its
-    keys are the scan path's one shape, YCSB's hashed names
-    (``hashed_keys``), and ``populated`` loads the table so.
+    ``use_scan`` attaches the dintscan ordered run and lets waves carry
+    Op.SCAN lanes (``scan_frac`` of the mix, zipfian start keys + uniform
+    lengths clipped to ``scan_max``); its keys are the scan path's one
+    shape, YCSB's hashed names (``hashed_keys``), and ``populated`` loads
+    the table so.
     In-doubt/retry semantics match the GET path's populated-key asserts:
     a scan must answer VAL, except when the run's overlay went stale —
     then the engine replies RETRY, the client rebuilds the run at the
@@ -120,57 +117,29 @@ class StoreClient(_SteppedClient):
     def __init__(self, table: kv.KVTable, n_keys: int, width: int = 4096,
                  val_words: int = 10, read_frac: float = 0.5,
                  key_dist: str = "uniform", zipf_theta: float = wl.ZIPF_THETA,
-                 hot_frac: float | None = None, use_hotset=None,
-                 use_scan=None, scan_frac: float = 0.0,
+                 use_scan: bool = False, scan_frac: float = 0.0,
                  scan_max: int = 8, max_scan_len: int | None = None,
                  delta_cap: int = 64, rebuild_every: int = 8):
-        from ..ops import hotset
         from ..tables import run as run_mod
 
         assert key_dist in ("uniform", "zipfian")
-        self.use_hotset = hotset.resolve_use_hotset(use_hotset)
-        self.use_scan = run_mod.resolve_use_scan(use_scan)
+        self.use_scan = bool(use_scan)
         self.scan_max = int(scan_max)
         self.scan_frac = float(scan_frac) if self.use_scan else 0.0
         self.max_scan_len = int(max_scan_len or scan_max)
         self.delta_cap = int(delta_cap)
         self.rebuild_every = max(int(rebuild_every), 1)
         self._waves_since_rebuild = 0
-        run0 = None
-        if self.use_scan:
-            run0 = run_mod.from_table(table, delta_cap=int(delta_cap))
-        hot = None
-        if self.use_hotset:
-            frac = 0.04 if hot_frac is None else float(hot_frac)
-            # mirror ids are key_lo < hot_n; keys are 1-based, so cover
-            # keys 1..frac*n with hot_n = frac*n + 1
-            hot_n = min(int(n_keys * frac) + 1, n_keys + 1)
-            hot = store.attach_hot(table, hot_n)
-
         smax = self.scan_max
-        if self.use_scan and self.use_hotset:
-            def step_fn(state, batch):
-                t, h, rn = state
-                t, rep, h, rn, srep = store.step(
-                    t, batch, hot=h, run=rn, scan_max=smax)
-                return (t, h, rn), (rep, srep)
-
-            state = (table, hot, run0)
-        elif self.use_scan:
+        if self.use_scan:
             def step_fn(state, batch):
                 t, rn = state
                 t, rep, rn, srep = store.step(
                     t, batch, run=rn, scan_max=smax)
                 return (t, rn), (rep, srep)
 
-            state = (table, run0)
-        elif self.use_hotset:
-            def step_fn(state, batch):
-                t, h = state
-                t, rep, h = store.step(t, batch, hot=h)
-                return (t, h), rep
-
-            state = (table, hot)
+            state = (table, run_mod.from_table(table,
+                                               delta_cap=int(delta_cap)))
         else:
             state, step_fn = table, store.step
         super().__init__(state, step_fn, width, val_words)
@@ -188,10 +157,9 @@ class StoreClient(_SteppedClient):
     @classmethod
     def populated(cls, n_keys: int, *, n_buckets: int | None = None,
                   val_words: int = 10, **kw):
-        from ..tables import run as run_mod
         table = make_store_table(
             n_keys, n_buckets=n_buckets, val_words=val_words,
-            hashed=run_mod.resolve_use_scan(kw.get("use_scan")))
+            hashed=bool(kw.get("use_scan")))
         return cls(table, n_keys, val_words=val_words, **kw)
 
     def _keys(self, rng: np.random.Generator, n: int) -> np.ndarray:

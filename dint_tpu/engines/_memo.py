@@ -9,26 +9,13 @@ geometry compiles once per test FILE, and in the serving plane a
 restarted engine recompiles its whole width menu. Memoising the
 builder collapses those to one compile per distinct configuration per
 process. Unhashable arguments (shouldn't happen, but e.g. an ad-hoc
-dict) fall back to an uncached build rather than failing.
+dict) fall back to an uncached build rather than failing. A builder
+reads nothing but its arguments (no environment variable), so they are
+the whole key.
 """
 from __future__ import annotations
 
 import functools
-
-# builders resolve None-valued knobs from the ambient environment at
-# BUILD time (ops/hotset.resolve_use_hotset, monitor/txnevents trace
-# defaults), so those values are part of the compiled program's
-# identity — fold a snapshot into the key or a monkeypatched env would
-# hit a stale entry. The snapshot is analysis/plan.env_knob_signature():
-# the CANONICALIZED resolution of every build-identity knob, from the
-# same single resolver the builders and the plan checker use — unset,
-# "" and "0" (all False to a builder) share one memo entry, and the
-# memo key can never disagree with the builder about what a flag means.
-
-
-def _env_signature() -> tuple:
-    from ..analysis import plan           # deferred: engines must import
-    return plan.env_knob_signature()      # without the analysis package
 
 
 def memoize_builder(fn):
@@ -36,9 +23,8 @@ def memoize_builder(fn):
 
     @functools.wraps(fn)
     def wrapped(*args, **kw):
-        env = _env_signature()
         try:
-            key = (args, tuple(sorted(kw.items())), env)
+            key = (args, tuple(sorted(kw.items())))
             hit = cache.get(key)         # hashing happens here too (ndarray
         except TypeError:                # mix= etc.): build uncached
             return fn(*args, **kw)
@@ -50,9 +36,13 @@ def memoize_builder(fn):
     return wrapped
 
 
-def refuse_kernel_flags(use_pallas, use_fused):
-    # benchmarks/deployments/*.py, benchmarks/checks.py and
-    # tests/bench/test_bench_harness.py still pass both as False
-    if use_pallas or use_fused:
-        raise ValueError("use_pallas / use_fused: the Pallas kernels were "
-                         "deleted in PR 35; the engines have one route")
+def refuse_retired(**flags):
+    """The retired builder keywords: ``use_pallas`` / ``use_fused`` (the
+    Pallas kernels are gone; the engines have one route) and
+    ``use_hotset`` (the hot-set mirror is gone). The benchmark's
+    deployments and its tests still pass them as False, so a falsy value
+    builds what omitting it builds, and a truthy one raises."""
+    on = sorted(k for k, v in flags.items() if v)
+    if on:
+        raise ValueError(f"{', '.join(on)}: a retired keyword, accepted "
+                         "only as False")

@@ -76,11 +76,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ._memo import memoize_builder, refuse_kernel_flags
+from ._memo import memoize_builder, refuse_retired
 from ..monitor import counters as mon
 from ..monitor import txnevents as txe
 from ..monitor import waves
-from ..ops import compact, hotset
+from ..ops import compact
 from ..tables import log as logring
 from .types import Op
 from .smallbank_pipeline import (AMT, L, MAGIC, N_SHARDS, TS_AMT_MAX, VW,     # noqa: F401 (re-exported)
@@ -110,27 +110,13 @@ def lock_slots_for(m1: int) -> int:
 @flax.struct.dataclass
 class DenseBank:
     """Both tables + locks + logs in flat dense arrays (row M = 2N is the
-    gather sentinel; masked scatters route out of bounds and drop).
-
-    The ``hot_*`` leaves are the dintcache hot tier (round 10): a compact
-    physical mirror of the hot-account prefix — mirror index
-    ``tbl * hot_n + acc`` for accounts ``acc < hot_n`` — that every
-    install writes through to, so mirror == table prefix is an invariant,
-    not a protocol. ``hot_x``/``hot_s`` exist only while the lock table
-    is EXACT (slot == row): under the hashed slot cap a cold account can
-    conflate onto a hot account's slot, which would make a slot mirror
-    incoherent, so hashed geometries serve stamps from the full arrays.
-    None (the default) = no hot tier; the pytree and jaxpr are unchanged."""
+    gather sentinel; masked scatters route out of bounds and drop)."""
     bal: jax.Array       # u32 [M+1]  balances (i32 bits)
     x_step: jax.Array    # u32 [H]    last step an X grant stamped the slot
     s_step: jax.Array    # u32 [H]    last step an S grant stamped the slot
     step: jax.Array      # u32 scalar, monotonic (starts at 2: stamp 0 is
                          #   "never held", so step-1 must never be 0)
     log: logring.RepLog  # 3 replica entries packed per slot (log x3)
-    hot_bal: jax.Array | None = None   # u32 [2*hot_n] balance mirror
-    hot_x: jax.Array | None = None     # u32 [2*hot_n] X-stamp mirror (exact)
-    hot_s: jax.Array | None = None     # u32 [2*hot_n] S-stamp mirror (exact)
-    hot_n: int = flax.struct.field(pytree_node=False, default=0)
 
     @property
     def n_accounts(self):
@@ -139,23 +125,6 @@ class DenseBank:
     @property
     def lock_slots(self):
         return self.x_step.shape[0]
-
-
-def attach_hotset(db: DenseBank, hot_n: int) -> DenseBank:
-    """Build the hot mirror for accounts [0, hot_n) from the current
-    tables (a few MiB at the bench's 960k-account hot set). Stamps are
-    mirrored only in the exact lock regime — see DenseBank."""
-    n = db.n_accounts
-    hot_n = int(min(max(int(hot_n), 1), n))
-    m1 = 2 * n + 1
-    idx = jnp.concatenate([jnp.arange(hot_n, dtype=I32),
-                           n + jnp.arange(hot_n, dtype=I32)])
-    exact = db.lock_slots >= m1
-    return db.replace(
-        hot_bal=db.bal[idx],
-        hot_x=db.x_step[idx] if exact else None,
-        hot_s=db.s_step[idx] if exact else None,
-        hot_n=hot_n)
 
 
 def create(n_accounts: int, init_balance: int = 1000, log_lanes: int = 16,
@@ -237,7 +206,6 @@ def _stats_of(c: BankCtx):
 
 def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
               gen_new: bool = True, hot_frac=None, hot_prob=None, mix=None,
-              use_hotset: bool = False,
               occupancy: jax.Array | None = None,
               shed: jax.Array | None = None,
               counters: mon.Counters | None = None,
@@ -246,15 +214,6 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
     """One fused device step: wave 1 of a NEW cohort acquires against c1's
     STILL-HELD stamps (stamp == step-1), then wave 2 installs c1's writes.
     Returns (db', new_ctx, stats-of-c1).
-
-    ``use_hotset`` (static) serves the step's random single-word gathers —
-    the held-stamp reads on x_step/s_step and the fused balance read —
-    through the dintcache partition (db must carry the hot mirror —
-    attach_hotset): hot lanes (account < hot_n) read the compact mirror
-    (a small-array gather) while cold lanes walk the full tables, and the
-    wave-2 install writes through to the mirror (a double 1-D
-    unique-index scatter). Outputs stay bit-identical to the default path
-    (pinned in tests/test_hotset.py).
 
     ``occupancy``/``shed`` (device i32 scalars, or None = off): the
     dintserve variable-occupancy plane — lanes >= occupancy have their
@@ -268,7 +227,7 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
     ``counters`` (monitor.Counters | None): the dintmon counter plane —
     txn outcomes from c1's completing stats, S/X arbitration won-vs-lost
     (held-slot rejects split from intra-batch losses), install/log
-    counts, ring high-water, backend dispatch. When threaded the updated
+    counts, ring high-water. When threaded the updated
     Counters is appended to the return tuple; None (default) leaves the
     jaxpr untouched.
 
@@ -325,15 +284,6 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
         is_s_lane = (l_op == Op.ACQ_S_READ).reshape(-1)
         lane = jnp.arange(w * L, dtype=I32)
 
-        # dintcache partition: a lane is hot iff its account sits in the
-        # mirrored prefix; mirror index = tbl*hot_n + acc. Stamps share the
-        # same mapping in the exact slot regime (slot == row).
-        hn = db.hot_n
-        stamp_hot = use_hotset and db.hot_x is not None
-        if use_hotset:
-            hot_lane = (active & (l_ac < hn)).reshape(-1)
-            midx = jnp.where(hot_lane, (l_tb * hn + l_ac).reshape(-1), -1)
-
     with waves.scope("smallbank_dense", "lock"):
         with waves.part("smallbank_dense", "lock_arb"):
             first_x = jnp.full((h,), BIG, I32).at[
@@ -343,14 +293,8 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
         # held = stamped by the previous step's cohort (released implicitly
         # one step later; acquire-before-release semantics preserved)
         with waves.part("smallbank_dense", "lock_held_read"):
-            if stamp_hot:
-                held_x = hotset.hot_gather(db.x_step, db.hot_x, slot, midx,
-                                           1) == t - 1
-                held_s = hotset.hot_gather(db.s_step, db.hot_s, slot, midx,
-                                           1) == t - 1
-            else:
-                held_x = db.x_step[slot] == t - 1
-                held_s = db.s_step[slot] == t - 1
+            held_x = db.x_step[slot] == t - 1
+            held_s = db.s_step[slot] == t - 1
             slot_free = ~held_x & ~held_s
         with waves.part("smallbank_dense", "lock_grant"):
             x_wins = (first_x[slot] < first_s[slot]) & slot_free
@@ -364,16 +308,6 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
             s_step = db.s_step.at[
                 jnp.where(s_writer, slot, h)].set(
                 t, mode="drop", unique_indices=True)
-            hot_x, hot_s = db.hot_x, db.hot_s
-            if stamp_hot:
-                # stamp write-through: the grant masks are one-writer-per-
-                # slot, so their hot subsets are one-writer-per-mirror-index
-                hot_x = hot_x.at[jnp.where(grant_x & (midx >= 0), midx,
-                                           2 * hn)].set(t, mode="drop",
-                                                        unique_indices=True)
-                hot_s = hot_s.at[jnp.where(s_writer & (midx >= 0), midx,
-                                           2 * hn)].set(t, mode="drop",
-                                                        unique_indices=True)
 
         with waves.part("smallbank_dense", "lock_grant"):
             granted = (grant_x | grant_s).reshape(w, L)
@@ -383,11 +317,7 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
     # fused reads from the pre-install table: rows c1 installs below were
     # X-stamped by c1, so this cohort never granted (or consumed) them
     with waves.scope("smallbank_dense", "read"):
-        if use_hotset:
-            raw_bal = hotset.hot_gather(db.bal, db.hot_bal, flat_rows, midx,
-                                        1)
-        else:
-            raw_bal = db.bal[flat_rows]
+        raw_bal = db.bal[flat_rows]
         bal = jnp.where(granted, raw_bal.astype(I32).reshape(w, L), 0)
 
     with waves.scope("smallbank_dense", "compute"):
@@ -416,27 +346,14 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
         dwf = c1.do_write.reshape(-1)
         wrows = jnp.where(dwf, c1.rows.reshape(-1), oob)       # [wL]
         newbal = c1.nw.reshape(-1)
-        if use_hotset:
-            # partitioned install: the full table AND the hot mirror take
-            # the write (a double 1-D unique-index scatter) — the
-            # write-through that keeps mirror == table prefix an
-            # invariant, not a protocol
-            w_acc = c1.acc.reshape(-1)
-            w_midx = jnp.where(dwf & (w_acc < hn),
-                               c1.tbl.reshape(-1) * hn + w_acc, -1)
-            bal_new, hot_bal = hotset.hot_scatter(
-                db.bal, db.hot_bal, c1.rows.reshape(-1), w_midx, dwf,
-                newbal.astype(U32), 1)
-        else:
-            hot_bal = db.hot_bal
-            # as many lanes as it takes for the compiler to sort the
-            # scatter's indices (ops/compact.py), the added ones dropped
-            # as the dead ones are
-            lanes = compact.sorted_scatter_lanes(db.bal.shape[0],
-                                                 wrows.shape[0])
-            bal_new = db.bal.at[compact.filled(wrows, lanes, oob)].set(
-                compact.filled(newbal.astype(U32), lanes, 0),
-                mode="drop", unique_indices=True)
+        # as many lanes as it takes for the compiler to sort the
+        # scatter's indices (ops/compact.py), the added ones dropped
+        # as the dead ones are
+        lanes = compact.sorted_scatter_lanes(db.bal.shape[0],
+                                             wrows.shape[0])
+        bal_new = db.bal.at[compact.filled(wrows, lanes, oob)].set(
+            compact.filled(newbal.astype(U32), lanes, 0),
+            mode="drop", unique_indices=True)
 
     with waves.scope("smallbank_dense", "log_append"):
         with waves.part("smallbank_dense", "log_build"):
@@ -456,8 +373,7 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
 
     with waves.part("smallbank_dense", "sb_ctx"):
         db = db.replace(bal=bal_new, x_step=x_step, s_step=s_step,
-                        step=t + 1, log=logs, hot_bal=hot_bal,
-                        hot_x=hot_x, hot_s=hot_s)
+                        step=t + 1, log=logs)
     with waves.part("smallbank_dense", "stats"):
         stats = _stats_of(c1)
     extra = ()
@@ -500,18 +416,6 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
             grant_l = granted.reshape(-1)
             held_l = held_x | held_s            # [wL] slot stamped last step
             rej_l = act_l & ~grant_l
-            hot_ctrs = {}
-            if use_hotset:
-                # partition accounting: every hot-partitioned gather serves
-                # (midx >= 0) lanes from the mirror and the rest from the
-                # full tables
-                n_g = 1 + (2 if stamp_hot else 0)
-                hits = (midx >= 0).sum(dtype=I32)
-                hot_ctrs = {
-                    mon.CTR_HOT_HITS: n_g * hits,
-                    mon.CTR_HOT_COLD_ROWS: n_g * (w * L) - n_g * hits,
-                    mon.CTR_HOT_REFRESH_BYTES: 0,
-                }
             serve_ctrs = {}
             if occupancy is not None:
                 serve_ctrs = {
@@ -521,7 +425,6 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
                         jnp.asarray(0 if shed is None else shed, I32),
                 }
             counters = mon.bump(counters, {
-                **hot_ctrs,
                 **serve_ctrs,
                 mon.CTR_STEPS: 1,
                 mon.CTR_TXN_ATTEMPTED: c1.attempted,
@@ -536,7 +439,6 @@ def pipe_step(db: DenseBank, c1: BankCtx, key, *, w: int, n_accounts: int,
                 mon.CTR_LOCK_REJECT_ARB: (rej_l & ~held_l).sum(dtype=I32),
                 mon.CTR_INSTALL_WRITES: dwf.sum(dtype=I32),
                 mon.CTR_LOG_APPENDS: dwf.sum(dtype=I32),
-                mon.CTR_DISPATCH_XLA: 1,
             })
             counters = mon.gauge_max(
                 counters, {mon.CTR_RING_HWM: logs.head.max()})
@@ -549,26 +451,24 @@ def build_pipelined_runner(n_accounts: int, w: int = 8192,
                            cohorts_per_block: int = 8, hot_frac=None,
                            hot_prob=None, mix=None, use_pallas=None,
                            use_hotset=None, use_fused=None,
-                           monitor: bool = False, trace=None,
-                           trace_rate=None, trace_cap=None,
+                           monitor: bool = False, trace: bool = False,
+                           trace_rate: float = 1.0, trace_cap=None,
                            serve: bool = False):
     """jit(scan(pipe_step)) over carry (db, c1). Returns (run, init, drain):
       run(carry, key) -> (carry', stats [cohorts_per_block, N_STATS])
       init(db)        -> carry with one bootstrap cohort in flight
       drain(carry)    -> (db, stats [1, N_STATS]) flushing the pipeline
 
-    ``use_hotset``: None = honor DINT_USE_HOTSET env. Serves the step's
-    random gathers through the dintcache hot/cold partition; the hot set
-    defaults to the WORKLOAD's hot set (``hot_frac``, else the SmallBank
-    90%/4% skew constant) so the mirror covers exactly the keys the skew
-    concentrates on. init() attaches the mirror to a db that lacks one.
+    ``hot_frac`` / ``hot_prob``: the traffic's skew (gen_cohort).
+    The three ``use_*`` keywords are retired: accepted only as False
+    (_memo.refuse_retired).
 
     ``monitor``: thread the dintmon counter plane — the carry grows a
     trailing monitor.Counters leaf and drain returns (db, stats,
     counters); off (default) = contract and jaxpr unchanged.
 
     ``trace``/``trace_rate``/``trace_cap``: thread the dinttrace event
-    ring (None = honor DINT_TRACE / DINT_TRACE_RATE). The carry grows a
+    ring (off by default). The carry grows a
     TxnRing leaf BEFORE the Counters leaf (counters stay carry[-1]); the
     ring is zeroed at each block/drain entry so every drained window is
     self-contained, and `init.trace_cfg` exposes the resolved TraceCfg
@@ -581,22 +481,17 @@ def build_pipelined_runner(n_accounts: int, w: int = 8192,
     (pipe_step's occupancy/shed). Carry layout, init, and drain are
     unchanged.
     """
-    from ..clients import workloads as wl
-    refuse_kernel_flags(use_pallas, use_fused)
-    use_hotset = hotset.resolve_use_hotset(use_hotset)
-    hot_n = 0
-    if use_hotset:
-        frac = wl.SB_HOT_FRAC if hot_frac is None else float(hot_frac)
-        hot_n = max(1, min(int(n_accounts * frac), n_accounts))
-    kw = dict(w=w, n_accounts=n_accounts, use_hotset=use_hotset)
+    refuse_retired(use_pallas=use_pallas, use_fused=use_fused,
+                   use_hotset=use_hotset)
+    kw = dict(w=w, n_accounts=n_accounts)
     kw_gen = dict(kw, hot_frac=hot_frac, hot_prob=hot_prob, mix=mix)
-    trace_on = txe.trace_enabled(trace)
+    trace_on = bool(trace)
     tcfg = None
     if trace_on:
         n_step = w * (2 * L + 1)    # lock wL + outcome w + install wL
         cap = int(trace_cap) if trace_cap is not None \
             else n_step * cohorts_per_block
-        tcfg = txe.TraceCfg(rate=txe.trace_rate(trace_rate), cap=cap,
+        tcfg = txe.TraceCfg(rate=float(trace_rate), cap=cap,
                             wave=waves.full_name("smallbank_dense",
                                                  "trace"))
 
@@ -638,8 +533,6 @@ def build_pipelined_runner(n_accounts: int, w: int = 8192,
             return jax.lax.scan(scan_fn, carry, keys)
 
     def init(db):
-        if use_hotset and db.hot_n == 0:
-            db = attach_hotset(db, hot_n)
         base = (db, empty_ctx(w))
         return (base + ((txe.create_ring(tcfg.cap),) if trace_on else ())
                 + ((mon.create(),) if monitor else ()))

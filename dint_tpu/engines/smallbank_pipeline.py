@@ -335,7 +335,6 @@ def cohort_step(stacked: smallbank.Shard, key, *, w: int, n_accounts: int,
             mon.CTR_LOCK_REJECTED: (active & ~granted).sum(dtype=I32),
             mon.CTR_INSTALL_WRITES: do_write.sum(dtype=I32),
             mon.CTR_LOG_APPENDS: do_write.sum(dtype=I32),
-            mon.CTR_DISPATCH_XLA: 1,
         })
         return stacked, stats, counters
     return stacked, stats

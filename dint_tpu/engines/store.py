@@ -24,14 +24,12 @@ from __future__ import annotations
 
 import functools
 
-import flax.struct
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..monitor import waves
 from ..ops import compact, hashing, segments
-from ..ops import hotset
 from ..tables import kv
 from ..tables import run as run_mod
 from .types import Batch, Op, Replies, Reply, ScanReplies
@@ -40,39 +38,9 @@ I32 = jnp.int32
 U32 = jnp.uint32
 
 
-@flax.struct.dataclass
-class HotKV:
-    """dintcache hot tier for the store engine: a key-indexed write-through
-    mirror of keys (0, k) with key_lo < hot_n and key_hi == 0 — the head
-    of the store benchmark's Zipfian distribution, whose rank IS the key
-    id (clients/workloads.zipf_keys). The mirror replaces the val/ver
-    gathers of the probe for hot lanes (a small-array gather); installs
-    write through, so mirror == table for every key the probe can hit. Mirror
-    entries of ABSENT keys are stale by design: every consumer of
-    val0/ver0 in step() is masked by hit0."""
-    val: jax.Array    # u32 [hot_n * VW]
-    ver: jax.Array    # u32 [hot_n]
-
-    @property
-    def hot_n(self):
-        return self.ver.shape[0]
-
-
-def attach_hot(table: kv.KVTable, hot_n: int) -> HotKV:
-    """Build the hot mirror for key ids [0, hot_n) from the current table
-    (one batched probe; run after populate)."""
-    hot_n = max(int(hot_n), 1)
-    klo = jnp.arange(hot_n, dtype=U32)
-    khi = jnp.zeros((hot_n,), U32)
-    b1, b2 = hashing.bucket_pair(khi, klo, table.n_buckets)
-    hit, _, _, val, ver, _, _ = kv.probe(table, khi, klo, b1, b2)
-    return HotKV(val=jnp.where(hit[:, None], val, U32(0)).reshape(-1),
-                 ver=jnp.where(hit, ver, U32(0)))
-
-
 def install_is_compacted(table: kv.KVTable, r: int) -> bool:
     """Whether ``step`` compacts the install of an ``r``-lane batch into
-    ``table`` (no hot tier): where the full-width scatters are too sparse
+    ``table``: where the full-width scatters are too sparse
     for the compiler to sort them first (``compact.compiler_sorts``: more
     than SORTED_SCATTER_WORDS_PER_LANE entries a lane; the ratio is the
     same for all five arrays). A rule in the shapes alone. The
@@ -85,22 +53,16 @@ def install_is_compacted(table: kv.KVTable, r: int) -> bool:
 
 
 def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
-         hot: HotKV | None = None,
          run: run_mod.OrderedRun | None = None, scan_max: int = 8):
     """One server step: certify and apply a batch. Returns (table', replies)
-    — plus `hot'` when the dintcache hot tier is threaded, plus
-    `(run', scan_replies)` when the dintscan ordered run is threaded
-    (in that order: table, replies[, hot][, run, scan_replies]).
+    — plus `(run', scan_replies)` when the dintscan ordered run is
+    threaded.
 
     ``maintain_bloom`` (static) keeps per-bucket bloom filters exact across
     inserts/deletes. The full-table fast path doesn't need them (probe() is
     exact); they exist for cache-mode parity with the reference's negative
     lookups (store/ebpf/store_kern.c:88-95) and cost a hash per slot per
     touched bucket, so they're off by default.
-
-    ``hot`` (a HotKV, or None = off): serve hot keys' val/ver reads from
-    the mirror and write installs through to it — replies and table are
-    bit-identical to the default path (tests/test_hotset.py).
 
     ``run`` (a tables.run.OrderedRun, or None = off): serve Op.SCAN lanes
     from the ordered run's merged run∪delta view — scans are phase-1
@@ -117,8 +79,8 @@ def step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool = False,
     A scan lane whose two windows cannot show its first ``slen`` live
     keys (tombstones in range, tables/run.py) answers RETRY too.
     """
-    out = _step(table, batch, maintain_bloom=maintain_bloom, hot=hot,
-                run=run, scan_max=scan_max)[0]
+    out = _step(table, batch, maintain_bloom=maintain_bloom, run=run,
+                scan_max=scan_max)[0]
     if run is None:
         return out
     srep = out[-1]
@@ -191,8 +153,7 @@ def _install_live(table: kv.KVTable, o_upd, ok, o_del, o_bkt, o_slot0,
 
 
 def _step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool,
-          hot: HotKV | None, run: run_mod.OrderedRun | None,
-          scan_max: int):
+          run: run_mod.OrderedRun | None, scan_max: int):
     """``step``, and beside its result the compacted install's (chunk
     trips, elected writers) as i32 scalars for the counter plane: None
     where the install runs at full width."""
@@ -206,29 +167,16 @@ def _step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool,
         with waves.part("store", "probe_keys"):
             b1, b2 = hashing.bucket_pair(sb.key_hi, sb.key_lo,
                                          table.n_buckets)
-        if hot is None:
-            # kv.probe's own body, under the two parts the trace splits
-            # the wave by: the [r, S] key / valid gathers of both
-            # candidate buckets, then the hit entry's value and version
-            with waves.part("store", "probe_keys"):
-                hit0, fbkt, slot0, free1, free2 = kv.probe_loc(
-                    table, sb.key_hi, sb.key_lo, b1, b2)
-            with waves.part("store", "probe_val"):
-                eidx0 = fbkt * table.slots + slot0
-                val0 = kv.entry_val(table, eidx0)
-                ver0 = table.ver[eidx0]
-        else:
-            hot_n = hot.hot_n
-            vw = table.val_words
+        # kv.probe's own body, under the two parts the trace splits
+        # the wave by: the [r, S] key / valid gathers of both
+        # candidate buckets, then the hit entry's value and version
+        with waves.part("store", "probe_keys"):
             hit0, fbkt, slot0, free1, free2 = kv.probe_loc(
                 table, sb.key_hi, sb.key_lo, b1, b2)
+        with waves.part("store", "probe_val"):
             eidx0 = fbkt * table.slots + slot0
-            kmidx = jnp.where((sb.key_hi == U32(0))
-                              & (sb.key_lo < U32(hot_n)),
-                              sb.key_lo.astype(I32), -1)
-            val0 = hotset.hot_gather(table.val, hot.val, eidx0, kmidx,
-                                     vw).reshape(r, vw)
-            ver0 = hotset.hot_gather(table.ver, hot.ver, eidx0, kmidx, 1)
+            val0 = kv.entry_val(table, eidx0)
+            ver0 = table.ver[eidx0]
     with waves.part("store", "key_sort"):
         # insert destination: the emptier of the two candidate buckets
         dest = jnp.where(free2 > free1, b2, b1)
@@ -335,7 +283,7 @@ def _step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool,
     s = table.slots
     live = None
     with waves.scope("store", "install"):
-        if hot is None and install_is_compacted(table, r):
+        if install_is_compacted(table, r):
             # the serve block's shapes: the elected writers in chunks
             table, live = _install_live(
                 table, o_upd, ok, o_del, o_bkt, o_slot0, slot_new, o_khi,
@@ -343,7 +291,7 @@ def _step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool,
         else:
             # every lane issued: the populate's shapes and the small
             # ones (the compiler sorts these scatters, and a dropped lane
-            # is cheap then), and the hot tier's write-through
+            # is cheap then)
             with waves.part("store", "kv_meta_scatter"):
                 w_any_slot = o_upd | ok | o_del
                 t_slot = jnp.where(o_upd | o_del, o_slot0, slot_new)
@@ -353,26 +301,12 @@ def _step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool,
                 wv = (o_upd | ok)
                 sl_v = jnp.where(o_upd, o_slot0, slot_new)
                 e_v = jnp.where(wv, o_bkt * s + sl_v, ne)
-            if hot is None:
-                with waves.part("store", "kv_val_scatter"):
-                    val_new = table.val.at[kv.val_word_idx(table, e_v)].set(
-                        o_val.reshape(-1), mode="drop", unique_indices=True)
-                with waves.part("store", "kv_meta_scatter"):
-                    ver_new = table.ver.at[e_v].set(o_ver, mode="drop",
-                                                    unique_indices=True)
-            else:
-                # write-through install: table entry AND key-indexed mirror.
-                # One writer per key segment,
-                # so entry AND mirror indices are unique among masked lanes.
-                w_midx = jnp.where(wv & (o_khi == U32(0))
-                                   & (o_klo < U32(hot_n)),
-                                   o_klo.astype(I32), -1)
-                e_w = o_bkt * s + sl_v
-                val_new, hot_val = hotset.hot_scatter(
-                    table.val, hot.val, e_w, w_midx, wv, o_val.reshape(-1), vw)
-                ver_new, hot_ver = hotset.hot_scatter(
-                    table.ver, hot.ver, e_w, w_midx, wv, o_ver, 1)
-                hot = hot.replace(val=hot_val, ver=hot_ver)
+            with waves.part("store", "kv_val_scatter"):
+                val_new = table.val.at[kv.val_word_idx(table, e_v)].set(
+                    o_val.reshape(-1), mode="drop", unique_indices=True)
+            with waves.part("store", "kv_meta_scatter"):
+                ver_new = table.ver.at[e_v].set(o_ver, mode="drop",
+                                                unique_indices=True)
             with waves.part("store", "kv_meta_scatter"):
                 table = table.replace(
                     key_hi=table.key_hi.at[e_v].set(o_khi, mode="drop",
@@ -429,8 +363,6 @@ def _step(table: kv.KVTable, batch: Batch, *, maintain_bloom: bool,
 
     replies = Replies(rtype=o_rtype, val=o_rval, ver=o_rver)
     out = (table, replies)
-    if hot is not None:
-        out = out + (hot,)
     if run is not None:
         out = out + (run, scan_rep)
     return out, live
@@ -685,8 +617,8 @@ def build_serve_runner(n_keys: int, w: int = 4096,
                        hot_frac: float | None = None,
                        hot_prob: float | None = None,
                        theta: float | None = None,
-                       use_scan=None,
-                       monitor: bool = False, trace=None,
+                       use_scan: bool = False,
+                       monitor: bool = False, trace: bool = False,
                        serve: bool = False, run_cap: int | None = None):
     """Serve-plane runner for the store engine, its normal path:
     jit(scan(step . gen)) over carry (table[, run][, counters]). Returns
@@ -704,7 +636,7 @@ def build_serve_runner(n_keys: int, w: int = 4096,
     committed = VAL/ACK replies (stale-scan RETRYs are NOT committed —
     the client re-sends after the rebuild).
 
-    ``use_scan``: None = honor DINT_USE_SCAN. Threads the ordered-run
+    ``use_scan``: thread the ordered-run
     snapshot + delta overlay through every step and merge-compacts it
     at each block's drain boundary (dint.store.run_rebuild) — the run
     stays sorted without ever stalling the step. Its cohorts are YCSB-E's
@@ -729,7 +661,6 @@ def build_serve_runner(n_keys: int, w: int = 4096,
     """
     del trace
     from ..monitor import counters as mon
-    use_scan = run_mod.resolve_use_scan(use_scan)
     if use_scan:
         ordered = build_ordered_generator(
             n_keys, w, val_words=val_words, scan_frac=scan_frac,
@@ -754,8 +685,7 @@ def build_serve_runner(n_keys: int, w: int = 4096,
             else:
                 batch = gen(key, occ)
         (table, rep, *scanned), live = _step(
-            table, batch, maintain_bloom=False, hot=None, run=run,
-            scan_max=scan_max)
+            table, batch, maintain_bloom=False, run=run, scan_max=scan_max)
         run, srep = scanned if use_scan else (None, None)
         with waves.part("store", "stats"):
             stats = reply_stats(batch, rep)
@@ -785,7 +715,6 @@ def build_serve_runner(n_keys: int, w: int = 4096,
                     mon.CTR_SERVE_OCC_LANES: occ,
                     mon.CTR_SERVE_PAD_LANES: jnp.asarray(w, I32) - occ,
                     mon.CTR_SERVE_SHED_LANES: shed,
-                    mon.CTR_DISPATCH_XLA: 1,
                     mon.CTR_STORE_GETS: stats[STAT_GETS],
                     mon.CTR_STORE_UPDATES: stats[STAT_UPDATES],
                     mon.CTR_STORE_NOT_EXIST: stats[STAT_NOT_EXIST],

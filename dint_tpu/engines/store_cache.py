@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import hashing, segments
-from ..ops import hotset
 from ..tables import kv
 from .types import Batch, Op, Replies, Reply
 
@@ -55,46 +54,20 @@ MISS = 100
 
 @flax.struct.dataclass
 class CacheTable:
-    """``hot_val``/``hot_ver`` (None = off) are the dintcache hot tier
-    inside the cache tier — "XDP within XDP": a key-indexed write-through
-    mirror of the hot key prefix (key_hi == 0, key_lo < hot_n) serving
-    the probe's val/ver reads for hot lanes, maintained at the write-back
-    and refill install points. Mirror entries of keys NOT currently
-    cached are stale by design: every val0/ver0 consumer in cache_step is
-    hit0-masked (same argument as engines/store.HotKV)."""
     kv: kv.KVTable
     dirty: jax.Array      # bool [NB*S] (flat entries, like kv.KVTable)
     clock: jax.Array      # u32 [] victim rotor (reference picks by slot scan)
-    hot_val: jax.Array | None = None   # u32 [hot_n * VW]
-    hot_ver: jax.Array | None = None   # u32 [hot_n]
 
 
-def create(n_buckets: int, slots: int = 4, val_words: int = 10,
-           hot_keys: int = 0) -> CacheTable:
-    """``hot_keys`` > 0 attaches the dintcache mirror for key ids
-    [0, hot_keys) (empty, coherent with the empty cache)."""
+def create(n_buckets: int, slots: int = 4,
+           val_words: int = 10) -> CacheTable:
     return CacheTable(kv=kv.create(n_buckets, slots, val_words),
                       dirty=jnp.zeros((n_buckets * slots,), bool),
-                      clock=U32(0),
-                      hot_val=(jnp.zeros((hot_keys * val_words,), U32)
-                               if hot_keys else None),
-                      hot_ver=(jnp.zeros((hot_keys,), U32)
-                               if hot_keys else None))
-
-
-def _hot_n(cache: CacheTable) -> int:
-    return cache.hot_ver.shape[0] if cache.hot_ver is not None else 0
+                      clock=U32(0))
 
 
 def _probe1(t: kv.KVTable, key_hi, key_lo, bkt):
     """Single-hash probe (the reference cache is single-hash 4-way)."""
-    hit, slot, eidx = _probe1_loc(t, key_hi, key_lo, bkt)
-    return hit, slot, kv.entry_val(t, eidx), t.ver[eidx]
-
-
-def _probe1_loc(t: kv.KVTable, key_hi, key_lo, bkt):
-    """Location-only probe half: the hot tier serves hot lanes' val/ver
-    from its mirror, so the value gather is the caller's choice."""
     rows = kv.bucket_rows(t, bkt)
     rows_hi = t.key_hi[rows]
     rows_lo = t.key_lo[rows]
@@ -102,7 +75,8 @@ def _probe1_loc(t: kv.KVTable, key_hi, key_lo, bkt):
     match = rows_valid & (rows_hi == key_hi[:, None]) & (rows_lo == key_lo[:, None])
     hit = match.any(axis=-1)
     slot = jnp.argmax(match, axis=-1).astype(I32)
-    return hit, slot, bkt * t.slots + slot
+    eidx = bkt * t.slots + slot
+    return hit, slot, kv.entry_val(t, eidx), t.ver[eidx]
 
 
 def cache_step(cache: CacheTable, batch: Batch, *, policy: str = WB_BLOOM):
@@ -126,18 +100,7 @@ def cache_step(cache: CacheTable, batch: Batch, *, policy: str = WB_BLOOM):
     val_in = batch.val[sb.perm]
 
     bkt = hashing.bucket(sb.key_hi, sb.key_lo, t.n_buckets)
-    hn = _hot_n(cache)
-    if hn:
-        # dintcache partition: hot keys' val/ver from the mirror, cold
-        # from the cache entries
-        hit0, slot0, eidx0 = _probe1_loc(t, sb.key_hi, sb.key_lo, bkt)
-        kmidx = jnp.where((sb.key_hi == U32(0)) & (sb.key_lo < U32(hn)),
-                          sb.key_lo.astype(I32), -1)
-        val0 = hotset.hot_gather(t.val, cache.hot_val, eidx0, kmidx,
-                                 t.val_words).reshape(r, t.val_words)
-        ver0 = hotset.hot_gather(t.ver, cache.hot_ver, eidx0, kmidx, 1)
-    else:
-        hit0, slot0, val0, ver0 = _probe1(t, sb.key_hi, sb.key_lo, bkt)
+    hit0, slot0, val0, ver0 = _probe1(t, sb.key_hi, sb.key_lo, bkt)
 
     is_get = op == Op.GET
     is_set = op == Op.SET
@@ -205,30 +168,14 @@ def cache_step(cache: CacheTable, batch: Batch, *, policy: str = WB_BLOOM):
         new_ver = ver0 + n_set_total.astype(U32)
         e_w = jnp.where(writer, bkt * t2.slots + slot0,
                         t2.n_buckets * t2.slots)
-        if hn:
-            # write-back writes through to the mirror (writer = one lane
-            # per key segment, distinct entries AND distinct key ids)
-            w_midx = jnp.where(writer & (kmidx >= 0), kmidx, -1)
-            e_raw = bkt * t2.slots + slot0
-            val_new, hot_val = hotset.hot_scatter(
-                t2.val, cache.hot_val, e_raw, w_midx, writer,
-                val_in[pos_last].reshape(-1), t2.val_words)
-            ver_new, hot_ver = hotset.hot_scatter(
-                t2.ver, cache.hot_ver, e_raw, w_midx, writer, new_ver, 1)
-            cache = cache.replace(
-                kv=t2.replace(val=val_new, ver=ver_new),
-                dirty=cache.dirty.at[e_w].set(True, mode="drop"),
-                hot_val=hot_val, hot_ver=hot_ver,
-            )
-        else:
-            cache = cache.replace(
-                kv=t2.replace(
-                    val=t2.val.at[kv.val_word_idx(t2, e_w)].set(
-                        val_in[pos_last].reshape(-1), mode="drop"),
-                    ver=t2.ver.at[e_w].set(new_ver, mode="drop"),
-                ),
-                dirty=cache.dirty.at[e_w].set(True, mode="drop"),
-            )
+        cache = cache.replace(
+            kv=t2.replace(
+                val=t2.val.at[kv.val_word_idx(t2, e_w)].set(
+                    val_in[pos_last].reshape(-1), mode="drop"),
+                ver=t2.ver.at[e_w].set(new_ver, mode="drop"),
+            ),
+            dirty=cache.dirty.at[e_w].set(True, mode="drop"),
+        )
 
     o_rtype, o_rver, o_miss = segments.unsort(sb, rtype, rver, miss)
     o_rval = segments.unsort(sb, rval)
@@ -278,25 +225,9 @@ def refill(cache: CacheTable, key_hi, key_lo, val, ver, bloom_hi, bloom_lo,
 
     ne = t.n_buckets * t.slots
     e_r = jnp.where(has_rec, e_vic, ne)
-    hn = _hot_n(cache)
-    if hn:
-        # refill installs write through to the mirror (the TC-egress
-        # install is the slow path, so the XLA double scatter suffices);
-        # one install per bucket and host-deduped keys keep both index
-        # sets unique
-        midx = jnp.where(has_rec & (key_hi.astype(U32) == U32(0))
-                         & (key_lo.astype(U32) < U32(hn)),
-                         key_lo.astype(I32), -1)
-        val_new, hot_val = hotset.hot_scatter(
-            t.val, cache.hot_val, e_vic, midx, has_rec, val.reshape(-1),
-            t.val_words)
-        ver_new, hot_ver = hotset.hot_scatter(
-            t.ver, cache.hot_ver, e_vic, midx, has_rec, ver, 1)
-        cache = cache.replace(hot_val=hot_val, hot_ver=hot_ver)
-    else:
-        val_new = t.val.at[kv.val_word_idx(t, e_r)].set(
-            val.reshape(-1), mode="drop")
-        ver_new = t.ver.at[e_r].set(ver, mode="drop")
+    val_new = t.val.at[kv.val_word_idx(t, e_r)].set(
+        val.reshape(-1), mode="drop")
+    ver_new = t.ver.at[e_r].set(ver, mode="drop")
     new = t.replace(
         key_hi=t.key_hi.at[e_r].set(key_hi.astype(U32), mode="drop"),
         key_lo=t.key_lo.at[e_r].set(key_lo.astype(U32), mode="drop"),

@@ -104,12 +104,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ._memo import memoize_builder, refuse_kernel_flags
+from ._memo import memoize_builder, refuse_retired
 from ..monitor import counters as mon
 from ..monitor import txnevents as txe
 from ..monitor import waves
 from ..ops import compact
-from ..ops import hotset
 from ..tables import log as logring
 from . import tatp
 from .types import Op, Reply
@@ -157,15 +156,6 @@ class DenseDB:
                         #   "never held", so step-1 must never be 0)
     log: logring.RepLog   # 3 replica entries packed per slot (log x3)
     val_words: int = flax.struct.field(pytree_node=False, default=10)
-    # dintcache hot tier (round 10; OFF by default — TATP is uniform, the
-    # partition is exposed for skewed-TATP experiments): the hot set is
-    # the flat ROW prefix [0, hot_n), which covers the subscriber-table
-    # prefix — the table every transaction touches. hot_meta/hot_val are
-    # physical write-through mirrors of that prefix; the arb prefix has
-    # no mirror.
-    hot_meta: jax.Array | None = None   # u32 [hot_n]
-    hot_val: jax.Array | None = None    # u32 [hot_n * VW]
-    hot_n: int = flax.struct.field(pytree_node=False, default=0)
 
     @property
     def n_sub(self):
@@ -326,15 +316,6 @@ def populate_device(key, n_sub: int, val_words: int = 10, **kw) -> DenseDB:
     return db.replace(val=val, meta=meta)
 
 
-def attach_hotset(db: DenseDB, hot_rows: int) -> DenseDB:
-    """Build the hot mirror for the flat row prefix [0, hot_rows) from the
-    current tables (DenseDB docstring; skewed-TATP experiments)."""
-    hot_rows = int(min(max(int(hot_rows), 1), n_rows(db.n_sub)))
-    return db.replace(hot_meta=db.meta[:hot_rows],
-                      hot_val=db.val[:hot_rows * db.val_words],
-                      hot_n=hot_rows)
-
-
 # ---------------------------------------------------------------- pipeline
 
 
@@ -407,7 +388,6 @@ class Installs:
 def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
               n_sub: int, val_words: int, gen_new: bool = True, mix=None,
               emit_installs: bool = False, check_magic: bool = True,
-              use_hotset: bool = False,
               occupancy: jax.Array | None = None,
               shed: jax.Array | None = None,
               counters: mon.Counters | None = None,
@@ -420,12 +400,6 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
     and this step's reads, and its unlocks free rows for this step's lock
     acquires. Returns (db', new_ctx, c1', stats-of-c2), plus the Installs
     record when ``emit_installs`` (static) is set.
-
-    ``use_hotset`` (static; OFF by default — TATP is uniform) serves the
-    meta/magic gathers through the dintcache row-prefix partition (db must
-    carry the mirror — attach_hotset), write-through at the wave-3
-    installs. Bit-identical to the default path (tests/test_hotset.py);
-    exposed for skewed-TATP experiments.
 
     ``occupancy``/``shed`` (device i32 scalars, or None = off): the
     dintserve variable-occupancy plane. Lanes >= occupancy of the freshly
@@ -443,7 +417,7 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
     counter plane. When threaded, the step bumps the dintmon registry
     in-step (txn outcomes from c2's completing stats, lock arbitration
     won-vs-lost for the new cohort, validate lanes/failures for c1,
-    install/log counts, ring high-water, backend dispatch) with
+    install/log counts, ring high-water) with
     unique-index scatter-adds and returns the updated Counters appended
     to the result tuple. None (the default) threads no counter state and
     leaves the jaxpr untouched.
@@ -483,8 +457,6 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
             #                             was X-held since, so still current
             meta_new = (((vv >> 1) + 1) << 1) | newex.astype(U32)
             wrows = jnp.where(wmask, c2.ws_rows.reshape(-1), oob)   # [2w]
-            hn = db.hot_n
-            hot_meta, hot_val = db.hot_meta, db.hot_val
             payload = jax.random.randint(kv3, (w, 2), 0, 1 << 16, dtype=I32)
             newval = jnp.zeros((w, 2, val_words), U32)
             newval = newval.at[:, :, 0].set(payload.astype(U32))
@@ -505,43 +477,31 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
         # chunk, as many chunks as this step's live count needs
         with waves.part("tatp_dense", "ws_compact"):
             ranks, n_live = compact.live_ranks(wmask)
-        if use_hotset:
-            # partitioned write-through install: the row prefix is the hot
-            # set, so mirror index == row for hot rows (double 1-D
-            # unique-index scatters)
-            wsr = c2.ws_rows.reshape(-1)
-            w_midx = jnp.where(wmask & (wsr < hn), wsr, -1)
-            meta, hot_meta = hotset.hot_scatter(db.meta, hot_meta, wsr,
-                                                w_midx, wmask, meta_new, 1)
-            val, hot_val = hotset.hot_scatter(db.val, hot_val, wsr, w_midx,
-                                              wmask, newval.reshape(-1),
-                                              val_words)
-        else:
-            with waves.part("tatp_dense", "ws_compact"):
-                def install_chunk(tabs, lanes, ok):
-                    meta, val = tabs
-                    rows_c = jnp.where(ok, wrows[lanes], oob)
-                    meta_c, val_c = meta_new[lanes], newval[lanes]
-                    with waves.part("tatp_dense", "meta_scatter"):
-                        meta = meta.at[rows_c].set(meta_c, mode="drop",
-                                                   unique_indices=True)
-                    # interleaved-1-D install: row r's words live at
-                    # [r*VW, (r+1)*VW); a position past the live count
-                    # rides the oob row, which lands at n1*VW >= len and
-                    # drops (the same chunked discipline as the backups'
-                    # install, parallel/dense_sharded._apply_backup)
-                    with waves.part("tatp_dense", "val_scatter"):
-                        wflat = (rows_c[:, None] * val_words
-                                 + jnp.arange(val_words, dtype=I32)
-                                 ).reshape(-1)
-                        val = val.at[wflat].set(val_c.reshape(-1),
-                                                mode="drop",
-                                                unique_indices=True)
-                    return meta, val
 
-                (meta, val), chunks = compact.for_chunks(
-                    ranks, n_live, compact.chunk_lanes(2 * w), install_chunk,
-                    (db.meta, db.val))
+            def install_chunk(tabs, lanes, ok):
+                meta, val = tabs
+                rows_c = jnp.where(ok, wrows[lanes], oob)
+                meta_c, val_c = meta_new[lanes], newval[lanes]
+                with waves.part("tatp_dense", "meta_scatter"):
+                    meta = meta.at[rows_c].set(meta_c, mode="drop",
+                                               unique_indices=True)
+                # interleaved-1-D install: row r's words live at
+                # [r*VW, (r+1)*VW); a position past the live count
+                # rides the oob row, which lands at n1*VW >= len and
+                # drops (the same chunked discipline as the backups'
+                # install, parallel/dense_sharded._apply_backup)
+                with waves.part("tatp_dense", "val_scatter"):
+                    wflat = (rows_c[:, None] * val_words
+                             + jnp.arange(val_words, dtype=I32)
+                             ).reshape(-1)
+                    val = val.at[wflat].set(val_c.reshape(-1),
+                                            mode="drop",
+                                            unique_indices=True)
+                return meta, val
+
+            (meta, val), chunks = compact.for_chunks(
+                ranks, n_live, compact.chunk_lanes(2 * w), install_chunk,
+                (db.meta, db.val))
 
     with waves.scope("tatp_dense", "log_append"):
         logs = logring.append_rep_live(
@@ -596,11 +556,7 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
     # 0.6-0.9 ms per 16-32k random indices
     with waves.scope("tatp_dense", "meta_gather"):
         gidx = jnp.concatenate([c1.rows.reshape(-1), rows.reshape(-1)])
-        if use_hotset:
-            g_midx = jnp.where(gidx < hn, gidx, -1)
-            g = hotset.hot_gather(meta, hot_meta, gidx, g_midx, 1)
-        else:
-            g = meta[gidx]
+        g = meta[gidx]
         vvB = g[: w * READ_LANES].reshape(w, READ_LANES)    # [w, R]
         rmeta = g[w * READ_LANES:].reshape(w, READ_LANES)   # [w, R]
     with waves.part("tatp_dense", "validate"):
@@ -635,14 +591,7 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
         # the default keeps the reference's every-read integrity check
         with waves.scope("tatp_dense", "magic_gather"):
             midx = (rows * val_words + 1).reshape(-1)
-            if use_hotset:
-                # the mirror is the flat word prefix [0, hn*VW): a hot
-                # row's magic word sits at the same flat offset in it
-                mg_midx = jnp.where((rows < hn).reshape(-1), midx, -1)
-                rmagic = hotset.hot_gather(val, hot_val, midx, mg_midx,
-                                           1).reshape(w, READ_LANES)
-            else:
-                rmagic = val[midx].reshape(w, READ_LANES)
+            rmagic = val[midx].reshape(w, READ_LANES)
             magic_bad = jnp.sum(is_read & rex & (rmagic != MAGIC),
                                 dtype=I32)
     else:
@@ -751,27 +700,13 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
             magic_bad=magic_bad)
 
     with waves.part("tatp_dense", "step_frame"):
-        db = db.replace(val=val, meta=meta, arb=arb, step=t + 1, log=logs,
-                        hot_meta=hot_meta, hot_val=hot_val)
+        db = db.replace(val=val, meta=meta, arb=arb, step=t + 1, log=logs)
     if counters is not None:
         with waves.part("tatp_dense", "monitor"):
             # a grant is an active slot on a row not held, so the two
             # kinds of rejection are what is left of the requests
             n_req = active.sum(dtype=I32)
             n_grant = (active & grant.reshape(-1)).sum(dtype=I32)
-            hot_ctrs = {}
-            if use_hotset:
-                # partition accounting over the meta + magic gathers
-                hits = (g_midx >= 0).sum(dtype=I32)
-                lanes = 2 * w * READ_LANES
-                if check_magic:
-                    hits = hits + (mg_midx >= 0).sum(dtype=I32)
-                    lanes += w * READ_LANES
-                hot_ctrs = {
-                    mon.CTR_HOT_HITS: hits,
-                    mon.CTR_HOT_COLD_ROWS: lanes - hits,
-                    mon.CTR_HOT_REFRESH_BYTES: 0,
-                }
             serve_ctrs = {}
             if occupancy is not None:
                 serve_ctrs = {
@@ -781,7 +716,6 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                         jnp.asarray(0 if shed is None else shed, I32),
                 }
             counters = mon.bump(counters, {
-                **hot_ctrs,
                 **serve_ctrs,
                 mon.CTR_STEPS: 1,
                 mon.CTR_TXN_ATTEMPTED: c2.attempted,
@@ -800,9 +734,7 @@ def pipe_step(db: DenseDB, c1: DenseCtx, c2: DenseCtx, key, *, w: int,
                 mon.CTR_VALIDATE_FAILED: v_failed,
                 mon.CTR_INSTALL_WRITES: wmask.sum(dtype=I32),
                 mon.CTR_LOG_APPENDS: wmask.sum(dtype=I32),
-                mon.CTR_DISPATCH_XLA: 1,
-                **({} if use_hotset
-                   else {mon.CTR_INSTALL_CHUNKS: chunks}),
+                mon.CTR_INSTALL_CHUNKS: chunks,
                 mon.CTR_LOCK_CHUNKS: lock_chunks,
             })
             counters = mon.gauge_max(
@@ -889,10 +821,9 @@ def rebase_stamps(db: DenseDB) -> DenseDB:
 def build_pipelined_runner(n_sub: int, w: int = 8192, val_words: int = 10,
                            cohorts_per_block: int = 8, mix=None,
                            check_magic: bool = True, use_pallas=None,
-                           use_hotset: bool = False, hot_frac=None,
-                           use_fused=None, monitor: bool = False, trace=None,
-                           trace_rate=None, trace_cap=None,
-                           serve: bool = False):
+                           use_fused=None, monitor: bool = False,
+                           trace: bool = False, trace_rate: float = 1.0,
+                           trace_cap=None, serve: bool = False):
     """jit(scan(pipe_step)) over carry (db, c1, c2); same contract as
     tatp_pipeline.build_pipelined_runner: returns (run, init, drain).
 
@@ -904,13 +835,6 @@ def build_pipelined_runner(n_sub: int, w: int = 8192, val_words: int = 10,
     drain are unchanged, so the serving engine reuses the closed-loop
     drain verbatim.
 
-    ``use_hotset`` / ``hot_frac``: the dintcache row-prefix partition,
-    OFF by default and deliberately NOT env-driven here — TATP's NURand
-    workload is near-uniform, so the hot tier only pays at this engine
-    unless the experiment skews it; pass use_hotset=True (hot_frac = the
-    mirrored fraction of the subscriber prefix, default 4%) for
-    skewed-TATP experiments. init() attaches the mirror.
-
     ``monitor``: thread the dintmon counter plane through the carry. The
     carry grows a trailing monitor.Counters leaf (init creates it; read
     it between dispatches with monitor.snapshot(carry[-1])) and drain
@@ -918,7 +842,7 @@ def build_pipelined_runner(n_sub: int, w: int = 8192, val_words: int = 10,
     unchanged, outputs bit-identical.
 
     ``trace`` / ``trace_rate`` / ``trace_cap``: the dinttrace flight
-    recorder (None = honor DINT_TRACE / DINT_TRACE_RATE). When on, the
+    recorder (off by default). When on, the
     carry gains a monitor.txnevents.TxnRing leaf BEFORE the counters leaf
     (so counters stay carry[-1]); each block starts from a zeroed ring and
     the caller drains it between dispatches with monitor.txnevents
@@ -927,22 +851,17 @@ def build_pipelined_runner(n_sub: int, w: int = 8192, val_words: int = 10,
     resolved txnevents.TraceCfg hangs off ``init.trace_cfg``. Off =
     engine outputs bit-identical, not one extra jaxpr eqn."""
     assert 2 * w <= (1 << K_ARB), f"w={w} exceeds the arb slot field"
-    use_hotset = bool(use_hotset)
-    refuse_kernel_flags(use_pallas, use_fused)
-    hot_rows = 0
-    if use_hotset:
-        frac = 0.04 if hot_frac is None else float(hot_frac)
-        hot_rows = max(1, min(int((n_sub + 1) * frac), n_rows(n_sub)))
+    refuse_retired(use_pallas=use_pallas, use_fused=use_fused)
     kw = dict(w=w, n_sub=n_sub, val_words=val_words,
-              check_magic=check_magic, use_hotset=use_hotset)
-    trace_on = txe.trace_enabled(trace)
+              check_magic=check_magic)
+    trace_on = bool(trace)
     tcfg = None
     if trace_on:
         # candidates/step: LOCK [2w] + VALIDATE [wR] + INSTALL [2w] +
         # OUTCOME x2 [2w] — default cap holds a full block at rate 1.0
         n_step = w * (READ_LANES + 6)
         cap = int(trace_cap) if trace_cap else n_step * cohorts_per_block
-        tcfg = txe.TraceCfg(rate=txe.trace_rate(trace_rate), cap=cap,
+        tcfg = txe.TraceCfg(rate=float(trace_rate), cap=cap,
                             wave=waves.full_name("tatp_dense", "trace"))
 
     def step_mon(db, c1, c2, key, cnt, ring, **skw):
@@ -987,8 +906,6 @@ def build_pipelined_runner(n_sub: int, w: int = 8192, val_words: int = 10,
             return jax.lax.scan(scan_fn, carry, keys)
 
     def init(db):
-        if use_hotset and db.hot_n == 0:
-            db = attach_hotset(db, hot_rows)
         base = (db, empty_ctx(w), empty_ctx(w))
         return (base + ((txe.create_ring(tcfg.cap),) if trace_on else ())
                 + ((mon.create(),) if monitor else ()))

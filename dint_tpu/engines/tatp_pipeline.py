@@ -606,7 +606,6 @@ def pipe_step(stacked: tatp.Shard, c1: PipeCtx, c2: PipeCtx, key, *, w: int,
             mon.CTR_VALIDATE_FAILED: bad_lane.sum(dtype=I32),
             mon.CTR_INSTALL_WRITES: dw2.sum(dtype=I32),
             mon.CTR_LOG_APPENDS: dw2.sum(dtype=I32),
-            mon.CTR_DISPATCH_XLA: 1,
         })
         return stacked, new_ctx, c1, stats, counters
     return stacked, new_ctx, c1, stats

@@ -48,9 +48,7 @@ from .counters import (CTR_STEPS, CTR_TXN_ATTEMPTED,  # noqa: F401
                        CTR_VALIDATE_FAILED, CTR_INSTALL_WRITES,
                        CTR_LOG_APPENDS, CTR_REPL_PUSH_HOP1,
                        CTR_REPL_PUSH_HOP2, CTR_ROUTE_OVERFLOW,
-                       CTR_RING_HWM, CTR_DISPATCH_XLA, CTR_DISPATCH_PALLAS,
-                       CTR_HOT_HITS, CTR_HOT_COLD_ROWS,
-                       CTR_HOT_REFRESH_BYTES, CTR_TRACE_DROPPED,
+                       CTR_RING_HWM, CTR_TRACE_DROPPED,
                        CTR_SERVE_OCC_LANES, CTR_SERVE_PAD_LANES,
                        CTR_SERVE_SHED_LANES)
 from .trace import (Monitor, TraceWriter, export_chrome_trace,  # noqa: F401

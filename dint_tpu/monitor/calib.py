@@ -17,20 +17,19 @@ of what the planner PREDICTS. Three artifacts, one discipline:
   least squares over the evidence samples, the per-wave implied-GB/s
   table reconciling measured wave times against dintcost-predicted
   bytes, the fit residuals, a tolerance band, and provenance hashes
-  with exactly PLAN.json's discipline (sha256 over sorted-keys JSON,
-  16 hex chars): `evidence_hash` pins the evidence the fit consumed,
+  (sha256 over sorted-keys JSON, 16 hex chars): `evidence_hash` pins the evidence the fit consumed,
   `calib_hash` pins the fitted content so hand-edits fail closed
   (passes/calib_check.py). The embedded samples make the pin
   self-verifying: refitting them must reproduce the recorded
   coefficients bit-for-bit, with no evidence file in reach.
 * **Decision journal** (`dintcal_journal`, controller.JOURNAL_SCHEMA):
   produced by `WidthController` (serve/controller.py); `audit_journal`
-  replays every recorded width/shed/hot_frac decision through the pure
+  replays every recorded width/shed decision through the pure
   policy functions and reports any entry whose recorded outcome the
   replay does not reproduce bit-for-bit.
 
 `resolve_service_model` is the single resolver every ServiceModel
-consumer routes through (analysis/plan.serve_priors, dintserve
+consumer routes through (ServeEngine's default prior, dintserve
 simulate): the pinned CALIB.json when present ($DINT_CALIB_PATH or
 <repo>/CALIB.json), else the ServiceModel defaults — and the returned
 meta records which, plus the calib hash, so capacity claims are always
@@ -76,7 +75,7 @@ def calib_path() -> Path:
 
 
 def _digest(obj) -> str:
-    """Same provenance-hash discipline as analysis/plan._digest."""
+    """The provenance hash: sha256 over sorted-keys JSON, 16 hex chars."""
     blob = json.dumps(obj, sort_keys=True, default=str)
     return hashlib.sha256(blob.encode()).hexdigest()[:16]
 
@@ -84,7 +83,7 @@ def _digest(obj) -> str:
 def calib_hash(doc: dict) -> str:
     """Digest of the pinned content (model + fit + samples + waves +
     tolerance): editing any fitted row without re-pinning fails the
-    calib_check stale-provenance gate, exactly like PLAN.json rows."""
+    calib_check stale-provenance gate."""
     return _digest({k: doc.get(k) for k in
                     ("model", "fit", "samples", "waves", "tolerance")})
 
@@ -276,8 +275,8 @@ def resolve_service_model(path=None) -> tuple:
     """THE ServiceModel resolver (ISSUE 18 satellite): prefer the pinned
     CALIB.json, fall back to the ServiceModel defaults, and always say
     which happened -> (model, meta) with meta = {"source":
-    "calib"|"defaults", "path", "hash"} recorded into PLAN.json serve
-    rows and the dintserve simulate report."""
+    "calib"|"defaults", "path", "hash"} recorded into the dintserve
+    simulate report."""
     from ..serve.controller import ServiceModel
     p = Path(path) if path else calib_path()
     try:
@@ -350,7 +349,7 @@ def check_calib(calib: dict, evidence: dict) -> list[dict]:
 
 def audit_journal(doc: dict) -> list[dict]:
     """Replay a decision journal through the pure policy functions
-    (choose_width / max_backlog / recommend_hot_frac) and return every
+    (choose_width / max_backlog) and return every
     entry whose recorded decision the replay does not reproduce
     bit-for-bit. [] == the journal is exactly what the policy would
     have decided on the recorded inputs."""
@@ -401,15 +400,6 @@ def audit_journal(doc: dict) -> list[dict]:
                     bad(i, e, f"recorded shed decision (bound, shed) "
                               f"= {got} but max_backlog reproduces "
                               f"({bound}, {shed})")
-            elif kind == "hot_frac":
-                inp = e["inputs"]
-                rec = C.recommend_hot_frac(float(inp["cur"]),
-                                           int(inp["hot_hits"]),
-                                           int(inp["hot_cold_rows"]))
-                if rec != e["decision"]["hot_frac"]:
-                    bad(i, e, f"recorded hot_frac "
-                              f"{e['decision']['hot_frac']} but "
-                              f"recommend_hot_frac reproduces {rec}")
             else:
                 bad(i, e, f"unknown journal entry kind {kind!r}")
         except (KeyError, TypeError, ValueError) as exc:
@@ -495,8 +485,8 @@ def synthesize_evidence() -> dict:
 def synthesize_journal() -> dict:
     """Deterministic decision journal for the checked-in fixture: drive
     a real WidthController (no engine, no clock) through a rate ramp
-    into saturation and back, with synthetic backlog shedding and one
-    hot_frac evaluation — every entry produced by the same code paths
+    into saturation and back, with synthetic backlog shedding — every
+    entry produced by the same code paths
     the serving plane journals through, so the fixture exercises the
     real producer, and audit replay is clean by construction."""
     from ..serve import controller as C
@@ -513,6 +503,4 @@ def synthesize_journal() -> dict:
             bound = ctl.max_backlog()
             if backlog > bound:
                 ctl.journal_shed(backlog, backlog - bound)
-    ctl.journal_hot_frac(0.0625, 900, 100,
-                         C.recommend_hot_frac(0.0625, 900, 100))
     return ctl.journal_doc()

@@ -10,7 +10,9 @@ window boundaries. Three rules keep it honest:
 * **Fixed registry.** Counter IDs are module constants into ONE flat
   array; names, kinds, and order are schema — artifacts and JSONL events
   key on the names, so adding a counter means appending here (never
-  reordering) and documenting it in OBSERVABILITY.md.
+  reordering) and documenting it in OBSERVABILITY.md. A removal
+  renumbers the counters after it and recompiles every ``monitor=True``
+  program, so removals wait for one resize that takes them together.
 * **Deterministic increments.** Every update is an elementwise add of
   reduced scalars via one `scatter-add`/`scatter-max` whose indices are a
   static, sorted, duplicate-free Python tuple — `unique_indices=True` is
@@ -41,7 +43,8 @@ FLOW = "flow"      # monotonic accumulator (wrap-safe window deltas sum)
 GAUGE = "gauge"    # high-water mark (windows report the current value)
 
 # --------------------------------------------------------------- registry
-# (name, kind, doc). APPEND ONLY — indices are schema. The docs are what
+# (name, kind, doc). Append; remove only in a batched resize (module
+# docstring) — indices are schema. The docs are what
 # `tools/dintmon.py summarize --describe` and OBSERVABILITY.md print.
 _REGISTRY: tuple[tuple[str, str, str], ...] = (
     ("steps", FLOW,
@@ -97,22 +100,6 @@ _REGISTRY: tuple[tuple[str, str, str], ...] = (
     ("ring_hwm", GAUGE,
      "log-ring high-water mark: max monotonic lane head observed "
      "(occupancy = min(ring_hwm, capacity))"),
-    ("dispatch_xla", FLOW,
-     "steps run (the engines have one route: equals steps)"),
-    ("dispatch_pallas", FLOW,
-     "reads 0: no engine bumps it (kept because the registry is "
-     "append-only and recorded JSONL indexes it)"),
-    ("hot_hits", FLOW,
-     "hot-partition gather lanes served from the dintcache mirror "
-     "(DINT_USE_HOTSET; hot_hits + hot_cold_rows = partitioned lanes)"),
-    ("hot_cold_rows", FLOW,
-     "hot-partition gather lanes that fell through to cold full-table "
-     "row access (the big-array gather)"),
-    ("hot_refresh_bytes", FLOW,
-     "reads 0: the index-compare partition has no residency to refresh "
-     "(kept: append-only registry)"),
-    ("fused_dispatch", FLOW,
-     "reads 0: no engine bumps it (kept: append-only registry)"),
     ("route_ici_lanes", FLOW,
      "routed lanes (lock requests + installs) whose owner lives on the "
      "SAME host: the exchange crosses only the ICI axis (2-D sharded "
@@ -166,7 +153,7 @@ _REGISTRY: tuple[tuple[str, str, str], ...] = (
      "install_chunks == sum over steps of ceil(install_writes / C): one a "
      "step under TATP's mix, none for a step with nothing to write; "
      "install_writes / (C x install_chunks) is the fill share of the "
-     "indices the scatters issue. 0 on the hot-tier route. The KV "
+     "indices the scatters issue. The KV "
      "store's compacted install (PR 40) is the second owner: the trips "
      "of its first loop (value words and versions), C = chunk_lanes(w), "
      "with the same identity and fill share; its second loop (valid and "
@@ -280,12 +267,6 @@ CTR_REPL_PUSH_HOP1 = COUNTER_INDEX["repl_push_hop1"]
 CTR_REPL_PUSH_HOP2 = COUNTER_INDEX["repl_push_hop2"]
 CTR_ROUTE_OVERFLOW = COUNTER_INDEX["route_overflow"]
 CTR_RING_HWM = COUNTER_INDEX["ring_hwm"]
-CTR_DISPATCH_XLA = COUNTER_INDEX["dispatch_xla"]
-CTR_DISPATCH_PALLAS = COUNTER_INDEX["dispatch_pallas"]
-CTR_HOT_HITS = COUNTER_INDEX["hot_hits"]
-CTR_HOT_COLD_ROWS = COUNTER_INDEX["hot_cold_rows"]
-CTR_HOT_REFRESH_BYTES = COUNTER_INDEX["hot_refresh_bytes"]
-CTR_FUSED_DISPATCH = COUNTER_INDEX["fused_dispatch"]
 CTR_ROUTE_ICI_LANES = COUNTER_INDEX["route_ici_lanes"]
 CTR_ROUTE_DCN_LANES = COUNTER_INDEX["route_dcn_lanes"]
 CTR_TRACE_DROPPED = COUNTER_INDEX["trace_dropped"]

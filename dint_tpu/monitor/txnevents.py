@@ -61,7 +61,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import os
 
 import flax.struct
 import jax
@@ -133,20 +132,6 @@ class TraceCfg:
         """16-bit sampling threshold; monotone in rate, so lower-rate
         event sets are strict subsets of higher-rate ones."""
         return max(0, min(65536, round(float(self.rate) * 65536)))
-
-
-def trace_enabled(flag: bool | None = None) -> bool:
-    """Builders' gate: explicit `trace=` wins, else DINT_TRACE=1."""
-    if flag is not None:
-        return bool(flag)
-    return os.environ.get("DINT_TRACE", "0") == "1"
-
-
-def trace_rate(rate: float | None = None) -> float:
-    """Explicit `trace_rate=` wins, else DINT_TRACE_RATE (default 1.0)."""
-    if rate is not None:
-        return float(rate)
-    return float(os.environ.get("DINT_TRACE_RATE", "1.0"))
 
 
 def create_ring(cap: int) -> TxnRing:

@@ -84,7 +84,7 @@ def chunk_lanes(r: int) -> int:
 
 
 # The v5e compiler was seen to sort a 1-D scatter at 1,616 table words an
-# index and not at 1,674 (AOT, PR 36; tests/test_chip_compile.py pins
+# index and not at 1,674 (AOT; tests/test_chip_compile_smallbank.py pins
 # it): a margin under the first.
 SORTED_SCATTER_WORDS_PER_LANE = 1536
 

@@ -57,7 +57,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..engines import tatp_dense as td
-from ..engines._memo import memoize_builder, refuse_kernel_flags
+from ..engines._memo import memoize_builder, refuse_retired
 from ..monitor import counters as mon
 from ..monitor import waves
 from ..ops import compact
@@ -232,7 +232,7 @@ def build_sharded_pipelined_runner(mesh: Mesh, n_shards: int,
     psummed stats totals (monitor.snapshot does that reduction); off
     (default) = contract and jaxpr unchanged."""
     assert 2 * w <= (1 << td.K_ARB), f"w={w} exceeds the arb slot field"
-    refuse_kernel_flags(use_pallas, use_fused)
+    refuse_retired(use_pallas=use_pallas, use_fused=use_fused)
     n_loc = n_sub_local(n_sub_global, n_shards)
     n1 = td.n_rows(n_loc) + 1
     kw = dict(w=w, n_sub=n_loc, val_words=val_words)

@@ -64,11 +64,11 @@ from ..engines.smallbank_pipeline import (L, MAGIC, TS_AMT_MAX, VW, N_STATS,
                                           STAT_BAL_DELTA, compute_phase,
                                           gen_cohort, _lock_slots)
 from ..engines.types import Op
-from ..engines._memo import memoize_builder
+from ..engines._memo import memoize_builder, refuse_retired
 from ..monitor import counters as mon
 from ..monitor import txnevents as txe
 from ..monitor import waves
-from ..ops import compact, hotset
+from ..ops import compact
 from ..tables import log as logring
 from .sharded import SHARD_AXIS, make_mesh, pcast_varying   # noqa: F401 (re-exported)
 
@@ -87,26 +87,13 @@ N_STATS = N_STATS + 1
 @flax.struct.dataclass
 class SBShard:
     """One device's slice: primary balances for its account range, backup
-    copies of the two predecessors' ranges, step-stamp lock tables, log.
-
-    The ``hot_*`` leaves are the per-device dintcache hot tier (round 10):
-    round-robin partitioning puts global hot account ``a < hot_n`` at
-    device ``a % D`` local index ``a // D``, so each device's hot set is
-    its LOCAL account prefix ``q < hot_loc`` (hot_loc = ceil(hot_n / D);
-    the mirror may cover a couple of tail accounts past the global hot_n
-    on some devices — a superset is harmless, coherence is per-row).
-    Mirror index = tbl*hot_loc + q; installs write through. The sharded
-    lock tables are exact (slot == local row), so stamps always mirror."""
+    copies of the two predecessors' ranges, step-stamp lock tables, log."""
     bal: jax.Array       # u32 [m1_loc]  (sentinel last)
     bck_bal: jax.Array   # u32 [N_BCK * m1_loc]
     x_step: jax.Array    # u32 [m1_loc]
     s_step: jax.Array    # u32 [m1_loc]
     step: jax.Array      # u32 scalar (starts at 2, == single-chip engine)
     log: logring.RepLog  # replicas=1: the 3 copies live on 3 devices
-    hot_bal: jax.Array | None = None   # u32 [2*hot_loc]
-    hot_x: jax.Array | None = None     # u32 [2*hot_loc]
-    hot_s: jax.Array | None = None     # u32 [2*hot_loc]
-    hot_loc: int = flax.struct.field(pytree_node=False, default=0)
 
 
 def n_acct_local(n_accounts: int, d: int) -> int:
@@ -115,22 +102,6 @@ def n_acct_local(n_accounts: int, d: int) -> int:
 
 def m1_local(n_accounts: int, d: int) -> int:
     return 2 * n_acct_local(n_accounts, d) + 1
-
-
-def attach_hotset_sb(mesh: Mesh, state: SBShard, hot_loc: int) -> SBShard:
-    """Build each device's hot mirror from its current local tables
-    (leaves here are the stacked [D, ...] arrays)."""
-    n_loc = state.bal.shape[1] // 2
-    hot_loc = int(min(max(int(hot_loc), 1), n_loc))
-    idx = jnp.concatenate([jnp.arange(hot_loc, dtype=I32),
-                           n_loc + jnp.arange(hot_loc, dtype=I32)])
-    shard = NamedSharding(mesh, P(AXIS))
-    put = lambda x: jax.device_put(x, shard)    # noqa: E731
-    return state.replace(
-        hot_bal=put(state.bal[:, idx]),
-        hot_x=put(state.x_step[:, idx]),
-        hot_s=put(state.s_step[:, idx]),
-        hot_loc=hot_loc)
 
 
 def create_sharded_sb(mesh: Mesh, n_shards: int, n_accounts: int,
@@ -185,14 +156,6 @@ def _positions(dest, active, n_shards):
     return jnp.take_along_axis(excl, dest[:, None], axis=1)[:, 0]
 
 
-def mirror_index(rows, mask, n_loc: int, hot_loc: int):
-    """Local row -> hot mirror index (tbl*hot_loc + q), -1 cold. The
-    sentinel row (q == n_loc) is never hot: hot_loc <= n_loc."""
-    tb = (rows >= n_loc).astype(I32)
-    q = rows - tb * n_loc
-    return jnp.where(mask & (q < hot_loc), tb * hot_loc + q, -1)
-
-
 class Arbitration(NamedTuple):
     """What the owner's arbitration of its inbox gives (``arbitrate``): the
     verdicts in slot layout, as the replies' all_to_all takes them, and
@@ -202,14 +165,11 @@ class Arbitration(NamedTuple):
     held: jax.Array          # bool [D*cap]: the row's X or S held from t-1
     x_step: jax.Array
     s_step: jax.Array
-    hot_x: jax.Array | None
-    hot_s: jax.Array | None
-    hot_hits: jax.Array      # i32: live slots whose row the mirror serves
     chunks: jax.Array        # i32: the trips of each of its three loops
 
 
-def arbitrate(state: SBShard, live: compact.Prefixed, r_op, r_row,
-              hot_loc: int = 0) -> Arbitration:
+def arbitrate(state: SBShard, live: compact.Prefixed, r_op,
+              r_row) -> Arbitration:
     """The owner's no-wait S/X arbitration of its request inbox and the
     fused balance read (the closed form of engines/smallbank_dense.py),
     over the inbox's live slots only. ``live`` =
@@ -231,11 +191,10 @@ def arbitrate(state: SBShard, live: compact.Prefixed, r_op, r_row,
     that reads t there takes the held flag its row's first S read (its
     stamp's writer, which read the row before any stamp). An X stamp
     needs no such care: an X is granted only on a row neither lock
-    holds, and t reads as not held. ``hot_loc`` > 0: the hot mirror
-    serves the reads and takes the stamps' write-through."""
+    holds, and t reads as not held."""
     own = "dense_sharded_sb"
     m1 = state.x_step.shape[0]
-    n_loc, sent, oob = (m1 - 1) // 2, m1 - 1, m1
+    sent, oob = m1 - 1, m1
     t = state.step
     r = r_op.shape[0]
     chunk = compact.sorted_chunk_lanes(m1, r)
@@ -254,13 +213,6 @@ def arbitrate(state: SBShard, live: compact.Prefixed, r_op, r_row,
         rows = jnp.where(ok, live.pack(r_row, size), sent)
         is_x = ok & (op == Op.ACQ_X_READ)
         is_s = ok & (op == Op.ACQ_S_READ)
-        if hot_loc:
-            midx = mirror_index(rows, ok, n_loc, hot_loc)
-
-    def gather(tab, mirror, c_rows, start):
-        if hot_loc:
-            return hotset.hot_gather(tab, mirror, c_rows, sl(midx, start), 1)
-        return tab[c_rows]
 
     def arrive(carry, start):
         first_x, first_s = carry
@@ -281,12 +233,12 @@ def arbitrate(state: SBShard, live: compact.Prefixed, r_op, r_row,
         return fx, fs
 
     def grant(carry, start):
-        x_step, s_step, hot_x, hot_s, held_x, held_s, granted, g_bal = carry
+        x_step, s_step, held_x, held_s, granted, g_bal = carry
         c_rows, c_at = sl(rows, start), sl(at, start)
         fx, fs = sl(fx_buf, start), sl(fs_buf, start)
         with waves.part(own, "owner_held_read"):
-            hx = gather(x_step, hot_x, c_rows, start) == t - 1
-            s_at = gather(s_step, hot_s, c_rows, start)
+            hx = x_step[c_rows] == t - 1
+            s_at = s_step[c_rows]
             # t: an earlier chunk's first S on the row stamped it
             hs = jnp.where(s_at == t, held_s[jnp.minimum(fs, size - 1)],
                            s_at == t - 1)
@@ -301,25 +253,14 @@ def arbitrate(state: SBShard, live: compact.Prefixed, r_op, r_row,
             granted = jax.lax.dynamic_update_slice(granted, g, (start,))
         with waves.part(own, "owner_bal_read"):
             g_bal = jax.lax.dynamic_update_slice(
-                g_bal, jnp.where(
-                    g, gather(state.bal, state.hot_bal, c_rows, start)
-                    .astype(I32), 0), (start,))
+                g_bal, jnp.where(g, state.bal[c_rows].astype(I32), 0),
+                (start,))
         with waves.part(own, "owner_stamp"):
             x_step = x_step.at[jnp.where(grant_x, c_rows, oob)].set(
                 t, mode="drop", unique_indices=True)
             s_step = s_step.at[jnp.where(s_writer, c_rows, oob)].set(
                 t, mode="drop", unique_indices=True)
-            if hot_loc:
-                # stamp write-through (one-writer grant masks stay unique
-                # on the mirror's index subset)
-                c_midx = sl(midx, start)
-                hot_x = hot_x.at[jnp.where(grant_x & (c_midx >= 0), c_midx,
-                                           2 * hot_loc)].set(
-                    t, mode="drop", unique_indices=True)
-                hot_s = hot_s.at[jnp.where(s_writer & (c_midx >= 0),
-                                           c_midx, 2 * hot_loc)].set(
-                    t, mode="drop", unique_indices=True)
-        return x_step, s_step, hot_x, hot_s, held_x, held_s, granted, g_bal
+        return x_step, s_step, held_x, held_s, granted, g_bal
 
     with waves.part(own, "owner_arb"):
         fill = varying(jnp.full((m1,), BIG, I32))
@@ -330,17 +271,14 @@ def arbitrate(state: SBShard, live: compact.Prefixed, r_op, r_row,
         zero = varying(jnp.zeros((size,), I32))
         (fx_buf, fs_buf), _ = compact.for_live_chunks(
             live.n_live, chunk, first_of_row, (zero, zero))
-        (x_step, s_step, hot_x, hot_s, held_x, held_s, granted, g_bal), _ = \
+        (x_step, s_step, held_x, held_s, granted, g_bal), _ = \
             compact.for_live_chunks(
                 live.n_live, chunk, grant,
-                (state.x_step, state.s_step, state.hot_x, state.hot_s, no,
-                 no, no, varying(jnp.zeros((size,), I32))))
+                (state.x_step, state.s_step, no, no, no,
+                 varying(jnp.zeros((size,), I32))))
         return Arbitration(
             grant=live.unpack(granted), bal=live.unpack(g_bal),
             held=live.unpack(held_x | held_s), x_step=x_step, s_step=s_step,
-            hot_x=hot_x, hot_s=hot_s,
-            hot_hits=((midx >= 0).sum(dtype=I32) if hot_loc
-                      else jnp.asarray(0, I32)),
             chunks=chunks)
 
 
@@ -382,15 +320,13 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
                             w: int = 2048, cohorts_per_block: int = 8,
                             hot_frac=None, hot_prob=None, mix=None,
                             use_hotset=None, monitor: bool = False,
-                            trace=None, trace_rate=None, trace_cap=None):
+                            trace: bool = False, trace_rate: float = 1.0,
+                            trace_cap=None):
     """jit(shard_map(scan(step))). Contract mirrors the single-chip dense
     runner: (run, init, drain); stats are psummed across the mesh.
 
-    ``use_hotset``: None = honor DINT_USE_HOTSET env. Per-device dintcache
-    partition over the owner-side gathers (SBShard docstring): hot lanes
-    read the local mirror, installs write through; init() attaches the
-    mirror. Hot set defaults to the workload's (``hot_frac``). Outputs
-    bit-identical to the default path (tests/test_hotset.py).
+    The ``use_*`` keyword is retired: accepted only as False
+    (engines/_memo.refuse_retired).
 
     ``monitor``: thread the dintmon counter plane PER DEVICE. Txn
     outcomes count at the source device (where the cohort completes);
@@ -402,7 +338,7 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
     jaxpr unchanged.
 
     ``trace`` / ``trace_rate`` / ``trace_cap``: the dinttrace flight
-    recorder (None = honor DINT_TRACE / DINT_TRACE_RATE); a per-device
+    recorder (off by default); a per-device
     txnevents.TxnRing carry leaf lands BEFORE the counters leaf. This is
     the payoff path: the txn id — (gen_step*D + source_dev)*w + lane, the
     same id on every device — RIDES THE ROUTE (one extra u32 field
@@ -411,24 +347,18 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
     ROUTE/VOTE/OUTCOME, owner-side LOCK/INSTALL, and backup-side REPL
     events of one transaction join by id into a single 2PC span tree.
     Off = routed fields, jaxpr, and outputs all bit-identical."""
+    refuse_retired(use_hotset=use_hotset)
     d = n_shards
     n_loc = n_acct_local(n_accounts, d)
     m1 = m1_local(n_accounts, d)
     oob = m1
     cap = 2 * ((w * L + d - 1) // d)
-    use_hotset = hotset.resolve_use_hotset(use_hotset)
-    hot_loc = 0
-    if use_hotset:
-        from ..clients import workloads as wl
-        frac = wl.SB_HOT_FRAC if hot_frac is None else float(hot_frac)
-        hot_n = max(1, min(int(n_accounts * frac), n_accounts))
-        hot_loc = min((hot_n + d - 1) // d, n_loc)
     kw_gen = {}
     if hot_frac is not None:
         kw_gen["hot_frac"] = hot_frac
     if hot_prob is not None:
         kw_gen["hot_prob"] = hot_prob
-    trace_on = txe.trace_enabled(trace)
+    trace_on = bool(trace)
     tcfg = None
     if trace_on:
         # per-device candidates/step: ROUTE [wL] + owner LOCK [d*cap] +
@@ -436,7 +366,7 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
         # OUTCOME [w]; d*cap = 2*wL rounded up
         n_step = w * L + 4 * d * cap + 2 * w
         rcap = int(trace_cap) if trace_cap else n_step * cohorts_per_block
-        tcfg = txe.TraceCfg(rate=txe.trace_rate(trace_rate), cap=rcap,
+        tcfg = txe.TraceCfg(rate=float(trace_rate), cap=rcap,
                             wave=waves.full_name("dense_sharded_sb",
                                                  "trace"))
 
@@ -498,7 +428,7 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
         with waves.part("dense_sharded_sb", "owner_addr"):
             live = compact.prefixed(r_op != 0, d)
         with waves.scope("dense_sharded_sb", "arbitrate"):
-            arb = arbitrate(state, live, r_op, r_row, hot_loc)
+            arb = arbitrate(state, live, r_op, r_row)
 
         # ---- replies back to sources + classify -----------------------
         with waves.scope("dense_sharded_sb", "reply"):
@@ -559,18 +489,9 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
             with waves.part("dense_sharded_sb", "owner_install"):
                 i_mask = i_m != 0
                 irows = jnp.where(i_mask, i_row, oob)
-                hot_bal = state.hot_bal
-                if use_hotset:
-                    # partitioned write-through install (double 1-D
-                    # unique-index scatter)
-                    i_midx = mirror_index(i_row, i_mask, n_loc, hot_loc)
-                    bal_new, hot_bal = hotset.hot_scatter(
-                        state.bal, hot_bal, i_row, i_midx, i_mask,
-                        i_bal.astype(U32), 1)
-                else:
-                    bal_new = state.bal.at[irows].set(i_bal.astype(U32),
-                                                      mode="drop",
-                                                      unique_indices=True)
+                bal_new = state.bal.at[irows].set(i_bal.astype(U32),
+                                                  mode="drop",
+                                                  unique_indices=True)
 
         def log_value(mask, balv):
             # the value a ring names: {balance, magic}, as
@@ -649,21 +570,8 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
         with waves.part("dense_sharded_sb", "sbx_frame"):
             state = state.replace(bal=bal_new, bck_bal=bck,
                                   x_step=arb.x_step, s_step=arb.s_step,
-                                  step=t + 1, log=log, hot_bal=hot_bal,
-                                  hot_x=arb.hot_x, hot_s=arb.hot_s)
+                                  step=t + 1, log=log)
 
-        if cnt is not None and use_hotset:
-            # partition accounting: 3 hot-partitioned gathers per step
-            # (x/s stamps + balances), each serving (midx >= 0) lanes
-            # from the mirror
-            with waves.part("dense_sharded_sb", "monitor"):
-                n_g = 3
-                hits = arb.hot_hits
-                cnt = mon.bump(cnt, {
-                    mon.CTR_HOT_HITS: n_g * hits,
-                    mon.CTR_HOT_COLD_ROWS: n_g * (d * cap) - n_g * hits,
-                    mon.CTR_HOT_REFRESH_BYTES: 0,
-                })
         if cnt is not None:
             # txn outcomes + overflow at the SOURCE (c1 completes here);
             # lock arbitration + installs at the OWNER (they ran here) —
@@ -703,7 +611,6 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
                     mon.CTR_INSTALL_CHUNKS: i_live.trips,
                     mon.CTR_BCK_CHUNKS: (fwd_lives[0].trips
                                          + fwd_lives[1].trips),
-                    mon.CTR_DISPATCH_XLA: 1,
                     mon.CTR_XSHARD_TXNS: xshard.sum(dtype=I32),
                     mon.CTR_REMOTE_LOCK_LANES:
                         (active & (dest != dev)).sum(dtype=I32),
@@ -826,8 +733,6 @@ def build_sharded_sb_runner(mesh: Mesh, n_shards: int, n_accounts: int,
         return out[:-1], out[-1]
 
     def init(state):
-        if use_hotset and state.hot_loc == 0:
-            state = attach_hotset_sb(mesh, state, hot_loc)
         base = (state, stack_leaf(_empty_sb_ctx(w)))
         return (base
                 + ((stack_leaf(txe.create_ring(tcfg.cap)),)

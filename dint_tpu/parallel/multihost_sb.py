@@ -40,9 +40,7 @@ are the budget, so route so only truly-remote lanes pay them):
     traffic is observable, not just priced.
 
 Requires n_hosts >= 3 (the +2 dcn hop would alias the source on a
-2-host mesh and double-log — same rule as multihost.py). The hotset
-lever of the 1-D runner is orthogonal to the transport and stays on the
-flat-axis path (PERF.md round 14).
+2-host mesh and double-log — same rule as multihost.py).
 """
 from __future__ import annotations
 
@@ -115,8 +113,8 @@ def build_multihost_sb_runner(mesh: Mesh, n_accounts: int, w: int = 2048,
                               cohorts_per_block: int = 8, hot_frac=None,
                               hot_prob=None, mix=None,
                               hierarchical: bool = False,
-                              monitor: bool = False, trace=None,
-                              trace_rate=None, trace_cap=None,
+                              monitor: bool = False, trace: bool = False,
+                              trace_rate: float = 1.0, trace_cap=None,
                               serve: bool = False, overlap: bool = False):
     """jit(shard_map(scan(step))) over the 2-D mesh. Contract mirrors
     build_sharded_sb_runner: (run, init, drain); stats psummed ici then
@@ -181,7 +179,7 @@ def build_multihost_sb_runner(mesh: Mesh, n_accounts: int, w: int = 2048,
         raise ValueError("overlap=True requires serve=True: the double-"
                          "buffered route is defined over admitted "
                          "serving cohorts (occ rides the prefetch carry)")
-    if overlap and txe.trace_enabled(trace):
+    if overlap and trace:
         raise ValueError("overlap=True is incompatible with trace: "
                          "dinttrace txn ids are stamped with the "
                          "generation step, which the double buffer "
@@ -191,7 +189,7 @@ def build_multihost_sb_runner(mesh: Mesh, n_accounts: int, w: int = 2048,
         kw_gen["hot_frac"] = hot_frac
     if hot_prob is not None:
         kw_gen["hot_prob"] = hot_prob
-    trace_on = txe.trace_enabled(trace)
+    trace_on = bool(trace)
     tcfg = None
     if trace_on:
         # per-device candidates/step: same census as the 1-D runner —
@@ -199,7 +197,7 @@ def build_multihost_sb_runner(mesh: Mesh, n_accounts: int, w: int = 2048,
         # REPL x2 [2*d*cap] + OUTCOME [w]
         n_step = w * L + 4 * d * cap + 2 * w
         rcap = int(trace_cap) if trace_cap else n_step * cohorts_per_block
-        tcfg = txe.TraceCfg(rate=txe.trace_rate(trace_rate), cap=rcap,
+        tcfg = txe.TraceCfg(rate=float(trace_rate), cap=rcap,
                             wave=waves.full_name("multihost_sb", "trace"))
 
     def _exchange(x):
@@ -491,7 +489,6 @@ def build_multihost_sb_runner(mesh: Mesh, n_accounts: int, w: int = 2048,
                 mon.CTR_LOG_APPENDS: i_mask.sum(dtype=I32),
                 mon.CTR_ROUTE_ICI_LANES: ici_lanes,
                 mon.CTR_ROUTE_DCN_LANES: dcn_lanes,
-                mon.CTR_DISPATCH_XLA: 1,
             })
             if serve and gen_new:
                 # admission accounting at the DISPATCH step (the cohort

@@ -18,7 +18,7 @@ from .arrivals import (ArrivalStream, burst_schedule,  # noqa: F401
                        constant_schedule, make_schedule, poisson_schedule)
 from .controller import (ControllerCfg, ServiceModel,  # noqa: F401
                          WidthController, choose_width, max_backlog,
-                         recommend_hot_frac, simulate_widths)
+                         simulate_widths)
 from .engine import (RealClock, ServeEngine, VirtualClock,  # noqa: F401
                      cached_runner)
 from .mesh import MeshServeEngine                      # noqa: F401

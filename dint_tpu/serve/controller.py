@@ -31,13 +31,13 @@ mirrored into the device counter ledger (serve_shed_lanes), the same
 two-sided audit trail dinttrace uses for trace_dropped.
 
 Decision journal (dintcal, round 19): every control decision — width
-re-evaluation, admission shed, hot_frac evaluation — is appended to
+re-evaluation, admission shed — is appended to
 ``WidthController.journal`` as a schema-stable entry carrying the exact
 inputs the pure policy functions above consumed (offered-rate EWMA,
 per-width service estimates, backlog bound) next to the recorded
 outcome. Because the policy functions are pure and the inputs are
 recorded, `tools/dintcal.py audit` can replay any journal through
-choose_width / max_backlog / recommend_hot_frac and verify every
+choose_width / max_backlog and verify every
 decision bit-for-bit; under a VirtualClock the journal itself is a
 deterministic function of (schedule, seed). monitor/calib.py ingests
 journals and the controller's (width, service_us) sample ledger as
@@ -116,27 +116,6 @@ def max_backlog(width: int, service_us_w: float, cfg: ControllerCfg) -> int:
     the SLO at this width's capacity. Admissions past this are shed."""
     cap = width / (service_us_w * 1e-6)
     return max(int(cap * cfg.slo_us * 1e-6), width)
-
-
-def recommend_hot_frac(cur: float, hot_hits: int, hot_cold_rows: int, *,
-                       target_hit_rate: float = 0.90,
-                       shrink_above: float = 0.995,
-                       lo: float = 1 / 64, hi: float = 0.5) -> float:
-    """Auto-size the hot-set fraction from the observed hot_hits /
-    hot_cold_rows counters (round 9's hot/cold split): double the hot
-    set while the hit rate misses ``target_hit_rate``, halve it once
-    hits are so saturated (> ``shrink_above``) that HBM is being spent
-    on rows the workload no longer touches. Pure; applied only at
-    engine-rebuild boundaries (hot_frac is a compile-time shape)."""
-    total = hot_hits + hot_cold_rows
-    if total == 0:
-        return cur
-    hit_rate = hot_hits / total
-    if hit_rate < target_hit_rate:
-        return min(cur * 2.0, hi)
-    if hit_rate > shrink_above:
-        return max(cur / 2.0, lo)
-    return cur
 
 
 class WidthController:
@@ -238,18 +217,6 @@ class WidthController:
             "decision": {
                 "bound": max_backlog(w, s, self.cfg) * int(scale),
                 "shed": int(shed)}})
-
-    def journal_hot_frac(self, cur: float, hot_hits: int,
-                         hot_cold_rows: int, rec: float) -> None:
-        """Record one hot_frac evaluation (engine rebuild boundaries):
-        the counter inputs recommend_hot_frac consumed and the outcome,
-        rebuilt or not — no-op evaluations are evidence too."""
-        self.journal.append({
-            "kind": "hot_frac", "block": int(self._block_idx),
-            "inputs": {"cur": float(cur), "hot_hits": int(hot_hits),
-                       "hot_cold_rows": int(hot_cold_rows)},
-            "decision": {"hot_frac": float(rec),
-                         "rebuilt": float(rec) != float(cur)}})
 
     def journal_meta(self) -> dict:
         """The journal header: everything audit replay needs beyond the

@@ -40,10 +40,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import monitor as mon
+from ..monitor import calib
 from ..stats import LatencyHistogram
 from .arrivals import ArrivalStream
-from .controller import (ControllerCfg, ServiceModel, WidthController,
-                         recommend_hot_frac)
+from .controller import ControllerCfg, ServiceModel, WidthController
 
 
 class RealClock:
@@ -120,9 +120,13 @@ class ServeEngine:
 
     Parameters
     ----------
-    engine : 'tatp_dense' | 'smallbank_dense'
-    size : table size (n_sub / n_accounts)
-    cfg / model : controller config + service-time prior
+    engine : 'tatp_dense' | 'smallbank_dense' | 'store'
+    size : table size (n_sub / n_accounts / n_keys)
+    cfg : controller config (default ``ControllerCfg()``: widths 256,
+        1,024, 4,096 and 8,192, SLO 5,000 µs)
+    model : service-time prior (default: the fitted model of the pinned
+        CALIB.json, monitor/calib.resolve_service_model, else
+        ``ServiceModel()``)
     cohorts_per_block : steps per dispatched block (pipeline depth rides
         the existing engines; this is the scan length per dispatch)
     depth : host->device pump depth — the host runs at most ``depth``
@@ -130,22 +134,9 @@ class ServeEngine:
         double buffer; shim/pump.py got the same knob this round)
     clock : RealClock (default) or VirtualClock (deterministic tests)
     monitor : thread the dintmon counter plane (needed for the serve
-        counter reconciliation identity and hot_frac auto-sizing)
-    runner_kw : forwarded to build_pipelined_runner (mix, use_hotset,
-        hot_frac, ...) — always wins over the plan
-    plan : "auto" (default) reads the pinned PLAN.json (analysis/plan):
-        the width menu + SLO come from the plan's serve priors when
-        ``cfg`` is None, build knobs the plan pins for this engine's
-        serve workload replace the env-flag default path, and the
-        hot_frac prior seeds the rebuild loop. A plan dict is accepted
-        directly (tests); None disables plan consumption. Without a
-        readable plan everything falls back to today's defaults and
-        the snapshot records ``"plan": None`` — never a silent default.
-    adapt_hot_frac : rebuild the width menu at the plan-recommended
-        hot_frac at width-switch drain boundaries (the only points the
-        pipeline is empty and the tables are host-side, so a re-shape
-        is safe). None = auto: on iff a hot_frac prior exists and the
-        counter plane that feeds the recommendation is threaded.
+        counter reconciliation identity)
+    runner_kw : forwarded to the engine's runner builder (mix,
+        hot_frac, use_scan, ...)
     """
 
     # engine families this class can drive; subclasses (serve/mesh.py's
@@ -158,8 +149,7 @@ class ServeEngine:
                  cohorts_per_block: int = 2, depth: int = 2,
                  val_words: int = 4, clock=None, monitor: bool = True,
                  seed: int = 0, idle_poll_us: float = 50_000.0,
-                 runner_kw: dict | None = None, plan="auto",
-                 adapt_hot_frac: bool | None = None):
+                 runner_kw: dict | None = None):
         assert engine in self.ENGINES, engine
         assert depth >= 1
         self.engine = engine
@@ -171,29 +161,8 @@ class ServeEngine:
         self.monitor = monitor
         self.idle_poll_us = idle_poll_us
         self.runner_kw = dict(runner_kw or {})
-
-        plan_knobs, priors, self.plan_meta = self._resolve_plan(plan)
-        if cfg is None and priors:
-            cfg = ControllerCfg(
-                widths=tuple(sorted(int(w) for w in priors["widths"])),
-                slo_us=float(priors["slo_us"]))
         self.cfg = cfg or ControllerCfg()
-        if model is None and priors:
-            model = ServiceModel(base_us=priors["model"]["base_us"],
-                                 per_lane_ns=priors["model"]["per_lane_ns"])
-        self.model = model or ServiceModel()
-        self._apply_plan_knobs(plan_knobs)
-
-        # hot_frac rebuild loop: prior from runner_kw if pinned by the
-        # caller, else the plan's serve prior; None = engine family has
-        # no hot tier and the loop stays off
-        self._hot_frac = self.runner_kw.get("hot_frac")
-        if self._hot_frac is None and priors:
-            self._hot_frac = priors.get("hot_frac")
-        if adapt_hot_frac is None:
-            adapt_hot_frac = self._hot_frac is not None and self.monitor
-        self.adapt_hot_frac = bool(adapt_hot_frac)
-        self.hot_frac_rebuilds = 0
+        self.model = model or calib.resolve_service_model()[0]
 
         self.base_key = jax.random.PRNGKey(seed)
         self.ctl = WidthController(self.cfg, self.model)
@@ -225,34 +194,6 @@ class ServeEngine:
 
     # -- construction ---------------------------------------------------
 
-    def _resolve_plan(self, plan):
-        """-> (knobs, serve_priors | None, meta | None) for this
-        engine's serve workload. Missing / unreadable plan degrades to
-        (today's env-default behaviour, no priors, meta None)."""
-        if plan is None:
-            return {}, None, None
-        from ..analysis import plan as P
-        doc = plan if isinstance(plan, dict) else None
-        if doc is None:
-            try:
-                doc = P.load_plan()
-            except (OSError, ValueError):
-                return {}, None, None
-        wname = P.SERVE_WORKLOADS.get(self.engine)
-        if wname is None or wname not in doc.get("workloads", {}):
-            return {}, None, None
-        knobs, meta = P.resolve_for(wname, plan=doc)
-        return knobs, doc["workloads"][wname].get("serve"), meta
-
-    def _apply_plan_knobs(self, knobs: dict) -> None:
-        """Plan-resolved build knobs replace the env-flag default path:
-        a knob the caller left out of runner_kw builds at the plan's
-        pinned value instead of whatever the ambient DINT_* flags say
-        (under DINT_PLAN_OVERRIDE=1 resolve_for already folded the env
-        value back in). Explicit runner_kw always wins."""
-        for k, v in knobs.items():
-            self.runner_kw.setdefault(k, v)
-
     def _fresh_db(self, seed: int):
         if self.engine == "tatp_dense":
             from ..engines import tatp_dense as td
@@ -260,11 +201,9 @@ class ServeEngine:
                                val_words=self.val_words)
         if self.engine == "store":
             from ..clients import micro
-            from ..tables import run as run_mod
             return micro.make_store_table(
                 self.size, val_words=self.val_words,
-                hashed=run_mod.resolve_use_scan(
-                    self.runner_kw.get("use_scan")))
+                hashed=bool(self.runner_kw.get("use_scan")))
         from ..engines import smallbank_dense as sd
         return sd.create(self.size)
 
@@ -320,26 +259,6 @@ class ServeEngine:
         row = stats.astype(np.int64).sum(axis=0)
         self.stats_total = (row if self.stats_total is None
                             else self.stats_total + row)
-
-    def _maybe_rebuild_hot_frac(self) -> None:
-        """At a width-switch drain boundary (pipeline empty, tables
-        host-side — the only safe re-shape points) fold the observed
-        hot-tier counters into a new hot_frac and rebuild the width
-        menu when the recommendation moved. With no hot-tier traffic
-        (hot counters zero) the recommendation is the status quo and
-        this is a no-op, so plans without a hot tier never rebuild."""
-        if not self.adapt_hot_frac or self._hot_frac is None:
-            return
-        rec = self.hot_frac_recommendation(self._hot_frac)
-        self.ctl.journal_hot_frac(
-            self._hot_frac, self.counters_total.get("hot_hits", 0),
-            self.counters_total.get("hot_cold_rows", 0), rec)
-        if rec == self._hot_frac:
-            return
-        self._hot_frac = rec
-        self.runner_kw["hot_frac"] = rec
-        self.hot_frac_rebuilds += 1
-        self._runners = {w: self._build(w) for w in self.cfg.widths}
 
     # -- the pump -------------------------------------------------------
 
@@ -450,7 +369,6 @@ class ServeEngine:
             if w != self._cur_w:
                 if self._cur_w is not None:
                     self._detach()
-                self._maybe_rebuild_hot_frac()
                 self._attach(w)
 
             occ = self._fill_block(w)
@@ -470,13 +388,6 @@ class ServeEngine:
             self._detach()
 
     # -- reporting ------------------------------------------------------
-
-    def hot_frac_recommendation(self, cur: float) -> float:
-        """Auto-size hot_frac from the observed hot-tier counters (to be
-        applied at the next engine rebuild — hot_frac is a shape)."""
-        return recommend_hot_frac(
-            cur, self.counters_total.get("hot_hits", 0),
-            self.counters_total.get("hot_cold_rows", 0))
 
     def snapshot(self) -> dict:
         elapsed = self._elapsed or max(self._rel_now(), 1e-9)
@@ -512,8 +423,4 @@ class ServeEngine:
             "service": {**sp, "hist": self.service_hist.to_dict()},
             "controller": self.ctl.snapshot(),
             "counters": counters,
-            "plan": self.plan_meta,
-            "hot_frac": {"current": self._hot_frac,
-                         "adaptive": self.adapt_hot_frac,
-                         "rebuilds": self.hot_frac_rebuilds},
         }

@@ -57,12 +57,9 @@ class MeshServeEngine(ServeEngine):
 
     Parameters beyond ServeEngine's: ``mesh_shape`` = (n_hosts, n_ici)
     (>= 3 hosts — the replication fault-domain rule); ``hierarchical``
-    picks the ici-then-dcn exchange; ``overlap`` enables the
-    double-buffered route. Both default to None = resolved from the
-    pinned plan's multihost_serve workload (analysis/plan; currently
-    hierarchical ON / overlap OFF pending the pre-registered hardware
-    A/B per PERF.md round 18) and fall back to the same values when no
-    plan is readable, so behaviour without a plan is unchanged. size is
+    picks the ici-then-dcn exchange (on by default); ``overlap`` enables
+    the double-buffered route (off by default, pending the
+    pre-registered hardware A/B of PERF.md round 18). size is
     n_accounts (global)."""
 
     ENGINES = ("multihost_sb",)
@@ -74,21 +71,18 @@ class MeshServeEngine(ServeEngine):
                  cohorts_per_block: int = 2, depth: int = 2,
                  clock=None, monitor: bool = True, seed: int = 0,
                  idle_poll_us: float = 50_000.0,
-                 hierarchical: bool | None = None,
-                 overlap: bool | None = None,
-                 runner_kw: dict | None = None, plan="auto",
-                 adapt_hot_frac: bool | None = None):
+                 hierarchical: bool = True, overlap: bool = False,
+                 runner_kw: dict | None = None):
         from ..parallel import multihost_sb as mhs
         self.n_hosts, self.n_ici = int(mesh_shape[0]), int(mesh_shape[1])
         self.mesh = mhs.make_mesh_2d(self.n_hosts, self.n_ici)
         self.n_devices = self.n_hosts * self.n_ici
-        self.hierarchical = hierarchical
-        self.overlap = overlap
+        self.hierarchical = bool(hierarchical)
+        self.overlap = bool(overlap)
         super().__init__("multihost_sb", n_accounts, cfg=cfg, model=model,
                          cohorts_per_block=cohorts_per_block, depth=depth,
                          clock=clock, monitor=monitor, seed=seed,
-                         idle_poll_us=idle_poll_us, runner_kw=runner_kw,
-                         plan=plan, adapt_hot_frac=adapt_hot_frac)
+                         idle_poll_us=idle_poll_us, runner_kw=runner_kw)
         # ONE global controller in per-device units: D cohorts of width w
         # serve every step, so the single-device policy functions apply
         # to offered_rate / D unchanged
@@ -103,20 +97,6 @@ class MeshServeEngine(ServeEngine):
         self._arrival_idx = 0
 
     # -- construction ---------------------------------------------------
-
-    def _apply_plan_knobs(self, knobs: dict) -> None:
-        """hierarchical/overlap are constructor attributes here, not
-        runner_kw: consume them from the plan when the caller left them
-        at None, then fall back to the historical defaults (ON / OFF)
-        so a missing plan changes nothing. Runs inside ServeEngine's
-        __init__ BEFORE the width menu is built."""
-        if self.hierarchical is None:
-            self.hierarchical = bool(knobs.get("hierarchical", True))
-        if self.overlap is None:
-            self.overlap = bool(knobs.get("overlap", False))
-        rest = {k: v for k, v in knobs.items()
-                if k not in ("hierarchical", "overlap")}
-        super()._apply_plan_knobs(rest)
 
     def _fresh_db(self, seed: int):
         from ..parallel import multihost_sb as mhs
@@ -250,7 +230,6 @@ class MeshServeEngine(ServeEngine):
                 # is the mesh-wide barrier, no extra protocol needed
                 if self._cur_w is not None:
                     self._detach()
-                self._maybe_rebuild_hot_frac()
                 self._attach(w)
 
             occ = self._fill_block(w)

@@ -386,11 +386,8 @@ class CachedStore:
 
     def __init__(self, cache_buckets: int, val_words: int = 10,
                  slots: int = 4, policy: str = store_cache.WB_BLOOM,
-                 width: int = 4096, hot_keys: int = 0):
-        """``hot_keys`` > 0 attaches the dintcache mirror for key ids
-        [0, hot_keys) inside the device cache (store_cache.CacheTable)."""
-        self.cache = store_cache.create(cache_buckets, slots, val_words,
-                                        hot_keys=hot_keys)
+                 width: int = 4096):
+        self.cache = store_cache.create(cache_buckets, slots, val_words)
         self.kvs = HostKVS(cache_buckets, val_words)
         self.policy = policy
         self.vw = val_words

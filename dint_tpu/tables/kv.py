@@ -109,8 +109,8 @@ def _match_bucket(table: KVTable, key_hi, key_lo, bkt):
 
 def probe_loc(table: KVTable, key_hi, key_lo, b1, b2):
     """Two-choice LOCATION probe: find each key in either candidate bucket
-    without fetching its value (the dintcache hot tier serves hot keys'
-    val/ver from its mirror, so the value gather is the caller's choice).
+    without fetching its value (the store's step gathers the value under
+    a trace part of its own, ``probe_val``).
 
     Returns (hit [R] bool, bkt [R] i32, slot [R] i32, free1 [R] i32,
     free2 [R] i32)."""

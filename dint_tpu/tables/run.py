@@ -45,7 +45,6 @@ the overlay's size: the merge compares [r, WR, WD]. A run holds at most
 from __future__ import annotations
 
 import math
-import os
 
 import flax.struct
 import jax
@@ -360,18 +359,6 @@ def locate(run: OrderedRun, q_hi, q_lo):
     """(run offset, overlay offset): each lane's lower bound in both."""
     return (lower_bound(run.key_hi, run.key_lo, q_hi, q_lo),
             lower_bound(run.d_key_hi, run.d_key_lo, q_hi, q_lo))
-
-
-def env_use_scan() -> bool:
-    return os.environ.get("DINT_USE_SCAN", "0") not in ("", "0")
-
-
-def resolve_use_scan(explicit: bool | None = None) -> bool:
-    """Engine-builder gate for the dintscan ordered-run scan path:
-    explicit kwarg wins, else the DINT_USE_SCAN env."""
-    if explicit is None:
-        return env_use_scan()
-    return bool(explicit)
 
 
 def window_widths(run: OrderedRun, scan_max: int) -> tuple:

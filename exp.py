@@ -66,51 +66,8 @@ def _trace_on() -> bool:
     return os.environ.get("DINT_TRACE") == "1"
 
 
-# plan consumption (ISSUE 17): the pinned PLAN.json replaces the env-flag
-# default path for the sweep's build knobs; ambient DINT_* flags win only
-# under DINT_PLAN_OVERRIDE=1 (which the per-workload meta records). One
-# load per process; _PLAN_OVERRIDDEN accumulates the union of knobs the
-# override actually changed so every point artifact can carry it.
-_PLAN_DOC: list | None = None
-_PLAN_OVERRIDDEN: set = set()
-
-
-def _plan_doc():
-    global _PLAN_DOC
-    if _PLAN_DOC is None:
-        doc = None
-        if os.environ.get("DINT_BENCH_PLAN", "1") != "0":
-            try:
-                from dint_tpu.analysis import plan as dplan
-                doc = dplan.load_plan()
-            except Exception:  # noqa: BLE001 — sweep must not die on a
-                doc = None     # missing/corrupt plan; points record null
-        _PLAN_DOC = [doc]
-    return _PLAN_DOC[0]
-
-
-def _plan_knobs(workload: str) -> dict:
-    """Plan-resolved build knobs for one workload ({} without a readable
-    plan — the builders then env-resolve exactly as before)."""
-    doc = _plan_doc()
-    if doc is None:
-        return {}
-    from dint_tpu.analysis import plan as dplan
-    knobs, meta = dplan.resolve_for(workload, plan=doc)
-    _PLAN_OVERRIDDEN.update(meta["overridden"])
-    return knobs
-
-
-def _plan_meta():
-    """The artifact's "plan" field: {source, hash, overridden} when the
-    sweep resolved knobs from a pinned plan, EXPLICIT None otherwise."""
-    doc = _plan_doc()
-    if doc is None:
-        return None
-    from dint_tpu.analysis import plan as dplan
-    return {"source": str(dplan.plan_path()),
-            "hash": doc.get("provenance", {}).get("cost_model_hash"),
-            "overridden": sorted(_PLAN_OVERRIDDEN)}
+def _trace_rate() -> float:
+    return float(os.environ.get("DINT_TRACE_RATE", "1.0"))
 
 
 def _drain(drain, carry):
@@ -268,14 +225,12 @@ def _tatp_runner(n_sub, w, cpb, seed=0):
 
     from dint_tpu.engines import tatp_dense as td
 
-    knobs = _plan_knobs("tatp_uniform")
-    kb = {k: knobs[k] for k in ("use_hotset",) if k in knobs}
     # on-device populate: the full sweep runs at the reference's 7M
     # subscribers (~6.2 GB) — generated in HBM, not via the host
     db = td.populate_device(jax.random.PRNGKey(seed), n_sub, val_words=10)
     run, init, drain = td.build_pipelined_runner(
         n_sub, w=w, val_words=10, cohorts_per_block=cpb,
-        monitor=_monitor_on(), trace=_trace_on(), **kb)
+        monitor=_monitor_on(), trace=_trace_on(), trace_rate=_trace_rate())
     run = _wrap_trace(run, init)
     return run, init(db), drain
 
@@ -299,13 +254,11 @@ def _sb_runner(n_acc, w, cpb, hot_frac=None, hot_prob=None):
 
     from dint_tpu.engines import smallbank_dense as sd
 
-    knobs = _plan_knobs("smallbank_skewed")
-    kb = {k: knobs[k] for k in ("use_hotset",) if k in knobs}
     db = sd.create(n_acc)
     run, init, drain = sd.build_pipelined_runner(
         n_acc, w=w, cohorts_per_block=cpb,
         hot_frac=hot_frac, hot_prob=hot_prob,
-        monitor=_monitor_on(), trace=_trace_on(), **kb)
+        monitor=_monitor_on(), trace=_trace_on(), trace_rate=_trace_rate())
     run = _wrap_trace(run, init)
     return run, init(db), drain
 
@@ -332,7 +285,7 @@ def _mh_sb_runner(n_acc, w, cpb, hierarchical):
     run, init, drain = mh.build_multihost_sb_runner(
         mesh, n_acc, w=w, cohorts_per_block=cpb,
         hierarchical=hierarchical, monitor=_monitor_on(),
-        trace=_trace_on())
+        trace=_trace_on(), trace_rate=_trace_rate())
     run = _wrap_trace(run, init)
     return run, init(mh.create_multihost_sb(mesh, n_acc)), drain
 
@@ -352,13 +305,7 @@ def run_point(results, name, fn):
     if getattr(results, "already_done", lambda n: False)(name):
         print(f"point {name}: skipped (already done)", flush=True)
         return
-    out = fn()
-    if isinstance(out, dict):
-        # artifact provenance: which pinned plan resolved the build
-        # knobs (object or EXPLICIT null — same consumer contract as
-        # counters/breakdown)
-        out.setdefault("plan", _plan_meta())
-    results[name] = out
+    results[name] = fn()
 
 
 def _metric_json(att, com, dt, p, extra, breakdown=None):
@@ -702,8 +649,7 @@ def sweep_micro(window_s, quick, results, want=lambda name: True):
             run_point(results, name, store_fn)
 
     # DINT's skewed store benchmark: Zipfian keys whose hot head is the
-    # dintcache prefix (DINT_USE_HOTSET=1 serves it from the mirror —
-    # record the A/B state in every artifact)
+    # smallest key ids
     for w in widths:
         name = f"store_zipf_w{w}"
         if not want(name):
@@ -716,7 +662,6 @@ def sweep_micro(window_s, quick, results, want=lambda name: True):
             return _timed_client(c, lambda: c.run_wave(rng), window_s) | {
                 "width": w, "key_dist": "zipfian",
                 "zipf_theta": wl.ZIPF_THETA,
-                "use_hotset": c.use_hotset,
                 "scan": None}
 
         run_point(results, name, zipf_fn)
@@ -725,7 +670,7 @@ def sweep_micro(window_s, quick, results, want=lambda name: True):
     # YCSB-B shape (0%) through YCSB-E (95% scans) at one fixed width,
     # Zipfian start keys, uniform lengths. Every artifact carries the
     # "scan" object (or EXPLICIT null on the point-op rows above — same
-    # consumer contract as plan/counters): resolved routes + the mix, so
+    # consumer contract as counters): resolved routes + the mix, so
     # the hw A/B behind PERF.md's round-20 decision rule is replayable.
     scan_w = 1024 if quick else 4096
     scan_max = 16 if quick else wl.YCSB_E_MAX_SCAN
@@ -1208,17 +1153,12 @@ def run_all(out: str, window_s: float = 10.0, quick: bool = False,
     if want("smallbank") and not skew_preset:
         from dint_tpu.clients import workloads as wl
         from dint_tpu.engines import smallbank_dense as sd
-        from dint_tpu.ops import hotset
 
         skew_extra = {
             "hot_frac": (wl.SB_HOT_FRAC if hot_frac is None
                          else float(hot_frac)),
             "hot_prob": (wl.SB_HOT_PROB if hot_prob is None
                          else float(hot_prob)),
-            # the value that actually built: plan-pinned when a plan is
-            # readable, env-resolved otherwise (matches _sb_runner)
-            "use_hotset": _plan_knobs("smallbank_skewed").get(
-                "use_hotset", hotset.resolve_use_hotset(None)),
         }
         sweep_pipeline("smallbank",
                        lambda w, b: _sb_runner(n_acc, w, b, hot_frac,
@@ -1264,10 +1204,8 @@ def run_all(out: str, window_s: float = 10.0, quick: bool = False,
 
     if skew_preset:
         # skew-sweep preset (--only smallbank_skew): one width, hot_frac
-        # swept across the 90%-hot workload — the dintcache decision curve
-        # (arm DINT_USE_HOTSET=0/1 runs to A/B the hot tier at each skew)
+        # swept across the 90%-hot workload
         from dint_tpu.engines import smallbank_dense as sd
-        from dint_tpu.ops import hotset
 
         skew_w = 256 if quick else 8192
         for frac in (0.01, 0.04, 0.16, 0.5):
@@ -1279,11 +1217,7 @@ def run_all(out: str, window_s: float = 10.0, quick: bool = False,
                 open_rates=(), results=results,
                 point_extra={"hot_frac": frac,
                              "hot_prob": (0.9 if hot_prob is None
-                                          else float(hot_prob)),
-                             "use_hotset": _plan_knobs(
-                                 "smallbank_skewed").get(
-                                 "use_hotset",
-                                 hotset.resolve_use_hotset(None))},
+                                          else float(hot_prob))},
                 geom={"l": sd.L, "vw": sd.VW})
     # --only serve_mesh is a preset (like skew): the bidirectional
     # substring filter would also fire the single-device serve legs
@@ -1326,8 +1260,7 @@ def main():
                          "(restart after a failed or killed run)")
     ap.add_argument("--hot-frac", type=float, default=None,
                     help="SmallBank hot-set fraction override (default: "
-                         "the reference 4%%); the dintcache mirror "
-                         "(DINT_USE_HOTSET=1) aligns to it")
+                         "the reference 4%%)")
     ap.add_argument("--hot-prob", type=float, default=None,
                     help="SmallBank hot-set probability override "
                          "(default: the reference 90%%)")

@@ -1,7 +1,7 @@
 """Test harness: force the CPU backend with 8 virtual devices so the full
 multi-chip sharding matrix runs without TPU hardware. The chip is
-chip_smoke.py's and bench.py's job; tests/test_chip_compile.py asks the
-chip's compiler without the chip."""
+chip_smoke.py's and bench.py's job; tests/test_chip_compile_*.py ask
+the chip's compiler without the chip."""
 import os
 
 flags = os.environ.get("XLA_FLAGS", "")

@@ -520,8 +520,10 @@ _RUNNER_CASES = {
     "plain": {},
     "monitor": {"monitor": True},
     "trace": {"trace": True},
-    "hotset": {"use_hotset": True},
-    "all_three": {"monitor": True, "trace": True, "use_hotset": True},
+    "hot_traffic": {"hot_frac": 0.01, "hot_prob": 0.99},
+    # monitor and trace together (the third knob, the hot-set mirror,
+    # is gone)
+    "all_three": {"monitor": True, "trace": True},
 }
 
 
@@ -532,7 +534,7 @@ def test_rings_equal_the_full_width_program_after_block_and_drain(
     runner with `append_rep` handed the plain masks (the parent's
     program), same seeds: every ring's `entries` and `head`, the tables
     and the stats after two blocks and a drain, with `monitor`, `trace`
-    and `use_hotset` on and off. w = 256 (an inbox of 1,536 slots, C =
+    and hot-prefix traffic on and off. w = 256 (an inbox of 1,536 slots, C =
     128) at a mix where a step's installs take more than one trip."""
     kw = _RUNNER_CASES[case]
     mesh = dsb.make_mesh(D4)
@@ -612,20 +614,14 @@ def _full_width_arbitrate(x_step, s_step, bal, t, r_op, r_row):
 
 
 @functools.cache
-def _arbitrate_pair(hot_loc):
+def _arbitrate_pair():
     def compacted(x_step, s_step, bal, t, r_op, r_row):
         state = dsb.SBShard(bal=bal, bck_bal=bal, x_step=x_step,
                             s_step=s_step, step=t, log=None)
-        if hot_loc:
-            n_loc = (x_step.shape[0] - 1) // 2
-            idx = jnp.concatenate([jnp.arange(hot_loc),
-                                   n_loc + jnp.arange(hot_loc)])
-            state = state.replace(hot_bal=bal[idx], hot_x=x_step[idx],
-                                  hot_s=s_step[idx], hot_loc=hot_loc)
         arb = dsb.arbitrate(state, compact.prefixed(r_op != 0, _AD), r_op,
-                            r_row, hot_loc)
+                            r_row)
         return ((arb.grant, arb.bal, arb.held, arb.x_step, arb.s_step),
-                arb.chunks, (arb.hot_x, arb.hot_s, arb.hot_hits))
+                arb.chunks)
 
     return jax.jit(compacted), jax.jit(_full_width_arbitrate)
 
@@ -669,25 +665,25 @@ _ARB_CASES = {
 }
 
 
-@pytest.mark.parametrize("hot_loc", [0, 25], ids=["cold", "hot"])
+@pytest.mark.parametrize("n_rows", [40, 4], ids=["cold", "hot"])
 @pytest.mark.parametrize("seed", [0, 7, 2**31 + 3])
 @pytest.mark.parametrize("case", list(_ARB_CASES))
 def test_the_compacted_arbitration_equals_the_full_width_wave(
-        case, seed, hot_loc):
+        case, seed, n_rows):
     """`dsb.arbitrate` over the live requests against the wave over every
     slot, on inboxes that hit the edges (an empty segment, a full one, 0
     live, exactly C, C + 1, several chunks, every slot): grants, balances
     read, held flags, both stamp tables, bit for bit; the loop makes
-    ceil(live / C) trips. With the hot mirror the same verdicts and
-    stamps, and the mirror's stamps those of its rows."""
+    ceil(live / C) trips. The requests name the first ``n_rows`` rows of
+    each table: 40, or a hot prefix of 4 where most requests collide."""
     assert _AC == 128
     per_segment = _ARB_CASES[case]
     rng = np.random.default_rng([seed, sorted(_ARB_CASES).index(case)])
     t = np.uint32(5)
     x_step, s_step, bal = _tables(rng, int(t))
-    r_op, r_row = _request_inbox(per_segment, rng, 40)
-    compacted, full = _arbitrate_pair(hot_loc)
-    got, chunks, (hot_x, hot_s, hits) = compacted(
+    r_op, r_row = _request_inbox(per_segment, rng, n_rows)
+    compacted, full = _arbitrate_pair()
+    got, chunks = compacted(
         x_step, s_step, bal, t, jnp.asarray(r_op), jnp.asarray(r_row))
     want = full(x_step, s_step, bal, t, jnp.asarray(r_op),
                 jnp.asarray(r_row))
@@ -700,15 +696,6 @@ def test_the_compacted_arbitration_equals_the_full_width_wave(
     assert grant.sum() <= n_live and not grant[r_op == 0].any()
     if n_live > 50:
         assert 0 < grant.sum() < n_live          # conflicts were resolved
-    if hot_loc:
-        n_loc = _AN // _AD
-        idx = np.concatenate([np.arange(hot_loc), n_loc + np.arange(hot_loc)])
-        np.testing.assert_array_equal(np.asarray(hot_x),
-                                      np.asarray(got[3])[idx])
-        np.testing.assert_array_equal(np.asarray(hot_s),
-                                      np.asarray(got[4])[idx])
-        q = r_row % n_loc
-        assert int(hits) == int(((r_op != 0) & (q < hot_loc)).sum())
 
 
 def test_a_later_chunk_reads_the_s_locks_as_the_step_began():
@@ -734,8 +721,8 @@ def test_a_later_chunk_reads_the_s_locks_as_the_step_began():
     r_op[2, :40] = [X] * 20 + [S] * 20
     r_row[2, :40] = np.concatenate([second, first])
     r_op, r_row = jnp.asarray(r_op.reshape(-1)), jnp.asarray(r_row.reshape(-1))
-    compacted, full = _arbitrate_pair(0)
-    got, chunks, _ = compacted(x_step, s_step, bal, t, r_op, r_row)
+    compacted, full = _arbitrate_pair()
+    got, chunks = compacted(x_step, s_step, bal, t, r_op, r_row)
     want = full(x_step, s_step, bal, t, r_op, r_row)
     assert int(chunks) == 2              # segment 2 lies in chunk 2
     for g, w_ in zip(got, want):

@@ -10,13 +10,11 @@ The acceptance pins, per ISSUE.md:
     seed) under VirtualClock — two runs give byte-identical journals —
     and its shed entries reconcile exactly with the dintmon
     serve_shed_lanes counter;
-  * `dintcal audit` replays every recorded width/shed/hot_frac decision
+  * `dintcal audit` replays every recorded width/shed decision
     through the pure policy functions; a hand-tampered decision fails
     the audit naming the entry and block;
   * the calib_check pass fails closed on hand-edited coefficients
-    (unfit-model), broken provenance, unregistered waves, and
-    plan-vs-calib model drift — and PLAN.json's serve rows record which
-    model priced them (source + hash).
+    (unfit-model), broken provenance and unregistered waves.
 
 Fixtures regenerate with `python tools/dintcal.py synth`.
 """
@@ -206,7 +204,6 @@ def test_audit_names_tampered_decisions():
     doc = CAL.synthesize_journal()
     kinds = [e["kind"] for e in doc["entries"]]
     iw, ish = kinds.index("width"), kinds.index("shed")
-    ihf = kinds.index("hot_frac")
 
     t = copy.deepcopy(doc)
     t["entries"][iw]["decision"]["width"] = 99999
@@ -218,11 +215,6 @@ def test_audit_names_tampered_decisions():
     t["entries"][ish]["decision"]["shed"] += 1
     v = CAL.audit_journal(t)
     assert len(v) == 1 and v[0]["index"] == ish and v[0]["kind"] == "shed"
-
-    t = copy.deepcopy(doc)
-    t["entries"][ihf]["decision"]["hot_frac"] = 0.5
-    v = CAL.audit_journal(t)
-    assert len(v) == 1 and v[0]["kind"] == "hot_frac"
 
     t = copy.deepcopy(doc)
     t["entries"][iw]["kind"] = "mystery"
@@ -317,9 +309,9 @@ def test_controller_journal_matches_policy_reevaluations():
 # -------------------------------------------------- the calib_check pass
 
 
-def _pass_check(calib, plan=None):
+def _pass_check(calib):
     from dint_tpu.analysis.passes import calib_check as CC
-    return CC.check_calib_doc(calib, "fixture/calib_check", plan=plan,
+    return CC.check_calib_doc(calib, "fixture/calib_check",
                               source_dir=REPO)
 
 
@@ -334,9 +326,8 @@ def broken_calib_findings():
 
 
 def test_calib_check_clean_on_pinned_artifacts():
-    from dint_tpu.analysis import plan as P
     calib = CAL.load_calib(CALIB_PINNED)
-    assert _pass_check(calib, plan=P.load_plan()) == []
+    assert _pass_check(calib) == []
 
 
 def test_calib_check_broken_fixture_fires():
@@ -371,62 +362,28 @@ def test_calib_check_codes_fire(mutate, code):
         [f.code for f in findings]
 
 
-def test_calib_check_plan_model_attribution():
-    """Cross-artifact: the plan's serve rows must have been priced with
-    the model the resolver picks now."""
-    from dint_tpu.analysis import plan as P
-    calib = CAL.load_calib(CALIB_PINNED)
-    plan = P.load_plan()
-
-    doctored = copy.deepcopy(plan)
-    for e in doctored["workloads"].values():
-        if isinstance(e.get("serve"), dict):
-            e["serve"]["model"]["hash"] = "f" * 16
-    fs = _pass_check(calib, plan=doctored)
-    assert {f.code for f in fs} == {"plan-model-drift"}
-
-    doctored = copy.deepcopy(plan)
-    for e in doctored["workloads"].values():
-        if isinstance(e.get("serve"), dict):
-            e["serve"]["model"].update(source="defaults", hash=None)
-    fs = _pass_check(calib, plan=doctored)
-    assert {f.code for f in fs} == {"plan-model-drift"}
-    assert any("DEFAULTS" in f.message for f in fs)
-
-    # plan says calib but no calib readable -> missing-calib
-    fs = _pass_check(None, plan=plan)
-    assert {f.code for f in fs} == {"missing-calib"}
-
-
 def test_calib_check_anchoring_and_opt_in(monkeypatch, tmp_path):
     """The registered pass lands whole-artifact findings exactly once
     (the anchor target) and returns [] when calibration is not in use
-    (no CALIB.json and no calib-sourced plan rows)."""
+    (no CALIB.json)."""
     from dint_tpu import analysis
-    from dint_tpu.analysis import plan as P
-    from dint_tpu.analysis.passes.calib_check import calib_check
+    from dint_tpu.analysis.passes.calib_check import ANCHOR, calib_check
 
     class _T:                           # a trace stub off-anchor
         name = "smallbank_dense/block"
     assert calib_check(_T()) == []
 
-    # opt-out world: no calib anywhere, plan priced with defaults
-    monkeypatch.setenv(CAL.ENV_CALIB_PATH, str(tmp_path / "none.json"))
-    plain = copy.deepcopy(P.load_plan())
-    for e in plain["workloads"].values():
-        if isinstance(e.get("serve"), dict):
-            e["serve"]["model"].update(source="defaults", hash=None)
-    ppath = tmp_path / "plan.json"
-    ppath.write_text(json.dumps(plain))
-    monkeypatch.setenv(P.ENV_PLAN_PATH, str(ppath))
-
     class _A:
-        name = os.environ.get(P.ENV_PLAN_ANCHOR, P.DEFAULT_ANCHOR)
+        name = ANCHOR
+    assert calib_check(_A()) == []      # the pinned CALIB.json is clean
+
+    # opt-out world: no calib anywhere
+    monkeypatch.setenv(CAL.ENV_CALIB_PATH, str(tmp_path / "none.json"))
     assert calib_check(_A()) == []
     assert not analysis.has_errors([])
 
 
-# ----------------------------------------- the resolver + plan threading
+# -------------------------------------------------------- the resolver
 
 
 def test_resolve_service_model_prefers_calib_and_says_so(monkeypatch,
@@ -447,29 +404,6 @@ def test_resolve_service_model_prefers_calib_and_says_so(monkeypatch,
     bad.write_text("{not json")
     model, meta = CAL.resolve_service_model(bad)   # soft-fail, never raise
     assert meta["source"] == "defaults"
-
-
-def test_plan_serve_rows_record_model_provenance():
-    """ISSUE 18 satellite fix: serve_priors no longer instantiates
-    ServiceModel() unconditionally — the pinned plan's serve rows carry
-    the resolver's coefficients plus source + hash."""
-    from dint_tpu.analysis import plan as P
-    calib = CAL.load_calib(CALIB_PINNED)
-    plan = P.load_plan()
-    rows = [e["serve"] for e in plan["workloads"].values()
-            if isinstance(e.get("serve"), dict)]
-    assert rows
-    for serve in rows:
-        m = serve["model"]
-        assert m["source"] == "calib"
-        assert m["hash"] == calib["provenance"]["calib_hash"]
-        assert m["base_us"] == calib["model"]["base_us"]
-        assert m["per_lane_ns"] == calib["model"]["per_lane_ns"]
-    # and the live function agrees with the pinned artifact
-    wl = next(w for w in P.WORKLOADS if w.serve)
-    fresh = P.serve_priors(wl)
-    assert fresh["model"]["source"] == "calib"
-    assert fresh["model"]["hash"] == calib["provenance"]["calib_hash"]
 
 
 # ----------------------------------------------------------------- CLI
@@ -530,7 +464,7 @@ def test_cli_propose_emits_repin_recipe(tmp_path, capsys):
     rep = json.loads(capsys.readouterr().out)
     assert rep["delta"]["base_us"]["pinned"] == \
         rep["delta"]["base_us"]["proposed"]     # clean evidence: no move
-    assert "dintplan.py plan --calib" in rep["repin"]
+    assert rep["repin"] == f"mv {out} CALIB.json"
     proposed = json.loads(out.read_text())
     with open(CALIB_PINNED) as fh:
         assert proposed["model"] == json.load(fh)["model"]

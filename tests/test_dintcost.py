@@ -266,18 +266,18 @@ def test_cli_report_check_and_diff(tmp_path, capsys):
     exit 0, and diff catching an injected regression by name."""
     main = _dintcost_main()
     art = tmp_path / "cost.json"
-    assert main(["report", "tatp_dense/block", "tatp_dense/block@hot",
+    assert main(["report", "tatp_dense/block", "tatp_dense/block@mon",
                  "--json", "-o", str(art)]) == 0
     payload = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert payload["metric"] == "dintcost"
     assert isinstance(payload["schema"], int)
-    e = payload["targets"]["tatp_dense/block@hot"]
+    e = payload["targets"]["tatp_dense/block@mon"]
     for k in ("bytes_per_step", "dispatches_per_step", "footprint_bytes",
               "waves", "reconcile", "budget", "ledger_bytes"):
         assert k in e
     assert all(c["ok"] for c in e["reconcile"])
 
-    assert main(["check", "--target", "tatp_dense/block@hot",
+    assert main(["check", "--target", "tatp_dense/block@mon",
                  "--json"]) == 0
     out = capsys.readouterr().out
     assert json.loads(out.strip().splitlines()[-1])["ok"] is True

@@ -411,13 +411,12 @@ def test_protocol_safe_replication_clean():
 @pytest.mark.lint
 @pytest.mark.parametrize("target", [
     "tatp_dense/block",            # dense OCC
-    "tatp_dense/block@hot",        # installs through the hot partition
     "tatp_pipeline/block",         # generic sort-based OCC
     "smallbank_dense/block",       # 2PL expiring stamps
     "dense_sharded/block",         # OCC + ICI replication
 ])
 def test_protocol_clean_on_real_engines(target):
-    """Safe-idiom controls: the dense, pipeline, and hot-set variants of
+    """Safe-idiom controls: the dense and pipeline variants of
     the real engines satisfy every protocol check through genuine
     dataflow (no allowlist involved)."""
     fs = analysis.run(targets=[target], passes=["protocol"])
@@ -539,11 +538,6 @@ def _broken_findings(pname):
         # rest of the dintdur fixtures
         import test_dintdur
         return test_dintdur.broken_wal_order_findings()
-    if pname == "plan_check":
-        # the canonical broken plan fixture (swapped frontier ranks =>
-        # flipped-ordering) lives with the rest of the dintplan fixtures
-        import test_dintplan
-        return test_dintplan.broken_plan_findings()
     if pname == "calib_check":
         # the canonical broken calibration fixture (hand-edited
         # coefficient => unfit-model + stale-provenance) lives with the
@@ -713,3 +707,105 @@ def test_prune_check_is_a_dry_run_that_fails_on_stale(tmp_path, capsys):
     with pytest.raises(SystemExit) as exc:     # --check needs the prune
         main(["--check", "--all"])
     assert exc.value.code == 2
+
+
+# ----------------------------------------------------- tools/dintgate.sh
+
+
+def test_dintgate_orchestration_smoke(tmp_path):
+    """tools/dintgate.sh is ONE entry point for the six standing gates.
+    The smoke pins the orchestration — seven invocations (dintcal
+    contributes check AND the journal audit) in order through $PYTHON,
+    the allowlist-rot dry-runs riding the three matrix gates, the five
+    finding gates' SARIF logs merged into one multi-run document, the
+    per-stage wall-clock timings JSON line, a failing gate named WITHOUT
+    stopping the others — against a millisecond stub; each real gate has
+    its own in-depth tests (and the full script runs in CI proper)."""
+    import stat
+    import textwrap
+
+    calls = tmp_path / "calls.log"
+    stub = tmp_path / "fakepy"
+    stub.write_text(textwrap.dedent("""\
+        #!/bin/sh
+        # dintgate's SARIF merge runs "$PY - out in..." — that one is
+        # real work, hand it to the actual interpreter
+        if [ "$1" = "-" ]; then exec python "$@"; fi
+        echo "$*" >> "$CALLS"
+        tool=$(basename "$1" .py)
+        out=""; prev=""
+        for a in "$@"; do
+            [ "$prev" = "--sarif" ] && out="$a"
+            prev="$a"
+        done
+        [ -n "$out" ] && printf \\
+          '{"version":"2.1.0","runs":[{"tool":{"driver":{"name":"%s"}},"results":[]}]}' \\
+          "$tool" > "$out"
+        [ "$tool" = dintdur ] && [ "${FAIL_DUR:-0}" = 1 ] && exit 1
+        exit 0
+        """))
+    stub.chmod(stub.stat().st_mode | stat.S_IEXEC)
+    script = os.path.join(REPO, "tools", "dintgate.sh")
+    env = dict(os.environ, PYTHON=str(stub), CALLS=str(calls))
+
+    merged = tmp_path / "gate.sarif"
+    timings = tmp_path / "timings.json"
+    r = subprocess.run(["bash", script, "--sarif", str(merged),
+                        "--timings", str(timings)],
+                       capture_output=True, text=True, env=env,
+                       timeout=120)
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "all 6 gates ok" in r.stdout
+
+    lines = calls.read_text().splitlines()
+    assert [ln.split()[0].rsplit("/", 1)[-1] for ln in lines] == \
+        ["dintlint.py", "dintcost.py", "dintdur.py", "dintmon.py",
+         "dintcal.py", "dintcal.py", "dintmut.py"]
+    # the three matrix gates carry the allowlist-rot dry-run
+    assert "--prune-allowlist --check" in lines[0]
+    assert "check --prune-allowlist --check" in lines[1]
+    assert "check --prune-allowlist --check" in lines[2]
+    assert lines[3].endswith("tests/fixtures/dintmon_counters.json")
+    assert os.path.exists(os.path.join(
+        REPO, "tests", "fixtures", "dintmon_counters.json"))
+    assert "check" in lines[4] and "--sarif" in lines[4]
+    assert lines[5].endswith("tests/fixtures/dintcal_journal.jsonl")
+    assert os.path.exists(os.path.join(
+        REPO, "tests", "fixtures", "dintcal_journal.jsonl"))
+    assert "check --quick" in lines[6]       # the dintmut sampled tier
+
+    doc = json.loads(merged.read_text())
+    assert doc["version"] == "2.1.0"
+    assert sorted(r_["tool"]["driver"]["name"] for r_ in doc["runs"]) \
+        == ["dintcal", "dintcost", "dintdur", "dintlint", "dintmut"]
+
+    # the per-stage wall-clock block: one JSON line, mirrored to --timings
+    tline = next(ln for ln in r.stdout.splitlines()
+                 if ln.startswith('{"metric": "dintgate"'))
+    tdoc = json.loads(tline)
+    assert tdoc == json.loads(timings.read_text())
+    assert [s["gate"] for s in tdoc["stages"]] == \
+        ["dintlint", "dintcost", "dintdur", "dintmon", "dintcal",
+         "dintcal-audit", "dintmut"]
+    assert all(s["ok"] is True and s["wall_s"] >= 0
+               for s in tdoc["stages"])
+    assert tdoc["total_s"] > 0
+
+    # one failing gate fails the run BY NAME, the rest still execute
+    calls.write_text("")
+    r = subprocess.run(["bash", script], capture_output=True, text=True,
+                       env=dict(env, FAIL_DUR="1"), timeout=120)
+    assert r.returncode == 1
+    assert "dintgate: FAIL" in r.stdout and "dintdur" in r.stdout
+    assert len(calls.read_text().splitlines()) == 7   # no fail-fast
+    tdoc = json.loads(next(ln for ln in r.stdout.splitlines()
+                           if ln.startswith('{"metric": "dintgate"')))
+    assert [s["gate"] for s in tdoc["stages"]
+            if s["ok"] is False] == ["dintdur"]
+
+    # unknown flags are a usage error; --help documents the contract
+    assert subprocess.run(["bash", script, "--frobnicate"],
+                          capture_output=True, timeout=120).returncode == 2
+    h = subprocess.run(["bash", script, "--help"], capture_output=True,
+                       text=True, timeout=120)
+    assert h.returncode == 0 and "dintcal  check+audit" in h.stdout

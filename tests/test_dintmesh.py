@@ -257,24 +257,3 @@ def test_dintserve_cli_mesh_virtual_run():
     served = sum(int(w) * n for w, n in rep["steps_by_width"].items())
     assert rep["counters"]["serve_occupancy_lanes"] + \
         rep["counters"]["serve_padded_lanes"] == served * 8
-
-
-def test_mesh_engine_resolves_geometry_knobs_from_plan():
-    """ISSUE 17: hierarchical/overlap left unset resolve from the
-    pinned plan's multihost_serve workload (hierarchical ON / overlap
-    OFF pending the pre-registered hardware A/B) and the snapshot
-    carries the plan provenance alongside the mesh geometry."""
-    eng = MeshServeEngine(N, mesh_shape=(H, C),
-                          cfg=ControllerCfg(widths=(8, W)),
-                          model=ServiceModel(),
-                          cohorts_per_block=CPB, clock=VirtualClock(),
-                          monitor=True, seed=0)
-    try:
-        eng.run(constant_schedule(100_000.0, 0.004))
-    finally:
-        eng.close()
-    rep = eng.snapshot()
-    assert rep["mesh"] == {"n_hosts": H, "n_ici": C,
-                           "hierarchical": True, "overlap": False}
-    assert rep["plan"]["source"].endswith("PLAN.json")
-    assert rep["plan"]["overridden"] == []

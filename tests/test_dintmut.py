@@ -105,6 +105,35 @@ def test_discovery_reproduces_the_pinned_anchor_cells():
     assert [(m.cell_id, m.site, m.note) for m in muts1] == pinned
 
 
+def test_cell_sites_carry_no_line_number(monkeypatch):
+    """A cell's site is 'file.py:function': the pinned cells name no
+    line, and a blank line inserted above every mutated function (a
+    frame stub offsetting each line by one) leaves the discovered cells
+    and their hash as they were."""
+    import re
+    # '' where the eqn recorded no source (a top-level pjit's donation)
+    site = re.compile(r"^([\w./-]+\.py:[A-Za-z_<][\w.<>]*)?$")
+    assert all(site.match(c["site"]) for c in _doc()["cells"]), \
+        [c["site"] for c in _doc()["cells"] if not site.match(c["site"])]
+
+    trace = T.get_trace(ANCHOR)
+    ops = _doc()["targets"][ANCHOR]["operators"]
+
+    def cells():
+        return [(m.cell_id, m.site, m.note) for m in M.discover(trace, ops)]
+
+    before = cells()
+    assert before and all(site.match(s) for _, s, _ in before)
+    real = M.user_frame_of
+
+    def shifted(eqn):
+        frame = real(eqn)
+        return frame and (frame[0], frame[1] + 1, frame[2])
+    monkeypatch.setattr(M, "user_frame_of", shifted)
+    assert cells() == before
+    assert M._digest(cells()) == M._digest(before)
+
+
 def test_every_discovered_mutant_builds():
     """Each mutant rewrite produces a ClosedJaxpr the passes can walk —
     the corruption is structural, never a crash of the mutator itself."""

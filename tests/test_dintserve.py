@@ -34,8 +34,7 @@ from dint_tpu.serve import (ArrivalStream, ControllerCfg, ServeEngine,
                             ServiceModel, VirtualClock, WidthController,
                             burst_schedule, cached_runner, choose_width,
                             constant_schedule, make_schedule, max_backlog,
-                            poisson_schedule, recommend_hot_frac,
-                            simulate_widths)
+                            poisson_schedule, simulate_widths)
 
 pytestmark = pytest.mark.serve
 
@@ -142,15 +141,6 @@ def test_max_backlog_floor_and_growth():
     small = max_backlog(256, m.service_us(256), cfg)
     big = max_backlog(8192, m.service_us(8192), cfg)
     assert big > small > 256
-
-
-def test_recommend_hot_frac():
-    assert recommend_hot_frac(0.1, 0, 0) == 0.1            # no evidence
-    assert recommend_hot_frac(0.1, 50, 50) == 0.2          # miss -> double
-    assert recommend_hot_frac(0.4, 0, 100) == 0.5          # clamped at hi
-    assert recommend_hot_frac(0.25, 1000, 1) == 0.125      # saturated -> halve
-    assert recommend_hot_frac(1 / 64, 1000, 0) == 1 / 64   # clamped at lo
-    assert recommend_hot_frac(0.2, 95, 5) == 0.2           # in band: hold
 
 
 def test_width_controller_hysteresis_and_both_directions():
@@ -562,144 +552,3 @@ def test_choose_width_exactly_at_knee_capacity():
     cap = knee / (m.service_us(knee) * 1e-6)
     assert choose_width(cap, s, cfg) == (knee, False)
     assert choose_width(cap * (1 + 1e-9), s, cfg) == (knee, True)
-
-
-def test_recommend_hot_frac_boundary_holds():
-    """Edge pins: a hit rate EXACTLY at the grow target (0.90) or at
-    the shrink threshold (0.995) HOLDS — both comparisons are strict —
-    while an all-hot tally (rate 1.0) shrinks, and a recommendation
-    already sitting on a clamp stays put."""
-    assert recommend_hot_frac(0.1, 90, 10) == 0.1        # == target
-    assert recommend_hot_frac(0.1, 995, 5) == 0.1        # == shrink
-    assert recommend_hot_frac(0.2, 100, 0) == 0.1        # all-hot: halve
-    assert recommend_hot_frac(0.5, 1, 99) == 0.5         # grow at hi
-    assert recommend_hot_frac(1 / 64, 100, 0) == 1 / 64  # shrink at lo
-
-
-# --------------------------------- plan-resolved serving (ISSUE 17)
-
-
-def test_serve_engine_resolves_plan_by_default():
-    """Tentpole consumer pin: with no plan argument the engine reads
-    the pinned PLAN.json — the snapshot records provenance (source +
-    cost-model hash, zero overrides) and the hot_frac rebuild loop
-    seeds from the plan's serve prior with the counter plane on."""
-    from dint_tpu.clients import workloads as wl
-    eng = ServeEngine("smallbank_dense", N_ACC,
-                      cfg=ControllerCfg(widths=(16, W)),
-                      cohorts_per_block=CPB, clock=VirtualClock(),
-                      monitor=True, seed=0)
-    try:
-        eng.run(constant_schedule(5_000.0, 0.004))
-    finally:
-        eng.close()
-    rep = eng.snapshot()
-    assert rep["plan"] is not None
-    assert rep["plan"]["source"].endswith("PLAN.json")
-    assert rep["plan"]["hash"] and rep["plan"]["overridden"] == []
-    assert rep["hot_frac"] == {"current": wl.SB_HOT_FRAC,
-                               "adaptive": True, "rebuilds": 0}
-
-
-def test_serve_engine_cfg_and_model_from_plan_priors():
-    """cfg=None pulls the width menu + SLO from the plan's serve
-    priors, model=None the ServiceModel coefficients. A doctored plan
-    dict proves the values actually flow (widths trimmed to the two
-    already-compiled test widths so no fresh jit rides the assert)."""
-    import copy
-
-    from dint_tpu.analysis import plan as P
-    doc = copy.deepcopy(P.load_plan())
-    serve = doc["workloads"]["smallbank_serve"]["serve"]
-    serve["widths"] = {"16": serve["widths"]["256"],
-                       str(W): serve["widths"]["256"]}
-    serve["slo_us"] = 4321.0
-    serve["model"] = {"base_us": 149.0, "per_lane_ns": 41.0}
-    eng = ServeEngine("smallbank_dense", N_ACC, cohorts_per_block=CPB,
-                      clock=VirtualClock(), monitor=True, seed=0,
-                      plan=doc)
-    try:
-        assert eng.cfg.widths == (16, W)
-        assert eng.cfg.slo_us == 4321.0
-        assert (eng.model.base_us, eng.model.per_lane_ns) == (149.0, 41.0)
-    finally:
-        eng.close()
-
-
-def test_serve_engine_plan_none_records_null():
-    """plan=None disables plan consumption: the snapshot records
-    ``"plan": None`` explicitly — never a silent default — and with no
-    prior and no caller pin the hot_frac loop stays off."""
-    eng = ServeEngine("smallbank_dense", N_ACC,
-                      cfg=ControllerCfg(widths=(16, W)),
-                      cohorts_per_block=CPB, clock=VirtualClock(),
-                      monitor=True, seed=0, plan=None)
-    try:
-        eng.run(constant_schedule(5_000.0, 0.004))
-    finally:
-        eng.close()
-    rep = eng.snapshot()
-    assert rep["plan"] is None
-    assert rep["hot_frac"] == {"current": None, "adaptive": False,
-                               "rebuilds": 0}
-
-
-def test_plan_resolved_run_bit_identical_to_hand_config():
-    """THE acceptance pin: a plan-resolved serve run is bit-identical
-    to the same configuration passed entirely by hand (plan=None +
-    hot_frac pinned to the plan's prior). Only the provenance stamp may
-    differ — every counter, histogram bucket, width decision and
-    committed lane must match field for field."""
-    from dint_tpu.clients import workloads as wl
-    sched = constant_schedule(30_000.0, 0.01)
-
-    def snap(**kw):
-        eng = ServeEngine("smallbank_dense", N_ACC,
-                          cfg=ControllerCfg(widths=(16, W)),
-                          cohorts_per_block=CPB, clock=VirtualClock(),
-                          monitor=True, seed=0, **kw)
-        try:
-            eng.run(sched)
-        finally:
-            eng.close()
-        return eng.snapshot()
-
-    # the plan's serve priors carry the resolver's ServiceModel (pinned
-    # CALIB.json coefficients when present) — the hand config must pass
-    # the SAME model or the two runs legitimately diverge
-    from dint_tpu.monitor.calib import resolve_service_model
-    model, _ = resolve_service_model()
-
-    a = snap()                                       # plan-resolved
-    b = snap(plan=None, model=model,                 # ... by hand
-             runner_kw={"hot_frac": wl.SB_HOT_FRAC})
-    assert a["plan"] is not None and b["plan"] is None
-    a.pop("plan"), b.pop("plan")
-    assert a == b
-
-
-def test_hot_frac_rebuild_at_width_switch_drain():
-    """The engine rebuilds its width menu at the recommended hot_frac
-    ONLY at width-switch drain boundaries: a pinned recommendation
-    (0.25) applies at the FIRST switch of an overload trajectory —
-    one rebuild, not one per switch — and later switches no-op once
-    cur == rec."""
-    from dint_tpu.clients import workloads as wl
-    # start from the prior the bit-identity test already compiled, so
-    # the only fresh jits here are the two post-rebuild runners
-    eng = ServeEngine("smallbank_dense", N_ACC,
-                      cfg=ControllerCfg(widths=(16, W)),
-                      cohorts_per_block=CPB, clock=VirtualClock(),
-                      monitor=True, seed=0, plan=None,
-                      runner_kw={"hot_frac": wl.SB_HOT_FRAC},
-                      adapt_hot_frac=True)
-    eng.hot_frac_recommendation = lambda cur: 0.25
-    try:
-        eng.run(constant_schedule(800_000.0, 0.01))
-    finally:
-        eng.close()
-    rep = eng.snapshot()
-    assert len(rep["controller"]["switches"]) >= 2       # up AND down
-    assert rep["hot_frac"] == {"current": 0.25, "adaptive": True,
-                               "rebuilds": 1}
-    assert eng.runner_kw["hot_frac"] == 0.25

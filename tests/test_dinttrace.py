@@ -355,7 +355,7 @@ def test_dintmon_check_cli(tmp_path):
     good = {"counters": {
         "lock_requests": 10, "lock_granted": 7, "lock_rejected": 3,
         "lock_reject_held": 2, "lock_reject_arb": 1,
-        "steps": 4, "dispatch_xla": 4, "dispatch_pallas": 0}}
+        "steps": 4}}
     p = str(tmp_path / "good.json")
     json.dump(good, open(p, "w"))
     r = _cli("tools/dintmon.py", "check", p)

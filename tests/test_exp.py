@@ -90,3 +90,19 @@ def test_run_point_raises_instead_of_recording_an_error_artifact(tmp_path):
     assert calls == [1]                          # ran once, not retried
     assert sorted(os.listdir(tmp_path)) == ["good.json"]
     assert "bad" not in sink
+
+
+@pytest.mark.parametrize("var, read, unset, value, want", [
+    ("DINT_TRACE", "_trace_on", False, "1", True),
+    ("DINT_TRACE_RATE", "_trace_rate", 1.0, "0.25", 0.25),
+    ("DINT_MONITOR", "_monitor_on", False, "1", True),
+], ids=["trace", "trace_rate", "monitor"])
+def test_the_sweep_reads_its_own_variables(monkeypatch, var, read, unset,
+                                           value, want):
+    """The library reads no environment variable that shapes a program:
+    the sweep reads the ones it documents and passes the values down to
+    the builders and clients."""
+    monkeypatch.delenv(var, raising=False)
+    assert getattr(exp, read)() == unset
+    monkeypatch.setenv(var, value)
+    assert getattr(exp, read)() == want

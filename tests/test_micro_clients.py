@@ -17,6 +17,16 @@ def test_store_client_mixes(rng):
         assert blk.p99_us >= blk.p50_us > 0
 
 
+def test_store_client_zipf_waves():
+    """The micro client end-to-end on Zipfian keys: the jitted step keeps
+    the magic intact and goodput == batch width."""
+    rng = np.random.default_rng(0)
+    c = micro.StoreClient.populated(2000, width=256, key_dist="zipfian")
+    assert not c.use_scan
+    for _ in range(3):
+        assert c.run_wave(rng) == 256
+
+
 def test_store_client_scan_mix(rng):
     """YCSB-E-shaped wave through the scan-threaded step: every scan
     lane answers VAL (run fresh each rebuild_every waves), counts obey

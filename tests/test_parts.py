@@ -42,8 +42,7 @@ def _dense(monitor=True):
 
 def _bank(monitor=True):
     run, init, drain = sd.build_pipelined_runner(
-        N_SUB, w=W, cohorts_per_block=CPB, monitor=monitor,
-        use_hotset=False, trace=False)
+        N_SUB, w=W, cohorts_per_block=CPB, monitor=monitor, trace=False)
     return run, init(sd.create(N_SUB, log_capacity=1 << 10)), drain
 
 
@@ -555,7 +554,7 @@ def test_the_sharded_smallbank_parts_reach_compiled_hlo_under_their_waves():
     names = _op_names(jax.jit(run).lower(
         carry, jax.random.PRNGKey(0)).compile().as_text())
     # the CPU compiles the carry's `x[0]` / `x[None]` to bitcasts, which
-    # keep no name; the v5e's copies do (tests/test_chip_compile.py)
+    # keep no name; the v5e's copies do (tests/test_chip_compile_*.py)
     _assert_parts_under_their_waves(names, ("dense_sharded_sb",),
                                     skip=("sbx_carry",))
     for part, under in SBX_PARTS.items():

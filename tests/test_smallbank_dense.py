@@ -125,7 +125,7 @@ def test_hashed_lock_slots_conserve_balance(monkeypatch):
 
 # ------------------------------------------- against the sequential reference
 
-ENGINE = dict(use_hotset=False, trace=False)
+ENGINE = dict(trace=False)
 N_ACC, W, CPB = 2000, 128, 2
 
 
@@ -295,8 +295,8 @@ def test_sorted_scatter_lanes(table_words, lanes, want):
     pytest.param(SMALL, W * sd.L, id="as_is"),
     pytest.param(FILLED, 384, id="filled")])
 def test_the_install_asks_for_no_sort(geometry, issued):
-    """The sort is the compiler's (tests/test_chip_compile.py): the block
-    holds none, filled or not, its one scatter into the balances issues
+    """The sort is the compiler's (tests/test_chip_compile_smallbank.py):
+    the block holds none, filled or not, its one scatter into the balances issues
     the lanes the rule gives, and the protocol pass proves that install
     from the lock grants, through the fill and with no sort to lean on."""
     n_acc, w = geometry

@@ -40,8 +40,8 @@ def test_the_geometry_compacts_and_a_chunk_is_half_the_width():
 
 
 def _step(table, batch):
-    return store._step(table, batch, maintain_bloom=False, hot=None,
-                       run=None, scan_max=8)
+    return store._step(table, batch, maintain_bloom=False, run=None,
+                       scan_max=8)
 
 
 def _step_at_full_width(table, batch):

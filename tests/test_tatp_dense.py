@@ -604,7 +604,7 @@ def test_kernel_keywords_are_accepted_only_as_off(name):
     _, build, _ = _small_builders()[name]
     for kw in ({"use_pallas": True}, {"use_fused": True},
                {"use_pallas": 1, "use_fused": False}):
-        with pytest.raises(ValueError, match="PR 35"):
+        with pytest.raises(ValueError, match="retired keyword"):
             build(**kw)
     for off in (False, None):
         run, init, drain = build(use_pallas=off, use_fused=off)

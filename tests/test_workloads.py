@@ -57,3 +57,15 @@ def test_ycsb_e_ops_scan_frac_knob():
     s0, _, l0 = wl.ycsb_e_ops(np.random.default_rng(5), 1_000, 1_000,
                               scan_frac=0.0)
     assert not s0.any() and (l0 == 0).all()
+
+
+def test_zipf_keys_hot_head():
+    """rank == key id: the Zipfian head concentrates on the smallest ids,
+    in range, strongly skewed at theta=0.99."""
+    rng = np.random.default_rng(0)
+    k = wl.zipf_keys(rng, 100_000, 10_000)
+    assert k.min() >= 1 and k.max() <= 10_000
+    assert (k <= 400).mean() > 0.5            # 4% of keys, >50% of draws
+    # theta=0 degenerates toward uniform
+    u = wl.zipf_keys(rng, 100_000, 10_000, theta=0.0)
+    assert abs((u <= 400).mean() - 0.04) < 0.01

@@ -1,17 +1,16 @@
-"""dintcal CLI: the calibration & prediction-audit plane + the sixth gate.
+"""dintcal CLI: the calibration & prediction-audit plane + its gate.
 
 Measured evidence closes the loop back into the model: `gather`
 normalizes bench/exp artifacts (dintscope breakdown blocks, dintmon
 counter snapshots, serve controller trajectories) into one evidence
 document, `fit` pins ServiceModel coefficients from it as a
-schema-versioned CALIB.json (PLAN.json's provenance-hash discipline),
+schema-versioned CALIB.json (provenance-hashed),
 `check` is the standing drift gate — tolerance-banded evidence
 reconciliation PLUS the static calib_check pass — and `audit` replays a
 controller decision journal through the pure policy functions,
-verifying every recorded width/shed/hot_frac decision bit-for-bit.
-`propose` emits the recalibration that `tools/dintplan.py plan --calib`
-consumes, so hardware sweeps re-pin the plan from evidence instead of
-DINT_PLAN_OVERRIDE=1 hand edits.
+verifying every recorded width/shed decision bit-for-bit.
+`propose` emits the recalibration from evidence, to be pinned as
+CALIB.json (the serving plane's default service-time prior).
 
 Usage:
     python tools/dintcal.py gather ART [ART...] -o evidence.json
@@ -125,17 +124,16 @@ def _resolve_evidence(args, calib, cpath):
 def cmd_check(args, ap) -> int:
     from dint_tpu import analysis
     from dint_tpu.analysis import allowlist as AL
-    from dint_tpu.analysis import plan as P
     from dint_tpu.analysis.core import Finding, SEV_ERROR
+    from dint_tpu.analysis.passes.calib_check import ANCHOR as anchor
 
     cpath = Path(args.calib) if args.calib else CAL.calib_path()
     if args.calib:
         os.environ[CAL.ENV_CALIB_PATH] = args.calib
-    anchor = os.environ.get(P.ENV_PLAN_ANCHOR, P.DEFAULT_ANCHOR)
     allowlist = cli.resolve_allowlist(args.allowlist)
 
     # half 1: the static calib_check pass (provenance, refit equality,
-    # wave registry, plan model attribution) under the dintlint allowlist
+    # wave registry) under the dintlint allowlist
     findings = analysis.run(targets=[anchor], passes=["calib_check"],
                             allowlist_path=allowlist)
 
@@ -164,8 +162,8 @@ def cmd_check(args, ap) -> int:
                     "calib_check", "evidence-drift", SEV_ERROR, anchor,
                     d["message"], site=f"{d['what']}:{d['name']}",
                     suggestion="recalibrate with `python tools/"
-                               "dintcal.py propose` and re-pin via "
-                               "`python tools/dintplan.py plan --calib`"))
+                               "dintcal.py propose` and pin the "
+                               "proposal as CALIB.json"))
     if allowlist:
         # drift findings are appended after analysis.run applied the
         # allowlist — give them the same suppression chance (no unused-
@@ -239,15 +237,14 @@ def cmd_propose(args, ap) -> int:
             "mode": "propose", "out": str(out),
             "evidence": evidence_path, "model": proposed["model"],
             "delta": delta, "provenance": proposed["provenance"],
-            "repin": f"python tools/dintplan.py plan --calib {out}"}),
+            "repin": f"mv {out} CALIB.json"}),
             flush=True)
         return 0
     print(f"wrote {out} from {evidence_path}")
     for c in ("base_us", "per_lane_ns"):
         was = f" (pinned {calib['model'][c]})" if calib else ""
         print(f"  {c:12s} {proposed['model'][c]}{was}")
-    print(f"re-pin the plan with: python tools/dintplan.py plan "
-          f"--calib {out}")
+    print(f"pin it with: mv {out} CALIB.json")
     return 0
 
 
@@ -350,8 +347,8 @@ def main(argv=None) -> int:
     p.set_defaults(fn=cmd_audit)
 
     p = sub.add_parser("propose",
-                       help="emit a recalibration from evidence for "
-                            "`dintplan plan --calib` to re-pin")
+                       help="emit a recalibration from evidence, to "
+                            "pin as CALIB.json")
     p.add_argument("--calib", default=None)
     p.add_argument("--evidence", default=None)
     p.add_argument("-o", "--out", default=None,

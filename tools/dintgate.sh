@@ -1,12 +1,12 @@
 #!/usr/bin/env bash
-# dintgate: ONE entry point for all seven standing static gates.
+# dintgate: ONE entry point for all six standing static gates.
 #
-#   tools/dintgate.sh [--quick] [--sarif PATH] [--timings PATH]
+#   tools/dintgate.sh [--sarif PATH] [--timings PATH]
 #
 # Gates, in dependency-free order:
 #   1. dintlint --prune-allowlist --check
 #                              every analysis pass over every target
-#                              (plan_check + calib_check + mut_check ride
+#                              (calib_check + mut_check ride
 #                              along in STATIC form), PLUS the allowlist
 #                              staleness dry-run: a stale entry fails the
 #                              gate without rewriting the file
@@ -18,15 +18,13 @@
 #                              the durability/replication gate over the
 #                              full matrix + durability-scoped allowlist
 #                              staleness
-#   4. dintplan check          the FULL planner gate (re-derives every
-#                              frontier price; --quick keeps it static)
-#   5. dintmon  check          the counter-identity gate on the pinned
+#   4. dintmon  check          the counter-identity gate on the pinned
 #                              fixture artifact (no trace run needed)
-#   6. dintcal  check+audit    the calibration gate: pinned CALIB.json
+#   5. dintcal  check+audit    the calibration gate: pinned CALIB.json
 #                              reconciles with its evidence fixture, and
 #                              the checked-in decision journal replays
 #                              bit-for-bit through the pure policy
-#   7. dintmut  check --quick  the mutation-coverage gate: the pinned
+#   6. dintmut  check --quick  the mutation-coverage gate: the pinned
 #                              deterministic mutant sample re-executes
 #                              bit-for-bit against MUTCOV.json, on top of
 #                              the static mut_check policy (kill-rate
@@ -47,16 +45,14 @@
 set -u
 cd "$(dirname "$0")/.."
 
-QUICK=0
 SARIF=""
 TIMINGS_OUT=""
 while [ $# -gt 0 ]; do
     case "$1" in
-        --quick) QUICK=1 ;;
         --sarif) shift; SARIF="${1:?--sarif needs a path}" ;;
         --timings) shift; TIMINGS_OUT="${1:?--timings needs a path}" ;;
         -h|--help)
-            sed -n '2,47p' "$0" | sed 's/^# \{0,1\}//'
+            sed -n '2,44p' "$0" | sed 's/^# \{0,1\}//'
             exit 0 ;;
         *) echo "dintgate: unknown argument: $1 (try --help)" >&2; exit 2 ;;
     esac
@@ -66,9 +62,6 @@ done
 PY="${PYTHON:-python}"
 TMP="$(mktemp -d)"
 trap 'rm -rf "$TMP"' EXIT
-
-PLAN_ARGS=""
-[ "$QUICK" = 1 ] && PLAN_ARGS="--static"
 
 FAIL=""
 STAGES=""
@@ -96,7 +89,6 @@ run_gate dintcost "$PY" tools/dintcost.py check --prune-allowlist --check \
     --sarif "$TMP/cost.sarif"
 run_gate dintdur  "$PY" tools/dintdur.py check --prune-allowlist --check \
     --sarif "$TMP/dur.sarif"
-run_gate dintplan "$PY" tools/dintplan.py check $PLAN_ARGS --sarif "$TMP/plan.sarif"
 run_gate dintmon  "$PY" tools/dintmon.py check tests/fixtures/dintmon_counters.json
 run_gate dintcal  "$PY" tools/dintcal.py check --sarif "$TMP/cal.sarif"
 run_gate dintcal-audit "$PY" tools/dintcal.py audit tests/fixtures/dintcal_journal.jsonl
@@ -125,8 +117,7 @@ fi
 
 T_ALL1=$(date +%s.%N)
 TOTAL=$(awk -v a="$T_ALL0" -v b="$T_ALL1" 'BEGIN{printf "%.3f", b - a}')
-QUICK_JSON=false; [ "$QUICK" = 1 ] && QUICK_JSON=true
-TIMING_LINE="{\"metric\": \"dintgate\", \"schema\": 1, \"quick\": $QUICK_JSON, \"stages\": [${STAGES%, }], \"total_s\": $TOTAL}"
+TIMING_LINE="{\"metric\": \"dintgate\", \"schema\": 2, \"stages\": [${STAGES%, }], \"total_s\": $TOTAL}"
 echo "$TIMING_LINE"
 if [ -n "$TIMINGS_OUT" ]; then
     printf '%s\n' "$TIMING_LINE" > "$TIMINGS_OUT"
@@ -134,7 +125,7 @@ if [ -n "$TIMINGS_OUT" ]; then
 fi
 
 if [ -z "$FAIL" ]; then
-    echo "dintgate: all 7 gates ok"
+    echo "dintgate: all 6 gates ok"
     exit 0
 fi
 echo "dintgate: FAIL —$FAIL"

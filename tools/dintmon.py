@@ -156,8 +156,6 @@ _IDENTITIES = (
      ("lock_requests",), ("lock_granted", "lock_rejected"), None),
     ("lock_rejected == lock_reject_held + lock_reject_arb",
      ("lock_rejected",), ("lock_reject_held", "lock_reject_arb"), None),
-    ("steps == dispatch_xla + dispatch_pallas",
-     ("steps",), ("dispatch_xla", "dispatch_pallas"), None),
     ("route_ici_lanes + route_dcn_lanes == lock_requests + install_writes",
      ("route_ici_lanes", "route_dcn_lanes"),
      ("lock_requests", "install_writes"),

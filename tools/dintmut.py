@@ -1,14 +1,14 @@
 #!/usr/bin/env python
 """dintmut — mutation-coverage gate over the static analysis matrix.
 
-The six standing gates claim they catch unlocked installs, dropped
+The other standing gates claim they catch unlocked installs, dropped
 replication, unbounded rings and cost regressions. dintmut PROVES it:
 analysis/mutate.py corrupts the traced engines with a registered
 operator set (drop-eqn, weaken-scatter, mask-swap, axis-swap,
 widen-gather, drop-donation, ring-shrink), re-runs every structural
 pass on each mutant, attributes the kill to the pass/code that fired,
-and pins the verdict matrix as MUTCOV.json under the PLAN.json
-provenance discipline. passes/mut_check.py is the standing gate over
+and pins the verdict matrix as MUTCOV.json under a provenance-hash
+discipline. passes/mut_check.py is the standing gate over
 the pinned artifact (kill-rate floor, survivor triage, killer-family
 coverage) — this CLI adds the re-execution tiers on top.
 

@@ -62,23 +62,11 @@ def _mesh_shape(s: str) -> tuple[int, int]:
     return int(m.group(1)), int(m.group(2))
 
 
-def _plan_arg(spec: str):
-    """--plan auto|off|PATH -> the ServeEngine plan parameter."""
-    if spec == "auto":
-        return "auto"
-    if spec == "off":
-        return None
-    with open(spec) as fh:
-        return json.load(fh)
-
-
 def cmd_run(args) -> int:
     from dint_tpu.serve import (ControllerCfg, MeshServeEngine, ServeEngine,
                                 ServiceModel, VirtualClock)
-    # flags still win; left at their defaults (None), the width menu /
-    # SLO / service prior resolve from the pinned plan's serve priors
-    # inside ServeEngine (and fall back to the historical defaults when
-    # no plan is readable)
+    # left at their defaults (None), the width menu / SLO / service prior
+    # are ServeEngine's own: ControllerCfg() and the pinned CALIB.json
     cfg = model = None
     if args.widths is not None or args.slo_us is not None:
         cfg = ControllerCfg(
@@ -90,21 +78,19 @@ def cmd_run(args) -> int:
             else 150.0,
             per_lane_ns=args.model_per_lane_ns
             if args.model_per_lane_ns is not None else 40.0)
-    plan = _plan_arg(args.plan)
     clock = VirtualClock() if args.virtual else None
     if args.mesh:
         eng = MeshServeEngine(args.size, mesh_shape=_mesh_shape(args.mesh),
                               cfg=cfg, model=model,
                               cohorts_per_block=args.cpb, depth=args.depth,
                               clock=clock, monitor=not args.no_monitor,
-                              seed=args.seed, overlap=args.overlap,
-                              plan=plan)
+                              seed=args.seed, overlap=args.overlap)
         label = f"mesh {args.mesh} multihost_sb"
     else:
         eng = ServeEngine(args.engine, args.size, cfg=cfg, model=model,
                           cohorts_per_block=args.cpb, depth=args.depth,
                           clock=clock, monitor=not args.no_monitor,
-                          seed=args.seed, plan=plan)
+                          seed=args.seed)
         label = args.engine
     cfg = eng.cfg
     if not args.virtual:
@@ -138,13 +124,6 @@ def cmd_run(args) -> int:
     ctl = rep["controller"]
     print(f"  width    final={ctl['width']} switches={ctl['switches']} "
           f"saturated={ctl['saturated']}")
-    pl = rep.get("plan")
-    if pl:
-        over = (" env-overridden: " + ",".join(pl["overridden"])
-                if pl["overridden"] else "")
-        print(f"  plan     {pl['source']} (cost_model {pl['hash']}){over}")
-    else:
-        print("  plan     (none)")
     c = rep["counters"]
     if c:
         print(f"  lanes    occupancy={c.get('serve_occupancy_lanes', 0)} "
@@ -261,8 +240,7 @@ def main() -> int:
         p.add_argument("--burst-lanes", type=int, default=4096)
         p.add_argument("--burst-every-s", type=float, default=0.01)
         p.add_argument("--widths", default=None,
-                       help="width menu (default: the pinned plan's "
-                            "serve priors, else 256,1024,4096,8192)")
+                       help="width menu (default: 256,1024,4096,8192)")
         p.add_argument("--slo-us", type=float, default=None)
         p.add_argument("--cpb", type=int, default=4,
                        help="cohorts per dispatched block")
@@ -278,17 +256,9 @@ def main() -> int:
         if engine:
             p.add_argument("--engine", default="tatp_dense",
                            choices=("tatp_dense", "smallbank_dense"))
-            p.add_argument("--overlap", action="store_true", default=None,
+            p.add_argument("--overlap", action="store_true",
                            help="mesh only: serve through the double-"
-                                "buffered route (PERF.md round 18); "
-                                "unset = the pinned plan's choice")
-            p.add_argument("--plan", default="auto", metavar="auto|off|PATH",
-                           help="PLAN.json consumption: 'auto' (default) "
-                                "reads the pinned plan, 'off' disables it "
-                                "(the report records \"plan\": null), a "
-                                "path reads that plan file; DINT_* env "
-                                "flags beat the plan only under "
-                                "DINT_PLAN_OVERRIDE=1")
+                                "buffered route (PERF.md round 18)")
             p.add_argument("--size", type=int, default=100_000,
                            help="n_sub / n_accounts")
             p.add_argument("--depth", type=int, default=2,

@@ -90,8 +90,8 @@ python tools/dintmon.py summarize mon_r18_mesh.jsonl | tail -8 || true
 
 echo "=== stage 6: archive CALIB evidence + recalibration proposal ==="
 # mesh-measured (width, service) samples + the per-host shed journal
-# feed the dintcal loop: re-pin with `dintplan plan --calib`, never a
-# DINT_PLAN_OVERRIDE=1 hand edit
+# feed the dintcal loop: pin the proposal as CALIB.json, never a hand
+# edit
 JAX_PLATFORMS=cpu python tools/dintcal.py gather serve_mesh_*.json \
     -o calib_evidence_mesh.json || true
 JAX_PLATFORMS=cpu python tools/dintcal.py propose \

@@ -15,7 +15,7 @@ echo "=== stage 1: scan-fraction ladder ==="
 # the tentpole measurement: YCSB-B (0%) through YCSB-E (95%) at one
 # width, Zipfian starts, run rebuilt at every drain boundary; every
 # artifact carries the "scan" object (resolved routes + mix)
-DINT_USE_SCAN=1 timeout 3600 python exp.py --out scan_results \
+timeout 3600 python exp.py --out scan_results \
     --window 10 --only store_scan > scan_sweep.log 2>&1 || true
 tail -5 scan_sweep.log
 for f in scan_results/store_scan_*.json; do
@@ -30,16 +30,7 @@ print(f"{sys.argv[1]}: goodput={d.get('goodput')}/s "
 EOF
 done
 
-echo "=== stage 2: serve-plane scan point (counters reconcile) ==="
-# the open-loop serve path with a 50% scan mix: scan_requests /
-# scan_rows / scan_delta_hits flow through dintmon and must reconcile
-# with the offered mix (requests ~= 0.5 * committed, rows <= max*requests)
-DINT_USE_SCAN=1 DINT_MONITOR=1 timeout 1200 python tools/dintserve.py \
-    run --engine store --size 1000000 --rate 200000 --window 5 \
-    --slo-us 5000 --widths 1024,4096 --json > scan_serve.json || true
-tail -1 scan_serve.json
-
-echo "=== stage 3: static model beside the measurements ==="
+echo "=== stage 2: static model beside the measurements ==="
 # the @scan dintcost rows the measured bytes should agree with,
 # including the scan-bytes-dominance gate (56 B/row < 92 B/probe at
 # the calibration geometry) — derived on CPU, no chip time
@@ -47,10 +38,9 @@ JAX_PLATFORMS=cpu python tools/dintcost.py report --all --json \
     > dintcost_r20.json 2> /dev/null || true
 JAX_PLATFORMS=cpu python tools/dintcost.py check --all || true
 
-echo "=== stage 4: archive CALIB evidence + recalibration proposal ==="
+echo "=== stage 3: archive CALIB evidence + recalibration proposal ==="
 # dintcal closes the loop: ladder artifacts feed a recalibration the
-# operator re-pins with `dintplan plan --calib`, never a
-# DINT_PLAN_OVERRIDE=1 hand edit
+# operator pins as CALIB.json, never a hand edit
 JAX_PLATFORMS=cpu python tools/dintcal.py gather scan_results/*.json \
     -o calib_evidence_scan.json || true
 JAX_PLATFORMS=cpu python tools/dintcal.py propose \

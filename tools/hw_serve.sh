@@ -64,8 +64,8 @@ JAX_PLATFORMS=cpu python tools/dintserve.py describe || true
 
 echo "=== stage 6: archive CALIB evidence + recalibration proposal ==="
 # dintcal closes the loop: the measured (width, service) samples and
-# journals feed a recalibration the operator re-pins with
-# `dintplan plan --calib` — never a DINT_PLAN_OVERRIDE=1 hand edit
+# journals feed a recalibration the operator pins as CALIB.json —
+# never a hand edit
 JAX_PLATFORMS=cpu python tools/dintcal.py gather serve_*.json \
     -o calib_evidence_serve.json || true
 JAX_PLATFORMS=cpu python tools/dintcal.py propose \
